@@ -15,6 +15,11 @@ from dataclasses import dataclass, field
 from pathlib import Path
 
 
+# where the persistent XLA compile cache lives when JAX_COMPILATION_CACHE_DIR
+# does not place it (``WorkerConfig.configure_jax``): fixed, inside the checkout
+DEFAULT_COMPILE_CACHE_DIR = str(Path(__file__).resolve().parent.parent / ".jax_cache")
+
+
 def _env(name: str, default: str) -> str:
     v = os.environ.get(name, "").strip()
     return v or default
@@ -47,14 +52,6 @@ class WorkerConfig:
     # documented knob; TPU_MESH is honored as the legacy alias.
     mesh_shape: str = field(
         default_factory=lambda: _env("MESH_SHAPE", "") or _env("TPU_MESH", "auto")
-    )
-    # opt-in persistent XLA compilation cache (ROADMAP item 5, first
-    # bite): a restarted worker (or an autoscaled replica on identical
-    # hardware) replays compiles from disk instead of paying the
-    # multi-second jit grid again. Empty = off. Applied by
-    # ``configure_jax()`` at startup, before the first compile.
-    compile_cache_dir: str = field(
-        default_factory=lambda: _env("JAX_COMPILE_CACHE_DIR", "")
     )
     max_batch_slots: int = field(default_factory=lambda: int(_env("MAX_BATCH_SLOTS", "8")))
     max_seq_len: int = field(default_factory=lambda: int(_env("MAX_SEQ_LEN", "4096")))
@@ -463,21 +460,24 @@ class WorkerConfig:
 
     def configure_jax(self) -> None:
         """Apply process-wide JAX settings. Must run before the first
-        compile (main.py calls it ahead of mesh construction); idempotent,
-        and a no-op when no knob is set — library users who never call it
-        lose nothing but the compile cache."""
-        if not self.compile_cache_dir:
-            return
+        compile (main.py calls it ahead of mesh construction); idempotent.
+
+        Persistent XLA compile cache: a restarted worker (or an autoscaled
+        replica on identical hardware) replays compiles from disk instead
+        of paying the multi-second jit grid again. Where
+        ``JAX_COMPILATION_CACHE_DIR`` is set JAX itself honours it and no
+        directory is set here; otherwise the cache lives at one fixed path
+        inside the checkout (the path is part of the cache key, so a
+        directory that moves never hits). Library users who never call this
+        lose nothing but the cache."""
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", self.compile_cache_dir)
-        try:
-            # the serving grid is many sub-second programs (per-bucket
-            # prefills, per-window chunks); cache all of them, not just
-            # the slow ones, so a supervisor bounce replays the whole grid
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        except AttributeError:  # older jax: keep the directory, lose the knob
-            pass
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+        # the serving grid is many sub-second programs (per-bucket
+        # prefills, per-window chunks); cache all of them, not just
+        # the slow ones, so a supervisor bounce replays the whole grid
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     # timeout ladder — mirrors the reference's per-op deadlines
     # (nats_llm_studio.go:229, :251, :289, :328)

@@ -15,6 +15,8 @@ from __future__ import annotations
 from functools import lru_cache
 from typing import Any, Iterable
 
+import regex as _re  # \p{L}/\p{N} classes for byte-level BPE pretokenization
+
 from .constants import (
     KEY_TOKENIZER_ADD_BOS,
     KEY_TOKENIZER_BOS,
@@ -27,15 +29,6 @@ from .constants import (
     TokenType,
 )
 
-try:  # proper \p{L}/\p{N} classes for byte-level BPE pretokenization
-    import regex as _re
-
-    _HAVE_REGEX = True
-except ImportError:  # pragma: no cover
-    import re as _re  # type: ignore[no-redef]
-
-    _HAVE_REGEX = False
-
 _SPIECE = "▁"  # ▁
 
 # llama-3 style pretokenizer (also a good default for gpt2-family vocabs)
@@ -43,11 +36,6 @@ _BPE_PATTERN = (
     r"(?i:'s|'t|'re|'ve|'m|'ll|'d)|[^\r\n\p{L}\p{N}]?\p{L}+|\p{N}{1,3}"
     r"| ?[^\s\p{L}\p{N}]+[\r\n]*|\s*[\r\n]+|\s+(?!\S)|\s+"
 )
-_BPE_PATTERN_ASCII = (  # fallback when `regex` is unavailable
-    r"(?i:'s|'t|'re|'ve|'m|'ll|'d)|[^\r\n\w\d]?[^\W\d_]+|\d{1,3}"
-    r"| ?[^\s\w\d]+[\r\n]*|\s*[\r\n]+|\s+(?!\S)|\s+"
-)
-
 
 @lru_cache(maxsize=1)
 def _byte_to_unicode() -> dict[int, str]:
@@ -103,8 +91,7 @@ class GGUFTokenizer:
         if model == "gpt2":
             self._b2u = _byte_to_unicode()
             self._u2b = {c: b for b, c in self._b2u.items()}
-            pat = _BPE_PATTERN if _HAVE_REGEX else _BPE_PATTERN_ASCII
-            self._pre = _re.compile(pat)
+            self._pre = _re.compile(_BPE_PATTERN)
         self._control_ids = {
             i for i, tt in enumerate(token_types or []) if tt == TokenType.CONTROL
         }

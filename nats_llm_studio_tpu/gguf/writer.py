@@ -61,9 +61,15 @@ class GGUFWriter:
         GGUF's dim order back)."""
         if ggml_type is None:
             ggml_type = GGMLType.F32
-        data = quantize(np.asarray(array), ggml_type)
-        assert len(data) == type_size(ggml_type, int(np.asarray(array).size))
-        self._tensors.append((name, tuple(np.asarray(array).shape), ggml_type, data))
+        arr = np.asarray(array)
+        self.add_encoded(name, arr.shape, ggml_type, quantize(arr, ggml_type))
+
+    def add_encoded(self, name: str, shape: tuple[int, ...], ggml_type: GGMLType, data: bytes) -> None:
+        """Queue a tensor that is already encoded as ``ggml_type``. The
+        writer keeps a reference, not a copy: a caller that queues the same
+        ``data`` under many names holds it once."""
+        assert len(data) == type_size(ggml_type, int(np.prod(shape, dtype=np.int64)))
+        self._tensors.append((name, tuple(shape), ggml_type, data))
 
     # -- serialization ------------------------------------------------------
 

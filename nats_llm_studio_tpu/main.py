@@ -16,9 +16,13 @@ fact"). This CLI is that wiring, made first-class:
 
 Env contract (reference README.md:489-494, minus the LM Studio URL):
 NATS_URL, LMSTUDIO_MODELS_DIR, NATS_QUEUE_GROUP, plus MESH_SHAPE (legacy
-alias TPU_MESH; default "auto" = all local devices on tp),
-JAX_COMPILE_CACHE_DIR, MAX_BATCH_SLOTS, MAX_SEQ_LEN. Multi-host meshes
-initialize through ``jax.distributed`` when JAX_COORDINATOR_ADDRESS is set.
+alias TPU_MESH; default "auto" = all local devices on tp), MAX_BATCH_SLOTS,
+MAX_SEQ_LEN. The persistent XLA compile cache lives where JAX's own
+JAX_COMPILATION_CACHE_DIR says, else at a fixed path inside the checkout
+(config.configure_jax). Multi-host meshes initialize through
+``jax.distributed`` when JAX_COORDINATOR_ADDRESS is set. One worker process
+owns all of a host's chips: ``serve`` refuses to start on a CPU backend that
+JAX_PLATFORMS did not ask for.
 """
 
 from __future__ import annotations
@@ -52,7 +56,32 @@ def _maybe_init_distributed() -> None:
     log.info("joined distributed mesh: %d devices", len(jax.devices()))
 
 
-async def _run_serve(args: argparse.Namespace) -> None:
+def _require_requested_backend() -> None:
+    """Refuse to serve from a backend nobody asked for. When the
+    accelerator's runtime fails to initialise (another process owns the
+    chip, a broken install) JAX falls back to the CPU with a warning; a
+    worker would then answer requests in float32 with every Pallas kernel
+    in interpret mode. Serving from the CPU is a choice: JAX_PLATFORMS
+    must name it."""
+    import jax
+
+    backend = jax.default_backend()
+    asked = (jax.config.jax_platforms or "").lower().split(",")
+    if backend == "cpu" and "cpu" not in asked:
+        raise SystemExit(
+            f"serve: JAX initialised the {backend!r} backend, which was not "
+            f"asked for (JAX_PLATFORMS={jax.config.jax_platforms or ''!r}) — "
+            "the accelerator is missing or held by another process. Set "
+            "JAX_PLATFORMS=cpu to serve from the CPU on purpose."
+        )
+
+
+async def start_serve(embedded_broker: bool = False, port: int = 4222,
+                      store_dir: str | None = None):
+    """Everything ``serve`` does up to the started worker: config from the
+    environment, JAX settings, optional embedded broker, mesh, store,
+    registry, ``Worker.start()``. Returns ``(worker, shutdown)``;
+    ``await shutdown()`` drains the worker and closes what this opened."""
     from .serve import Worker
     from .serve.registry import LocalRegistry
     from .store import JetStreamStoreModule, ModelStore
@@ -70,13 +99,14 @@ async def _run_serve(args: argparse.Namespace) -> None:
     if plan is not None:
         faults.install(plan)
     broker = None
-    if args.embedded_broker:
-        broker = await EmbeddedBroker(port=args.port).start()
-        JetStreamStoreModule(broker, store_dir=args.store_dir).install()
+    if embedded_broker:
+        broker = await EmbeddedBroker(port=port).start()
+        JetStreamStoreModule(broker, store_dir=store_dir).install()
         cfg.nats_url = broker.url
         log.info("embedded broker on %s", broker.url)
 
     _maybe_init_distributed()
+    _require_requested_backend()
     from .parallel import serving_mesh
 
     mesh = serving_mesh(cfg.mesh_shape)
@@ -117,16 +147,26 @@ async def _run_serve(args: argparse.Namespace) -> None:
              cfg.subject_prefix, cfg.nats_url, cfg.worker_role or "monolithic",
              cfg.models_dir)
 
+    async def shutdown() -> None:
+        log.info("draining...")
+        await worker.drain()
+        for eng in registry.loaded_engines().values():
+            await eng.unload()  # stop the batcher owner threads
+        await nc.close()
+        if broker is not None:
+            await broker.stop()
+
+    return worker, shutdown
+
+
+async def _run_serve(args: argparse.Namespace) -> None:
+    _, shutdown = await start_serve(args.embedded_broker, args.port, args.store_dir)
     stop = asyncio.Event()
     loop = asyncio.get_running_loop()
     for sig in (signal.SIGINT, signal.SIGTERM):
         loop.add_signal_handler(sig, stop.set)
     await stop.wait()
-    log.info("draining...")
-    await worker.drain()
-    await nc.close()
-    if broker is not None:
-        await broker.stop()
+    await shutdown()
 
 
 async def _run_broker(args: argparse.Namespace) -> None:

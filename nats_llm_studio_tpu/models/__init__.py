@@ -11,6 +11,6 @@ unit regardless of depth) and sharding rules address whole stacks at once.
 """
 
 from .config import ModelConfig
-from .llama import forward, init_params, load_params_from_gguf
+from .llama import forward, init_params
 
-__all__ = ["ModelConfig", "forward", "init_params", "load_params_from_gguf"]
+__all__ = ["ModelConfig", "forward", "init_params"]

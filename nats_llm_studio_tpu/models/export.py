@@ -1,6 +1,6 @@
 """Export a params pytree to a GGUF file.
 
-Round-trips with ``load_params_from_gguf``: the fixture-creation path for
+Round-trips with ``parallel.loader.load_params_sharded``: the fixture-creation path for
 integration tests (SURVEY.md §4.1) and the conversion path for publishing
 models into the Object Store bucket in the reference's
 ``<publisher>/<model>/<file>.gguf`` layout (/root/reference/README.md:279-281).
@@ -34,16 +34,8 @@ def _rope_interleave(w: np.ndarray, n_heads: int, head_dim: int) -> np.ndarray:
     )
 
 
-def export_params_to_gguf(
-    path: str | Path,
-    params: dict[str, Any],
-    cfg: ModelConfig,
-    tokenizer_md: dict[str, Any] | None = None,
-    name: str = "exported-model",
-    quant: GGMLType = GGMLType.F32,
-    norm_quant: GGMLType = GGMLType.F32,
-) -> Path:
-    w = GGUFWriter(path)
+def config_metadata(cfg: ModelConfig, name: str) -> dict[str, Any]:
+    """The GGUF metadata ``ModelConfig.from_gguf_metadata`` reads back."""
     md: dict[str, Any] = {
         "general.architecture": cfg.arch,
         "general.name": name,
@@ -68,7 +60,20 @@ def export_params_to_gguf(
         md["granite.logit_scale"] = 1.0 / cfg.logit_scale  # stored as divisor
         if cfg.attention_scale is not None:
             md["granite.attention.scale"] = cfg.attention_scale
-    w.add_dict(md)
+    return md
+
+
+def export_params_to_gguf(
+    path: str | Path,
+    params: dict[str, Any],
+    cfg: ModelConfig,
+    tokenizer_md: dict[str, Any] | None = None,
+    name: str = "exported-model",
+    quant: GGMLType = GGMLType.F32,
+    norm_quant: GGMLType = GGMLType.F32,
+) -> Path:
+    w = GGUFWriter(path)
+    w.add_dict(config_metadata(cfg, name))
     if tokenizer_md:
         w.add_dict(tokenizer_md)
 

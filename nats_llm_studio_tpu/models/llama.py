@@ -21,6 +21,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..ops.flash_attention import (
     chunk_block_multiple,
@@ -186,6 +187,12 @@ def _attention_block(
 
         sp_ring = use_ring_prefill(mesh, t)
 
+    # flash kernels under a mesh are shard_mapped, heads on tp (_on_mesh):
+    # q/k/v [B, T, H, D] time-major, cache slabs [B, Hkv, S, D] head-major
+    h_ax = _tp_heads_axis(mesh)
+    tmajor = P(None, None, h_ax, None)
+    hmajor = P(None, h_ax, None, None)
+    hmajor_s = P(None, h_ax, None)
     if t > 1 and (sp_ring or (cfg.use_flash_attention and allow_flash)):
         # prefill at start_pos 0: the cache holds exactly k/v, so causal
         # attention over the fresh block equals attention over the cache.
@@ -201,7 +208,10 @@ def _attention_block(
                 from ..parallel.ring_attention import ring_attention
 
                 return ring_attention(q, k, v, cfg.attn_scale, mesh)
-            return flash_attention_auto(q, k, v, cfg.attn_scale)
+            return _on_mesh(
+                lambda q, k, v: flash_attention_auto(q, k, v, cfg.attn_scale),
+                mesh, (tmajor, tmajor, tmajor), tmajor,
+            )(q, k, v)
 
         if fresh_prefill:
             # the caller guarantees start_pos == 0 (single-shot prefill /
@@ -240,14 +250,19 @@ def _attention_block(
                         # HBM bytes of a bf16 slab and, decisively, no
                         # full-window dequant transient per layer per chunk
                         # (the r4 O(T^2) long-context prefill tail)
-                        return flash_attention_chunk_kvq_auto(
-                            qq, k_sl.q, k_sl.s, v_sl.q, v_sl.s,
-                            cfg.attn_scale, start_pos[0]
-                        )
-                    return flash_attention_chunk_auto(
-                        qq, k_sl.astype(qq.dtype), v_sl.astype(qq.dtype),
-                        cfg.attn_scale, start_pos[0]
-                    )
+                        return _on_mesh(
+                            lambda qq, kq, ks, vq, vs, st: flash_attention_chunk_kvq_auto(
+                                qq, kq, ks, vq, vs, cfg.attn_scale, st),
+                            mesh,
+                            (tmajor, hmajor, hmajor_s, hmajor, hmajor_s, P()),
+                            tmajor,
+                        )(qq, k_sl.q, k_sl.s, v_sl.q, v_sl.s, start_pos[0])
+                    return _on_mesh(
+                        lambda qq, ks, vs, st: flash_attention_chunk_auto(
+                            qq, ks, vs, cfg.attn_scale, st),
+                        mesh, (tmajor, hmajor, hmajor, P()), tmajor,
+                    )(qq, k_sl.astype(qq.dtype), v_sl.astype(qq.dtype),
+                      start_pos[0])
                 return gqa_attention_hmajor(
                     qq, as_attn_operand(k_sl),
                     as_attn_operand(layer_slice(v_all)),
@@ -384,55 +399,61 @@ def forward(
     return logits, k_cache, v_cache
 
 
-def _paged_attn_dispatch(q, k_pool, v_pool, tbl, pos, layer, scale: float, mesh):
-    """The Pallas paged-decode kernel, shard_mapped over tp when a mesh is
-    present (pallas_call is not GSPMD-partitionable, so the heads split is
-    explicit: q heads and pool heads shard on tp, tables/positions
-    replicate — the same layout pool_spec pins for the XLA path). The
-    batcher only routes here when Hkv % tp == 0 (the replicated-KV GQA
-    fallback stays on the XLA path)."""
-    from ..ops.paged_attention import paged_decode_attention_auto
-
-    tp = 0
-    if mesh is not None:
-        from ..parallel.mesh import AXIS_TP
-
-        tp = mesh.shape.get(AXIS_TP, 1)
-    if tp <= 1:
-        return paged_decode_attention_auto(q, k_pool, v_pool, tbl, pos, layer, scale)
-
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
+def _tp_heads_axis(mesh):
+    """The mesh axis that splits attention heads (tp), or None when there is
+    no mesh or tp == 1."""
+    if mesh is None:
+        return None
     from ..parallel.mesh import AXIS_TP
 
-    qspec = P(None, None, AXIS_TP, None)
-    cspec = P(None, None, AXIS_TP, None, None)  # pool codes: heads at index 2
-    sspec = P(None, None, AXIS_TP, None)
+    return AXIS_TP if mesh.shape.get(AXIS_TP, 1) > 1 else None
+
+
+def _on_mesh(fn, mesh, in_specs, out_specs):
+    """A Pallas kernel call under a mesh. pallas_call is not
+    GSPMD-partitionable ("Mosaic kernels cannot be automatically
+    partitioned"), so inside a sharded jit every kernel is an explicit
+    shard_map: heads split on tp — the layout the projections and cache
+    specs already pin — everything else replicated. Callers only route here
+    when Hkv % tp == 0, so each shard keeps whole GQA groups."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    from jax import shard_map
+
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
+
+
+def _paged_attn_dispatch(q, k_pool, v_pool, tbl, pos, layer, scale: float, mesh):
+    """The Pallas paged-decode kernel, shard_mapped over tp when a mesh is
+    present (``_on_mesh``): q heads and pool heads shard on tp,
+    tables/positions replicate — the same layout pool_spec pins for the XLA
+    path. The batcher only routes here when Hkv % tp == 0 (the
+    replicated-KV GQA fallback stays on the XLA path)."""
+    from ..ops.paged_attention import paged_decode_attention_auto
+
+    h = _tp_heads_axis(mesh)
+    qspec = P(None, None, h, None)
+    cspec = P(None, None, h, None, None)  # pool codes: heads at index 2
+    sspec = P(None, None, h, None)
     rep2, rep1, rep0 = P(None, None), P(None), P()
+    layer = jnp.asarray(layer, jnp.int32)
     if kv_is_quantized(k_pool):
         def f(qh, kq, ks, vq, vs, tb, ps, ly):
             return paged_decode_attention_auto(
                 qh, KVQ(q=kq, s=ks), KVQ(q=vq, s=vs), tb, ps, ly, scale
             )
 
-        fn = shard_map(
-            f, mesh=mesh,
-            in_specs=(qspec, cspec, sspec, cspec, sspec, rep2, rep1, rep0),
-            out_specs=qspec, check_rep=False,
-        )
-        return fn(q, k_pool.q, k_pool.s, v_pool.q, v_pool.s, tbl, pos,
-                  jnp.asarray(layer, jnp.int32))
+        return _on_mesh(
+            f, mesh, (qspec, cspec, sspec, cspec, sspec, rep2, rep1, rep0), qspec,
+        )(q, k_pool.q, k_pool.s, v_pool.q, v_pool.s, tbl, pos, layer)
 
     def g(qh, kp, vp, tb, ps, ly):
         return paged_decode_attention_auto(qh, kp, vp, tb, ps, ly, scale)
 
-    fn = shard_map(
-        g, mesh=mesh,
-        in_specs=(qspec, cspec, cspec, rep2, rep1, rep0),
-        out_specs=qspec, check_rep=False,
-    )
-    return fn(q, k_pool, v_pool, tbl, pos, jnp.asarray(layer, jnp.int32))
+    return _on_mesh(
+        g, mesh, (qspec, cspec, cspec, rep2, rep1, rep0), qspec,
+    )(q, k_pool, v_pool, tbl, pos, layer)
 
 
 def forward_decode_paged(
@@ -640,7 +661,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     return params
 
 
-# -- GGUF loading -----------------------------------------------------------
+# -- GGUF layout (the loader is parallel/loader.py) -------------------------
 
 
 def _rope_deinterleave(w: np.ndarray, n_heads: int, head_dim: int) -> np.ndarray:
@@ -654,64 +675,3 @@ def _rope_deinterleave(w: np.ndarray, n_heads: int, head_dim: int) -> np.ndarray
         .transpose(0, 1, 3, 2)
         .reshape(d_in, n_heads * head_dim)
     )
-
-
-def load_params_from_gguf(reader, cfg: ModelConfig, dtype: str | None = None) -> Params:
-    """Build the stacked-params pytree from a GGUFReader.
-
-    Tensor names follow the public GGUF convention (token_embd, blk.N.*,
-    output_norm, output). Weights are stored [out, in] (after the reader's
-    dim reversal) and transposed here to [in, out] so forward() uses plain
-    ``x @ w`` — the layout XLA maps straight onto the MXU.
-    """
-    dt = jnp.dtype(dtype or cfg.dtype)
-
-    def t(name: str) -> np.ndarray:
-        return reader.tensor(name).to_numpy()
-
-    def mat(name: str) -> jax.Array:
-        return jnp.asarray(np.ascontiguousarray(t(name).T), dt)
-
-    L = cfg.n_layers
-    stacked: dict[str, list] = {}
-
-    def push(key: str, arr) -> None:
-        stacked.setdefault(key, []).append(arr)
-
-    for i in range(L):
-        pre = f"blk.{i}"
-        push("attn_norm", jnp.asarray(t(f"{pre}.attn_norm.weight"), dt))
-        push("ffn_norm", jnp.asarray(t(f"{pre}.ffn_norm.weight"), dt))
-        wq = np.ascontiguousarray(t(f"{pre}.attn_q.weight").T)
-        wk = np.ascontiguousarray(t(f"{pre}.attn_k.weight").T)
-        push("wq", jnp.asarray(_rope_deinterleave(wq, cfg.n_heads, cfg.head_dim), dt))
-        push("wk", jnp.asarray(_rope_deinterleave(wk, cfg.n_kv_heads, cfg.head_dim), dt))
-        push("wv", mat(f"{pre}.attn_v.weight"))
-        push("wo", mat(f"{pre}.attn_output.weight"))
-        if cfg.attn_bias:
-            # biases live in the same output-feature space as the weights,
-            # so q/k biases need the same rope pair permutation
-            push("bq", jnp.asarray(_rope_deinterleave(
-                t(f"{pre}.attn_q.bias")[None], cfg.n_heads, cfg.head_dim)[0], dt))
-            push("bk", jnp.asarray(_rope_deinterleave(
-                t(f"{pre}.attn_k.bias")[None], cfg.n_kv_heads, cfg.head_dim)[0], dt))
-            push("bv", jnp.asarray(t(f"{pre}.attn_v.bias"), dt))
-        if cfg.is_moe:
-            push("router", mat(f"{pre}.ffn_gate_inp.weight"))
-            # stacked expert tensors: reader shape (E, ff, d) -> [E, d, ff]
-            push("w_gate_e", jnp.asarray(t(f"{pre}.ffn_gate_exps.weight").transpose(0, 2, 1), dt))
-            push("w_up_e", jnp.asarray(t(f"{pre}.ffn_up_exps.weight").transpose(0, 2, 1), dt))
-            push("w_down_e", jnp.asarray(t(f"{pre}.ffn_down_exps.weight").transpose(0, 2, 1), dt))
-        else:
-            push("w_gate", mat(f"{pre}.ffn_gate.weight"))
-            push("w_up", mat(f"{pre}.ffn_up.weight"))
-            push("w_down", mat(f"{pre}.ffn_down.weight"))
-
-    params: Params = {
-        "embed": jnp.asarray(t("token_embd.weight"), dt),
-        "out_norm": jnp.asarray(t("output_norm.weight"), dt),
-        "blocks": {k: jnp.stack(v) for k, v in stacked.items()},
-    }
-    if "output.weight" in reader.tensors:
-        params["lm_head"] = mat("output.weight")
-    return params

@@ -1,7 +1,7 @@
 """Process-wide XLA compile-cache hit/miss counters.
 
-JAX's persistent compilation cache (config.configure_jax wires
-``JAX_COMPILE_CACHE_DIR``) reports hits and misses only through
+JAX's persistent compilation cache (placed by ``JAX_COMPILATION_CACHE_DIR``
+or, without it, by config.configure_jax) reports hits and misses only through
 ``jax.monitoring`` events — invisible to operators unless something
 listens. This module turns them into two monotonic counters the worker
 exposes as ``lmstudio_compile_cache_{hits,misses}_total``, which is how
@@ -22,8 +22,8 @@ _lock = threading.Lock()
 _counts = {"hits": 0, "misses": 0}
 _installed = False
 
-# jax.monitoring event suffixes → counter keys (jax 0.4.x names the
-# events /jax/compilation_cache/cache_{hits,misses})
+# jax.monitoring event suffixes → counter keys (the events are
+# /jax/compilation_cache/cache_{hits,misses})
 _EVENT_KEYS = {"cache_hits": "hits", "cache_misses": "misses"}
 
 
@@ -36,16 +36,13 @@ def _on_event(event: str, **kwargs) -> None:
 
 def install_compile_cache_listener() -> bool:
     """Register the jax.monitoring listener once per process. Returns True
-    when the listener is (now) installed, False when jax.monitoring is
-    unavailable. Safe to call repeatedly."""
+    once the listener is installed. Safe to call repeatedly."""
     global _installed
     with _lock:
         if _installed:
             return True
-    try:
-        from jax import monitoring
-    except Exception:  # noqa: BLE001 — counters just stay at zero
-        return False
+    from jax import monitoring
+
     with _lock:
         if _installed:  # lost a race to another caller
             return True

@@ -179,10 +179,12 @@ _peaks_lock = threading.Lock()
 _peaks_cache: tuple[float, float] | None = None
 
 
-def resolve_chip_peaks(device_kind: str) -> tuple[float, float]:
+def resolve_chip_peaks(device_kind: str, platform: str = "") -> tuple[float, float]:
     """Pure lookup: (peak_flops_per_s, peak_hbm_bytes_per_s) for a device kind.
 
-    Env overrides win over the table; unknown kinds get the CPU fallback.
+    Env overrides win over the table. On platform ``tpu`` a kind that
+    matches no row raises, naming the kind — a utilization against made-up
+    peaks is worse than none; elsewhere unknown kinds get the CPU fallback.
     ``TPU_PEAK_FLOPS`` is raw FLOP/s; ``TPU_HBM_GBPS`` is GB/s (decimal).
     """
     flops = bw = 0.0
@@ -192,6 +194,11 @@ def resolve_chip_peaks(device_kind: str) -> tuple[float, float]:
             flops, bw = f, b
             break
     else:
+        if platform == "tpu":
+            raise ValueError(
+                f"no peak FLOP/s and HBM bytes/s known for TPU device_kind "
+                f"{device_kind!r}; add a row to obs/roofline.py _CHIP_PEAKS"
+            )
         flops, bw = _CPU_PEAKS
     try:
         env_f = os.environ.get("TPU_PEAK_FLOPS")
@@ -209,19 +216,16 @@ def resolve_chip_peaks(device_kind: str) -> tuple[float, float]:
 
 
 def chip_peaks() -> tuple[float, float]:
-    """Resolve and cache peaks for the local jax backend (lazy; never raises)."""
+    """Resolve and cache peaks for the local jax backend (lazy). Raises on
+    a TPU whose device_kind has no row (``resolve_chip_peaks``)."""
     global _peaks_cache
     with _peaks_lock:
         if _peaks_cache is not None:
             return _peaks_cache
-    kind = ""
-    try:
-        import jax
+    import jax
 
-        kind = getattr(jax.devices()[0], "device_kind", "") or ""
-    except Exception:
-        kind = ""
-    peaks = resolve_chip_peaks(kind)
+    dev = jax.devices()[0]
+    peaks = resolve_chip_peaks(dev.device_kind, dev.platform)
     with _peaks_lock:
         _peaks_cache = peaks
     return peaks

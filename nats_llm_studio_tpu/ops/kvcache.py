@@ -271,10 +271,20 @@ def kv_pool_write_rows(pool, rows, tbl, pos, layer):
     offs = pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]  # [B, W]
 
     def put(p, r):
+        # every index but the minor-most axis is explicit, so each update
+        # is one contiguous row of the pool as it lies in memory. Leaving
+        # the head axis a slice makes the scatter's window [Hkv, D]; the
+        # TPU compiler then re-lays the WHOLE pool out heads-minor for the
+        # scatter and back for the attention kernel — two pool-sized copies
+        # per layer per step, and a pool-sized temporary next to the
+        # weights.
         t = p.shape[3]
         vb = jnp.clip(offs // t, 0, tbl.shape[1] - 1)
         bids = jnp.take_along_axis(tbl, vb, axis=1)  # [B, W]
-        return p.at[bids, layer, :, offs % t].set(r.astype(p.dtype))
+        heads = jnp.arange(p.shape[2], dtype=jnp.int32)
+        return p.at[
+            bids[:, :, None], layer, heads[None, None, :], (offs % t)[:, :, None]
+        ].set(r.astype(p.dtype))
 
     if not is_quantized(pool):
         return put(pool, rows)

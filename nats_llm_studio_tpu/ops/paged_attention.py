@@ -45,10 +45,15 @@ def paged_decode_eligible(
 ) -> bool:
     """Whether the Pallas paged-decode kernel can serve this pool layout on
     a real TPU. The block-token extent T is the sublane dim of every K/V
-    tile (int8 codes need 32 rows, f32 8, bf16 16), the head_dim D is the
-    lane dim (128 multiple), and under tensor parallelism each shard must
-    own whole KV heads. Anything else downshifts to the XLA path."""
-    sub = 32 if quantized else (8 if itemsize >= 4 else 16)
+    tile (f32 8 rows, bf16 16), the head_dim D is the lane dim (128
+    multiple), and under tensor parallelism each shard must own whole KV
+    heads. int8 KVQ codes pack 32 rows to a native tile, but every K/V
+    block spans the pool's WHOLE [T, D] minor plane, which Mosaic accepts
+    below the native tile: codes at the default KV_BLOCK_TOKENS=16 compile
+    for a v5e (tests/test_tpu_compile.py) and agree with the XLA path on
+    the chip (PERF.md, PR 21), so TPU_KV_QUANT=int8 keeps the kernel at
+    the default block size. Anything else downshifts to the XLA path."""
+    sub = 8 if itemsize >= 4 and not quantized else 16
     return t % sub == 0 and d % 128 == 0 and hkv % tp == 0
 
 

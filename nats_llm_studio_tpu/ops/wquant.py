@@ -224,9 +224,11 @@ def quantizable(key: str) -> bool:
     return key.rsplit(".", 1)[-1] in _QUANT_KEYS
 
 
-def quantize_params(params: dict, device: bool = False, mode: str = "int8",
-                    group: int = 32) -> dict:
-    """Quantize every eligible leaf of a materialized params pytree.
+def quantize_params(params: dict, mode: str = "int8", group: int = 32) -> dict:
+    """Quantize, on the host, every eligible leaf of a materialized params
+    pytree — for trees that exist already (``init_params`` in tests). Serving
+    never materializes one: the loader (parallel/loader.py) quantizes tensor
+    by tensor with the same ``quantize_weight`` / ``quantize_weight4``.
 
     ``mode``: "int8" (per-output-channel QTensor) or "int4" (grouped
     QTensor4, ``group`` rows per scale/zero-point).
@@ -236,9 +238,8 @@ def quantize_params(params: dict, device: bool = False, mode: str = "int8",
 
     def quant_one(v):
         if mode == "int4":
-            return quantize_weight4(v if device else np.asarray(v),
-                                    group=group, device=device)
-        return quantize_weight(v if device else np.asarray(v), device=device)
+            return quantize_weight4(np.asarray(v), group=group)
+        return quantize_weight(np.asarray(v))
 
     def walk(node: dict, prefix: str = "") -> dict:
         out = {}

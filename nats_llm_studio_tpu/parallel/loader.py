@@ -1,18 +1,22 @@
 """Streaming sharded weight loading.
 
 SURVEY.md §7 hard part #2: a 70B GGUF is ~40 GB on disk and ~140 GB as bf16 —
-materializing the full pytree on host before sharding (models/llama.py's
-``load_params_from_gguf``) cannot work there. This loader walks the tensor
-index one entry at a time: mmap read -> dequant (native C++ path) -> cast ->
-``jax.device_put`` with the tensor's NamedSharding -> host buffer released,
-so peak host memory is one tensor, not one model. Stacked [L]-leading leaves
-are assembled on device layer-by-layer via per-layer placement and
-``jax.lax`` concatenation-free stacking (device_put per layer slice into the
-stacked sharding).
+materializing the full pytree before sharding or quantizing it cannot work
+there, and on one 16 GB chip it already fails for an 8B file. This is the
+repo's one GGUF loader, for every placement. It walks the tensor
+index one entry at a time: mmap read -> dequant (native C++ path) -> cast or
+quantize on the host -> ``jax.device_put`` with the tensor's NamedSharding ->
+host buffer released, so peak host memory is one tensor, not one model.
+Stacked [L]-leading leaves are allocated once at their final shape and
+sharding, and each layer slice is written into them in place (a donated
+dynamic-update-slice), so peak device memory is the final tree plus one
+layer slice — never a second copy of a leaf. A one-device mesh is how the
+registry loads for unsharded serving.
 """
 
 from __future__ import annotations
 
+import functools
 import gc
 import logging
 from typing import Any
@@ -36,13 +40,14 @@ from .sharding import param_sharding_rules, scale_spec
 log = logging.getLogger(__name__)
 
 
-def _layer_sharding(mesh: Mesh, spec: P) -> NamedSharding:
-    """Sharding for one [L]-slice of a stacked leaf (drop the L axis rule)."""
-    return NamedSharding(mesh, P(*spec[1:]))
-
-
-def _place(arr: np.ndarray, mesh: Mesh, spec: P, dtype) -> jax.Array:
-    return jax.device_put(jnp.asarray(arr, dtype), NamedSharding(mesh, spec))
+@functools.lru_cache(maxsize=None)
+def _layer_writer(sharding: NamedSharding):
+    """Jitted ``buf[i] = x`` that donates ``buf`` and keeps its sharding, so
+    filling a stacked leaf never holds two copies of it."""
+    return jax.jit(
+        lambda buf, x, i: jax.lax.dynamic_update_index_in_dim(buf, x, i, 0),
+        donate_argnums=0, out_shardings=sharding,
+    )
 
 
 def load_params_sharded(
@@ -50,14 +55,20 @@ def load_params_sharded(
     quant: str = "none", group: int = 32,
 ) -> dict[str, Any]:
     """Build the stacked-params pytree directly on the mesh, one tensor at a
-    time. Same tensor-name contract as models.llama.load_params_from_gguf.
+    time.
+
+    Tensor names follow the public GGUF convention (token_embd, blk.N.*,
+    output_norm, output). Weights are stored [out, in] (after the reader's
+    dim reversal) and transposed here to [in, out] so forward() uses plain
+    ``x @ w`` — the layout XLA maps straight onto the MXU.
 
     ``quant="int8"`` re-quantizes each matmul weight to symmetric
     per-output-channel int8 on the host *before* placement, so device HBM
     holds int8 + scales — the path that fits Llama-3-70B on a v5e-8
-    (BASELINE.md config 3) and halves decode weight traffic. ``quant="int4"``
-    goes further: asymmetric grouped QTensor4 (``group`` rows per
-    scale/zero-point), ~4.3 bits/weight, halving traffic again.
+    (BASELINE.md config 3), Llama-3-8B on one 16 GB chip, and halves decode
+    weight traffic. ``quant="int4"`` goes further: asymmetric grouped
+    QTensor4 (``group`` rows per scale/zero-point), ~4.3 bits/weight,
+    halving traffic again.
     """
     dt = jnp.dtype(dtype or cfg.dtype)
     if quant not in ("none", "int8", "int4"):
@@ -70,99 +81,86 @@ def load_params_sharded(
     def mat(name: str) -> np.ndarray:
         return np.ascontiguousarray(t(name).T)
 
-    def place_leaf(key: str, arr: np.ndarray, spec: P, layered: bool):
-        """Host tensor -> device leaf (bf16 array or int8/int4 QTensor)."""
-        w_sh = _layer_sharding(mesh, spec) if layered else NamedSharding(mesh, spec)
+    def host_leaf(key: str, arr: np.ndarray, spec: P):
+        """Host tensor -> (component arrays, their specs, rebuild): one bf16
+        array, or the codes/scales of an int8 QTensor / int4 QTensor4.
+        ``spec`` is the spec of ``arr`` itself (no [L] axis)."""
         if quant == "int8" and quantizable(key):
             qt = quantize_weight(arr)
-            s_spec = scale_spec(P(*spec[1:])) if layered else scale_spec(spec)
-            return QTensor(
-                q=jax.device_put(jnp.asarray(qt.q), w_sh),
-                s=jax.device_put(jnp.asarray(qt.s), NamedSharding(mesh, s_spec)),
-            )
+            return (qt.q, qt.s), (spec, scale_spec(spec)), QTensor
         if quant == "int4" and quantizable(key):
             # codes AND grouped scales/zeros all keep the weight's spec
             # (see shard_params: the grouped axis shards with the
             # contraction axis, it is not extent-1 like the int8 scale)
             qt = quantize_weight4(arr, group=group)
-            return QTensor4(
-                q=jax.device_put(jnp.asarray(qt.q), w_sh),
-                s=jax.device_put(jnp.asarray(qt.s), w_sh),
-                z=jax.device_put(jnp.asarray(qt.z), w_sh),
-                group=qt.group,
+            return (
+                (qt.q, qt.s, qt.z), (spec, spec, spec),
+                functools.partial(QTensor4, group=qt.group),
             )
-        return jax.device_put(jnp.asarray(arr, dt), w_sh)
+        # cast on the host: the device only ever sees the serving dtype
+        return (np.asarray(arr).astype(dt),), (spec,), lambda a: a
+
+    def place(key: str, arr: np.ndarray) -> Any:
+        parts, specs, rebuild = host_leaf(key, arr, rules[key])
+        return rebuild(*(
+            jax.device_put(a, NamedSharding(mesh, sp))
+            for a, sp in zip(parts, specs)
+        ))
 
     params: dict[str, Any] = {
-        "embed": _place(t("token_embd.weight"), mesh, rules["embed"], dt),
-        "out_norm": _place(t("output_norm.weight"), mesh, rules["out_norm"], dt),
+        "embed": place("embed", t("token_embd.weight")),
+        "out_norm": place("out_norm", t("output_norm.weight")),
     }
-    if "output.weight" in reader.tensors:
-        params["lm_head"] = place_leaf(
-            "lm_head", mat("output.weight"), rules["lm_head"], layered=False
-        )
-    else:
-        # tied embeddings: materialize the [d, vocab] head now (contiguous,
-        # shardable, quantizable) instead of transposing embed every step
-        params["lm_head"] = place_leaf(
-            "lm_head", np.ascontiguousarray(t("token_embd.weight").T),
-            rules["lm_head"], layered=False,
-        )
+    # tied embeddings: materialize the [d, vocab] head now (contiguous,
+    # shardable, quantizable) instead of transposing embed every step
+    head = "output.weight" if "output.weight" in reader.tensors else "token_embd.weight"
+    params["lm_head"] = place("lm_head", mat(head))
 
-    # stacked per-layer leaves: place each layer slice with the slice
-    # sharding, then stack on-device (jnp.stack of committed sharded arrays
-    # stays on device; the host copy of each slice dies right after placement)
-    per_layer: dict[str, list] = {}
+    # stacked per-layer leaves: key -> (device buffers, rebuild). The
+    # buffers exist at their final [L, ...] shape from layer 0 on; every
+    # layer slice is placed with the slice sharding and written in place,
+    # and its host and device copies die right after
+    stacked: dict[str, tuple[list, Any]] = {}
 
-    def push(key: str, arr: np.ndarray) -> None:
-        spec = rules[f"blocks.{key}"]
-        per_layer.setdefault(key, []).append(place_leaf(key, arr, spec, layered=True))
+    def push(i: int, key: str, arr: np.ndarray) -> None:
+        full = rules[f"blocks.{key}"]
+        parts, specs, rebuild = host_leaf(key, arr, P(*full[1:]))
+        if key not in stacked:
+            stacked[key] = ([
+                jnp.zeros((cfg.n_layers, *a.shape), a.dtype,
+                          device=NamedSharding(mesh, P(full[0], *sp)))
+                for a, sp in zip(parts, specs)
+            ], rebuild)
+        bufs = stacked[key][0]
+        for j, (a, sp) in enumerate(zip(parts, specs)):
+            x = jax.device_put(a, NamedSharding(mesh, sp))
+            bufs[j] = _layer_writer(bufs[j].sharding)(bufs[j], x, np.int32(i))
 
     for i in range(cfg.n_layers):
         pre = f"blk.{i}"
-        push("attn_norm", t(f"{pre}.attn_norm.weight"))
-        push("ffn_norm", t(f"{pre}.ffn_norm.weight"))
-        push("wq", _rope_deinterleave(mat(f"{pre}.attn_q.weight"), cfg.n_heads, cfg.head_dim))
-        push("wk", _rope_deinterleave(mat(f"{pre}.attn_k.weight"), cfg.n_kv_heads, cfg.head_dim))
-        push("wv", mat(f"{pre}.attn_v.weight"))
-        push("wo", mat(f"{pre}.attn_output.weight"))
+        push(i, "attn_norm", t(f"{pre}.attn_norm.weight"))
+        push(i, "ffn_norm", t(f"{pre}.ffn_norm.weight"))
+        push(i, "wq", _rope_deinterleave(mat(f"{pre}.attn_q.weight"), cfg.n_heads, cfg.head_dim))
+        push(i, "wk", _rope_deinterleave(mat(f"{pre}.attn_k.weight"), cfg.n_kv_heads, cfg.head_dim))
+        push(i, "wv", mat(f"{pre}.attn_v.weight"))
+        push(i, "wo", mat(f"{pre}.attn_output.weight"))
         if cfg.attn_bias:
-            push("bq", _rope_deinterleave(
+            push(i, "bq", _rope_deinterleave(
                 t(f"{pre}.attn_q.bias")[None], cfg.n_heads, cfg.head_dim)[0])
-            push("bk", _rope_deinterleave(
+            push(i, "bk", _rope_deinterleave(
                 t(f"{pre}.attn_k.bias")[None], cfg.n_kv_heads, cfg.head_dim)[0])
-            push("bv", t(f"{pre}.attn_v.bias"))
+            push(i, "bv", t(f"{pre}.attn_v.bias"))
         if cfg.is_moe:
-            push("router", mat(f"{pre}.ffn_gate_inp.weight"))
-            push("w_gate_e", t(f"{pre}.ffn_gate_exps.weight").transpose(0, 2, 1))
-            push("w_up_e", t(f"{pre}.ffn_up_exps.weight").transpose(0, 2, 1))
-            push("w_down_e", t(f"{pre}.ffn_down_exps.weight").transpose(0, 2, 1))
+            push(i, "router", mat(f"{pre}.ffn_gate_inp.weight"))
+            push(i, "w_gate_e", t(f"{pre}.ffn_gate_exps.weight").transpose(0, 2, 1))
+            push(i, "w_up_e", t(f"{pre}.ffn_up_exps.weight").transpose(0, 2, 1))
+            push(i, "w_down_e", t(f"{pre}.ffn_down_exps.weight").transpose(0, 2, 1))
         else:
-            push("w_gate", mat(f"{pre}.ffn_gate.weight"))
-            push("w_up", mat(f"{pre}.ffn_up.weight"))
-            push("w_down", mat(f"{pre}.ffn_down.weight"))
+            push(i, "w_gate", mat(f"{pre}.ffn_gate.weight"))
+            push(i, "w_up", mat(f"{pre}.ffn_up.weight"))
+            push(i, "w_down", mat(f"{pre}.ffn_down.weight"))
         if i % 8 == 7:
             gc.collect()  # drop dequant temporaries promptly on big models
 
-    blocks: dict[str, Any] = {}
-    for key, slices in per_layer.items():
-        spec = rules[f"blocks.{key}"]
-        if isinstance(slices[0], QTensor):
-            blocks[key] = QTensor(
-                q=jax.device_put(jnp.stack([s.q for s in slices]),
-                                 NamedSharding(mesh, spec)),
-                s=jax.device_put(jnp.stack([s.s for s in slices]),
-                                 NamedSharding(mesh, scale_spec(spec))),
-            )
-        elif isinstance(slices[0], QTensor4):
-            sh = NamedSharding(mesh, spec)
-            blocks[key] = QTensor4(
-                q=jax.device_put(jnp.stack([s.q for s in slices]), sh),
-                s=jax.device_put(jnp.stack([s.s for s in slices]), sh),
-                z=jax.device_put(jnp.stack([s.z for s in slices]), sh),
-                group=slices[0].group,
-            )
-        else:
-            blocks[key] = jax.device_put(jnp.stack(slices), NamedSharding(mesh, spec))
-    params["blocks"] = blocks
+    params["blocks"] = {k: rebuild(*bufs) for k, (bufs, rebuild) in stacked.items()}
     return params

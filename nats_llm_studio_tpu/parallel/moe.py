@@ -31,11 +31,7 @@ import math
 
 import jax
 import jax.numpy as jnp
-
-try:  # moved out of experimental in newer JAX
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.config import ModelConfig

@@ -30,7 +30,7 @@ import os
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..ops.layers import swiglu
@@ -130,7 +130,7 @@ def overlap_row_proj(x: jax.Array, w, mesh) -> jax.Array:
     return shard_map(
         f, mesh=mesh,
         in_specs=(xspec, _weight_specs(w, row_sharded=True)),
-        out_specs=P(*([None] * x.ndim)), check_rep=False,
+        out_specs=P(*([None] * x.ndim)), check_vma=False,
     )(x, w)
 
 
@@ -156,5 +156,5 @@ def overlap_ffn(h: jax.Array, w_gate, w_up, w_down, act: str, mesh) -> jax.Array
             _weight_specs(w_up, row_sharded=False),
             _weight_specs(w_down, row_sharded=True),
         ),
-        out_specs=hspec, check_rep=False,
+        out_specs=hspec, check_vma=False,
     )(h, w_gate, w_up, w_down)

@@ -375,17 +375,28 @@ class Autoscaler:
         return decision
 
     def _expire_pending(self, now: float) -> None:
-        for wid in [w for w, p in self._pending.items()
-                    if now - p["mono"] > self.spawn_grace_s]:
-            p = self._pending.pop(wid)
-            proc = p.get("proc")
-            if proc is not None and getattr(proc, "poll", None) is not None:
-                try:
-                    if proc.poll() is None:
-                        proc.kill()
-                except Exception:  # noqa: BLE001 — cleanup is best-effort
-                    pass
-            self._record_spawn_failure(now, wid, "no_advert_within_grace")
+        """Fail the pending spawns that died or never advertised. A child
+        that has already exited is a failure NOW, with its exit code — the
+        second worker on a host whose chips another process owns exits at
+        start-up (``serve`` refuses an unrequested CPU backend), and must
+        not sit out the grace as if it were still compiling."""
+        for wid, p in list(self._pending.items()):
+            poll = getattr(p.get("proc"), "poll", None)
+            try:
+                rc = poll() if poll is not None else None
+            except Exception:  # noqa: BLE001 — treat as still running
+                rc = None
+            if rc is not None:
+                del self._pending[wid]
+                self._record_spawn_failure(now, wid, f"exited rc={rc}")
+            elif now - p["mono"] > self.spawn_grace_s:
+                del self._pending[wid]
+                if poll is not None:
+                    try:
+                        p["proc"].kill()
+                    except Exception:  # noqa: BLE001 — cleanup is best-effort
+                        pass
+                self._record_spawn_failure(now, wid, "no_advert_within_grace")
 
     def _record_spawn_failure(self, now: float, wid: str, why: str) -> None:
         self.spawn_failures_total += 1

@@ -21,7 +21,6 @@ from ..engine.generator import GenStats, SamplingParams
 from ..gguf.reader import open_gguf
 from ..gguf.tokenizer import GGUFTokenizer
 from ..models.config import ModelConfig
-from ..models.llama import load_params_from_gguf
 from ..obs import FlightRecorder, HbmLedger, LogHistogram, efficiency_enabled
 from ..obs import emit as obs_emit
 from ..parallel.sharding import validate_mesh_for_config
@@ -207,13 +206,10 @@ def _pull_precompile_env(default: bool = True) -> bool:
 
 def _compile_cache_dir_configured() -> bool:
     """Whether a persistent XLA compile cache is active in this process
-    (WorkerConfig.configure_jax or the JAX env knob). Pull-time precompile
-    only pays off when the compiled grid lands somewhere a replacement
-    worker can replay it from."""
-    try:
-        return bool(jax.config.jax_compilation_cache_dir)
-    except AttributeError:
-        return False
+    (WorkerConfig.configure_jax or JAX_COMPILATION_CACHE_DIR). Pull-time
+    precompile only pays off when the compiled grid lands somewhere a
+    replacement worker can replay it from."""
+    return bool(jax.config.jax_compilation_cache_dir)
 
 
 def _deadline_min_tokens_env(default: int = 1) -> int:
@@ -774,6 +770,9 @@ class LocalRegistry(Registry):
         self.qos_preempt = qos_preempt
         self._engines: dict[str, JaxChatEngine] = {}
         self._load_lock = asyncio.Lock()
+        # model id -> the task loading it (get_engine): a load belongs to
+        # the registry, not to the request that happened to ask first
+        self._loading: dict[str, asyncio.Task] = {}
         self._requests = 0
         # HBM admission bookkeeping: estimated per-device bytes committed by
         # each loaded engine, and last-use times for idle-eviction order.
@@ -1004,6 +1003,26 @@ class LocalRegistry(Registry):
         if eng is not None:
             self._last_used[model_id] = time.monotonic()
             return eng
+        # one load per model, in a task of its own. A caller whose deadline
+        # fires meanwhile is cancelled out of the wait, not out of the load:
+        # the load thread cannot be stopped, so a load abandoned with its
+        # caller would finish anyway, drop an engine's worth of device
+        # memory on the floor, and let the retry start a second load next to
+        # it. The retry joins this one instead, or finds the engine loaded.
+        task = self._loading.get(model_id)
+        if task is None:
+            task = asyncio.ensure_future(self._load_engine(model_id))
+            self._loading[model_id] = task
+
+            def done(t: asyncio.Task) -> None:
+                self._loading.pop(model_id, None)
+                if not t.cancelled():
+                    t.exception()  # every waiter may be gone; not a leak
+
+            task.add_done_callback(done)
+        return await asyncio.shield(task)
+
+    async def _load_engine(self, model_id: str) -> ChatEngine:
         async with self._load_lock:
             eng = self._engines.get(model_id)
             if eng is not None:
@@ -1275,21 +1294,35 @@ class LocalRegistry(Registry):
         # serving a third of the weights); otherwise keep the long-standing
         # behavior of serving the first .gguf in the dir
         reader = open_gguf(split[0] if split else paths[0])
-        cfg = ModelConfig.from_gguf_metadata(reader.metadata).with_(
+        cfg = ModelConfig.from_gguf_metadata(reader.metadata)
+        tp = dict(self.mesh.shape).get("tp", 1) if self.mesh is not None else 1
+        cfg = cfg.with_(
             dtype=self.dtype,
-            use_flash_attention=jax.default_backend() == "tpu",  # prefill TTFT
+            # prefill TTFT. Under tp the kernels are shard_mapped over heads
+            # (models/llama.py _on_mesh), which needs whole GQA groups per
+            # shard; the replicated-KV fallback prefills on the XLA path
+            use_flash_attention=(
+                jax.default_backend() == "tpu" and self._kv_tp(cfg) == tp
+            ),
             use_routed_moe=True,  # sparse dispatch (parallel/moe.py)
             kv_quant=self.kv_quant,
         )
         tokenizer = GGUFTokenizer.from_metadata(reader.metadata)
         quant = {t.ggml_type.name for t in reader.tensors.values()}
-        submeshes: list[Any] = [self.mesh]
-        if self.mesh is not None:
-            # stream tensors straight onto the mesh: peak host memory is one
-            # tensor, so 70B-class files load on small-RAM workers
-            from ..parallel.loader import load_params_sharded
-            from ..parallel.mesh import dp_submeshes
+        # stream tensors straight onto the device(s): each is dequantized,
+        # cast or re-quantized on the host and placed at its final sharding,
+        # so peak host memory is one tensor (70B-class files load on
+        # small-RAM workers) and peak device memory is the final tree plus
+        # one layer slice (an int8 8B tree loads on one 16 GB chip). The
+        # one loader serves every placement: unsharded serving is a
+        # one-device mesh here, and the batcher still gets mesh=None.
+        from ..parallel.loader import load_params_sharded
+        from ..parallel.mesh import build_mesh, dp_submeshes
 
+        if self.mesh is None:
+            submeshes: list[Any] = [None]
+            load_mesh = build_mesh({"tp": 1}, devices=jax.local_devices()[:1])
+        else:
             validate_mesh_for_config(self.mesh, cfg)
             # a dp axis means batcher REPLICAS: one submesh per dp slice
             # (disjoint devices, ep/sp/tp intact). The GGUF streams onto
@@ -1297,21 +1330,10 @@ class LocalRegistry(Registry):
             # of the same tree below — weights replicated ALONG dp, sharded
             # WITHIN each slice, one host read total
             submeshes = dp_submeshes(self.mesh)
-            params = load_params_sharded(
-                reader, cfg, submeshes[0], quant=self.quant, group=self.wquant_group
-            )
-        elif self.quant in ("int8", "int4"):
-            from ..models.llama import ensure_lm_head
-            from ..ops.wquant import quantize_params
-
-            params = quantize_params(
-                ensure_lm_head(load_params_from_gguf(reader, cfg)),
-                mode=self.quant, group=self.wquant_group,
-            )
-        else:
-            from ..models.llama import ensure_lm_head
-
-            params = ensure_lm_head(load_params_from_gguf(reader, cfg))
+            load_mesh = submeshes[0]
+        params = load_params_sharded(
+            reader, cfg, load_mesh, quant=self.quant, group=self.wquant_group
+        )
         meta = dict(reader.metadata)
         reader.close()
         n_dp = len(submeshes)

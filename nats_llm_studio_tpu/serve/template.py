@@ -14,19 +14,14 @@ from __future__ import annotations
 import logging
 from typing import Any
 
+import jinja2
+
 from ..gguf.constants import KEY_CHAT_TEMPLATE
 from ..gguf.tokenizer import GGUFTokenizer
 
 log = logging.getLogger(__name__)
 
-try:
-    import jinja2
-
-    _JINJA: jinja2.Environment | None = jinja2.Environment(
-        loader=jinja2.BaseLoader(), keep_trailing_newline=True
-    )
-except ImportError:  # pragma: no cover
-    _JINJA = None
+_JINJA = jinja2.Environment(loader=jinja2.BaseLoader(), keep_trailing_newline=True)
 
 # stop-string candidates looked up in the vocab (model families use different
 # end-of-turn markers; anything present becomes a stop id)
@@ -54,8 +49,6 @@ def stop_token_ids(tok: GGUFTokenizer) -> frozenset[int]:
 
 def _render_jinja(template: str, messages: list[dict], add_generation_prompt: bool,
                   md: dict[str, Any]) -> str | None:
-    if _JINJA is None:
-        return None
     try:
         tokens = md.get("tokenizer.ggml.tokens")
         bos_id = md.get("tokenizer.ggml.bos_token_id")

@@ -74,6 +74,16 @@ log = logging.getLogger(__name__)
 KV_MODEL_HEADER = "X-KV-Model"
 
 
+def _devices() -> list[dict]:
+    """The JAX devices of this process, as health and metrics report them."""
+    import jax
+
+    return [
+        {"id": d.id, "platform": d.platform, "kind": d.device_kind}
+        for d in jax.devices()
+    ]
+
+
 def _zip_dir(path: str) -> bytes:
     """Zip a directory tree (relative paths) into an in-memory archive —
     runs in a thread from on_profile; trace dirs are tens of MB at most."""
@@ -816,20 +826,26 @@ class Worker:
             payload["_tenant"] = str(hdrs[TENANT_HEADER])
         if hdrs.get(PRIORITY_HEADER):
             payload["_priority"] = str(hdrs[PRIORITY_HEADER])
-        if self.config.deadline_propagation:
-            # client budget (X-Deadline-Ms, wall ms) → monotonic deadline
-            # capped by the per-op ladder; the batcher sheds expired work at
-            # submit/admit and aborts mid-decode slots past it. An
-            # already-expired budget still flows through: the shed there is
-            # a retryable envelope, not a silent drop.
-            remaining = deadline_remaining_s((msg.headers or {}).get(DEADLINE_HEADER))
-            if remaining is not None:
-                payload["_deadline"] = time.monotonic() + min(
-                    remaining, self.config.chat_timeout_s
-                )
         try:
-            async with _timeout(self.config.chat_timeout_s):
+            # a chat that finds its model cached-not-loaded loads it first,
+            # under the PULL deadline of the ladder: getting a model ready
+            # is a pull-class operation (an 8B int8 load is minutes of host
+            # requantization), and the chat deadline starts once the
+            # engine exists
+            async with _timeout(self.config.pull_timeout_s):
                 engine = await self.registry.get_engine(model_id)
+            if self.config.deadline_propagation:
+                # client budget (X-Deadline-Ms, wall ms) → monotonic
+                # deadline capped by the per-op ladder; the batcher sheds
+                # expired work at submit/admit and aborts mid-decode slots
+                # past it. An already-expired budget still flows through:
+                # the shed there is a retryable envelope, not a silent drop.
+                remaining = deadline_remaining_s(hdrs.get(DEADLINE_HEADER))
+                if remaining is not None:
+                    payload["_deadline"] = time.monotonic() + min(
+                        remaining, self.config.chat_timeout_s
+                    )
+            async with _timeout(self.config.chat_timeout_s):
                 prefill_peer = (hdrs.get(KV_PREFILL_HEADER) or "").strip()
                 if prefill_peer and prefill_peer != self.worker_id:
                     # disaggregated two-hop: the router already ran (or is
@@ -1597,6 +1613,9 @@ class Worker:
             "reconnects": getattr(self.nc, "reconnects", 0),
         }
         data.update(self.registry.stats())
+        # the devices this worker serves from: a worker that came up on a
+        # backend nobody meant is visible in its heartbeat
+        data["devices"] = _devices()
         # per-engine liveness/readiness (additive keys): lets clients and the
         # bench route around a worker whose engine is restarting
         health_fn = getattr(self.registry, "engine_health", None)
@@ -1615,8 +1634,6 @@ class Worker:
         """metrics — full observability snapshot (SURVEY.md §5: counters on a
         NATS metrics subject): worker totals plus per-engine batcher stats
         (decode steps, tokens/step, peak active slots) and device info."""
-        import jax
-
         engines = {}
         for mid, eng in self.registry.loaded_engines().items():
             batcher = getattr(eng, "batcher", None)
@@ -1626,10 +1643,6 @@ class Worker:
             for ri, rb in enumerate(reps):
                 key = mid if len(reps) == 1 else f"{mid}#dp{ri}"
                 engines[key] = rb.stats.snapshot()
-        devices = [
-            {"id": d.id, "platform": d.platform, "kind": d.device_kind}
-            for d in jax.devices()
-        ]
         data = {
             "uptime_s": round(time.monotonic() - self._t0, 3),
             "requests_total": self._requests_total,
@@ -1637,7 +1650,7 @@ class Worker:
             "queue_group": self.config.queue_group,
             "registry": self.registry.stats(),
             "engines": engines,
-            "devices": devices,
+            "devices": _devices(),
         }
         await self._respond_ok(msg, data)
 

@@ -240,10 +240,14 @@ class EmbeddedBroker:
 
     async def stop(self) -> None:
         if self._server:
-            self._server.close()
-            await self._server.wait_closed()
+            self._server.close()  # stop accepting
+        # connections first: since Python 3.12.1 Server.wait_closed() waits
+        # for every open connection, so waiting before closing them hangs
+        # for as long as any client stays connected
         for c in list(self._clients):
             await c._close()
+        if self._server:
+            await self._server.wait_closed()
         # close registered modules (e.g. the object store's append-log file
         # handles) deterministically instead of leaving them to GC
         for m in self._modules:

@@ -610,6 +610,12 @@ async def test_autoscaler_replaces_killed_worker_with_warm_replacement(tmp_path)
     XLA compile cache AND the prefix cache warmed by the donor's kv_handoff
     push, and every wave request is served or cleanly retryable."""
     install_compile_cache_listener()
+    # persist every program, as ``serve`` does (config.configure_jax; these
+    # in-process workers skip it): the tiny model's sub-second compiles would
+    # otherwise never reach the cache, and the hits asserted below would
+    # depend on what earlier runs happened to leave in it
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     models = tmp_path / "models"
     _publish_tiny(models)
     broker = await EmbeddedBroker().start()
@@ -719,4 +725,5 @@ async def test_autoscaler_replaces_killed_worker_with_warm_replacement(tmp_path)
         await donor.drain()
         await victim.drain()
     finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
         await broker.stop()

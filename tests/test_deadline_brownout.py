@@ -374,6 +374,53 @@ async def test_shed_only_recovers_while_idle_via_submit_ticks(model):
         b.stop()
 
 
+def test_queue_age_leaves_out_cold_dispatch_spans(model):
+    """The load signals (brownout queue age, rate EWMAs) read a wall span
+    less what overlapped a program's first dispatch (trace + compile) on
+    the owner thread."""
+    cfg, params = model
+    b = ContinuousBatcher(params, cfg, max_slots=1, max_seq_len=64, buckets=[8, 64])
+    try:
+        assert b._warm_s(100.0, 106.0) == pytest.approx(6.0)
+        b._cold_spans.append((99.0, 104.0))   # began before the enqueue
+        b._cold_spans.append((105.0, 105.5))  # wholly inside the wait
+        b._cold_spans.append((105.9, 107.0))  # still running at ``now``
+        assert b._warm_s(100.0, 106.0) == pytest.approx(1.4)
+        assert b._warm_s(107.0, 108.0) == pytest.approx(1.0)
+    finally:
+        b.stop()
+
+
+@async_test
+async def test_request_behind_a_cold_compile_is_not_shed(model):
+    """A fresh worker's second request queues behind the first one's
+    compiles. That wait is a one-time cost, not saturation: the controller
+    stays at NORMAL and both requests are served (found on the chip: a cold
+    8B worker answered its second request 'brownout shed-only')."""
+    cfg, params = model
+    b = ContinuousBatcher(
+        params, cfg, max_slots=2, max_seq_len=64, buckets=[8, 64],
+        max_queue=8, brownout=BrownoutConfig(age_hi_ms=100.0, age_lo_ms=50.0),
+    )
+    try:
+        async def client(toks, delay):
+            await asyncio.sleep(delay)
+            sp = SamplingParams(temperature=0.0, max_tokens=6)
+            return [t async for t in b.submit(toks, sp)]
+
+        first, second = await asyncio.gather(
+            client([1, 2, 3], 0.0), client([4, 5], 0.05))
+        assert len(first) == 6 and len(second) == 6
+        # the second request did queue behind a first dispatch longer than
+        # the controller's SHED_ONLY mark ...
+        assert max(e - s for s, e in b._cold_spans) * 1e3 > 150.0
+        # ... and the controller never read it as load
+        assert b.brownout.transitions == 0
+        assert b.stats.shed_cause_counts().get("brownout") is None
+    finally:
+        b.stop()
+
+
 # -- prometheus exposition (serve/worker.py) ---------------------------------
 
 

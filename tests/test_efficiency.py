@@ -62,9 +62,12 @@ def test_resolve_chip_peaks_table(monkeypatch):
     assert resolve_chip_peaks("TPU v5p") == (459e12, 2765e9)
     assert resolve_chip_peaks("TPU v6e") == (918e12, 1640e9)
     assert resolve_chip_peaks("TPU v4") == (275e12, 1228e9)
-    # unknown kinds (and the CPU backend's empty kind) get the modest fallback
+    # off the TPU platform, unknown kinds (and the CPU backend's) get the
+    # modest fallback; on it, an unknown kind raises and names the kind
     assert resolve_chip_peaks("") == (5e11, 5e10)
     assert resolve_chip_peaks("Quantum Abacus 9000") == (5e11, 5e10)
+    with pytest.raises(ValueError, match="Quantum Abacus 9000"):
+        resolve_chip_peaks("Quantum Abacus 9000", platform="tpu")
 
 
 def test_resolve_chip_peaks_env_overrides(monkeypatch):

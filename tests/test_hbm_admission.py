@@ -164,6 +164,38 @@ async def test_failed_load_releases_hbm_reservation(tmp_path, monkeypatch):
 
 
 @async_test
+async def test_load_outlives_the_caller_that_gave_up(tmp_path, monkeypatch):
+    """A caller whose deadline fires while its model loads is cancelled out
+    of the wait, not out of the load: the load finishes once, the engine is
+    registered, and a retry joins it instead of starting a second load next
+    to the first (on a chip: a second 8 GB tree beside the one in flight)."""
+    import threading
+
+    models = tmp_path / "models"
+    _publish(models, "acme/a", 1)
+    reg = LocalRegistry(ModelStore(models), dtype="float32", max_batch_slots=2,
+                        max_seq_len=64)
+    real_load, loads, gate = reg._load, [], threading.Event()
+
+    def slow_load(*a, **k):
+        loads.append(a[0])
+        gate.wait(30.0)
+        return real_load(*a, **k)
+
+    monkeypatch.setattr(reg, "_load", slow_load)
+    with pytest.raises(asyncio.TimeoutError):
+        await asyncio.wait_for(reg.get_engine("acme/a"), timeout=0.2)
+    retry = asyncio.ensure_future(reg.get_engine("acme/a"))
+    await asyncio.sleep(0.1)
+    gate.set()
+    eng = await asyncio.wait_for(retry, timeout=120.0)
+    assert loads == ["acme/a"]  # one load, joined by the retry
+    assert reg.loaded_engines() == {"acme/a": eng}
+    assert not reg._loading
+    await eng.unload()
+
+
+@async_test
 async def test_no_budget_known_means_no_check(tmp_path, monkeypatch):
     """CPU backends without memory stats (and no env override) skip
     admission — loads behave exactly as before."""

@@ -12,12 +12,16 @@ from nats_llm_studio_tpu.engine.sampling import sample
 from nats_llm_studio_tpu.gguf import GGUFReader
 from nats_llm_studio_tpu.models.config import ModelConfig
 from nats_llm_studio_tpu.models.export import export_params_to_gguf
-from nats_llm_studio_tpu.models.llama import (
-    forward,
-    init_params,
-    load_params_from_gguf,
-    make_cache,
-)
+from nats_llm_studio_tpu.models.llama import forward, init_params, make_cache
+from nats_llm_studio_tpu.parallel.loader import load_params_sharded
+from nats_llm_studio_tpu.parallel.mesh import build_mesh
+
+
+def load_params_from_gguf(reader, cfg):
+    """The repo's one loader, onto a one-device mesh: what unsharded serving
+    does (serve/registry.py)."""
+    mesh = build_mesh({"tp": 1}, devices=jax.devices()[:1])
+    return load_params_sharded(reader, cfg, mesh)
 
 
 @pytest.fixture(scope="module")

@@ -270,9 +270,11 @@ def test_paged_decode_eligible_rules():
     # bf16 pool: 16-row sublanes
     assert paged_decode_eligible(16, 128, 2, False)
     assert not paged_decode_eligible(24, 128, 2, False)
-    # int8 KVQ codes: 32-row sublanes
+    # int8 KVQ codes: the block spans the pool's whole [T, D] plane, so the
+    # default 16-token block serves (tests/test_tpu_compile.py); 8 does not
     assert paged_decode_eligible(32, 128, 2, True)
-    assert not paged_decode_eligible(16, 128, 2, True)
+    assert paged_decode_eligible(16, 128, 2, True)
+    assert not paged_decode_eligible(8, 128, 2, True)
     # the shard_map heads split needs Hkv % tp == 0
     assert paged_decode_eligible(16, 128, 4, False, hkv=2, tp=2)
     assert not paged_decode_eligible(16, 128, 4, False, hkv=1, tp=2)

@@ -106,10 +106,9 @@ def test_sharded_decode_consistency():
 
 def test_streaming_sharded_loader_matches(tmp_path):
     """load_params_sharded (per-tensor streaming onto the mesh) must produce
-    the same numbers as full-host load + shard_params."""
+    the same numbers on a tp=8 mesh as on one device."""
     from nats_llm_studio_tpu.gguf import GGUFReader
     from nats_llm_studio_tpu.models.export import export_params_to_gguf
-    from nats_llm_studio_tpu.models.llama import load_params_from_gguf
     from nats_llm_studio_tpu.parallel.loader import load_params_sharded
 
     cfg = ModelConfig.tiny(n_heads=8, n_kv_heads=8, head_dim=8, d_model=64, d_ff=128, n_layers=3)
@@ -118,7 +117,8 @@ def test_streaming_sharded_loader_matches(tmp_path):
     export_params_to_gguf(path, params, cfg)
     mesh = build_mesh("tp=8")
     with GGUFReader(path) as r:
-        host = load_params_from_gguf(r, cfg)
+        host = load_params_sharded(
+            r, cfg, build_mesh({"tp": 1}, devices=jax.devices()[:1]))
         streamed = load_params_sharded(r, cfg, mesh)
     tokens = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
     k, v = make_cache(cfg, 1, 16)

@@ -1,0 +1,199 @@
+"""The main path's kernels compile for a TPU v5e at Llama-3-8B widths.
+
+No chip is attached here: the TPU compiler that ships with jaxlib/libtpu
+compiles for a *described* ``v5e:2x2`` topology, which is what refuses a
+kernel Mosaic cannot tile, a program that does not fit 16 GB, or a kernel
+that cannot be partitioned — none of which the Pallas interpreter the other
+tests run under can see. Nothing executes; a compile that passes is not a
+chip run.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load the TPU library, and every xdist worker imports
+this file), everything built from it is built in fixtures or tests, and the
+whole family lives in this one file so one worker owns the library.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from nats_llm_studio_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_attention_chunk,
+    flash_attention_chunk_kvq,
+)
+from nats_llm_studio_tpu.ops.kvcache import KVQ, kv_pool_write_rows
+from nats_llm_studio_tpu.ops.paged_attention import (
+    paged_decode_attention,
+    paged_decode_eligible,
+)
+
+# Llama-3-8B attention geometry under the serving defaults: MAX_BATCH_SLOTS=8,
+# MAX_SEQ_LEN=4096, KV_BLOCK_TOKENS=16, prefill chunk 256, SPEC_DECODE_K=6,
+# pool = 8 x 256 blocks + 64 prefix blocks + the null block
+L, HQ, HKV, D = 32, 32, 8, 128
+SLOTS, SEQ, T, CHUNK, SPEC_W = 8, 4096, 16, 256, 7
+POOL_BLOCKS = SLOTS * (SEQ // T) + 64 + 1
+SCALE = D ** -0.5
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp4(topo):
+    return Mesh(np.array(topo.devices), ("tp",))
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip; keep these out of it."""
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # a Mosaic kernel, not interpret mode
+    return compiled
+
+
+def _pool(sharding, quantized: bool, t: int = T):
+    shape = (POOL_BLOCKS, L, HKV, t, D)
+    if not quantized:
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+    return KVQ(q=jax.ShapeDtypeStruct(shape, jnp.int8, sharding=sharding),
+               s=jax.ShapeDtypeStruct(shape[:-1], jnp.float32, sharding=sharding))
+
+
+def _decode_args(sharding, w: int, quantized: bool, t: int = T):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)  # noqa: E731
+    return (
+        sds((SLOTS, w, HQ, D), jnp.bfloat16),
+        _pool(sharding, quantized, t), _pool(sharding, quantized, t),
+        sds((SLOTS, SEQ // t), jnp.int32), sds((SLOTS,), jnp.int32),
+        sds((), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("w", [1, SPEC_W], ids=["decode", "spec_verify"])
+def test_paged_decode_bf16(one_chip, no_cache, w):
+    assert paged_decode_eligible(T, D, 2, False, HKV)
+    _compile(
+        lambda q, kp, vp, tbl, pos, layer: paged_decode_attention(
+            q, kp, vp, tbl, pos, layer, SCALE),
+        *_decode_args(one_chip, w, quantized=False),
+    )
+
+
+@pytest.mark.parametrize("t", [16, 32])
+def test_paged_decode_int8_kvq(one_chip, no_cache, t):
+    """int8 codes at the default KV_BLOCK_TOKENS=16 compile too (the block's
+    sublane extent IS the array's, which Mosaic always accepts), so the
+    eligibility predicate must not downshift TPU_KV_QUANT=int8 to XLA."""
+    assert paged_decode_eligible(t, D, 2, True, HKV)
+    _compile(
+        lambda q, kp, vp, tbl, pos, layer: paged_decode_attention(
+            q, kp, vp, tbl, pos, layer, SCALE),
+        *_decode_args(one_chip, 1, quantized=True, t=t),
+    )
+
+
+def test_pool_row_write_keeps_the_pool_in_place(one_chip, no_cache):
+    """Write-then-attend as forward_decode_paged runs it, over a layer scan:
+    the compiled program must not copy the pool. A scatter whose window
+    spans the head axis made the TPU compiler re-lay the whole pool out for
+    the write and back for the kernel, per layer (2 GB each at these
+    widths)."""
+    def step(q, rows, kp, vp, tbl, pos):
+        def body(carry, layer):
+            kp, vp = carry
+            kp = kv_pool_write_rows(kp, rows, tbl, pos, layer)
+            vp = kv_pool_write_rows(vp, rows, tbl, pos, layer)
+            out = paged_decode_attention(q, kp, vp, tbl, pos, layer, SCALE)
+            return (kp, vp), out.sum()
+
+        (kp, vp), outs = jax.lax.scan(body, (kp, vp), jnp.arange(L, dtype=jnp.int32))
+        return outs, kp, vp
+
+    q, kp, vp, tbl, pos, _ = _decode_args(one_chip, 1, quantized=False)
+    rows = jax.ShapeDtypeStruct((SLOTS, 1, HKV, D), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(step, donate_argnums=(2, 3)).lower(q, rows, kp, vp, tbl, pos).compile()
+    pool_bytes = POOL_BLOCKS * L * HKV * T * D * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+    assert f"bf16[{POOL_BLOCKS},{L},{HKV},{T},{D}]" in compiled.as_text()
+    assert not [
+        ln for ln in compiled.as_text().splitlines()
+        if " copy(" in ln and f"bf16[{POOL_BLOCKS}," in ln
+    ]
+
+
+def test_flash_attention_prefill(one_chip, no_cache):
+    sds = lambda h: jax.ShapeDtypeStruct((1, 2048, h, D), jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    _compile(lambda q, k, v: flash_attention(q, k, v, SCALE), sds(HQ), sds(HKV), sds(HKV))
+
+
+def test_flash_attention_chunk(one_chip, no_cache):
+    q = jax.ShapeDtypeStruct((1, CHUNK, HQ, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, HKV, SEQ, D), jnp.bfloat16, sharding=one_chip)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    _compile(lambda q, k, v, st: flash_attention_chunk(q, k, v, SCALE, st), q, kv, kv, start)
+
+
+def test_flash_attention_chunk_kvq(one_chip, no_cache):
+    q = jax.ShapeDtypeStruct((1, CHUNK, HQ, D), jnp.bfloat16, sharding=one_chip)
+    codes = jax.ShapeDtypeStruct((1, HKV, SEQ, D), jnp.int8, sharding=one_chip)
+    scales = jax.ShapeDtypeStruct((1, HKV, SEQ), jnp.float32, sharding=one_chip)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    _compile(
+        lambda q, kq, ks, vq, vs, st: flash_attention_chunk_kvq(q, kq, ks, vq, vs, SCALE, st),
+        q, codes, scales, codes, scales, start,
+    )
+
+
+def test_paged_decode_shard_map_tp4(tp4, no_cache):
+    """The tp serving path: the kernel under shard_map on four devices, two
+    KV heads per shard (models/llama.py _paged_attn_dispatch)."""
+    from nats_llm_studio_tpu.models.llama import _paged_attn_dispatch
+
+    heads = lambda *spec: NamedSharding(tp4, P(*spec))  # noqa: E731
+    rep = NamedSharding(tp4, P())
+    pool = jax.ShapeDtypeStruct((POOL_BLOCKS, L, HKV, T, D), jnp.bfloat16,
+                                sharding=heads(None, None, "tp", None, None))
+    q = jax.ShapeDtypeStruct((SLOTS, 1, HQ, D), jnp.bfloat16,
+                             sharding=heads(None, None, "tp", None))
+    tbl = jax.ShapeDtypeStruct((SLOTS, SEQ // T), jnp.int32, sharding=rep)
+    pos = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=rep)
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    # steer the program's backend check: lowering happens on the CPU
+    # backend, and the kernel must not take its interpret branch
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        compiled = _compile(
+            lambda q, kp, vp, tbl, pos, layer: _paged_attn_dispatch(
+                q, kp, vp, tbl, pos, layer, SCALE, tp4),
+            q, pool, pool, tbl, pos, layer,
+        )
+    finally:
+        jax.default_backend = orig
+    # per device: a quarter of each pool, nothing gathered
+    per_dev = compiled.memory_analysis().argument_size_in_bytes
+    assert per_dev < 0.3 * 2 * POOL_BLOCKS * L * HKV * T * D * 2
+    assert "all-gather" not in compiled.as_text()

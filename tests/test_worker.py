@@ -48,6 +48,41 @@ class Harness:
 
 
 @async_test
+async def test_chat_loads_under_the_pull_deadline_then_chats_under_its_own():
+    """The deadline ladder of chat_model: getting the model ready is a
+    pull-class step (an 8B int8 load is minutes) and runs under
+    pull_timeout_s; chat_timeout_s starts once the engine exists."""
+    broker = await EmbeddedBroker().start()
+    reg = FakeRegistry(models=["m1"])
+    real_get = reg.get_engine
+
+    async def slow_get(model_id):
+        await asyncio.sleep(0.6)  # longer than the chat deadline below
+        return await real_get(model_id)
+
+    reg.get_engine = slow_get
+    cfg = WorkerConfig(nats_url=broker.url)
+    cfg.chat_timeout_s, cfg.pull_timeout_s = 0.3, 5.0
+    w = Worker(cfg, reg)
+    await w.start()
+    nc = await connect(broker.url)
+    try:
+        body = json.dumps({"model": "m1", "messages": [
+            {"role": "user", "content": "hi"}]}).encode()
+        resp = json.loads((await nc.request("lmstudio.chat_model", body, timeout=10)).payload)
+        assert resp["ok"] is True, resp
+        # a load that outlasts the pull deadline is still a deadline error
+        cfg.pull_timeout_s = 0.2
+        resp = json.loads((await nc.request("lmstudio.chat_model", body, timeout=10)).payload)
+        assert resp["ok"] is False
+        assert resp["error"] == "error in chat: deadline exceeded"
+    finally:
+        await nc.close()
+        await w.drain()
+        await broker.stop()
+
+
+@async_test
 async def test_list_models_envelope():
     async with Harness(models=["m1", "m2"]) as h:
         resp = await h.req("list_models", {})
