@@ -1,0 +1,329 @@
+"""The one general traffic generator. A traffic mix is a data file
+(``benchmark/traffic/<name>.json``) of parameters; nothing here knows a mix
+by name. It drives closed loops, which is what every cell of the benchmark
+is; a PR that adds an open-loop, bursty or shared-prefix cell brings that
+generator code with its chip runs (PERF.md section 7).
+
+Parameters of a mix:
+
+    loop            "closed": ``callers`` clients, each sends its next request
+                    when its reply ends
+    callers         number of clients
+    prompt_tokens   <dist>: whole prompt as the engine counts it (template included)
+    output_tokens   <dist>: max_tokens of each request; a reply must run to it
+    temperature     sampling temperature; each request carries its own seed
+    deck            how many requests one shuffled deck holds (default 64)
+    order_seed      optional: the order of sizes comes from this number, not
+                    from --seed, which then decides only the texts, the
+                    sampling seeds and the weights. For mixes whose window
+                    holds a few tens of requests: there the order alone moved
+                    tokens/s by 4-5 % from seed to seed while two runs of one
+                    seed agreed to 0.1-0.3 % (PR 23, call 6)
+    warmup          {"concurrency": [..], "background": k, "max_tokens": n,
+                     "quiet_s": s, "min_settle_s": s, "max_settle_s": s,
+                     "settle_requests": n}: the sweep's widths, how many long
+                    streams keep the engine live under it, and when the
+                    settle phase may end: after ``settle_requests`` sends of
+                    the mix and ``quiet_s`` without a new program, at the next
+                    send (so the window always starts at the same point of the
+                    mix's sequence and at the same phase of the decode bursts)
+
+A <dist> is {"dist": "loguniform", "min": a, "max": b} or
+{"dist": "fixed", "value": v}.
+
+Every seed gets the SAME multiset of sizes — the stratified quantiles of each
+distribution, one deck at a time — in another order, so a seed changes the
+order of the work and not its amount.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import json
+import math
+import random
+import time
+from dataclasses import dataclass, field
+
+# the chat template the header-only GGUF carries: role tags and the text, so
+# the benchmark knows a prompt's token count (one token per byte) without
+# asking the program
+CHAT_TEMPLATE = (
+    "{% for m in messages %}<|{{ m['role'] }}|>{{ m['content'] }}{% endfor %}"
+    "<|assistant|>"
+)
+TEMPLATE_OVERHEAD = len("<|user|>") + len("<|assistant|>")
+ALPHABET = "abcdefghijklmnopqrstuvwxyz ABCDEFGHIJKLMNOPQRSTUVWXYZ 0123456789 .,;:"
+
+
+def quantiles(dist: dict, n: int) -> list[int]:
+    """The n stratified quantiles of a length distribution (whole tokens)."""
+    kind = dist["dist"]
+    if kind == "fixed":
+        return [int(dist["value"])] * n
+    lo, hi = float(dist["min"]), float(dist["max"])
+    us = [(i + 0.5) / n for i in range(n)]
+    if kind == "loguniform":
+        return [int(round(lo * (hi / lo) ** u)) for u in us]
+    raise ValueError(f"unknown distribution {kind!r}")
+
+
+def dist_bounds(dist: dict) -> tuple[int, int]:
+    if dist["dist"] == "fixed":
+        return int(dist["value"]), int(dist["value"])
+    return int(dist["min"]), int(dist["max"])
+
+
+def make_text(rng: random.Random, n: int) -> str:
+    return "".join(rng.choices(ALPHABET, k=n))
+
+
+@dataclass
+class Request:
+    idx: int
+    prompt: str
+    prompt_tokens: int
+    max_tokens: int
+    seed: int
+
+
+class Generator:
+    """Endless seeded stream of requests for one traffic mix."""
+
+    def __init__(self, mix: dict, seed: int):
+        if mix["loop"] != "closed":
+            raise ValueError(f"unknown loop {mix['loop']!r}: this generator drives closed loops")
+        self.mix = mix
+        self.rng = random.Random(seed)
+        self.order = random.Random(mix.get("order_seed", seed ^ 0x0DDE))
+        self.deck_n = int(mix.get("deck", 64))
+        self._idx = 0
+        self._deck: list[tuple[int, int]] = []
+
+    def _next_sizes(self) -> tuple[int, int]:
+        if not self._deck:
+            p = quantiles(self.mix["prompt_tokens"], self.deck_n)
+            o = quantiles(self.mix["output_tokens"], self.deck_n)
+            self.order.shuffle(p)
+            self.order.shuffle(o)
+            self._deck = list(zip(p, o))
+        return self._deck.pop()
+
+    def make(self, prompt_tokens: int, max_tokens: int) -> Request:
+        text = make_text(self.rng, max(1, prompt_tokens - TEMPLATE_OVERHEAD))
+        req = Request(self._idx, text, len(text) + TEMPLATE_OVERHEAD, max_tokens,
+                      self.rng.randrange(2**31))
+        self._idx += 1
+        return req
+
+    def next(self) -> Request:
+        return self.make(*self._next_sizes())
+
+
+def warmup_lengths(mix: dict) -> list[int]:
+    """The range's ends and one length just past every power of two inside
+    it: whatever ladder of buckets, chunks or windows the program compiles
+    by, a doubling ladder with "n <= rung" lands one length in each rung."""
+    lo, hi = dist_bounds(mix["prompt_tokens"])
+    out = {lo, hi}
+    p = 1
+    while p < hi:
+        if lo <= p + 1 <= hi:
+            out.add(p + 1)
+        p *= 2
+    return sorted(out)
+
+
+@dataclass
+class Record:
+    """Everything the client saw of one request, on ``time.perf_counter``."""
+    idx: int
+    prompt_tokens: int
+    max_tokens: int
+    t_sent: float
+    chunks: list = field(default_factory=list)  # (time, tokens in the chunk)
+    t_done: float | None = None
+    ok: bool = False
+    error: str | None = None
+    usage: dict | None = None
+    stats: dict | None = None
+    first_logprobs: list | None = None
+    mismatch: str | None = None
+
+
+class Client:
+    """Drives ``lmstudio.chat_model`` with ``"stream": true`` over one NATS
+    connection and keeps a Record per request."""
+
+    def __init__(self, nc, model_id: str, temperature: float, timeout_s: float = 300.0):
+        self.nc = nc
+        self.model_id = model_id
+        self.temperature = temperature
+        self.timeout_s = timeout_s
+        self.records: list[Record] = []
+        self.send_event: asyncio.Event | None = None  # set at the next send
+        self.send_time = 0.0                          # ... with its time here
+
+    async def chat(self, req: Request, logprobs: int = 0) -> Record:
+        body = {
+            "model": self.model_id,
+            "messages": [{"role": "user", "content": req.prompt}],
+            "max_tokens": req.max_tokens,
+            "temperature": self.temperature,
+            "seed": req.seed,
+            "stream": True,
+        }
+        if logprobs:
+            body |= {"logprobs": True, "top_logprobs": logprobs}
+        now = time.perf_counter()
+        rec = Record(req.idx, req.prompt_tokens, req.max_tokens, now)
+        self.records.append(rec)
+        if self.send_event is not None and not self.send_event.is_set():
+            self.send_time = now
+            self.send_event.set()
+        stream = self.nc.request_stream(
+            "lmstudio.chat_model", json.dumps(body).encode(),
+            timeout=self.timeout_s, idle_timeout=self.timeout_s)
+        try:
+            async for msg in stream:
+                t = time.perf_counter()
+                if (msg.headers or {}).get("Nats-Stream-Done") is not None:
+                    rec.t_done = t
+                    self._finish(rec, json.loads(msg.payload))
+                    break
+                choice = json.loads(msg.payload)["data"]["chunk"]["choices"][0]
+                if logprobs and rec.first_logprobs is None and choice.get("logprobs"):
+                    rec.first_logprobs = choice["logprobs"]["content"][0]["top_logprobs"]
+                # byte-level tokenizer: one printable character is one token
+                rec.chunks.append((t, len(choice["delta"].get("content", ""))))
+            else:
+                rec.error = "stream ended without a terminal message"
+        except Exception as e:  # noqa: BLE001 — a failed request is a result
+            rec.error = f"{type(e).__name__}: {e}"
+        finally:
+            await stream.aclose()
+        return rec
+
+    @staticmethod
+    def _finish(rec: Record, env: dict) -> None:
+        if not env.get("ok"):
+            rec.error = str(env.get("error"))
+            return
+        resp = env["data"]["response"]
+        rec.usage = resp["usage"]
+        rec.stats = resp.get("stats")
+        rec.ok = True
+        got = (rec.usage["prompt_tokens"], rec.usage["completion_tokens"],
+               sum(n for _, n in rec.chunks))
+        want = (rec.prompt_tokens, rec.max_tokens, rec.max_tokens)
+        if got != want:
+            rec.mismatch = (f"request {rec.idx}: prompt/completion/streamed tokens "
+                            f"{got}, expected {want}")
+
+
+class Load:
+    """Runs a mix's closed loop until stopped; the window is a slice of a
+    load that is already steady when it starts."""
+
+    def __init__(self, client: Client, gen: Generator):
+        self.client = client
+        self.gen = gen
+        self.tasks: set[asyncio.Task] = set()
+        self.stopping = False
+
+    def start(self) -> None:
+        for _ in range(int(self.gen.mix["callers"])):
+            t = asyncio.ensure_future(self._caller())
+            self.tasks.add(t)
+            t.add_done_callback(self.tasks.discard)
+
+    async def _caller(self) -> None:
+        while not self.stopping:
+            await self.client.chat(self.gen.next())
+
+    async def stop(self, must_have_first_chunk_since: float) -> None:
+        """Stop sending. A request sent inside the window is waited for
+        until its first chunk came (its TTFT is a result); then the replies
+        still in flight are abandoned."""
+        self.stopping = True
+        hard = time.perf_counter() + 60.0
+        while time.perf_counter() < hard and any(
+            r.t_done is None and r.error is None and not r.chunks
+            and r.t_sent >= must_have_first_chunk_since
+            for r in self.client.records
+        ):
+            await asyncio.sleep(0.05)
+        for t in list(self.tasks):
+            t.cancel()
+        if self.tasks:
+            await asyncio.wait(self.tasks, timeout=30.0)
+
+
+def tokens_in_window(chunks: list, w0: float, w1: float) -> float:
+    """Output tokens of one stream that fall into [w0, w1). A chunk carries
+    the tokens of a whole decode burst, made over the time since the
+    stream's previous chunk, so they are spread evenly over that time and
+    the part inside the window counts: a burst that straddles an edge of
+    the window is shared out, not won or lost whole. The first chunk (the
+    token the prefill sampled) counts at the moment it came."""
+    total = 0.0
+    prev = None
+    for t, n in chunks:
+        if prev is None or t <= prev:
+            total += n if w0 <= t < w1 else 0.0
+        else:
+            total += n * max(0.0, min(t, w1) - max(prev, w0)) / (t - prev)
+        prev = t
+    return total
+
+
+def reduce_client(records: list[Record], w0: float, w1: float) -> dict:
+    """Client-side numbers of one window, from the records alone.
+
+    out_tok_s    output tokens received in [w0, w1), a chunk's tokens spread
+                 over the time since its stream's previous chunk
+                 (``tokens_in_window``), over the window's seconds
+    ttft         sent to the first streamed chunk, over every request sent
+                 in the window; a request that failed or never got a chunk
+                 is +inf
+    gaps         between consecutive chunks of one stream, pooled over all
+                 streams, for every chunk received in the window
+    """
+    from .stats import percentile
+
+    tokens = 0.0
+    gaps: list[float] = []
+    ttft: list[float] = []
+    attempted = failed = completed = 0
+    for r in records:
+        tokens += tokens_in_window(r.chunks, w0, w1)
+        for (prev, _), (t, _) in zip(r.chunks, r.chunks[1:]):
+            if w0 <= t < w1:
+                gaps.append(t - prev)
+        if w0 <= r.t_sent < w1:
+            attempted += 1
+            if r.error is None and r.chunks:
+                ttft.append(r.chunks[0][0] - r.t_sent)
+            else:
+                failed += 1
+                ttft.append(math.inf)
+            if r.ok:
+                completed += 1
+    mid = min(len(ttft) - 1, int(len(ttft) * 0.5))  # the index ``percentile`` takes
+    return {
+        "window_s": w1 - w0,
+        "out_tokens": tokens,
+        "out_tok_s": tokens / (w1 - w0),
+        "attempted": attempted,
+        "failed": failed,
+        "completed": completed,
+        # the order statistics around the median and the longest tenth of the
+        # gaps: how far each percentile jumps when one sample changes sides
+        "ttft_near_p50_s": sorted(ttft)[max(0, mid - 4):mid + 5],
+        "ttft_p50_s": percentile(ttft, 0.5),
+        "gap_n": len(gaps),
+        "gap_p50_s": percentile(gaps, 0.5),
+        "gap_p95_s": percentile(gaps, 0.95),
+        "gap_top_s": sorted(gaps)[-(len(gaps) // 10 + 1):],
+        "mismatches": [r.mismatch for r in records if r.mismatch],
+    }

@@ -1,0 +1,151 @@
+import asyncio
+import math
+import time
+from collections import Counter
+
+from benchmark.lib import traffic
+from benchmark.lib.stats import INF_MS, finite_ms, hist_mean, hist_percentile, percentile, spread
+
+CLOSED = {"loop": "closed", "callers": 2, "deck": 16,
+          "prompt_tokens": {"dist": "loguniform", "min": 64, "max": 1024},
+          "output_tokens": {"dist": "loguniform", "min": 16, "max": 128}}
+
+
+def take(gen, n):
+    return [gen.next() for _ in range(n)]
+
+
+def test_generator_is_seeded_and_counts_template_tokens():
+    a, b = take(traffic.Generator(CLOSED, 5), 40), take(traffic.Generator(CLOSED, 5), 40)
+    assert [(r.prompt, r.max_tokens, r.seed) for r in a] == [
+        (r.prompt, r.max_tokens, r.seed) for r in b]
+    for r in a:
+        assert r.prompt_tokens == len(f"<|user|>{r.prompt}<|assistant|>")
+        assert 64 <= r.prompt_tokens <= 1024 and 16 <= r.max_tokens <= 128
+
+
+def test_every_seed_gets_the_same_sizes_in_another_order():
+    a, b = take(traffic.Generator(CLOSED, 1), 16), take(traffic.Generator(CLOSED, 2**31 + 7), 16)
+    assert Counter(r.prompt_tokens for r in a) == Counter(r.prompt_tokens for r in b)
+    assert Counter(r.max_tokens for r in a) == Counter(r.max_tokens for r in b)
+    assert [r.prompt_tokens for r in a] != [r.prompt_tokens for r in b]
+
+
+def test_a_mix_the_generator_does_not_drive_is_refused():
+    import pytest
+
+    with pytest.raises(ValueError):
+        traffic.Generator(dict(CLOSED, loop="open"), 1)
+    with pytest.raises(ValueError):
+        traffic.quantiles({"dist": "uniform", "min": 1, "max": 2}, 4)
+
+
+def test_closed_loop_keeps_callers_in_flight_and_stops():
+    mix = dict(CLOSED, output_tokens={"dist": "fixed", "value": 2})
+
+    async def go():
+        client = traffic.Client(FakeNC(), "m", 0.8)
+        load = traffic.Load(client, traffic.Generator(mix, 4))
+        w0 = time.perf_counter()
+        load.start()
+        await asyncio.sleep(0.2)
+        w1 = time.perf_counter()
+        await load.stop(w0)
+        return client, load, w0, w1
+
+    client, load, w0, w1 = asyncio.run(go())
+    cm = traffic.reduce_client(client.records, w0, w1)
+    assert not load.tasks and cm["attempted"] >= 4 and cm["failed"] == 0 and not cm["mismatches"]
+    assert cm["ttft_p50_s"] in cm["ttft_near_p50_s"] and cm["ttft_p50_s"] < 0.1
+
+
+def test_warmup_lengths_land_in_every_rung_of_a_doubling_ladder():
+    assert traffic.warmup_lengths(CLOSED) == [64, 65, 129, 257, 513, 1024]
+
+
+class FakeNC:
+    """Streams ``n`` one-token chunks ``delay`` apart, then the terminal
+    message; ``stall`` blocks the event loop once (a server-side stall)."""
+
+    def __init__(self, delay=0.01, fail=False):
+        self.delay, self.fail = delay, fail
+
+    async def request_stream(self, subject, payload, timeout, idle_timeout):
+        import json
+
+        body = json.loads(payload)
+
+        class M:
+            def __init__(self, payload, headers=None):
+                self.payload, self.headers = payload, headers
+
+        n = body["max_tokens"]
+        if self.fail:
+            yield M(json.dumps({"ok": False, "error": "shed"}).encode(), {"Nats-Stream-Done": "1"})
+            return
+        for _ in range(n):
+            await asyncio.sleep(self.delay)
+            yield M(json.dumps({"ok": True, "data": {"chunk": {"choices": [
+                {"delta": {"content": "x"}}]}}}).encode())
+        n_prompt = len(f"<|user|>{body['messages'][0]['content']}<|assistant|>")
+        yield M(json.dumps({"ok": True, "data": {"response": {
+            "usage": {"prompt_tokens": n_prompt, "completion_tokens": n}, "stats": {}}}}).encode(),
+            {"Nats-Stream-Done": "1"})
+
+
+def test_failures_count_as_infinite_and_token_mismatch_is_caught():
+    async def go():
+        client = traffic.Client(FakeNC(fail=True), "m", 0.8)
+        gen = traffic.Generator(CLOSED, 1)
+        t0 = time.perf_counter()
+        await asyncio.gather(*(client.chat(gen.next()) for _ in range(3)))
+        return client, t0
+
+    client, t0 = asyncio.run(go())
+    cm = traffic.reduce_client(client.records, t0, time.perf_counter())
+    assert cm["attempted"] == 3 and cm["failed"] == 3
+    assert math.isinf(cm["ttft_p50_s"]) and finite_ms(cm["ttft_p50_s"]) == INF_MS
+    rec = traffic.Record(0, 10, 5, 0.0, chunks=[(0.1, 4)])
+    traffic.Client._finish(rec, {"ok": True, "data": {"response": {
+        "usage": {"prompt_tokens": 10, "completion_tokens": 5}}}})
+    assert rec.mismatch and "streamed" in rec.mismatch
+
+
+def test_window_slices_gaps_and_shares_out_a_chunk_that_straddles_an_edge():
+    r = traffic.Record(0, 10, 9, t_sent=0.5,
+                       chunks=[(0.9, 1), (1.1, 2), (1.4, 2), (2.2, 4)])
+    cm = traffic.reduce_client([r], 1.0, 2.0)
+    # (0.9, 1.1] holds 2 tokens, half of it inside; (1.4, 2.2] holds 4, 0.6 / 0.8 inside
+    assert abs(cm["out_tokens"] - (1.0 + 2.0 + 3.0)) < 1e-9 and cm["attempted"] == 0
+    assert cm["gap_n"] == 2 and abs(cm["gap_p95_s"] - 0.3) < 1e-9
+    # whatever the window's phase, windows laid end to end count every token once
+    edges = [0.0, 0.95, 1.3, 2.05, 3.0]
+    parts = [traffic.tokens_in_window(r.chunks, a, b) for a, b in zip(edges, edges[1:])]
+    assert abs(sum(parts) - 9.0) < 1e-9
+    # a shift of the window by 10 ms moves the count by 10 ms of the rate, not by a chunk
+    a, b = (traffic.tokens_in_window(r.chunks, 1.0, w1) for w1 in (2.195, 2.205))
+    assert 0.0 < b - a < 0.06
+
+
+def test_first_chunk_counts_where_it_came():
+    chunks = [(1.5, 1), (2.0, 7)]
+    assert traffic.tokens_in_window(chunks, 1.0, 1.4) == 0.0
+    assert traffic.tokens_in_window(chunks, 1.0, 1.75) == 1.0 + 3.5
+
+
+def test_percentiles_and_histogram_deltas():
+    assert percentile([3, 1, 2, math.inf], 0.5) == 3 and math.isinf(percentile([1, math.inf], 0.95))
+    assert math.isnan(percentile([], 0.5))
+    assert abs(spread([10, 11, 12, 13, 14, 15]) - 3.5 / 12.5) < 1e-12
+    h = {"bounds": (1.0, 2.0, 4.0), "counts": (0, 4, 0, 0), "count": 4, "total": 6.0}
+    assert hist_mean(h) == 1.5 and 1.0 < hist_percentile(h, 0.5) <= 2.0
+    assert hist_percentile({"bounds": (1.0,), "counts": (0, 0), "count": 0, "total": 0.0}, 0.5) is None
+
+
+def test_order_seed_fixes_the_order_and_leaves_the_texts_to_the_seed():
+    mix = dict(CLOSED, order_seed=23)
+    a, b = take(traffic.Generator(mix, 1), 20), take(traffic.Generator(mix, 2**31 + 5), 20)
+    assert [(r.prompt_tokens, r.max_tokens) for r in a] == [
+        (r.prompt_tokens, r.max_tokens) for r in b]
+    assert [r.prompt for r in a] != [r.prompt for r in b]
+    assert [r.seed for r in a] != [r.seed for r in b]
