@@ -13,7 +13,11 @@ from .aggregator import (
     assemble_trace,
     merge_expositions,
 )
-from .compile_cache import compile_cache_counts, install_compile_cache_listener
+from .compile_cache import (
+    build_ledger,
+    compile_cache_counts,
+    install_compile_cache_listener,
+)
 from .events import EVENTS, EventRing, emit
 from .histogram import (
     HistSnapshot,
@@ -59,6 +63,7 @@ __all__ = [
     "EventRing",
     "emit",
     "FlightRecorder",
+    "build_ledger",
     "compile_cache_counts",
     "install_compile_cache_listener",
     "HistSnapshot",

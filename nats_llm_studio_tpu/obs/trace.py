@@ -111,7 +111,7 @@ class Span:
 
 class Trace:
     __slots__ = ("trace_id", "attempt", "span_id", "parent_span_id",
-                 "t0_wall", "_marks", "_lock")
+                 "t0_wall", "_marks", "_lock", "emitted")
 
     def __init__(self, trace_id: str | None = None, attempt: int | None = None,
                  parent_span_id: str = ""):
@@ -128,6 +128,10 @@ class Trace:
         self.t0_wall = time.time()
         self._marks: dict[str, float] = {}
         self._lock = threading.Lock()
+        # (perf_counter, tokens so far) of the newest token the batcher's
+        # owner thread handed to the stream: one tuple store, read by the
+        # worker's reply path for the worker.publish span's lag (obs/spans.py)
+        self.emitted: tuple[float, int] | None = None
 
     def to_span(self, stage: str, worker_id: str = "",
                 attrs: dict | None = None) -> dict:

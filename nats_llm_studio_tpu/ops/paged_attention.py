@@ -212,6 +212,9 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows_p, d), q.dtype),
         interpret=interpret,
+        # a constant: it is the custom call's name in a device trace and part
+        # of the program's bytes, so of its compile-cache key
+        name="paged_decode_attention",
     )(
         tbl.astype(jnp.int32),
         jnp.asarray(pos, jnp.int32).reshape(b),

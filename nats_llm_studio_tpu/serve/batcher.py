@@ -38,6 +38,7 @@ from ..models.llama import forward, forward_decode_paged, make_cache
 from ..engine.sampling import sample_rows, spec_accept_rows
 from ..obs import LogHistogram, Trace
 from ..obs import emit as obs_emit
+from ..obs import spans as obs_spans
 from ..obs.roofline import (
     SPEC_PROGRAMS,
     WASTE_CATEGORIES,
@@ -75,6 +76,10 @@ from .qos import (
     class_weight,
 )
 from .spec import SpecConfig, SpecSlot, make_slot
+
+# obs/ stays import-light: the span primitive gets the profiler's annotation
+# from here, where JAX is imported anyway
+obs_spans.use_annotation(jax.profiler.TraceAnnotation)
 
 log = logging.getLogger(__name__)
 
@@ -1653,9 +1658,12 @@ class ContinuousBatcher:
             key = dispatch_shape_key(args, kwargs)
             cold = key not in cost_cache
             if cold:
-                cost_cache[key] = (
-                    extract_dispatch_cost(fn, args, kwargs) if eff else None
-                )
+                if eff:
+                    # lowers the program a second time, on the calling thread
+                    with obs_spans.span("batcher.cost_probe", program=name):
+                        cost_cache[key] = extract_dispatch_cost(fn, args, kwargs)
+                else:
+                    cost_cache[key] = None
             cost = cost_cache[key]
             t0 = time.monotonic()
             out = fn(*args, **kwargs)
@@ -2812,217 +2820,233 @@ class ContinuousBatcher:
             nonlocal tok_dev, dirty
             if rec[0] == "decode":
                 _, toks_ref, n, rows, t_disp = rec
-                ids = np.asarray(toks_ref)  # ONE [B, n] readback per burst
+                with obs_spans.span("batcher.readback", program="decode"):
+                    ids = np.asarray(toks_ref)  # ONE [B, n] readback per burst
                 # observed per-step latency (dispatch -> tokens readable);
                 # includes pipeline wait, i.e. what a stream experiences
                 now = time.monotonic()
                 step_s = (now - t_disp) / n
                 self.stats.decode_step_ms.record(step_s * 1e3)
                 self._note_decode_spt(self._warm_s(t_disp, now) / n)
-                for slot, req in rows:
-                    if self._slots[slot] is not req:
-                        continue  # finished at an earlier record; zombie rows
-                    if req.cancelled:
-                        self._ledger_finalize(
-                            req, "deadline_abort" if req.deadline_hit else "cancelled"
-                        )
-                        finish_slot(slot)
-                        self.stats.record_cancel(
-                            "deadline" if req.deadline_hit else "decode"
-                        )
-                        continue
-                    st = spec_slots[slot]
-                    try:
-                        for j in range(n):
-                            req.pos += 1
-                            t = int(ids[slot, j])
-                            if st is not None:
-                                st.index.append(t)
-                            reason = self._deliver(req, t)
-                            if reason is not None:
-                                self._ledger_finalize(req, "served")
-                                self._tenant_served(req)
-                                finish_slot(slot)  # free BEFORE the end event
-                                req.emit("end", reason)
-                                break
-                    except Exception:  # noqa: BLE001 — dead client
-                        log.exception("delivery failed; dropping slot %d", slot)
-                        self._ledger_finalize(req, "cancelled")
-                        finish_slot(slot)
+                with obs_spans.span("batcher.deliver") as spn:
+                    tok0 = self.stats.tokens
+                    for slot, req in rows:
+                        if self._slots[slot] is not req:
+                            continue  # finished at an earlier record; zombie rows
+                        if req.cancelled:
+                            self._ledger_finalize(
+                                req, "deadline_abort" if req.deadline_hit else "cancelled"
+                            )
+                            finish_slot(slot)
+                            self.stats.record_cancel(
+                                "deadline" if req.deadline_hit else "decode"
+                            )
+                            continue
+                        st = spec_slots[slot]
+                        try:
+                            for j in range(n):
+                                req.pos += 1
+                                t = int(ids[slot, j])
+                                if st is not None:
+                                    st.index.append(t)
+                                reason = self._deliver(req, t)
+                                if reason is not None:
+                                    self._ledger_finalize(req, "served")
+                                    self._tenant_served(req)
+                                    finish_slot(slot)  # free BEFORE the end event
+                                    req.emit("end", reason)
+                                    break
+                        except Exception:  # noqa: BLE001 — dead client
+                            log.exception("delivery failed; dropping slot %d", slot)
+                            self._ledger_finalize(req, "cancelled")
+                            finish_slot(slot)
+                    spn.attrs["tokens"] = self.stats.tokens - tok0
             elif rec[0] == "spec":
                 _, out_ref, nacc_ref, rows, t_disp = rec
-                ids = np.asarray(out_ref)  # [B, k+1]
-                nacc = np.asarray(nacc_ref)  # [B] emitted counts (a + 1)
+                with obs_spans.span("batcher.readback", program="spec"):
+                    ids = np.asarray(out_ref)  # [B, k+1]
+                    nacc = np.asarray(nacc_ref)  # [B] emitted counts (a + 1)
                 self.stats.decode_step_ms.record((time.monotonic() - t_disp) * 1e3)
-                for slot, req, dlen in rows:
-                    if self._slots[slot] is not req:
-                        continue  # spec is depth-0, but stay defensive
-                    n_emit = int(nacc[slot])
-                    # host pos catches up to the device carry HERE (spec is
-                    # the one dispatch whose advance is data-dependent);
-                    # host_steps advanced by k+1 at dispatch
-                    host_pos[slot] += n_emit
-                    if dlen > 0:
-                        self.stats.spec_drafted += dlen
-                        self.stats.spec_accepted += n_emit - 1
-                        rate = (n_emit - 1) / dlen
-                        self.stats.spec_accept_rate.record(max(rate, 0.01))
-                        prev = self._spec_accept_ewma
-                        self._spec_accept_ewma = (
-                            rate if prev == 0.0 else 0.8 * prev + 0.2 * rate
-                        )
-                        if self._efficiency and req.dev_spec_ms > 0.0:
-                            # ledger: the rejected-draft fraction of this
-                            # verify's cost moves out of the request's
-                            # accrual immediately — it can never serve a
-                            # token, whatever the request's outcome
-                            waste = min(
-                                req.dev_spec_ms * (dlen + 1 - n_emit) / (dlen + 1),
-                                req.dev_decode_ms,
+                with obs_spans.span("batcher.deliver") as spn:
+                    tok0 = self.stats.tokens
+                    for slot, req, dlen in rows:
+                        if self._slots[slot] is not req:
+                            continue  # spec is depth-0, but stay defensive
+                        n_emit = int(nacc[slot])
+                        # host pos catches up to the device carry HERE (spec is
+                        # the one dispatch whose advance is data-dependent);
+                        # host_steps advanced by k+1 at dispatch
+                        host_pos[slot] += n_emit
+                        if dlen > 0:
+                            self.stats.spec_drafted += dlen
+                            self.stats.spec_accepted += n_emit - 1
+                            rate = (n_emit - 1) / dlen
+                            self.stats.spec_accept_rate.record(max(rate, 0.01))
+                            prev = self._spec_accept_ewma
+                            self._spec_accept_ewma = (
+                                rate if prev == 0.0 else 0.8 * prev + 0.2 * rate
                             )
-                            if waste > 0.0:
-                                req.dev_decode_ms -= waste
-                                self.stats.attribute_device_time(
-                                    "spec_rejected", waste
+                            if self._efficiency and req.dev_spec_ms > 0.0:
+                                # ledger: the rejected-draft fraction of this
+                                # verify's cost moves out of the request's
+                                # accrual immediately — it can never serve a
+                                # token, whatever the request's outcome
+                                waste = min(
+                                    req.dev_spec_ms * (dlen + 1 - n_emit) / (dlen + 1),
+                                    req.dev_decode_ms,
                                 )
-                            req.dev_spec_ms = 0.0
-                    if req.cancelled:
-                        self._ledger_finalize(
-                            req, "deadline_abort" if req.deadline_hit else "cancelled"
-                        )
-                        finish_slot(slot)
-                        self.stats.record_cancel(
-                            "deadline" if req.deadline_hit else "decode"
-                        )
-                        continue
-                    st = spec_slots[slot]
-                    try:
-                        for j in range(n_emit):
-                            req.pos += 1
-                            t = int(ids[slot, j])
-                            if st is not None:
-                                st.index.append(t)
-                            reason = self._deliver(req, t)
-                            if reason is not None:
-                                self._ledger_finalize(req, "served")
-                                self._tenant_served(req)
-                                finish_slot(slot)  # free BEFORE the end event
-                                req.emit("end", reason)
-                                break
-                    except Exception:  # noqa: BLE001 — dead client
-                        log.exception("delivery failed; dropping slot %d", slot)
-                        self._ledger_finalize(req, "cancelled")
-                        finish_slot(slot)
+                                if waste > 0.0:
+                                    req.dev_decode_ms -= waste
+                                    self.stats.attribute_device_time(
+                                        "spec_rejected", waste
+                                    )
+                                req.dev_spec_ms = 0.0
+                        if req.cancelled:
+                            self._ledger_finalize(
+                                req, "deadline_abort" if req.deadline_hit else "cancelled"
+                            )
+                            finish_slot(slot)
+                            self.stats.record_cancel(
+                                "deadline" if req.deadline_hit else "decode"
+                            )
+                            continue
+                        st = spec_slots[slot]
+                        try:
+                            for j in range(n_emit):
+                                req.pos += 1
+                                t = int(ids[slot, j])
+                                if st is not None:
+                                    st.index.append(t)
+                                reason = self._deliver(req, t)
+                                if reason is not None:
+                                    self._ledger_finalize(req, "served")
+                                    self._tenant_served(req)
+                                    finish_slot(slot)  # free BEFORE the end event
+                                    req.emit("end", reason)
+                                    break
+                        except Exception:  # noqa: BLE001 — dead client
+                            log.exception("delivery failed; dropping slot %d", slot)
+                            self._ledger_finalize(req, "cancelled")
+                            finish_slot(slot)
+                    spn.attrs["tokens"] = self.stats.tokens - tok0
             elif rec[0] == "ext":
                 _, toks_ref, lp_ref, topids_ref, toplps_ref, rows, t_disp = rec
-                ids = np.asarray(toks_ref)  # [B]
-                lps = np.asarray(lp_ref)  # [B]
-                tis = np.asarray(topids_ref)  # [B, LOGPROBS_K]
-                tls = np.asarray(toplps_ref)  # [B, LOGPROBS_K]
+                with obs_spans.span("batcher.readback", program="ext"):
+                    ids = np.asarray(toks_ref)  # [B]
+                    lps = np.asarray(lp_ref)  # [B]
+                    tis = np.asarray(topids_ref)  # [B, LOGPROBS_K]
+                    tls = np.asarray(toplps_ref)  # [B, LOGPROBS_K]
                 now = time.monotonic()
                 step_s = now - t_disp
                 self.stats.decode_step_ms.record(step_s * 1e3)
                 self._note_decode_spt(self._warm_s(t_disp, now))
-                for slot, req in rows:
-                    if self._slots[slot] is not req:
-                        continue
-                    if req.cancelled:
-                        self._ledger_finalize(
-                            req, "deadline_abort" if req.deadline_hit else "cancelled"
-                        )
-                        finish_slot(slot)
-                        self.stats.record_cancel(
-                            "deadline" if req.deadline_hit else "decode"
-                        )
-                        continue
-                    st = spec_slots[slot]
-                    try:
-                        req.pos += 1
-                        t = int(ids[slot])
-                        if st is not None:
-                            st.index.append(t)  # normal slot riding along
-                        dead = False
-                        if req.constrain is not None:
-                            nstate = req.constrain.advance(req.cstate, t)
-                            if nstate is not None:
-                                # (None only for an EOS outside an accept
-                                # state, which the mask already forbids —
-                                # _deliver maps stop ids to "stop" below)
-                                req.cstate = nstate
-                            dead = not req.constrain.live(req.cstate)
-                        if req.want_logprobs:
-                            reason = self._deliver(
-                                req, t, logprob=float(lps[slot]),
-                                top_ids=tis[slot].tolist(),
-                                top_lps=tls[slot].tolist(),
+                with obs_spans.span("batcher.deliver") as spn:
+                    tok0 = self.stats.tokens
+                    for slot, req in rows:
+                        if self._slots[slot] is not req:
+                            continue
+                        if req.cancelled:
+                            self._ledger_finalize(
+                                req, "deadline_abort" if req.deadline_hit else "cancelled"
                             )
-                        else:
-                            reason = self._deliver(req, t)
-                        if reason is None and dead:
-                            # the DFA can extend the document no further:
-                            # the constrained output is complete
-                            reason = "stop"
-                        if reason is not None:
-                            self._ledger_finalize(req, "served")
-                            self._tenant_served(req)
-                            finish_slot(slot)  # free BEFORE the end event
-                            req.emit("end", reason)
-                    except Exception:  # noqa: BLE001 — dead client
-                        log.exception("delivery failed; dropping slot %d", slot)
-                        self._ledger_finalize(req, "cancelled")
-                        finish_slot(slot)
+                            finish_slot(slot)
+                            self.stats.record_cancel(
+                                "deadline" if req.deadline_hit else "decode"
+                            )
+                            continue
+                        st = spec_slots[slot]
+                        try:
+                            req.pos += 1
+                            t = int(ids[slot])
+                            if st is not None:
+                                st.index.append(t)  # normal slot riding along
+                            dead = False
+                            if req.constrain is not None:
+                                nstate = req.constrain.advance(req.cstate, t)
+                                if nstate is not None:
+                                    # (None only for an EOS outside an accept
+                                    # state, which the mask already forbids —
+                                    # _deliver maps stop ids to "stop" below)
+                                    req.cstate = nstate
+                                dead = not req.constrain.live(req.cstate)
+                            if req.want_logprobs:
+                                reason = self._deliver(
+                                    req, t, logprob=float(lps[slot]),
+                                    top_ids=tis[slot].tolist(),
+                                    top_lps=tls[slot].tolist(),
+                                )
+                            else:
+                                reason = self._deliver(req, t)
+                            if reason is None and dead:
+                                # the DFA can extend the document no further:
+                                # the constrained output is complete
+                                reason = "stop"
+                            if reason is not None:
+                                self._ledger_finalize(req, "served")
+                                self._tenant_served(req)
+                                finish_slot(slot)  # free BEFORE the end event
+                                req.emit("end", reason)
+                        except Exception:  # noqa: BLE001 — dead client
+                            log.exception("delivery failed; dropping slot %d", slot)
+                            self._ledger_finalize(req, "cancelled")
+                            finish_slot(slot)
+                    spn.attrs["tokens"] = self.stats.tokens - tok0
             else:
                 _, firsts_ref, rows = rec
-                ids = np.asarray(firsts_ref)
-                for row, slot, req in rows:
-                    if self._slots[slot] is not req:
-                        continue
-                    if req.cancelled:
-                        self._ledger_finalize(
-                            req, "deadline_abort" if req.deadline_hit else "cancelled"
-                        )
-                        finish_slot(slot)
-                        self.stats.record_cancel(
-                            "deadline" if req.deadline_hit else "admit"
-                        )
-                        continue
-                    if req.is_ext and not req.rewound:
-                        # the rewind trick: the fused admit sampled token 0
-                        # without mask or logprob readback — drop it, step
-                        # the slot back one position, and put prompt[-1]
-                        # back on the device carry. The next ext step
-                        # re-processes prompt[-1] at position n-1 (the KV
-                        # write repeats identical values; CoW privatizes any
-                        # shared block first) and samples the REAL first
-                        # token under the mask. host_steps resets to 0 so
-                        # the delivered token 0 consumes rng (seed, step 0)
-                        # exactly like an unconstrained first token would.
-                        req.rewound = True
-                        host_pos[slot] -= 1
-                        host_steps[slot] = 0
-                        tok_dev = tok_dev.at[slot].set(
-                            jnp.int32(req.prompt_ids[-1])
-                        )
-                        dirty = True
-                        continue
-                    try:
-                        first = int(ids[row])
-                        reason = self._deliver(req, first)
-                        if reason is not None:
-                            self._ledger_finalize(req, "served")
-                            self._tenant_served(req)
-                            finish_slot(slot)  # free BEFORE the end event
-                            req.emit("end", reason)
-                        elif spec is not None:
-                            # history = prompt + the first sampled token
-                            # (still riding the device carry, unwritten)
-                            spec_slots[slot] = make_slot(
-                                req.prompt_ids, first, spec
+                with obs_spans.span("batcher.readback", program="admit"):
+                    ids = np.asarray(firsts_ref)
+                with obs_spans.span("batcher.deliver") as spn:
+                    tok0 = self.stats.tokens
+                    for row, slot, req in rows:
+                        if self._slots[slot] is not req:
+                            continue
+                        if req.cancelled:
+                            self._ledger_finalize(
+                                req, "deadline_abort" if req.deadline_hit else "cancelled"
                             )
-                    except Exception:  # noqa: BLE001 — dead client
-                        log.exception("delivery failed; dropping slot %d", slot)
-                        self._ledger_finalize(req, "cancelled")
-                        finish_slot(slot)
+                            finish_slot(slot)
+                            self.stats.record_cancel(
+                                "deadline" if req.deadline_hit else "admit"
+                            )
+                            continue
+                        if req.is_ext and not req.rewound:
+                            # the rewind trick: the fused admit sampled token 0
+                            # without mask or logprob readback — drop it, step
+                            # the slot back one position, and put prompt[-1]
+                            # back on the device carry. The next ext step
+                            # re-processes prompt[-1] at position n-1 (the KV
+                            # write repeats identical values; CoW privatizes any
+                            # shared block first) and samples the REAL first
+                            # token under the mask. host_steps resets to 0 so
+                            # the delivered token 0 consumes rng (seed, step 0)
+                            # exactly like an unconstrained first token would.
+                            req.rewound = True
+                            host_pos[slot] -= 1
+                            host_steps[slot] = 0
+                            tok_dev = tok_dev.at[slot].set(
+                                jnp.int32(req.prompt_ids[-1])
+                            )
+                            dirty = True
+                            continue
+                        try:
+                            first = int(ids[row])
+                            reason = self._deliver(req, first)
+                            if reason is not None:
+                                self._ledger_finalize(req, "served")
+                                self._tenant_served(req)
+                                finish_slot(slot)  # free BEFORE the end event
+                                req.emit("end", reason)
+                            elif spec is not None:
+                                # history = prompt + the first sampled token
+                                # (still riding the device carry, unwritten)
+                                spec_slots[slot] = make_slot(
+                                    req.prompt_ids, first, spec
+                                )
+                        except Exception:  # noqa: BLE001 — dead client
+                            log.exception("delivery failed; dropping slot %d", slot)
+                            self._ledger_finalize(req, "cancelled")
+                            finish_slot(slot)
+                    spn.attrs["tokens"] = self.stats.tokens - tok0
 
         def pump(depth: int = 1) -> None:
             """Process oldest readbacks until at most ``depth`` dispatches
@@ -3106,99 +3130,102 @@ class ContinuousBatcher:
             # charge this burst (and its CoW/alloc side dispatches) to the
             # active requests; restore the previous context because decode
             # interleaves inside admit chunk loops
-            prev_ctx = self._charge_ctx
-            self._charge_ctx = tuple(
-                r for r in (self._slots[i] for i in act) if isinstance(r, _Request)
-            )
-            refresh_rows()
-            # cap the burst so no active row can run past the cache capacity.
-            # n is a static jit arg: snap to single steps near capacity
-            # instead of counting down through n-1 fresh compiles.
-            # NOTE: with the depth-2 pipeline, host_pos may TRANSIENTLY sit at
-            # or past max_seq for a row whose terminal burst is still awaiting
-            # readback (the delivery in process_record ends it with "length").
-            # Those zombie steps are safe: the ring mask's mod-S arithmetic
-            # degrades to full-window attention once start_pos >= max_seq, so
-            # the extra decode computes a token nobody delivers — headroom
-            # may be <= 0 here and n=1 covers it.
-            headroom = self.max_seq - 1 - max(host_pos[i] for i in act)
-            # brownout shrinks the burst (shorter dispatch windows → faster
-            # shed/abort reaction under pressure); n stays a static jit arg
-            # from a tiny set {burst, burst//2, 1}, so compiles stay bounded
-            burst = (
-                self.brownout.effective_burst(self.decode_burst)
-                if self.brownout is not None
-                else self.decode_burst
-            )
-            n = burst if headroom >= burst else 1
-            if paged:
-                # grow each row's table to cover its writes, privatize any
-                # still-shared block in the write range (CoW), then decode
-                # through the gathered block-table view. The view extent
-                # nb*T rides the SAME pow2 ladder as the contiguous
-                # positional window, so softmax reduction extents match
-                # bit-for-bit.
-                if not grow_for_burst(act, lambda i: host_pos[i] + n, prev_ctx):
-                    return
-                refresh_tables()
-                if use_pallas:
-                    self._note_compile("decode_pallas", n)
-                    toks, K, V, tok_dev, pos_dev, steps_dev = (
-                        self._decode_pos_pallas(
-                            self.params, tok_dev, K, V, tbl_dev, pos_dev,
-                            seeds_dev, steps_dev, temp, topk, topp, n,
-                            _tokens=len(act) * n,
+            with obs_spans.span("batcher.dispatch", program="decode",
+                                rows=len(act)) as spn:
+                prev_ctx = self._charge_ctx
+                self._charge_ctx = tuple(
+                    r for r in (self._slots[i] for i in act) if isinstance(r, _Request)
+                )
+                refresh_rows()
+                # cap the burst so no active row can run past the cache capacity.
+                # n is a static jit arg: snap to single steps near capacity
+                # instead of counting down through n-1 fresh compiles.
+                # NOTE: with the depth-2 pipeline, host_pos may TRANSIENTLY sit at
+                # or past max_seq for a row whose terminal burst is still awaiting
+                # readback (the delivery in process_record ends it with "length").
+                # Those zombie steps are safe: the ring mask's mod-S arithmetic
+                # degrades to full-window attention once start_pos >= max_seq, so
+                # the extra decode computes a token nobody delivers — headroom
+                # may be <= 0 here and n=1 covers it.
+                headroom = self.max_seq - 1 - max(host_pos[i] for i in act)
+                # brownout shrinks the burst (shorter dispatch windows → faster
+                # shed/abort reaction under pressure); n stays a static jit arg
+                # from a tiny set {burst, burst//2, 1}, so compiles stay bounded
+                burst = (
+                    self.brownout.effective_burst(self.decode_burst)
+                    if self.brownout is not None
+                    else self.decode_burst
+                )
+                n = burst if headroom >= burst else 1
+                spn.attrs["steps"] = n
+                if paged:
+                    # grow each row's table to cover its writes, privatize any
+                    # still-shared block in the write range (CoW), then decode
+                    # through the gathered block-table view. The view extent
+                    # nb*T rides the SAME pow2 ladder as the contiguous
+                    # positional window, so softmax reduction extents match
+                    # bit-for-bit.
+                    if not grow_for_burst(act, lambda i: host_pos[i] + n, prev_ctx):
+                        return
+                    refresh_tables()
+                    if use_pallas:
+                        self._note_compile("decode_pallas", n)
+                        toks, K, V, tok_dev, pos_dev, steps_dev = (
+                            self._decode_pos_pallas(
+                                self.params, tok_dev, K, V, tbl_dev, pos_dev,
+                                seeds_dev, steps_dev, temp, topk, topp, n,
+                                _tokens=len(act) * n,
+                            )
                         )
+                    else:
+                        nb = paged_window(max(host_pos[i] for i in act) + n + 1)
+                        self._note_compile("decode_pos_paged", n, nb)
+                        toks, K, V, tok_dev, pos_dev, steps_dev = (
+                            self._decode_pos_paged(
+                                self.params, tok_dev, K, V, tbl_dev, pos_dev,
+                                seeds_dev, steps_dev, temp, topk, topp, n, nb,
+                                _tokens=len(act) * n,
+                            )
+                        )
+                elif positional:
+                    # writes land at each row's own position: the window only
+                    # needs to cover the highest live position after the burst
+                    # (pow2 ladder, same bounded-compile argument as prefill)
+                    w = self._win_bucket(max(host_pos[i] for i in act) + n + 1)
+                    window = w if w < self.max_seq else None
+                    self._note_compile("decode_pos", n, window)
+                    toks, K, V, tok_dev, pos_dev, steps_dev = self._decode_pos(
+                        self.params, tok_dev, K, V, pos_dev,
+                        seeds_dev, steps_dev, temp, topk, topp, n, window,
+                        _tokens=len(act) * n,
                     )
                 else:
-                    nb = paged_window(max(host_pos[i] for i in act) + n + 1)
-                    self._note_compile("decode_pos_paged", n, nb)
-                    toks, K, V, tok_dev, pos_dev, steps_dev = (
-                        self._decode_pos_paged(
-                            self.params, tok_dev, K, V, tbl_dev, pos_dev,
-                            seeds_dev, steps_dev, temp, topk, topp, n, nb,
-                            _tokens=len(act) * n,
-                        )
+                    # until the ring wraps, every live slot index is < ring_next:
+                    # attention can read just a bucket covering the head (static
+                    # windows come from self.buckets, so compiles stay bounded)
+                    window = None
+                    if not self._ring_wrapped:
+                        w = self._bucket(self._ring_next + n)
+                        if w < self.max_seq:
+                            window = w
+                    self._note_compile("decode", n, window)
+                    toks, K, V, tok_dev, pos_dev, steps_dev = self._decode(
+                        self.params, tok_dev, K, V, pos_dev, jnp.int32(self._ring_next),
+                        seeds_dev, steps_dev, temp, topk, topp, n, window,
+                        _tokens=len(act) * n,
                     )
-            elif positional:
-                # writes land at each row's own position: the window only
-                # needs to cover the highest live position after the burst
-                # (pow2 ladder, same bounded-compile argument as prefill)
-                w = self._win_bucket(max(host_pos[i] for i in act) + n + 1)
-                window = w if w < self.max_seq else None
-                self._note_compile("decode_pos", n, window)
-                toks, K, V, tok_dev, pos_dev, steps_dev = self._decode_pos(
-                    self.params, tok_dev, K, V, pos_dev,
-                    seeds_dev, steps_dev, temp, topk, topp, n, window,
-                    _tokens=len(act) * n,
+                    if self._ring_next + n >= self.max_seq:
+                        self._ring_wrapped = True
+                    self._ring_next = (self._ring_next + n) % self.max_seq
+                self.stats.steps += n
+                self.stats.tokens_per_step.record(float(len(act)))
+                for i in act:
+                    host_pos[i] += n
+                    host_steps[i] += n
+                inflight.append(
+                    ("decode", toks, n, [(i, self._slots[i]) for i in act], time.monotonic())
                 )
-            else:
-                # until the ring wraps, every live slot index is < ring_next:
-                # attention can read just a bucket covering the head (static
-                # windows come from self.buckets, so compiles stay bounded)
-                window = None
-                if not self._ring_wrapped:
-                    w = self._bucket(self._ring_next + n)
-                    if w < self.max_seq:
-                        window = w
-                self._note_compile("decode", n, window)
-                toks, K, V, tok_dev, pos_dev, steps_dev = self._decode(
-                    self.params, tok_dev, K, V, pos_dev, jnp.int32(self._ring_next),
-                    seeds_dev, steps_dev, temp, topk, topp, n, window,
-                    _tokens=len(act) * n,
-                )
-                if self._ring_next + n >= self.max_seq:
-                    self._ring_wrapped = True
-                self._ring_next = (self._ring_next + n) % self.max_seq
-            self.stats.steps += n
-            self.stats.tokens_per_step.record(float(len(act)))
-            for i in act:
-                host_pos[i] += n
-                host_steps[i] += n
-            inflight.append(
-                ("decode", toks, n, [(i, self._slots[i]) for i in act], time.monotonic())
-            )
-            self._charge_ctx = prev_ctx
+                self._charge_ctx = prev_ctx
 
         def decode_ext_once() -> None:
             """Dispatch ONE masked single-step decode covering every active
@@ -3212,60 +3239,62 @@ class ContinuousBatcher:
             act = active()
             if not act:
                 return
-            prev_ctx = self._charge_ctx
-            self._charge_ctx = tuple(
-                r for r in (self._slots[i] for i in act) if isinstance(r, _Request)
-            )
-            refresh_rows()
-            mask = np.ones((B, cfg.vocab_size), dtype=bool)
-            for i in act:
-                r = self._slots[i]
-                if isinstance(r, _Request) and r.constrain is not None:
-                    dm = r.constrain.mask(r.cstate)
-                    mask[i, :] = False
-                    mask[i, : dm.shape[0]] = dm
-            mask_dev = jnp.asarray(mask)
-            if paged:
-                if not grow_for_burst(act, lambda i: host_pos[i] + 1, prev_ctx):
-                    return
-                refresh_tables()
-                if use_pallas:
-                    self._note_compile("decode_pallas_ext")
-                    (toks, lps, top_ids, top_lps, K, V, tok_dev, pos_dev,
-                     steps_dev) = self._decode_pos_pallas_ext(
-                        self.params, tok_dev, K, V, tbl_dev, pos_dev,
-                        seeds_dev, steps_dev, temp, topk, topp, mask_dev,
-                        _tokens=len(act),
-                    )
-                else:
-                    nb = paged_window(max(host_pos[i] for i in act) + 2)
-                    self._note_compile("decode_pos_paged_ext", nb)
-                    (toks, lps, top_ids, top_lps, K, V, tok_dev, pos_dev,
-                     steps_dev) = self._decode_pos_paged_ext(
-                        self.params, tok_dev, K, V, tbl_dev, pos_dev,
-                        seeds_dev, steps_dev, temp, topk, topp, mask_dev, nb,
-                        _tokens=len(act),
-                    )
-            else:
-                w = self._win_bucket(max(host_pos[i] for i in act) + 2)
-                window = w if w < self.max_seq else None
-                self._note_compile("decode_pos_ext", window)
-                (toks, lps, top_ids, top_lps, K, V, tok_dev, pos_dev,
-                 steps_dev) = self._decode_pos_ext(
-                    self.params, tok_dev, K, V, pos_dev,
-                    seeds_dev, steps_dev, temp, topk, topp, mask_dev, window,
-                    _tokens=len(act),
+            with obs_spans.span("batcher.dispatch", program="ext", rows=len(act),
+                                steps=1):
+                prev_ctx = self._charge_ctx
+                self._charge_ctx = tuple(
+                    r for r in (self._slots[i] for i in act) if isinstance(r, _Request)
                 )
-            self.stats.steps += 1
-            self.stats.tokens_per_step.record(float(len(act)))
-            for i in act:
-                host_pos[i] += 1
-                host_steps[i] += 1
-            inflight.append(
-                ("ext", toks, lps, top_ids, top_lps,
-                 [(i, self._slots[i]) for i in act], time.monotonic())
-            )
-            self._charge_ctx = prev_ctx
+                refresh_rows()
+                mask = np.ones((B, cfg.vocab_size), dtype=bool)
+                for i in act:
+                    r = self._slots[i]
+                    if isinstance(r, _Request) and r.constrain is not None:
+                        dm = r.constrain.mask(r.cstate)
+                        mask[i, :] = False
+                        mask[i, : dm.shape[0]] = dm
+                mask_dev = jnp.asarray(mask)
+                if paged:
+                    if not grow_for_burst(act, lambda i: host_pos[i] + 1, prev_ctx):
+                        return
+                    refresh_tables()
+                    if use_pallas:
+                        self._note_compile("decode_pallas_ext")
+                        (toks, lps, top_ids, top_lps, K, V, tok_dev, pos_dev,
+                         steps_dev) = self._decode_pos_pallas_ext(
+                            self.params, tok_dev, K, V, tbl_dev, pos_dev,
+                            seeds_dev, steps_dev, temp, topk, topp, mask_dev,
+                            _tokens=len(act),
+                        )
+                    else:
+                        nb = paged_window(max(host_pos[i] for i in act) + 2)
+                        self._note_compile("decode_pos_paged_ext", nb)
+                        (toks, lps, top_ids, top_lps, K, V, tok_dev, pos_dev,
+                         steps_dev) = self._decode_pos_paged_ext(
+                            self.params, tok_dev, K, V, tbl_dev, pos_dev,
+                            seeds_dev, steps_dev, temp, topk, topp, mask_dev, nb,
+                            _tokens=len(act),
+                        )
+                else:
+                    w = self._win_bucket(max(host_pos[i] for i in act) + 2)
+                    window = w if w < self.max_seq else None
+                    self._note_compile("decode_pos_ext", window)
+                    (toks, lps, top_ids, top_lps, K, V, tok_dev, pos_dev,
+                     steps_dev) = self._decode_pos_ext(
+                        self.params, tok_dev, K, V, pos_dev,
+                        seeds_dev, steps_dev, temp, topk, topp, mask_dev, window,
+                        _tokens=len(act),
+                    )
+                self.stats.steps += 1
+                self.stats.tokens_per_step.record(float(len(act)))
+                for i in act:
+                    host_pos[i] += 1
+                    host_steps[i] += 1
+                inflight.append(
+                    ("ext", toks, lps, top_ids, top_lps,
+                     [(i, self._slots[i]) for i in act], time.monotonic())
+                )
+                self._charge_ctx = prev_ctx
 
         def spec_once() -> bool:
             """Dispatch ONE verify forward when at least one live slot has a
@@ -3298,63 +3327,65 @@ class ContinuousBatcher:
                     total += len(d)
             if total == 0:
                 return False  # nothing to verify: a plain burst is cheaper
-            prev_ctx = self._charge_ctx
-            self._charge_ctx = tuple(
-                r for r in (self._slots[i] for i in act) if isinstance(r, _Request)
-            )
-            refresh_rows()
-            if paged:
-                if not grow_for_burst(
-                    act, lambda i: host_pos[i] + kspec + 1, prev_ctx
-                ):
-                    return False  # slot list is stale; plain burst re-scans
-                refresh_tables()
-                if use_pallas:
-                    self._note_compile("spec_verify_pallas", kspec)
-                    out, nacc, K, V, tok_dev, pos_dev, steps_dev = (
-                        self._spec_verify_pallas(
-                            self.params, tok_dev, K, V, tbl_dev, pos_dev,
-                            jnp.asarray(drafts), jnp.asarray(dlens, jnp.int32),
-                            seeds_dev, steps_dev, temp, topk, topp,
-                            _tokens=len(act) * (kspec + 1),
-                        )
-                    )
-                else:
-                    nb = paged_window(max(host_pos[i] for i in act) + kspec + 1)
-                    self._note_compile("spec_verify_paged", nb)
-                    out, nacc, K, V, tok_dev, pos_dev, steps_dev = (
-                        self._spec_verify_paged(
-                            self.params, tok_dev, K, V, tbl_dev, pos_dev,
-                            jnp.asarray(drafts), jnp.asarray(dlens, jnp.int32),
-                            seeds_dev, steps_dev, temp, topk, topp, nb,
-                            _tokens=len(act) * (kspec + 1),
-                        )
-                    )
-            else:
-                w = self._win_bucket(max(host_pos[i] for i in act) + kspec + 1)
-                window = w if w < self.max_seq else None
-                self._note_compile("spec_verify", window)
-                out, nacc, K, V, tok_dev, pos_dev, steps_dev = self._spec_verify(
-                    self.params, tok_dev, K, V, pos_dev,
-                    jnp.asarray(drafts), jnp.asarray(dlens, jnp.int32),
-                    seeds_dev, steps_dev, temp, topk, topp, window,
-                    _tokens=len(act) * (kspec + 1),
+            with obs_spans.span("batcher.dispatch", program="spec", rows=len(act),
+                                steps=kspec + 1):
+                prev_ctx = self._charge_ctx
+                self._charge_ctx = tuple(
+                    r for r in (self._slots[i] for i in act) if isinstance(r, _Request)
                 )
-            self.stats.steps += 1
-            self.stats.spec_verifies += 1
-            self.stats.tokens_per_step.record(float(len(act)))
-            for i in act:
-                # rng streams advance by the verify width for every row
-                # (deterministic, matches the device carry); host_pos
-                # advances at READBACK — acceptance is data-dependent
-                host_steps[i] += kspec + 1
-            inflight.append((
-                "spec", out, nacc,
-                [(i, self._slots[i], dlens[i]) for i in act],
-                time.monotonic(),
-            ))
-            self._charge_ctx = prev_ctx
-            return True
+                refresh_rows()
+                if paged:
+                    if not grow_for_burst(
+                        act, lambda i: host_pos[i] + kspec + 1, prev_ctx
+                    ):
+                        return False  # slot list is stale; plain burst re-scans
+                    refresh_tables()
+                    if use_pallas:
+                        self._note_compile("spec_verify_pallas", kspec)
+                        out, nacc, K, V, tok_dev, pos_dev, steps_dev = (
+                            self._spec_verify_pallas(
+                                self.params, tok_dev, K, V, tbl_dev, pos_dev,
+                                jnp.asarray(drafts), jnp.asarray(dlens, jnp.int32),
+                                seeds_dev, steps_dev, temp, topk, topp,
+                                _tokens=len(act) * (kspec + 1),
+                            )
+                        )
+                    else:
+                        nb = paged_window(max(host_pos[i] for i in act) + kspec + 1)
+                        self._note_compile("spec_verify_paged", nb)
+                        out, nacc, K, V, tok_dev, pos_dev, steps_dev = (
+                            self._spec_verify_paged(
+                                self.params, tok_dev, K, V, tbl_dev, pos_dev,
+                                jnp.asarray(drafts), jnp.asarray(dlens, jnp.int32),
+                                seeds_dev, steps_dev, temp, topk, topp, nb,
+                                _tokens=len(act) * (kspec + 1),
+                            )
+                        )
+                else:
+                    w = self._win_bucket(max(host_pos[i] for i in act) + kspec + 1)
+                    window = w if w < self.max_seq else None
+                    self._note_compile("spec_verify", window)
+                    out, nacc, K, V, tok_dev, pos_dev, steps_dev = self._spec_verify(
+                        self.params, tok_dev, K, V, pos_dev,
+                        jnp.asarray(drafts), jnp.asarray(dlens, jnp.int32),
+                        seeds_dev, steps_dev, temp, topk, topp, window,
+                        _tokens=len(act) * (kspec + 1),
+                    )
+                self.stats.steps += 1
+                self.stats.spec_verifies += 1
+                self.stats.tokens_per_step.record(float(len(act)))
+                for i in act:
+                    # rng streams advance by the verify width for every row
+                    # (deterministic, matches the device carry); host_pos
+                    # advances at READBACK — acceptance is data-dependent
+                    host_steps[i] += kspec + 1
+                inflight.append((
+                    "spec", out, nacc,
+                    [(i, self._slots[i], dlens[i]) for i in act],
+                    time.monotonic(),
+                ))
+                self._charge_ctx = prev_ctx
+                return True
 
         pc = self.prefix_cache
 
@@ -4570,258 +4601,262 @@ class ContinuousBatcher:
             )
             poll_s = 0.05 if (block and self._suspended) else None
             first_intake = block
-            while True:
-                try:
-                    item = self._inbox.get(block=block, timeout=poll_s)
-                except _queue.Empty:
-                    break
-                block = False
-                if item is None:
-                    self._drain_all("shutdown", waitlist)
-                    return
-                if isinstance(item, _ControlOp):
-                    run_control(item)
-                    continue
-                if item.cancelled:
-                    self.stats.record_cancel("inbox")
-                    continue
-                waitlist.append(item)
-                self._wl_len = len(waitlist)  # keep idle() honest mid-intake
-                if first_intake and coalesce_s > 0:
-                    # the worker was idle and one request just arrived —
-                    # concurrent arrivals are usually a few scheduler ticks
-                    # apart; waiting a few ms turns 1 + (m-1) admit
-                    # dispatches (each a full device round trip) into ONE
-                    # batched admit
-                    first_intake = False
-                    deadline = time.monotonic() + coalesce_s
-                    while True:
-                        left = deadline - time.monotonic()
-                        if left <= 0:
-                            break
+            with obs_spans.span("batcher.intake") as spn:
+                n_wl = len(waitlist)
+                while True:
+                    try:
+                        item = self._inbox.get(block=block, timeout=poll_s)
+                    except _queue.Empty:
+                        break
+                    block = False
+                    if item is None:
+                        self._drain_all("shutdown", waitlist)
+                        return
+                    if isinstance(item, _ControlOp):
+                        run_control(item)
+                        continue
+                    if item.cancelled:
+                        self.stats.record_cancel("inbox")
+                        continue
+                    waitlist.append(item)
+                    self._wl_len = len(waitlist)  # keep idle() honest mid-intake
+                    if first_intake and coalesce_s > 0:
+                        # the worker was idle and one request just arrived —
+                        # concurrent arrivals are usually a few scheduler ticks
+                        # apart; waiting a few ms turns 1 + (m-1) admit
+                        # dispatches (each a full device round trip) into ONE
+                        # batched admit
+                        first_intake = False
+                        deadline = time.monotonic() + coalesce_s
+                        while True:
+                            left = deadline - time.monotonic()
+                            if left <= 0:
+                                break
+                            try:
+                                nxt = self._inbox.get(timeout=left)
+                            except _queue.Empty:
+                                break
+                            if nxt is None:
+                                self._drain_all("shutdown", waitlist)
+                                return
+                            if isinstance(nxt, _ControlOp):
+                                run_control(nxt)
+                                continue
+                            if nxt.cancelled:
+                                self.stats.record_cancel("inbox")
+                                continue
+                            waitlist.append(nxt)
+                            self._wl_len = len(waitlist)
+                spn.attrs["items"] = len(waitlist) - n_wl
+            with obs_spans.span("batcher.tick"):
+                drain_cancels(waitlist)
+                now = time.monotonic()
+                depth = len(waitlist) + self._inbox.qsize()
+                rebuild_slot_view()
+                rec = self.recorder
+                if rec is not None and rec.due(now):
+                    rec.sample(
+                        self._recorder_frame(depth=depth, n_active=len(active())),
+                        now=now,
+                    )
+                bo = self.brownout
+                lvl_before = bo.level if bo is not None else SHED_ONLY
+                if bo is not None:
+                    # controller tick: queue depth as a fraction of the
+                    # (configured, or nominal 4x-slots) limit, queue-age p95
+                    # over the current waiters, HBM headroom via the
+                    # registry-injected probe
+                    limit = self.max_queue or 4 * self.max_slots
+                    ages = sorted(self._warm_s(r.t_enq, now) * 1e3 for r in waitlist)
+                    age_p95 = ages[max(0, int(len(ages) * 0.95) - 1)] if ages else 0.0
+                    headroom_frac = None
+                    if self.hbm_headroom_fn is not None:
                         try:
-                            nxt = self._inbox.get(timeout=left)
-                        except _queue.Empty:
-                            break
-                        if nxt is None:
-                            self._drain_all("shutdown", waitlist)
-                            return
-                        if isinstance(nxt, _ControlOp):
-                            run_control(nxt)
-                            continue
-                        if nxt.cancelled:
-                            self.stats.record_cancel("inbox")
-                            continue
-                        waitlist.append(nxt)
-                        self._wl_len = len(waitlist)
-            drain_cancels(waitlist)
-            now = time.monotonic()
-            depth = len(waitlist) + self._inbox.qsize()
-            rebuild_slot_view()
-            rec = self.recorder
-            if rec is not None and rec.due(now):
-                rec.sample(
-                    self._recorder_frame(depth=depth, n_active=len(active())),
-                    now=now,
-                )
-            bo = self.brownout
-            lvl_before = bo.level if bo is not None else SHED_ONLY
-            if bo is not None:
-                # controller tick: queue depth as a fraction of the
-                # (configured, or nominal 4x-slots) limit, queue-age p95
-                # over the current waiters, HBM headroom via the
-                # registry-injected probe
-                limit = self.max_queue or 4 * self.max_slots
-                ages = sorted(self._warm_s(r.t_enq, now) * 1e3 for r in waitlist)
-                age_p95 = ages[max(0, int(len(ages) * 0.95) - 1)] if ages else 0.0
-                headroom_frac = None
-                if self.hbm_headroom_fn is not None:
-                    try:
-                        headroom_frac = self.hbm_headroom_fn()
-                    except Exception:  # noqa: BLE001 — probe is best-effort
-                        headroom_frac = None
-                bo.update(depth_frac=depth / limit, age_p95_ms=age_p95,
-                          hbm_headroom_frac=headroom_frac, now=now)
-                if (
-                    bo.level == SHED_ONLY
-                    and lvl_before < SHED_ONLY
-                    and rec is not None
-                ):
-                    # entering full shed is an incident, not a metric blip:
-                    # capture the ramp that led here (rate-limited)
-                    rec.dump(
-                        "shed_only_entry",
-                        extra={"depth": depth, "age_p95_ms": round(age_p95, 1),
-                               "hbm_headroom_frac": headroom_frac,
-                               "device_ms": self.stats.device_time_snapshot()["ms"]},
-                    )
-                if bo.level == SHED_ONLY and lvl_before < SHED_ONLY:
-                    # swap-don't-shed on the incident edge: park the
-                    # youngest streams on the host tier so the survivors
-                    # keep full decode width; they resume once the level
-                    # drops back below SHED_ONLY (resume_suspended gates
-                    # on it)
-                    target = bo.suspend_target(self.max_slots)
-                    while suspend_on:
-                        live = [
-                            i for i, r in enumerate(self._slots)
-                            if isinstance(r, _Request)
-                        ]
-                        if len(live) <= target:
-                            break
-                        # lowest class first, youngest within a class — a
-                        # premium stream is the last to be parked
-                        victim = min(
-                            live,
-                            key=lambda i: (
-                                self._slots[i].rank, -self._slots[i].t_admit
-                            ),
-                        )
-                        if not suspend_slot(victim, "brownout"):
-                            break
-            if tier is not None and paged:
-                # proactive demotion: keep ~demote_free_frac of the pool
-                # free by demoting cold cache chunks to the host tier
-                # BETWEEN bursts, so admissions stop paying the reclaim at
-                # the worst moment (and the tier fills before pressure
-                # peaks). No-op once the cache holds nothing unpinned.
-                floor_blocks = int(pool.n_blocks * tier.demote_free_frac)
-                if pool.free_blocks < floor_blocks:
-                    pc.reclaim(floor_blocks - pool.free_blocks, demote=True)
-            # deadline sweep, queued side: waiters whose budget already ran
-            # out — or whose remaining budget the live rate EWMAs say cannot
-            # cover prefill plus the token floor — are shed BEFORE any
-            # prefill work, with a retryable envelope
-            if waitlist and any(r.deadline is not None for r in waitlist):
-                kept = []
-                for r in waitlist:
-                    left = None if r.deadline is None else r.deadline - now
-                    if left is None or (
-                        left > 0 and self._estimate_serve_s(r) <= left
+                            headroom_frac = self.hbm_headroom_fn()
+                        except Exception:  # noqa: BLE001 — probe is best-effort
+                            headroom_frac = None
+                    bo.update(depth_frac=depth / limit, age_p95_ms=age_p95,
+                              hbm_headroom_frac=headroom_frac, now=now)
+                    if (
+                        bo.level == SHED_ONLY
+                        and lvl_before < SHED_ONLY
+                        and rec is not None
                     ):
-                        kept.append(r)
-                        continue
-                    waited_ms = (now - r.t_enq) * 1e3
-                    self.stats.record_shed("deadline", waited_ms=waited_ms)
-                    self.tenant_stats.record_shed(r.tenant)
-                    msg = (
-                        f"deadline infeasible (~{self._estimate_serve_s(r) * 1e3:.0f} ms "
-                        f"needed, {left * 1e3:.0f} ms left) "
-                        f"(shed_cause=deadline); skipped prefill; "
-                        if left > 0
-                        else f"deadline expired after {waited_ms:.0f} ms "
-                        f"queued (shed_cause=deadline); "
-                    )
-                    try:
-                        r.emit("err", BatcherOverloaded(
-                            msg + "retry on another worker"
-                        ))
-                    except Exception:  # noqa: BLE001 — dead client loop
-                        pass
-                waitlist[:] = kept
-                self._wl_len = len(waitlist)
-            # deadline sweep, active side: a slot past its deadline is
-            # cooperatively aborted through the consumer-gone cancel path
-            # (freed at the next burst readback, cause-tagged "deadline")
-            for r in self._slots:
-                if (
-                    isinstance(r, _Request)
-                    and r.deadline is not None
-                    and not r.cancelled
-                    and now > r.deadline
-                ):
-                    r.deadline_hit = True
-                    r.cancelled = True
-                    try:
-                        r.emit("err", BatcherOverloaded(
-                            f"deadline exceeded mid-decode after {r.generated} "
-                            f"tokens (shed_cause=deadline); retry on another "
-                            f"worker"
-                        ))
-                    except Exception:  # noqa: BLE001 — dead client loop
-                        pass
-            # deadline sweep, suspended side: a parked slot's clock keeps
-            # running — an expired one is failed right here with the same
-            # retryable deadline cause (it holds no pool blocks, so there
-            # is nothing to free), and a cancelled one is dropped
-            if self._suspended:
-                kept_s = []
-                for srec in self._suspended:
-                    r = srec.req
-                    if r.cancelled:
-                        self._ledger_finalize(
-                            r,
-                            "deadline_abort" if r.deadline_hit else "cancelled",
+                        # entering full shed is an incident, not a metric blip:
+                        # capture the ramp that led here (rate-limited)
+                        rec.dump(
+                            "shed_only_entry",
+                            extra={"depth": depth, "age_p95_ms": round(age_p95, 1),
+                                   "hbm_headroom_frac": headroom_frac,
+                                   "device_ms": self.stats.device_time_snapshot()["ms"]},
                         )
-                        self.stats.record_cancel("active")
-                        continue
-                    if r.deadline is not None and now > r.deadline:
-                        r.deadline_hit = True
+                    if bo.level == SHED_ONLY and lvl_before < SHED_ONLY:
+                        # swap-don't-shed on the incident edge: park the
+                        # youngest streams on the host tier so the survivors
+                        # keep full decode width; they resume once the level
+                        # drops back below SHED_ONLY (resume_suspended gates
+                        # on it)
+                        target = bo.suspend_target(self.max_slots)
+                        while suspend_on:
+                            live = [
+                                i for i, r in enumerate(self._slots)
+                                if isinstance(r, _Request)
+                            ]
+                            if len(live) <= target:
+                                break
+                            # lowest class first, youngest within a class — a
+                            # premium stream is the last to be parked
+                            victim = min(
+                                live,
+                                key=lambda i: (
+                                    self._slots[i].rank, -self._slots[i].t_admit
+                                ),
+                            )
+                            if not suspend_slot(victim, "brownout"):
+                                break
+                if tier is not None and paged:
+                    # proactive demotion: keep ~demote_free_frac of the pool
+                    # free by demoting cold cache chunks to the host tier
+                    # BETWEEN bursts, so admissions stop paying the reclaim at
+                    # the worst moment (and the tier fills before pressure
+                    # peaks). No-op once the cache holds nothing unpinned.
+                    floor_blocks = int(pool.n_blocks * tier.demote_free_frac)
+                    if pool.free_blocks < floor_blocks:
+                        pc.reclaim(floor_blocks - pool.free_blocks, demote=True)
+                # deadline sweep, queued side: waiters whose budget already ran
+                # out — or whose remaining budget the live rate EWMAs say cannot
+                # cover prefill plus the token floor — are shed BEFORE any
+                # prefill work, with a retryable envelope
+                if waitlist and any(r.deadline is not None for r in waitlist):
+                    kept = []
+                    for r in waitlist:
+                        left = None if r.deadline is None else r.deadline - now
+                        if left is None or (
+                            left > 0 and self._estimate_serve_s(r) <= left
+                        ):
+                            kept.append(r)
+                            continue
                         waited_ms = (now - r.t_enq) * 1e3
-                        self.stats.record_shed(
-                            "deadline", waited_ms=waited_ms
-                        )
-                        self._suspend_stats["suspended_deadline_expired"] += 1
-                        self._ledger_finalize(r, "deadline_abort")
+                        self.stats.record_shed("deadline", waited_ms=waited_ms)
                         self.tenant_stats.record_shed(r.tenant)
+                        msg = (
+                            f"deadline infeasible (~{self._estimate_serve_s(r) * 1e3:.0f} ms "
+                            f"needed, {left * 1e3:.0f} ms left) "
+                            f"(shed_cause=deadline); skipped prefill; "
+                            if left > 0
+                            else f"deadline expired after {waited_ms:.0f} ms "
+                            f"queued (shed_cause=deadline); "
+                        )
                         try:
                             r.emit("err", BatcherOverloaded(
-                                f"deadline exceeded while suspended after "
-                                f"{r.generated} tokens (shed_cause=deadline); "
-                                f"retry on another worker"
+                                msg + "retry on another worker"
                             ))
                         except Exception:  # noqa: BLE001 — dead client loop
                             pass
-                        continue
-                    kept_s.append(srec)
-                self._suspended = kept_s
-            # resume parked slots BEFORE admitting new waiters: they are
-            # strictly older work and already hold their first tokens
-            resume_suspended()
-            # weighted fair-share admission: reorder the waitlist by
-            # deficit round-robin over tenants (FIFO within a tenant,
-            # prompt tokens as cost, class/key weight as share). A single
-            # tenant degenerates to exact FIFO, so every pre-QoS workload
-            # admits in the same order it always did.
-            if len(waitlist) > 1:
-                waitlist[:] = self._drr.order(
-                    waitlist,
-                    tenant_of=lambda r: r.tenant,
-                    cost_of=lambda r: len(r.prompt_ids),
-                    weight_of=lambda r: r.drr_weight,
-                )
-                # the premium depth grace in _enqueue can leave the queue
-                # over its bound; settle it here by displacing the excess
-                # from the BACK of the DRR order, lowest class first — the
-                # requests weighted fair share says would wait the longest
-                # anyway go retry on a less loaded worker
-                limit = (
-                    bo.effective_queue_limit(self.max_queue)
-                    if bo is not None else self.max_queue
-                )
-                if limit and len(waitlist) > limit:
-                    order = {id(r): i for i, r in enumerate(waitlist)}
-                    excess = len(waitlist) - limit
-                    victims = sorted(
-                        waitlist, key=lambda r: (r.rank, -order[id(r)])
-                    )[:excess]
-                    vset = {id(r) for r in victims}
-                    waitlist[:] = [r for r in waitlist if id(r) not in vset]
-                    for r in victims:
-                        waited_ms = (now - r.t_enq) * 1e3
-                        self.stats.record_shed(
-                            "fair_share", waited_ms=waited_ms
-                        )
-                        self.tenant_stats.record_shed(r.tenant)
+                    waitlist[:] = kept
+                    self._wl_len = len(waitlist)
+                # deadline sweep, active side: a slot past its deadline is
+                # cooperatively aborted through the consumer-gone cancel path
+                # (freed at the next burst readback, cause-tagged "deadline")
+                for r in self._slots:
+                    if (
+                        isinstance(r, _Request)
+                        and r.deadline is not None
+                        and not r.cancelled
+                        and now > r.deadline
+                    ):
+                        r.deadline_hit = True
+                        r.cancelled = True
                         try:
                             r.emit("err", BatcherOverloaded(
-                                "displaced by weighted fair share "
-                                "(shed_cause=fair_share); retry on another "
-                                "worker"
+                                f"deadline exceeded mid-decode after {r.generated} "
+                                f"tokens (shed_cause=deadline); retry on another "
+                                f"worker"
                             ))
-                        except Exception:  # noqa: BLE001 — dead client
+                        except Exception:  # noqa: BLE001 — dead client loop
                             pass
-            self._wl_len = len(waitlist)
+                # deadline sweep, suspended side: a parked slot's clock keeps
+                # running — an expired one is failed right here with the same
+                # retryable deadline cause (it holds no pool blocks, so there
+                # is nothing to free), and a cancelled one is dropped
+                if self._suspended:
+                    kept_s = []
+                    for srec in self._suspended:
+                        r = srec.req
+                        if r.cancelled:
+                            self._ledger_finalize(
+                                r,
+                                "deadline_abort" if r.deadline_hit else "cancelled",
+                            )
+                            self.stats.record_cancel("active")
+                            continue
+                        if r.deadline is not None and now > r.deadline:
+                            r.deadline_hit = True
+                            waited_ms = (now - r.t_enq) * 1e3
+                            self.stats.record_shed(
+                                "deadline", waited_ms=waited_ms
+                            )
+                            self._suspend_stats["suspended_deadline_expired"] += 1
+                            self._ledger_finalize(r, "deadline_abort")
+                            self.tenant_stats.record_shed(r.tenant)
+                            try:
+                                r.emit("err", BatcherOverloaded(
+                                    f"deadline exceeded while suspended after "
+                                    f"{r.generated} tokens (shed_cause=deadline); "
+                                    f"retry on another worker"
+                                ))
+                            except Exception:  # noqa: BLE001 — dead client loop
+                                pass
+                            continue
+                        kept_s.append(srec)
+                    self._suspended = kept_s
+                # resume parked slots BEFORE admitting new waiters: they are
+                # strictly older work and already hold their first tokens
+                resume_suspended()
+                # weighted fair-share admission: reorder the waitlist by
+                # deficit round-robin over tenants (FIFO within a tenant,
+                # prompt tokens as cost, class/key weight as share). A single
+                # tenant degenerates to exact FIFO, so every pre-QoS workload
+                # admits in the same order it always did.
+                if len(waitlist) > 1:
+                    waitlist[:] = self._drr.order(
+                        waitlist,
+                        tenant_of=lambda r: r.tenant,
+                        cost_of=lambda r: len(r.prompt_ids),
+                        weight_of=lambda r: r.drr_weight,
+                    )
+                    # the premium depth grace in _enqueue can leave the queue
+                    # over its bound; settle it here by displacing the excess
+                    # from the BACK of the DRR order, lowest class first — the
+                    # requests weighted fair share says would wait the longest
+                    # anyway go retry on a less loaded worker
+                    limit = (
+                        bo.effective_queue_limit(self.max_queue)
+                        if bo is not None else self.max_queue
+                    )
+                    if limit and len(waitlist) > limit:
+                        order = {id(r): i for i, r in enumerate(waitlist)}
+                        excess = len(waitlist) - limit
+                        victims = sorted(
+                            waitlist, key=lambda r: (r.rank, -order[id(r)])
+                        )[:excess]
+                        vset = {id(r) for r in victims}
+                        waitlist[:] = [r for r in waitlist if id(r) not in vset]
+                        for r in victims:
+                            waited_ms = (now - r.t_enq) * 1e3
+                            self.stats.record_shed(
+                                "fair_share", waited_ms=waited_ms
+                            )
+                            self.tenant_stats.record_shed(r.tenant)
+                            try:
+                                r.emit("err", BatcherOverloaded(
+                                    "displaced by weighted fair share "
+                                    "(shed_cause=fair_share); retry on another "
+                                    "worker"
+                                ))
+                            except Exception:  # noqa: BLE001 — dead client
+                                pass
+                self._wl_len = len(waitlist)
             # admit waiters: bursts of short same-bucket prompts go through
             # one batched dispatch; runs of LONG prompts go through one
             # batched CHUNKED dispatch; odd ones admit individually
@@ -4952,7 +4987,10 @@ class ContinuousBatcher:
                     self._wl_len = len(waitlist)
                     if len(group) > 1:
                         try:
-                            admit_group_chunked(group)
+                            with obs_spans.span(
+                                    "batcher.admit", path="chunked", width=len(group),
+                                    tokens=max(len(r.prompt_ids) for r in group)):
+                                admit_group_chunked(group)
                         except _PoolExhausted as e:
                             # raised pre-dispatch: the device pool is intact,
                             # shed the group without the cache reset
@@ -4976,7 +5014,9 @@ class ContinuousBatcher:
                 self._wl_len = len(waitlist)  # popped-into-group != queued
                 if len(group) > 1:  # here only via the short same-bucket path
                     try:
-                        handled = admit_group(group, head_bucket)
+                        with obs_spans.span("batcher.admit", path="group",
+                                            width=len(group), bucket=head_bucket):
+                            handled = admit_group(group, head_bucket)
                     except Exception as e:  # noqa: BLE001 — surface to callers
                         for req in group:
                             self._ledger_finalize(req, "failed")
@@ -4989,7 +5029,9 @@ class ContinuousBatcher:
                     # cannot fit the whole group): admit one by one
                 for req in group:
                     try:
-                        admit_one(req)
+                        with obs_spans.span("batcher.admit", path="one", width=1,
+                                            tokens=len(req.prompt_ids)):
+                            admit_one(req)
                     except _PoolExhausted as e:
                         # pre-dispatch shed: pool state is intact, the other
                         # streams keep decoding; no cache reset — but a long
@@ -5118,6 +5160,9 @@ class ContinuousBatcher:
             req.emit("tok", (tok_id, logprob, top_ids, top_lps))
         else:
             req.emit("tok", tok_id)
+        if req.trace is not None:
+            # request mark for the reply path's worker.publish lag
+            req.trace.emitted = (time.perf_counter(), req.generated)
         req.emitted.append(int(tok_id))
         if req.generated >= req.sp.max_tokens or req.pos + 1 >= self.max_seq:
             if req.trace is not None:

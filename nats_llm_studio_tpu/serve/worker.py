@@ -33,11 +33,13 @@ import os
 import time
 
 from ..config import WorkerConfig
+from ..obs import spans as obs_spans
 from ..obs import (
     EVENTS,
     PromRenderer,
     Trace,
     Span,
+    build_ledger,
     compile_cache_counts,
     efficiency_enabled,
     install_compile_cache_listener,
@@ -971,6 +973,7 @@ class Worker:
             return
         final: dict | None = None
         seq = 0
+        sent_tokens = 0  # of trace.emitted, already published
         model_id = payload.get("model", "")
         # consumer-gone watcher: request_stream publishes an empty message
         # to <inbox>.cancel when its consumer abandons the stream before the
@@ -1011,11 +1014,19 @@ class Worker:
                 if chunk.get("object") == "chat.completion":
                     final = chunk  # engines yield the aggregate last
                     continue
-                await self.nc.publish(
-                    msg.reply,
-                    json.dumps({"ok": True, "data": {"chunk": chunk}}, separators=(",", ":")).encode(),
-                    headers={"X-Seq": str(seq)},
-                )
+                with obs_spans.span("worker.publish") as sp:
+                    await self.nc.publish(
+                        msg.reply,
+                        json.dumps({"ok": True, "data": {"chunk": chunk}}, separators=(",", ":")).encode(),
+                        headers={"X-Seq": str(seq)},
+                    )
+                    emitted = trace.emitted
+                    if emitted is not None:
+                        # owner thread's emit of the chunk's last token ->
+                        # publish returned, across the two threads
+                        sp.attrs["tokens"] = emitted[1] - sent_tokens
+                        sp.attrs["lag_ms"] = (time.perf_counter() - emitted[0]) * 1e3
+                        sent_tokens = emitted[1]
                 seq += 1
         finally:
             if cancel_task is not None:
@@ -1757,6 +1768,12 @@ class Worker:
                   help="XLA persistent compile-cache hits in this process")
         r.counter("lmstudio_compile_cache_misses_total", cc["misses"],
                   help="XLA persistent compile-cache misses in this process")
+        # the build ledger: where start-up went, by JAX's own duration events
+        for kind, secs in build_ledger(top=0)["seconds"].items():
+            r.counter("lmstudio_program_build_seconds_total", round(secs, 6),
+                      labels={"kind": kind},
+                      help="seconds this process spent building programs "
+                           "(trace, lower, compile; cache_load lies inside compile)")
         # fault-tolerance families — ALWAYS present (zero-valued when
         # nothing has failed) so dashboards and the chaos tests can assert
         # their existence, not just their increments

@@ -65,8 +65,9 @@ def test_moe_bench_smoke():
 
 def test_obs_overhead_bench_smoke():
     """The flight-recorder overhead phase must run at tiny scale: both arms
-    measured, the recorder-on arm actually sampled frames, and the noise-
-    floor-guarded overhead bound held (the phase asserts it internally)."""
+    measured, the recorder-on arm actually sampled frames, and both arms
+    served every token. The overhead itself is reported, not bounded: a CPU
+    timing under xdist says nothing, the chip's reading is in PERF.md."""
     import bench
     from nats_llm_studio_tpu.models.config import ModelConfig
     from nats_llm_studio_tpu.models.llama import ensure_lm_head, init_params
@@ -79,7 +80,8 @@ def test_obs_overhead_bench_smoke():
     assert out["frames_sampled"] > 0
     assert len(out["off_tok_s"]) == 2 and len(out["on_tok_s"]) == 2
     assert out["off_median_tok_s"] > 0 and out["on_median_tok_s"] > 0
-    assert out["overhead_pct"] < max(1.0, out["noise_floor_pct"])
+    assert out["off_tokens_served"] == out["on_tokens_served"] == 3 * 2 * 12
+    assert isinstance(out["overhead_pct"], float) and out["noise_floor_pct"] >= 0.0
 
 
 def test_e2e_long_context_bench_smoke(monkeypatch):
