@@ -2063,8 +2063,8 @@ def decode_kernel_bench(cfg, params, *, batches=None, seq=None,
       DECODE_KERNEL value serves the same closed greedy wave — decode
       step_ms p50/p95 from the batcher histograms, served tok/s, and the
       engine's first-seen decode-program count (stats.decode_recompiles:
-      the Pallas grid spans the whole table width, so it must register no
-      more program keys than the XLA window ladder). Greedy tokens must
+      the Pallas kernel walks the whole table in one program, so it must
+      register no more program keys than the XLA window ladder). Greedy tokens must
       MATCH between the kernels — the bit-equivalence the unit tests prove
       per-program, re-proven here at wave scale. Off-TPU the forced Pallas
       path runs in interpreter mode — correct but slow — so the CPU smoke

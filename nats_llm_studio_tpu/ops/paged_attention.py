@@ -1,11 +1,14 @@
 """Paged-attention decode as a Pallas TPU kernel.
 
 vLLM-style PagedAttention for the decode path (SURVEY.md §7 hard part #2,
-ROADMAP item 1): one grid cell per (slot, kv-head, pool-block), reading each
-slot's block table directly from scalar-prefetch SMEM — the kernel walks
-``[NB, L, Hkv, T, D]`` pool storage block-by-block in VMEM, dequantizes int8
-KVQ codes per tile, and runs online softmax across blocks. This removes the
-two costs of the XLA fallback in serve/batcher.py:
+ROADMAP item 1): one grid cell per SLOT, every kv head of the pool inside
+it, reading the slot's block table from scalar-prefetch SMEM. The cell walks
+the slot's live table entries in runs of k consecutive entries: the pools
+``[NB, L, Hkv, T, D]`` stay in HBM, one block id and layer give a contiguous
+``[Hkv, T, D]`` slab, and the kernel copies a run's k slabs into a
+double-buffered VMEM landing area itself, dequantizes int8 KVQ codes per
+run, and folds one ``[rows, D] x [D, k*T]`` product per head into an online
+softmax. This removes the two costs of the XLA fallback in serve/batcher.py:
 
 - ``kv_pool_gather_view`` materializes every slot's live window as a dense
   [B, L, Hkv, W, D] copy per decode step (HBM round-trip proportional to
@@ -13,17 +16,21 @@ two costs of the XLA fallback in serve/batcher.py:
 - the pow2 window ladder re-jits ``decode_pos_paged`` per (bucket, window)
   pair as contexts grow.
 
-Here the grid's block axis spans the WHOLE table width (static = max_seq/T),
-so one compiled program serves every context length: blocks past a slot's
-live window skip compute (``pl.when``) and their DMA is elided because the
-index map revisits the last live block (the same trick as the causal
-revisit-skip in ops/flash_attention.py).
+The table width (static = max_seq/T) is only the bound of the walk: its trip
+count is the slot's live runs, a scalar computed from ``pos``, so one
+compiled program serves every context length and the kernel's time follows
+the KV that is live — a slot with no request costs one run of the null
+block, not a table's worth of grid steps. (The grid this replaced was one
+cell per (slot, kv-head, pool-block): 8,192 cells a layer at 8 slots x 8
+heads x 128 entries, ~175 ns each whatever the context; PERF.md, PR 26.)
 
 Queries arrive as the slot's GQA group x query-width bundle: decode is
 W == 1, speculative verify passes the draft bundle W == k+1 — one kernel,
 one compiled program per width. Off-TPU the kernel runs in interpreter mode
-(bit-level tests on the CPU backend); ``paged_decode_eligible`` gates the
-auto-downshift to the XLA path for shapes Mosaic cannot tile.
+(bit-level tests on the CPU backend; the interpreter performs a copy where
+it is started, so only a chip run orders the double buffer);
+``paged_decode_eligible`` gates the auto-downshift to the XLA path for
+shapes Mosaic cannot tile.
 """
 
 from __future__ import annotations
@@ -47,81 +54,155 @@ def paged_decode_eligible(
     a real TPU. The block-token extent T is the sublane dim of every K/V
     tile (f32 8 rows, bf16 16), the head_dim D is the lane dim (128
     multiple), and under tensor parallelism each shard must own whole KV
-    heads. int8 KVQ codes pack 32 rows to a native tile, but every K/V
-    block spans the pool's WHOLE [T, D] minor plane, which Mosaic accepts
+    heads. int8 KVQ codes pack 32 rows to a native tile, but every copied
+    K/V slab spans the pool's WHOLE [T, D] minor plane, which Mosaic accepts
     below the native tile: codes at the default KV_BLOCK_TOKENS=16 compile
-    for a v5e (tests/test_tpu_compile.py) and agree with the XLA path on
-    the chip (PERF.md, PR 21), so TPU_KV_QUANT=int8 keeps the kernel at
-    the default block size. Anything else downshifts to the XLA path."""
+    for a v5e (tests/test_tpu_compile.py), so TPU_KV_QUANT=int8 keeps the
+    kernel at the default block size. Anything else downshifts to the XLA
+    path."""
     sub = 8 if itemsize >= 4 and not quantized else 16
     return t % sub == 0 and d % 128 == 0 and hkv % tp == 0
 
 
+# The keys one step of a slot's walk attends to: a run of k = _RUN_TOKENS // T
+# consecutive table entries, two passes of the 128-wide MXU per kv head.
+# Measured on a v5e at the benchmark's shapes (PERF.md, PR 26): 256 reads a
+# full 2048-token table at 87 % of HBM bandwidth where 128 reads it at 71 %
+# (a run's copies cost a fixed issue time), 512 is no better and re-reads more
+# of the last block, and at ~400 tokens a slot all three are within 5 %.
+_RUN_TOKENS = 256
+# What the landing buffers of one run (K and V, two halves each) may take of
+# VMEM: a quarter of a v5e core's 16 MiB scoped default.
+_RUN_VMEM_BYTES = 4 << 20
+
+
+def _run_blocks(t: int, nb: int, hkv: int, d: int, itemsize: int) -> int:
+    """k: how many table entries one run covers. The largest divisor of the
+    table width ``nb`` whose keys fit ``_RUN_TOKENS`` and whose landing
+    buffers ([Hkv, T, D] per entry, K and V, two halves) fit
+    ``_RUN_VMEM_BYTES`` — all from shapes, so a model with more kv heads or
+    a wider block gets a shorter run, never a knob."""
+    per_entry = 2 * 2 * hkv * t * d * itemsize
+    cap = max(1, min(_RUN_TOKENS // t, _RUN_VMEM_BYTES // per_entry, nb))
+    return next(k for k in range(cap, 0, -1) if nb % k == 0)
+
+
 def _paged_kernel(
     tbl_ref, pos_ref, layer_ref, q_ref, *refs,
-    scale: float, t: int, group: int, w: int, quantized: bool
+    scale: float, t: int, k: int, nb: int, group: int, w: int, quantized: bool
 ):
-    """One grid step = one (slot, kv-head, POOL-BLOCK). Scratch carries the
-    online-softmax state across the block axis; q rows are the slot's GQA
-    bundle (row r = query-offset r//group within the W-wide bundle, q-head
-    r%group within the group), so the causal frontier is per-row:
-    ``key_pos <= pos + r//group``. Rows written this step (write-then-
-    attend in models/llama.py) are already in the pool, so the frontier
-    includes them. Dead blocks (j past the slot's last live block) skip
-    compute; their index maps revisit the last live block so the DMA is
-    elided. Slots whose table is unallocated read the null block (id 0) and
-    produce finite junk the caller discards — the same contract as the XLA
-    gather-view path."""
-    if quantized:
-        kq_ref, ks_ref, vq_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    b, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    pos = pos_ref[b]
-    last = jnp.minimum(jnp.maximum(pos + w - 1, 0) // t, pl.num_programs(2) - 1)
+    """One grid step = one SLOT, every kv head of the (local) pool inside it;
+    the step walks the slot's live table entries in RUNS of k and stops at
+    the last live one, so a slot costs what its context holds, not the table
+    width. The K and V pools (the int8 codes of a KVQ pool) stay in HBM
+    (``refs`` = the two pools, a KVQ pool's two scale views, the output, a
+    VMEM landing buffer per pool, the softmax scratch, DMA semaphores, a
+    parity word): a run is k async copies per pool of the [Hkv, T, D] slab
+    that one block id and layer give into one half of a double buffer,
+    started while the run before it is attended to. The first run of the
+    NEXT slot is started behind this slot's last one, so only slot 0 waits
+    for a cold copy; the parity word carries which half that run landed in
+    across grid steps (the grid runs in order). An entry past the last live
+    block re-reads that block and is masked by key_pos, so every tile of a
+    live run holds real rows. A KVQ pool's f32 scales arrive as the slot's
+    rows already gathered by the caller, one [Hkv, k*T] tile per run
+    (``paged_decode_attention`` says why), and the codes are dequantised
+    per run, in VMEM.
+
+    q rows are the slot's GQA bundle per kv head (row r = query-offset
+    r//group within the W-wide bundle, q-head r%group within the group), so
+    the causal frontier is per-row: ``key_pos <= pos + r//group``. Rows
+    written this step (write-then-attend in models/llama.py) are already in
+    the pool, so the frontier includes them. The k tiles are joined into one
+    [Hkv, k*T, D] operand, so a head's scores are ONE [rows, D] x [D, k*T]
+    product, folded into the online-softmax scratch. Slots whose table is
+    unallocated read the null block (id 0) and produce finite junk the
+    caller discards — the same contract as the XLA gather-view path."""
+    hbm, refs = refs[:2], refs[2:]
+    scales, refs = (refs[:2], refs[2:]) if quantized else ((None, None), refs)
+    o_ref, *bufs, acc_ref, m_ref, l_ref, sem, parity = refs
+    b, slots = pl.program_id(0), pl.num_programs(0)
+    layer = layer_ref[0]
     rows = q_ref.shape[-2]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def last_block(bi):
+        return jnp.minimum(jnp.maximum(pos_ref[bi] + w - 1, 0) // t, nb - 1)
 
-    @pl.when(j <= last)
-    def _compute():
-        q = q_ref[0, 0]  # [rows, D]
+    def run_copies(bi, run, half, last):
+        """The async copies of run ``run`` of slot ``bi`` into ``half``;
+        ``last`` None builds them for a wait, which needs the shapes and the
+        semaphore only."""
+        out = []
+        for i in range(k):
+            blk = 0 if last is None else tbl_ref[bi, jnp.minimum(run * k + i, last)]
+            for src, dst in zip(hbm, bufs):
+                out.append(pltpu.make_async_copy(
+                    src.at[blk, layer], dst.at[half, i], sem.at[half]))
+        return out
+
+    def start(bi, run, half):
+        for c in run_copies(bi, run, half, last_block(bi)):
+            c.start()
+
+    def run_tiles(buf, srows, half, r, dtype):
+        # dequant in f32, cast after: Mosaic's minor-dim [T] -> [T, 1]
+        # insertion only lowers for 32-bit vectors (ops/flash_attention.py)
+        mid = jnp.float32 if quantized else dtype
+        tiles = [buf[half, i].astype(mid) for i in range(k)]  # k x [Hkv, T, D]
+        x = tiles[0] if k == 1 else jnp.concatenate(tiles, axis=1)
         if quantized:
-            # dequant in f32, cast after: Mosaic's minor-dim [T] -> [T, 1]
-            # insertion only lowers for 32-bit vectors (ops/flash_attention.py)
-            k = (kq_ref[0, 0, 0].astype(jnp.float32)
-                 * ks_ref[0, 0, h].astype(jnp.float32)[:, None]).astype(q.dtype)
-            v = (vq_ref[0, 0, 0].astype(jnp.float32)
-                 * vs_ref[0, 0, h].astype(jnp.float32)[:, None]).astype(q.dtype)
-        else:
-            k = k_ref[0, 0, 0].astype(q.dtype)  # [T, D]
-            v = v_ref[0, 0, 0].astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [rows, T] f32
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, t), 0)
-        key_pos = j * t + jax.lax.broadcasted_iota(jnp.int32, (rows, t), 1)
-        s = jnp.where(key_pos <= pos + row // group, s, _NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+            x = x * srows[0, r].astype(jnp.float32)[:, :, None]
+        return x.astype(dtype)  # [Hkv, k*T, D]
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+    @pl.when(b == 0)
+    def _first():
+        parity[0] = 0
+        start(b, 0, 0)
+
+    pos = pos_ref[b]
+    runs = last_block(b) // k + 1
+    first = parity[0]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def attend(r, carry):
+        half = (first + r) % 2
+        more = r + 1 < runs
+
+        @pl.when(jnp.logical_or(more, b + 1 < slots))
+        def _prefetch():
+            start(jnp.where(more, b, jnp.minimum(b + 1, slots - 1)),
+                  jnp.where(more, r + 1, 0), 1 - half)
+
+        for c in run_copies(b, r, half, None):
+            c.wait()
+        q = q_ref[0]  # [Hkv, rows, D]
+        kk = run_tiles(bufs[0], scales[0], half, r, q.dtype)
+        vv = run_tiles(bufs[1], scales[1], half, r, q.dtype)
+        s = jax.lax.dot_general(
+            q, kk, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        ) * scale  # [Hkv, rows, k*T] f32
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, k * t), 0)
+        key_pos = r * (k * t) + jax.lax.broadcasted_iota(jnp.int32, (rows, k * t), 1)
+        s = jnp.where((key_pos <= pos + row // group)[None], s, _NEG_INF)
+        m_prev = m_ref[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, :, :1] * corr + jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(vv.dtype), vv,
+            (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        return carry
+
+    jax.lax.fori_loop(0, runs, attend, 0)
+    parity[0] = (first + runs) % 2
+    out = acc_ref[...] / jnp.maximum(l_ref[:, :, :1], 1e-30)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -135,21 +216,33 @@ def paged_decode_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Attention for W new tokens per slot against the slot's ENTIRE paged
-    history, read block-by-block straight from the pool. Returns
-    [B, W, Hq, D] in q.dtype. The caller must have scattered the W new K/V
-    rows into the pool first (write-then-attend); the kernel's causal mask
-    then covers them exactly.
+    history, read run-by-run straight from the pool. Returns [B, W, Hq, D]
+    in q.dtype. The caller must have scattered the W new K/V rows into the
+    pool first (write-then-attend); the kernel's causal mask then covers
+    them exactly.
 
-    The grid block axis is ``tbl.shape[1]`` — STATIC, so the compiled
-    program is shared by every context length (dead blocks cost one elided
-    grid step each, not a recompile). Per-block work is [rows, T] x [T, D];
-    rows = GQA group x W (padded to the sublane multiple)."""
+    The grid is (slots,) and a slot's walk over its table is a loop inside
+    the cell whose trip count is the slot's live runs — a scalar read from
+    ``pos``, not a shape — so ONE compiled program serves every context
+    length and its time follows the KV that is live. A slot's blocks are
+    scattered in the pool, so the pools are left in HBM and the kernel
+    copies each run's k [Hkv, T, D] slabs itself (``_paged_kernel``). k
+    comes from the shapes (``_run_blocks``). Per-run work per head is
+    [rows, k*T] x [k*T, D]; rows = GQA group x W (padded to the sublane
+    multiple).
+
+    A KVQ pool's scale rows are [Hkv, T] f32 per block and layer, which an
+    in-kernel copy cannot address (Mosaic wants the minor dim of a copied
+    window on the 128-lane tiling, and T is 16 or 32), so they are gathered
+    here, by table, into [B, NB/k, Hkv, k*T] — 1/D of the codes' bytes over
+    the whole table — and reach the kernel as one block per slot."""
     b, w, hq, d = q.shape
     quantized = is_quantized(k_pool)
     kq = k_pool.q if quantized else k_pool
     hkv, t = kq.shape[2], kq.shape[3]
     group = hq // hkv
     nb = tbl.shape[1]
+    k = _run_blocks(t, nb, hkv, d, kq.dtype.itemsize)
     rows = group * w
     mult = 8 if q.dtype.itemsize >= 4 else 16
     rows_p = -(-rows // mult) * mult
@@ -161,56 +254,43 @@ def paged_decode_attention(
     if rows_p != rows:
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
 
-    def q_map(bi, hi, ji, tbl_ref, pos_ref, layer_ref):
-        return (bi, hi, 0, 0)
+    def q_map(bi, tbl_ref, pos_ref, layer_ref):
+        return (bi, 0, 0, 0)
 
-    def kv_map(bi, hi, ji, tbl_ref, pos_ref, layer_ref):
-        # dead-block revisit-skip: blocks past the slot's live frontier
-        # remap to the last live block, eliding their DMA
-        last = jnp.minimum(jnp.maximum(pos_ref[bi] + w - 1, 0) // t, nb - 1)
-        return (tbl_ref[bi, jnp.minimum(ji, last)], layer_ref[0], hi, 0, 0)
+    def run_scales(s):  # [NBp, L, Hkv, T] -> the slots' rows, a tile per run
+        rows_of = s[tbl, layer].reshape(b, nb // k, k, hkv, t)
+        return rows_of.transpose(0, 1, 3, 2, 4).reshape(b, nb // k, hkv, k * t)
 
-    def s_map(bi, hi, ji, tbl_ref, pos_ref, layer_ref):
-        # scale tiles block the whole head axis (a (.., 1, T) block violates
-        # Mosaic's sublane rule); the cell's own head is picked in-kernel
-        last = jnp.minimum(jnp.maximum(pos_ref[bi] + w - 1, 0) // t, nb - 1)
-        return (tbl_ref[bi, jnp.minimum(ji, last)], layer_ref[0], 0, 0)
-
-    if quantized:
-        in_specs = [
-            pl.BlockSpec((1, 1, rows_p, d), q_map),
-            pl.BlockSpec((1, 1, 1, t, d), kv_map),
-            pl.BlockSpec((1, 1, hkv, t), s_map),
-            pl.BlockSpec((1, 1, 1, t, d), kv_map),
-            pl.BlockSpec((1, 1, hkv, t), s_map),
-        ]
-        operands = (kq, k_pool.s, v_pool.q, v_pool.s)
-    else:
-        in_specs = [
-            pl.BlockSpec((1, 1, rows_p, d), q_map),
-            pl.BlockSpec((1, 1, 1, t, d), kv_map),
-            pl.BlockSpec((1, 1, 1, t, d), kv_map),
-        ]
-        operands = (k_pool, v_pool)
+    pools = (kq, v_pool.q) if quantized else (k_pool, v_pool)
+    scales = (run_scales(k_pool.s), run_scales(v_pool.s)) if quantized else ()
+    # a landing buffer per pool: two halves of k [Hkv, T, D] tiles
+    landing = [pltpu.VMEM((2, k, hkv, t, d), p.dtype) for p in pools]
 
     kernel = functools.partial(
-        _paged_kernel, scale=scale, t=t, group=group, w=w, quantized=quantized
+        _paged_kernel, scale=scale, t=t, k=k, nb=nb, group=group, w=w,
+        quantized=quantized,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows_p, d), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((rows_p, d), jnp.float32),
-            pltpu.VMEM((rows_p, 128), jnp.float32),
-            pltpu.VMEM((rows_p, 128), jnp.float32),
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, hkv, rows_p, d), q_map)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        + [pl.BlockSpec((1, nb // k, hkv, k * t), q_map)] * len(scales),
+        out_specs=pl.BlockSpec((1, hkv, rows_p, d), q_map),
+        scratch_shapes=landing + [
+            pltpu.VMEM((hkv, rows_p, d), jnp.float32),
+            pltpu.VMEM((hkv, rows_p, 128), jnp.float32),
+            pltpu.VMEM((hkv, rows_p, 128), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows_p, d), q.dtype),
+        # slot b+1's first run is started by slot b: the grid runs in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         # a constant: it is the custom call's name in a device trace and part
         # of the program's bytes, so of its compile-cache key
@@ -219,7 +299,7 @@ def paged_decode_attention(
         tbl.astype(jnp.int32),
         jnp.asarray(pos, jnp.int32).reshape(b),
         jnp.asarray(layer, jnp.int32).reshape(1),
-        qh, *operands,
+        qh, *pools, *scales,
     )
     out = out[:, :, :rows].reshape(b, hkv, w, group, d)
     return out.transpose(0, 2, 1, 3, 4).reshape(b, w, hq, d)

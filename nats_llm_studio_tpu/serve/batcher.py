@@ -1470,10 +1470,11 @@ class ContinuousBatcher:
 
             # -- Pallas paged-decode twins (ops/paged_attention.py) --------
             # Same signatures and return contracts as the *_paged programs
-            # minus the ``nb`` static arg: the kernel's grid spans the WHOLE
-            # table, so one compile per burst width serves every context
-            # length — no gather-view materialization, no scatter-back, no
-            # pow2-ladder recompiles. Write-then-attend happens per layer
+            # minus the ``nb`` static arg: the kernel walks a slot's table up
+            # to its last live block inside one program, so one compile per
+            # burst width serves every context length — no gather-view
+            # materialization, no scatter-back, no pow2-ladder recompiles.
+            # Write-then-attend happens per layer
             # inside forward_decode_paged (the pool is the only KV storage
             # these programs touch).
             fwd_paged = partial(forward_decode_paged, cfg=cfg, mesh=mesh)

@@ -41,7 +41,12 @@ from nats_llm_studio_tpu.models.llama import (
     init_params,
     make_cache,
 )
-from nats_llm_studio_tpu.ops.paged_attention import paged_decode_eligible
+from nats_llm_studio_tpu.ops.kvcache import quantize_rows
+from nats_llm_studio_tpu.ops.paged_attention import (
+    _run_blocks,
+    paged_decode_attention,
+    paged_decode_eligible,
+)
 from nats_llm_studio_tpu.ops.wquant import (
     QTensor4,
     effective_group,
@@ -184,6 +189,113 @@ async def test_tp_overlap_greedy_matches(model):
         got = await _greedy_batch(sharded, cfg, PROMPTS[:3], 6, "pallas",
                                   mesh=mesh)
     assert got == want
+
+
+# -- the kernel's grid against a plain softmax --------------------------------
+
+
+def _plain_paged_attention(q, k_pool, v_pool, tbl, pos, layer, scale):
+    """Plain jax.numpy softmax over each slot's whole table in f32: gather the
+    slot's blocks, mask keys past ``pos + query offset``, one softmax."""
+    b, w, hq, d = q.shape
+    hkv, t = k_pool.shape[2], k_pool.shape[3]
+    nb = tbl.shape[1]
+    f32 = jnp.float32
+
+    def keys(pool):  # [B, NB, Hkv, T, D] -> [B, Hq, NB*T, D]
+        x = pool[tbl, layer].astype(f32).transpose(0, 2, 1, 3, 4)
+        return jnp.repeat(x.reshape(b, hkv, nb * t, d), hq // hkv, axis=1)
+
+    s = jnp.einsum("bwhd,bhsd->bwhs", q.astype(f32), keys(k_pool)) * scale
+    live = (jnp.arange(nb * t)[None, None, :]
+            <= (pos[:, None] + jnp.arange(w)[None, :])[:, :, None])
+    p = jax.nn.softmax(jnp.where(live[:, :, None, :], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bwhs,bhsd->bwhd", p, keys(v_pool))
+
+
+GRID_CASES = {
+    # name: (dtype, Hkv, group, T, table width, W, KVQ)
+    "f32-hkv8": (jnp.float32, 8, 4, 16, 32, 1, False),
+    "bf16-hkv8": (jnp.bfloat16, 8, 4, 16, 32, 1, False),
+    "f32-hkv2-tp-shard": (jnp.float32, 2, 4, 16, 32, 1, False),
+    "bf16-hkv2-tp-shard": (jnp.bfloat16, 2, 4, 16, 32, 1, False),
+    "f32-hkv16": (jnp.float32, 16, 2, 16, 32, 1, False),
+    "bf16-hkv16": (jnp.bfloat16, 16, 2, 16, 32, 1, False),
+    "f32-spec-w7": (jnp.float32, 8, 4, 16, 32, 7, False),
+    "bf16-spec-w7": (jnp.bfloat16, 8, 4, 16, 32, 7, False),
+    "f32-spec-w5-hkv2": (jnp.float32, 2, 4, 16, 48, 5, False),
+    "f32-kvq-t16": (jnp.float32, 8, 4, 16, 32, 1, True),
+    "bf16-kvq-t16": (jnp.bfloat16, 8, 4, 16, 32, 1, True),
+    "f32-kvq-t32": (jnp.float32, 8, 4, 32, 16, 1, True),
+    "bf16-kvq-t32-spec-w7": (jnp.bfloat16, 8, 4, 32, 16, 7, True),
+    # widths the 256-key run does not divide: k falls to a divisor
+    "f32-width12-k-divides-down": (jnp.float32, 8, 4, 16, 12, 1, False),
+    "bf16-width20-k-divides-down": (jnp.bfloat16, 2, 4, 16, 20, 7, False),
+    "f32-kvq-width24-k-divides-down": (jnp.float32, 8, 4, 16, 24, 1, True),
+    "f32-width7-prime-one-run": (jnp.float32, 8, 4, 16, 7, 1, False),
+    "f32-width17-prime-k1": (jnp.float32, 8, 4, 16, 17, 1, False),
+    "f32-t8-k32": (jnp.float32, 8, 4, 8, 64, 1, False),
+}
+
+
+def test_run_blocks_come_from_shapes():
+    # 256 keys a run: 16 entries of 16 tokens, 8 of 32, and always a divisor
+    assert _run_blocks(16, 128, 8, 128, 2) == 16   # the benchmark's cells
+    assert _run_blocks(32, 64, 8, 128, 1) == 8
+    assert _run_blocks(16, 256, 2, 128, 2) == 16   # tp=4 shard
+    assert _run_blocks(16, 12, 8, 128, 4) == 12
+    assert _run_blocks(16, 20, 2, 128, 2) == 10
+    assert _run_blocks(16, 24, 8, 128, 1) == 12
+    assert _run_blocks(16, 7, 8, 128, 4) == 7
+    assert _run_blocks(16, 17, 8, 128, 4) == 1
+    assert _run_blocks(8, 64, 8, 128, 4) == 32
+    # landing buffers too large for the VMEM budget shorten the run, from
+    # shapes alone: 16 f32 kv heads are 128 KiB a slab, 8 of them a half
+    assert _run_blocks(16, 128, 16, 128, 4) == 8
+
+
+@pytest.mark.parametrize("case", list(GRID_CASES))
+def test_paged_kernel_grid_matches_plain_softmax(case):
+    """One grid cell per slot, all kv heads inside, walking the live table
+    entries in runs of k: against a plain softmax, at every edge of block,
+    run and table, with an unallocated slot (null block, position 0) beside
+    the live ones, so that every slot but the first finds its first run
+    already started by the slot before it."""
+    dtype, hkv, group, t, nb, w, kvq = GRID_CASES[case]
+    d, n_layers, layer = 128, 2, 1
+    k = _run_blocks(t, nb, hkv, d, 1 if kvq else jnp.dtype(dtype).itemsize)
+    assert nb % k == 0
+    # contexts around every edge the walk has: position 0, inside a block,
+    # on a block edge, the last key of a run, the first of the next, one
+    # past it, and the full table
+    edges = [0, t // 2, t - 1, t, k * t - 1, k * t, k * t + 1]
+    ctx = [e for e in edges if e + w <= nb * t] + [nb * t - w]
+    b = len(ctx) + 1                      # the last slot is unallocated
+    n_pool = (b - 1) * nb + 1             # block 0 is the null block
+    rng = np.random.default_rng(sum(map(ord, case)))
+    kf = rng.standard_normal((n_pool, n_layers, hkv, t, d)).astype(np.float32)
+    vf = rng.standard_normal((n_pool, n_layers, hkv, t, d)).astype(np.float32)
+    q = jnp.asarray(rng.standard_normal((b, w, hkv * group, d)), dtype)
+    # each live slot owns a shuffled set of pool blocks: runs are scattered
+    tbl = np.zeros((b, nb), np.int32)
+    tbl[:-1] = 1 + rng.permutation((b - 1) * nb).reshape(b - 1, nb)
+    tbl, pos = jnp.asarray(tbl), jnp.asarray(ctx + [0], jnp.int32)
+    if kvq:
+        k_pool, v_pool = quantize_rows(jnp.asarray(kf)), quantize_rows(jnp.asarray(vf))
+        k_ref = k_pool.q.astype(jnp.float32) * k_pool.s[..., None]
+        v_ref = v_pool.q.astype(jnp.float32) * v_pool.s[..., None]
+    else:
+        k_pool, v_pool = jnp.asarray(kf, dtype), jnp.asarray(vf, dtype)
+        k_ref, v_ref = k_pool, v_pool
+    scale = d ** -0.5
+    got = paged_decode_attention(q, k_pool, v_pool, tbl, pos, layer, scale,
+                                 interpret=True)
+    want = _plain_paged_attention(q, k_ref, v_ref, tbl, pos, layer, scale)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    got = np.asarray(got.astype(jnp.float32))
+    assert np.isfinite(got).all()         # the null slot's junk is finite
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got[:-1], np.asarray(want)[:-1], atol=tol, rtol=tol)
 
 
 # -- knob resolution, ladder cap, recompile counter ---------------------------

@@ -102,6 +102,24 @@ def test_paged_decode_bf16(one_chip, no_cache, w):
     )
 
 
+def test_paged_decode_at_the_benchmark_cells_shapes(one_chip, no_cache):
+    """Granite-3.1-8B as both benchmark cells serve it: 8 slots, 8 kv heads,
+    MAX_SEQ_LEN 2048 = table width 128, a 40-layer pool, the decode and the
+    speculative widths. A VMEM overflow of the run's tiles shows here, not
+    in a chip call."""
+    layers, width = 40, 2048 // T
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    pool = sds((SLOTS * width + 64 + 1, layers, HKV, T, D), jnp.bfloat16)
+    for w in (1, SPEC_W):
+        _compile(
+            lambda q, kp, vp, tbl, pos, layer: paged_decode_attention(
+                q, kp, vp, tbl, pos, layer, SCALE),
+            sds((SLOTS, w, HQ, D), jnp.bfloat16), pool, pool,
+            sds((SLOTS, width), jnp.int32), sds((SLOTS,), jnp.int32),
+            sds((), jnp.int32),
+        )
+
+
 @pytest.mark.parametrize("t", [16, 32])
 def test_paged_decode_int8_kvq(one_chip, no_cache, t):
     """int8 codes at the default KV_BLOCK_TOKENS=16 compile too (the block's
