@@ -12,6 +12,14 @@ Parameters of a mix:
     prompt_tokens   <dist>: whole prompt as the engine counts it (template included)
     output_tokens   <dist>: max_tokens of each request; a reply must run to it
     temperature     sampling temperature; each request carries its own seed
+    greedy_every    optional k: every k-th request of the sequence is sent at
+                    temperature 0 (the same positions of the sequence for
+                    every seed). ``correct`` holds a sample of the requests a
+                    window finished to the plain reference by the served
+                    tokens' ids, which is valid for greedy tokens only; the
+                    row's temperature is an input of the decode program the
+                    others run too, so a greedy row costs what a sampled one
+                    does
     deck            how many requests one shuffled deck holds (default 64)
     order_seed      optional: the order of sizes comes from this number, not
                     from --seed, which then decides only the texts, the
@@ -26,7 +34,11 @@ Parameters of a mix:
                     settle phase may end: after ``settle_requests`` sends of
                     the mix and ``quiet_s`` without a new program, at the next
                     send (so the window always starts at the same point of the
-                    mix's sequence and at the same phase of the decode bursts)
+                    mix's sequence and at the same phase of the decode bursts).
+                    ``settle_requests`` is sized to be the LAST condition met:
+                    a count fixes the point of the sequence, a time does not
+                    (with the time binding, one run in five started a send
+                    early or late and read tokens/s 0.5-1.4 % off; PR 28)
 
 A <dist> is {"dist": "loguniform", "min": a, "max": b} or
 {"dist": "fixed", "value": v}.
@@ -85,6 +97,7 @@ class Request:
     prompt_tokens: int
     max_tokens: int
     seed: int
+    temperature: float | None = None  # None: the mix's
 
 
 class Generator:
@@ -97,7 +110,9 @@ class Generator:
         self.rng = random.Random(seed)
         self.order = random.Random(mix.get("order_seed", seed ^ 0x0DDE))
         self.deck_n = int(mix.get("deck", 64))
+        self.greedy_every = int(mix.get("greedy_every", 0))
         self._idx = 0
+        self._n = 0
         self._deck: list[tuple[int, int]] = []
 
     def _next_sizes(self) -> tuple[int, int]:
@@ -117,7 +132,11 @@ class Generator:
         return req
 
     def next(self) -> Request:
-        return self.make(*self._next_sizes())
+        req = self.make(*self._next_sizes())
+        self._n += 1
+        if self.greedy_every and self._n % self.greedy_every == 0:
+            req.temperature = 0.0
+        return req
 
 
 def warmup_lengths(mix: dict) -> list[int]:
@@ -147,8 +166,11 @@ class Record:
     error: str | None = None
     usage: dict | None = None
     stats: dict | None = None
-    first_logprobs: list | None = None
+    logprobs: list = field(default_factory=list)  # one entry per served token
     mismatch: str | None = None
+    temperature: float = 0.0
+    prompt: str = ""
+    text: str = ""  # the streamed reply; one printable byte is one token
 
 
 class Client:
@@ -164,19 +186,23 @@ class Client:
         self.send_event: asyncio.Event | None = None  # set at the next send
         self.send_time = 0.0                          # ... with its time here
 
-    async def chat(self, req: Request, logprobs: int = 0) -> Record:
+    async def chat(self, req: Request, logprobs: int = 0,
+                   temperature: float | None = None) -> Record:
+        if temperature is None:
+            temperature = self.temperature if req.temperature is None else req.temperature
         body = {
             "model": self.model_id,
             "messages": [{"role": "user", "content": req.prompt}],
             "max_tokens": req.max_tokens,
-            "temperature": self.temperature,
+            "temperature": temperature,
             "seed": req.seed,
             "stream": True,
         }
         if logprobs:
             body |= {"logprobs": True, "top_logprobs": logprobs}
         now = time.perf_counter()
-        rec = Record(req.idx, req.prompt_tokens, req.max_tokens, now)
+        rec = Record(req.idx, req.prompt_tokens, req.max_tokens, now,
+                     temperature=temperature, prompt=req.prompt)
         self.records.append(rec)
         if self.send_event is not None and not self.send_event.is_set():
             self.send_time = now
@@ -192,10 +218,12 @@ class Client:
                     self._finish(rec, json.loads(msg.payload))
                     break
                 choice = json.loads(msg.payload)["data"]["chunk"]["choices"][0]
-                if logprobs and rec.first_logprobs is None and choice.get("logprobs"):
-                    rec.first_logprobs = choice["logprobs"]["content"][0]["top_logprobs"]
+                if logprobs and choice.get("logprobs"):
+                    rec.logprobs.extend(choice["logprobs"]["content"])
                 # byte-level tokenizer: one printable character is one token
-                rec.chunks.append((t, len(choice["delta"].get("content", ""))))
+                piece = choice["delta"].get("content", "")
+                rec.text += piece
+                rec.chunks.append((t, len(piece)))
             else:
                 rec.error = "stream ended without a terminal message"
         except Exception as e:  # noqa: BLE001 — a failed request is a result

@@ -3,24 +3,48 @@ overrides to serve them.
 
 ``LocalRegistry._load`` looks ``parallel.loader.load_params_sharded`` up at
 call time; ``install(seed)`` sets that module attribute to ``seeded_params``,
-which builds the same tree the loader would (stacked ``blocks``, ``embed``,
-``out_norm``, ``lm_head``; int8 ``QTensor`` leaves under ``quant="int8"``)
-in ONE jitted call from the seed, each leaf at the sharding
-``parallel.sharding.param_sharding_rules`` gives it. Nothing touches the
-host and no GGUF tensor is read: the models dir holds a header-only file.
+which builds the tree the program would load in ONE jitted call from the
+seed. Nothing touches the host and no GGUF tensor is read: the models dir
+holds a header-only file.
 
-The substitution fails loudly. If the attribute is gone or its signature
-changed, ``install`` raises; there is no fallback to a GGUF load.
+The SCHEMA is the program's: ``jax.eval_shape`` of its own initialiser
+(``program_param_shapes``), or of the initialiser a reference module names
+for its family (``param_shapes(mcfg)`` in ``references/<name>.py``). Every
+leaf is then made by rules on its name and shape alone (``leaf_rule``), so a
+family with leaves this file has never seen is a new reference file and a new
+program function, and no edit here:
 
-Copied, not imported: the leaf schema of ``bench.py:101-157``
-(``init_params_int8``) and the printable-ASCII-loud head of
-``chip_smoke.py:112-117``.
+    rank-1 per layer, name ends in ``norm``   ones
+    ``lm_head``                               N(0, INIT_STD), printable-ASCII
+                                              columns loud (ASCII_LOGIT_STD)
+    every other float leaf                    N(0, INIT_STD x gain), rounded
+                                              to the serving dtype; gain is
+                                              QK_GAIN for ``wq``/``wk``, 1
+                                              otherwise, or what the
+                                              reference module's
+                                              ``weight_gains`` gives
+    int8 under quant="int8"                   where ``ops.wquant.quantizable``
+                                              says so
+    sharding                                  ``parallel.sharding.
+                                              param_sharding_rules``; a leaf
+                                              without a rule is an error
+
+Leaves under ``blocks`` are stacked on a leading layer axis and drawn one
+layer slice at a time, so the float32 transient stays one slice.
+
+The substitution and the schema fail loudly. If a program name they need is
+gone or its signature changed, they raise; there is no fallback to a GGUF
+load or to a list of leaves kept here.
+
+Copied, not imported: the int8 coding of ``ops.wquant.quantize_weight`` and
+the printable-ASCII-loud head of ``chip_smoke.py:112-117``.
 """
 
 from __future__ import annotations
 
 import inspect
 import time
+import zlib
 
 # every N(0, INIT_STD) like models.llama.init_params
 INIT_STD = 0.02
@@ -37,11 +61,23 @@ ASCII_LOGIT_STD = 10.0
 # returns the mean of the values and adds almost nothing to the stream: a
 # wrong kv head, a wrong block table or a wrong mask would then barely move
 # the logits the reference check reads. x4 on both sides puts the scores'
-# spread near 2, as peaked as a trained model's.
+# spread near 2, as peaked as a trained model's. A reference module's
+# ``weight_gains`` does the same for the gates or routers of its family.
 QK_GAIN = 4.0
+DEFAULT_GAIN = {"wq": QK_GAIN, "wk": QK_GAIN}
 ASCII_LO, ASCII_HI = 32, 127  # [lo, hi)
 
+# The draw of the leaves the first benchmark had, so that its cells' trees
+# stay bit for bit what they were: ``fold_in(k_blocks, i)``. Any other leaf
+# folds in a hash of its path. Where two leaves of one tree claim one number,
+# the one named first here keeps it and the other takes its hash.
+FIRST_DRAWS = {"wq": 0, "wk": 1, "wv": 2, "wo": 3,
+               "w_gate": 4, "w_up": 5, "w_down": 6,
+               "router": 4, "w_gate_e": 5, "w_up_e": 6, "w_down_e": 7}
+STACKED = "blocks."  # leaves under it carry a leading layer axis
+
 EXPECTED_SIGNATURE = ("reader", "cfg", "mesh", "dtype", "quant", "group")
+
 
 def ascii_column_scale(cfg) -> float:
     plain = INIT_STD * (cfg.d_model ** 0.5) * cfg.logit_scale
@@ -56,10 +92,97 @@ def _seed_key(seed: int):
     return jax.random.fold_in(key, seed >> 31)
 
 
-def make_seeded_params(seed: int):
+def program_param_shapes(cfg):
+    """The tree the program would load for ``cfg``, as shapes: its own
+    initialiser with the head materialised, never run."""
+    import jax
+
+    from nats_llm_studio_tpu.models import llama
+
+    init = getattr(llama, "init_params", None)
+    ensure = getattr(llama, "ensure_lm_head", None)
+    if init is None or ensure is None:
+        raise RuntimeError(
+            "models.llama.init_params / ensure_lm_head is gone: the seeded "
+            "weights take their schema from it (see benchmark/README.md); name "
+            "the program's initialiser here or in the reference's param_shapes")
+    return jax.eval_shape(lambda: ensure(init(cfg, jax.random.PRNGKey(0))))
+
+
+def flatten(tree: dict, prefix: str = "") -> dict:
+    """{dotted path: leaf} of nested dicts."""
+    out = {}
+    for k, v in tree.items():
+        if isinstance(v, dict):
+            out.update(flatten(v, f"{prefix}{k}."))
+        else:
+            out[f"{prefix}{k}"] = v
+    return out
+
+
+def unflatten(flat: dict) -> dict:
+    out: dict = {}
+    for path, v in flat.items():
+        node = out
+        *parents, last = path.split(".")
+        for p in parents:
+            node = node.setdefault(p, {})
+        node[last] = v
+    return out
+
+
+def leaf_rule(path: str, shape: tuple) -> str:
+    """How one leaf is made, from its name and shape: "ones", "head" or
+    "normal"."""
+    name = path.rsplit(".", 1)[-1]
+    per_layer = shape[1:] if path.startswith(STACKED) else shape
+    if name.endswith("norm") and len(per_layer) == 1:
+        return "ones"
+    return "head" if path == "lm_head" else "normal"
+
+
+def path_number(path: str) -> int:
+    """A stable 31-bit number for a leaf's path, clear of FIRST_DRAWS."""
+    return (zlib.crc32(path.encode()) & 0x3FFFFFFF) | 0x40000000
+
+
+def draw_numbers(paths: list[str]) -> dict[str, int]:
+    """The number each stacked leaf folds into the blocks' key."""
+    out: dict[str, int] = {}
+    taken: set[int] = set()
+    names = {p[len(STACKED):]: p for p in paths}
+    for name, i in FIRST_DRAWS.items():
+        if name in names and i not in taken:
+            out[names[name]] = i
+            taken.add(i)
+    for p in paths:
+        out.setdefault(p, path_number(p))
+    return out
+
+
+def resolve_gains(gain: dict | None, paths: list[str]) -> dict[str, float]:
+    """{path: gain}; a key is a leaf's dotted path or its last name. A key
+    that names no leaf of the tree is an error."""
+    asked = DEFAULT_GAIN | dict(gain or {})
+    out = {}
+    for key, g in asked.items():
+        hits = [p for p in paths if p == key or p.rsplit(".", 1)[-1] == key]
+        if not hits and key not in DEFAULT_GAIN:
+            raise ValueError(f"weight_gains names {key!r}, which is no leaf of {sorted(paths)}")
+        out.update({p: float(g) for p in hits})
+    return out
+
+
+def make_seeded_params(seed: int, family=None):
     """Returns a function with the loader's signature that ignores the
-    reader's tensors and builds the tree from ``seed``. Its ``last_build``
-    attribute holds the seconds and bytes of its last call."""
+    reader's tensors and builds the tree from ``seed``. ``family`` is the
+    configuration's reference module (or anything with its two optional
+    names): ``param_shapes(cfg)`` gives the schema (default:
+    ``program_param_shapes``), ``weight_gains`` a {leaf: factor} beside it.
+    Its ``last_build`` attribute holds the seconds and bytes of its last
+    call."""
+    param_shapes = getattr(family, "param_shapes", None)
+    gain = getattr(family, "weight_gains", None)
 
     def seeded_params(reader, cfg, mesh, dtype=None, quant="none", group=32):
         import jax
@@ -73,14 +196,22 @@ def make_seeded_params(seed: int):
         if quant not in ("none", "int8"):
             raise NotImplementedError(
                 f"seeded weights cover quant 'none' and 'int8', not {quant!r}")
-        if cfg.attn_bias:
-            raise NotImplementedError("seeded weights build the no-bias schema")
         t0 = time.perf_counter()
         dt = jnp.dtype(dtype or cfg.dtype)
+        schema = flatten((param_shapes or program_param_shapes)(cfg))
+        for path, x in schema.items():
+            if not jnp.issubdtype(x.dtype, jnp.floating):
+                raise NotImplementedError(
+                    f"leaf {path!r} is {x.dtype}: seeded weights have rules for float leaves only")
         rules = param_sharding_rules(mesh, cfg)
-        L, d, ff = cfg.n_layers, cfg.d_model, cfg.d_ff
-        hq, hkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-        v = cfg.vocab_size
+        missing = sorted(p for p in schema if p not in rules)
+        if missing:
+            raise KeyError(
+                f"parallel.sharding.param_sharding_rules has no rule for {missing}: "
+                "the program cannot place a leaf its own initialiser makes")
+        stacked = [p for p in schema if p.startswith(STACKED)]
+        draws = draw_numbers(stacked)
+        gains = resolve_gains(gain, list(schema))
         col = ascii_column_scale(cfg)
 
         def q8(w):
@@ -93,60 +224,59 @@ def make_seeded_params(seed: int):
             q = jnp.clip(jnp.round(wf / safe), -127, 127).astype(jnp.int8)
             return QTensor(q=q, s=safe.astype(jnp.float32))
 
-        def leaf(name, w):
-            return q8(w) if quant == "int8" and quantizable(name) else w.astype(dt)
+        def coded(path, w):
+            return q8(w) if quant == "int8" and quantizable(path) else w.astype(dt)
 
         def randn(k, shape, gain=1.0):
             # rounded to the serving dtype first, as a loaded bf16 file is
             return (jax.random.normal(k, shape, jnp.float32) * (INIT_STD * gain)).astype(dt)
 
-        stacked = {"wq": (d, hq), "wk": (d, hkv), "wv": (d, hkv), "wo": (hq, d)}
-        if cfg.is_moe:
-            e = cfg.n_experts
-            stacked |= {"router": (d, e), "w_gate_e": (e, d, ff),
-                        "w_up_e": (e, d, ff), "w_down_e": (e, ff, d)}
-        else:
-            stacked |= {"w_gate": (d, ff), "w_up": (d, ff), "w_down": (ff, d)}
+        def head(k, shape):
+            # drawn on its own even where the published model ties it to the
+            # embedding: a random tied head gives the last prompt token's own
+            # column a logit of ~12 |e|^2 x the loudness (50 at these
+            # widths), and the model repeats one byte for ever. The served
+            # tree holds a materialised lm_head either way.
+            v = shape[-1]
+            loud = jnp.where(
+                (jnp.arange(v) >= ASCII_LO) & (jnp.arange(v) < ASCII_HI), col, 1.0)
+            return randn(k, shape).astype(jnp.float32) * loud[None, :]
 
         def build(key):
             k_embed, k_head, k_blocks = jax.random.split(key, 3)
-            embed = randn(k_embed, (v, d))
-            loud = jnp.where(
-                (jnp.arange(v) >= ASCII_LO) & (jnp.arange(v) < ASCII_HI), col, 1.0)
-            # the head is drawn on its own even where the published model
-            # ties it to the embedding: a random tied head gives the last
-            # prompt token's own column a logit of ~12 |e|^2 x the loudness
-            # (50 at these widths), and the model repeats one byte for ever.
-            # The served tree holds a materialised lm_head either way.
-            head = randn(k_head, (d, v)).astype(jnp.float32) * loud[None, :]
-            blocks = {"attn_norm": jnp.ones((L, d), dt), "ffn_norm": jnp.ones((L, d), dt)}
-            for i, (name, shape) in enumerate(stacked.items()):
-                keys = jax.random.split(jax.random.fold_in(k_blocks, i), L)
-                # one layer slice at a time: the f32 transient is one slice
-                gain = QK_GAIN if name in ("wq", "wk") else 1.0
-                blocks[name] = jax.lax.map(
-                    lambda k, name=name, shape=shape, gain=gain: leaf(
-                        name, randn(k, shape, gain)), keys)
-            return {"embed": embed, "out_norm": jnp.ones((d,), dt),
-                    "lm_head": leaf("lm_head", head), "blocks": blocks}
+            top_keys = {"embed": k_embed, "lm_head": k_head}
+            out = {}
+            for path, x in schema.items():
+                rule = leaf_rule(path, x.shape)
+                g = gains.get(path, 1.0)
+                if rule == "ones":
+                    out[path] = jnp.ones(x.shape, dt)
+                elif path in draws:
+                    keys = jax.random.split(jax.random.fold_in(k_blocks, draws[path]), x.shape[0])
+                    # one layer slice at a time: the f32 transient is one slice
+                    out[path] = jax.lax.map(
+                        lambda k, path=path, shape=x.shape[1:], g=g: coded(
+                            path, randn(k, shape, g)), keys)
+                else:
+                    k = top_keys.get(path)
+                    if k is None:
+                        k = jax.random.fold_in(k_blocks, path_number(path))
+                    out[path] = coded(path, head(k, x.shape) if rule == "head"
+                                      else randn(k, x.shape, g))
+            return unflatten(out)
 
-        def shard(name, x, stacked_axis):
-            spec = rules[name]
+        def placed(path, x):
+            spec = rules[path]
             if isinstance(x, QTensor):
-                if stacked_axis:
+                if path in draws:
                     s_spec = P(spec[0], *scale_spec(P(*spec[1:])))
                 else:
                     s_spec = scale_spec(spec)
                 return QTensor(q=NamedSharding(mesh, spec), s=NamedSharding(mesh, s_spec))
             return NamedSharding(mesh, spec)
 
-        shapes = jax.eval_shape(build, _seed_key(seed))
-        out_shardings = {
-            k: shard(k, shapes[k], False) for k in ("embed", "out_norm", "lm_head")
-        }
-        out_shardings["blocks"] = {
-            k: shard(f"blocks.{k}", x, True) for k, x in shapes["blocks"].items()
-        }
+        built = flatten(jax.eval_shape(build, _seed_key(seed)))
+        out_shardings = unflatten({p: placed(p, x) for p, x in built.items()})
         params = jax.jit(build, out_shardings=out_shardings)(_seed_key(seed))
         jax.block_until_ready(params)
         seeded_params.last_build.update(
@@ -158,7 +288,7 @@ def make_seeded_params(seed: int):
     return seeded_params
 
 
-def install(seed: int):
+def install(seed: int, family=None):
     """Point ``parallel.loader.load_params_sharded`` at the seeded builder
     and return it. The only program name the benchmark overrides."""
     from nats_llm_studio_tpu.parallel import loader
@@ -173,5 +303,5 @@ def install(seed: int):
         raise RuntimeError(
             f"parallel.loader.load_params_sharded{names} no longer matches "
             f"{EXPECTED_SIGNATURE}: refusing to substitute seeded weights")
-    loader.load_params_sharded = builder = make_seeded_params(seed)
+    loader.load_params_sharded = builder = make_seeded_params(seed, family)
     return builder

@@ -9,7 +9,10 @@ LocalRegistry -> Worker — connects with ``transport.connect``, checks the
 served path against the plain reference, warms up, drives
 ``lmstudio.chat_model`` with ``"stream": true`` for ``--seconds``, prints one
 JSON line, shuts down. Every earlier line of stdout is one JSON object that
-says where set-up time went. See ``benchmark/README.md``.
+says where the time went: a ``{"phase": ..., "begin": true, "t_s": ...}`` line
+when a phase begins, a line with its ``seconds`` when it ends, each with the
+seconds since process start, and ``run_s`` on the last. The driver stops a
+run at 360 s; see ``benchmark/README.md``.
 
 A run that finds no TPU, or fewer chips than the cell asks for, exits
 non-zero and prints no result. ``--rehearse`` (never given by the driver)
@@ -41,7 +44,18 @@ REHEARSAL_EXIT = 3
 
 
 def emit(**kw) -> None:
+    """One JSON line. A phase line says where the run stands: ``t_s`` is the
+    seconds since the process started, so a run that is killed leaves its
+    place behind."""
+    if "phase" in kw:
+        kw["t_s"] = round(time.perf_counter() - T_START, 3)
     print(json.dumps(kw), flush=True)
+
+
+def begin(phase: str) -> float:
+    """Say that a phase begins (its own line follows when it ends)."""
+    emit(phase=phase, begin=True)
+    return time.perf_counter()
 
 
 def load_module(path: Path):
@@ -103,8 +117,8 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
     from benchmark.lib import correct, model_files, weights
     from benchmark.lib.sources import CompileClock, memory_by_device
     from benchmark.lib.stats import finite_ms
-    from benchmark.lib.traffic import (Client, Generator, Load, reduce_client,
-                                       warmup_lengths)
+    from benchmark.lib.traffic import (Client, Generator, Load, dist_bounds, quantiles,
+                                       reduce_client, warmup_lengths)
     from nats_llm_studio_tpu.main import start_serve
     from nats_llm_studio_tpu.transport import connect
 
@@ -121,7 +135,9 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
     os.environ["LMSTUDIO_MODELS_DIR"] = str(models_dir)
 
     clock = CompileClock()
-    builder = weights.install(args.seed)
+    # the schema is the program's own initialiser, or the one the reference
+    # names for its family (``param_shapes``, with ``weight_gains`` beside it)
+    builder = weights.install(args.seed, reference)
     worker, shutdown = await start_serve(embedded_broker=True, port=0,
                                          store_dir=str(scratch / "store"))
     nc = await connect(worker.config.nats_url, name="benchmark")
@@ -133,7 +149,7 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
         wu = mix.get("warmup", {})
 
         # -- load: the first request makes the registry load the model ------
-        t0 = time.perf_counter()
+        t0 = begin("load")
         first = await client.chat(wgen.make(64, 2))
         if not first.ok:
             raise RuntimeError(f"first request failed: {first.error}")
@@ -148,23 +164,35 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
              compile_cache_dir=jax.config.jax_compilation_cache_dir,
              memory=memory_by_device())
 
-        # -- reference check (set-up): first generated token's top-5 --------
-        t0 = time.perf_counter()
-        probes = [wgen.make(64, 1) for _ in range(correct.PROBES)]
-        served = await asyncio.gather(*(client.chat(p, logprobs=correct.TOP_K) for p in probes))
+        # -- probes (set-up): what the reference check will compare ----------
+        # greedy, with logprobs, DECODE_TOKENS each: the first token out of
+        # the prefill, the others decoded through the pool. Served now, on an
+        # engine that holds nothing else; compared once the window has closed
+        t0 = begin("probes")
+        probes = [wgen.make(correct.PROBE_TOKENS, correct.DECODE_TOKENS)
+                  for _ in range(correct.PROBES)]
+        served = list(await asyncio.gather(*(
+            client.chat(p, logprobs=correct.TOP_K, temperature=0.0) for p in probes)))
+        # one more at the mix's median prompt length where that is more than
+        # one prefill chunk of this engine (chunked prefill and a table of many
+        # blocks come under the check; a shorter one would add a program to
+        # build and nothing to see), after the others, from a stream of its own
+        median_prompt = quantiles(mix["prompt_tokens"], 1)[0]
+        if median_prompt > int(getattr(batcher, "prefill_chunk", None) or 0):
+            probes.append(Generator(mix, args.seed ^ 0x10C6).make(
+                median_prompt, correct.DECODE_TOKENS))
+            served.append(await client.chat(probes[-1], logprobs=correct.TOP_K, temperature=0.0))
         for r in served:
-            if not r.ok or not r.first_logprobs:
-                raise RuntimeError(f"reference probe failed: {r.error or 'no logprobs'}")
-        ref_check = correct.compare_all([
-            (reference.last_logprobs(batcher.params, conf, list(_rendered(p).encode())),
-             r.first_logprobs) for p, r in zip(probes, served)])
-        emit(phase="reference", seconds=time.perf_counter() - t0, **ref_check)
+            if not r.ok or len(r.logprobs) != r.max_tokens:
+                raise RuntimeError(f"reference probe failed: {r.error or r.mismatch or 'no logprobs'}")
+        emit(phase="probes", seconds=time.perf_counter() - t0,
+             prompt_tokens=[p.prompt_tokens for p in probes], tokens=correct.DECODE_TOKENS)
 
         # -- warm-up 1: a sweep over the mix's own shapes --------------------
         # each length at the mix's widths while ``background`` long streams
         # keep the engine decoding, as it is all through the window: an admit
         # under live decode is another program than one on an idle engine
-        t0 = time.perf_counter()
+        t0 = begin("warmup_sweep")
         lo_out = int(wu.get("max_tokens", 9))
         n_sweep = 0
 
@@ -203,13 +231,14 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
              lengths=lengths, programs=len(clock.events))
 
         # -- warm-up 2: the mix itself, until no new program appears ---------
-        t0 = time.perf_counter()
+        t0 = begin("warmup_settle")
         load = Load(client, gen)
         load.start()
         quiet_s = float(wu.get("quiet_s", 5.0))
         settle_min = float(wu.get("min_settle_s", quiet_s))
         settle_max = float(wu.get("max_settle_s", 60.0))
-        settle_sends = len(client.records) + int(wu.get("settle_requests", 0))
+        sent_before = len(client.records)
+        settle_sends = sent_before + int(wu.get("settle_requests", 0))
         while True:
             await asyncio.sleep(0.05)
             now = time.perf_counter()
@@ -227,10 +256,14 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
         except asyncio.TimeoutError:
             w0 = time.perf_counter()
         client.send_event = None
+        # ``sends``: how many requests of the mix went out before the window's
+        # first, i.e. the point of the mix's sequence the window starts at
         emit(phase="warmup_settle", seconds=time.perf_counter() - t0,
+             sends=sum(r.t_sent < w0 for r in client.records[sent_before:]),
              programs=len(clock.events), compile=clock.summary())
 
         # -- the window ------------------------------------------------------
+        begin("window")
         stats0 = _stat_snapshot(batcher)
         w1 = w0 + args.seconds
         setup_s = w0 - T_START
@@ -251,9 +284,55 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
         cm = reduce_client(client.records, w0, w1)
         emit(phase="window", setup_s=setup_s, programs_in_window=in_window, **{
             k: v for k, v in cm.items() if k != "mismatches"})
+
+        # -- reference check: once the window has closed and the peak is read -
+        # one float32 forward per probe, and per request of a sample of the
+        # greedy ones the window itself finished, over its prompt and served
+        # tokens; every forward is padded to one (T, N), so ONE program
+        t0 = begin("reference")
+        sample = correct.window_sample(client.records, w0, w1, args.seed)
+        # [prompt + served tokens but the last, served tokens] of each
+        probe_io = [(list(_rendered(p.prompt).encode()) + correct.served_tokens(r.logprobs)[:-1],
+                     correct.served_tokens(r.logprobs)) for p, r in zip(probes, served)]
+        sample_io = [(list((_rendered(r.prompt) + r.text[:-1]).encode()),
+                      list(r.text.encode())) for r in sample]
+        hi_prompt, hi_out = (dist_bounds(mix[k])[1] for k in ("prompt_tokens", "output_tokens"))
+        longest = max([hi_prompt + hi_out] + [len(toks) for toks, _ in probe_io])
+        pad = (-(-longest // 128) * 128, max(correct.DECODE_TOKENS, hi_out))
+
+        def forward(toks, out, **kw):
+            return reference.tail_logprobs(batcher.params, conf, toks, len(out), pad_to=pad, **kw)
+
+        probe_ref = [forward(toks, out) for toks, out in probe_io]
+        sample_ref = [forward(toks, out) for toks, out in sample_io]
+        ref_check = correct.compare_probes(
+            [(ref, r.logprobs) for ref, r in zip(probe_ref, served)])
+        win_check = correct.compare_window(
+            [(ref, out) for ref, (_, out) in zip(sample_ref, sample_io)])
+        emit(phase="reference", seconds=time.perf_counter() - t0, pad_to=pad,
+             **ref_check, window=dict(
+                 win_check, greedy_finished=sum(
+                     r.ok and r.temperature == 0.0 and w0 <= (r.t_done or 0) < w1
+                     for r in client.records),
+                 sampled=[[r.prompt_tokens, r.max_tokens] for r in sample]))
+        if args.control:
+            # never in the driver's runs: the reference at the precision below
+            # the served one, in the served path's place, at the same prompts
+            # and tokens; every limit must tell it from the served path
+            t0 = begin("control")
+            low = [forward(toks, out, lower=args.control) for toks, out in probe_io + sample_io]
+            emit(phase="control", seconds=time.perf_counter() - t0, lower=args.control,
+                 **correct.compare_probes([
+                     (ref, correct.entries_of(lp)) for ref, lp in zip(probe_ref, low)]),
+                 window=correct.compare_window([
+                     (ref, [int(i) for i in lp.argmax(-1)])
+                     for ref, lp in zip(sample_ref, low[len(probe_io):])]))
+
         problems = list(cm["mismatches"][:3])
         if not ref_check["ok"]:
             problems.append(f"reference check outside its tolerance: {ref_check}")
+        if not win_check["ok"]:
+            problems.append(f"the window's own requests outside their tolerance: {win_check}")
         if in_window:
             problems.append(f"programs built inside the window: {in_window}")
         want_kernel = serving.get("require_decode_kernel")
@@ -273,8 +352,12 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
         }
         dev_out = dict(device, memory_peak_bytes=max(
             (d["peak_bytes_in_use"] or 0) for d in mem))
+        # each number compared beside its limit: main() prints it again as the
+        # last lines of standard error, where the driver's record of a run ends
         result = {"correct": not problems, "attempted": cm["attempted"],
-                  "failed": cm["failed"], "metrics": {}, "device": dev_out}
+                  "failed": cm["failed"], "metrics": {}, "device": dev_out,
+                  "compared": correct.compared(ref_check, win_check) + [
+                      f"problem: {p}" for p in problems]}
         if not args.trace:
             for m in man.metrics("end_to_end", cell["name"]):
                 v = values[m["name"]]
@@ -284,6 +367,7 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
         else:
             from benchmark.lib import reduce_trace
 
+            begin("trace")
             trace = reduce_trace.reduce(
                 reduce_trace.load_planes(reduce_trace.find_xplane(str(trace_dir))))
             emit(phase="trace", span=trace_span, **{
@@ -316,14 +400,17 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
             result["breakdown"] = {"device_ops": trace.get("device_ops", []),
                                    "idle_gaps": trace.get("idle_gaps", [])}
     finally:
+        t0 = begin("shutdown")
         await nc.close()
         await asyncio.wait_for(shutdown(), timeout=60.0)
+        emit(phase="shutdown", seconds=time.perf_counter() - t0,
+             run_s=time.perf_counter() - T_START)
     return result
 
 
-def _rendered(req) -> str:
+def _rendered(prompt: str) -> str:
     """The prompt as the header's chat template renders it."""
-    return f"<|user|>{req.prompt}<|assistant|>"
+    return f"<|user|>{prompt}<|assistant|>"
 
 
 def _stat_snapshot(batcher) -> dict:
@@ -381,6 +468,12 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--manifest", type=Path, default=ROOT / "BENCHMARK.json")
     ap.add_argument("--rehearse", action="store_true",
                     help="allow a CPU: prints rehearsal lines, no result, exits 3")
+    ap.add_argument("--control", choices=("fp8",), default=None,
+                    help="also put the reference at this lower precision in the served "
+                         "path's place and print what every limit reads of it")
+    ap.add_argument("--env", action="append", default=[], metavar="KEY=VALUE",
+                    help="override the configuration's serving env: the program's own "
+                         "lower-precision paths as a control, never a cell")
     args = ap.parse_args(argv)
 
     man = Manifest(args.manifest.resolve())
@@ -388,6 +481,7 @@ def main(argv: list[str] | None = None) -> int:
     conf = man.config(cell["config"])
     mix = man.traffic(cell["traffic"])
     # the cell's serving environment: the configuration's existing knobs
+    conf["serving"]["env"] |= dict(kv.split("=", 1) for kv in args.env)
     for k, v in conf["serving"]["env"].items():
         os.environ[k] = str(v)
     # one fixed compile-cache directory inside the checkout (the path is part
@@ -405,6 +499,8 @@ def main(argv: list[str] | None = None) -> int:
         return 2
 
     result = asyncio.run(run_cell(args, man, cell, conf, mix))
+    sys.stdout.flush()
+    print("\n".join(result["compared"]), file=sys.stderr, flush=True)
     if not on_chip:
         emit(rehearsal=True, note="CPU run: no number below is a measurement",
              would_print=result)

@@ -149,3 +149,18 @@ def test_order_seed_fixes_the_order_and_leaves_the_texts_to_the_seed():
         (r.prompt_tokens, r.max_tokens) for r in b]
     assert [r.prompt for r in a] != [r.prompt for r in b]
     assert [r.seed for r in a] != [r.seed for r in b]
+
+
+def test_every_kth_request_of_the_sequence_is_greedy_whatever_the_seed():
+    mix = dict(CLOSED, greedy_every=4, order_seed=23)
+    a, b = take(traffic.Generator(mix, 1), 24), take(traffic.Generator(mix, 2**31 + 9), 24)
+    greedy = [i for i, r in enumerate(a) if r.temperature == 0.0]
+    assert greedy == [3, 7, 11, 15, 19, 23]
+    assert greedy == [i for i, r in enumerate(b) if r.temperature == 0.0]
+    assert all(r.temperature is None for i, r in enumerate(a) if i not in greedy)
+    # with the order pinned, the greedy requests have the same sizes in every run
+    assert [(a[i].prompt_tokens, a[i].max_tokens) for i in greedy] == [
+        (b[i].prompt_tokens, b[i].max_tokens) for i in greedy]
+    # warm-up and probe requests (``make``) are never marked
+    assert traffic.Generator(mix, 1).make(64, 4).temperature is None
+    assert all(r.temperature is None for r in take(traffic.Generator(CLOSED, 1), 8))
