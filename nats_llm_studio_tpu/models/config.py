@@ -3,10 +3,25 @@
 Key names follow the public GGUF conventions (``<arch>.block_count`` etc.)
 that conversion tools write; ``from_gguf`` therefore loads any
 llama/granite/mixtral-family file without sidecar config.
+
+Families, by what the metadata carries (README.md, "Model families, and what
+each refuses", has the causes each refusal gives):
+
+* ``llama``, ``granite`` (four multipliers), ``qwen2`` (q/k/v biases),
+  ``gemma`` (GeGLU, scaled tied embedding), any arch with ``expert_count``
+  (Mixtral-style softmax top-k experts): one stack, ``models/llama.py``.
+* ``attention.kv_lora_rank`` present (written by ``models/export.py`` for
+  ``is_mla`` configs): latent attention with YaRN, leading dense layers then
+  sigmoid-routed experts beside shared ones, residual streams:
+  ``models/mla_moe.py``. Served on the paged pool of one chip only; int8 KV,
+  the KV tiers, KVX1 export and a real GGUF's tensors are refused.
+* ``gemma2``, ``gemma3``, ``qwen2moe``: rejected here (post-norms,
+  soft-capping, a softmax-gated shared expert).
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field, replace
 from typing import Any
 
@@ -57,10 +72,75 @@ class ModelConfig:
     # that is most of a decode step). Costs ~n_layers x compile time for the
     # decode program only; prefill keeps the scan.
     decode_unroll: bool = False
+    # -- latent (MLA) attention; kv_lora_rank == 0 = plain GQA ----------------
+    # q and kv go through a low-rank pair with a norm between (q_lora_rank,
+    # kv_lora_rank); a head's key is [nope | rope] wide, the rope part ONE key
+    # shared by all heads; the cache holds the normalised latent and the
+    # rotated shared key (models/mla_moe.py). head_dim is nope + rope there.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rotary scaling (factor 1 = plain rope)
+    rope_factor: float = 1.0
+    rope_orig_ctx: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # -- sigmoid-routed experts beside shared ones (DeepSeek-V3 style) --------
+    # n_dense_layers leading layers keep a dense SwiGLU of width d_ff; the
+    # others route n_experts_used of n_experts experts of width moe_d_ff by
+    # sigmoid score + a selection bias, and add n_shared_experts always-on
+    # experts. Dropless: moe_capacity_factor is never read for this family.
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    n_dense_layers: int = 0
+    router_scoring: str = "softmax"  # "softmax" (Mixtral) | "sigmoid"
+    routed_scaling: float = 1.0
+    # -- multi-stream residual (mHC); 1 = the plain residual ------------------
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp_min: float = -10.0
+    hc_res_clamp_max: float = 10.0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_moe_layers(self) -> int:
+        """Layers whose FFN is the routed-expert form (MLA family only: the
+        Mixtral family routes in every layer and keeps no dense stack)."""
+        return self.n_layers - self.n_dense_layers if self.is_mla and self.is_moe else 0
+
+    @property
+    def rope_mscale_sq(self) -> float:
+        """YaRN's softmax-scale correction, squared (both q and k carry it)."""
+        if self.rope_factor <= 1.0 or not self.rope_mscale_all_dim:
+            return 1.0
+        m = 0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1.0
+        return m * m
 
     @property
     def attn_scale(self) -> float:
-        return self.attention_scale if self.attention_scale is not None else self.head_dim**-0.5
+        if self.attention_scale is not None:
+            return self.attention_scale
+        return self.head_dim**-0.5 * self.rope_mscale_sq
+
+    def kv_cache_dims(self) -> tuple[tuple[int, int], tuple[int, int]]:
+        """(heads, width) of the two caches a token a layer: K and V alike
+        for GQA; for MLA the normalised latent and the rotated shared key
+        (zero-padded to the lane tile), one "head" each (ops/kvcache.py
+        treats the pair as opaque)."""
+        if self.is_mla:
+            # the rotary key's rows are padded to whole 128-lane tiles: the
+            # device lays a narrower minor plane out that wide anyway, and the
+            # decode kernel can only copy whole tiles out of the pool
+            return (1, self.kv_lora_rank), (1, -(-self.qk_rope_head_dim // 128) * 128)
+        return (self.n_kv_heads, self.head_dim), (self.n_kv_heads, self.head_dim)
 
     @property
     def is_moe(self) -> bool:
@@ -93,7 +173,8 @@ class ModelConfig:
         if arch in ("gemma2", "gemma3", "qwen2moe"):
             raise NotImplementedError(
                 f"architecture {arch!r} needs topology this model does not "
-                "implement (post-norms/softcapping or shared experts)"
+                "implement (post-norms/softcapping; qwen2moe's softmax-gated "
+                "shared expert)"
             )
         family: dict[str, Any] = {}
         if arch == "qwen2":
@@ -132,6 +213,35 @@ class ModelConfig:
             # final logits by 1/f_logit_scale); internally we keep a multiplier
             logit_scale=1.0 / float(g("logit_scale", 1.0)),
         )
+        if g("attention.kv_lora_rank") is not None:
+            # latent attention + sigmoid experts + residual streams: the keys
+            # models/export.config_metadata writes for the family
+            nope = int(g("attention.key_length_nope", head_dim))
+            rope = int(g("rope.dimension_count", 0))
+            family |= dict(
+                q_lora_rank=int(g("attention.q_lora_rank", 0)),
+                kv_lora_rank=int(g("attention.kv_lora_rank")),
+                qk_nope_head_dim=nope,
+                qk_rope_head_dim=rope,
+                v_head_dim=int(g("attention.value_length", head_dim)),
+                head_dim=nope + rope,
+                rope_factor=float(g("rope.scaling.factor", 1.0)),
+                rope_orig_ctx=int(g("rope.scaling.original_context_length", 0)),
+                rope_beta_fast=float(g("rope.scaling.yarn_beta_fast", 32.0)),
+                rope_beta_slow=float(g("rope.scaling.yarn_beta_slow", 1.0)),
+                rope_mscale=float(g("rope.scaling.yarn_mscale", 1.0)),
+                rope_mscale_all_dim=float(g("rope.scaling.yarn_mscale_all_dim", 0.0)),
+                moe_d_ff=int(g("expert_feed_forward_length", 0)),
+                n_shared_experts=int(g("expert_shared_count", 0)),
+                n_dense_layers=int(g("leading_dense_block_count", 0)),
+                router_scoring="sigmoid" if int(g("expert_gating_func", 1)) == 2 else "softmax",
+                routed_scaling=float(g("expert_weights_scale", 1.0)),
+                hc_mult=int(g("hyper_connection.count", 1)),
+                hc_sinkhorn_iters=int(g("hyper_connection.sinkhorn_iterations", 20)),
+                hc_eps=float(g("hyper_connection.epsilon", 1e-6)),
+                hc_res_clamp_min=float(g("hyper_connection.res_clamp_min", -10.0)),
+                hc_res_clamp_max=float(g("hyper_connection.res_clamp_max", 10.0)),
+            )
         kwargs.update(family)  # family quirks win over absent metadata keys
         return cls(**kwargs)
 

@@ -54,6 +54,33 @@ def config_metadata(cfg: ModelConfig, name: str) -> dict[str, Any]:
     if cfg.is_moe:
         md[f"{cfg.arch}.expert_count"] = cfg.n_experts
         md[f"{cfg.arch}.expert_used_count"] = cfg.n_experts_used
+    if cfg.is_mla:
+        a = cfg.arch
+        md |= {
+            f"{a}.attention.key_length": cfg.head_dim,
+            f"{a}.attention.key_length_nope": cfg.qk_nope_head_dim,
+            f"{a}.attention.value_length": cfg.v_head_dim,
+            f"{a}.attention.q_lora_rank": cfg.q_lora_rank,
+            f"{a}.attention.kv_lora_rank": cfg.kv_lora_rank,
+            f"{a}.rope.dimension_count": cfg.qk_rope_head_dim,
+            f"{a}.rope.scaling.type": "yarn" if cfg.rope_factor > 1.0 else "none",
+            f"{a}.rope.scaling.factor": cfg.rope_factor,
+            f"{a}.rope.scaling.original_context_length": cfg.rope_orig_ctx,
+            f"{a}.rope.scaling.yarn_beta_fast": cfg.rope_beta_fast,
+            f"{a}.rope.scaling.yarn_beta_slow": cfg.rope_beta_slow,
+            f"{a}.rope.scaling.yarn_mscale": cfg.rope_mscale,
+            f"{a}.rope.scaling.yarn_mscale_all_dim": cfg.rope_mscale_all_dim,
+            f"{a}.expert_feed_forward_length": cfg.moe_d_ff,
+            f"{a}.expert_shared_count": cfg.n_shared_experts,
+            f"{a}.leading_dense_block_count": cfg.n_dense_layers,
+            f"{a}.expert_gating_func": 2 if cfg.router_scoring == "sigmoid" else 1,
+            f"{a}.expert_weights_scale": cfg.routed_scaling,
+            f"{a}.hyper_connection.count": cfg.hc_mult,
+            f"{a}.hyper_connection.sinkhorn_iterations": cfg.hc_sinkhorn_iters,
+            f"{a}.hyper_connection.epsilon": cfg.hc_eps,
+            f"{a}.hyper_connection.res_clamp_min": cfg.hc_res_clamp_min,
+            f"{a}.hyper_connection.res_clamp_max": cfg.hc_res_clamp_max,
+        }
     if cfg.arch == "granite":
         md["granite.embedding_scale"] = cfg.embedding_scale
         md["granite.residual_scale"] = cfg.residual_scale

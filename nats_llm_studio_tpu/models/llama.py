@@ -340,6 +340,14 @@ def forward(
     * ``ring_slot`` None (tests, ragged callers): slots equal per-row
       positions, written by a per-layer batched scatter.
     """
+    if cfg.is_mla:
+        # latent attention, routed + shared experts, residual streams: the
+        # sibling model file, same contract (the caches hold latents)
+        from . import mla_moe
+
+        return mla_moe.forward(
+            params, cfg, tokens, k_cache, v_cache, start_pos, attn_window, mesh,
+            ring_slot, logit_positions, fresh_prefill, uniform_start)
     b, t = tokens.shape
     s_max = k_cache.shape[3]
     positions = start_pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # [B,T]
@@ -465,6 +473,8 @@ def forward_decode_paged(
     tbl: jax.Array,     # [B, NB] int32 block table (NB static = max width)
     start_pos: jax.Array,  # int32 [B] — tokens already in each slot's cache
     mesh=None,
+    moe_stats: bool = False,  # static: also return the expert-layer counters
+    # (models/mla_moe.py; only families with cfg.n_moe_layers have them)
 ) -> tuple[jax.Array, Any, Any]:
     """Decode forward that reads/writes the paged pool DIRECTLY — no
     ``kv_pool_gather_view`` materialization, no windowed attention, no
@@ -481,6 +491,12 @@ def forward_decode_paged(
     token-identical through the batcher."""
     from ..ops.kvcache import kv_pool_write_rows
 
+    if cfg.is_mla:
+        from . import mla_moe
+
+        out = mla_moe.forward_decode_paged(
+            params, cfg, tokens, k_pool, v_pool, tbl, start_pos, mesh)
+        return out if moe_stats else out[:3]
     b, w = tokens.shape
     hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     positions = start_pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
@@ -596,6 +612,10 @@ def make_cache(
     With ``cfg.kv_quant == "int8"`` each cache is a ``KVQ`` pytree (int8
     codes + f32 per-position-per-head scales, ops/kvcache.py) in the same
     layout — half the HBM traffic and capacity per step."""
+    if cfg.is_mla:
+        from . import mla_moe
+
+        return mla_moe.make_cache(cfg, batch, seq_len, dtype)
     s = seq_len or cfg.max_seq_len
     shape = (batch, cfg.n_layers, cfg.n_kv_heads, s, cfg.head_dim)
     if cfg.kv_quant == "int8":
@@ -613,6 +633,10 @@ def make_cache(
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Random small-scale init (tests / golden-logit fixtures)."""
+    if cfg.is_mla:
+        from . import mla_moe
+
+        return mla_moe.init_params(cfg, key)
     dt = jnp.dtype(cfg.dtype)
     keys = iter(jax.random.split(key, 24))
 

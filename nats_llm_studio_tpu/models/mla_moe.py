@@ -1,0 +1,504 @@
+"""Latent-attention (MLA) decoder with sigmoid-routed experts and a
+multi-stream (mHC) residual, beside ``models/llama.py``.
+
+``models.llama.forward`` / ``forward_decode_paged`` / ``make_cache`` hand a
+config with ``cfg.is_mla`` to the twins here, so the batcher, the pool, the
+table and the sampling are the ones every other family uses. What differs:
+
+* **Two stacks.** ``blocks.dense`` (the leading ``n_dense_layers``, SwiGLU of
+  width ``d_ff``) and ``blocks.moe`` (routed experts of width ``moe_d_ff``
+  beside ``n_shared_experts`` always-on ones), each one ``lax.scan``; a layer's
+  cache index is its place in the whole model.
+* **The cache pair is (latent, rotary key)**, one "kv head" each:
+  ``[B, L, 1, S, kv_lora_rank]`` holds the latent AFTER its norm and
+  ``[B, L, 1, S, qk_rope_head_dim]`` (rows zero-padded to whole 128-lane
+  tiles, as the device lays them out anyway) the shared key AFTER rotation —
+  576 numbers a token a layer where GQA would hold 2 x Hkv x D. ``ops/kvcache.py``
+  and the batcher treat the pair as they treat K and V.
+* **Attention.** T > ``_ABSORB_MAX_T`` (prefill, chunks): the window's latents
+  are expanded to per-head keys and values (qk 192 / v 128 at the published
+  widths) and attended in XLA, queries in blocks. T small (decode, the draft
+  bundle of a verify): absorbed — ``W_uk`` folds into the query, ``W_uv`` into
+  the output, and the scores read the latents themselves
+  (``ops/mla_attention.py``: a Pallas kernel over the pool and the table, or
+  the XLA form over a gathered view).
+* **Experts are dropless.** Dense dispatch: every expert computes every row,
+  weighted by the gate (0 for experts a row did not pick), a group of experts
+  at a time so the [rows, experts, width] intermediates stay small.
+  ``moe_capacity_factor`` is not read. The decode path also counts, per layer,
+  the distinct experts the live rows hit and the most rows on one expert: the
+  yardstick for a grouped matmul that reads only the experts hit.
+* **The residual is n streams** (``hc_mult``): ``X <- H_res X + H_post^T f(norm(H_pre X))``
+  with the three maps made from the streams themselves, ``H_res`` projected
+  onto doubly stochastic matrices by Sinkhorn rounds (rows first).
+
+Norms, the router, softmax and the stream mixers run in float32; the small
+float32 products (router, mixers) at ``Precision.HIGHEST``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.kvcache import kv_pool_write_rows, kv_update_slice
+from ..ops.layers import apply_rope, rms_norm, swiglu
+from ..ops.wquant import mm, q_einsum
+from .config import ModelConfig
+
+Params = dict[str, Any]
+
+_HI = jax.lax.Precision.HIGHEST
+# query widths up to this take the absorbed form (decode is 1, a speculative
+# verify k+1); anything wider is a prefill and expands the window's latents
+_ABSORB_MAX_T = 16
+# what the [rows, experts, width] activations of one group of experts in the
+# dense dispatch may take: a prefill of many rows computes its experts in
+# several groups, a decode step in one
+_EXPERT_ACT_BYTES = 256 << 20
+# queries attended together in the expanded form: [B, H, block, S] f32 scores
+_Q_BLOCK = 512
+
+
+# ---------------------------------------------------------------------------
+# rotary embedding with YaRN
+# ---------------------------------------------------------------------------
+
+
+def yarn_inv_freq(cfg: ModelConfig) -> np.ndarray:
+    """Inverse frequencies [rope/2] of the rotary part. Factor 1 is plain
+    rope; otherwise YaRN blends the scaled and the unscaled frequency by a
+    ramp over the correction range of ``beta_fast`` / ``beta_slow``."""
+    dim = cfg.qk_rope_head_dim
+    i = np.arange(0, dim, 2, dtype=np.float64)
+    extra = 1.0 / (cfg.rope_theta ** (i / dim))
+    if cfg.rope_factor <= 1.0 or not cfg.rope_orig_ctx:
+        return extra.astype(np.float32)
+    inter = extra / cfg.rope_factor
+
+    def corr_dim(rotations: float) -> float:
+        return dim * math.log(cfg.rope_orig_ctx / (rotations * 2 * math.pi)) / (
+            2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(corr_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.rope_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    m = 1.0 - ramp
+    return (inter * (1 - m) + extra * m).astype(np.float32)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 or not mscale else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_tables(cfg: ModelConfig, positions: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """cos/sin [..., rope/2] f32 at ``positions`` (YaRN frequencies; the
+    tables carry mscale(factor, mscale) / mscale(factor, mscale_all_dim))."""
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(yarn_inv_freq(cfg))
+    m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / _yarn_mscale(
+        cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
+# ---------------------------------------------------------------------------
+# the multi-stream residual
+# ---------------------------------------------------------------------------
+
+
+def hc_maps(X: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array, cfg: ModelConfig):
+    """The three maps of one mixer from the streams X [n, B, T, d]: ``pre``
+    and ``post`` [n, B, T], ``res`` [n, n, B, T] (row i = what new stream i
+    takes of each old stream). The stream axes lead, so nothing is laid out
+    4 wide and a row or column sum adds whole [B, T] planes; the Sinkhorn
+    rounds are one ``fori_loop`` (unrolled they are 20 x the program text of
+    every mixer, and half a minute of compile each)."""
+    n = cfg.hc_mult
+    xf = X.astype(jnp.float32)
+    rrms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=(0, 3), keepdims=True) + cfg.rms_eps)
+    wf = w.astype(jnp.float32).reshape(n, X.shape[-1], -1)
+    z = jnp.einsum("nbtd,ndk->kbt", xf * rrms, wf, precision=_HI)
+    af = a.astype(jnp.float32)
+    bf = b.astype(jnp.float32)[:, None, None]
+    pre = jax.nn.sigmoid(af[0] * z[:n] + bf[:n])
+    post = 2.0 * jax.nn.sigmoid(af[1] * z[n: 2 * n] + bf[n: 2 * n])
+    res = jnp.exp(jnp.clip(af[2] * z[2 * n:] + bf[2 * n:],
+                           cfg.hc_res_clamp_min, cfg.hc_res_clamp_max))
+    res = res.reshape((n, n) + res.shape[1:])
+
+    def sinkhorn(_, r):  # rows first
+        r = r / (jnp.sum(r, axis=1, keepdims=True) + cfg.hc_eps)
+        return r / (jnp.sum(r, axis=0, keepdims=True) + cfg.hc_eps)
+
+    return pre, post, jax.lax.fori_loop(0, cfg.hc_sinkhorn_iters, sinkhorn, res)
+
+
+def hc_read(X: jax.Array, pre: jax.Array) -> jax.Array:
+    """u = H_pre X: the one stream a sublayer reads, [B, T, d] float32."""
+    return jnp.sum(pre[..., None] * X.astype(jnp.float32), axis=0)
+
+
+def hc_write(X: jax.Array, post: jax.Array, res: jax.Array, y: jax.Array) -> jax.Array:
+    """X <- H_res X + H_post^T y."""
+    xf, yf = X.astype(jnp.float32), y.astype(jnp.float32)
+    mixed = jnp.sum(res[..., None] * xf[None], axis=1)  # [n(i), B, T, d]
+    return (mixed + post[..., None] * yf[None]).astype(X.dtype)
+
+
+def _residual(X, p, which: str, cfg: ModelConfig, f):
+    """One sublayer ("attn" or "ffn") around the streams: y = f(RMSNorm(H_pre
+    X)). ``f`` may return (y, aux); aux is passed through."""
+    pre, post, res = hc_maps(X, p[f"hc_{which}_w"], p[f"hc_{which}_a"],
+                             p[f"hc_{which}_b"], cfg)
+    u = rms_norm(hc_read(X, pre), p[f"{which}_norm"].astype(jnp.float32), cfg.rms_eps)
+    out = f(u.astype(jnp.dtype(cfg.dtype)))
+    y, aux = out if isinstance(out, tuple) else (out, None)
+    return hc_write(X, post, res, y), aux
+
+
+# ---------------------------------------------------------------------------
+# experts
+# ---------------------------------------------------------------------------
+
+
+def route(h: jax.Array, p: Params, cfg: ModelConfig):
+    """(idx [B, T, k], gate [B, T, k] f32): the k experts with the largest
+    sigmoid score + selection bias, gated by their normalised scores times
+    ``routed_scaling`` (the bias picks, it does not weigh)."""
+    logits = jnp.einsum("btd,de->bte", h.astype(jnp.float32),
+                        p["router"].astype(jnp.float32), precision=_HI)
+    score = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(score + p["e_bias"].astype(jnp.float32), cfg.n_experts_used)
+    chosen = jnp.take_along_axis(score, idx, axis=-1)
+    gate = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling
+    return idx, gate
+
+
+def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = None):
+    """Routed experts + the shared expert(s), dropless. Returns (y, stats):
+    ``stats`` is int32 [3] = (distinct experts the ``live`` rows hit, most
+    rows on one expert, live rows) when ``live`` [B] is given, else None.
+
+    Dense dispatch in groups of experts, the groups STATIC slices of the
+    layer's expert stacks: a slice of a scan's slice still fuses into the
+    product that reads it, where an inner ``lax.scan`` over the groups made
+    XLA copy a layer's 1.4 GB of experts into the loop's operand every step
+    (46 ms a decode step for 15: PERF.md, PR 29). A decode step is one
+    group; a prefill splits so that [rows, group, width] stays under
+    ``_EXPERT_ACT_BYTES``."""
+    e = cfg.n_experts
+    idx, gate = route(h, p, cfg)
+    picked = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # [B, T, k, E]
+    combine = jnp.sum(picked * gate[..., None], axis=-2).astype(h.dtype)  # [B, T, E]
+    stats = None
+    if live is not None:
+        rows_on = jnp.sum(jnp.sum(picked, axis=-2) * live[:, None, None], axis=(0, 1))
+        stats = jnp.stack([jnp.sum(rows_on > 0), jnp.max(rows_on),
+                           jnp.sum(live) * h.shape[1]]).astype(jnp.int32)
+    rows = h.shape[0] * h.shape[1]
+    act_bytes = rows * e * cfg.moe_d_ff * h.dtype.itemsize
+    groups = next(g for g in range(1, e + 1)
+                  if e % g == 0 and act_bytes // g <= _EXPERT_ACT_BYTES or g == e)
+    size = e // groups
+    acc = swiglu(h, p["w_gate_s"], p["w_up_s"], p["w_down_s"], cfg.mlp_act).astype(jnp.float32)
+    for g in range(groups):
+        wg, wu, wd = (jax.tree.map(lambda x: x[g * size: (g + 1) * size], p[k])
+                      for k in ("w_gate_e", "w_up_e", "w_down_e"))
+        act = jax.nn.silu(q_einsum("btd,gdf->btgf", h, wg)) * q_einsum("btd,gdf->btgf", h, wu)
+        act = act * combine[..., g * size: (g + 1) * size, None]
+        acc = acc + q_einsum("btgf,gfd->btd", act, wd).astype(jnp.float32)
+    return acc.astype(h.dtype), stats
+
+
+# ---------------------------------------------------------------------------
+# attention
+# ---------------------------------------------------------------------------
+
+
+def mla_project(h: jax.Array, p: Params, cfg: ModelConfig, cos, sin):
+    """q_nope [B,T,H,dn], q_rope [B,T,H,dr] (rotated), the normalised latent
+    c [B,T,R] and the rotated shared key kr [B,T,dr]: what the cache holds is
+    exactly (c, kr)."""
+    b, t, _ = h.shape
+    dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    cq = rms_norm(mm(h, p["w_dq"]), p["q_norm"], cfg.rms_eps)
+    q = mm(cq, p["w_uq"]).reshape(b, t, cfg.n_heads, dn + dr)
+    q_rope = apply_rope(q[..., dn:], cos, sin)
+    ckr = mm(h, p["w_dkv"])
+    c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.rms_eps)
+    kr = apply_rope(ckr[:, :, None, r:], cos, sin)[:, :, 0]
+    return q[..., :dn], q_rope, c, kr
+
+
+def _lane_padded(x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """A rotary key (or query) zero-padded to the rotary cache's row width
+    (``cfg.kv_cache_dims``): zeros add nothing to a score."""
+    pad = cfg.kv_cache_dims()[1][1] - x.shape[-1]
+    return x if pad == 0 else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def _w_ukv(p: Params, cfg: ModelConfig):
+    """W_ukv as (W_uk [R, H, dn], W_uv [R, H, dv])."""
+    w = p["w_ukv"].reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+    return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def expanded_attention(q_nope, q_rope, c_win, kr_win, p, cfg: ModelConfig,
+                       positions: jax.Array) -> jax.Array:
+    """Attention of T queries over a window of S cached tokens in the
+    EXPANDED form: every latent becomes a key [H, dn] (+ the shared rotary
+    key) and a value [H, dv]. ``positions`` [B, T]: query t sees keys at
+    index <= positions[b, t]. Returns [B, T, H*dv]."""
+    b, t, hq, _ = q_nope.shape
+    w_uk, w_uv = _w_ukv(p, cfg)
+    k_nope = jnp.einsum("bsr,rhd->bshd", c_win, w_uk)
+    v = jnp.einsum("bsr,rhd->bshd", c_win, w_uv)
+    key_pos = jnp.arange(c_win.shape[1], dtype=jnp.int32)
+
+    def block(qn, qr, pos):  # [B, t', H, .], [B, t']
+        s = jnp.einsum("bthd,bshd->bhts", qn, k_nope, preferred_element_type=jnp.float32)
+        s = s + jnp.einsum("bthd,bsd->bhts", qr, kr_win, preferred_element_type=jnp.float32)
+        s = jnp.where((key_pos[None, None, :] <= pos[:, :, None])[:, None],
+                      s * cfg.attn_scale, jnp.float32(-1e30))
+        pr = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhts,bshd->bthd", pr, v)
+
+    if t <= _Q_BLOCK or t % _Q_BLOCK:
+        out = block(q_nope, q_rope, positions)
+    else:
+        def split(x):  # [B, T, ...] -> [T/blk, B, blk, ...]
+            return jnp.moveaxis(x.reshape((b, t // _Q_BLOCK, _Q_BLOCK) + x.shape[2:]), 1, 0)
+
+        out = jax.lax.map(lambda a: block(*a), (split(q_nope), split(q_rope), split(positions)))
+        out = jnp.moveaxis(out, 0, 1).reshape(b, t, hq, -1)
+    return out.reshape(b, t, -1)
+
+
+def absorbed_queries(q_nope: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
+    """q~_h = W_uk_h q_nope_h: [B, T, H, R], the query in latent space."""
+    return jnp.einsum("bthd,rhd->bthr", q_nope, _w_ukv(p, cfg)[0])
+
+
+def absorbed_output(o_lat: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
+    """o_h = W_uv_h^T (sum_s p_s c_s): [B, T, H, R] -> [B, T, H*dv]."""
+    o = jnp.einsum("bthr,rhd->bthd", o_lat, _w_ukv(p, cfg)[1])
+    return o.reshape(o.shape[0], o.shape[1], -1)
+
+
+# ---------------------------------------------------------------------------
+# forward passes
+# ---------------------------------------------------------------------------
+
+
+def _stacks(params: Params, cfg: ModelConfig):
+    """((stack leaves, first layer index, ffn kind), ...) in model order."""
+    out = []
+    if cfg.n_dense_layers:
+        out.append((params["blocks"]["dense"], 0, "dense"))
+    if cfg.n_layers > cfg.n_dense_layers:
+        out.append((params["blocks"]["moe"], cfg.n_dense_layers, "moe"))
+    return out
+
+
+def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
+    """The embedding copied into all n streams: [n, B, T, d] float32. The
+    streams stay float32 from here to the head (the mixers read and write
+    them in float32 anyway): carried in bf16 they would be rounded 14 times a
+    token in 7 layers, and that noise is what flips a token's 4th and 5th
+    expert against the reference. A sublayer's input is cast to ``cfg.dtype``
+    after its norm, so every product runs as in the other families."""
+    x = params["embed"][tokens].astype(jnp.float32) * cfg.embedding_scale
+    return jnp.broadcast_to(x[None], (cfg.hc_mult,) + x.shape)
+
+
+def _head(params: Params, cfg: ModelConfig, X, logit_positions, t: int) -> jax.Array:
+    """The final norm and head read the SUM of the streams."""
+    from .llama import lm_head_logits
+
+    x = jnp.sum(X, axis=0).astype(jnp.dtype(cfg.dtype))
+    return lm_head_logits(params, cfg, x, logit_positions, t)
+
+
+def _layers(params: Params, cfg: ModelConfig, X, caches, attention, live=None):
+    """Both stacks in model order, each one scan: attention then FFN around
+    the streams. ``attention(h, p, caches, layer) -> (out, caches)`` is the
+    caller's (row caches or pools). Returns (X, caches, the expert layers'
+    counters [n_moe_layers, 3] or None without ``live``)."""
+    stats = None
+    for stack, first, kind in _stacks(params, cfg):
+        def block(carry, inputs, kind=kind):
+            X, caches = carry
+            p, layer = inputs
+            X, caches = _residual(X, p, "attn", cfg, lambda h: attention(h, p, caches, layer))
+            if kind == "dense":
+                X, st = _residual(X, p, "ffn", cfg, lambda h: swiglu(
+                    h, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act))
+            else:
+                X, st = _residual(X, p, "ffn", cfg, lambda h: moe_ffn(h, p, cfg, live))
+            return (X, caches), st
+
+        n = jax.tree.leaves(stack)[0].shape[0]
+        (X, caches), st = jax.lax.scan(
+            block, (X, caches), (stack, first + jnp.arange(n, dtype=jnp.int32)))
+        if kind == "moe":
+            stats = st
+    return X, caches, stats
+
+
+def forward(
+    params: Params, cfg: ModelConfig, tokens: jax.Array,
+    k_cache: jax.Array,  # latents [B, L, 1, S, R]
+    v_cache: jax.Array,  # rotary keys [B, L, 1, S, dr]
+    start_pos: jax.Array, attn_window: int | None = None, mesh=None,
+    ring_slot=None, logit_positions=None, fresh_prefill: bool = False,
+    uniform_start: bool = False,
+):
+    """``models.llama.forward``'s contract over row caches of latents
+    (positional layout only: the family is served on the paged pool, whose
+    programs pass no ``ring_slot``)."""
+    if ring_slot is not None:
+        raise NotImplementedError(
+            "latent-attention models are served on the paged pool (KV_PAGED=1): "
+            "the shared-ring cache layout has no latent form")
+    del mesh, fresh_prefill, uniform_start  # one attention path for every start
+    b, t = tokens.shape
+    s_max = k_cache.shape[3]
+    win = attn_window if (attn_window is not None and attn_window < s_max) else s_max
+    positions = start_pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    cos, sin = rope_tables(cfg, positions)
+    zero = jnp.zeros((), jnp.int32)
+    X = _embed(params, cfg, tokens)
+
+    def attention(h, p, caches, layer):
+        c_all, r_all = caches
+        q_nope, q_rope, c, kr = mla_project(h, p, cfg, cos, sin)
+
+        def write(cache_b, rows_b, s):  # [L, 1, S, W] <- [T, W] at (layer, 0, s)
+            return kv_update_slice(cache_b, rows_b[None, None], (layer, zero, s, zero))
+
+        c_all = jax.vmap(write)(c_all, c, start_pos)
+        r_all = jax.vmap(write)(r_all, _lane_padded(kr, cfg), start_pos)
+
+        def window(cache):
+            w = cache.shape[-1]
+            sl = jax.lax.dynamic_slice(cache, (zero, layer, zero, zero, zero), (b, 1, 1, win, w))
+            return sl[:, 0, 0].astype(h.dtype)
+
+        c_win, kr_win = window(c_all), window(r_all)[..., : cfg.qk_rope_head_dim]
+        if t <= _ABSORB_MAX_T:
+            from ..ops.mla_attention import mla_absorbed_attention
+
+            o = absorbed_output(mla_absorbed_attention(
+                absorbed_queries(q_nope, p, cfg), q_rope, c_win, kr_win,
+                positions, cfg.attn_scale), p, cfg)
+        else:
+            o = expanded_attention(q_nope, q_rope, c_win, kr_win, p, cfg, positions)
+        return mm(o, p["wo"]), (c_all, r_all)
+
+    X, caches, _ = _layers(params, cfg, X, (k_cache, v_cache), attention)
+    return _head(params, cfg, X, logit_positions, t), caches[0], caches[1]
+
+
+def forward_decode_paged(
+    params: Params, cfg: ModelConfig, tokens: jax.Array,
+    k_pool, v_pool,  # latents [NB, L, 1, T, R], rotary keys [NB, L, 1, T, dr]
+    tbl: jax.Array, start_pos: jax.Array, mesh=None,
+):
+    """``models.llama.forward_decode_paged``'s contract: W tokens a slot,
+    written into the pool and then attended over the slot's whole table by
+    the absorbed Pallas kernel. Returns (logits, k_pool, v_pool, moe_stats):
+    ``moe_stats`` int32 [n_moe_layers, 3] counts, over the slots that hold a
+    request (table entry 0 is a real block), the distinct experts hit, the
+    most rows on one expert and the live rows in each expert layer."""
+    from ..ops.mla_attention import mla_paged_decode_attention_auto
+
+    del mesh
+    b, w = tokens.shape
+    positions = start_pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
+    cos, sin = rope_tables(cfg, positions)
+    live = (tbl[:, 0] > 0).astype(jnp.float32)
+    X = _embed(params, cfg, tokens)
+
+    def attention(h, p, pools, layer):
+        cp, rp = pools
+        q_nope, q_rope, c, kr = mla_project(h, p, cfg, cos, sin)
+        cp = kv_pool_write_rows(cp, c[:, :, None], tbl, start_pos, layer)
+        rp = kv_pool_write_rows(rp, _lane_padded(kr, cfg)[:, :, None], tbl, start_pos, layer)
+        o_lat = mla_paged_decode_attention_auto(
+            absorbed_queries(q_nope, p, cfg), _lane_padded(q_rope, cfg), cp, rp, tbl,
+            start_pos, layer, cfg.attn_scale)
+        return mm(absorbed_output(o_lat, p, cfg), p["wo"]), (cp, rp)
+
+    X, pools, stats = _layers(params, cfg, X, (k_pool, v_pool), attention, live)
+    if stats is None:
+        stats = jnp.zeros((0, 3), jnp.int32)
+    return _head(params, cfg, X, None, w), pools[0], pools[1], stats
+
+
+def make_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
+               dtype: str | None = None):
+    """Zeroed (latent, rotary key) row caches [B, L, 1, S, .]."""
+    if cfg.kv_quant == "int8":
+        raise NotImplementedError(
+            "TPU_KV_QUANT=int8 is not implemented for latent-attention models: "
+            "the latent is both key and value, and one scale a row cannot serve both")
+    s = seq_len or cfg.max_seq_len
+    dt = jnp.dtype(dtype or cfg.dtype)
+    return tuple(jnp.zeros((batch, cfg.n_layers, h, s, w), dt)
+                 for h, w in cfg.kv_cache_dims())
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+
+
+def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
+    """Random small-scale init; the tree is what a loader of the family would
+    build (``benchmark/references/mla_moe_mhc.py param_shapes`` names it)."""
+    dt = jnp.dtype(cfg.dtype)
+    keys = iter(jax.random.split(key, 64))
+
+    def rand(*shape):
+        return (jax.random.normal(next(keys), shape, jnp.float32) * 0.02).astype(dt)
+
+    d, hq, n = cfg.d_model, cfg.n_heads, cfg.hc_mult
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rq, rkv, maps = cfg.q_lora_rank, cfg.kv_lora_rank, n * n + 2 * n
+
+    def stack(L: int, ffn: dict) -> Params:
+        out: Params = {
+            "attn_norm": jnp.ones((L, d), dt), "ffn_norm": jnp.ones((L, d), dt),
+            "q_norm": jnp.ones((L, rq), dt), "kv_norm": jnp.ones((L, rkv), dt),
+            "w_dq": rand(L, d, rq), "w_uq": rand(L, rq, hq * (dn + dr)),
+            "w_dkv": rand(L, d, rkv + dr), "w_ukv": rand(L, rkv, hq * (dn + dv)),
+            "wo": rand(L, hq * dv, d),
+        }
+        for which in ("attn", "ffn"):
+            out |= {f"hc_{which}_w": rand(L, n * d, maps), f"hc_{which}_a": rand(L, 3),
+                    f"hc_{which}_b": rand(L, maps)}
+        return out | ffn
+
+    blocks: Params = {}
+    ld, lm = cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers
+    if ld:
+        ff = cfg.d_ff
+        blocks["dense"] = stack(ld, {
+            "w_gate": rand(ld, d, ff), "w_up": rand(ld, d, ff), "w_down": rand(ld, ff, d)})
+    if lm:
+        e, fe, fs = cfg.n_experts, cfg.moe_d_ff, cfg.n_shared_experts * cfg.moe_d_ff
+        blocks["moe"] = stack(lm, {
+            "router": rand(lm, d, e), "e_bias": rand(lm, e),
+            "w_gate_e": rand(lm, e, d, fe), "w_up_e": rand(lm, e, d, fe),
+            "w_down_e": rand(lm, e, fe, d),
+            "w_gate_s": rand(lm, d, fs), "w_up_s": rand(lm, d, fs), "w_down_s": rand(lm, fs, d)})
+    params: Params = {"embed": rand(cfg.vocab_size, d), "out_norm": jnp.ones((d,), dt),
+                      "blocks": blocks}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = rand(d, cfg.vocab_size)
+    return params

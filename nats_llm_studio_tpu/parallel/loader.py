@@ -73,6 +73,11 @@ def load_params_sharded(
     dt = jnp.dtype(dtype or cfg.dtype)
     if quant not in ("none", "int8", "int4"):
         raise ValueError(f"unknown quant mode {quant!r}")
+    if cfg.is_mla:
+        raise NotImplementedError(
+            f"{cfg.arch}: no GGUF tensor-name map for latent-attention models "
+            "yet; the tree to build is models.mla_moe.init_params' (two stacks, "
+            "blocks.dense and blocks.moe), placed by param_sharding_rules")
     rules = param_sharding_rules(mesh, cfg)
 
     def t(name: str) -> np.ndarray:

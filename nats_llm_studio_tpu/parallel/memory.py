@@ -27,6 +27,8 @@ def _leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
         cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
         cfg.d_ff, cfg.n_layers, cfg.vocab_size,
     )
+    if cfg.is_mla:
+        return _mla_leaves(cfg, dtype_bytes)
     out: dict[str, _Leaf] = {
         "embed": _Leaf((V, d), (), dtype_bytes),
         "out_norm": _Leaf((d,), (), dtype_bytes),
@@ -60,6 +62,43 @@ def _leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
             "blocks.w_down": _Leaf((L, ff, d), (1,), dtype_bytes, True),
         }
     return out
+
+
+def _mla_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
+    """The leaves of ``models.mla_moe.init_params``, unsharded (the family
+    serves on one chip a replica); the mixers' small leaves are left out."""
+    d, hq, V = cfg.d_model, cfg.n_heads, cfg.vocab_size
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rq, rkv, maps = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.hc_mult * (cfg.hc_mult + 2)
+    out = {"embed": _Leaf((V, d), (), dtype_bytes),
+           "lm_head": _Leaf((d, V), (), dtype_bytes, True)}
+    stacks = (("dense", cfg.n_dense_layers, {"w_gate": (d, cfg.d_ff), "w_up": (d, cfg.d_ff),
+                                              "w_down": (cfg.d_ff, d)}),
+              ("moe", cfg.n_layers - cfg.n_dense_layers, {
+                  "router": (d, cfg.n_experts),
+                  "w_gate_e": (cfg.n_experts, d, cfg.moe_d_ff),
+                  "w_up_e": (cfg.n_experts, d, cfg.moe_d_ff),
+                  "w_down_e": (cfg.n_experts, cfg.moe_d_ff, d),
+                  "w_gate_s": (d, cfg.n_shared_experts * cfg.moe_d_ff),
+                  "w_up_s": (d, cfg.n_shared_experts * cfg.moe_d_ff),
+                  "w_down_s": (cfg.n_shared_experts * cfg.moe_d_ff, d)}))
+    from ..ops.wquant import quantizable
+
+    for name, L, ffn in stacks:
+        if not L:
+            continue
+        attn = {"w_dq": (d, rq), "w_uq": (rq, hq * (dn + dr)), "w_dkv": (d, rkv + dr),
+                "w_ukv": (rkv, hq * (dn + dv)), "wo": (hq * dv, d),
+                "hc_attn_w": (cfg.hc_mult * d, maps), "hc_ffn_w": (cfg.hc_mult * d, maps)}
+        for k, shape in (attn | ffn).items():
+            out[f"blocks.{name}.{k}"] = _Leaf((L,) + shape, (), dtype_bytes, quantizable(k))
+    return out
+
+
+def kv_token_values(cfg: ModelConfig) -> int:
+    """Cached numbers a token a layer: K and V over the kv heads, or the
+    latent and the rotary key of an MLA cache."""
+    return sum(h * w for h, w in cfg.kv_cache_dims())
 
 
 def estimate_device_bytes(
@@ -121,7 +160,7 @@ def estimate_device_bytes(
             params += n * dtype_bytes
 
     cb = cache_dtype_bytes or dtype_bytes
-    kv = 2 * cfg.n_layers * batch * seq * cfg.n_kv_heads * cfg.head_dim * cb
+    kv = cfg.n_layers * batch * seq * kv_token_values(cfg) * cb
     # dp is served as independent batcher REPLICAS over disjoint device
     # slices (mesh.dp_submeshes): each replica holds its own full-``batch``
     # cache, so per-DEVICE kv bytes do not divide by dp — only the kv-head
@@ -144,6 +183,8 @@ def kv_pool_block_bytes(cfg: ModelConfig, block_tokens: int,
     fallback) — the registry prices the whole pool as blocks x this."""
     quant = (kv_quant if kv_quant is not None else cfg.kv_quant) == "int8"
     dtype_bytes = 4 if cfg.dtype == "float32" else 2
+    if cfg.is_mla:  # never quantized, never split (models/mla_moe.make_cache)
+        return cfg.n_layers * block_tokens * kv_token_values(cfg) * dtype_bytes
     per_pos = (
         cfg.head_dim * (1 if quant else dtype_bytes) + (4 if quant else 0)
     )

@@ -52,6 +52,8 @@ def param_sharding_rules(mesh: Mesh, cfg: ModelConfig | None = None) -> dict[str
     ep = _axis(mesh, AXIS_EP)
     pp = _axis(mesh, AXIS_PP)
     kv = None if cfg is not None and kv_replicated(mesh, cfg) else tp
+    if cfg is not None and cfg.is_mla:
+        return _mla_rules(cfg, ep, pp)
     return {
         "embed": P(None, None),  # replicated: read once per token, cheap
         "out_norm": P(None),
@@ -73,6 +75,25 @@ def param_sharding_rules(mesh: Mesh, cfg: ModelConfig | None = None) -> dict[str
         "blocks.w_up_e": P(pp, ep, None, tp),
         "blocks.w_down_e": P(pp, ep, tp, None),
     }
+
+
+def _mla_rules(cfg: ModelConfig, ep, pp) -> dict[str, P]:
+    """A rule for every leaf ``models.mla_moe.init_params`` makes: the two
+    stacks' layer axis on pp, the routed experts on ep, everything else whole
+    (the family is served on one chip a replica: ``validate_mesh_for_config``
+    refuses a tp split of the latent cache)."""
+    rank = {"attn_norm": 1, "ffn_norm": 1, "q_norm": 1, "kv_norm": 1,
+            "w_dq": 2, "w_uq": 2, "w_dkv": 2, "w_ukv": 2, "wo": 2,
+            "hc_attn_w": 2, "hc_attn_a": 1, "hc_attn_b": 1,
+            "hc_ffn_w": 2, "hc_ffn_a": 1, "hc_ffn_b": 1}
+    dense = rank | {"w_gate": 2, "w_up": 2, "w_down": 2}
+    moe = rank | {"router": 2, "e_bias": 1, "w_gate_s": 2, "w_up_s": 2, "w_down_s": 2}
+    rules = {"embed": P(None, None), "out_norm": P(None), "lm_head": P(None, None)}
+    rules |= {f"blocks.dense.{k}": P(pp, *[None] * r) for k, r in dense.items()}
+    rules |= {f"blocks.moe.{k}": P(pp, *[None] * r) for k, r in moe.items()}
+    rules |= {f"blocks.moe.{k}": P(pp, ep, None, None)
+              for k in ("w_gate_e", "w_up_e", "w_down_e")}
+    return rules
 
 
 def scale_spec(weight_spec: P) -> P:
@@ -216,6 +237,12 @@ def validate_mesh_for_config(mesh: Mesh, cfg: ModelConfig,
         )
     tp = mesh.shape.get(AXIS_TP, 1)
     ep = mesh.shape.get(AXIS_EP, 1)
+    if cfg.is_mla and mesh.size > 1:
+        raise ValueError(
+            f"latent-attention models ({cfg.arch}) serve on one chip a replica "
+            "(MESH_SHAPE=off): the latent cache has one kv head, so there is no "
+            "tp split of it, and the dropless expert layer has no ep exchange yet"
+        )
     # every message names the FULL axis factoring, not just the failing
     # axis — a multi-axis mesh ("dp=2,ep=2,tp=2") read back as bare "tp=2"
     # sends the operator hunting the wrong knob

@@ -306,6 +306,13 @@ class BatcherStats:
     spec_verifies: int = 0  # width-(k+1) verify dispatches
     spec_drafted: int = 0
     spec_accepted: int = 0
+    # routed-expert layers (models/mla_moe.py), summed over decode steps and
+    # expert layers: distinct experts the live rows hit, the most rows on one
+    # expert, the live rows, and how many (step, layer) samples that is
+    experts_hit: int = 0
+    expert_rows_max: int = 0
+    expert_rows: int = 0
+    expert_steps: int = 0
     # bounded log-bucket histograms (obs/histogram.py): O(1) record on the
     # batcher owner thread, O(buckets) snapshot from the asyncio metrics
     # handlers, fixed memory for the life of the worker. Phase deltas come
@@ -477,6 +484,23 @@ class BatcherStats:
             "spec_accept_rate": self.spec_accept_rate,
         }
 
+    def record_moe(self, counters) -> dict[str, int]:
+        """One burst's expert counters ([3 x layers, steps] ints: per layer
+        the experts hit, the most rows on one expert, the live rows). Returns
+        the burst's sums (the readback span carries them)."""
+        c = np.asarray(counters).reshape(-1, 3, counters.shape[-1])
+        burst = {"experts_hit": int(c[:, 0].sum()), "expert_rows_max": int(c[:, 1].sum()),
+                 "expert_rows": int(c[:, 2].sum()), "expert_steps": int(c[:, 0].size)}
+        for k, v in burst.items():
+            setattr(self, k, getattr(self, k) + v)
+        return burst
+
+    def moe_counters(self) -> dict[str, int]:
+        """Expert-layer counters, exposed by serve/worker.py as
+        lmstudio_moe_*_total (all zero for a family without expert layers)."""
+        return {"experts_hit": self.experts_hit, "expert_rows_max": self.expert_rows_max,
+                "expert_rows": self.expert_rows, "expert_steps": self.expert_steps}
+
     def spec_counters(self) -> dict[str, int]:
         """Speculative-decoding counters, exposed by serve/worker.py as the
         dedicated lmstudio_spec_*_total metric families."""
@@ -638,6 +662,24 @@ class ContinuousBatcher:
                 "0", "false", "off"
             )
         self.paged = bool(paged)
+        if cfg.is_mla:
+            # what the latent-attention family does not serve yet, refused
+            # here with its cause and its off-switch, never half-served
+            if not self.paged:
+                raise ValueError(
+                    f"{cfg.arch}: latent-attention models are served on the "
+                    "paged pool only (unset KV_PAGED=0): the shared-ring cache "
+                    "has no latent form")
+            if cfg.kv_quant == "int8":
+                raise ValueError(
+                    f"{cfg.arch}: TPU_KV_QUANT=int8 is not implemented for a "
+                    "latent cache (the latent is key and value at once; one "
+                    "scale a row cannot serve both): unset TPU_KV_QUANT")
+            if kv_tiers is not None:
+                raise ValueError(
+                    f"{cfg.arch}: the host/Object-Store KV tiers spill blocks "
+                    "as KVX1, which holds one shape for keys and values; a "
+                    "latent cache's pair differs: set KV_HOST_POOL_BYTES=0")
         self._pool: BlockPool | None = None
         if self.paged:
             # block size: the requested tokens-per-block snapped down (pow2
@@ -875,6 +917,13 @@ class ContinuousBatcher:
 
             pin_row = pin_cache
 
+        def row_of(c, i):
+            """Row i of a transient row cache as a [1, ...] cache of its own.
+            The two caches of a pair are sliced each by its own shape: K and
+            V alike for GQA, latent and rotary key for MLA."""
+            zero = jnp.zeros((), jnp.int32)
+            return kv_slice(c, (i, zero, zero, zero, zero), (1,) + tuple(c.shape[1:]))
+
         @partial(jax.jit, static_argnums=(6,))
         def prefill1(params, tokens, k1, v1, start, last_pos, window):
             # lm_head at one position only ([1,1,vocab]); non-final chunks
@@ -971,14 +1020,9 @@ class ContinuousBatcher:
                 logits[:, 0], seeds, jnp.zeros((m,), jnp.int32), temps, topks, topps
             )
 
-            lkv, hkv, hd = km.shape[1], km.shape[2], km.shape[4]
-
             def body(carry, i):
                 K, V, tok = carry
-                src_idx = (i, zero, zero, zero, zero)
-                size = (1, lkv, hkv, bucket, hd)
-                k1 = kv_slice(km, src_idx, size)
-                v1 = kv_slice(vm, src_idx, size)
+                k1, v1 = row_of(km, i), row_of(vm, i)
                 K = kv_copy_slice(K, k1, (slots[i], zero, zero, offsets[i], zero))
                 V = kv_copy_slice(V, v1, (slots[i], zero, zero, offsets[i], zero))
                 tok = jax.lax.dynamic_update_slice(
@@ -1060,8 +1104,6 @@ class ContinuousBatcher:
             largest operands here — donating them would spuriously reject
             configs whose real peak fits comfortably."""
             m = final_logits.shape[0]
-            lkv, hkv, hd = km.shape[1], km.shape[2], km.shape[4]
-            s_full = km.shape[3]
             zero = jnp.zeros((), jnp.int32)
             firsts = sample_rows(
                 final_logits[:, 0], seeds, jnp.zeros((m,), jnp.int32),
@@ -1070,11 +1112,8 @@ class ContinuousBatcher:
 
             def body(carry, i):
                 K, V, tok = carry
-                size = (1, lkv, hkv, s_full, hd)
-                k1 = kv_roll_s(kv_slice(km, (i, zero, zero, zero, zero), size),
-                               shifts[i], s_axis=3)
-                v1 = kv_roll_s(kv_slice(vm, (i, zero, zero, zero, zero), size),
-                               shifts[i], s_axis=3)
+                k1 = kv_roll_s(row_of(km, i), shifts[i], s_axis=3)
+                v1 = kv_roll_s(row_of(vm, i), shifts[i], s_axis=3)
                 K = kv_copy_slice(K, k1, (slots[i], zero, zero, zero, zero))
                 V = kv_copy_slice(V, v1, (slots[i], zero, zero, zero, zero))
                 tok = jax.lax.dynamic_update_slice(
@@ -1281,13 +1320,9 @@ class ContinuousBatcher:
                     logits[:, 0], seeds, jnp.zeros((m,), jnp.int32), temps,
                     topks, topps,
                 )
-                lkv, hkv, hd = km.shape[1], km.shape[2], km.shape[4]
-
                 def body(carry, i):
                     KP, VP, tok = carry
-                    size = (1, lkv, hkv, bucket, hd)
-                    k1 = kv_slice(km, (i, zero, zero, zero, zero), size)
-                    v1 = kv_slice(vm, (i, zero, zero, zero, zero), size)
+                    k1, v1 = row_of(km, i), row_of(vm, i)
                     KP = kv_pool_write_row(KP, k1, bids[i])
                     VP = kv_pool_write_row(VP, v1, bids[i])
                     tok = jax.lax.dynamic_update_slice(
@@ -1323,9 +1358,6 @@ class ContinuousBatcher:
                 """Batched chunked tail, paged. km/vm NOT donated — same
                 AOT double-count reasoning as finish_admit_group."""
                 m = final_logits.shape[0]
-                lkv, hkv, hd = km.shape[1], km.shape[2], km.shape[4]
-                s_full = km.shape[3]
-                zero = jnp.zeros((), jnp.int32)
                 firsts = sample_rows(
                     final_logits[:, 0], seeds, jnp.zeros((m,), jnp.int32),
                     temps, topks, topps,
@@ -1333,9 +1365,7 @@ class ContinuousBatcher:
 
                 def body(carry, i):
                     KP, VP, tok = carry
-                    size = (1, lkv, hkv, s_full, hd)
-                    k1 = kv_slice(km, (i, zero, zero, zero, zero), size)
-                    v1 = kv_slice(vm, (i, zero, zero, zero, zero), size)
+                    k1, v1 = row_of(km, i), row_of(vm, i)
                     KP = kv_pool_write_row(KP, k1, bids[i])
                     VP = kv_pool_write_row(VP, v1, bids[i])
                     tok = jax.lax.dynamic_update_slice(
@@ -1480,6 +1510,33 @@ class ContinuousBatcher:
             fwd_paged = partial(forward_decode_paged, cfg=cfg, mesh=mesh)
 
             @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(11,))
+            def decode_pos_moe(params, tok, KP, VP, tbl, pos, seeds,
+                               steps, temp, topk, topp, n):
+                """decode_pos_pallas for a family with routed-expert layers:
+                the same burst, and per step and expert layer the distinct
+                experts hit, the most rows on one expert and the
+                live rows, appended to the token array as 3 x layers rows
+                ([B + 3 Le, n]) so that they come back in the burst's one
+                readback."""
+                def body(carry, i):
+                    tok, KP, VP = carry
+                    logits, KP, VP, st = fwd_paged(
+                        params, tokens=tok[:, None], k_pool=KP, v_pool=VP,
+                        tbl=tbl, start_pos=pos + i, moe_stats=True,
+                    )
+                    nxt = sample_rows(
+                        logits[:, -1, :], seeds, steps + i, temp, topk, topp
+                    )
+                    return (nxt, KP, VP), (nxt, st.reshape(-1))
+
+                (tok, KP, VP), (toks, st) = jax.lax.scan(
+                    body, (tok, KP, VP), jnp.arange(n, dtype=jnp.int32)
+                )
+                out = jnp.concatenate([toks.T, st.T.astype(toks.dtype)], axis=0)
+                return (out, pin_pool(KP), pin_pool(VP), tok, pos + n,
+                        steps + n)
+
+            @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(11,))
             def decode_pos_pallas(params, tok, KP, VP, tbl, pos, seeds,
                                   steps, temp, topk, topp, n):
                 """Pallas decode burst: n single-token paged forwards in one
@@ -1558,7 +1615,10 @@ class ContinuousBatcher:
             )
             self._spec_verify_paged = self._timed("spec_verify_paged", spec_verify_paged)
             self._pool_copy_block = self._timed("pool_copy_block", pool_copy_block)
-            self._decode_pos_pallas = self._timed("decode_pallas", decode_pos_pallas)
+            self._decode_pos_pallas = self._timed(
+                "decode_pallas",
+                decode_pos_moe if cfg.n_moe_layers else decode_pos_pallas,
+            )
             self._decode_pos_pallas_ext = self._timed(
                 "decode_pallas_ext", decode_pos_pallas_ext
             )
@@ -2428,13 +2488,20 @@ class ContinuousBatcher:
             tp = self.mesh.shape.get(AXIS_TP, 1)
         on_tpu = jax.default_backend() == "tpu"
         heads_split = tp <= 1 or cfg.n_kv_heads % tp == 0
+        itemsize = 4 if cfg.dtype == "float32" else 2
         # off-TPU the interpreter runs any layout; Mosaic's tiling rules
         # only bind on the chip
-        eligible = heads_split and (not on_tpu or paged_decode_eligible(
-            self.kv_block_tokens, cfg.head_dim,
-            4 if cfg.dtype == "float32" else 2,
-            cfg.kv_quant == "int8", cfg.n_kv_heads, tp,
-        ))
+        if cfg.is_mla:
+            # the absorbed kernel (ops/mla_attention.py): one latent head
+            from ..ops.mla_attention import mla_paged_decode_eligible
+
+            eligible = tp <= 1 and (not on_tpu or mla_paged_decode_eligible(
+                self.kv_block_tokens, cfg.kv_lora_rank, itemsize))
+        else:
+            eligible = heads_split and (not on_tpu or paged_decode_eligible(
+                self.kv_block_tokens, cfg.head_dim, itemsize,
+                cfg.kv_quant == "int8", cfg.n_kv_heads, tp,
+            ))
         if mode == "auto":
             return "pallas" if (on_tpu and eligible) else "xla"
         if not eligible:
@@ -2509,12 +2576,15 @@ class ContinuousBatcher:
             """The device block pool pair [NB, L, Hkv, T, D] (KVQ under
             int8) — ONE allocation serves live slots, the prefix cache,
             and spec decode; per-slot worst-case rows are gone."""
-            shape = (pool.n_blocks, cfg.n_layers, cfg.n_kv_heads, T,
-                     cfg.head_dim)
             quant = cfg.kv_quant == "int8"
             dt = jnp.float32 if cfg.dtype == "float32" else jnp.bfloat16
-            KP = kv_pool_zeros(shape, dtype=dt, quant=quant)
-            VP = kv_pool_zeros(shape, dtype=dt, quant=quant)
+            # K and V alike for GQA; (latent, rotary key), one head each, for
+            # MLA: nothing below this line looks into the pair
+            KP, VP = (
+                kv_pool_zeros((pool.n_blocks, cfg.n_layers, h, T, width),
+                              dtype=dt, quant=quant)
+                for h, width in cfg.kv_cache_dims()
+            )
             if self.mesh is not None:
                 from ..parallel.sharding import pool_spec, shard_cache
 
@@ -2821,8 +2891,12 @@ class ContinuousBatcher:
             nonlocal tok_dev, dirty
             if rec[0] == "decode":
                 _, toks_ref, n, rows, t_disp = rec
-                with obs_spans.span("batcher.readback", program="decode"):
+                with obs_spans.span("batcher.readback", program="decode") as spn:
                     ids = np.asarray(toks_ref)  # ONE [B, n] readback per burst
+                    if ids.shape[0] > B:
+                        # an expert family's burst: the rows past B are its
+                        # counters (decode_pos_moe)
+                        spn.attrs.update(self.stats.record_moe(ids[B:]))
                 # observed per-step latency (dispatch -> tokens readable);
                 # includes pipeline wait, i.e. what a stream experiences
                 now = time.monotonic()
@@ -5157,13 +5231,16 @@ class ContinuousBatcher:
                     len(req.prompt_ids), self._warm_s(req.t_admit, now))
             if req.trace is not None:
                 req.trace.mark("first_token", now)
+        if req.trace is not None:
+            # request mark for the reply path's worker.publish lag: set
+            # BEFORE the token is handed over, or the loop thread can publish
+            # the chunk while the mark still says the token before it (None
+            # for a stream's first: tests/test_obs_spans.py under load)
+            req.trace.emitted = (time.perf_counter(), req.generated)
         if req.want_logprobs:
             req.emit("tok", (tok_id, logprob, top_ids, top_lps))
         else:
             req.emit("tok", tok_id)
-        if req.trace is not None:
-            # request mark for the reply path's worker.publish lag
-            req.trace.emitted = (time.perf_counter(), req.generated)
         req.emitted.append(int(tok_id))
         if req.generated >= req.sp.max_tokens or req.pos + 1 >= self.max_seq:
             if req.trace is not None:

@@ -96,7 +96,9 @@ def encode_kv_blob(export: dict) -> bytes:
                 raise KVTransferFormatError("mixed dense/kvq leaves in one export")
             if list(q.shape) != header["k_shape"]:
                 raise KVTransferFormatError(
-                    f"ragged chunk shape {q.shape} vs {header['k_shape']}"
+                    f"ragged chunk shape {q.shape} vs {header['k_shape']} (KVX1 "
+                    "holds ONE shape for keys and values: a latent (MLA) "
+                    "cache's pair of latent and rotary key is not exportable)"
                 )
             body += q.tobytes()
             if s is not None:

@@ -73,12 +73,9 @@ def prefix_block_bytes(cfg, chunk: int, kv_quant: str | None = None,
     ``tp`` is the tensor-parallel factor actually sharding the block's head
     axis (1 under the replicated-KV GQA fallback) — blocks live split
     across the mesh, so each chip holds 1/tp of the KV bytes."""
-    quant = (kv_quant if kv_quant is not None else cfg.kv_quant) == "int8"
-    dtype_bytes = 4 if cfg.dtype == "float32" else 2
-    per_pos = (
-        cfg.head_dim * (1 if quant else dtype_bytes) + (4 if quant else 0)
-    )
-    kv = 2 * cfg.n_layers * cfg.n_kv_heads * chunk * per_pos // max(1, tp)
+    from ..parallel.memory import kv_pool_block_bytes
+
+    kv = kv_pool_block_bytes(cfg, chunk, kv_quant=kv_quant, tp=tp)
     return kv + 4 * cfg.vocab_size  # + [1, 1, vocab] f32 end-logits
 
 

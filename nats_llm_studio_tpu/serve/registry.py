@@ -253,7 +253,10 @@ class JaxChatEngine(ChatEngine):
         return SamplingParams(
             temperature=float(payload.get("temperature", 0.8)),
             top_p=float(payload.get("top_p", 1.0)),
-            top_k=int(payload.get("top_k", 0)),
+            # DEFAULT_TOP_K: what a request that names no top_k samples from
+            # (0 = the whole vocabulary; llama.cpp, which LM Studio runs,
+            # defaults to 40)
+            top_k=int(payload.get("top_k", os.environ.get("DEFAULT_TOP_K") or 0)),
             max_tokens=int(payload.get("max_tokens") or payload.get("max_completion_tokens") or 256),
             seed=payload.get("seed"),
             stop_ids=self._stop_ids,
@@ -1301,8 +1304,12 @@ class LocalRegistry(Registry):
             # prefill TTFT. Under tp the kernels are shard_mapped over heads
             # (models/llama.py _on_mesh), which needs whole GQA groups per
             # shard; the replicated-KV fallback prefills on the XLA path
+            # (a latent-attention model prefills through XLA attention in
+            # query blocks, models/mla_moe.py: no flash kernel at its widths
+            # yet, so none of the idle-engine single-dispatch shortcuts)
             use_flash_attention=(
                 jax.default_backend() == "tpu" and self._kv_tp(cfg) == tp
+                and not cfg.is_mla
             ),
             use_routed_moe=True,  # sparse dispatch (parallel/moe.py)
             kv_quant=self.kv_quant,
@@ -1391,6 +1398,13 @@ class LocalRegistry(Registry):
                 and b.paged
                 and b.prefix_cache is not None
             ):
+                if cfg.is_mla:
+                    b.stop()
+                    raise ValueError(
+                        f"{cfg.arch}: the host/Object-Store KV tiers spill "
+                        "blocks as KVX1, which holds one shape for keys and "
+                        "values; a latent cache's pair differs: set "
+                        "KV_HOST_POOL_BYTES=0 to serve this model")
                 from .kv_tiers import KVTierManager
 
                 spill = None

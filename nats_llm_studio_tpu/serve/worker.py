@@ -1881,6 +1881,14 @@ class Worker:
                 # rides the generic histograms() loop below
                 for name, v in stats.spec_counters().items():
                     r.counter(f"lmstudio_spec_{name}_total", v, labels=labels)
+            moe = getattr(stats, "moe_counters", None)
+            if moe is not None and moe()["expert_steps"]:
+                # routed-expert layers (models/mla_moe.py), summed over
+                # decode steps x expert layers: hit / steps is the experts a
+                # step reads if it reads only those hit, rows_max / steps
+                # against rows x k / experts is the imbalance
+                for name, v in moe().items():
+                    r.counter(f"lmstudio_moe_{name}_total", v, labels=labels)
             tier_fn = getattr(rb, "tier_stats", None)
             tier = tier_fn() if tier_fn is not None else None
             if tier:

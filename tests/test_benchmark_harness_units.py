@@ -1,0 +1,7 @@
+"""The benchmark's quick unit tests under tier 1: file discovery by name, the
+trace reduction against its recorded fixture, the traffic generator (see
+``test_benchmark_harness.py``; no two of the three share a name)."""
+
+from benchmark.tests.test_discovery import *  # noqa: F401,F403
+from benchmark.tests.test_reduce_trace import *  # noqa: F401,F403
+from benchmark.tests.test_traffic import *  # noqa: F401,F403

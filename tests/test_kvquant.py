@@ -136,7 +136,10 @@ async def test_ring_compaction_quantized():
                 got_long.append(t)
 
         async def run_short_late():
-            while len(got_long) < 220:
+            # late enough that the short stream is live when the ring wraps
+            # at 256, early enough that a loaded machine (tier 1 runs six
+            # workers) admits it before the long stream's 248th token
+            while len(got_long) < 200:
                 await asyncio.sleep(0.002)
             sp = SamplingParams(temperature=0.0, max_tokens=60)
             async for t in b.submit([4, 5, 6, 7], sp):

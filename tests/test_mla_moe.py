@@ -1,0 +1,300 @@
+"""The latent-attention / routed-expert / residual-stream family
+(``models/mla_moe.py``, ``ops/mla_attention.py``) against its plain reference
+(``benchmark/references/mla_moe_mhc.py``) on seeded weights, at toy size on
+the CPU: logits, not tokens. The served side is driven the way the batcher
+drives it: ``models.llama.forward`` prefill (whole, or in three chunks) into a
+row cache of latents, scattered into a pool through a slot's table, then
+``forward_decode_paged`` steps (the absorbed Pallas kernel in interpreter
+mode) over a table that opens new blocks. Faults put in on purpose must each
+fail the toy limits."""
+
+import json
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import run
+from benchmark.lib import correct, weights
+from nats_llm_studio_tpu.models import llama, mla_moe
+from nats_llm_studio_tpu.models.config import ModelConfig
+
+ROOT = Path(__file__).resolve().parents[1]
+CONF = json.loads((ROOT / "benchmark/tests/rehearsal/configs/tiny-mla.json").read_text())
+REF = run.load_module(ROOT / "benchmark/references/mla_moe_mhc.py")
+
+T, SEQ = 16, 128            # pool block tokens; a slot's table spans SEQ
+PROMPT = 40                 # not a multiple of T; 24 decoded steps open blocks 3 and 4
+STEPS = 24
+TABLE = [3, 5, 2, 7, 1, 4, 6, 8]
+# float32 through three toy layers: the sound path agrees to ~1e-5, so the
+# limits sit three orders above it and every fault far above them
+TOY_FIRST = {"median_tol": 0.02, "token_tol": 0.05}
+TOY_DECODED = {"median_tol": 0.02, "token_tol": 0.05, "gap_tol": 0.05}
+
+
+@pytest.fixture(scope="module")
+def model():
+    mp = pytest.MonkeyPatch()
+    mp.setattr(weights, "INIT_STD", 0.125)   # N(0, 0.02) adds nothing at d 64
+    try:
+        from nats_llm_studio_tpu.parallel.mesh import build_mesh
+
+        cfg = REF.model_config(CONF, SEQ).with_(dtype="float32")
+        mesh = build_mesh({"tp": 1}, devices=jax.local_devices()[:1])
+        # the schema is the reference's param_shapes, the gains its
+        # weight_gains, the placement the program's rule for every leaf
+        yield cfg, weights.make_seeded_params(4321, REF)(None, cfg, mesh)
+    finally:
+        mp.undo()
+
+
+@pytest.fixture(scope="module")
+def prompt():
+    return [int(t) for t in np.random.default_rng(1).integers(32, 127, size=PROMPT)]
+
+
+def entry(logits) -> dict:
+    lp = np.asarray(jax.nn.log_softmax(jnp.asarray(logits, jnp.float32)))
+
+    def one(i):
+        return {"token": chr(int(i)), "bytes": [int(i)], "logprob": float(lp[i])}
+
+    return dict(one(int(np.argmax(lp))),
+                top_logprobs=[one(i) for i in np.argsort(-lp)[:correct.TOP_K]])
+
+
+def serve(cfg, params, prompt, n, chunks=(PROMPT,)):
+    """Prefill ``prompt`` in ``chunks`` into a row cache of latents, scatter
+    it into the pool through the slot's table, decode n-1 greedy tokens."""
+    from nats_llm_studio_tpu.ops.kvcache import kv_pool_scatter_view, kv_pool_zeros
+
+    tbl = jnp.asarray([TABLE], jnp.int32)
+    k, v = llama.make_cache(cfg, 1, SEQ)
+    fwd = jax.jit(lambda tok, k, v, start: llama.forward(
+        params, cfg, tok, k, v, start, uniform_start=True))
+    at = 0
+    for c in chunks:
+        logits, k, v = fwd(jnp.asarray([prompt[at: at + c]], jnp.int32), k, v,
+                           jnp.asarray([at], jnp.int32))
+        at += c
+    pools = [kv_pool_zeros((1 + 2 * len(TABLE), cfg.n_layers, h, T, w), jnp.dtype(cfg.dtype))
+             for h, w in cfg.kv_cache_dims()]
+    vb = jnp.asarray([list(range(len(TABLE)))], jnp.int32)
+    kp, vp = (kv_pool_scatter_view(p, c, tbl, vb) for p, c in zip(pools, (k, v)))
+    entries = [entry(logits[0, -1])]
+    step = jax.jit(lambda tok, kp, vp, pos: llama.forward_decode_paged(
+        params, cfg, tok, kp, vp, tbl, pos))
+    for pos in range(len(prompt), len(prompt) + n - 1):
+        tok = jnp.asarray([[entries[-1]["bytes"][0]]], jnp.int32)
+        logits, kp, vp = step(tok, kp, vp, jnp.asarray([pos], jnp.int32))
+        entries.append(entry(logits[0, -1]))
+    return entries
+
+
+def check(params, prompt, entries) -> dict:
+    toks = correct.served_tokens(entries)
+    ref = REF.tail_logprobs(params, CONF, list(prompt) + toks[:-1], len(toks))
+    return correct.compare_probes([(ref, entries)], TOY_FIRST, TOY_DECODED)
+
+
+def test_prefill_then_24_paged_decode_steps_agree_with_the_reference(model, prompt):
+    cfg, params = model
+    out = check(params, prompt, serve(cfg, params, prompt, STEPS + 1))
+    assert out["ok"] and out["first_ok"] and out["decoded"]["ok"], out
+    assert out["decoded"]["positions"] == STEPS
+    assert out["decoded"]["max_abs_diff"] < 1e-3, out
+
+
+def test_a_prompt_prefilled_in_three_chunks_agrees_with_the_reference(model, prompt):
+    """Chunks two and three expand latents read back from the row cache."""
+    cfg, params = model
+    out = check(params, prompt, serve(cfg, params, prompt, 4, chunks=(17, 17, 6)))
+    assert out["ok"] and out["first_ok"] and out["decoded"]["ok"], out
+
+
+def _unshared_rotary_key(h, p, cfg, cos, sin):
+    """mla_project with head h's rotary query turned h positions on: what
+    the scores see if the rotary key were rotated per head, not shared."""
+    q_nope, q_rope, c, kr = SOUND_PROJECT(h, p, cfg, cos, sin)
+    heads = jnp.arange(cfg.n_heads, dtype=jnp.int32)[None]
+    hc, hs = mla_moe.rope_tables(cfg, jnp.broadcast_to(heads, q_rope.shape[:1] + heads.shape[1:]))
+    turned = jnp.swapaxes(mla_moe.apply_rope(jnp.swapaxes(q_rope, 1, 2), hc, hs), 1, 2)
+    return q_nope, turned, c, kr
+
+
+def _latent_before_its_norm(h, p, cfg, cos, sin):
+    q_nope, q_rope, _, kr = SOUND_PROJECT(h, p, cfg, cos, sin)
+    return q_nope, q_rope, mla_moe.mm(h, p["w_dkv"])[..., : cfg.kv_lora_rank], kr
+
+
+SOUND_PROJECT = mla_moe.mla_project
+
+
+def _zeroed(params, leaf):
+    moe = dict(params["blocks"]["moe"])
+    moe[leaf] = jnp.zeros_like(moe[leaf])
+    return dict(params, blocks=dict(params["blocks"], moe=moe))
+
+
+FAULTS = {
+    "shared expert left out": dict(params=lambda p: _zeroed(p, "w_down_s")),
+    "selection bias ignored": dict(params=lambda p: _zeroed(p, "e_bias")),
+    "routed_scaling_factor dropped": dict(cfg=dict(routed_scaling=1.0)),
+    "Sinkhorn skipped": dict(cfg=dict(hc_sinkhorn_iters=0)),
+    "k_r rotated per head instead of shared": dict(project=_unshared_rotary_key),
+    "YaRN left out": dict(cfg=dict(rope_factor=1.0)),
+    "the latent cached before its norm": dict(project=_latent_before_its_norm),
+}
+
+
+@pytest.mark.parametrize("name", list(FAULTS))
+def test_a_fault_put_in_on_purpose_fails_the_toy_limits(model, prompt, name, monkeypatch):
+    cfg, params = model
+    how = FAULTS[name]
+    if "project" in how:
+        monkeypatch.setattr(mla_moe, "mla_project", how["project"])
+    served = serve(cfg.with_(**how.get("cfg", {})), how.get("params", lambda p: p)(params),
+                   prompt, 6)
+    out = check(params, prompt, served)
+    d = out["decoded"]
+    worst = max(d["median_abs_diff"] / d["median_tolerance"],
+                d["max_abs_diff"] / d["token_tolerance"])
+    assert not out["ok"] and worst > 5, (name, out)
+    print(f"\n{name}: decoded median {d['median_abs_diff']:.3f}, max {d['max_abs_diff']:.3f}")
+
+
+def test_every_row_on_the_same_experts_is_held_to_the_reference(model, prompt):
+    """Dropless: a selection bias that sends every row to experts 0 and 1
+    (any capacity factor would drop most of them) changes nothing about the
+    agreement, and the decode counters say two experts, every row on each."""
+    cfg, params = model
+    moe = dict(params["blocks"]["moe"])
+    moe["e_bias"] = jnp.zeros_like(moe["e_bias"]).at[:, :2].set(100.0)
+    crowded = dict(params, blocks=dict(params["blocks"], moe=moe))
+    out = check(crowded, prompt, serve(cfg.with_(moe_capacity_factor=0.01), crowded, prompt, 4))
+    assert out["ok"] and out["decoded"]["max_abs_diff"] < 1e-3, out
+    pools = [jnp.zeros((4, cfg.n_layers, h, T, w), jnp.float32) for h, w in cfg.kv_cache_dims()]
+    tbl = jnp.asarray([[1, 0], [2, 0], [3, 0], [0, 0]], jnp.int32)   # the fourth slot is empty
+    *_, stats = jax.jit(lambda: llama.forward_decode_paged(
+        crowded, cfg, jnp.ones((4, 1), jnp.int32), *pools, tbl, jnp.zeros((4,), jnp.int32),
+        moe_stats=True))()
+    assert np.asarray(stats).tolist() == [[2, 3, 3]] * cfg.n_moe_layers
+
+
+def test_absorbed_attention_is_expanded_attention():
+    cfg = REF.model_config(CONF, SEQ).with_(dtype="float32")
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    b, t, s, hq = 2, 5, 48, cfg.n_heads
+    q_nope = jax.random.normal(ks[0], (b, t, hq, cfg.qk_nope_head_dim))
+    q_rope = jax.random.normal(ks[1], (b, t, hq, cfg.qk_rope_head_dim))
+    c_win = jax.random.normal(ks[2], (b, s, cfg.kv_lora_rank))
+    kr_win = jax.random.normal(ks[3], (b, s, cfg.qk_rope_head_dim))
+    p = {"w_ukv": jax.random.normal(ks[4], (cfg.kv_lora_rank, hq * (
+        cfg.qk_nope_head_dim + cfg.v_head_dim))) * 0.1}
+    positions = jnp.asarray([[20, 21, 22, 23, 24], [40, 41, 42, 43, 44]], jnp.int32)
+    from nats_llm_studio_tpu.ops.mla_attention import mla_absorbed_attention
+
+    with jax.default_matmul_precision("highest"):
+        want = mla_moe.expanded_attention(q_nope, q_rope, c_win, kr_win, p, cfg, positions)
+        got = mla_moe.absorbed_output(mla_absorbed_attention(
+            mla_moe.absorbed_queries(q_nope, p, cfg), q_rope, c_win, kr_win, positions,
+            cfg.attn_scale), p, cfg)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("w", [1, 3])
+def test_the_pallas_kernel_is_the_xla_path_at_the_cells_widths_ratio(w):
+    """32 heads over one latent head, latent : rotary key = 8 : 1, ragged
+    contexts, a table in no order of the pool, an empty slot."""
+    from nats_llm_studio_tpu.ops.mla_attention import (
+        mla_absorbed_attention, mla_paged_decode_attention)
+
+    b, hq, r, dr, t, nb, layers = 3, 32, 128, 16, 16, 8, 2
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    qt = jax.random.normal(ks[0], (b, w, hq, r), jnp.float32)
+    qr = jax.random.normal(ks[1], (b, w, hq, dr), jnp.float32)
+    c_pool = jax.random.normal(ks[2], (1 + b * nb, layers, 1, t, r), jnp.float32)
+    r_pool = jax.random.normal(ks[3], (1 + b * nb, layers, 1, t, dr), jnp.float32)
+    tbl = np.zeros((b, nb), np.int32)
+    tbl[0] = np.random.default_rng(0).permutation(np.arange(1, 1 + nb))
+    tbl[1, :3] = [20, 11, 17]
+    pos = jnp.asarray([100, 37, 0], jnp.int32)
+    got = mla_paged_decode_attention(qt, qr, c_pool, r_pool, jnp.asarray(tbl), pos, 1, 0.11,
+                                     interpret=True)
+    view = lambda pool: pool[jnp.asarray(tbl), 1, 0].reshape(b, nb * t, -1)  # noqa: E731
+    positions = pos[:, None] + jnp.arange(w)[None]
+    want = mla_absorbed_attention(qt, qr, view(c_pool), view(r_pool), positions, 0.11)
+    np.testing.assert_allclose(np.asarray(got[:2]), np.asarray(want[:2]), rtol=2e-4, atol=2e-4)
+
+
+def test_yarn_is_its_closed_form_below_and_above_the_original_context():
+    hf = json.loads((ROOT / "benchmark/configs/xing4.0-29b-a4b.json").read_text())
+    cfg = REF.model_config(hf, 4096)
+    inv = mla_moe.yarn_inv_freq(cfg)
+    i = np.arange(32)
+    plain = 10000.0 ** (-2 * i / 64)
+    # the correction range of beta_fast 32 / beta_slow 1 over 4,096 positions:
+    # dims below 11 turn too fast to need scaling, dims from 23 on take all of it
+    np.testing.assert_allclose(inv[:11], plain[:11], rtol=1e-6)
+    np.testing.assert_allclose(inv[23:], plain[23:] / 64, rtol=1e-6)
+    ramp = (i[11:23] - 10) / (23 - 10)
+    np.testing.assert_allclose(inv[11:23], plain[11:23] / 64 * ramp + plain[11:23] * (1 - ramp),
+                               rtol=1e-6)
+    np.testing.assert_allclose(inv, REF.yarn_inv_freq(hf), rtol=1e-6)
+    for pos in (17, 4095, 4097, 200_000):
+        cos, sin = mla_moe.rope_tables(cfg, jnp.asarray([pos], jnp.int32))
+        np.testing.assert_allclose(np.asarray(cos[0]), np.cos(pos * inv.astype(np.float64)), atol=2e-2)
+        np.testing.assert_allclose(np.asarray(sin[0]), np.sin(pos * inv.astype(np.float64)), atol=2e-2)
+    assert abs(cfg.attn_scale - 192 ** -0.5 * (0.1 * np.log(64) + 1) ** 2) < 1e-9
+
+
+def test_the_metadata_round_trip_keeps_the_family():
+    from nats_llm_studio_tpu.models.export import config_metadata
+
+    hf = json.loads((ROOT / "benchmark/configs/xing4.0-29b-a4b.json").read_text())
+    cfg = REF.model_config(hf, 4096)
+    back = ModelConfig.from_gguf_metadata(config_metadata(cfg, "m")).with_(dtype=cfg.dtype)
+    for name in cfg.__dataclass_fields__:
+        a, b = getattr(cfg, name), getattr(back, name)
+        assert a == pytest.approx(b, rel=1e-6) if isinstance(a, float) else a == b, name
+    assert back.is_mla and back.n_moe_layers == 6
+    assert back.kv_cache_dims() == ((1, 512), (1, 128))   # the rotary key's rows lane-padded
+
+
+@pytest.mark.parametrize("how,cause", [
+    (dict(paged=False), "paged pool only"),
+    (dict(cfg=dict(kv_quant="int8")), "TPU_KV_QUANT=int8 is not implemented for a latent cache"),
+    (dict(kv_tiers=object()), "set KV_HOST_POOL_BYTES=0"),
+])
+def test_what_the_family_does_not_serve_is_refused_with_its_cause(model, how, cause):
+    from nats_llm_studio_tpu.serve.batcher import ContinuousBatcher
+
+    cfg, params = model
+    with pytest.raises(ValueError, match=cause):
+        ContinuousBatcher(params, cfg.with_(**how.get("cfg", {})), max_slots=2,
+                          **{k: v for k, v in how.items() if k != "cfg"})
+
+
+def test_a_mesh_of_more_than_one_chip_is_refused_with_its_cause(model):
+    from nats_llm_studio_tpu.parallel.mesh import build_mesh
+    from nats_llm_studio_tpu.parallel.sharding import validate_mesh_for_config
+
+    cfg, _ = model
+    with pytest.raises(ValueError, match="serve on one chip a replica"):
+        validate_mesh_for_config(build_mesh({"tp": 2}, devices=jax.local_devices()[:2]), cfg)
+
+
+def test_a_bursts_expert_counters_are_summed_and_named():
+    from nats_llm_studio_tpu.serve.batcher import BatcherStats
+
+    st = BatcherStats()
+    # two expert layers x (hit, rows max, live rows), three steps
+    burst = st.record_moe(np.asarray([[5, 6, 7], [2, 2, 3], [4, 4, 4],
+                                      [8, 8, 8], [1, 1, 1], [4, 4, 4]]))
+    assert burst == {"experts_hit": 42, "expert_rows_max": 10, "expert_rows": 24,
+                     "expert_steps": 6}
+    assert st.moe_counters() == burst and st.record_moe(np.zeros((3, 2), int))["expert_steps"] == 2
+    assert st.expert_steps == 8

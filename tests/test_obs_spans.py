@@ -48,6 +48,23 @@ async def _stream_chat(nc, model: str, text: str, max_tokens: int) -> int:
     return chunks
 
 
+async def _settled(batcher, timeout: float = 20.0) -> None:
+    """Wait until the owner thread holds nothing (no slot, no waiter: it then
+    blocks on its inbox and opens no span) and the ring has stopped growing.
+    A reply reaches the client BEFORE the spans around it close: the burst the
+    depth-2 pipeline had in flight when the stream ended is still read back
+    and delivered, and the last ``worker.publish`` ends after its message is
+    out. On a loaded machine that is tens of milliseconds."""
+    end, seen = time.monotonic() + timeout, -1
+    while time.monotonic() < end:
+        await asyncio.sleep(0.05)
+        n = len(spans.records())
+        if batcher.idle and n == seen:
+            return
+        seen = n
+    raise AssertionError("the owner thread did not come to rest")
+
+
 @async_test
 async def test_one_streamed_request_records_every_span_in_loop_order(tmp_path):
     src = tmp_path / "tiny.gguf"
@@ -60,8 +77,11 @@ async def test_one_streamed_request_records_every_span_in_loop_order(tmp_path):
     nc = await connect(broker.url)
     try:
         await _stream_chat(nc, "acme/tiny-spans", "warm", 3)  # load + compile
+        batcher = worker.registry.loaded_engines()["acme/tiny-spans"].batcher
+        await _settled(batcher)  # the warm request's last spans are in the ring
         spans.clear()
         chunks = await _stream_chat(nc, "acme/tiny-spans", "stream me", 12)
+        await _settled(batcher)  # ... and this request's
         recs = spans.records()
     finally:
         await nc.close()

@@ -215,3 +215,29 @@ def test_paged_decode_shard_map_tp4(tp4, no_cache):
     per_dev = compiled.memory_analysis().argument_size_in_bytes
     assert per_dev < 0.3 * 2 * POOL_BLOCKS * L * HKV * T * D * 2
     assert "all-gather" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("w", [1, SPEC_W], ids=["decode", "spec_verify"])
+def test_mla_paged_decode_at_the_benchmark_cells_shapes(one_chip, no_cache, w):
+    """Xing4.0-29B-A4B as ``xing29b.answer_closed`` serves it: 8 slots, 32
+    query heads over ONE latent head of 512 + a rotary key of 64 in rows
+    padded to the 128 lanes (a copy out of the pool cannot slice inside a lane
+    tile: 64-wide rows are refused), MAX_SEQ_LEN 4096 = table width 256, a
+    7-layer pool pair."""
+    from nats_llm_studio_tpu.ops.mla_attention import (
+        mla_paged_decode_attention,
+        mla_paged_decode_eligible,
+    )
+
+    layers, width, r, dr = 7, SEQ // T, 512, 128
+    assert mla_paged_decode_eligible(T, r, 2)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    blocks = SLOTS * width + 64 + 1
+    _compile(
+        lambda qt, qr, cp, rp, tbl, pos, layer: mla_paged_decode_attention(
+            qt, qr, cp, rp, tbl, pos, layer, 0.1447),
+        sds((SLOTS, w, HQ, r), jnp.bfloat16), sds((SLOTS, w, HQ, dr), jnp.bfloat16),
+        sds((blocks, layers, 1, T, r), jnp.bfloat16),
+        sds((blocks, layers, 1, T, dr), jnp.bfloat16),
+        sds((SLOTS, width), jnp.int32), sds((SLOTS,), jnp.int32), sds((), jnp.int32),
+    )
