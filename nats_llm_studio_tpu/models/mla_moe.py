@@ -22,12 +22,18 @@ table and the sampling are the ones every other family uses. What differs:
   the output, and the scores read the latents themselves
   (``ops/mla_attention.py``: a Pallas kernel over the pool and the table, or
   the XLA form over a gathered view).
-* **Experts are dropless.** Dense dispatch: every expert computes every row,
-  weighted by the gate (0 for experts a row did not pick), a group of experts
-  at a time so the [rows, experts, width] intermediates stay small.
-  ``moe_capacity_factor`` is not read. The decode path also counts, per layer,
-  the distinct experts the live rows hit and the most rows on one expert: the
-  yardstick for a grouped matmul that reads only the experts hit.
+* **Experts are dropless.** A call with fewer (row, expert) picks than the
+  layer has experts (a decode step: 8 rows x top-4 under 64) lists on the
+  device the experts its live rows hit and reads those alone, each once, out
+  of the whole stacks indexed by (layer, expert): ``ops/moe_experts.py``.
+  Every other call (a prefill chunk, a verify bundle, quantised expert
+  stacks, a mesh of several chips) is dense dispatch: every expert computes
+  every row, weighted by the gate (0 for experts a row did not pick), a group
+  of experts at a time so the [rows, experts, width] intermediates stay
+  small. ``expert_path`` chooses, from shapes and leaf types alone.
+  ``moe_capacity_factor`` is not read. The decode path also counts, per
+  layer, the distinct experts the live rows hit (the list's length: what the
+  step streams) and the most rows on one expert.
 * **The residual is n streams** (``hc_mult``): ``X <- H_res X + H_post^T f(norm(H_pre X))``
   with the three maps made from the streams themselves, ``H_res`` projected
   onto doubly stochastic matrices by Sinkhorn rounds (rows first).
@@ -45,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import sinkhorn
 from ..ops.kvcache import kv_pool_write_rows, kv_update_slice
 from ..ops.layers import apply_rope, rms_norm, swiglu
 from ..ops.wquant import mm, q_einsum
@@ -115,9 +122,10 @@ def hc_maps(X: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array, cfg: ModelCo
     """The three maps of one mixer from the streams X [n, B, T, d]: ``pre``
     and ``post`` [n, B, T], ``res`` [n, n, B, T] (row i = what new stream i
     takes of each old stream). The stream axes lead, so nothing is laid out
-    4 wide and a row or column sum adds whole [B, T] planes; the Sinkhorn
-    rounds are one ``fori_loop`` (unrolled they are 20 x the program text of
-    every mixer, and half a minute of compile each)."""
+    4 wide and a row or column sum adds whole [B, T] planes. The Sinkhorn
+    rounds are one kernel where the rows fit a lane tile (``ops/sinkhorn.py``:
+    decode) and one ``fori_loop`` otherwise (unrolled they are 20 x the
+    program text of every mixer, and half a minute of compile each)."""
     n = cfg.hc_mult
     xf = X.astype(jnp.float32)
     rrms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=(0, 3), keepdims=True) + cfg.rms_eps)
@@ -131,11 +139,20 @@ def hc_maps(X: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array, cfg: ModelCo
                            cfg.hc_res_clamp_min, cfg.hc_res_clamp_max))
     res = res.reshape((n, n) + res.shape[1:])
 
-    def sinkhorn(_, r):  # rows first
+    def round_(_, r):  # rows first
         r = r / (jnp.sum(r, axis=1, keepdims=True) + cfg.hc_eps)
         return r / (jnp.sum(r, axis=0, keepdims=True) + cfg.hc_eps)
 
-    return pre, post, jax.lax.fori_loop(0, cfg.hc_sinkhorn_iters, sinkhorn, res)
+    rows = X.shape[1] * X.shape[2]
+    if rows <= sinkhorn.LANES:
+        # a decode step, a verify bundle: the rounds in one kernel, where the
+        # loop below is 6 launches a round of a few hundred numbers each
+        res = sinkhorn.sinkhorn_rounds(
+            res.reshape(n, n, rows), cfg.hc_sinkhorn_iters, cfg.hc_eps,
+            interpret=jax.default_backend() != "tpu").reshape(res.shape)
+    else:
+        res = jax.lax.fori_loop(0, cfg.hc_sinkhorn_iters, round_, res)
+    return pre, post, res
 
 
 def hc_read(X: jax.Array, pre: jax.Array) -> jax.Array:
@@ -179,36 +196,74 @@ def route(h: jax.Array, p: Params, cfg: ModelConfig):
     return idx, gate
 
 
-def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = None):
+_EXPERT_LEAVES = ("w_gate_e", "w_up_e", "w_down_e")
+
+
+def expert_path(cfg: ModelConfig, rows: int, stack: Params, mesh=None) -> str:
+    """``"hit_list"`` or ``"dense"``: the form the routed experts of a call of
+    ``rows`` rows take, from what the call can see and nothing else. The hit
+    list needs fewer (row, expert) picks than the layer has experts (a decode
+    step of 8 rows x top-4 under 64; a prefill chunk or a verify bundle is
+    over it, and reads every expert anyway), plain expert leaves and one
+    device. Quantised stacks (``WQUANT`` makes ``w_*_e`` QTensors) and meshes
+    of more than one chip keep the dense dispatch until a cell measures
+    them."""
+    few = rows * cfg.n_experts_used < cfg.n_experts
+    plain = all(isinstance(stack[k], jax.Array) for k in _EXPERT_LEAVES)
+    one_device = mesh is None or mesh.size == 1
+    return "hit_list" if few and plain and one_device else "dense"
+
+
+def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = None,
+            stacks=None, place=None):
     """Routed experts + the shared expert(s), dropless. Returns (y, stats):
     ``stats`` is int32 [3] = (distinct experts the ``live`` rows hit, most
     rows on one expert, live rows) when ``live`` [B] is given, else None.
 
-    Dense dispatch in groups of experts, the groups STATIC slices of the
-    layer's expert stacks: a slice of a scan's slice still fuses into the
+    **Hit list** (``stacks`` = the three WHOLE expert stacks [L, E, ., .],
+    ``place`` this layer's place in them; ``expert_path`` says when): the experts the
+    live rows hit are listed on the device and only those are read, each
+    once, every row gated by its own weight on that expert (0 for a row that
+    did not pick it) into a float32 sum that starts from the shared expert's
+    output: the dense dispatch's sums without the terms that are exactly
+    zero. A row of a slot that holds no request adds nothing to the list; its
+    output is whatever the listed experts give it, and the batcher discards
+    it.
+
+    **Dense dispatch** (``p`` holds the layer's own expert leaves): every
+    expert computes every row, in groups of experts that are STATIC slices of
+    the layer's stacks: a slice of a scan's slice still fuses into the
     product that reads it, where an inner ``lax.scan`` over the groups made
     XLA copy a layer's 1.4 GB of experts into the loop's operand every step
-    (46 ms a decode step for 15: PERF.md, PR 29). A decode step is one
-    group; a prefill splits so that [rows, group, width] stays under
-    ``_EXPERT_ACT_BYTES``."""
+    (46 ms a decode step for 15: PERF.md, PR 29). A prefill splits so that
+    [rows, group, width] stays under ``_EXPERT_ACT_BYTES``."""
     e = cfg.n_experts
     idx, gate = route(h, p, cfg)
     picked = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # [B, T, k, E]
-    combine = jnp.sum(picked * gate[..., None], axis=-2).astype(h.dtype)  # [B, T, E]
-    stats = None
-    if live is not None:
-        rows_on = jnp.sum(jnp.sum(picked, axis=-2) * live[:, None, None], axis=(0, 1))
-        stats = jnp.stack([jnp.sum(rows_on > 0), jnp.max(rows_on),
-                           jnp.sum(live) * h.shape[1]]).astype(jnp.int32)
+    combine = jnp.sum(picked * gate[..., None], axis=-2)  # [B, T, E] f32
+    on = jnp.sum(picked, axis=-2)  # [B, T, E]: 1 where a row picked the expert
+    rows_on = jnp.sum(on if live is None else on * live[:, None, None], axis=(0, 1))
+    stats = None if live is None else jnp.stack(
+        [jnp.sum(rows_on > 0), jnp.max(rows_on), jnp.sum(live) * h.shape[1]]).astype(jnp.int32)
     rows = h.shape[0] * h.shape[1]
+    acc = swiglu(h, p["w_gate_s"], p["w_up_s"], p["w_down_s"], cfg.mlp_act).astype(jnp.float32)
+    if stacks is not None:
+        from ..ops import moe_experts
+
+        ids, n_hit = moe_experts.hit_list(rows_on, min(e, rows * cfg.n_experts_used))
+        gates = jnp.take(combine.reshape(rows, e).T, ids, axis=0)  # [places, rows]
+        acc = moe_experts.moe_hit_experts_auto(
+            h.reshape(rows, -1), gates, ids, n_hit, place, *stacks,
+            acc.reshape(rows, -1)).reshape(acc.shape)
+        return acc.astype(h.dtype), stats
+    combine = combine.astype(h.dtype)
     act_bytes = rows * e * cfg.moe_d_ff * h.dtype.itemsize
     groups = next(g for g in range(1, e + 1)
                   if e % g == 0 and act_bytes // g <= _EXPERT_ACT_BYTES or g == e)
     size = e // groups
-    acc = swiglu(h, p["w_gate_s"], p["w_up_s"], p["w_down_s"], cfg.mlp_act).astype(jnp.float32)
     for g in range(groups):
         wg, wu, wd = (jax.tree.map(lambda x: x[g * size: (g + 1) * size], p[k])
-                      for k in ("w_gate_e", "w_up_e", "w_down_e"))
+                      for k in _EXPERT_LEAVES)
         act = jax.nn.silu(q_einsum("btd,gdf->btgf", h, wg)) * q_einsum("btd,gdf->btgf", h, wu)
         act = act * combine[..., g * size: (g + 1) * size, None]
         acc = acc + q_einsum("btgf,gfd->btd", act, wd).astype(jnp.float32)
@@ -324,27 +379,38 @@ def _head(params: Params, cfg: ModelConfig, X, logit_positions, t: int) -> jax.A
     return lm_head_logits(params, cfg, x, logit_positions, t)
 
 
-def _layers(params: Params, cfg: ModelConfig, X, caches, attention, live=None):
+def _layers(params: Params, cfg: ModelConfig, X, caches, attention, live=None, mesh=None):
     """Both stacks in model order, each one scan: attention then FFN around
     the streams. ``attention(h, p, caches, layer) -> (out, caches)`` is the
     caller's (row caches or pools). Returns (X, caches, the expert layers'
-    counters [n_moe_layers, 3] or None without ``live``)."""
+    counters [n_moe_layers, 3] or None without ``live``).
+
+    Where the expert layers take the hit list (``expert_path``), the three
+    expert stacks leave the scan's ``xs`` and are closed over WHOLE, the scan
+    carrying the layer's place in them: a scan's slice of a stack handed to a
+    kernel or a loop as an operand would be copied, 1.4 GB a layer."""
     stats = None
+    rows = X.shape[1] * X.shape[2]
     for stack, first, kind in _stacks(params, cfg):
-        def block(carry, inputs, kind=kind):
+        whole = None
+        if kind == "moe" and expert_path(cfg, rows, stack, mesh) == "hit_list":
+            whole = tuple(stack[k] for k in _EXPERT_LEAVES)
+            stack = {k: v for k, v in stack.items() if k not in _EXPERT_LEAVES}
+
+        def block(carry, inputs, kind=kind, whole=whole):
             X, caches = carry
-            p, layer = inputs
+            p, layer, place = inputs
             X, caches = _residual(X, p, "attn", cfg, lambda h: attention(h, p, caches, layer))
             if kind == "dense":
                 X, st = _residual(X, p, "ffn", cfg, lambda h: swiglu(
                     h, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act))
             else:
-                X, st = _residual(X, p, "ffn", cfg, lambda h: moe_ffn(h, p, cfg, live))
+                X, st = _residual(X, p, "ffn", cfg, lambda h: moe_ffn(
+                    h, p, cfg, live, whole, place))
             return (X, caches), st
 
-        n = jax.tree.leaves(stack)[0].shape[0]
-        (X, caches), st = jax.lax.scan(
-            block, (X, caches), (stack, first + jnp.arange(n, dtype=jnp.int32)))
+        place = jnp.arange(jax.tree.leaves(stack)[0].shape[0], dtype=jnp.int32)
+        (X, caches), st = jax.lax.scan(block, (X, caches), (stack, first + place, place))
         if kind == "moe":
             stats = st
     return X, caches, stats
@@ -365,7 +431,7 @@ def forward(
         raise NotImplementedError(
             "latent-attention models are served on the paged pool (KV_PAGED=1): "
             "the shared-ring cache layout has no latent form")
-    del mesh, fresh_prefill, uniform_start  # one attention path for every start
+    del fresh_prefill, uniform_start  # one attention path for every start
     b, t = tokens.shape
     s_max = k_cache.shape[3]
     win = attn_window if (attn_window is not None and attn_window < s_max) else s_max
@@ -400,7 +466,7 @@ def forward(
             o = expanded_attention(q_nope, q_rope, c_win, kr_win, p, cfg, positions)
         return mm(o, p["wo"]), (c_all, r_all)
 
-    X, caches, _ = _layers(params, cfg, X, (k_cache, v_cache), attention)
+    X, caches, _ = _layers(params, cfg, X, (k_cache, v_cache), attention, mesh=mesh)
     return _head(params, cfg, X, logit_positions, t), caches[0], caches[1]
 
 
@@ -417,7 +483,6 @@ def forward_decode_paged(
     most rows on one expert and the live rows in each expert layer."""
     from ..ops.mla_attention import mla_paged_decode_attention_auto
 
-    del mesh
     b, w = tokens.shape
     positions = start_pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
     cos, sin = rope_tables(cfg, positions)
@@ -434,7 +499,7 @@ def forward_decode_paged(
             start_pos, layer, cfg.attn_scale)
         return mm(absorbed_output(o_lat, p, cfg), p["wo"]), (cp, rp)
 
-    X, pools, stats = _layers(params, cfg, X, (k_pool, v_pool), attention, live)
+    X, pools, stats = _layers(params, cfg, X, (k_pool, v_pool), attention, live, mesh)
     if stats is None:
         stats = jnp.zeros((0, 3), jnp.int32)
     return _head(params, cfg, X, None, w), pools[0], pools[1], stats

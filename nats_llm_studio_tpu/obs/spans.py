@@ -35,7 +35,11 @@ SPAN_NAMES = (
     "worker.publish",      # serialising a chunk + await nc.publish on the loop thread
 )
 
-RING_SIZE = 16384  # ~20 records a second at 8 slots: a quarter of an hour
+# ~200 records a second at 8 slots and a 50 ms burst (5 owner-thread spans a
+# burst, a publish a stream): ten minutes. A traced benchmark run reads its
+# window's records ~3 minutes after the window (the profiler writes its file
+# first, under load): 16384 had rolled past it by then (PERF.md, PR 30)
+RING_SIZE = 131072
 
 # deque.append / iteration snapshots are atomic under the GIL; the owner
 # thread and the event-loop thread both append

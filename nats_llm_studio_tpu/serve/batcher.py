@@ -313,6 +313,10 @@ class BatcherStats:
     expert_rows_max: int = 0
     expert_rows: int = 0
     expert_steps: int = 0
+    # the form the expert layers of a decode burst take: "hit_list" (only the
+    # experts the live rows hit are read) or "dense" (models/mla_moe.py
+    # expert_path); "" for a family without expert layers
+    expert_path: str = ""
     # bounded log-bucket histograms (obs/histogram.py): O(1) record on the
     # batcher owner thread, O(buckets) snapshot from the asyncio metrics
     # handlers, fixed memory for the life of the worker. Phase deltas come
@@ -487,13 +491,14 @@ class BatcherStats:
     def record_moe(self, counters) -> dict[str, int]:
         """One burst's expert counters ([3 x layers, steps] ints: per layer
         the experts hit, the most rows on one expert, the live rows). Returns
-        the burst's sums (the readback span carries them)."""
+        what the readback span carries: the burst's sums, and ``expert_path``
+        where the batcher has named it."""
         c = np.asarray(counters).reshape(-1, 3, counters.shape[-1])
         burst = {"experts_hit": int(c[:, 0].sum()), "expert_rows_max": int(c[:, 1].sum()),
                  "expert_rows": int(c[:, 2].sum()), "expert_steps": int(c[:, 0].size)}
         for k, v in burst.items():
             setattr(self, k, getattr(self, k) + v)
-        return burst
+        return burst | ({"expert_path": self.expert_path} if self.expert_path else {})
 
     def moe_counters(self) -> dict[str, int]:
         """Expert-layer counters, exposed by serve/worker.py as
@@ -803,6 +808,12 @@ class ContinuousBatcher:
         # recorder frame's one-number answer to "is spec still paying?"
         self._spec_accept_ewma = 0.0
         self.stats = BatcherStats()
+        if cfg.n_moe_layers:
+            from ..models.mla_moe import expert_path
+
+            # a decode burst is max_slots rows of one token
+            self.stats.expert_path = expert_path(
+                cfg, max_slots, self.params["blocks"]["moe"], mesh)
         # compute-efficiency plane (obs/roofline.py): per-dispatch cost
         # extraction + the device-time ledger. EFFICIENCY=0 disables both
         # (the _timed wrapper then degrades to the plain timer).

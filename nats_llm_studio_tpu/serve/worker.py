@@ -1885,10 +1885,15 @@ class Worker:
             if moe is not None and moe()["expert_steps"]:
                 # routed-expert layers (models/mla_moe.py), summed over
                 # decode steps x expert layers: hit / steps is the experts a
-                # step reads if it reads only those hit, rows_max / steps
-                # against rows x k / experts is the imbalance
+                # step reads where its path is "hit_list" (every expert where
+                # it is "dense"), rows_max / steps against rows x k / experts
+                # is the imbalance
                 for name, v in moe().items():
                     r.counter(f"lmstudio_moe_{name}_total", v, labels=labels)
+                r.gauge("lmstudio_moe_expert_path", 1,
+                        labels={**labels, "path": getattr(stats, "expert_path", "")},
+                        help="the form a decode burst's expert layers take: "
+                             "hit_list reads only the experts hit, dense all")
             tier_fn = getattr(rb, "tier_stats", None)
             tier = tier_fn() if tier_fn is not None else None
             if tier:

@@ -505,7 +505,9 @@ async def test_suspend_fault_falls_back_to_retryable_shed(model):
     b = ContinuousBatcher(params, cfg, **_SUSPEND_KW)
     faults.install(faults.FaultPlan().drop(faults.SUSPEND, 0))
     try:
-        spa = SamplingParams(temperature=0.0, max_tokens=12)
+        # 24 tokens (33 + 24 stay inside A's two blocks): with 12, a loaded
+        # machine let A finish before B's admit arrived, and nothing shed
+        spa = SamplingParams(temperature=0.0, max_tokens=24)
         started = asyncio.get_running_loop().create_future()
 
         async def run_a():
@@ -522,7 +524,7 @@ async def test_suspend_fault_falls_back_to_retryable_shed(model):
             await _serve(b, [pb], 8)
         assert "retry" in str(ei.value)
         got_a = await ta  # the would-be victim kept decoding untouched
-        assert len(got_a) == 12
+        assert len(got_a) == 24
         assert b._suspend_stats["suspend_failures"] >= 1
         assert b._suspend_stats["suspended_total"] == 0
         assert b.stats.shed_cause_counts().get("kv_pool", 0) == 1

@@ -184,6 +184,94 @@ def test_every_row_on_the_same_experts_is_held_to_the_reference(model, prompt):
     assert np.asarray(stats).tolist() == [[2, 3, 3]] * cfg.n_moe_layers
 
 
+# (picks of the 8 rows [8, 4], live rows, experts the list must hold, most rows on one)
+_SAME = [[3, 17, 40, 63]] * 8
+_DISTINCT = (np.arange(32).reshape(8, 4) * 2 + 1).tolist()
+HIT_CASES = {
+    "every_row_on_the_same_four": (_SAME, [1] * 8, 4, 8),
+    "every_pick_distinct": (_DISTINCT, [1] * 8, 32, 1),
+    "one_live_row_of_eight": (_DISTINCT, [0, 0, 0, 1, 0, 0, 0, 0], 4, 1),
+    "no_live_row": (_DISTINCT, [0] * 8, 0, 0),
+    "a_dead_slots_picks_stay_out": (_SAME[:7] + [[5, 6, 7, 8]], [1] * 7 + [0], 4, 7),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(HIT_CASES))
+def test_the_hit_list_is_the_dense_dispatch_over_the_experts_hit(case, dtype, monkeypatch):
+    """The same layer twice on the same rows: dense dispatch (the layer's own
+    expert leaves) and the hit list (the WHOLE stacks and the layer's place in
+    them). Live rows agree to the order of the products; the list holds the
+    experts of live rows only, so a row of an empty slot gets the shared
+    expert's output plus whatever listed experts it also picked."""
+    picks, live, n_hit, rows_max = HIT_CASES[case]
+    cfg = REF.model_config(CONF, SEQ).with_(n_experts=64, n_experts_used=4, dtype=dtype)
+    dt, d, f, e, place = jnp.dtype(dtype), cfg.d_model, cfg.moe_d_ff, cfg.n_experts, 1
+    ks = iter(jax.random.split(jax.random.PRNGKey(11), 8))
+    rand = lambda *shape: (jax.random.normal(next(ks), shape) * 0.3).astype(dt)  # noqa: E731
+    stacks = {"w_gate_e": rand(2, e, d, f), "w_up_e": rand(2, e, d, f),
+              "w_down_e": rand(2, e, f, d)}
+    p = {"w_gate_s": rand(d, f), "w_up_s": rand(d, f), "w_down_s": rand(f, d)}
+    h = rand(8, 1, d)
+    idx = jnp.asarray(picks, jnp.int32)[:, None]
+    gate = jax.random.uniform(next(ks), idx.shape, minval=0.1, maxval=1.0)
+    monkeypatch.setattr(mla_moe, "route", lambda *_: (idx, gate))
+    live = jnp.asarray(live, jnp.float32)
+    assert mla_moe.expert_path(cfg, 8, stacks) == "hit_list"
+    dense, _ = mla_moe.moe_ffn(h, p | {k: v[place] for k, v in stacks.items()}, cfg, live)
+    got, stats = jax.jit(lambda: mla_moe.moe_ffn(
+        h, p, cfg, live, tuple(stacks[k] for k in mla_moe._EXPERT_LEAVES), place))()
+    assert stats.tolist() == [n_hit, rows_max, int(sum(live))]
+    from nats_llm_studio_tpu.ops.layers import swiglu
+
+    shared = swiglu(h, p["w_gate_s"], p["w_up_s"], p["w_down_s"])
+    on = np.asarray(live) > 0
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))  # noqa: E731
+    tol = 2e-4 if dtype == "float32" else 2.0 ** -6 * float(np.abs(f32(dense)).max())
+    np.testing.assert_allclose(f32(got)[on], f32(dense)[on], rtol=tol, atol=tol)
+    if case in ("no_live_row", "a_dead_slots_picks_stay_out"):
+        # the dead rows picked nothing the list holds
+        np.testing.assert_allclose(f32(got)[~on], f32(shared)[~on], rtol=tol, atol=tol)
+        assert not np.allclose(f32(dense)[~on], f32(shared)[~on], atol=10 * tol)
+
+
+@pytest.mark.parametrize("rows,leaves,devices,path", [
+    (8, "plain", 1, "hit_list"),      # a decode step of the cell: 8 x 4 < 64
+    (16, "plain", 1, "dense"),        # 16 x 4 = 64: every expert may be hit
+    (256, "plain", 1, "dense"),       # a prefill chunk
+    (8, "int8", 1, "dense"),          # WQUANT=int8 expert stacks
+    (8, "plain", 2, "dense"),         # a mesh of more than one chip
+])
+def test_the_expert_path_is_chosen_from_shapes_leaf_types_and_devices(rows, leaves, devices, path):
+    from nats_llm_studio_tpu.ops.wquant import quantize_weight
+    from nats_llm_studio_tpu.parallel.mesh import build_mesh
+
+    cfg = REF.model_config(CONF, SEQ).with_(n_experts=64, n_experts_used=4)
+    w = jnp.ones((1, 64, 8, 8), jnp.bfloat16)
+    stack = {k: w if leaves == "plain" else quantize_weight(w) for k in mla_moe._EXPERT_LEAVES}
+    mesh = build_mesh({"tp": devices}, devices=jax.local_devices()[:devices])
+    assert mla_moe.expert_path(cfg, rows, stack, mesh) == path
+    assert mla_moe.expert_path(cfg, rows, stack) == (path if devices == 1 else "hit_list")
+
+
+@pytest.mark.parametrize("rows,iters", [(1, 20), (8, 20), (56, 20), (128, 3), (8, 0)])
+def test_the_sinkhorn_kernel_is_the_loop_it_stands_for(rows, iters):
+    """A mixer of few rows (decode: 8, a verify bundle: 56) runs its rounds in
+    one kernel; more rows than a lane tile keep the ``fori_loop``. Both are the
+    same rounds, rows first."""
+    from nats_llm_studio_tpu.ops.sinkhorn import sinkhorn_rounds
+
+    res = jnp.exp(jnp.clip(3 * jax.random.normal(jax.random.PRNGKey(rows), (4, 4, rows)), -30, 30))
+    want = res
+    for _ in range(iters):
+        want = want / (jnp.sum(want, axis=1, keepdims=True) + 1e-6)
+        want = want / (jnp.sum(want, axis=0, keepdims=True) + 1e-6)
+    got = sinkhorn_rounds(res, iters, 1e-6, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-7)
+    if iters == 20:   # doubly stochastic by then
+        np.testing.assert_allclose(np.asarray(got.sum(0)), 1.0, atol=1e-3)
+
+
 def test_absorbed_attention_is_expanded_attention():
     cfg = REF.model_config(CONF, SEQ).with_(dtype="float32")
     ks = jax.random.split(jax.random.PRNGKey(3), 6)
@@ -298,3 +386,10 @@ def test_a_bursts_expert_counters_are_summed_and_named():
                      "expert_steps": 6}
     assert st.moe_counters() == burst and st.record_moe(np.zeros((3, 2), int))["expert_steps"] == 2
     assert st.expert_steps == 8
+    # the batcher names the form its decode bursts take; the readback span
+    # carries it beside the sums, the counters stay numbers
+    st.expert_path = "hit_list"
+    assert st.record_moe(np.zeros((3, 1), int)) == {
+        "experts_hit": 0, "expert_rows_max": 0, "expert_rows": 0, "expert_steps": 1,
+        "expert_path": "hit_list"}
+    assert "expert_path" not in st.moe_counters()

@@ -241,3 +241,35 @@ def test_mla_paged_decode_at_the_benchmark_cells_shapes(one_chip, no_cache, w):
         sds((blocks, layers, 1, T, dr), jnp.bfloat16),
         sds((SLOTS, width), jnp.int32), sds((SLOTS,), jnp.int32), sds((), jnp.int32),
     )
+
+
+def test_moe_hit_experts_at_the_benchmark_cells_shapes(one_chip, no_cache):
+    """The expert layer of a decode step of ``xing29b.answer_closed``: 8 rows
+    x top-4 = 32 places over the WHOLE stacks of 6 expert layers x 64 experts
+    of [3584, 1024] bf16 (8.5 GB, indexed in place by the scalar-prefetched
+    layer and hit list), tiles of 512 columns of ``f``: three double-buffered
+    3.7 MB tiles are 22 MB of VMEM, over the 16 MB a kernel gets unasked."""
+    from nats_llm_studio_tpu.ops.moe_experts import _f_tile, moe_hit_experts
+
+    layers, e, d, f, rows, places = 6, 64, 3584, 1024, SLOTS, 32
+    assert _f_tile(d, f, 2) == 512
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    compiled = _compile(
+        moe_hit_experts,
+        sds((rows, d), jnp.bfloat16), sds((places, rows), jnp.float32),
+        sds((places,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
+        sds((layers, e, d, f), jnp.bfloat16), sds((layers, e, d, f), jnp.bfloat16),
+        sds((layers, e, f, d), jnp.bfloat16), sds((rows, d), jnp.float32),
+    )
+    # the stacks are read where they lie: no copy of a layer's slice
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("rows", [SLOTS, SLOTS * SPEC_W], ids=["decode", "spec_verify"])
+def test_hc_sinkhorn_at_the_benchmark_cells_shapes(one_chip, no_cache, rows):
+    """The mixing map of the four-stream residual, 20 rounds in one kernel:
+    [4, 4, rows] float32 on one lane tile."""
+    from nats_llm_studio_tpu.ops.sinkhorn import sinkhorn_rounds
+
+    _compile(lambda res: sinkhorn_rounds(res, 20, 1e-6),
+             jax.ShapeDtypeStruct((4, 4, rows), jnp.float32, sharding=one_chip))
