@@ -220,7 +220,6 @@ async def serve_and_drive(cfg, out_dir: Path, seed: int, *, logprobs: bool = Fal
     from nats_llm_studio_tpu.gguf.reader import open_gguf
     from nats_llm_studio_tpu.gguf.tokenizer import GGUFTokenizer
     from nats_llm_studio_tpu.main import start_serve
-    from nats_llm_studio_tpu.obs import roofline
     from nats_llm_studio_tpu.serve.template import render_chat_template
     from nats_llm_studio_tpu.transport import connect
 
@@ -381,9 +380,6 @@ async def serve_and_drive(cfg, out_dir: Path, seed: int, *, logprobs: bool = Fal
                 for ln in prom.splitlines()
                 if ln.startswith("lmstudio_program_ms_count")
             }),
-            "roofline_peaks": dict(zip(
-                ("flops_per_s", "hbm_bytes_per_s"),
-                roofline.resolve_chip_peaks(dev.device_kind, dev.platform))),
             "roofline_device_kind": dev.device_kind,
         }
         emit(phase="engine", **out["engine"])

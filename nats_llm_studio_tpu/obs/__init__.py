@@ -35,13 +35,9 @@ from .roofline import (
     SPEC_PROGRAMS,
     WASTE_CATEGORIES,
     HbmLedger,
-    RollingUtilization,
-    chip_peaks,
     classify_program,
     dispatch_shape_key,
     efficiency_enabled,
-    extract_dispatch_cost,
-    resolve_chip_peaks,
 )
 from .trace import (
     STAGES,
@@ -78,13 +74,9 @@ __all__ = [
     "SPEC_PROGRAMS",
     "WASTE_CATEGORIES",
     "HbmLedger",
-    "RollingUtilization",
-    "chip_peaks",
     "classify_program",
     "dispatch_shape_key",
     "efficiency_enabled",
-    "extract_dispatch_cost",
-    "resolve_chip_peaks",
     "STAGES",
     "Span",
     "Trace",

@@ -103,12 +103,10 @@ def _resolve_family(name: str, types: dict[str, str]) -> tuple[str, str, str] | 
     return None
 
 
-# Ratio/rate gauges where summing across workers is meaningless (a 2-worker
-# fleet at 40% MFU each is NOT at 80%): these average over the contributing
-# samples instead. Totals-style families (device_ms, flops, bytes) still sum.
+# Ratio/rate gauges where summing across workers is meaningless (two workers
+# at 500 tokens per device-second each are NOT at 1000): these average over
+# the contributing samples instead. Totals-style families (device_ms) still sum.
 MEAN_GAUGE_FAMILIES = frozenset({
-    "lmstudio_mfu",
-    "lmstudio_mbu",
     "lmstudio_goodput_tokens_per_device_s",
 })
 

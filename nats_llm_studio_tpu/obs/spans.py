@@ -31,7 +31,6 @@ SPAN_NAMES = (
     "batcher.dispatch",    # host preparation + enqueue of a decode burst
     "batcher.readback",    # np.asarray(...) of a burst: owner thread blocked on the device
     "batcher.deliver",     # the row loop after a readback, through _deliver and req.emit
-    "batcher.cost_probe",  # extract_dispatch_cost: a shape's first dispatch lowers it twice
     "worker.publish",      # serialising a chunk + await nc.publish on the loop thread
 )
 

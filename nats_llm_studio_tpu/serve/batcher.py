@@ -33,40 +33,33 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..engine.generator import SamplingParams, default_buckets
+from ..engine.sampling import sample_rows
 from ..models.config import ModelConfig
-from ..models.llama import forward, forward_decode_paged, make_cache
-from ..engine.sampling import sample_rows, spec_accept_rows
+from ..models.llama import make_cache
 from ..obs import LogHistogram, Trace
 from ..obs import emit as obs_emit
 from ..obs import spans as obs_spans
 from ..obs.roofline import (
     SPEC_PROGRAMS,
     WASTE_CATEGORIES,
-    RollingUtilization,
     classify_program,
     dispatch_shape_key,
     efficiency_enabled,
-    extract_dispatch_cost,
     program_base,
 )
 from ..transport import faults as _faults
 from ..ops.kvcache import (
     KVQ,
     is_quantized,
-    kv_copy_slice,
     kv_gather_block,
-    kv_pool_copy_block,
-    kv_pool_gather_view,
     kv_pool_read_blocks,
-    kv_pool_scatter_view,
     kv_pool_write_row,
     kv_pool_zeros,
-    kv_roll_s,
-    kv_slice,
 )
 from .block_pool import BlockPool
 from .brownout import LEVEL_NAMES, SHED_ONLY, BrownoutConfig, BrownoutController
 from .prefix_cache import PrefixCache
+from .programs import build_programs, recorded_name, ring_name
 from .qos import (
     ANON_TENANT,
     DEFAULT_PRIORITY,
@@ -87,24 +80,6 @@ log = logging.getLogger(__name__)
 # not yet written: decode steps during the chunk loop must neither deliver
 # tokens for it nor let another admit claim the slot
 _RESERVED = object()
-
-# how many top-logprob (id, logprob) pairs the ext decode programs read back
-# per step; OpenAI caps top_logprobs requests well below this
-LOGPROBS_K = 8
-
-# forward-bearing programs that record under a "_moe" name suffix when the
-# model runs capacity-factor routed experts (roofline.program_family) —
-# sampling/bookkeeping programs (finish_admit, select_end, pool copies)
-# never touch the FFN and keep their plain names
-_MOE_TAGGED_PROGRAMS = frozenset({
-    "prefill1", "prefill_full", "prefill_chunk_group",
-    "admit_fused", "admit_many_fused",
-    "admit_fused_paged", "admit_many_fused_paged",
-    "decode", "decode_pos", "decode_pos_ext",
-    "decode_pos_paged", "decode_pos_paged_ext",
-    "decode_pallas", "decode_pallas_ext",
-    "spec_verify", "spec_verify_paged", "spec_verify_pallas",
-})
 
 
 class BatcherStopped(RuntimeError):
@@ -345,12 +320,8 @@ class BatcherStats:
     # record; exposition copies the dict under the lock.
     program_ms: dict = field(default_factory=dict)  # name -> LogHistogram
     program_tokens: dict = field(default_factory=dict)  # name -> LogHistogram
-    # -- compute-efficiency plane (obs/roofline.py) -----------------------
-    # cumulative per-program flops / bytes-accessed from XLA cost analysis;
-    # keys materialize on the first costed dispatch of each program
-    program_flops: dict = field(default_factory=dict)  # name -> float
-    program_bytes: dict = field(default_factory=dict)  # name -> float
-    # device-time ledger: outcome category -> accumulated dispatch ms, and
+    # -- device-time ledger (obs/roofline.py) -----------------------------
+    # outcome category -> accumulated dispatch ms, and
     # tokens delivered (tokens accrue only under "served")
     device_ms: dict = field(default_factory=dict)
     device_tokens: dict = field(default_factory=dict)
@@ -358,9 +329,6 @@ class BatcherStats:
     # approximately): reconciliation denominator for the ledger — the bench
     # `efficiency` phase asserts category sums match this within 10%
     dispatch_ms_total: float = 0.0
-    # rolling flops/bytes windows per program class -> MFU/MBU gauges
-    util_prefill: RollingUtilization = field(default_factory=RollingUtilization)
-    util_decode: RollingUtilization = field(default_factory=RollingUtilization)
     _lock: threading.Lock = field(default_factory=threading.Lock)
 
     def record_program(self, name: str, ms: float, tokens: float | None = None) -> None:
@@ -390,22 +358,6 @@ class BatcherStats:
         with self._lock:
             return dict(self.program_tokens)
 
-    def record_dispatch_cost(self, name: str, cost: tuple | None) -> None:
-        """Fold one dispatch's (flops, bytes) into the per-program totals and
-        the rolling roofline windows. ``cost`` is None when XLA cost analysis
-        was unavailable for the program — the dispatch simply isn't costed."""
-        if not cost:
-            return
-        fl, by = cost
-        with self._lock:
-            self.program_flops[name] = self.program_flops.get(name, 0.0) + fl
-            self.program_bytes[name] = self.program_bytes.get(name, 0.0) + by
-        cls = classify_program(name)
-        if cls == "prefill":
-            self.util_prefill.add(fl, by)
-        elif cls == "decode":
-            self.util_decode.add(fl, by)
-
     def attribute_device_time(self, category: str, ms: float, tokens: int = 0) -> None:
         """Ledger entry: ``ms`` of device dispatch time resolved to an outcome
         ``category`` (roofline.WASTE_CATEGORIES, plus "failed" for crash
@@ -433,20 +385,6 @@ class BatcherStats:
             total_ms = sum(self.device_ms.values())
             served = self.device_tokens.get("served", 0)
         return served / (total_ms / 1e3) if total_ms > 0 else 0.0
-
-    def cost_counters(self) -> tuple[dict, dict]:
-        """(program_flops, program_bytes) copies for exposition."""
-        with self._lock:
-            return dict(self.program_flops), dict(self.program_bytes)
-
-    def utilization(self, peaks: tuple | None = None) -> dict:
-        """Rolling MFU/MBU per program class against chip peaks."""
-        pf_mfu, pf_mbu = self.util_prefill.utilization(peaks)
-        dc_mfu, dc_mbu = self.util_decode.utilization(peaks)
-        return {
-            "prefill": {"mfu": pf_mfu, "mbu": pf_mbu},
-            "decode": {"mfu": dc_mfu, "mbu": dc_mbu},
-        }
 
     def record_admit_delay(self, ms: float) -> None:
         """Queue delay (enqueue -> admit DISPATCH), ms — the scheduling
@@ -814,9 +752,8 @@ class ContinuousBatcher:
             # a decode burst is max_slots rows of one token
             self.stats.expert_path = expert_path(
                 cfg, max_slots, self.params["blocks"]["moe"], mesh)
-        # compute-efficiency plane (obs/roofline.py): per-dispatch cost
-        # extraction + the device-time ledger. EFFICIENCY=0 disables both
-        # (the _timed wrapper then degrades to the plain timer).
+        # the device-time ledger (obs/roofline.py): EFFICIENCY=0 turns it
+        # off and _timed is then the plain timer
         self._efficiency = efficiency_enabled()
         # the requests the in-progress dispatch works for (owner thread
         # only); _timed splits each dispatch's ms across this context, and
@@ -882,775 +819,16 @@ class ContinuousBatcher:
         # plain dict ref swap is atomic under the GIL.
         self._slot_view: dict[int, dict] = {}
 
-        fwd = partial(forward, cfg=cfg, mesh=mesh)
-
-        # -- explicit cache shardings (tensor-parallel serving) --------------
-        # With a mesh, the serving K/V ring arrives in every jit already
-        # sharded (heads on tp — shard_cache in _run), but values *created
-        # inside* a jit (the fused admits' fresh row caches) and the cache
-        # write boundaries would otherwise be left to the partitioner's
-        # guess — worst case a replicated transient per chip plus an
-        # all-gather at the serving-cache write. ``pin_cache``/``pin_row``
-        # pin the KV head axis to tp at creation and at every read/write
-        # boundary; the constraint matches the donated inputs' shardings
-        # exactly, so buffer donation survives. Both are identity with no
-        # mesh — the tp=1 path compiles byte-for-byte unchanged.
-        if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            from ..parallel.sharding import (
-                cache_spec,
-                row_cache_spec,
-                validate_mesh_for_config,
-            )
-
-            validate_mesh_for_config(mesh, cfg)
-            cache_sh = NamedSharding(mesh, cache_spec(mesh, cfg))
-            row_sh = NamedSharding(mesh, row_cache_spec(mesh, cfg))
-
-            def _pin_with(c, sh):
-                if is_quantized(c):
-                    s_sh = NamedSharding(mesh, PartitionSpec(*list(sh.spec)[:-1]))
-                    return KVQ(
-                        q=jax.lax.with_sharding_constraint(c.q, sh),
-                        s=jax.lax.with_sharding_constraint(c.s, s_sh),
-                    )
-                return jax.lax.with_sharding_constraint(c, sh)
-
-            def pin_cache(c):
-                return _pin_with(c, cache_sh)
-
-            def pin_row(c):
-                return _pin_with(c, row_sh)
-        else:
-
-            def pin_cache(c):
-                return c
-
-            pin_row = pin_cache
-
-        def row_of(c, i):
-            """Row i of a transient row cache as a [1, ...] cache of its own.
-            The two caches of a pair are sliced each by its own shape: K and
-            V alike for GQA, latent and rotary key for MLA."""
-            zero = jnp.zeros((), jnp.int32)
-            return kv_slice(c, (i, zero, zero, zero, zero), (1,) + tuple(c.shape[1:]))
-
-        @partial(jax.jit, static_argnums=(6,))
-        def prefill1(params, tokens, k1, v1, start, last_pos, window):
-            # lm_head at one position only ([1,1,vocab]); non-final chunks
-            # ignore the logits, the final chunk's last_pos is the prompt end.
-            # uniform_start: all rows share `start`, so chunk continuations
-            # ride the cache-backed flash kernel, not the dense fallback.
-            # window (static, bucketed >= start + C): each chunk reads only
-            # the live cache prefix instead of the full max_seq slab — the
-            # r4 bench measured 16k chunked prefill at 43% of the
-            # single-dispatch kernel from the O(T^2) full-window reads
-            # (and KVQ dequant transients) this removes.
-            logits, k1, v1 = fwd(
-                params, tokens=tokens, k_cache=pin_row(k1), v_cache=pin_row(v1),
-                start_pos=start,
-                logit_positions=last_pos, uniform_start=True, attn_window=window,
-            )
-            return logits, pin_row(k1), pin_row(v1)
-
-        def _insert_and_sample(params, K, V, tok, k1, v1, logits, slot, shift,
-                               seed, temp, topk, topp):
-            """Roll the prefilled row onto the ring, write it, sample token 0,
-            and write it into the device-resident next-token carry ``tok``.
-
-            The prefix (tokens at [0, n) of k1) must land on the ring slots
-            ending at the current ring head, so the whole row is rolled by
-            ``shift`` = (ring_next - n) mod S before the row write — decode
-            validity is "the start_pos+1 most recent ring slots" and relies
-            on every row's tokens being slot-contiguous there.
-            """
-            zero = jnp.zeros((), jnp.int32)
-            k1 = kv_roll_s(k1, shift, s_axis=3)
-            v1 = kv_roll_s(v1, shift, s_axis=3)
-            K = pin_cache(kv_copy_slice(K, k1, (slot, zero, zero, zero, zero)))
-            V = pin_cache(kv_copy_slice(V, v1, (slot, zero, zero, zero, zero)))
-            first = sample_rows(
-                logits[:, 0], seed[None], jnp.zeros((1,), jnp.int32),
-                temp[None], topk[None], topp[None],
-            )
-            tok = jax.lax.dynamic_update_slice(tok, first, (slot,))
-            return first, K, V, tok
-
-        @partial(jax.jit, donate_argnums=(1, 2, 3))
-        def admit_fused(params, K, V, tok, tokens, n, slot, shift, seed, temp,
-                        topk, topp):
-            """Whole short-prompt admit in ONE dispatch: fresh row cache is
-            created on device, prefilled, ring-aligned, written, and the
-            first token sampled — host round trips per admit drop from ~5 to
-            2 (tokens in, first token out), which bounds TTFT under
-            concurrent load."""
-            from ..models.llama import make_cache as _mk
-
-            k1, v1 = _mk(cfg, 1, self.max_seq)
-            k1, v1 = pin_row(k1), pin_row(v1)
-            # logit_positions: lm_head at the prompt end only — skips
-            # bucket× the lm_head FLOPs and the [1, bucket, vocab] f32
-            logits, k1, v1 = fwd(
-                params, tokens=tokens, k_cache=k1, v_cache=v1,
-                start_pos=jnp.zeros((1,), jnp.int32),
-                logit_positions=jnp.reshape(n - 1, (1,)),
-                fresh_prefill=True,
-            )
-            return _insert_and_sample(
-                params, K, V, tok, k1, v1, logits, slot, shift, seed, temp,
-                topk, topp,
-            )
-
-        @partial(jax.jit, donate_argnums=(1, 2, 3))
-        def admit_many_fused(params, K, V, tok, tokens, ns, slots, offsets,
-                             seeds, temps, topks, topps):
-            """Admit m short prompts in ONE dispatch: a single batched
-            prefill over [m, bucket] plus per-row insert/sample — concurrent
-            arrivals pay one prefill's latency instead of m (the dominant
-            term in TTFT p95 under bursty load).
-
-            The transient prefill cache is [m, ..., bucket] long, not
-            max_seq (which at m = max_slots would duplicate the whole
-            serving cache's HBM). Each bucket-length block lands at
-            ``offsets[i]`` = ring_next - n_i so the prefix ends at the ring
-            head; the caller guarantees no block wraps (falls back to
-            per-request admits otherwise)."""
-            from ..models.llama import make_cache as _mk
-
-            m, bucket = tokens.shape
-            km, vm = _mk(cfg, m, bucket)
-            km, vm = pin_row(km), pin_row(vm)
-            logits, km, vm = fwd(
-                params, tokens=tokens, k_cache=km, v_cache=vm,
-                start_pos=jnp.zeros((m,), jnp.int32),
-                logit_positions=ns - 1,  # [m,1,vocab]: prompt-end rows only
-                fresh_prefill=True,
-            )
-            zero = jnp.zeros((), jnp.int32)
-            firsts = sample_rows(
-                logits[:, 0], seeds, jnp.zeros((m,), jnp.int32), temps, topks, topps
-            )
-
-            def body(carry, i):
-                K, V, tok = carry
-                k1, v1 = row_of(km, i), row_of(vm, i)
-                K = kv_copy_slice(K, k1, (slots[i], zero, zero, offsets[i], zero))
-                V = kv_copy_slice(V, v1, (slots[i], zero, zero, offsets[i], zero))
-                tok = jax.lax.dynamic_update_slice(
-                    tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1), (slots[i],)
-                )
-                return (K, V, tok), None
-
-            (K, V, tok), _ = jax.lax.scan(
-                body, (K, V, tok), jnp.arange(m, dtype=jnp.int32)
-            )
-            return firsts, pin_cache(K), pin_cache(V), tok
-
-        @partial(jax.jit, donate_argnums=(1, 2, 3, 4, 5))
-        def finish_admit(params, K, V, tok, k1, v1, logits, slot, shift,
-                         seed, temp, topk, topp):
-            """Chunked-prefill tail: ring-align + write + sample, one dispatch."""
-            return _insert_and_sample(
-                params, K, V, tok, k1, v1, logits, slot, shift,
-                seed, temp, topk, topp,
-            )
-
-        @partial(jax.jit, donate_argnums=(0, 1))
-        def write_prefix_block(k1, v1, kb, vb, start):
-            """Write one CACHED prefix block into a transient row cache at
-            S-offset ``start`` (hit-path admit): the block lands exactly
-            where the chunked prefill would have written it, so the suffix
-            chunks resume through prefill1 unchanged. kb/vb are NOT donated
-            — they stay resident in the prefix cache for the next hit."""
-            zero = jnp.zeros((), jnp.int32)
-            k1 = kv_copy_slice(k1, kb, (zero, zero, zero, start, zero))
-            v1 = kv_copy_slice(v1, vb, (zero, zero, zero, start, zero))
-            return pin_row(k1), pin_row(v1)
-
-        @jax.jit
-        def prefill_full(params, tokens, k1, v1, n):
-            """A whole LONG prompt in ONE fresh flash dispatch (idle-engine
-            admits). Chunking exists to bound live streams' inter-token
-            gap; with nothing else decoding it is pure overhead — measured
-            on-chip at 16k: ~110-180 ms per chunk of structural cost
-            beyond the matmuls (scripts/ablate_chunk_one.py), 5.2 s
-            chunked vs 2.3 s for this path. Tokens are right-padded to a
-            pow2 bucket (pad keys sit at positions only pad queries can
-            see; the rolled-in junk above ``n`` lands on future ring slots
-            that decode overwrites before they can become valid)."""
-            logits, k1, v1 = fwd(
-                params, tokens=tokens, k_cache=pin_row(k1), v_cache=pin_row(v1),
-                start_pos=jnp.zeros((1,), jnp.int32),
-                logit_positions=jnp.reshape(n - 1, (1,)),
-                fresh_prefill=True,
-            )
-            return logits, pin_row(k1), pin_row(v1)
-
-        @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(6,))
-        def prefill_chunk_group(params, tokens, km, vm, start, last_pos, window):
-            """One [m, C] chunk of a BATCHED chunked admit. Donates the
-            m-row transient cache pair (reassigned every iteration; without
-            donation each chunk would briefly hold 2x the m-row caches).
-            ``window`` (static, bucketed >= start + C) bounds reads to the
-            live prefix — see prefill1."""
-            logits, km, vm = fwd(
-                params, tokens=tokens, k_cache=pin_row(km), v_cache=pin_row(vm),
-                start_pos=start,
-                logit_positions=last_pos, uniform_start=True, attn_window=window,
-            )
-            return logits, pin_row(km), pin_row(vm)
-
-        @jax.jit
-        def select_end(final, logits, is_end):
-            """Keep each row's logits from the chunk its prompt ENDS in."""
-            return jnp.where(is_end[:, None, None], logits, final)
-
-        @partial(jax.jit, donate_argnums=(1, 2, 3))
-        def finish_admit_group(params, K, V, tok, km, vm, final_logits,
-                               slots, shifts, seeds, temps, topks, topps):
-            """Batched chunked-prefill tail: per-row ring-align + write +
-            first-token sample for m rows in ONE dispatch. km/vm are NOT
-            donated: the AOT compile path double-counts donated buffers
-            against the HBM budget, and the m-row transients are the
-            largest operands here — donating them would spuriously reject
-            configs whose real peak fits comfortably."""
-            m = final_logits.shape[0]
-            zero = jnp.zeros((), jnp.int32)
-            firsts = sample_rows(
-                final_logits[:, 0], seeds, jnp.zeros((m,), jnp.int32),
-                temps, topks, topps,
-            )
-
-            def body(carry, i):
-                K, V, tok = carry
-                k1 = kv_roll_s(row_of(km, i), shifts[i], s_axis=3)
-                v1 = kv_roll_s(row_of(vm, i), shifts[i], s_axis=3)
-                K = kv_copy_slice(K, k1, (slots[i], zero, zero, zero, zero))
-                V = kv_copy_slice(V, v1, (slots[i], zero, zero, zero, zero))
-                tok = jax.lax.dynamic_update_slice(
-                    tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1), (slots[i],)
-                )
-                return (K, V, tok), None
-
-            (K, V, tok), _ = jax.lax.scan(
-                body, (K, V, tok), jnp.arange(m, dtype=jnp.int32)
-            )
-            return firsts, pin_cache(K), pin_cache(V), tok
-
-        max_seq = self.max_seq
-
-        @partial(jax.jit, donate_argnums=(0, 1))
-        def compact_ring(K, V, shift):
-            """Roll every row's S axis so the shared validity window ends at
-            a fresh head below max_seq again — the wrapped ring's recovery
-            path (VERDICT r2 weak #7: without this, one wrap costs windowed
-            attention reads for the rest of the worker's life)."""
-            return (
-                pin_cache(kv_roll_s(K, shift, s_axis=3)),
-                pin_cache(kv_roll_s(V, shift, s_axis=3)),
-            )
-
-        @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(11, 12))
-        def decode(params, tok, K, V, pos, ring, seeds, steps, temp, topk, topp,
-                   n, window):
-            """n decode steps in one dispatch (device-side scan): the host
-            sees one transfer in and one [B, n] token readback — and the
-            next-token carry stays ON DEVICE (returned as ``tok``), so the
-            NEXT burst can be dispatched before this one's tokens are read
-            back (the depth-2 pipeline in _run). ``pos``/``steps`` are
-            device-resident carries too (returned advanced by n): with them
-            re-uploaded every burst, every burst would pay three more
-            host->device transfers. ``window`` (static) bounds attention reads to the live
-            ring prefix while the ring has not wrapped — the dominant HBM
-            saving at partial cache occupancy (~35% step time at half-full,
-            granite-2b b32)."""
-
-            def body(carry, i):
-                tok, K, V = carry
-                logits, K, V = fwd(
-                    params, tokens=tok[:, None], k_cache=K, v_cache=V,
-                    start_pos=pos + i, ring_slot=(ring + i) % max_seq,
-                    attn_window=window,
-                )
-                nxt = sample_rows(logits[:, -1, :], seeds, steps + i, temp, topk, topp)
-                return (nxt, K, V), nxt
-
-            (tok, K, V), toks = jax.lax.scan(
-                body, (tok, pin_cache(K), pin_cache(V)), jnp.arange(n, dtype=jnp.int32)
-            )
-            # [B, n] tokens, caches, device-side carries
-            return toks.T, pin_cache(K), pin_cache(V), tok, pos + n, steps + n
-
-        @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(10, 11))
-        def decode_pos(params, tok, K, V, pos, seeds, steps, temp, topk, topp,
-                       n, window):
-            """Positional-layout decode burst: spec mode's fallback when no
-            slot has a draft (or occupancy passed spec_max_active). Same
-            contract as ``decode`` minus the ring scalar — each row writes
-            its fresh KV at its own sequence position ``pos + i`` (per-row
-            scatter) and attention masks by ``key_pos <= position``."""
-
-            def body(carry, i):
-                tok, K, V = carry
-                logits, K, V = fwd(
-                    params, tokens=tok[:, None], k_cache=K, v_cache=V,
-                    start_pos=pos + i, attn_window=window,
-                )
-                nxt = sample_rows(logits[:, -1, :], seeds, steps + i, temp, topk, topp)
-                return (nxt, K, V), nxt
-
-            (tok, K, V), toks = jax.lax.scan(
-                body, (tok, pin_cache(K), pin_cache(V)), jnp.arange(n, dtype=jnp.int32)
-            )
-            return toks.T, pin_cache(K), pin_cache(V), tok, pos + n, steps + n
-
-        @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(11,))
-        def decode_pos_ext(params, tok, K, V, pos, seeds, steps, temp, topk,
-                           topp, mask, window):
-            """Single masked positional decode step with logprob readback —
-            the "ext" regime program, dispatched whenever any live slot
-            needs constrained decoding or logprobs. ``mask`` [B, V] bans
-            tokens before truncation inside sample_rows; all-True rows are
-            a bitwise no-op, so normal slots ride along unchanged. n is
-            fixed at 1: the mask for step i+1 depends on the token chosen
-            at step i (a host-side DFA walk), so bursts cannot scan."""
-            logits, K, V = fwd(
-                params, tokens=tok[:, None], k_cache=pin_cache(K),
-                v_cache=pin_cache(V), start_pos=pos, attn_window=window,
-            )
-            raw = logits[:, -1, :]
-            nxt = sample_rows(raw, seeds, steps, temp, topk, topp, mask=mask)
-            logp = jax.nn.log_softmax(raw.astype(jnp.float32), axis=-1)
-            chosen = jnp.take_along_axis(logp, nxt[:, None], axis=-1)[:, 0]
-            kk = min(LOGPROBS_K, raw.shape[-1])
-            top_lp, top_ids = jax.lax.top_k(logp, kk)
-            return (nxt, chosen, top_ids, top_lp, pin_cache(K), pin_cache(V),
-                    nxt, pos + 1, steps + 1)
-
-        @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(12,))
-        def spec_verify(params, tok, K, V, pos, drafts, dlen, seeds, steps,
-                        temp, topk, topp, window):
-            """One width-(k+1) VERIFY dispatch: forward the device carry
-            token plus k drafted tokens through the positional decode
-            cache-write path in a single program (the weight tree is read
-            once for k+1 token positions — the bandwidth conversion the
-            whole feature exists for), then run the rejection-sampling
-            acceptance rule on device. Only the accepted prefix advances
-            the carries; KV written for rejected positions is stale by
-            construction (see spec.py: masked by position, overwritten by
-            this row's own future writes — no rollback)."""
-            toks_in = jnp.concatenate([tok[:, None], drafts], axis=1)  # [B,k+1]
-            logits, K, V = fwd(
-                params, tokens=toks_in, k_cache=pin_cache(K), v_cache=pin_cache(V),
-                start_pos=pos, attn_window=window,
-            )
-            K, V = pin_cache(K), pin_cache(V)
-            out, n_emit = spec_accept_rows(
-                logits, drafts, dlen, seeds, steps, temp, topk, topp
-            )
-            new_tok = jnp.take_along_axis(out, (n_emit - 1)[:, None], axis=1)[:, 0]
-            width = toks_in.shape[1]
-            return out, n_emit, K, V, new_tok, pos + n_emit, steps + width
-
-        # -- paged-KV jit grid ------------------------------------------------
-        # Every program below reads/writes the serving cache THROUGH a block
-        # table over the shared pool [NB, L, Hkv, T, D] instead of a
-        # contiguous per-slot ring. The pool replaces K/V wholesale in _run
-        # when self.paged; the legacy programs above stay untouched (and are
-        # the KV_PAGED=0 equivalence baseline).
-        if self.paged:
-            T = self.kv_block_tokens
-            pin_pool = pin_row  # pool [NB, L, Hkv, T, D]: heads at index 2
-
-            @partial(jax.jit, donate_argnums=(0,))
-            def sample_first(tok, logits, slot, seed, temp, topk, topp):
-                """Full-prefix-hit admit: ZERO KV copies — the slot's block
-                table already references the cached blocks, so all that is
-                left on device is sampling token 0 from the stored
-                prompt-end logits into the carry."""
-                first = sample_rows(
-                    logits[:, 0], seed[None], jnp.zeros((1,), jnp.int32),
-                    temp[None], topk[None], topp[None],
-                )
-                tok = jax.lax.dynamic_update_slice(tok, first, (slot,))
-                return first, tok
-
-            def _write_and_sample(KP, VP, tok, k1, v1, logits, bids, slot,
-                                  seed, temp, topk, topp):
-                KP = pin_pool(kv_pool_write_row(KP, k1, bids))
-                VP = pin_pool(kv_pool_write_row(VP, v1, bids))
-                first = sample_rows(
-                    logits[:, 0], seed[None], jnp.zeros((1,), jnp.int32),
-                    temp[None], topk[None], topp[None],
-                )
-                tok = jax.lax.dynamic_update_slice(tok, first, (slot,))
-                return first, KP, VP, tok
-
-            @partial(jax.jit, donate_argnums=(1, 2, 3))
-            def admit_fused_paged(params, KP, VP, tok, tokens, n, bids, slot,
-                                  seed, temp, topk, topp):
-                """Short-prompt admit, paged: prefill a bucket-length
-                transient row on device and write its blocks straight into
-                the pool at ``bids`` (null-padded — bucket junk past the
-                prompt's last block lands in block 0 and is never read
-                unmasked). No ring roll: paged mode is positional."""
-                from ..models.llama import make_cache as _mk
-
-                k1, v1 = _mk(cfg, 1, tokens.shape[1])
-                k1, v1 = pin_row(k1), pin_row(v1)
-                logits, k1, v1 = fwd(
-                    params, tokens=tokens, k_cache=k1, v_cache=v1,
-                    start_pos=jnp.zeros((1,), jnp.int32),
-                    logit_positions=jnp.reshape(n - 1, (1,)),
-                    fresh_prefill=True,
-                )
-                return _write_and_sample(
-                    KP, VP, tok, k1, v1, logits, bids, slot, seed, temp,
-                    topk, topp,
-                )
-
-            @partial(jax.jit, donate_argnums=(1, 2, 3))
-            def admit_many_fused_paged(params, KP, VP, tok, tokens, ns, bids,
-                                       slots, seeds, temps, topks, topps):
-                """Batched short admit, paged: one [m, bucket] prefill, then
-                a scan writes each row's blocks to its own table entries.
-                Pad rows carry all-null bids (junk into block 0)."""
-                from ..models.llama import make_cache as _mk
-
-                m, bucket = tokens.shape
-                km, vm = _mk(cfg, m, bucket)
-                km, vm = pin_row(km), pin_row(vm)
-                logits, km, vm = fwd(
-                    params, tokens=tokens, k_cache=km, v_cache=vm,
-                    start_pos=jnp.zeros((m,), jnp.int32),
-                    logit_positions=ns - 1,
-                    fresh_prefill=True,
-                )
-                zero = jnp.zeros((), jnp.int32)
-                firsts = sample_rows(
-                    logits[:, 0], seeds, jnp.zeros((m,), jnp.int32), temps,
-                    topks, topps,
-                )
-                def body(carry, i):
-                    KP, VP, tok = carry
-                    k1, v1 = row_of(km, i), row_of(vm, i)
-                    KP = kv_pool_write_row(KP, k1, bids[i])
-                    VP = kv_pool_write_row(VP, v1, bids[i])
-                    tok = jax.lax.dynamic_update_slice(
-                        tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1),
-                        (slots[i],),
-                    )
-                    return (KP, VP, tok), None
-
-                (KP, VP, tok), _ = jax.lax.scan(
-                    body, (KP, VP, tok), jnp.arange(m, dtype=jnp.int32)
-                )
-                return firsts, pin_pool(KP), pin_pool(VP), tok
-
-            @partial(jax.jit, donate_argnums=(1, 2, 3))
-            def finish_admit_paged(params, KP, VP, tok, k1, v1, logits, bids,
-                                   slot, seed, temp, topk, topp):
-                """Chunked/flash-prefill tail, paged: scatter the transient
-                row into the pool and sample token 0. ``bids`` is a full
-                [max_seq/T] row with NULL entries for blocks that must not
-                be written — shared prefix blocks (the slot references the
-                cache's copies directly) and the junk tail past the
-                prompt. k1/v1 are NOT donated: the block re-layout cannot
-                alias the row buffer, so donation would only warn."""
-                return _write_and_sample(
-                    KP, VP, tok, k1, v1, logits, bids, slot, seed, temp,
-                    topk, topp,
-                )
-
-            @partial(jax.jit, donate_argnums=(1, 2, 3))
-            def finish_admit_group_paged(params, KP, VP, tok, km, vm,
-                                         final_logits, bids, slots, seeds,
-                                         temps, topks, topps):
-                """Batched chunked tail, paged. km/vm NOT donated — same
-                AOT double-count reasoning as finish_admit_group."""
-                m = final_logits.shape[0]
-                firsts = sample_rows(
-                    final_logits[:, 0], seeds, jnp.zeros((m,), jnp.int32),
-                    temps, topks, topps,
-                )
-
-                def body(carry, i):
-                    KP, VP, tok = carry
-                    k1, v1 = row_of(km, i), row_of(vm, i)
-                    KP = kv_pool_write_row(KP, k1, bids[i])
-                    VP = kv_pool_write_row(VP, v1, bids[i])
-                    tok = jax.lax.dynamic_update_slice(
-                        tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1),
-                        (slots[i],),
-                    )
-                    return (KP, VP, tok), None
-
-                (KP, VP, tok), _ = jax.lax.scan(
-                    body, (KP, VP, tok), jnp.arange(m, dtype=jnp.int32)
-                )
-                return firsts, pin_pool(KP), pin_pool(VP), tok
-
-            @partial(jax.jit, donate_argnums=(0, 1))
-            def fill_row_chunk(k1, v1, KP, VP, bids, start):
-                """Copy C//T cached pool blocks into a transient row cache
-                at S-offset ``start`` (partial-prefix-hit admit): suffix
-                chunks then attend over the prefix exactly as if it had
-                been prefilled here. KP/VP are read-only — the cached
-                blocks stay shared; only the transient gets a copy."""
-                kb = kv_pool_read_blocks(KP, bids)
-                vb = kv_pool_read_blocks(VP, bids)
-                zero = jnp.zeros((), jnp.int32)
-                k1 = kv_copy_slice(k1, kb, (zero, zero, zero, start, zero))
-                v1 = kv_copy_slice(v1, vb, (zero, zero, zero, start, zero))
-                return pin_row(k1), pin_row(v1)
-
-            def _touched(pos, width, nb):
-                """View-block positions a ``width``-token write starting at
-                ``pos`` can touch, clipped into the view (zombie rows past
-                max_seq clamp into their own last block — always private,
-                and their tokens are never delivered)."""
-                ntb = min(nb, (width - 1) // T + 2)
-                return jnp.clip(
-                    pos[:, None] // T
-                    + jnp.arange(ntb, dtype=jnp.int32)[None, :],
-                    0, nb - 1,
-                )
-
-            @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(11, 12))
-            def decode_pos_paged(params, tok, KP, VP, tbl, pos, seeds, steps,
-                                 temp, topk, topp, n, nb):
-                """Paged decode burst: gather each slot's first ``nb`` table
-                blocks into a contiguous [B, L, Hkv, nb*T, D] view, run the
-                same positional scan as decode_pos over it (the view extent
-                IS the attention window — nb rides the same pow2 ladder, so
-                reduction extents match the contiguous path), then scatter
-                back only the blocks this burst could have written."""
-                tbl_n = jax.lax.slice_in_dim(tbl, 0, nb, axis=1)
-                Kv = pin_row(kv_pool_gather_view(KP, tbl_n))
-                Vv = pin_row(kv_pool_gather_view(VP, tbl_n))
-
-                def body(carry, i):
-                    tok, Kc, Vc = carry
-                    logits, Kc, Vc = fwd(
-                        params, tokens=tok[:, None], k_cache=Kc, v_cache=Vc,
-                        start_pos=pos + i,
-                    )
-                    nxt = sample_rows(
-                        logits[:, -1, :], seeds, steps + i, temp, topk, topp
-                    )
-                    return (nxt, Kc, Vc), nxt
-
-                (tok, Kv, Vv), toks = jax.lax.scan(
-                    body, (tok, Kv, Vv), jnp.arange(n, dtype=jnp.int32)
-                )
-                vb = _touched(pos, n, nb)
-                KP = pin_pool(kv_pool_scatter_view(KP, Kv, tbl_n, vb))
-                VP = pin_pool(kv_pool_scatter_view(VP, Vv, tbl_n, vb))
-                return toks.T, KP, VP, tok, pos + n, steps + n
-
-            @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(12,))
-            def decode_pos_paged_ext(params, tok, KP, VP, tbl, pos, seeds,
-                                     steps, temp, topk, topp, mask, nb):
-                """Paged twin of decode_pos_ext: one masked step with
-                logprob readback through the gather-view / scatter-back
-                frame. Same n=1 constraint (next mask needs this token)."""
-                tbl_n = jax.lax.slice_in_dim(tbl, 0, nb, axis=1)
-                Kv = pin_row(kv_pool_gather_view(KP, tbl_n))
-                Vv = pin_row(kv_pool_gather_view(VP, tbl_n))
-                logits, Kv, Vv = fwd(
-                    params, tokens=tok[:, None], k_cache=Kv, v_cache=Vv,
-                    start_pos=pos,
-                )
-                raw = logits[:, -1, :]
-                nxt = sample_rows(raw, seeds, steps, temp, topk, topp,
-                                  mask=mask)
-                logp = jax.nn.log_softmax(raw.astype(jnp.float32), axis=-1)
-                chosen = jnp.take_along_axis(logp, nxt[:, None], axis=-1)[:, 0]
-                kk = min(LOGPROBS_K, raw.shape[-1])
-                top_lp, top_ids = jax.lax.top_k(logp, kk)
-                vb = _touched(pos, 1, nb)
-                KP = pin_pool(kv_pool_scatter_view(KP, Kv, tbl_n, vb))
-                VP = pin_pool(kv_pool_scatter_view(VP, Vv, tbl_n, vb))
-                return (nxt, chosen, top_ids, top_lp, KP, VP, nxt, pos + 1,
-                        steps + 1)
-
-            @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(13,))
-            def spec_verify_paged(params, tok, KP, VP, tbl, pos, drafts, dlen,
-                                  seeds, steps, temp, topk, topp, nb):
-                """Paged spec verify: the same gather-view / scatter-back
-                frame as decode_pos_paged around the width-(k+1) verify
-                forward — spec decode's positional layout IS the block
-                table, no separate positional cache."""
-                tbl_n = jax.lax.slice_in_dim(tbl, 0, nb, axis=1)
-                Kv = pin_row(kv_pool_gather_view(KP, tbl_n))
-                Vv = pin_row(kv_pool_gather_view(VP, tbl_n))
-                toks_in = jnp.concatenate([tok[:, None], drafts], axis=1)
-                logits, Kv, Vv = fwd(
-                    params, tokens=toks_in, k_cache=Kv, v_cache=Vv,
-                    start_pos=pos,
-                )
-                out, n_emit = spec_accept_rows(
-                    logits, drafts, dlen, seeds, steps, temp, topk, topp
-                )
-                new_tok = jnp.take_along_axis(
-                    out, (n_emit - 1)[:, None], axis=1
-                )[:, 0]
-                width = toks_in.shape[1]
-                vb = _touched(pos, width, nb)
-                KP = pin_pool(kv_pool_scatter_view(KP, Kv, tbl_n, vb))
-                VP = pin_pool(kv_pool_scatter_view(VP, Vv, tbl_n, vb))
-                return out, n_emit, KP, VP, new_tok, pos + n_emit, steps + width
-
-            @partial(jax.jit, donate_argnums=(0, 1))
-            def pool_copy_block(KP, VP, dst, src):
-                """Copy-on-write: duplicate one shared block before a write."""
-                return (
-                    pin_pool(kv_pool_copy_block(KP, dst, src)),
-                    pin_pool(kv_pool_copy_block(VP, dst, src)),
-                )
-
-            # -- Pallas paged-decode twins (ops/paged_attention.py) --------
-            # Same signatures and return contracts as the *_paged programs
-            # minus the ``nb`` static arg: the kernel walks a slot's table up
-            # to its last live block inside one program, so one compile per
-            # burst width serves every context length — no gather-view
-            # materialization, no scatter-back, no pow2-ladder recompiles.
-            # Write-then-attend happens per layer
-            # inside forward_decode_paged (the pool is the only KV storage
-            # these programs touch).
-            fwd_paged = partial(forward_decode_paged, cfg=cfg, mesh=mesh)
-
-            @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(11,))
-            def decode_pos_moe(params, tok, KP, VP, tbl, pos, seeds,
-                               steps, temp, topk, topp, n):
-                """decode_pos_pallas for a family with routed-expert layers:
-                the same burst, and per step and expert layer the distinct
-                experts hit, the most rows on one expert and the
-                live rows, appended to the token array as 3 x layers rows
-                ([B + 3 Le, n]) so that they come back in the burst's one
-                readback."""
-                def body(carry, i):
-                    tok, KP, VP = carry
-                    logits, KP, VP, st = fwd_paged(
-                        params, tokens=tok[:, None], k_pool=KP, v_pool=VP,
-                        tbl=tbl, start_pos=pos + i, moe_stats=True,
-                    )
-                    nxt = sample_rows(
-                        logits[:, -1, :], seeds, steps + i, temp, topk, topp
-                    )
-                    return (nxt, KP, VP), (nxt, st.reshape(-1))
-
-                (tok, KP, VP), (toks, st) = jax.lax.scan(
-                    body, (tok, KP, VP), jnp.arange(n, dtype=jnp.int32)
-                )
-                out = jnp.concatenate([toks.T, st.T.astype(toks.dtype)], axis=0)
-                return (out, pin_pool(KP), pin_pool(VP), tok, pos + n,
-                        steps + n)
-
-            @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(11,))
-            def decode_pos_pallas(params, tok, KP, VP, tbl, pos, seeds,
-                                  steps, temp, topk, topp, n):
-                """Pallas decode burst: n single-token paged forwards in one
-                on-device scan, pool carried through."""
-                def body(carry, i):
-                    tok, KP, VP = carry
-                    logits, KP, VP = fwd_paged(
-                        params, tokens=tok[:, None], k_pool=KP, v_pool=VP,
-                        tbl=tbl, start_pos=pos + i,
-                    )
-                    nxt = sample_rows(
-                        logits[:, -1, :], seeds, steps + i, temp, topk, topp
-                    )
-                    return (nxt, KP, VP), nxt
-
-                (tok, KP, VP), toks = jax.lax.scan(
-                    body, (tok, KP, VP), jnp.arange(n, dtype=jnp.int32)
-                )
-                return (toks.T, pin_pool(KP), pin_pool(VP), tok, pos + n,
-                        steps + n)
-
-            @partial(jax.jit, donate_argnums=(2, 3))
-            def decode_pos_pallas_ext(params, tok, KP, VP, tbl, pos, seeds,
-                                      steps, temp, topk, topp, mask):
-                """Pallas twin of decode_pos_paged_ext: one masked step with
-                logprob readback straight off the pool."""
-                logits, KP, VP = fwd_paged(
-                    params, tokens=tok[:, None], k_pool=KP, v_pool=VP,
-                    tbl=tbl, start_pos=pos,
-                )
-                raw = logits[:, -1, :]
-                nxt = sample_rows(raw, seeds, steps, temp, topk, topp,
-                                  mask=mask)
-                logp = jax.nn.log_softmax(raw.astype(jnp.float32), axis=-1)
-                chosen = jnp.take_along_axis(logp, nxt[:, None], axis=-1)[:, 0]
-                kk = min(LOGPROBS_K, raw.shape[-1])
-                top_lp, top_ids = jax.lax.top_k(logp, kk)
-                return (nxt, chosen, top_ids, top_lp, pin_pool(KP),
-                        pin_pool(VP), nxt, pos + 1, steps + 1)
-
-            @partial(jax.jit, donate_argnums=(2, 3))
-            def spec_verify_pallas(params, tok, KP, VP, tbl, pos, drafts,
-                                   dlen, seeds, steps, temp, topk, topp):
-                """Pallas spec verify: the width-(k+1) draft bundle rides the
-                same kernel (W = k+1 query rows per slot) — rejected drafts'
-                pool rows are stale-by-position, overwritten by that slot's
-                next writes, exactly the positional-layout contract."""
-                toks_in = jnp.concatenate([tok[:, None], drafts], axis=1)
-                logits, KP, VP = fwd_paged(
-                    params, tokens=toks_in, k_pool=KP, v_pool=VP,
-                    tbl=tbl, start_pos=pos,
-                )
-                out, n_emit = spec_accept_rows(
-                    logits, drafts, dlen, seeds, steps, temp, topk, topp
-                )
-                new_tok = jnp.take_along_axis(
-                    out, (n_emit - 1)[:, None], axis=1
-                )[:, 0]
-                width = toks_in.shape[1]
-                return (out, n_emit, pin_pool(KP), pin_pool(VP), new_tok,
-                        pos + n_emit, steps + width)
-
-            self._sample_first = self._timed("sample_first", sample_first)
-            self._admit_fused_paged = self._timed("admit_fused_paged", admit_fused_paged)
-            self._admit_many_fused_paged = self._timed(
-                "admit_many_fused_paged", admit_many_fused_paged
-            )
-            self._finish_admit_paged = self._timed("finish_admit_paged", finish_admit_paged)
-            self._finish_admit_group_paged = self._timed(
-                "finish_admit_group_paged", finish_admit_group_paged
-            )
-            self._fill_row_chunk = self._timed("fill_row_chunk", fill_row_chunk)
-            self._decode_pos_paged = self._timed("decode_pos_paged", decode_pos_paged)
-            self._decode_pos_paged_ext = self._timed(
-                "decode_pos_paged_ext", decode_pos_paged_ext
-            )
-            self._spec_verify_paged = self._timed("spec_verify_paged", spec_verify_paged)
-            self._pool_copy_block = self._timed("pool_copy_block", pool_copy_block)
-            self._decode_pos_pallas = self._timed(
-                "decode_pallas",
-                decode_pos_moe if cfg.n_moe_layers else decode_pos_pallas,
-            )
-            self._decode_pos_pallas_ext = self._timed(
-                "decode_pallas_ext", decode_pos_pallas_ext
-            )
-            self._spec_verify_pallas = self._timed(
-                "spec_verify_pallas", spec_verify_pallas
-            )
-
-        self._prefill1 = self._timed("prefill1", prefill1)
-        self._prefill_full = self._timed("prefill_full", prefill_full)
-        self._write_prefix_block = self._timed("write_prefix_block", write_prefix_block)
-        self._admit_fused = self._timed("admit_fused", admit_fused)
-        self._admit_many_fused = self._timed("admit_many_fused", admit_many_fused)
-        self._finish_admit = self._timed("finish_admit", finish_admit)
-        self._prefill_chunk_group = self._timed("prefill_chunk_group", prefill_chunk_group)
-        self._select_end = self._timed("select_end", select_end)
-        self._finish_admit_group = self._timed("finish_admit_group", finish_admit_group)
-        self._decode = self._timed("decode", decode)
-        self._decode_pos = self._timed("decode_pos", decode_pos)
-        self._decode_pos_ext = self._timed("decode_pos_ext", decode_pos_ext)
-        self._spec_verify = self._timed("spec_verify", spec_verify)
-        self._compact_ring = self._timed("compact_ring", compact_ring)
+        # the device programs (serve/programs.py), each behind the dispatch
+        # timer as ``self._<table name>``
+        for name, fn in build_programs(
+            cfg, mesh, max_seq=self.max_seq, paged=self.paged,
+            kv_block_tokens=self.kv_block_tokens, sample_rows=sample_rows,
+        ).items():
+            setattr(self, "_" + name, self._timed(name, fn))
+        # per-dispatch ``_name=`` override: the ``_ring`` tag of a prefill
+        # whose width takes the sp ring-attention path
+        self._ring_name = partial(ring_name, cfg, mesh)
 
         self._inbox: _queue.Queue[_Request | None] = _queue.Queue()
         # cancel notices for the owner thread (consumer-gone requests); the
@@ -1676,82 +854,47 @@ class ContinuousBatcher:
         self.crashed: BaseException | None = None
         self._waitlist: list[_Request] = []
 
-    def _ring_name(self, base: str, t: int) -> str | None:
-        """Per-dispatch metrics-name override for a full-prefill of padded
-        width ``t``: tagged ``_ring`` when this bucket's program takes the
-        sp ring-attention path (parallel.ring_attention.use_ring_prefill —
-        t is trace-time static, so the tag matches what the jit compiled).
-        None means "use the wrapped name"."""
-        if self.mesh is None:
-            return None
-        from ..parallel.ring_attention import use_ring_prefill
-
-        if not use_ring_prefill(self.mesh, t):
-            return None
-        if self.cfg.is_moe and getattr(self.cfg, "use_routed_moe", False):
-            base += "_moe"
-        return base + "_ring"
-
     def _timed(self, name: str, fn):
-        """Wrap one jit-grid program so every dispatch lands in
+        """Wrap one program of the table so every dispatch lands in
         stats.program_ms[name] (and, when the caller passes ``_tokens=``,
         tokens-per-dispatch in program_tokens[name]). Times the host-side
         call only — it never blocks on the result, so the depth-2 decode
         pipeline is untouched; decode_step_ms remains the
-        readback-inclusive per-step number.
+        readback-inclusive per-step number. ``name`` is the table's; what
+        is recorded carries the family tag (programs.recorded_name).
 
-        Forward-bearing programs of a routed-MoE model record under a
-        ``_moe``-suffixed name (roofline.program_family) — same timing,
-        same prefill/decode classification (classify_program strips the
-        suffix), distinct metrics family.
-
-        With the efficiency plane on, the first dispatch per shape-bucket
-        also extracts flops/bytes from XLA cost analysis — BEFORE the call,
-        because the programs donate their input buffers — and every dispatch
-        then folds into the roofline counters plus, via the owner thread's
-        charge context, the per-request device-time ledger. A failed
-        extraction caches None so a program is probed at most once per
-        shape.
-
-        The first dispatch per shape-bucket is also the one that traces and
+        The first dispatch per shape-bucket is the one that traces and
         compiles: on the owner thread its wall span goes to ``_cold_spans``
-        (see ``_warm_s``)."""
-        if (name in _MOE_TAGGED_PROGRAMS and self.cfg.is_moe
-                and getattr(self.cfg, "use_routed_moe", False)):
-            name = name + "_moe"
+        (see ``_warm_s``). Nothing builds the program ahead of that call.
+
+        With the efficiency plane on, every dispatch is also charged, via
+        the owner thread's charge context, to the per-request device-time
+        ledger."""
+        name = recorded_name(self.cfg, name)
         stats = self.stats
-        eff = self._efficiency
-        cost_cache: dict = {}
+        ledger = self._efficiency
+        seen: set = set()
         is_prefill = classify_program(name) == "prefill"
         is_spec = program_base(name) in SPEC_PROGRAMS
 
         def run(*args, _tokens=None, _name=None, **kwargs):
-            t_in = time.monotonic()
             key = dispatch_shape_key(args, kwargs)
-            cold = key not in cost_cache
-            if cold:
-                if eff:
-                    # lowers the program a second time, on the calling thread
-                    with obs_spans.span("batcher.cost_probe", program=name):
-                        cost_cache[key] = extract_dispatch_cost(fn, args, kwargs)
-                else:
-                    cost_cache[key] = None
-            cost = cost_cache[key]
             t0 = time.monotonic()
             out = fn(*args, **kwargs)
             t1 = time.monotonic()
             ms = (t1 - t0) * 1e3
-            if cold and threading.current_thread() is self._thread:
-                # first dispatch of this shape: the call traced and compiled
-                # (and the cost probe above lowered it) on the owner thread,
-                # which served nobody meanwhile
-                self._cold_spans.append((t_in, t1))
+            if key not in seen:
+                seen.add(key)
+                if threading.current_thread() is self._thread:
+                    # first dispatch of this shape: the call traced and
+                    # compiled on the owner thread, which served nobody
+                    # meanwhile
+                    self._cold_spans.append((t0, t1))
             # _name: per-dispatch family tag (e.g. "prefill_full_ring" when
             # this bucket's program takes the sp ring path) — same jit, same
             # classification, distinct metrics row
             stats.record_program(_name or name, ms, _tokens)
-            if eff:
-                stats.record_dispatch_cost(_name or name, cost)
+            if ledger:
                 ctx = self._charge_ctx
                 if ctx:
                     share = ms / len(ctx)
@@ -3257,7 +2400,7 @@ class ContinuousBatcher:
                     if use_pallas:
                         self._note_compile("decode_pallas", n)
                         toks, K, V, tok_dev, pos_dev, steps_dev = (
-                            self._decode_pos_pallas(
+                            self._decode_pallas(
                                 self.params, tok_dev, K, V, tbl_dev, pos_dev,
                                 seeds_dev, steps_dev, temp, topk, topp, n,
                                 _tokens=len(act) * n,
@@ -3347,7 +2490,7 @@ class ContinuousBatcher:
                     if use_pallas:
                         self._note_compile("decode_pallas_ext")
                         (toks, lps, top_ids, top_lps, K, V, tok_dev, pos_dev,
-                         steps_dev) = self._decode_pos_pallas_ext(
+                         steps_dev) = self._decode_pallas_ext(
                             self.params, tok_dev, K, V, tbl_dev, pos_dev,
                             seeds_dev, steps_dev, temp, topk, topp, mask_dev,
                             _tokens=len(act),

@@ -29,13 +29,13 @@ from ..utils.nuid import next_nuid
 from . import constrain as constrain_mod
 from .api import ChatEngine, EngineError, ModelNotFound, Registry
 from .batcher import (
-    LOGPROBS_K,
     BatcherOverloaded,
     BatcherStopped,
     ContinuousBatcher,
 )
 from .brownout import BrownoutConfig
 from .constrain import ConstraintError, compile_token_dfa, validate_response_format
+from .programs import LOGPROBS_K
 from .qos import ANON_TENANT, DEFAULT_PRIORITY, parse_priority_header
 from .template import render_chat_template, stop_token_ids
 

@@ -1937,34 +1937,9 @@ class Worker:
                 for name, h in sorted(stats.program_token_histograms().items()):
                     r.histogram("lmstudio_program_tokens", h.snapshot(),
                                 labels={**labels, "program": name})
-            if efficiency_enabled() and hasattr(stats, "cost_counters"):
-                # compute-efficiency plane (obs/roofline.py): per-program
-                # roofline totals, rolling MFU/MBU split by program class
-                # (prefill is compute-bound → MFU headline; decode is
-                # bandwidth-bound → MBU headline), and the device-time
-                # ledger attributing every dispatch's ms to an outcome
-                flops, bytes_ = stats.cost_counters()
-                for name, v in sorted(flops.items()):
-                    r.counter("lmstudio_program_flops_total", v,
-                              labels={**labels, "program": name},
-                              help="XLA cost-analysis flops dispatched, "
-                                   "by program")
-                for name, v in sorted(bytes_.items()):
-                    r.counter("lmstudio_program_bytes_total", v,
-                              labels={**labels, "program": name},
-                              help="XLA cost-analysis bytes accessed, "
-                                   "by program")
-                util = stats.utilization()
-                for cls in ("prefill", "decode"):
-                    cl = {**labels, "class": cls}
-                    r.gauge("lmstudio_mfu", round(util[cls]["mfu"], 6),
-                            labels=cl,
-                            help="achieved / peak FLOP rate over a rolling "
-                                 "window, by program class")
-                    r.gauge("lmstudio_mbu", round(util[cls]["mbu"], 6),
-                            labels=cl,
-                            help="achieved / peak HBM bandwidth over a "
-                                 "rolling window, by program class")
+            if efficiency_enabled() and hasattr(stats, "device_time_snapshot"):
+                # the device-time ledger (obs/roofline.py): every
+                # dispatch's ms attributed to a request outcome
                 dt = stats.device_time_snapshot()
                 for cat in sorted(dt["ms"]):
                     cl = {**labels, "category": cat}

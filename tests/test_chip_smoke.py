@@ -114,14 +114,6 @@ def test_explicit_pallas_on_ineligible_layout_raises(monkeypatch):
     assert kernel() == "xla"
 
 
-def test_unknown_tpu_kind_has_no_peaks():
-    from nats_llm_studio_tpu.obs.roofline import resolve_chip_peaks
-
-    assert resolve_chip_peaks("TPU v5 lite", platform="tpu") == (197e12, 819e9)
-    with pytest.raises(ValueError, match="TPU v9 mega"):
-        resolve_chip_peaks("TPU v9 mega", platform="tpu")
-
-
 def test_unsharded_quantized_load_streams(tmp_path, monkeypatch):
     """registry._load has one load path: with mesh=None and quant="int8" a
     Q8_0 GGUF goes through the streaming loader (host requantization, then

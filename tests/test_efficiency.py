@@ -1,12 +1,11 @@
-"""Compute-efficiency plane (ISSUE 16): roofline units, the device-time
-ledger's outcome attribution through real shed/cancel/spec paths, HBM drift
-gating, and the merged cluster exposition carrying fleet MFU/MBU families.
+"""Efficiency plane (ISSUE 16): program classes, the device-time ledger's
+outcome attribution through real shed/cancel/spec paths, HBM drift gating,
+and the merged cluster exposition carrying the fleet ledger families.
 
-Unit tests pin exact values (XLA counts 2*m*n*k flops for a matmul; the
-rolling window math is checked against a fake clock); the batcher tests drive
-real served / cancelled / deadline-aborted / speculative requests and assert
-the ledger's per-category device-ms reconcile with the measured dispatch time
-within 10% — the same invariant bench.py's ``efficiency`` phase enforces.
+The batcher tests drive real served / cancelled / deadline-aborted /
+speculative requests and assert the ledger's per-category device-ms reconcile
+with the measured dispatch time within 10% — the same invariant bench.py's
+``efficiency`` phase enforces.
 """
 
 import asyncio
@@ -23,12 +22,9 @@ from nats_llm_studio_tpu.obs.aggregator import merge_expositions
 from nats_llm_studio_tpu.obs.roofline import (
     WASTE_CATEGORIES,
     HbmLedger,
-    RollingUtilization,
     classify_program,
     dispatch_shape_key,
     efficiency_enabled,
-    extract_dispatch_cost,
-    resolve_chip_peaks,
 )
 from nats_llm_studio_tpu.serve.batcher import BatcherOverloaded, ContinuousBatcher, _Request
 
@@ -51,34 +47,7 @@ async def _wait_for(pred, timeout=10.0, what=""):
     raise AssertionError(f"timed out waiting for {what}")
 
 
-# -- chip peak table ----------------------------------------------------------
-
-
-def test_resolve_chip_peaks_table(monkeypatch):
-    monkeypatch.delenv("TPU_PEAK_FLOPS", raising=False)
-    monkeypatch.delenv("TPU_HBM_GBPS", raising=False)
-    assert resolve_chip_peaks("TPU v5e") == (197e12, 819e9)
-    assert resolve_chip_peaks("TPU v5 lite") == (197e12, 819e9)
-    assert resolve_chip_peaks("TPU v5p") == (459e12, 2765e9)
-    assert resolve_chip_peaks("TPU v6e") == (918e12, 1640e9)
-    assert resolve_chip_peaks("TPU v4") == (275e12, 1228e9)
-    # off the TPU platform, unknown kinds (and the CPU backend's) get the
-    # modest fallback; on it, an unknown kind raises and names the kind
-    assert resolve_chip_peaks("") == (5e11, 5e10)
-    assert resolve_chip_peaks("Quantum Abacus 9000") == (5e11, 5e10)
-    with pytest.raises(ValueError, match="Quantum Abacus 9000"):
-        resolve_chip_peaks("Quantum Abacus 9000", platform="tpu")
-
-
-def test_resolve_chip_peaks_env_overrides(monkeypatch):
-    monkeypatch.setenv("TPU_PEAK_FLOPS", "123e12")
-    monkeypatch.setenv("TPU_HBM_GBPS", "456")
-    assert resolve_chip_peaks("TPU v5e") == (123e12, 456e9)
-    assert resolve_chip_peaks("") == (123e12, 456e9)
-    # garbage overrides fall back to the table, never raise
-    monkeypatch.setenv("TPU_PEAK_FLOPS", "not-a-number")
-    monkeypatch.setenv("TPU_HBM_GBPS", "")
-    assert resolve_chip_peaks("TPU v5e") == (197e12, 819e9)
+# -- switch, program classes, shape keys --------------------------------------
 
 
 def test_efficiency_kill_switch(monkeypatch):
@@ -100,22 +69,6 @@ def test_classify_program():
     assert set(WASTE_CATEGORIES) >= {"served", "spec_rejected", "other"}
 
 
-# -- per-dispatch cost extraction ---------------------------------------------
-
-
-def test_extract_dispatch_cost_exact_matmul():
-    """XLA's cost model counts 2*m*n*k flops for one matmul — pin the exact
-    value so a silently broken extraction can't pass as 'nonzero'."""
-    fn = jax.jit(lambda a, b: a @ b)
-    a = jnp.ones((64, 64), jnp.float32)
-    cost = extract_dispatch_cost(fn, (a, a), {})
-    assert cost is not None
-    flops, bytes_ = cost
-    assert flops == 2 * 64**3 == 524288
-    # two (64,64) f32 inputs + one output = 3 * 16 KiB minimum traffic
-    assert bytes_ >= 3 * 64 * 64 * 4
-
-
 def test_dispatch_shape_key_buckets():
     a = jnp.ones((8, 4), jnp.float32)
     b = jnp.ones((8, 4), jnp.float32)
@@ -123,35 +76,6 @@ def test_dispatch_shape_key_buckets():
     assert dispatch_shape_key((a, 3), {}) == dispatch_shape_key((b, 3), {})
     assert dispatch_shape_key((a,), {}) != dispatch_shape_key((c,), {})
     assert dispatch_shape_key((a,), {"k": 1}) != dispatch_shape_key((a,), {"k": 2})
-
-
-def test_extract_dispatch_cost_never_raises():
-    assert extract_dispatch_cost(object(), (), {}) is None
-
-
-# -- rolling utilization ------------------------------------------------------
-
-
-def test_rolling_utilization_fake_clock():
-    t = [0.0]
-    u = RollingUtilization(window_s=10.0, clock=lambda: t[0])
-    u.add(1e9, 2e9)
-    t[0] = 10.0
-    # span is now - oldest sample = 10 s
-    assert u.rates() == (1e8, 2e8)
-    assert u.utilization((1e12, 1e12)) == (1e-4, 2e-4)
-    # past the window the sample expires and the plane reads idle, not stale
-    t[0] = 21.0
-    assert u.rates() == (0.0, 0.0)
-    assert u.utilization((1e12, 1e12)) == (0.0, 0.0)
-
-
-def test_rolling_utilization_clamps_to_one():
-    t = [0.0]
-    u = RollingUtilization(window_s=10.0, clock=lambda: t[0])
-    u.add(1e15, 1e15)
-    t[0] = 1.0
-    assert u.utilization((1e9, 1e9)) == (1.0, 1.0)
 
 
 # -- HBM ledger ---------------------------------------------------------------
@@ -266,12 +190,6 @@ async def test_ledger_attributes_served_and_cancelled(model):
         # tokens count toward goodput only for the served outcome
         assert dt["tokens"]["served"] >= 8
         assert b.stats.goodput_tokens_per_device_s() > 0.0
-        # the rolling roofline saw both prefill and decode dispatches
-        util = b.stats.utilization((1e12, 1e12))
-        assert util["prefill"]["mfu"] > 0.0 and util["prefill"]["mbu"] > 0.0
-        assert util["decode"]["mfu"] > 0.0 and util["decode"]["mbu"] > 0.0
-        flops, bytes_ = b.stats.cost_counters()
-        assert sum(flops.values()) > 0 and sum(bytes_.values()) > 0
     finally:
         b.stop()
 
@@ -352,30 +270,30 @@ async def test_ledger_waste_tag_reclassifies_prefill(model):
 
 
 def test_merge_expositions_averages_ratio_gauges():
-    """Two workers at 40% and 20% MFU merge to 30%, not 60% — while totals
-    (counters) still sum."""
+    """Two workers at 400 and 200 tokens per device-second merge to 300, not
+    600 — while totals (counters) still sum."""
     w1 = (
-        "# TYPE lmstudio_mfu gauge\n"
-        'lmstudio_mfu{class="decode",worker_id="w1"} 0.4\n'
+        "# TYPE lmstudio_goodput_tokens_per_device_s gauge\n"
+        'lmstudio_goodput_tokens_per_device_s{model="m",worker_id="w1"} 400\n'
         "# TYPE lmstudio_device_ms_total counter\n"
         'lmstudio_device_ms_total{category="served",worker_id="w1"} 100\n'
     )
     w2 = (
-        "# TYPE lmstudio_mfu gauge\n"
-        'lmstudio_mfu{class="decode",worker_id="w2"} 0.2\n'
+        "# TYPE lmstudio_goodput_tokens_per_device_s gauge\n"
+        'lmstudio_goodput_tokens_per_device_s{model="m",worker_id="w2"} 200\n'
         "# TYPE lmstudio_device_ms_total counter\n"
         'lmstudio_device_ms_total{category="served",worker_id="w2"} 50\n'
     )
     merged = merge_expositions([w1, w2])
-    assert 'lmstudio_mfu{class="decode"} 0.3' in merged
+    assert 'lmstudio_goodput_tokens_per_device_s{model="m"} 300' in merged
     assert 'lmstudio_device_ms_total{category="served"} 150' in merged
 
 
 @async_test
 async def test_cluster_exposition_carries_efficiency_families(tmp_path, monkeypatch):
     """Acceptance e2e: after one real chat, the aggregator's merged cluster
-    exposition carries fleet lmstudio_mfu / lmstudio_device_ms_total{category}
-    families plus the gateway's lmstudio_gateway_* (folded in via the
+    exposition carries fleet lmstudio_device_ms_total{category} /
+    lmstudio_goodput_tokens_per_device_s families plus the gateway's lmstudio_gateway_* (folded in via the
     gateway's advert + directed metrics.prom subject), and the whole text
     passes the strict Prometheus checker. Gateway adverts must NOT count as
     workers in the router or the cluster gauge."""
@@ -432,12 +350,10 @@ async def test_cluster_exposition_carries_efficiency_families(tmp_path, monkeypa
         await agg.scrape_once()
         text = agg.render_cluster()
         check_prom_exposition(text)
-        assert 'lmstudio_mfu{class="prefill"' in text
-        assert 'lmstudio_mfu{class="decode"' in text
-        assert 'lmstudio_mbu{class="decode"' in text
         assert 'lmstudio_device_ms_total{category="served"' in text
         assert "lmstudio_goodput_tokens_per_device_s" in text
-        assert "lmstudio_program_flops_total{" in text
+        assert "lmstudio_device_tokens_total{" in text
+        assert "lmstudio_mfu" not in text and "lmstudio_program_flops_total" not in text
         assert "lmstudio_hbm_drift_bytes" in text
         # gateway families folded into the same cluster view
         assert "lmstudio_gateway_requests_total" in text

@@ -275,12 +275,17 @@ def _lowered_programs(monkeypatch, with_spans: bool) -> dict:
     the lowered text of every program the batcher dispatched."""
     texts: dict = {}
 
-    def capture(fn, args, kwargs):
-        texts.setdefault((fn.__name__, batcher_mod.dispatch_shape_key(args, kwargs)),
-                         fn.lower(*args, **kwargs).as_text())
-        return None
+    def capture(fn):
+        def run(*args, **kwargs):
+            # before the call: the programs donate their inputs
+            texts.setdefault((fn.__name__, batcher_mod.dispatch_shape_key(args, kwargs)),
+                             fn.lower(*args, **kwargs).as_text())
+            return fn(*args, **kwargs)
+        return run
 
-    monkeypatch.setattr(batcher_mod, "extract_dispatch_cost", capture)
+    build = batcher_mod.build_programs
+    monkeypatch.setattr(batcher_mod, "build_programs", lambda *a, **k: {
+        name: capture(fn) for name, fn in build(*a, **k).items()})
     if not with_spans:
         monkeypatch.setattr(spans, "span", _NoSpan)
         monkeypatch.setattr(spans, "record", lambda *a, **k: None)

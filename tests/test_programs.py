@@ -1,0 +1,150 @@
+"""The program table (serve/programs.py): built with no batcher, under the
+names dispatches are recorded by, importing nothing of the scheduler; and the
+one seam every dispatch crosses (``ContinuousBatcher._timed``), which builds a
+shape once and feeds ``lmstudio_program_ms``."""
+
+import ast
+import json
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from nats_llm_studio_tpu.config import WorkerConfig
+from nats_llm_studio_tpu.engine.sampling import sample_rows
+from nats_llm_studio_tpu.models.config import ModelConfig
+from nats_llm_studio_tpu.models.llama import init_params
+from nats_llm_studio_tpu.serve import Worker
+from nats_llm_studio_tpu.serve.batcher import ContinuousBatcher
+from nats_llm_studio_tpu.serve.programs import build_programs
+from nats_llm_studio_tpu.serve.registry import LocalRegistry
+from nats_llm_studio_tpu.store import ModelStore
+from nats_llm_studio_tpu.transport import EmbeddedBroker, connect
+
+from conftest import async_test
+from test_serve_e2e import build_tiny_gguf
+
+ROOT = Path(__file__).resolve().parent.parent
+
+RING = {"prefill1", "prefill_full", "write_prefix_block", "admit_fused", "admit_many_fused",
+        "finish_admit", "prefill_chunk_group", "select_end", "finish_admit_group", "decode",
+        "decode_pos", "decode_pos_ext", "spec_verify", "compact_ring"}
+PAGED = {"sample_first", "admit_fused_paged", "admit_many_fused_paged", "finish_admit_paged",
+         "finish_admit_group_paged", "fill_row_chunk", "decode_pos_paged",
+         "decode_pos_paged_ext", "spec_verify_paged", "pool_copy_block", "decode_pallas",
+         "decode_pallas_ext", "spec_verify_pallas"}
+
+
+def _cfg(family: str) -> ModelConfig:
+    if family == "dense":
+        return ModelConfig.tiny(n_layers=2, max_seq_len=64)
+    from benchmark import run
+
+    ref = run.load_module(ROOT / "benchmark/references/mla_moe_mhc.py")
+    conf = json.loads((ROOT / "benchmark/tests/rehearsal/configs/tiny-mla.json").read_text())
+    return ref.model_config(conf, 64).with_(dtype="float32")
+
+
+@pytest.mark.parametrize("family,paged,names", [
+    ("dense", True, RING | PAGED),
+    ("dense", False, RING),
+    ("latent", True, RING | PAGED),
+])
+def test_the_table_builds_with_no_batcher(family, paged, names):
+    table = build_programs(_cfg(family), None, max_seq=64, paged=paged,
+                           kv_block_tokens=16 if paged else 0, sample_rows=sample_rows)
+    assert set(table) == names
+    # every entry is a jitted program that can be lowered on its own
+    assert all(callable(getattr(fn, "lower", None)) for fn in table.values())
+
+
+@pytest.mark.parametrize("family,program", [
+    ("dense", "decode_pos_pallas"),
+    ("latent", "decode_pos_moe"),   # the burst that reads the expert counters back
+])
+def test_the_family_picks_its_decode_program(family, program):
+    table = build_programs(_cfg(family), None, max_seq=64, paged=True, kv_block_tokens=16,
+                           sample_rows=sample_rows)
+    assert table["decode_pallas"].__name__ == program
+    # the names the device trace's reduction finds the programs by
+    assert table["admit_many_fused_paged"].__name__ == "admit_many_fused_paged"
+
+
+def test_programs_imports_nothing_of_the_scheduler():
+    tree = ast.parse((ROOT / "nats_llm_studio_tpu/serve/programs.py").read_text())
+    modules = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom):
+            base = "." * node.level + (node.module or "")
+            modules |= {base} | {f"{base}.{a.name}".replace("..", ".") for a in node.names}
+        elif isinstance(node, ast.Import):
+            modules |= {a.name for a in node.names}
+    banned = ("batcher", "block_pool", "prefix_cache", "obs")
+    assert not [m for m in modules if set(m.split(".")) & set(banned)], sorted(modules)
+    src = (ROOT / "nats_llm_studio_tpu/serve/batcher.py").read_text()
+    assert "jax.jit" not in src  # no device program is defined in the scheduler
+
+
+def test_a_shapes_first_dispatch_builds_it_once():
+    """One trace, one lowering, one compile of a shape, all inside the call
+    itself: nothing asks JAX for the program before ``_timed`` dispatches it
+    (a cost probe did, and JAX then reported the shape's trace twice)."""
+    cfg = _cfg("dense")
+    b = ContinuousBatcher(init_params(cfg, jax.random.PRNGKey(0)), cfg, max_slots=2,
+                          max_seq_len=64)
+    built = []
+
+    def on_duration(event, seconds, **kw):
+        if "select_end" in str(kw.get("fun_name", "")):
+            built.append(event.rsplit("/", 1)[-1])
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        args = (jnp.zeros((3, 1, 7)), jnp.ones((3, 1, 7)), jnp.asarray([True, False, True]))
+        out = b._select_end(*args)
+        once = ["jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+                "backend_compile_duration"]
+        assert built == once, built
+        b._select_end(*args)
+        assert built == once  # the same shape again builds nothing
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+        b.stop()
+    assert out[:, 0, 0].tolist() == [1.0, 0.0, 1.0]
+    assert b.stats.program_histograms()["select_end"].snapshot().count == 2
+
+
+@async_test
+async def test_the_exposition_names_every_dispatched_program(tmp_path):
+    src = tmp_path / "tiny.gguf"
+    build_tiny_gguf(src)
+    store = ModelStore(tmp_path / "worker")
+    store.import_file(src, "acme/tiny-programs")
+    broker = await EmbeddedBroker().start()
+    worker = Worker(WorkerConfig(nats_url=broker.url), LocalRegistry(store, dtype="float32"))
+    await worker.start()
+    nc = await connect(broker.url)
+    try:
+        body = {"model": "acme/tiny-programs", "max_tokens": 6, "temperature": 0.0,
+                "messages": [{"role": "user", "content": "programs"}]}
+        reply = await nc.request("lmstudio.chat_model", json.dumps(body).encode(), timeout=50.0)
+        assert json.loads(reply.payload)["ok"]
+        batcher = worker.registry.loaded_engines()["acme/tiny-programs"].batcher
+        dispatched = set(batcher.stats.program_histograms())
+        prom = (await nc.request("lmstudio.metrics.prom", b"", timeout=10)).payload.decode()
+    finally:
+        await nc.close()
+        await worker.drain()
+        await broker.stop()
+    table = set(build_programs(batcher.cfg, None, max_seq=batcher.max_seq, paged=batcher.paged,
+                               kv_block_tokens=batcher.kv_block_tokens, sample_rows=sample_rows))
+    assert dispatched and dispatched <= table
+    assert any("admit" in n for n in dispatched) and any("decode" in n for n in dispatched)
+    in_prom = {ln.split('program="', 1)[1].split('"', 1)[0] for ln in prom.splitlines()
+               if ln.startswith("lmstudio_program_ms_count")}
+    assert in_prom == dispatched
+    for gone in ("lmstudio_mfu", "lmstudio_mbu", "lmstudio_program_flops_total",
+                 "lmstudio_program_bytes_total"):
+        assert gone not in prom
+    assert "lmstudio_device_ms_total" in prom  # the ledger stays
