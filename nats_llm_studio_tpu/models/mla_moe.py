@@ -22,15 +22,17 @@ table and the sampling are the ones every other family uses. What differs:
   the output, and the scores read the latents themselves
   (``ops/mla_attention.py``: a Pallas kernel over the pool and the table, or
   the XLA form over a gathered view).
-* **Experts are dropless.** A call with fewer (row, expert) picks than the
-  layer has experts (a decode step: 8 rows x top-4 under 64) lists on the
-  device the experts its live rows hit and reads those alone, each once, out
-  of the whole stacks indexed by (layer, expert): ``ops/moe_experts.py``.
-  Every other call (a prefill chunk, a verify bundle, quantised expert
-  stacks, a mesh of several chips) is dense dispatch: every expert computes
-  every row, weighted by the gate (0 for experts a row did not pick), a group
-  of experts at a time so the [rows, experts, width] intermediates stay
-  small. ``expert_path`` chooses, from shapes and leaf types alone.
+* **Experts are dropless**, in one of three forms. A call with fewer (row,
+  expert) picks than the layer has experts (a decode step: 8 rows x top-4
+  under 64) lists on the device the experts its live rows hit and reads
+  those alone, each once (the hit list). A call with more (a prefill chunk,
+  a chunk group, a verify bundle) sorts its picks by expert and computes each
+  on its own expert only (grouped). Both index the whole stacks by (layer,
+  expert): ``ops/moe_experts.py``. Quantised expert stacks and a mesh of
+  several chips are dense dispatch: every expert computes every row,
+  weighted by the gate (0 for experts a row did not pick), a group of
+  experts at a time so the [rows, experts, width] intermediates stay small.
+  ``expert_path`` chooses, from shapes and leaf types alone.
   ``moe_capacity_factor`` is not read. The decode path also counts, per
   layer, the distinct experts the live rows hit (the list's length: what the
   step streams) and the most rows on one expert.
@@ -123,9 +125,13 @@ def hc_maps(X: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array, cfg: ModelCo
     and ``post`` [n, B, T], ``res`` [n, n, B, T] (row i = what new stream i
     takes of each old stream). The stream axes lead, so nothing is laid out
     4 wide and a row or column sum adds whole [B, T] planes. The Sinkhorn
-    rounds are one kernel where the rows fit a lane tile (``ops/sinkhorn.py``:
-    decode) and one ``fori_loop`` otherwise (unrolled they are 20 x the
-    program text of every mixer, and half a minute of compile each)."""
+    rounds are one kernel (``ops/sinkhorn.py``): as an XLA loop they are 6
+    launches a round, 1,680 a pass through 7 layers, which was 60 % of the
+    operations of a prefill chunk and of the events a device trace of it
+    holds (PERF.md, PR 32; PR 30 for decode). Only a call of more rows than
+    the kernel's one block holds keeps the ``fori_loop`` (unrolled the rounds
+    are 20 x the program text of every mixer, and half a minute of compile
+    each)."""
     n = cfg.hc_mult
     xf = X.astype(jnp.float32)
     rrms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=(0, 3), keepdims=True) + cfg.rms_eps)
@@ -144,9 +150,7 @@ def hc_maps(X: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array, cfg: ModelCo
         return r / (jnp.sum(r, axis=0, keepdims=True) + cfg.hc_eps)
 
     rows = X.shape[1] * X.shape[2]
-    if rows <= sinkhorn.LANES:
-        # a decode step, a verify bundle: the rounds in one kernel, where the
-        # loop below is 6 launches a round of a few hundred numbers each
+    if rows <= sinkhorn.MAX_ROWS:
         res = sinkhorn.sinkhorn_rounds(
             res.reshape(n, n, rows), cfg.hc_sinkhorn_iters, cfg.hc_eps,
             interpret=jax.default_backend() != "tpu").reshape(res.shape)
@@ -200,35 +204,52 @@ _EXPERT_LEAVES = ("w_gate_e", "w_up_e", "w_down_e")
 
 
 def expert_path(cfg: ModelConfig, rows: int, stack: Params, mesh=None) -> str:
-    """``"hit_list"`` or ``"dense"``: the form the routed experts of a call of
-    ``rows`` rows take, from what the call can see and nothing else. The hit
-    list needs fewer (row, expert) picks than the layer has experts (a decode
-    step of 8 rows x top-4 under 64; a prefill chunk or a verify bundle is
-    over it, and reads every expert anyway), plain expert leaves and one
-    device. Quantised stacks (``WQUANT`` makes ``w_*_e`` QTensors) and meshes
-    of more than one chip keep the dense dispatch until a cell measures
-    them."""
-    few = rows * cfg.n_experts_used < cfg.n_experts
+    """``"hit_list"``, ``"grouped"`` or ``"dense"``: the form the routed
+    experts of a call of ``rows`` rows take, from what the call can see and
+    nothing else. With plain expert leaves on one device: the hit list where
+    the (row, expert) picks are fewer than the layer's experts (a decode step
+    of 8 rows x top-4 under 64: some experts are certainly not hit, and
+    reading is all a few rows cost), the grouped form from there up (a
+    prefill chunk, a chunk group, a verify bundle: the picks sorted by expert
+    and each computed on its own expert only). There is no row count under
+    which dense dispatch is the better of the two: it reads all 64 experts
+    whatever was picked, 2.0-2.1 ms a layer on a v5e, where the grouped form
+    reads and computes what was picked (PERF.md, PR 32: the layer alone at
+    16-1,024 rows). Quantised stacks (``WQUANT`` makes ``w_*_e`` QTensors)
+    and meshes of more than one chip keep the dense dispatch until a cell
+    measures them."""
     plain = all(isinstance(stack[k], jax.Array) for k in _EXPERT_LEAVES)
     one_device = mesh is None or mesh.size == 1
-    return "hit_list" if few and plain and one_device else "dense"
+    if not (plain and one_device):
+        return "dense"
+    return "hit_list" if rows * cfg.n_experts_used < cfg.n_experts else "grouped"
 
 
 def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = None,
-            stacks=None, place=None):
-    """Routed experts + the shared expert(s), dropless. Returns (y, stats):
-    ``stats`` is int32 [3] = (distinct experts the ``live`` rows hit, most
-    rows on one expert, live rows) when ``live`` [B] is given, else None.
+            form: str = "dense", stacks=None, place=None):
+    """Routed experts + the shared expert(s), dropless, in the ``form`` that
+    ``expert_path`` named. Returns (y, stats): ``stats`` is int32 [3] =
+    (distinct experts the ``live`` rows hit, most rows on one expert, live
+    rows) when ``live`` [B] is given, else None.
 
-    **Hit list** (``stacks`` = the three WHOLE expert stacks [L, E, ., .],
-    ``place`` this layer's place in them; ``expert_path`` says when): the experts the
-    live rows hit are listed on the device and only those are read, each
-    once, every row gated by its own weight on that expert (0 for a row that
-    did not pick it) into a float32 sum that starts from the shared expert's
-    output: the dense dispatch's sums without the terms that are exactly
-    zero. A row of a slot that holds no request adds nothing to the list; its
-    output is whatever the listed experts give it, and the batcher discards
-    it.
+    Every form is the same sum: each row's k picked experts, weighted by
+    their gates, in float32 on top of the shared expert's output. The hit
+    list and the grouped form take ``stacks``, the three WHOLE expert stacks
+    [L, E, ., .], and ``place``, this layer's place in them
+    (``ops/moe_experts.py``).
+
+    **Hit list**: the experts the live rows hit are listed on the device and
+    only those are read, each once, every row gated by its own weight on that
+    expert (0 for a row that did not pick it): the dense dispatch's sums
+    without the experts whose terms are all zero. A row of a slot that holds
+    no request adds nothing to the list; its output is whatever the listed
+    experts give it, and the batcher discards it.
+
+    **Grouped**: the rows x k (row, pick) pairs are sorted by expert, each
+    pair's row is multiplied by its own expert's matrices only, scaled by
+    its gate, and a row's k results are gathered back and summed: the dense
+    dispatch's sums without ANY term that is zero, rows x k products where
+    it makes rows x E. Every row is computed, live or not.
 
     **Dense dispatch** (``p`` holds the layer's own expert leaves): every
     expert computes every row, in groups of experts that are STATIC slices of
@@ -237,7 +258,7 @@ def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = 
     XLA copy a layer's 1.4 GB of experts into the loop's operand every step
     (46 ms a decode step for 15: PERF.md, PR 29). A prefill splits so that
     [rows, group, width] stays under ``_EXPERT_ACT_BYTES``."""
-    e = cfg.n_experts
+    e, k = cfg.n_experts, cfg.n_experts_used
     idx, gate = route(h, p, cfg)
     picked = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # [B, T, k, E]
     combine = jnp.sum(picked * gate[..., None], axis=-2)  # [B, T, E] f32
@@ -247,14 +268,21 @@ def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = 
         [jnp.sum(rows_on > 0), jnp.max(rows_on), jnp.sum(live) * h.shape[1]]).astype(jnp.int32)
     rows = h.shape[0] * h.shape[1]
     acc = swiglu(h, p["w_gate_s"], p["w_up_s"], p["w_down_s"], cfg.mlp_act).astype(jnp.float32)
-    if stacks is not None:
+    if form != "dense":
         from ..ops import moe_experts
-
-        ids, n_hit = moe_experts.hit_list(rows_on, min(e, rows * cfg.n_experts_used))
+    if form == "hit_list":
+        ids, n_hit = moe_experts.hit_list(rows_on, min(e, rows * k))
         gates = jnp.take(combine.reshape(rows, e).T, ids, axis=0)  # [places, rows]
         acc = moe_experts.moe_hit_experts_auto(
             h.reshape(rows, -1), gates, ids, n_hit, place, *stacks,
             acc.reshape(rows, -1)).reshape(acc.shape)
+        return acc.astype(h.dtype), stats
+    if form == "grouped":
+        order, at = moe_experts.sort_by_expert(idx.reshape(rows, k))
+        y = moe_experts.moe_grouped_experts_auto(
+            jnp.take(h.reshape(rows, -1), order // k, axis=0), gate.reshape(-1)[order],
+            jnp.sum(on, axis=(0, 1)), place, *stacks)  # [rows x k, d] f32, sorted
+        acc = acc + jnp.sum(y[at], axis=1).reshape(acc.shape)
         return acc.astype(h.dtype), stats
     combine = combine.astype(h.dtype)
     act_bytes = rows * e * cfg.moe_d_ff * h.dtype.itemsize
@@ -262,8 +290,8 @@ def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = 
                   if e % g == 0 and act_bytes // g <= _EXPERT_ACT_BYTES or g == e)
     size = e // groups
     for g in range(groups):
-        wg, wu, wd = (jax.tree.map(lambda x: x[g * size: (g + 1) * size], p[k])
-                      for k in _EXPERT_LEAVES)
+        wg, wu, wd = (jax.tree.map(lambda x: x[g * size: (g + 1) * size], p[k_])
+                      for k_ in _EXPERT_LEAVES)
         act = jax.nn.silu(q_einsum("btd,gdf->btgf", h, wg)) * q_einsum("btd,gdf->btgf", h, wu)
         act = act * combine[..., g * size: (g + 1) * size, None]
         acc = acc + q_einsum("btgf,gfd->btd", act, wd).astype(jnp.float32)
@@ -385,19 +413,22 @@ def _layers(params: Params, cfg: ModelConfig, X, caches, attention, live=None, m
     caller's (row caches or pools). Returns (X, caches, the expert layers'
     counters [n_moe_layers, 3] or None without ``live``).
 
-    Where the expert layers take the hit list (``expert_path``), the three
-    expert stacks leave the scan's ``xs`` and are closed over WHOLE, the scan
-    carrying the layer's place in them: a scan's slice of a stack handed to a
-    kernel or a loop as an operand would be copied, 1.4 GB a layer."""
+    Where the expert layers take the hit list or the grouped form
+    (``expert_path``), the three expert stacks leave the scan's ``xs`` and
+    are closed over WHOLE, the scan carrying the layer's place in them: a
+    scan's slice of a stack handed to a kernel or a loop as an operand would
+    be copied, 1.4 GB a layer."""
     stats = None
     rows = X.shape[1] * X.shape[2]
     for stack, first, kind in _stacks(params, cfg):
-        whole = None
-        if kind == "moe" and expert_path(cfg, rows, stack, mesh) == "hit_list":
+        form, whole = "dense", None
+        if kind == "moe":
+            form = expert_path(cfg, rows, stack, mesh)
+        if form != "dense":
             whole = tuple(stack[k] for k in _EXPERT_LEAVES)
             stack = {k: v for k, v in stack.items() if k not in _EXPERT_LEAVES}
 
-        def block(carry, inputs, kind=kind, whole=whole):
+        def block(carry, inputs, kind=kind, form=form, whole=whole):
             X, caches = carry
             p, layer, place = inputs
             X, caches = _residual(X, p, "attn", cfg, lambda h: attention(h, p, caches, layer))
@@ -406,7 +437,7 @@ def _layers(params: Params, cfg: ModelConfig, X, caches, attention, live=None, m
                     h, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act))
             else:
                 X, st = _residual(X, p, "ffn", cfg, lambda h: moe_ffn(
-                    h, p, cfg, live, whole, place))
+                    h, p, cfg, live, form, whole, place))
             return (X, caches), st
 
         place = jnp.arange(jax.tree.leaves(stack)[0].shape[0], dtype=jnp.int32)

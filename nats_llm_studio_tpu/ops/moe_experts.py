@@ -1,16 +1,30 @@
-"""The routed experts of a step with few rows: only the experts hit are read.
+"""The routed experts without the terms that are zero: two kernels.
 
-A decode step of 8 rows x top-4 picks at most 32 of a layer's 64 experts,
-and usually far fewer; the dense dispatch (``models/mla_moe.py moe_ffn``)
-streams all 64. Here the experts the live rows hit are listed on the device
-(``hit_list``) and ``moe_hit_experts`` streams the three matrices of those
-experts alone, each once, out of the WHOLE stacks ``[L, E, d, f]`` with
-(layer, expert) as indices: a layer's slice handed over as an operand would
-be copied first (1.4 GB a layer at the published widths).
+**Few rows: only the experts hit are read.** A decode step of 8 rows x top-4
+picks at most 32 of a layer's 64 experts, and usually far fewer; the dense
+dispatch (``models/mla_moe.py moe_ffn``) streams all 64. Here the experts
+the live rows hit are listed on the device (``hit_list``) and
+``moe_hit_experts`` streams the three matrices of those experts alone, each
+once, every row computed on every listed expert.
+
+**Many rows: only the picks are computed.** A prefill chunk of 256-1,024
+rows hits every expert it is going to hit anyway; what the dense dispatch
+wastes there is products, rows x 64 experts where rows x 4 were picked. The
+(row, pick) pairs are sorted by expert (``sort_by_expert``) and
+``moe_grouped_experts`` walks the sorted rows a tile at a time, each tile
+once for every expert that has rows in it (``group_visits``): rows x 4
+products plus the tiles' edges under any routing, an expert's matrices read
+once a run of tiles and not at all where no row picked it.
+
+Both take the WHOLE stacks ``[L, E, d, f]`` with (layer, expert) as indices:
+a layer's slice handed over as an operand would be copied first (1.4 GB a
+layer at the published widths).
 """
 
 from __future__ import annotations
 
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +35,15 @@ from jax.experimental.pallas import tpu as pltpu
 # (at d 3,584 in bf16: 512 columns of f, 3.7 MB a tile; half that read 7 %
 # slower on a v5e, twice that the same: PERF.md, PR 30)
 _TILE_BYTES = 24 << 20
+# sorted (row, pick) pairs a grid step of the grouped kernel computes (256
+# read 8-10 % slower at 1,024 rows: a tile is computed whole for every expert
+# with a row in it, and the tail experts have few), and the columns of f a
+# trip of its inner loop takes (the kernel's program text is one trip's:
+# unrolled over all 1,024 columns it compiled in 2.7 s for 0.85 and ran 3 %
+# faster, and ~20 prefill programs of a cold run each compile it: PERF.md,
+# PR 32)
+_ROW_TILE = 128
+_F_TRIP = 256
 
 
 def hit_list(rows_on: jax.Array, places: int) -> tuple[jax.Array, jax.Array]:
@@ -137,3 +160,124 @@ def moe_hit_experts_auto(h, gates, ids, n_hit, layer, w_gate, w_up, w_down, init
     """The kernel, through the Pallas interpreter off-TPU."""
     return moe_hit_experts(h, gates, ids, n_hit, layer, w_gate, w_up, w_down, init,
                            interpret=jax.default_backend() != "tpu")
+
+
+def sort_by_expert(idx: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(order, place) for picks ``idx`` [rows, k]: ``order`` [rows x k] lists
+    the (row, pick) pairs (pair = row x k + pick) by rising expert, a stable
+    sort; ``place`` [rows, k] is where each pair stands in that order."""
+    order = jnp.argsort(idx.reshape(-1), stable=True)
+    return order.astype(jnp.int32), jnp.argsort(order).astype(jnp.int32).reshape(idx.shape)
+
+
+def group_visits(sizes: jax.Array, pairs: int, tm: int):
+    """The grouped kernel's walk over ``pairs`` sorted rows in tiles of
+    ``tm``, from ``sizes`` [E], the rows of each expert: (expert [V], tile
+    [V], n) for V = tiles + E - 1 places of which the first ``n`` are real,
+    and (starts, ends) [E], each expert's rows. A tile is visited once for
+    every expert with a row in it, experts rising, tiles rising: an expert's
+    visits are consecutive, and so are a tile's."""
+    e = sizes.shape[0]
+    sizes = sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    n_of = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    upto = jnp.cumsum(n_of)
+    v = jnp.arange(-(-pairs // tm) + e - 1, dtype=jnp.int32)
+    expert = jnp.minimum(jnp.sum(v[:, None] >= upto[None, :], axis=1), e - 1)
+    tile = first[expert] + v - (upto - n_of)[expert]
+    return (expert.astype(jnp.int32), tile.astype(jnp.int32), upto[-1].astype(jnp.int32),
+            starts, ends)
+
+
+def _grouped_kernel(tm, fc, expert_ref, tile_ref, starts_ref, ends_ref, layer_ref,
+                    x_ref, gate_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
+    """Grid (visits,): visit i is tile ``tile[i]`` of the sorted rows on
+    expert ``expert[i]``, whose three matrices are the step's blocks WHOLE (a
+    run of visits on one expert fetches them once). The whole tile is
+    computed, ``fc`` columns of f a trip of one loop; only the rows of that
+    expert are kept, the rest of the output block staying what the tile's
+    earlier visits made it (it is held in VMEM until the tile changes)."""
+    i = pl.program_id(0)
+    x = x_ref[...]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def columns(c, carry):
+        at = pl.ds(pl.multiple_of(c * fc, fc), fc)
+        g = jnp.dot(x, wg_ref[:, at], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[:, at], preferred_element_type=jnp.float32)
+        acc_ref[...] += jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), wd_ref[at, :],
+                                preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, wg_ref.shape[1] // fc, columns, 0)
+    row = tile_ref[i] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    mine = (row >= starts_ref[expert_ref[i]]) & (row < ends_ref[expert_ref[i]])
+    o_ref[...] = jnp.where(mine, acc_ref[...] * gate_ref[...], o_ref[...])
+
+
+def moe_grouped_experts(
+    x: jax.Array,       # [P, d]: the pairs' rows, sorted by expert
+    gate: jax.Array,    # [P] f32: each pair's gate
+    sizes: jax.Array,   # [E] int: rows of each expert (they sum to the real pairs)
+    layer,              # int32 scalar: the layer's place in the stacks
+    w_gate: jax.Array,  # [L, E, d, f]
+    w_up: jax.Array,    # [L, E, d, f]
+    w_down: jax.Array,  # [L, E, f, d]
+    interpret: bool = False,
+) -> jax.Array:
+    """gate x SwiGLU of each sorted row on its own expert: [P, d] f32. An
+    expert's three matrices, double-buffered, have to fit VMEM beside the row
+    tiles (44 of 128 MB at [3,584, 1,024] in bf16)."""
+    p, d = x.shape
+    f = w_gate.shape[-1]
+    isz = w_gate.dtype.itemsize
+    mult = 8 if x.dtype.itemsize >= 4 else 16
+    tm = min(_ROW_TILE, -(-p // mult) * mult)
+    pp = -(-p // tm) * tm
+    if pp != p:  # rows past the last expert's: in no group, kept by no visit
+        x = jnp.pad(x, ((0, pp - p), (0, 0)))
+        gate = jnp.pad(gate, (0, pp - p))
+    expert, tile, n, starts, ends = group_visits(sizes, pp, tm)
+
+    def rows_map(i, expert_ref, tile_ref, *_):
+        return (tile_ref[i], 0)
+
+    def expert_map(i, expert_ref, tile_ref, starts_ref, ends_ref, layer_ref):
+        return (layer_ref[0], expert_ref[i], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(n,),
+        in_specs=[pl.BlockSpec((tm, d), rows_map),
+                  pl.BlockSpec((tm, 1), rows_map),
+                  pl.BlockSpec((None, None, d, f), expert_map),
+                  pl.BlockSpec((None, None, d, f), expert_map),
+                  pl.BlockSpec((None, None, f, d), expert_map)],
+        out_specs=pl.BlockSpec((tm, d), rows_map),
+        scratch_shapes=[pltpu.VMEM((tm, d), jnp.float32)],
+    )
+    fc = math.gcd(f, _F_TRIP)
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, tm, fc),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((pp, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=(6 * d * f * isz + tm * d * (2 * x.dtype.itemsize + 12)
+                              + 4 * tm * fc * 4 + (8 << 20))),
+        interpret=interpret,
+        # a constant: the custom call's name in a device trace
+        name="moe_grouped_experts",
+    )(
+        expert, tile, starts, ends, jnp.asarray(layer, jnp.int32).reshape(1),
+        x, gate.astype(jnp.float32)[:, None], w_gate, w_up, w_down,
+    )
+    return out[:p]
+
+
+def moe_grouped_experts_auto(x, gate, sizes, layer, w_gate, w_up, w_down):
+    """The kernel, through the Pallas interpreter off-TPU."""
+    return moe_grouped_experts(x, gate, sizes, layer, w_gate, w_up, w_down,
+                               interpret=jax.default_backend() != "tpu")

@@ -19,11 +19,13 @@ from __future__ import annotations
 import asyncio
 import collections
 import logging
+import math
 import os
 import queue as _queue
 import random
 import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from functools import partial
 from typing import AsyncIterator
@@ -59,7 +61,7 @@ from ..ops.kvcache import (
 from .block_pool import BlockPool
 from .brownout import LEVEL_NAMES, SHED_ONLY, BrownoutConfig, BrownoutController
 from .prefix_cache import PrefixCache
-from .programs import build_programs, recorded_name, ring_name
+from .programs import build_programs, prompt_tokens_arg, recorded_name, ring_name
 from .qos import (
     ANON_TENANT,
     DEFAULT_PRIORITY,
@@ -289,9 +291,17 @@ class BatcherStats:
     expert_rows: int = 0
     expert_steps: int = 0
     # the form the expert layers of a decode burst take: "hit_list" (only the
-    # experts the live rows hit are read) or "dense" (models/mla_moe.py
+    # experts the live rows hit are read), "grouped" (a burst of 16 slots and
+    # more: each pick computed on its own expert) or "dense" (models/mla_moe.py
     # expert_path); "" for a family without expert layers
     expert_path: str = ""
+    # prompt rows through the expert layers, by the form expert_path gave
+    # their dispatch: the static [B, T] of every prefill and fused admit
+    # (padding rows and warm-up dispatches included: what the device
+    # computed, not what a prompt held), counted on the host at dispatch
+    expert_prefill_rows_grouped: int = 0
+    expert_prefill_rows_dense: int = 0
+    expert_prefill_rows_hit_list: int = 0
     # bounded log-bucket histograms (obs/histogram.py): O(1) record on the
     # batcher owner thread, O(buckets) snapshot from the asyncio metrics
     # handlers, fixed memory for the life of the worker. Phase deltas come
@@ -437,6 +447,18 @@ class BatcherStats:
         for k, v in burst.items():
             setattr(self, k, getattr(self, k) + v)
         return burst | ({"expert_path": self.expert_path} if self.expert_path else {})
+
+    def record_expert_prefill(self, form: str, rows: int) -> None:
+        """One prefill dispatch of ``rows`` rows whose expert layers took
+        ``form`` (models/mla_moe.py expert_path)."""
+        name = f"expert_prefill_rows_{form}"
+        setattr(self, name, getattr(self, name) + rows)
+
+    def expert_prefill_rows(self) -> dict[str, int]:
+        """Prompt rows through the expert layers by form, exposed by
+        serve/worker.py as lmstudio_moe_prefill_rows_total{path=...}."""
+        return {form: getattr(self, f"expert_prefill_rows_{form}")
+                for form in ("grouped", "dense", "hit_list")}
 
     def moe_counters(self) -> dict[str, int]:
         """Expert-layer counters, exposed by serve/worker.py as
@@ -749,9 +771,14 @@ class ContinuousBatcher:
         if cfg.n_moe_layers:
             from ..models.mla_moe import expert_path
 
+            # the form the expert layers of a call of ``rows`` rows take
+            self._expert_form = lambda rows: expert_path(
+                cfg, rows, self.params["blocks"]["moe"], mesh)
             # a decode burst is max_slots rows of one token
-            self.stats.expert_path = expert_path(
-                cfg, max_slots, self.params["blocks"]["moe"], mesh)
+            self.stats.expert_path = self._expert_form(max_slots)
+        # the forms the prefill dispatches of the open batcher.admit span
+        # gave their expert layers (owner thread; _timed adds, _admit_span reads)
+        self._admit_experts: set[str] = set()
         # the device-time ledger (obs/roofline.py): EFFICIENCY=0 turns it
         # off and _timed is then the plain timer
         self._efficiency = efficiency_enabled()
@@ -876,9 +903,16 @@ class ContinuousBatcher:
         seen: set = set()
         is_prefill = classify_program(name) == "prefill"
         is_spec = program_base(name) in SPEC_PROGRAMS
+        # where a program of an expert family takes its [B, T] prompt tokens
+        tokens_at = prompt_tokens_arg(fn) if self.cfg.n_moe_layers else None
 
         def run(*args, _tokens=None, _name=None, **kwargs):
             key = dispatch_shape_key(args, kwargs)
+            if tokens_at is not None:
+                rows = math.prod(args[tokens_at].shape)
+                form = self._expert_form(rows)
+                self._admit_experts.add(form)
+                stats.record_expert_prefill(form, rows)
             t0 = time.monotonic()
             out = fn(*args, **kwargs)
             t1 = time.monotonic()
@@ -911,6 +945,21 @@ class ContinuousBatcher:
 
         run.__name__ = f"timed_{name}"
         return run
+
+    @contextmanager
+    def _admit_span(self, **attrs):
+        """A ``batcher.admit`` span. For a family with expert layers it also
+        carries ``experts``: the form (``models/mla_moe.py expert_path``) its
+        prefill dispatches gave their expert layers, from their static
+        shapes ("grouped", "dense", "hit_list"; joined by "+" where an admit's
+        dispatches differed)."""
+        self._admit_experts.clear()
+        with obs_spans.span("batcher.admit", **attrs) as spn:
+            try:
+                yield spn
+            finally:
+                if self._admit_experts:
+                    spn.attrs["experts"] = "+".join(sorted(self._admit_experts))
 
     def _ledger_finalize(self, req, category: str) -> None:
         """Resolve a request's accrued device time into an outcome category.
@@ -4216,8 +4265,8 @@ class ContinuousBatcher:
                     self._wl_len = len(waitlist)
                     if len(group) > 1:
                         try:
-                            with obs_spans.span(
-                                    "batcher.admit", path="chunked", width=len(group),
+                            with self._admit_span(
+                                    path="chunked", width=len(group),
                                     tokens=max(len(r.prompt_ids) for r in group)):
                                 admit_group_chunked(group)
                         except _PoolExhausted as e:
@@ -4243,8 +4292,8 @@ class ContinuousBatcher:
                 self._wl_len = len(waitlist)  # popped-into-group != queued
                 if len(group) > 1:  # here only via the short same-bucket path
                     try:
-                        with obs_spans.span("batcher.admit", path="group",
-                                            width=len(group), bucket=head_bucket):
+                        with self._admit_span(path="group", width=len(group),
+                                              bucket=head_bucket):
                             handled = admit_group(group, head_bucket)
                     except Exception as e:  # noqa: BLE001 — surface to callers
                         for req in group:
@@ -4258,8 +4307,8 @@ class ContinuousBatcher:
                     # cannot fit the whole group): admit one by one
                 for req in group:
                     try:
-                        with obs_spans.span("batcher.admit", path="one", width=1,
-                                            tokens=len(req.prompt_ids)):
+                        with self._admit_span(path="one", width=1,
+                                              tokens=len(req.prompt_ids)):
                             admit_one(req)
                     except _PoolExhausted as e:
                         # pre-dispatch shed: pool state is intact, the other

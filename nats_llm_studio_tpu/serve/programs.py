@@ -16,6 +16,7 @@ add the ``_moe`` / ``_ring`` family tags.
 
 from __future__ import annotations
 
+import inspect
 from functools import partial
 from typing import Callable
 
@@ -79,6 +80,14 @@ def ring_name(cfg: ModelConfig, mesh, base: str, t: int) -> str | None:
     if mesh is None or not use_ring_prefill(mesh, t):
         return None
     return recorded_name(cfg, base) + "_ring"
+
+
+def prompt_tokens_arg(fn: Callable) -> int | None:
+    """Where a program of the table takes its [B, T] block of prompt tokens
+    (the argument named ``tokens``: the prefills and the fused admits), None
+    for a program that takes none (decode and verify feed back ``tok``)."""
+    names = list(inspect.signature(fn).parameters)
+    return names.index("tokens") if "tokens" in names else None
 
 
 def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,

@@ -1885,15 +1885,24 @@ class Worker:
             if moe is not None and moe()["expert_steps"]:
                 # routed-expert layers (models/mla_moe.py), summed over
                 # decode steps x expert layers: hit / steps is the experts a
-                # step reads where its path is "hit_list" (every expert where
-                # it is "dense"), rows_max / steps against rows x k / experts
-                # is the imbalance
+                # step reads where its path is "hit_list" or "grouped" (every
+                # expert where it is "dense"), rows_max / steps against
+                # rows x k / experts is the imbalance
                 for name, v in moe().items():
                     r.counter(f"lmstudio_moe_{name}_total", v, labels=labels)
                 r.gauge("lmstudio_moe_expert_path", 1,
                         labels={**labels, "path": getattr(stats, "expert_path", "")},
                         help="the form a decode burst's expert layers take: "
-                             "hit_list reads only the experts hit, dense all")
+                             "hit_list reads only the experts hit, grouped "
+                             "computes each pick on its own expert, dense all")
+            rows_fn = getattr(stats, "expert_prefill_rows", None)
+            if rows_fn is not None and any(rows_fn().values()):
+                # the prefill side: rows of prompt tokens (padding included)
+                # dispatched through the expert layers, by the form
+                # expert_path gave the dispatch's shape
+                for path, v in rows_fn().items():
+                    r.counter("lmstudio_moe_prefill_rows_total", v,
+                              labels={**labels, "path": path})
             tier_fn = getattr(rb, "tier_stats", None)
             tier = tier_fn() if tier_fn is not None else None
             if tier:

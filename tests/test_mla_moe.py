@@ -220,7 +220,7 @@ def test_the_hit_list_is_the_dense_dispatch_over_the_experts_hit(case, dtype, mo
     assert mla_moe.expert_path(cfg, 8, stacks) == "hit_list"
     dense, _ = mla_moe.moe_ffn(h, p | {k: v[place] for k, v in stacks.items()}, cfg, live)
     got, stats = jax.jit(lambda: mla_moe.moe_ffn(
-        h, p, cfg, live, tuple(stacks[k] for k in mla_moe._EXPERT_LEAVES), place))()
+        h, p, cfg, live, "hit_list", tuple(stacks[k] for k in mla_moe._EXPERT_LEAVES), place))()
     assert stats.tolist() == [n_hit, rows_max, int(sum(live))]
     from nats_llm_studio_tpu.ops.layers import swiglu
 
@@ -235,12 +235,60 @@ def test_the_hit_list_is_the_dense_dispatch_over_the_experts_hit(case, dtype, mo
         assert not np.allclose(f32(dense)[~on], f32(shared)[~on], atol=10 * tol)
 
 
+def _picks(how: str, rows: int, e: int, k: int) -> np.ndarray:
+    rng = np.random.default_rng(rows)
+    if how == "one_expert_takes_every_row":   # and three others a third of them each
+        return np.stack([np.full(rows, 5), 10 + np.arange(rows) % 3,
+                         20 + np.arange(rows) % 3, 30 + np.arange(rows) % 3], axis=1)
+    if how == "experts_with_no_row":          # the upper half of the experts is never picked
+        return np.stack([rng.choice(e // 2, k, replace=False) for _ in range(rows)])
+    return np.stack([rng.choice(e, k, replace=False) for _ in range(rows)])   # balanced
+
+
+@pytest.mark.parametrize("rows", [250, 256, 1000])
+@pytest.mark.parametrize("how", ["one_expert_takes_every_row", "balanced", "experts_with_no_row"])
+def test_the_grouped_form_is_the_dense_dispatch_without_its_zero_terms(how, rows, monkeypatch):
+    """The same layer twice on the same rows, picks and gates: dense dispatch
+    (every expert computes every row) and the grouped form (the pairs sorted
+    by expert, each on its own expert, over the WHOLE stacks and the layer's
+    place in them), to float32-accumulation tolerance; row counts that are
+    and are not a multiple of the row tile."""
+    cfg = REF.model_config(CONF, SEQ).with_(n_experts=64, n_experts_used=4, dtype="float32")
+    d, f, e, k, place = cfg.d_model, cfg.moe_d_ff, cfg.n_experts, cfg.n_experts_used, 1
+    ks = iter(jax.random.split(jax.random.PRNGKey(rows), 8))
+    rand = lambda *shape: jax.random.normal(next(ks), shape) * 0.3  # noqa: E731
+    stacks = {"w_gate_e": rand(2, e, d, f), "w_up_e": rand(2, e, d, f),
+              "w_down_e": rand(2, e, f, d)}
+    p = {"w_gate_s": rand(d, f), "w_up_s": rand(d, f), "w_down_s": rand(f, d)}
+    b = 2 if rows % 2 == 0 else 1
+    h = rand(b, rows // b, d)
+    idx = jnp.asarray(_picks(how, rows, e, k), jnp.int32).reshape(b, rows // b, k)
+    gate = jax.random.uniform(next(ks), idx.shape, minval=0.1, maxval=1.0)
+    monkeypatch.setattr(mla_moe, "route", lambda *_: (idx, gate))
+    assert mla_moe.expert_path(cfg, rows, stacks) == "grouped"
+    dense, _ = jax.jit(lambda: mla_moe.moe_ffn(
+        h, p | {k_: v[place] for k_, v in stacks.items()}, cfg))()
+    got, stats = jax.jit(lambda: mla_moe.moe_ffn(
+        h, p, cfg, None, "grouped", tuple(stacks[k_] for k_ in mla_moe._EXPERT_LEAVES), place))()
+    assert stats is None and got.shape == dense.shape
+    scale = float(np.abs(np.asarray(dense)).max())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dense), rtol=0, atol=2e-5 * scale)
+    # and they are not both the shared expert alone
+    from nats_llm_studio_tpu.ops.layers import swiglu
+
+    shared = swiglu(h, p["w_gate_s"], p["w_up_s"], p["w_down_s"])
+    assert float(np.abs(np.asarray(got) - np.asarray(shared)).max()) > 0.05 * scale
+
+
 @pytest.mark.parametrize("rows,leaves,devices,path", [
     (8, "plain", 1, "hit_list"),      # a decode step of the cell: 8 x 4 < 64
-    (16, "plain", 1, "dense"),        # 16 x 4 = 64: every expert may be hit
-    (256, "plain", 1, "dense"),       # a prefill chunk
+    (16, "plain", 1, "grouped"),      # 16 x 4 = 64: the picks reach the experts' count
+    (56, "plain", 1, "grouped"),      # a verify bundle: 8 slots x (6 drafts + 1)
+    (256, "plain", 1, "grouped"),     # a prefill chunk
     (8, "int8", 1, "dense"),          # WQUANT=int8 expert stacks
+    (256, "int8", 1, "dense"),
     (8, "plain", 2, "dense"),         # a mesh of more than one chip
+    (256, "plain", 2, "dense"),
 ])
 def test_the_expert_path_is_chosen_from_shapes_leaf_types_and_devices(rows, leaves, devices, path):
     from nats_llm_studio_tpu.ops.wquant import quantize_weight
@@ -251,14 +299,16 @@ def test_the_expert_path_is_chosen_from_shapes_leaf_types_and_devices(rows, leav
     stack = {k: w if leaves == "plain" else quantize_weight(w) for k in mla_moe._EXPERT_LEAVES}
     mesh = build_mesh({"tp": devices}, devices=jax.local_devices()[:devices])
     assert mla_moe.expert_path(cfg, rows, stack, mesh) == path
-    assert mla_moe.expert_path(cfg, rows, stack) == (path if devices == 1 else "hit_list")
+    if devices > 1:   # the mesh alone made it dense
+        assert mla_moe.expert_path(cfg, rows, stack) == ("hit_list" if rows == 8 else "grouped")
 
 
-@pytest.mark.parametrize("rows,iters", [(1, 20), (8, 20), (56, 20), (128, 3), (8, 0)])
+@pytest.mark.parametrize("rows,iters", [(1, 20), (8, 20), (56, 20), (128, 3), (8, 0),
+                                        (256, 20), (1000, 3)])   # a prefill chunk, no lane multiple
 def test_the_sinkhorn_kernel_is_the_loop_it_stands_for(rows, iters):
-    """A mixer of few rows (decode: 8, a verify bundle: 56) runs its rounds in
-    one kernel; more rows than a lane tile keep the ``fori_loop``. Both are the
-    same rounds, rows first."""
+    """A mixer runs its rounds in one kernel (decode: 8 rows, a verify bundle:
+    56, a prefill chunk: 256 and more); only more rows than the kernel's one
+    block holds keep the ``fori_loop``. Both are the same rounds, rows first."""
     from nats_llm_studio_tpu.ops.sinkhorn import sinkhorn_rounds
 
     res = jnp.exp(jnp.clip(3 * jax.random.normal(jax.random.PRNGKey(rows), (4, 4, rows)), -30, 30))

@@ -265,7 +265,8 @@ def test_moe_hit_experts_at_the_benchmark_cells_shapes(one_chip, no_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("rows", [SLOTS, SLOTS * SPEC_W], ids=["decode", "spec_verify"])
+@pytest.mark.parametrize("rows", [SLOTS, SLOTS * SPEC_W, 4 * CHUNK],
+                         ids=["decode", "spec_verify", "chunk_group_of_4"])
 def test_hc_sinkhorn_at_the_benchmark_cells_shapes(one_chip, no_cache, rows):
     """The mixing map of the four-stream residual, 20 rounds in one kernel:
     [4, 4, rows] float32 on one lane tile."""
@@ -273,3 +274,44 @@ def test_hc_sinkhorn_at_the_benchmark_cells_shapes(one_chip, no_cache, rows):
 
     _compile(lambda res: sinkhorn_rounds(res, 20, 1e-6),
              jax.ShapeDtypeStruct((4, 4, rows), jnp.float32, sharding=one_chip))
+
+
+@pytest.mark.parametrize("width", [1, 4], ids=["prefill1", "chunk_group_of_4"])
+def test_a_prefill_chunk_at_the_benchmark_cells_shapes_copies_no_expert_stack(
+        one_chip, no_cache, width):
+    """A chunk of 256 tokens x ``width`` prompts of ``xing29b.answer_closed``
+    through the whole cut model (1 dense + 6 expert layers, 64 experts of
+    [3584, 1024] x 3 in bf16: 8.5 GB of stacks, 1.4 GB a layer): the expert
+    layers take the grouped form, one ``moe_grouped_experts`` kernel in the
+    scan over the layers, and the stacks are read where they lie. A scan's
+    slice of a stack handed to the kernel would show as 470 MB of ``temp`` a
+    stack; what is there is the chunk's activations."""
+    import json
+    from pathlib import Path
+
+    from benchmark import run
+    from nats_llm_studio_tpu.models import llama, mla_moe
+
+    root = Path(__file__).resolve().parents[1]
+    ref = run.load_module(root / "benchmark/references/mla_moe_mhc.py")
+    conf = json.loads((root / "benchmark/configs/xing4.0-29b-a4b.json").read_text())
+    cfg = ref.model_config(conf, SEQ)
+    moe = jax.eval_shape(lambda: mla_moe.init_params(cfg, jax.random.PRNGKey(0)))
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    caches = [jax.ShapeDtypeStruct((width, cfg.n_layers, h, SEQ, w), jnp.bfloat16,
+                                   sharding=one_chip) for h, w in cfg.kv_cache_dims()]
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"   # the kernel itself, not the interpreter
+    try:
+        compiled = _compile(
+            lambda params, tokens, k, v, start, last: llama.forward(
+                params, cfg, tokens, k, v, start, logit_positions=last,
+                uniform_start=True, attn_window=2 * CHUNK),
+            jax.tree.map(sds, moe), ints(width, CHUNK), *caches, ints(width), ints(width))
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    assert text.count("moe_grouped_experts") >= 1 and "moe_hit_experts" not in text
+    stack_slice = cfg.n_experts * cfg.d_model * cfg.moe_d_ff * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < stack_slice
