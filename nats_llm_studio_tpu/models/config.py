@@ -15,6 +15,13 @@ each refuses", has the causes each refusal gives):
   sigmoid-routed experts beside shared ones, residual streams:
   ``models/mla_moe.py``. Served on the paged pool of one chip only; int8 KV,
   the KV tiers, KVX1 export and a real GGUF's tensors are refused.
+* ``layer_types`` present (``<arch>.attention.head_count_kv`` a list, 0 for
+  a layer that keeps a recurrent state): Mamba-2 state-space layers beside
+  grouped-query attention layers without rotary embedding
+  (``granitehybrid``): ``models/ssm_hybrid.py``. Served on the paged pool of
+  one chip, with a per-slot state pool beside it; the prefix cache,
+  speculation, int8 KV, the KV tiers, KVX1 export and a real GGUF's tensors
+  are refused.
 * ``gemma2``, ``gemma3``, ``qwen2moe``: rejected here (post-norms,
   soft-capping, a softmax-gated shared expert).
 """
@@ -105,10 +112,59 @@ class ModelConfig:
     hc_eps: float = 1e-6
     hc_res_clamp_min: float = -10.0
     hc_res_clamp_max: float = 10.0
+    # -- state-space (Mamba-2) layers beside attention layers -----------------
+    # layer_types names every layer "mamba" or "attention" (empty = all
+    # attention); a mamba layer keeps a state [ssm_n_heads, ssm_head_dim,
+    # ssm_d_state] and the convolution's last inputs in place of KV, so only
+    # the attention layers hold KV (n_kv_layers). use_rope False = no
+    # positional embedding at all (NoPE): the state layers carry order.
+    layer_types: tuple[str, ...] = ()
+    ssm_n_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_d_state: int = 0
+    ssm_n_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    use_rope: bool = True
 
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return sum(t == "mamba" for t in self.layer_types)
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that hold KV: the pool's layer axis."""
+        return self.n_layers - self.n_ssm_layers
+
+    @property
+    def ssm_d_inner(self) -> int:
+        return self.ssm_n_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the causal convolution runs over: x, B and C."""
+        return self.ssm_d_inner + 2 * self.ssm_n_groups * self.ssm_d_state
+
+    @property
+    def kv_pack(self) -> int:
+        """kv heads laid side by side in one 128-lane cache row (state-space
+        family only): a head of 64 would be padded to 128 lanes by the device
+        and is not a row the paged decode kernel can copy, two of them are."""
+        d = self.head_dim
+        if self.n_ssm_layers and d < 128 and 128 % d == 0 and self.n_kv_heads % (128 // d) == 0:
+            return 128 // d
+        return 1
+
+    @property
+    def family(self) -> str:
+        """The model file that runs this configuration (models/<family>.py)."""
+        if self.n_ssm_layers:
+            return "ssm_hybrid"
+        return "mla_moe" if self.is_mla else "llama"
 
     @property
     def n_moe_layers(self) -> int:
@@ -140,6 +196,9 @@ class ModelConfig:
             # device lays a narrower minor plane out that wide anyway, and the
             # decode kernel can only copy whole tiles out of the pool
             return (1, self.kv_lora_rank), (1, -(-self.qk_rope_head_dim // 128) * 128)
+        if self.kv_pack > 1:
+            packed = (self.n_kv_heads // self.kv_pack, self.head_dim * self.kv_pack)
+            return packed, packed
         return (self.n_kv_heads, self.head_dim), (self.n_kv_heads, self.head_dim)
 
     @property
@@ -159,6 +218,11 @@ class ModelConfig:
             return md.get(f"{arch}.{key}", default)
 
         n_heads = int(g("attention.head_count", 32))
+        kv_heads = g("attention.head_count_kv", n_heads)
+        if hasattr(kv_heads, "tolist"):
+            kv_heads = kv_heads.tolist()
+        # a list: one entry a layer, 0 for a layer that keeps no KV
+        kv_by_layer = [int(h) for h in kv_heads] if isinstance(kv_heads, (list, tuple)) else None
         d_model = int(g("embedding_length", 4096))
         head_dim = int(g("attention.key_length", d_model // n_heads))
         vocab = md.get(f"{arch}.vocab_size")
@@ -196,7 +260,7 @@ class ModelConfig:
             d_model=d_model,
             n_layers=int(g("block_count", 32)),
             n_heads=n_heads,
-            n_kv_heads=int(g("attention.head_count_kv", n_heads)),
+            n_kv_heads=max(kv_by_layer) if kv_by_layer else int(kv_heads),
             head_dim=head_dim,
             d_ff=int(g("feed_forward_length", 4 * d_model)),
             rope_theta=float(g("rope.freq_base", 10000.0)),
@@ -241,6 +305,20 @@ class ModelConfig:
                 hc_eps=float(g("hyper_connection.epsilon", 1e-6)),
                 hc_res_clamp_min=float(g("hyper_connection.res_clamp_min", -10.0)),
                 hc_res_clamp_max=float(g("hyper_connection.res_clamp_max", 10.0)),
+            )
+        if g("ssm.state_size") is not None and kv_by_layer:
+            # state-space layers beside attention: a layer with no kv head
+            # keeps a state (the keys llama.cpp's granitehybrid writes)
+            heads = int(g("ssm.time_step_rank"))
+            family |= dict(
+                layer_types=tuple("attention" if h else "mamba" for h in kv_by_layer),
+                ssm_n_heads=heads,
+                ssm_head_dim=int(g("ssm.inner_size")) // heads,
+                ssm_d_state=int(g("ssm.state_size")),
+                ssm_n_groups=int(g("ssm.group_count", 1)),
+                ssm_conv=int(g("ssm.conv_kernel", 4)),
+                ssm_chunk=int(g("ssm.chunk_size", 256)),
+                use_rope=bool(g("rope.scaling.finetuned", False)),
             )
         kwargs.update(family)  # family quirks win over absent metadata keys
         return cls(**kwargs)

@@ -81,12 +81,27 @@ def config_metadata(cfg: ModelConfig, name: str) -> dict[str, Any]:
             f"{a}.hyper_connection.res_clamp_min": cfg.hc_res_clamp_min,
             f"{a}.hyper_connection.res_clamp_max": cfg.hc_res_clamp_max,
         }
-    if cfg.arch == "granite":
-        md["granite.embedding_scale"] = cfg.embedding_scale
-        md["granite.residual_scale"] = cfg.residual_scale
-        md["granite.logit_scale"] = 1.0 / cfg.logit_scale  # stored as divisor
+    if cfg.n_ssm_layers:
+        # llama.cpp's granitehybrid keys: kv heads a layer (0 = a state-space
+        # layer), the Mamba-2 sizes, and no rotary embedding unless finetuned
+        a = cfg.arch
+        md |= {
+            f"{a}.attention.head_count_kv": [
+                cfg.n_kv_heads if t == "attention" else 0 for t in cfg.layer_types],
+            f"{a}.ssm.conv_kernel": cfg.ssm_conv,
+            f"{a}.ssm.inner_size": cfg.ssm_d_inner,
+            f"{a}.ssm.state_size": cfg.ssm_d_state,
+            f"{a}.ssm.time_step_rank": cfg.ssm_n_heads,
+            f"{a}.ssm.group_count": cfg.ssm_n_groups,
+            f"{a}.ssm.chunk_size": cfg.ssm_chunk,
+            f"{a}.rope.scaling.finetuned": cfg.use_rope,
+        }
+    if cfg.arch in ("granite", "granitehybrid"):
+        md[f"{cfg.arch}.embedding_scale"] = cfg.embedding_scale
+        md[f"{cfg.arch}.residual_scale"] = cfg.residual_scale
+        md[f"{cfg.arch}.logit_scale"] = 1.0 / cfg.logit_scale  # stored as divisor
         if cfg.attention_scale is not None:
-            md["granite.attention.scale"] = cfg.attention_scale
+            md[f"{cfg.arch}.attention.scale"] = cfg.attention_scale
     return md
 
 
@@ -99,6 +114,10 @@ def export_params_to_gguf(
     quant: GGMLType = GGMLType.F32,
     norm_quant: GGMLType = GGMLType.F32,
 ) -> Path:
+    if cfg.family != "llama":
+        raise NotImplementedError(
+            f"{cfg.arch}: no GGUF tensor-name map for the {cfg.family} family; "
+            "config_metadata writes its header (benchmark/lib/model_files.py)")
     w = GGUFWriter(path)
     w.add_dict(config_metadata(cfg, name))
     if tokenizer_md:

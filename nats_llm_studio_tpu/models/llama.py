@@ -44,6 +44,20 @@ from .config import ModelConfig
 Params = dict[str, Any]
 
 
+def family_module(cfg: ModelConfig):
+    """The sibling model file that runs ``cfg`` (``cfg.family``: latent
+    attention with experts in ``mla_moe``, state-space layers in
+    ``ssm_hybrid``), or None for the stack in this file. ``forward``,
+    ``forward_decode_paged``, ``make_cache`` and ``init_params`` hand such a
+    config to the function of the same name there: same contracts, so the
+    batcher, the pool and the sampling never ask which family they serve."""
+    if cfg.family == "llama":
+        return None
+    import importlib
+
+    return importlib.import_module(f"{__package__}.{cfg.family}")
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -340,12 +354,9 @@ def forward(
     * ``ring_slot`` None (tests, ragged callers): slots equal per-row
       positions, written by a per-layer batched scatter.
     """
-    if cfg.is_mla:
-        # latent attention, routed + shared experts, residual streams: the
-        # sibling model file, same contract (the caches hold latents)
-        from . import mla_moe
-
-        return mla_moe.forward(
+    family = family_module(cfg)
+    if family is not None:
+        return family.forward(
             params, cfg, tokens, k_cache, v_cache, start_pos, attn_window, mesh,
             ring_slot, logit_positions, fresh_prefill, uniform_start)
     b, t = tokens.shape
@@ -491,10 +502,9 @@ def forward_decode_paged(
     token-identical through the batcher."""
     from ..ops.kvcache import kv_pool_write_rows
 
-    if cfg.is_mla:
-        from . import mla_moe
-
-        out = mla_moe.forward_decode_paged(
+    family = family_module(cfg)
+    if family is not None:
+        out = family.forward_decode_paged(
             params, cfg, tokens, k_pool, v_pool, tbl, start_pos, mesh)
         return out if moe_stats else out[:3]
     b, w = tokens.shape
@@ -612,10 +622,9 @@ def make_cache(
     With ``cfg.kv_quant == "int8"`` each cache is a ``KVQ`` pytree (int8
     codes + f32 per-position-per-head scales, ops/kvcache.py) in the same
     layout — half the HBM traffic and capacity per step."""
-    if cfg.is_mla:
-        from . import mla_moe
-
-        return mla_moe.make_cache(cfg, batch, seq_len, dtype)
+    family = family_module(cfg)
+    if family is not None:
+        return family.make_cache(cfg, batch, seq_len, dtype)
     s = seq_len or cfg.max_seq_len
     shape = (batch, cfg.n_layers, cfg.n_kv_heads, s, cfg.head_dim)
     if cfg.kv_quant == "int8":
@@ -633,10 +642,9 @@ def make_cache(
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Random small-scale init (tests / golden-logit fixtures)."""
-    if cfg.is_mla:
-        from . import mla_moe
-
-        return mla_moe.init_params(cfg, key)
+    family = family_module(cfg)
+    if family is not None:
+        return family.init_params(cfg, key)
     dt = jnp.dtype(cfg.dtype)
     keys = iter(jax.random.split(key, 24))
 

@@ -28,6 +28,7 @@ first-class device representation selected by ``ModelConfig.kv_quant``.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -320,3 +321,57 @@ def kv_pool_read_blocks(pool, bids):
     if not is_quantized(pool):
         return rd(pool)
     return KVQ(q=rd(pool.q), s=rd(pool.s))
+
+
+# -- recurrent state beside the KV ---------------------------------------------
+#
+# A family with state-space layers (models/ssm_hybrid.py) keeps, beside the
+# KV of its attention layers, a per-slot state that no block table describes:
+# it is indexed by SLOT. ``WithState`` pairs the two so that whatever carries
+# a cache (a pool, a transient row cache, one row of either) carries the state
+# with it: the batcher hands the pair through its programs as it hands K and V.
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["kv", "st"], meta_fields=["axes"])
+@dataclass
+class WithState:
+    """A cache with per-row state beside it.
+
+    kv: the KV leaf, pool [NB, L, H, T, D] or rows [B, L, H, S, D]
+    st: tuple of arrays: a slot's (pool) or a row's (transient) recurrent
+        state; row i of a pool's ``st`` belongs to slot i
+    axes: where each leaf of ``st`` has its row axis (static). A leaf that a
+        layer scan updates a layer at a time is laid out layer-major
+        ([layers, rows, ...], axis 1): the device would re-lay it out so on
+        every dispatch otherwise.
+    """
+
+    kv: jax.Array
+    st: tuple
+    axes: tuple = ()
+
+    @property
+    def shape(self):
+        return self.kv.shape
+
+    @property
+    def ndim(self):
+        return self.kv.ndim
+
+
+def has_state(cache) -> bool:
+    return isinstance(cache, WithState)
+
+
+def state_row(cache: WithState, i) -> tuple:
+    """Row ``i`` (traced) of every state leaf, one row long on its row axis."""
+    return tuple(jax.lax.dynamic_slice_in_dim(a, i, 1, axis=ax)
+                 for a, ax in zip(cache.st, cache.axes))
+
+
+def state_write_row(cache: WithState, row: tuple, slot) -> tuple:
+    """``cache.st`` with the one-row leaves ``row`` written at row ``slot``
+    (traced)."""
+    return tuple(jax.lax.dynamic_update_slice_in_dim(a, r.astype(a.dtype), slot, axis=ax)
+                 for a, r, ax in zip(cache.st, row, cache.axes))

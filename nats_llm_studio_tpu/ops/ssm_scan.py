@@ -1,0 +1,232 @@
+"""The state-space (Mamba-2 / SSD) scan: its chunked form for prefill, its
+one-step form for decode, and the causal convolution with its carried tail.
+
+One layer, one token, head h of width P, state width N (one group):
+
+    a = exp(dt_h A_h)            S[h, p, n] <- a S[h, p, n] + dt_h x[h, p] B[n]
+    y[h, p] = sum_n C[n] S[h, p, n]              (+ D_h x[h, p], the caller's)
+
+**Prefill** (``ssd_chunked``) computes the same in chunks of Q tokens: inside
+a chunk the outputs are one masked [Q, Q] product a head (the decays between
+two positions of a chunk are exp of a difference of cumulative sums), the
+chunk's contribution to the state is one product over its positions, and the
+state is carried from chunk to chunk. A position with dt = 0 neither decays
+nor feeds the state: that is how padding is left out.
+
+**Decode** (``ssm_state_step``) is ONE Pallas kernel over the whole state pool
+``[slots, layers, H / k, N, k P]``, aliased onto its output: a grid cell reads
+a slot's block of heads, updates it and writes it back to the same place, so
+a step moves every state byte once in and once out and copies nothing. The
+pool's minor plane is [N, k P] with k = 128 / P heads side by side on the 128
+lanes (P = 64: two heads a row): the decay and dt x are then plain lane rows,
+B and C columns, the update a broadcast multiply-add and the read-out a sum
+over sublanes, none of which needs a relayout in the kernel. ``pack_state`` /
+``unpack_state`` go between that plane and the [H, P, N] of the equations.
+
+**The convolution** is depthwise and causal over K inputs. The pool keeps a
+slot's last K raw inputs (K - 1 are needed to go on; the K-th lets the last
+prompt position be replayed, which the batcher does for a request that wants
+its first token masked or with log-probabilities).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+# what one grid cell's block of a slot's state may take: 1 MiB, double
+# buffered in and out is 4 MiB of a v5e core's 16 MiB of scoped VMEM
+_STATE_BLOCK_BYTES = 1 << 20
+
+
+def heads_per_row(n_heads: int, head_dim: int) -> int:
+    """k: heads laid side by side on the lanes of one state row."""
+    if head_dim < LANES and LANES % head_dim == 0 and n_heads % (LANES // head_dim) == 0:
+        return LANES // head_dim
+    return 1
+
+
+def state_plane(n_heads: int, head_dim: int, d_state: int) -> tuple[int, int, int]:
+    """(H / k, N, k P): a slot's state of one layer as the pool holds it."""
+    k = heads_per_row(n_heads, head_dim)
+    return n_heads // k, d_state, k * head_dim
+
+
+def pack_state(s: jax.Array, k: int) -> jax.Array:
+    """[..., H, P, N] -> [..., H / k, N, k P]."""
+    *lead, h, p, n = s.shape
+    s = s.reshape(*lead, h // k, k, p, n)
+    s = jnp.moveaxis(s, -1, -3)  # [..., H/k, N, k, P]
+    return s.reshape(*lead, h // k, n, k * p)
+
+
+def unpack_state(s: jax.Array, k: int) -> jax.Array:
+    """[..., H / k, N, k P] -> [..., H, P, N]."""
+    *lead, hk, n, kp = s.shape
+    s = s.reshape(*lead, hk, n, k, kp // k)
+    s = jnp.moveaxis(s, -3, -1)  # [..., H/k, k, P, N]
+    return s.reshape(*lead, hk * k, kp // k, n)
+
+
+# ---------------------------------------------------------------------------
+# the causal convolution
+# ---------------------------------------------------------------------------
+
+
+def causal_conv(xbc: jax.Array, tail: jax.Array, w: jax.Array, b: jax.Array,
+                valid: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """silu(depthwise causal conv + bias) over ``xbc`` [B, T, C] that goes on
+    from ``tail`` [B, K, C], the K raw inputs before it (zeros at a start).
+    ``w`` [K, C]: w[K - 1] weighs the position itself. ``valid`` [B]: how many
+    of a row's T positions are real; the new tail is the K raw inputs ending
+    at the last real one (the old tail where none is)."""
+    k = w.shape[0]
+    t = xbc.shape[1]
+    ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)  # [B, K + T, C]
+    wf = w.astype(jnp.float32)
+    out = b.astype(jnp.float32) + sum(
+        wf[j] * ext[:, 1 + j: 1 + j + t].astype(jnp.float32) for j in range(k))
+    new_tail = jax.vmap(lambda e, v: jax.lax.dynamic_slice_in_dim(e, v, k, axis=0))(ext, valid)
+    return jax.nn.silu(out).astype(xbc.dtype), new_tail.astype(tail.dtype)
+
+
+def conv_step(xbc: jax.Array, tail: jax.Array, w: jax.Array, b: jax.Array,
+              fresh: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """One position a row: ``xbc`` [B, C], ``tail`` [B, K, C]. A ``fresh`` row
+    shifts its input in; a row that replays its last position finds it there
+    already and reads the tail as it is."""
+    shifted = jnp.concatenate([tail[:, 1:], xbc[:, None].astype(tail.dtype)], axis=1)
+    tail = jnp.where(fresh[:, None, None], shifted, tail)
+    out = b.astype(jnp.float32) + jnp.sum(
+        w.astype(jnp.float32)[None] * tail.astype(jnp.float32), axis=1)
+    return jax.nn.silu(out).astype(xbc.dtype), tail
+
+
+# ---------------------------------------------------------------------------
+# prefill: the chunked form
+# ---------------------------------------------------------------------------
+
+
+def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array, cm: jax.Array,
+                s0: jax.Array, chunk: int) -> tuple[jax.Array, jax.Array]:
+    """The recurrence over T positions in chunks of ``chunk``.
+
+    x [B, T, H, P]; dt [B, T, H] f32 >= 0 (0 at a position that is not
+    real); a [H] f32 < 0; bm, cm [B, T, N]; s0 [B, H, P, N] f32, the state
+    before position 0. Returns (y [B, T, H, P] f32, the state after the last
+    position [B, H, P, N] f32). T is padded to whole chunks with dt = 0."""
+    b, t, h, p = x.shape
+    q = min(chunk, t)
+    pad = -t % q
+    if pad:
+        x, dt, bm, cm = (jnp.pad(z, [(0, 0), (0, pad)] + [(0, 0)] * (z.ndim - 2))
+                         for z in (x, dt, bm, cm))
+    nc = (t + pad) // q
+    xf = x.astype(jnp.float32).reshape(b, nc, q, h, p)
+    dt = dt.astype(jnp.float32).reshape(b, nc, q, h)
+    bf = bm.astype(jnp.float32).reshape(b, nc, q, -1)
+    cf = cm.astype(jnp.float32).reshape(b, nc, q, -1)
+    cs = jnp.cumsum(dt * a.astype(jnp.float32), axis=2)  # [B, nc, Q, H], <= 0 and falling
+    dtx = xf * dt[..., None]
+    # inside a chunk: y[t] = sum_{s <= t} (C_t . B_s) exp(cs_t - cs_s) dt_s x_s
+    g = jnp.einsum("bcqn,bcsn->bcqs", cf, bf)
+    diff = cs[:, :, :, None, :] - cs[:, :, None, :, :]  # [B, nc, Q(t), Q(s), H]
+    tril = jnp.tril(jnp.ones((q, q), bool))[None, None, :, :, None]
+    m = g[..., None] * jnp.exp(jnp.where(tril, diff, -jnp.inf))
+    y = jnp.einsum("bcqsh,bcshp->bcqhp", m, dtx)
+    # what a chunk adds to the state, decayed to the chunk's end
+    to_end = jnp.exp(cs[:, :, -1:, :] - cs)  # [B, nc, Q, H]
+    s_add = jnp.einsum("bcqh,bcqhp,bcqn->bchpn", to_end, dtx, bf)
+    through = jnp.exp(cs[:, :, -1, :])  # [B, nc, H]: a whole chunk's decay
+
+    def carry(s, xs):
+        add, thr = xs
+        return thr[..., None, None] * s + add, s
+
+    s_end, s_in = jax.lax.scan(
+        carry, s0.astype(jnp.float32),
+        (jnp.moveaxis(s_add, 1, 0), jnp.moveaxis(through, 1, 0)))
+    s_in = jnp.moveaxis(s_in, 0, 1)  # [B, nc, H, P, N]: the state a chunk starts from
+    y = y + jnp.einsum("bcqn,bchpn,bcqh->bcqhp", cf, s_in, jnp.exp(cs))
+    return y.reshape(b, nc * q, h, p)[:, :t], s_end
+
+
+# ---------------------------------------------------------------------------
+# decode: one step over the state pool, in place
+# ---------------------------------------------------------------------------
+
+
+def _heads_block(rows: int, n: int, lanes: int) -> int:
+    """Rows of heads one grid cell takes: the largest divisor of ``rows``
+    whose [rows, N, lanes] f32 block stays under ``_STATE_BLOCK_BYTES``."""
+    cap = max(1, _STATE_BLOCK_BYTES // (n * lanes * 4))
+    return next(r for r in range(min(cap, rows), 0, -1) if rows % r == 0)
+
+
+def _step_kernel(hb, lanes, layer_ref, a_ref, u_ref, bc_ref, s_ref, so_ref, y_ref):
+    """Grid (slots, blocks of head rows). a, u: [1, hb x lanes] rows (decay
+    and dt x of the block's heads); bc: [N, 2] (B and C as columns); s: the
+    block [hb, N, lanes] of the slot's state in this layer."""
+    del layer_ref
+    bcol = bc_ref[:, 0:1]
+    ccol = bc_ref[:, 1:2]
+    for j in range(hb):
+        at = slice(j * lanes, (j + 1) * lanes)
+        s = s_ref[j] * a_ref[:, at] + bcol * u_ref[:, at]
+        so_ref[j] = s
+        y_ref[:, at] = jnp.sum(s * ccol, axis=0, keepdims=True)
+
+
+def ssm_state_step(pool: jax.Array, layer, decay: jax.Array, dtx: jax.Array,
+                   bm: jax.Array, cm: jax.Array, interpret: bool = False):
+    """One position of every slot in layer ``layer`` of the state pool
+    ``[slots, L, H / k, N, k P]`` f32, in place (the pool is aliased onto the
+    result: donate it). ``decay`` [slots, H] = exp(dt A) (1 for a row that must
+    keep its state), ``dtx`` [slots, H, P] = dt x (0 likewise), ``bm``, ``cm``
+    [slots, N]. Returns (pool, y [slots, H, P] f32 = C . S after the update)."""
+    slots, _, rows, n, lanes = pool.shape
+    h, p = dtx.shape[1], dtx.shape[2]
+    hb = _heads_block(rows, n, lanes)
+    a_row = jnp.repeat(decay.astype(jnp.float32), p, axis=1).reshape(slots, 1, h * p)
+    u_row = dtx.astype(jnp.float32).reshape(slots, 1, h * p)
+    bc = jnp.stack([bm, cm], axis=-1).astype(jnp.float32)  # [slots, N, 2]
+
+    def row_map(b, j, layer_ref):
+        return (b, 0, j)
+
+    def state_map(b, j, layer_ref):
+        return (b, layer_ref[0], j, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(slots, rows // hb),
+        in_specs=[pl.BlockSpec((None, 1, hb * lanes), row_map),
+                  pl.BlockSpec((None, 1, hb * lanes), row_map),
+                  pl.BlockSpec((None, n, 2), lambda b, j, layer_ref: (b, 0, 0)),
+                  pl.BlockSpec((None, None, hb, n, lanes), state_map)],
+        out_specs=[pl.BlockSpec((None, None, hb, n, lanes), state_map),
+                   pl.BlockSpec((None, 1, hb * lanes), row_map)],
+    )
+    pool, y = pl.pallas_call(
+        lambda *refs: _step_kernel(hb, lanes, *refs),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                   jax.ShapeDtypeStruct((slots, 1, h * p), jnp.float32)],
+        # operand 4 (after the prefetched layer) is the pool; result 0 is it again
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        # a constant: the custom call's name in a device trace
+        name="ssm_state_step",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), a_row, u_row, bc, pool)
+    return pool, y.reshape(slots, h, p)
+
+
+def ssm_state_step_auto(pool, layer, decay, dtx, bm, cm):
+    """The kernel, through the Pallas interpreter off-TPU."""
+    return ssm_state_step(pool, layer, decay, dtx, bm, cm,
+                          interpret=jax.default_backend() != "tpu")

@@ -78,6 +78,11 @@ def load_params_sharded(
             f"{cfg.arch}: no GGUF tensor-name map for latent-attention models "
             "yet; the tree to build is models.mla_moe.init_params' (two stacks, "
             "blocks.dense and blocks.moe), placed by param_sharding_rules")
+    if cfg.n_ssm_layers:
+        raise NotImplementedError(
+            f"{cfg.arch}: no GGUF tensor-name map for state-space models yet; "
+            "the tree to build is models.ssm_hybrid.init_params' (two stacks, "
+            "blocks.mamba and blocks.attn), placed by param_sharding_rules")
     rules = param_sharding_rules(mesh, cfg)
 
     def t(name: str) -> np.ndarray:

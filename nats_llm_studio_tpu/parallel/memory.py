@@ -29,6 +29,8 @@ def _leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
     )
     if cfg.is_mla:
         return _mla_leaves(cfg, dtype_bytes)
+    if cfg.n_ssm_layers:
+        return _ssm_leaves(cfg, dtype_bytes)
     out: dict[str, _Leaf] = {
         "embed": _Leaf((V, d), (), dtype_bytes),
         "out_norm": _Leaf((d,), (), dtype_bytes),
@@ -93,6 +95,38 @@ def _mla_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
         for k, shape in (attn | ffn).items():
             out[f"blocks.{name}.{k}"] = _Leaf((L,) + shape, (), dtype_bytes, quantizable(k))
     return out
+
+
+def _ssm_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
+    """The leaves of ``models.ssm_hybrid.init_params``, unsharded (the family
+    serves on one chip a replica); the scan's small leaves are left out."""
+    from ..ops.wquant import quantizable
+
+    d, ff, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
+    hq, hkv, hd, di = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.ssm_d_inner
+    out = {"embed": _Leaf((V, d), (), dtype_bytes),
+           "lm_head": _Leaf((d, V), (), dtype_bytes, True)}
+    ffn = {"w_gate": (d, ff), "w_up": (d, ff), "w_down": (ff, d)}
+    stacks = (("mamba", cfg.n_ssm_layers, {
+                  "w_in": (d, di + cfg.ssm_conv_dim), "w_dt": (d, cfg.ssm_n_heads),
+                  "w_out": (di, d)}),
+              ("attn", cfg.n_kv_layers, {"wq": (d, hq * hd), "wk": (d, hkv * hd),
+                                         "wv": (d, hkv * hd), "wo": (hq * hd, d)}))
+    for name, L, mixer in stacks:
+        for k, shape in (mixer | ffn).items() if L else ():
+            out[f"blocks.{name}.{k}"] = _Leaf((L,) + shape, (), dtype_bytes, quantizable(k))
+    return out
+
+
+def state_slot_bytes(cfg: ModelConfig) -> int:
+    """Device bytes of ONE slot of the recurrent-state pool beside the KV
+    pool (0 for a family that keeps none): what admission prices a slot at
+    whatever its context, where KV is priced by the block."""
+    if not cfg.n_ssm_layers:
+        return 0
+    from ..models.ssm_hybrid import state_bytes_per_slot
+
+    return state_bytes_per_slot(cfg)
 
 
 def kv_token_values(cfg: ModelConfig) -> int:
@@ -160,7 +194,8 @@ def estimate_device_bytes(
             params += n * dtype_bytes
 
     cb = cache_dtype_bytes or dtype_bytes
-    kv = cfg.n_layers * batch * seq * kv_token_values(cfg) * cb
+    kv = cfg.n_kv_layers * batch * seq * kv_token_values(cfg) * cb
+    kv += batch * state_slot_bytes(cfg)  # a slot's recurrent state, whole
     # dp is served as independent batcher REPLICAS over disjoint device
     # slices (mesh.dp_submeshes): each replica holds its own full-``batch``
     # cache, so per-DEVICE kv bytes do not divide by dp — only the kv-head
@@ -188,4 +223,4 @@ def kv_pool_block_bytes(cfg: ModelConfig, block_tokens: int,
     per_pos = (
         cfg.head_dim * (1 if quant else dtype_bytes) + (4 if quant else 0)
     )
-    return 2 * cfg.n_layers * cfg.n_kv_heads * block_tokens * per_pos // max(1, tp)
+    return 2 * cfg.n_kv_layers * cfg.n_kv_heads * block_tokens * per_pos // max(1, tp)

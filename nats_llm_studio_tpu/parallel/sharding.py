@@ -54,6 +54,8 @@ def param_sharding_rules(mesh: Mesh, cfg: ModelConfig | None = None) -> dict[str
     kv = None if cfg is not None and kv_replicated(mesh, cfg) else tp
     if cfg is not None and cfg.is_mla:
         return _mla_rules(cfg, ep, pp)
+    if cfg is not None and cfg.n_ssm_layers:
+        return _ssm_rules(pp)
     return {
         "embed": P(None, None),  # replicated: read once per token, cheap
         "out_norm": P(None),
@@ -93,6 +95,20 @@ def _mla_rules(cfg: ModelConfig, ep, pp) -> dict[str, P]:
     rules |= {f"blocks.moe.{k}": P(pp, *[None] * r) for k, r in moe.items()}
     rules |= {f"blocks.moe.{k}": P(pp, ep, None, None)
               for k in ("w_gate_e", "w_up_e", "w_down_e")}
+    return rules
+
+
+def _ssm_rules(pp) -> dict[str, P]:
+    """A rule for every leaf ``models.ssm_hybrid.init_params`` makes: the two
+    stacks' layer axis on pp, everything else whole (the family is served on
+    one chip a replica: ``validate_mesh_for_config`` refuses a mesh over it)."""
+    ffn = {"mix_norm": 1, "ffn_norm": 1, "w_gate": 2, "w_up": 2, "w_down": 2}
+    mamba = ffn | {"w_in": 2, "w_dt": 2, "conv_w": 2, "conv_b": 1, "dt_bias": 1, "a_log": 1,
+                   "d_skip": 1, "gate_norm": 1, "w_out": 2}
+    attn = ffn | {"wq": 2, "wk": 2, "wv": 2, "wo": 2}
+    rules = {"embed": P(None, None), "out_norm": P(None), "lm_head": P(None, None)}
+    rules |= {f"blocks.mamba.{k}": P(pp, *[None] * r) for k, r in mamba.items()}
+    rules |= {f"blocks.attn.{k}": P(pp, *[None] * r) for k, r in attn.items()}
     return rules
 
 
@@ -242,6 +258,12 @@ def validate_mesh_for_config(mesh: Mesh, cfg: ModelConfig,
             f"latent-attention models ({cfg.arch}) serve on one chip a replica "
             "(MESH_SHAPE=off): the latent cache has one kv head, so there is no "
             "tp split of it, and the dropless expert layer has no ep exchange yet"
+        )
+    if cfg.n_ssm_layers and mesh.size > 1:
+        raise ValueError(
+            f"state-space models ({cfg.arch}) serve on one chip a replica "
+            "(MESH_SHAPE=off): the scan's heads and the per-slot state pool "
+            "have no mesh split yet"
         )
     # every message names the FULL axis factoring, not just the failing
     # axis — a multi-axis mesh ("dp=2,ep=2,tp=2") read back as bare "tp=2"

@@ -52,13 +52,15 @@ from ..obs.roofline import (
 from ..transport import faults as _faults
 from ..ops.kvcache import (
     KVQ,
+    has_state,
     is_quantized,
     kv_gather_block,
     kv_pool_read_blocks,
     kv_pool_write_row,
     kv_pool_zeros,
+    state_row,
 )
-from .block_pool import BlockPool
+from .block_pool import BlockPool, StatePool
 from .brownout import LEVEL_NAMES, SHED_ONLY, BrownoutConfig, BrownoutController
 from .prefix_cache import PrefixCache
 from .programs import build_programs, prompt_tokens_arg, recorded_name, ring_name
@@ -82,6 +84,11 @@ log = logging.getLogger(__name__)
 # not yet written: decode steps during the chunk loop must neither deliver
 # tokens for it nor let another admit claim the slot
 _RESERVED = object()
+
+# a decode burst that hands over more tokens than this (live rows x steps)
+# gives each row's tokens to its stream as one event; 64 = 8 slots x a burst
+# of 8, serve's defaults, whose streams keep a hand-over a token
+_TOKEN_HANDOVERS_A_BURST = 64
 
 
 class BatcherStopped(RuntimeError):
@@ -153,14 +160,17 @@ class _Suspended:
     delivered token), and the request itself (whose ``emitted`` tail
     re-seeds the device carry token). Owner thread only."""
 
-    __slots__ = ("req", "k", "v", "n_blocks", "min_blocks", "pos", "steps",
-                 "seed", "spec", "t_suspend", "reason")
+    __slots__ = ("req", "k", "v", "st", "n_blocks", "min_blocks", "pos",
+                 "steps", "seed", "spec", "t_suspend", "reason")
 
     def __init__(self, req, k, v, n_blocks, pos, steps, seed, spec,
-                 t_suspend, reason, min_blocks=None):
+                 t_suspend, reason, min_blocks=None, st=None):
         self.req = req
         self.k = k
         self.v = v
+        # host copies of the slot's recurrent state (K's and V's leaves, a
+        # [1, ...] row each) for a family that keeps one beside its KV
+        self.st = st
         self.n_blocks = n_blocks
         # resume gate: don't re-admit until this many blocks are free. For
         # a slot parked by a FAILED mid-decode growth this covers n_blocks
@@ -181,7 +191,7 @@ class _Request:
     prompt_ids: list[int]
     sp: SamplingParams
     loop: asyncio.AbstractEventLoop
-    out: asyncio.Queue  # (kind, value): ("tok", id) | ("end", reason) | ("err", exc)
+    out: asyncio.Queue  # (kind, value): ("tok", id) | ("toks", [id, ..]) | ("end", reason) | ("err", exc)
     slot: int = -1
     pos: int = 0
     generated: int = 0
@@ -290,6 +300,14 @@ class BatcherStats:
     expert_rows_max: int = 0
     expert_rows: int = 0
     expert_steps: int = 0
+    # state-space layers (models/ssm_hybrid.py): rows whose recurrent state a
+    # decode step advanced (live rows x steps of every burst), the steps, and
+    # the admits by how their state was made (from zeros in one dispatch, or
+    # carried from chunk to chunk of a prompt over one chunk)
+    state_rows: int = 0
+    state_steps: int = 0
+    state_admits_fresh: int = 0
+    state_admits_carried: int = 0
     # the form the expert layers of a decode burst take: "hit_list" (only the
     # experts the live rows hit are read), "grouped" (a burst of 16 slots and
     # more: each pick computed on its own expert) or "dense" (models/mla_moe.py
@@ -447,6 +465,20 @@ class BatcherStats:
         for k, v in burst.items():
             setattr(self, k, getattr(self, k) + v)
         return burst | ({"expert_path": self.expert_path} if self.expert_path else {})
+
+    def record_state(self, rows: int, steps: int) -> dict[str, int]:
+        """One decode burst of a family with a recurrent state: ``rows`` live
+        rows advanced their state ``steps`` times. Returns what the readback
+        span carries."""
+        self.state_rows += rows * steps
+        self.state_steps += steps
+        return {"state_rows": rows * steps, "state_steps": steps}
+
+    def state_counters(self) -> dict[str, int]:
+        """Exposed by serve/worker.py as lmstudio_ssm_*_total."""
+        return {"state_rows": self.state_rows, "state_steps": self.state_steps,
+                "state_admits_fresh": self.state_admits_fresh,
+                "state_admits_carried": self.state_admits_carried}
 
     def record_expert_prefill(self, form: str, rows: int) -> None:
         """One prefill dispatch of ``rows`` rows whose expert layers took
@@ -645,7 +677,43 @@ class ContinuousBatcher:
                     f"{cfg.arch}: the host/Object-Store KV tiers spill blocks "
                     "as KVX1, which holds one shape for keys and values; a "
                     "latent cache's pair differs: set KV_HOST_POOL_BYTES=0")
+        # what was asked for and this family does not serve, by feature: the
+        # cause an operator reads on the metrics page and in health
+        self.refusals: dict[str, str] = {}
+        if cfg.n_ssm_layers:
+            # state-space layers keep a recurrent state a slot beside the KV
+            # (models/ssm_hybrid.py). What cannot be served is refused with
+            # its cause: an error where the knob contradicts the family, a
+            # feature turned off (and said so) where it is an optimisation
+            if not self.paged:
+                raise ValueError(
+                    f"{cfg.arch}: state-space models are served on the paged "
+                    "pool only (unset KV_PAGED=0): the shared ring rolls and "
+                    "shifts rows (compact_ring), and a recurrent state cannot "
+                    "be shifted")
+            if cfg.kv_quant == "int8":
+                raise ValueError(
+                    f"{cfg.arch}: TPU_KV_QUANT=int8 is not implemented for "
+                    "state-space models (two kv heads share a cache row; one "
+                    "scale a row cannot serve both): unset TPU_KV_QUANT")
+            if kv_tiers is not None:
+                raise ValueError(
+                    f"{cfg.arch}: the host/Object-Store KV tiers hold KV "
+                    "blocks and no state: a prefix promoted from a tier could "
+                    "not be decoded from: set KV_HOST_POOL_BYTES=0")
+            if prefix_cache_blocks > 0:
+                self.refusals["prefix_cache"] = (
+                    "off: the cache holds KV blocks and no snapshot of the "
+                    "recurrent state at a block's end, so a hit could not be "
+                    "decoded from")
+                prefix_cache_blocks = 0
+            if spec_decode_k > 0:
+                self.refusals["spec_decode"] = (
+                    "off: a verify would advance the state past rejected "
+                    "drafts and the pool keeps no snapshot to go back to")
+                spec_decode_k = 0
         self._pool: BlockPool | None = None
+        self._state_pool = None
         if self.paged:
             # block size: the requested tokens-per-block snapped down (pow2
             # halving) until it divides the prefill chunk — cached chunks
@@ -668,6 +736,12 @@ class ContinuousBatcher:
                 else max_slots * self.blocks_per_row + max(0, prefix_cache_blocks)
             )
             self._pool = BlockPool(usable + 1, T)
+            if cfg.n_ssm_layers:
+                from ..parallel.memory import state_slot_bytes
+
+                self._state_pool = StatePool(
+                    max_slots, state_slot_bytes(cfg),
+                    lambda: sum(r is not None for r in self._slots))
         else:
             self.kv_block_tokens = 0
             self.blocks_per_row = 0
@@ -954,6 +1028,15 @@ class ContinuousBatcher:
         shapes ("grouped", "dense", "hit_list"; joined by "+" where an admit's
         dispatches differed)."""
         self._admit_experts.clear()
+        if self._state_pool is not None:
+            # how the slot's recurrent state is made: from zeros in one
+            # dispatch, or carried from chunk to chunk of a longer prompt
+            carried = attrs.get("tokens", 0) > self.prefill_chunk
+            attrs["state"] = "carried" if carried else "fresh"
+            if carried:
+                self.stats.state_admits_carried += attrs.get("width", 1)
+            else:
+                self.stats.state_admits_fresh += attrs.get("width", 1)
         with obs_spans.span("batcher.admit", **attrs) as spn:
             try:
                 yield spn
@@ -1301,7 +1384,12 @@ class ContinuousBatcher:
     def pool_stats(self) -> dict | None:
         """Paged-KV block pool counters for metrics/bench (None when the
         batcher runs the legacy contiguous layout). Thread-safe snapshot."""
-        return self._pool.stats() if self._pool is not None else None
+        if self._pool is None:
+            return None
+        out = self._pool.stats()
+        if self._state_pool is not None:
+            out["state"] = self._state_pool.stats()
+        return out
 
     def drop_prefix_cache(self) -> int:
         """Evict every cached prefix block and zero the budget (the
@@ -1575,6 +1663,8 @@ class ContinuousBatcher:
                 while True:
                     if kind == "tok":
                         batch.append(value)
+                    elif kind == "toks":  # a decode burst's tokens of this row
+                        batch.extend(value)
                     elif kind == "end":
                         done = True
                         if batch:
@@ -1614,6 +1704,7 @@ class ContinuousBatcher:
         nothing useful for this prompt (short prompt, cache miss, pool
         reset). Thread-safe: marshals onto the owner thread through the
         inbox; blocking — call via ``asyncio.to_thread`` from a loop."""
+        self._refuse_kv_transfer()
         return self._control(_ControlOp(
             "export", {"prompt_ids": [int(t) for t in prompt_ids]}
         ), timeout)
@@ -1628,7 +1719,15 @@ class ContinuousBatcher:
         hold the import — the decode-pool-exhaustion failure mode; the
         caller falls back to local prefill. Thread-safe and blocking,
         like :meth:`export_prefix_blocks`."""
+        self._refuse_kv_transfer()
         return self._control(_ControlOp("import", {"export": export}), timeout)
+
+    def _refuse_kv_transfer(self) -> None:
+        if self.cfg.n_ssm_layers:
+            raise ValueError(
+                f"{self.cfg.arch}: KVX1 carries KV blocks and no recurrent "
+                "state: a prefix transferred without its state could not be "
+                "decoded from (state-space models prefill where they decode)")
 
     def _control(self, op: _ControlOp, timeout: float):
         if not self._started:
@@ -1679,21 +1778,38 @@ class ContinuousBatcher:
             raise ValueError(
                 f"DECODE_KERNEL must be pallas|xla|auto, got {mode!r}"
             )
-        if mode == "xla":
-            return "xla"
         from ..ops.paged_attention import paged_decode_eligible
 
         cfg = self.cfg
+        on_tpu = jax.default_backend() == "tpu"
+        itemsize = 4 if cfg.dtype == "float32" else 2
+        # off-TPU the interpreter runs any layout; Mosaic's tiling rules
+        # only bind on the chip
+        if cfg.n_ssm_layers:
+            # the family has one decode path: the kernel over packed rows
+            # (models/ssm_hybrid.py), through the interpreter off the chip
+            if mode == "xla":
+                raise ValueError(
+                    f"{cfg.arch}: DECODE_KERNEL=xla gathers a slot's blocks "
+                    "into a view and scatters them back; state-space models "
+                    "decode on the pool in place only: unset DECODE_KERNEL")
+            (hp, width), _ = cfg.kv_cache_dims()
+            if on_tpu and not paged_decode_eligible(
+                    self.kv_block_tokens, width, itemsize, False, hp, 1):
+                raise ValueError(
+                    f"{cfg.arch}: the paged decode kernel cannot serve this "
+                    f"layout (packed rows of {width} lanes, T="
+                    f"{self.kv_block_tokens}) and state-space models have no "
+                    "gather-view decode path")
+            return "pallas"
+        if mode == "xla":
+            return "xla"
         tp = 1
         if self.mesh is not None:
             from ..parallel.mesh import AXIS_TP
 
             tp = self.mesh.shape.get(AXIS_TP, 1)
-        on_tpu = jax.default_backend() == "tpu"
         heads_split = tp <= 1 or cfg.n_kv_heads % tp == 0
-        itemsize = 4 if cfg.dtype == "float32" else 2
-        # off-TPU the interpreter runs any layout; Mosaic's tiling rules
-        # only bind on the chip
         if cfg.is_mla:
             # the absorbed kernel (ops/mla_attention.py): one latent head
             from ..ops.mla_attention import mla_paged_decode_eligible
@@ -1784,10 +1900,17 @@ class ContinuousBatcher:
             # K and V alike for GQA; (latent, rotary key), one head each, for
             # MLA: nothing below this line looks into the pair
             KP, VP = (
-                kv_pool_zeros((pool.n_blocks, cfg.n_layers, h, T, width),
+                kv_pool_zeros((pool.n_blocks, cfg.n_kv_layers, h, T, width),
                               dtype=dt, quant=quant)
                 for h, width in cfg.kv_cache_dims()
             )
+            if cfg.n_ssm_layers:
+                # the state pool beside the blocks: row i is slot i's
+                from ..models.ssm_hybrid import make_state
+                from ..ops.kvcache import WithState
+
+                KP, VP = (WithState(p, st, ax) for p, (st, ax) in zip(
+                    (KP, VP), make_state(cfg, B)))
             if self.mesh is not None:
                 from ..parallel.sharding import pool_spec, shard_cache
 
@@ -2100,12 +2223,23 @@ class ContinuousBatcher:
                         # an expert family's burst: the rows past B are its
                         # counters (decode_pos_moe)
                         spn.attrs.update(self.stats.record_moe(ids[B:]))
+                    if self._state_pool is not None:
+                        spn.attrs.update(self.stats.record_state(len(rows), n))
                 # observed per-step latency (dispatch -> tokens readable);
                 # includes pipeline wait, i.e. what a stream experiences
                 now = time.monotonic()
                 step_s = (now - t_disp) / n
                 self.stats.decode_step_ms.record(step_s * 1e3)
                 self._note_decode_spt(self._warm_s(t_disp, now) / n)
+                # a wide burst's tokens of a row go to its stream in ONE
+                # hand-over: a wake-up of the event loop a token (a GIL
+                # release each) made the owner thread's way from here to its
+                # next intake as long as the loop thread's way round a closed
+                # loop at 24 live rows, and which request made an intake was
+                # a race. A burst of up to _TOKEN_HANDOVERS_A_BURST tokens
+                # keeps a hand-over a token: its streams' chunks, and so the
+                # gaps a client measures between them, stay as they were
+                one_event = len(rows) * n > _TOKEN_HANDOVERS_A_BURST
                 with obs_spans.span("batcher.deliver") as spn:
                     tok0 = self.stats.tokens
                     for slot, req in rows:
@@ -2121,19 +2255,25 @@ class ContinuousBatcher:
                             )
                             continue
                         st = spec_slots[slot]
+                        held: list[int] | None = [] if one_event else None
                         try:
                             for j in range(n):
                                 req.pos += 1
                                 t = int(ids[slot, j])
                                 if st is not None:
                                     st.index.append(t)
-                                reason = self._deliver(req, t)
+                                reason = self._deliver(req, t, into=held)
                                 if reason is not None:
                                     self._ledger_finalize(req, "served")
                                     self._tenant_served(req)
                                     finish_slot(slot)  # free BEFORE the end event
+                                    if held:
+                                        req.emit("toks", held)
+                                        held = []
                                     req.emit("end", reason)
                                     break
+                            if held:
+                                req.emit("toks", held)
                         except Exception:  # noqa: BLE001 — dead client
                             log.exception("delivery failed; dropping slot %d", slot)
                             self._ledger_finalize(req, "cancelled")
@@ -2209,7 +2349,9 @@ class ContinuousBatcher:
                     spn.attrs["tokens"] = self.stats.tokens - tok0
             elif rec[0] == "ext":
                 _, toks_ref, lp_ref, topids_ref, toplps_ref, rows, t_disp = rec
-                with obs_spans.span("batcher.readback", program="ext"):
+                with obs_spans.span("batcher.readback", program="ext") as spn:
+                    if self._state_pool is not None:
+                        spn.attrs.update(self.stats.record_state(len(rows), 1))
                     ids = np.asarray(toks_ref)  # [B]
                     lps = np.asarray(lp_ref)  # [B]
                     tis = np.asarray(topids_ref)  # [B, LOGPROBS_K]
@@ -2784,8 +2926,16 @@ class ContinuousBatcher:
                 return False
             try:
                 bids = jnp.asarray(tables[i], jnp.int32)
-                k_host = _host_kv(kv_pool_read_blocks(K, bids))
-                v_host = _host_kv(kv_pool_read_blocks(V, bids))
+                st_host = None
+                kv_k, kv_v = K, V
+                if has_state(K):
+                    # the slot's state goes to the host with its KV: what
+                    # resume writes back is the state after host_pos tokens
+                    st_host = jax.device_get(
+                        (state_row(K, i), state_row(V, i)))
+                    kv_k, kv_v = K.kv, V.kv
+                k_host = _host_kv(kv_pool_read_blocks(kv_k, bids))
+                v_host = _host_kv(kv_pool_read_blocks(kv_v, bids))
             except Exception:  # noqa: BLE001 — readback failed; keep in HBM
                 log.exception("suspend readback failed; slot %d stays", i)
                 self._suspend_stats["suspend_failures"] += 1
@@ -2794,7 +2944,7 @@ class ContinuousBatcher:
                 req=req, k=k_host, v=v_host, n_blocks=len(tables[i]),
                 pos=host_pos[i], steps=host_steps[i], seed=host_seed[i],
                 spec=spec_slots[i], t_suspend=time.monotonic(),
-                reason=reason, min_blocks=min_blocks,
+                reason=reason, min_blocks=min_blocks, st=st_host,
             )
             finish_slot(i)  # decrefs the blocks; the host copy owns the KV
             self._suspended.append(srec)
@@ -2875,8 +3025,16 @@ class ContinuousBatcher:
                 slot = self._slots.index(None)
                 try:
                     bids = jnp.asarray(ids, jnp.int32)
-                    K = kv_pool_write_row(K, _dev_kv(rec.k), bids)
-                    V = kv_pool_write_row(V, _dev_kv(rec.v), bids)
+                    if has_state(K):
+                        # KV into the fresh blocks and the state into the
+                        # slot's row, in one donated dispatch (an eager
+                        # write would copy the whole state pool)
+                        K, V = self._state_restore(
+                            K, V, _dev_kv(rec.k), _dev_kv(rec.v), bids,
+                            rec.st, jnp.int32(slot))
+                    else:
+                        K = kv_pool_write_row(K, _dev_kv(rec.k), bids)
+                        V = kv_pool_write_row(V, _dev_kv(rec.v), bids)
                     if self.mesh is not None:
                         # same re-pin as control_import: the eager writes
                         # may lose the pool sharding the donated dispatches
@@ -3710,8 +3868,13 @@ class ContinuousBatcher:
                     for i in idx:
                         chunk = reqs[i].prompt_ids[start : start + C]
                         rows.append(chunk + [0] * (C - len(chunk)))
+                    # a row whose prompt ended in an earlier chunk reads
+                    # position 0; a family with a recurrent state is told so
+                    # (-1: none of this chunk's positions is real), or the
+                    # padding would run through the row's state
+                    lo = -1 if cfg.n_ssm_layers else 0
                     last_pos = [
-                        min(max(ns[i] - 1 - start, 0), C - 1) for i in idx
+                        min(max(ns[i] - 1 - start, lo), C - 1) for i in idx
                     ]
                     logits, km, vm = self._prefill_chunk_group(
                         self.params, jnp.asarray(rows, jnp.int32), km, vm,
@@ -4292,8 +4455,9 @@ class ContinuousBatcher:
                 self._wl_len = len(waitlist)  # popped-into-group != queued
                 if len(group) > 1:  # here only via the short same-bucket path
                     try:
-                        with self._admit_span(path="group", width=len(group),
-                                              bucket=head_bucket):
+                        with self._admit_span(
+                                path="group", width=len(group), bucket=head_bucket,
+                                tokens=max(len(r.prompt_ids) for r in group)):
                             handled = admit_group(group, head_bucket)
                     except Exception as e:  # noqa: BLE001 — surface to callers
                         for req in group:
@@ -4408,9 +4572,12 @@ class ContinuousBatcher:
         logprob: float | None = None,
         top_ids: list | None = None,
         top_lps: list | None = None,
+        into: list | None = None,
     ) -> str | None:
-        """Push one token; returns the end reason when the request just
-        finished, else None. The END event is NOT emitted here — the caller
+        """Push one token (or, with ``into``, append it there for the caller
+        to hand over as one ``("toks", [...])`` event); returns the end reason
+        when the request just finished, else None. The END event is NOT
+        emitted here — the caller
         frees the slot first, then emits, so a consumer observing "end" can
         rely on the slot (and the batcher's ``idle`` view) being current
         (the registry's idle-eviction check reads it immediately after a
@@ -4442,6 +4609,8 @@ class ContinuousBatcher:
             req.trace.emitted = (time.perf_counter(), req.generated)
         if req.want_logprobs:
             req.emit("tok", (tok_id, logprob, top_ids, top_lps))
+        elif into is not None:
+            into.append(tok_id)
         else:
             req.emit("tok", tok_id)
         req.emitted.append(int(tok_id))

@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import threading
 
-__all__ = ["BlockPool"]
+__all__ = ["BlockPool", "StatePool"]
 
 
 class BlockPool:
@@ -130,3 +130,28 @@ class BlockPool:
                 "cow_copies": self.cow_copies,
                 "epoch": self.epoch,
             }
+
+
+class StatePool:
+    """The books of the per-slot state pool beside the block pool.
+
+    A family with state-space layers keeps a recurrent state a slot
+    (models/ssm_hybrid.py): device arrays indexed by SLOT, not by a block
+    table, so there is nothing to allocate: slot i's state is row i, written
+    whole by the slot's admit and dead when the slot finishes (no copy, no
+    free list). What is kept here is what admission and the metrics page
+    need: how many slots there are, what one costs, how many hold a request.
+    """
+
+    def __init__(self, slots: int, bytes_per_slot: int, live_fn):
+        self.slots = int(slots)
+        self.bytes_per_slot = int(bytes_per_slot)
+        self._live_fn = live_fn
+
+    @property
+    def nbytes(self) -> int:
+        return self.slots * self.bytes_per_slot
+
+    def stats(self) -> dict:
+        return {"slots_total": self.slots, "slots_live": int(self._live_fn()),
+                "bytes": self.nbytes}

@@ -29,6 +29,8 @@ from ..models.config import ModelConfig
 from ..models.llama import forward, forward_decode_paged, make_cache
 from ..ops.kvcache import (
     KVQ,
+    WithState,
+    has_state,
     is_quantized,
     kv_copy_slice,
     kv_pool_copy_block,
@@ -38,6 +40,8 @@ from ..ops.kvcache import (
     kv_pool_write_row,
     kv_roll_s,
     kv_slice,
+    state_row,
+    state_write_row,
 )
 from ..parallel.ring_attention import use_ring_prefill
 from ..parallel.sharding import cache_spec, row_cache_spec, validate_mesh_for_config
@@ -144,6 +148,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
         The two caches of a pair are sliced each by its own shape: K and
         V alike for GQA, latent and rotary key for MLA."""
         zero = jnp.zeros((), jnp.int32)
+        if has_state(c):  # a row's state goes with it
+            return WithState(row_of(c.kv, i), state_row(c, i), c.axes)
         return kv_slice(c, (i, zero, zero, zero, zero), (1,) + tuple(c.shape[1:]))
 
     @partial(jax.jit, static_argnums=(6,))
@@ -484,6 +490,17 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
         T = kv_block_tokens
         pin_pool = pin_row  # pool [NB, L, Hkv, T, D]: heads at index 2
 
+        def pool_write(P, row, bids, slot):
+            """One prefilled row into the pool: its KV into the blocks
+            ``bids``, and, for a family that keeps a recurrent state beside
+            the KV (ops.kvcache.WithState), its state into row ``slot`` of
+            the state pool. A slot's state is whole after this write:
+            nothing of the slot's previous request is read again."""
+            if has_state(P):
+                return WithState(kv_pool_write_row(P.kv, row.kv, bids),
+                                 state_write_row(P, row.st, slot), P.axes)
+            return kv_pool_write_row(P, row, bids)
+
         @partial(jax.jit, donate_argnums=(0,))
         def sample_first(tok, logits, slot, seed, temp, topk, topp):
             """Full-prefix-hit admit: ZERO KV copies — the slot's block
@@ -499,8 +516,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
 
         def _write_and_sample(KP, VP, tok, k1, v1, logits, bids, slot,
                               seed, temp, topk, topp):
-            KP = pin_pool(kv_pool_write_row(KP, k1, bids))
-            VP = pin_pool(kv_pool_write_row(VP, v1, bids))
+            KP = pin_pool(pool_write(KP, k1, bids, slot))
+            VP = pin_pool(pool_write(VP, v1, bids, slot))
             first = sample_rows(
                 logits[:, 0], seed[None], jnp.zeros((1,), jnp.int32),
                 temp[None], topk[None], topp[None],
@@ -552,8 +569,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             def body(carry, i):
                 KP, VP, tok = carry
                 k1, v1 = row_of(km, i), row_of(vm, i)
-                KP = kv_pool_write_row(KP, k1, bids[i])
-                VP = kv_pool_write_row(VP, v1, bids[i])
+                KP = pool_write(KP, k1, bids[i], slots[i])
+                VP = pool_write(VP, v1, bids[i], slots[i])
                 tok = jax.lax.dynamic_update_slice(
                     tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1),
                     (slots[i],),
@@ -595,8 +612,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             def body(carry, i):
                 KP, VP, tok = carry
                 k1, v1 = row_of(km, i), row_of(vm, i)
-                KP = kv_pool_write_row(KP, k1, bids[i])
-                VP = kv_pool_write_row(VP, v1, bids[i])
+                KP = pool_write(KP, k1, bids[i], slots[i])
+                VP = pool_write(VP, v1, bids[i], slots[i])
                 tok = jax.lax.dynamic_update_slice(
                     tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1),
                     (slots[i],),
@@ -722,10 +739,12 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
         @partial(jax.jit, donate_argnums=(0, 1))
         def pool_copy_block(KP, VP, dst, src):
             """Copy-on-write: duplicate one shared block before a write."""
-            return (
-                pin_pool(kv_pool_copy_block(KP, dst, src)),
-                pin_pool(kv_pool_copy_block(VP, dst, src)),
-            )
+            def cp(P):  # blocks are KV; a slot's state is never shared
+                if has_state(P):
+                    return WithState(kv_pool_copy_block(P.kv, dst, src), P.st, P.axes)
+                return kv_pool_copy_block(P, dst, src)
+
+            return pin_pool(cp(KP)), pin_pool(cp(VP))
 
         # -- Pallas paged-decode twins (ops/paged_attention.py) --------
         # Same signatures and return contracts as the *_paged programs
@@ -827,6 +846,17 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             width = toks_in.shape[1]
             return (out, n_emit, pin_pool(KP), pin_pool(VP), new_tok,
                     pos + n_emit, steps + width)
+
+        if cfg.n_ssm_layers:
+            @partial(jax.jit, donate_argnums=(0, 1))
+            def state_restore(KP, VP, krow, vrow, bids, st, slot):
+                """Resume of a suspended slot of a family with a recurrent
+                state: the host copies of its KV into fresh blocks and of
+                its state into the slot's row, pools donated."""
+                return (pool_write(KP, WithState(krow, st[0], KP.axes), bids, slot),
+                        pool_write(VP, WithState(vrow, st[1], VP.axes), bids, slot))
+
+            programs["state_restore"] = state_restore
 
         programs.update({
             "sample_first": sample_first,

@@ -1150,7 +1150,7 @@ class LocalRegistry(Registry):
         )
         if not self.kv_paged:
             return est["total"]
-        from ..parallel.memory import kv_pool_block_bytes
+        from ..parallel.memory import kv_pool_block_bytes, state_slot_bytes
         from .prefix_cache import serving_chunk
 
         # mirror the batcher's block-size snap (T | serving chunk) and its
@@ -1168,6 +1168,9 @@ class LocalRegistry(Registry):
         pool = nb * kv_pool_block_bytes(
             cfg, T, kv_quant=self.kv_quant, tp=self._kv_tp(cfg)
         )
+        # beside the blocks, a slot's recurrent state (state-space layers):
+        # priced whole a slot, whatever the slot's context
+        pool += self.max_batch_slots * state_slot_bytes(cfg)
         return est["total"] - est["kv_cache"] + pool
 
     def _mesh_unservable(self, path: str) -> str | None:
@@ -1309,7 +1312,7 @@ class LocalRegistry(Registry):
             # yet, so none of the idle-engine single-dispatch shortcuts)
             use_flash_attention=(
                 jax.default_backend() == "tpu" and self._kv_tp(cfg) == tp
-                and not cfg.is_mla
+                and cfg.family == "llama"
             ),
             use_routed_moe=True,  # sparse dispatch (parallel/moe.py)
             kv_quant=self.kv_quant,
@@ -1393,6 +1396,10 @@ class LocalRegistry(Registry):
             # Per-replica managers: demote/promote stay owner-thread-local,
             # and per-replica spill namespaces keep the Object Store index
             # single-writer.
+            if cfg.n_ssm_layers and self.kv_host_pool_bytes > 0:
+                b.refusals["kv_tiers"] = (
+                    "off: the host/Object-Store tiers hold KV blocks and no "
+                    "recurrent state (KV_HOST_POOL_BYTES=0 says the same)")
             if (
                 self.kv_host_pool_bytes > 0
                 and b.paged

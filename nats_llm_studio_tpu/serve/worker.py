@@ -1895,6 +1895,28 @@ class Worker:
                         help="the form a decode burst's expert layers take: "
                              "hit_list reads only the experts hit, grouped "
                              "computes each pick on its own expert, dense all")
+            ssm = getattr(stats, "state_counters", None)
+            pool_fn = getattr(rb, "pool_stats", None)
+            state_pool = ((pool_fn() if pool_fn else None) or {}).get("state")
+            if ssm is not None and state_pool:
+                # state-space layers (models/ssm_hybrid.py): rows / steps is
+                # the live rows whose recurrent state a decode step advanced;
+                # the pool is indexed by slot, beside the paged KV pool
+                for name, v in ssm().items():
+                    r.counter(f"lmstudio_ssm_{name}_total", v, labels=labels)
+                r.gauge("lmstudio_ssm_state_pool_bytes", state_pool["bytes"],
+                        labels=labels,
+                        help="device bytes of the per-slot recurrent-state pool")
+                r.gauge("lmstudio_ssm_state_pool_slots_live",
+                        state_pool["slots_live"], labels=labels)
+                r.gauge("lmstudio_ssm_state_pool_slots_total",
+                        state_pool["slots_total"], labels=labels)
+            for feature, cause in sorted(getattr(rb, "refusals", {}).items()):
+                # what was asked for and this model's family does not serve
+                r.gauge("lmstudio_feature_refused", 1,
+                        labels={**labels, "feature": feature, "cause": cause},
+                        help="a serving feature turned off for this model, "
+                             "with the cause")
             rows_fn = getattr(stats, "expert_prefill_rows", None)
             if rows_fn is not None and any(rows_fn().values()):
                 # the prefill side: rows of prompt tokens (padding included)

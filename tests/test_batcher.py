@@ -488,3 +488,44 @@ async def test_idle_full_prefill_matches(model):
         assert got2 == want2
     finally:
         b.stop()
+
+
+@pytest.mark.parametrize("wide", [True, False], ids=["wide_burst", "narrow_burst"])
+def test_a_wide_bursts_tokens_of_a_row_reach_its_stream_in_one_hand_over(model, wide):
+    """A decode burst that hands over more than ``_TOKEN_HANDOVERS_A_BURST``
+    tokens gives a row's n tokens to its stream as ONE event (one wake-up of
+    the event loop), not n: at 24 live rows x 8 tokens the per-token
+    hand-overs stretched the owner thread's way from a readback to its next
+    intake until a closed loop's next request raced it (PR 34). A burst
+    under the mark keeps a hand-over a token, as the 8-slot cells measure it.
+    Either way the stream's tokens are the single-stream reference's."""
+    from nats_llm_studio_tpu.serve import batcher as bt
+
+    cfg, params = model
+    prompt, n = [3, 1, 4, 1, 5, 9, 2, 6], 13
+    want = reference_greedy(cfg, params, prompt, n)
+    kinds: list[tuple[str, int]] = []
+    sound, mark = bt._Request.emit, bt._TOKEN_HANDOVERS_A_BURST
+
+    def noting(self, kind, value):
+        kinds.append((kind, len(value) if kind == "toks" else 1))
+        sound(self, kind, value)
+
+    async def stream():
+        b = ContinuousBatcher(params, cfg, max_slots=2, max_seq_len=64, decode_burst=4)
+        try:
+            sp = SamplingParams(temperature=0.0, max_tokens=n)
+            return [batch async for batch in b.submit_batched(prompt, sp)]
+        finally:
+            b.stop()
+
+    bt._Request.emit = noting
+    bt._TOKEN_HANDOVERS_A_BURST = 3 if wide else mark  # one row x four steps
+    try:
+        batches = asyncio.run(stream())
+    finally:
+        bt._Request.emit, bt._TOKEN_HANDOVERS_A_BURST = sound, mark
+    assert [t for batch in batches for t in batch] == want
+    # the admit's first token alone, then three bursts of four, then the end
+    burst = [("toks", 4)] if wide else [("tok", 1)] * 4
+    assert kinds == [("tok", 1), *burst * 3, ("end", 1)]

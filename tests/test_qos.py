@@ -56,7 +56,7 @@ from nats_llm_studio_tpu.transport.envelope import (
     shed_cause_of,
 )
 
-from conftest import async_test
+from conftest import async_test, hold_decodes_until_queued
 from fakes import EchoEngine, FakeRegistry
 from test_gateway import CHAT, GatewayHarness
 
@@ -336,7 +336,11 @@ async def test_shed_only_spares_premium(model):
 async def _pressure_pair(b, pa, pb, na, nb, qa, qb):
     """A (tenant/priority ``qa``) decodes first; once 2 of A's tokens
     arrived, B (``qb``) submits — whose admit exhausts the 3-block pool.
+    The owner thread holds A's fourth decode step until B is in its inbox
+    (``hold_decodes_until_queued``): A cannot run out its 12 tokens before B
+    has arrived, however late this loop gets to submit it.
     Returns (a_tokens, b_tokens)."""
+    hold_decodes_until_queued(b)
     spa = SamplingParams(temperature=0.0, max_tokens=na)
     spb = SamplingParams(temperature=0.0, max_tokens=nb)
     started = asyncio.get_running_loop().create_future()

@@ -315,3 +315,111 @@ def test_a_prefill_chunk_at_the_benchmark_cells_shapes_copies_no_expert_stack(
     assert text.count("moe_grouped_experts") >= 1 and "moe_hit_experts" not in text
     stack_slice = cfg.n_experts * cfg.d_model * cfg.moe_d_ff * 2
     assert compiled.memory_analysis().temp_size_in_bytes < stack_slice
+
+
+# -- the state-space / attention hybrid family at granite4hmicro.chat32_closed's shapes --
+
+
+@pytest.fixture(scope="module")
+def ssm_cell():
+    """(cfg, the served tree's shapes) of ``benchmark/configs/granite-4.0-h-micro.json``."""
+    import json
+    from pathlib import Path
+
+    from benchmark import run
+
+    root = Path(__file__).resolve().parents[1]
+    ref = run.load_module(root / "benchmark/references/ssm_hybrid.py")
+    conf = json.loads((root / "benchmark/configs/granite-4.0-h-micro.json").read_text())
+    cfg = ref.model_config(conf, SEQ)
+    return cfg, ref.param_shapes(cfg)
+
+
+SSM_SLOTS = 32
+
+
+def _ssm_pools(cfg, sharding):
+    """The cell's pools: 32 x 256 + 1 blocks of 4 layers of packed rows, each
+    with the 32 slots' state beside it."""
+    from nats_llm_studio_tpu.models import ssm_hybrid
+    from nats_llm_studio_tpu.ops.kvcache import WithState
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    (h, w), _ = cfg.kv_cache_dims()
+    nb = SSM_SLOTS * (SEQ // T) + 1
+    (tail, seen), (plane,) = ssm_hybrid.state_shapes(cfg, SSM_SLOTS)
+    kv = lambda: sds((nb, cfg.n_kv_layers, h, T, w), jnp.bfloat16)  # noqa: E731
+    return (WithState(kv(), (sds(tail, jnp.bfloat16), sds(seen, jnp.int32)), ssm_hybrid.K_AXES),
+            WithState(kv(), (sds(plane, jnp.float32),), ssm_hybrid.V_AXES))
+
+
+def test_ssm_state_step_at_the_benchmark_cells_shapes(one_chip, no_cache, ssm_cell):
+    """One layer's step over the whole state pool [32, 36, 32, 128, 128] f32
+    (2.4 GB): Mosaic tiles it, and the pool is the result's own buffer (the
+    alias holds: no second pool among the temporaries)."""
+    from nats_llm_studio_tpu.ops import ssm_scan
+
+    cfg, _ = ssm_cell
+    _, vp = _ssm_pools(cfg, one_chip)
+    pool = vp.st[0]
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    h, p, n = cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_d_state
+    compiled = jax.jit(
+        lambda pool, layer, decay, dtx, bm, cm: ssm_scan.ssm_state_step(
+            pool, layer, decay, dtx, bm, cm),
+        donate_argnums=(0,)).lower(
+        pool, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        f32(SSM_SLOTS, h), f32(SSM_SLOTS, h, p), f32(SSM_SLOTS, n), f32(SSM_SLOTS, n)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_state_step" in text
+    ma = compiled.memory_analysis()
+    pool_bytes = int(np.prod(pool.shape)) * 4
+    assert ma.alias_size_in_bytes >= pool_bytes and ma.temp_size_in_bytes < pool_bytes // 8
+
+
+def test_a_decode_burst_of_the_state_space_family_copies_no_pool(one_chip, no_cache, ssm_cell):
+    """Four steps of all 40 layers over the cell's pools, donated: one scan
+    over the 4 periods and one over each run of layers inside it (compiled
+    in seconds, not 40 unrolled layers), both kernels in it, the pools
+    aliased onto the results, and no ``copy`` of the float32 state pool, of
+    the convolution tails or of a KV pool anywhere in the program."""
+    from nats_llm_studio_tpu.models import llama
+
+    cfg, shapes = ssm_cell
+    kp, vp = _ssm_pools(cfg, one_chip)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    def burst(params, tok, kp, vp, tbl, pos):
+        def step(c, i):
+            tok, kp, vp = c
+            logits, kp, vp = llama.forward_decode_paged(
+                params, cfg, tok[:, None], kp, vp, tbl, pos + i)
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return (nxt, kp, vp), nxt
+
+        (_, kp, vp), toks = jax.lax.scan(step, (tok, kp, vp), jnp.arange(4, dtype=jnp.int32))
+        return toks, kp, vp
+
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
+    try:
+        compiled = jax.jit(burst, donate_argnums=(2, 3)).lower(
+            jax.tree.map(sds, shapes), ints(SSM_SLOTS), kp, vp,
+            ints(SSM_SLOTS, SEQ // T), ints(SSM_SLOTS)).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    assert "ssm_state_step" in text and "paged_decode_attention" in text
+    state, kv, tails = vp.st[0], kp.kv, kp.st[0]
+    pools = (f"f32[{','.join(map(str, state.shape))}]", f"bf16[{','.join(map(str, kv.shape))}]",
+             f"bf16[{','.join(map(str, tails.shape))}]")
+    copies = [ln.strip()[:160] for ln in text.splitlines()
+              if (" copy(" in ln or "copy-start(" in ln) and any(p in ln for p in pools)]
+    assert not copies, copies
+    ma = compiled.memory_analysis()
+    state_bytes = int(np.prod(state.shape)) * 4
+    assert ma.alias_size_in_bytes >= state_bytes + 2 * int(np.prod(kv.shape)) * 2
+    assert ma.temp_size_in_bytes < state_bytes // 8
