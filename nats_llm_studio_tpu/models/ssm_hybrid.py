@@ -32,11 +32,17 @@ every other family uses. What differs:
   Prefill runs the chunked scan and returns the state after the last REAL
   position of each row (``logit_positions + 1`` positions are real: padding
   neither decays nor feeds a state and is never in a convolution tail).
-  Decode updates the pool in place, one Pallas call a layer. A slot whose
-  position is one the state has consumed already (``start_pos < seen``: the
-  batcher replays the last prompt position of a request that wants its first
-  token masked or with log-probabilities) reads its state and does not
-  advance it.
+  Decode updates the pool in place, one Pallas call a layer, and only where
+  a slot holds a request: the slots whose row of the block table names a
+  block (``ops.kvcache.table_rows_in_use``) are listed once a launch
+  (``ops.ssm_scan.live_slots``) and every layer's call moves those slots'
+  state alone. An empty slot, and one a chunked admit has reserved and not
+  finished, has no block yet: its state, tail and ``seen`` come out of a
+  launch as they went in, and its row of the mixer's output is zeros. A live
+  slot whose position is one the state has consumed already (``start_pos <
+  seen``: the batcher replays the last prompt position of a request that
+  wants its first token masked or with log-probabilities) reads its state
+  and does not advance it.
 
 The state, dt, the decays and the gated norm run in float32; products take
 the weights' dtype as in the other families.
@@ -51,7 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import ssm_scan
-from ..ops.kvcache import WithState, kv_pool_write_rows, kv_update_slice
+from ..ops.kvcache import WithState, kv_pool_write_rows, kv_update_slice, table_rows_in_use
 from ..ops.layers import apply_rope, gqa_attention_hmajor, rms_norm, rope_cos_sin, swiglu
 from ..ops.wquant import mm
 from .config import ModelConfig
@@ -170,10 +176,11 @@ def mamba_prefill(h, p: Params, cfg: ModelConfig, tails, states, layer, valid):
     return _mixer_out(y, x, z, p, cfg), tails, states
 
 
-def mamba_step(h, p: Params, cfg: ModelConfig, tails, states, layer, fresh):
-    """The mixer over ONE position of every slot, the state pool updated in
-    place. ``fresh`` [B] bool: rows that consume their position (the others
-    read their state as it is)."""
+def mamba_step(h, p: Params, cfg: ModelConfig, tails, states, layer, live, fresh):
+    """The mixer over ONE position of the ``live`` slots (``ssm_scan.
+    LiveSlots``), their state updated in place in the pool. ``fresh`` [B]
+    bool: live rows that consume their position (the other live rows read
+    their state as it is; a row that is not live gives zeros)."""
     zero = jnp.zeros((), jnp.int32)
     z, xbc, dt_raw = _project_in(h[:, 0], p, cfg)
     tail = jax.lax.dynamic_index_in_dim(tails, layer, axis=0, keepdims=False)
@@ -183,7 +190,7 @@ def mamba_step(h, p: Params, cfg: ModelConfig, tails, states, layer, fresh):
     dt = _dt(dt_raw, p)
     decay = jnp.where(fresh[:, None], jnp.exp(dt * _a(p)), 1.0)
     dtx = jnp.where(fresh[:, None, None], dt[..., None] * x.astype(jnp.float32), 0.0)
-    states, y = ssm_scan.ssm_state_step_auto(states, layer, decay, dtx, bm, cm)
+    states, y = ssm_scan.ssm_state_step_auto(states, layer, live, decay, dtx, bm, cm)
     return _mixer_out(y, x, z, p, cfg)[:, None], tails, states
 
 
@@ -360,7 +367,8 @@ def forward_decode_paged(
     """``models.llama.forward_decode_paged``'s contract, one position a slot:
     the attention layers write their packed row into the pool and attend over
     the slot's table (the paged decode kernel), the mamba layers update the
-    slot's state in place. Row i of the batch IS slot i of the state."""
+    state in place of the slots that hold a request, those whose row of
+    ``tbl`` names a block. Row i of the batch IS slot i of the state."""
     from ..ops.paged_attention import paged_decode_attention_auto
 
     del mesh
@@ -371,13 +379,16 @@ def forward_decode_paged(
             "would advance the state past the drafts that are rejected, and the "
             "pool keeps no snapshot to go back to (SPEC_DECODE=0)")
     (tails, seen), (states,) = k_pool.st, v_pool.st
-    fresh = start_pos >= seen
+    # one list for all the layers of the step (and of the burst: ``tbl`` is
+    # the launch's, and no step changes it)
+    live = ssm_scan.live_slots(table_rows_in_use(tbl))
+    fresh = live.mask & (start_pos >= seen)
     positions = start_pos[:, None]
     x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
 
     def mamba(h, p, carry, layer):
         kp, vp, tails, states = carry
-        out, tails, states = mamba_step(h, p, cfg, tails, states, layer, fresh)
+        out, tails, states = mamba_step(h, p, cfg, tails, states, layer, live, fresh)
         return out, (kp, vp, tails, states)
 
     def attention(h, p, carry, layer):

@@ -173,6 +173,14 @@ def kv_roll_s(cache, shift, s_axis: int):
 # benign even though jnp scatter leaves duplicate-index order undefined.
 
 
+def table_rows_in_use(tbl):
+    """[B] bool from a block table [B, NB]: the rows that name a block other
+    than the null block, i.e. the slots that hold a request. A numpy table
+    gives a numpy mask and a traced one a traced mask: the host counts its
+    slots by the rule the device lists them by."""
+    return (tbl != 0).any(axis=1)
+
+
 def kv_pool_zeros(shape, dtype=None, quant: bool = False):
     """A zeroed pool leaf-set: bf16/f32 array or KVQ pair, [NB, L, H, T, D]."""
     if quant:

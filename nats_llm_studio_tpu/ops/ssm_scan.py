@@ -13,10 +13,15 @@ chunk's contribution to the state is one product over its positions, and the
 state is carried from chunk to chunk. A position with dt = 0 neither decays
 nor feeds the state: that is how padding is left out.
 
-**Decode** (``ssm_state_step``) is ONE Pallas kernel over the whole state pool
+**Decode** (``ssm_state_step``) is ONE Pallas kernel over the state pool
 ``[slots, layers, H / k, N, k P]``, aliased onto its output: a grid cell reads
 a slot's block of heads, updates it and writes it back to the same place, so
-a step moves every state byte once in and once out and copies nothing. The
+a step copies nothing. Which slots it touches is data: ``live_slots`` lists
+the slots that hold a decoding request, once a launch, and the list rides in
+as scalars beside the layer. Grid place g is slot ``order[g]``; a place past
+the list names the block the place before it named, which is neither fetched
+nor written again, so a step moves the LIVE slots' state once in and once out
+and a slot without a request comes out bit for bit as it went in. The
 pool's minor plane is [N, k P] with k = 128 / P heads side by side on the 128
 lanes (P = 64: two heads a row): the decay and dt x are then plain lane rows,
 B and C columns, the update a broadcast multiply-add and the read-out a sum
@@ -31,10 +36,14 @@ its first token masked or with log-probabilities).
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .moe_experts import hit_list
 
 LANES = 128
 # what one grid cell's block of a slot's state may take: 1 MiB, double
@@ -166,46 +175,85 @@ def _heads_block(rows: int, n: int, lanes: int) -> int:
     return next(r for r in range(min(cap, rows), 0, -1) if rows % r == 0)
 
 
-def _step_kernel(hb, lanes, layer_ref, a_ref, u_ref, bc_ref, s_ref, so_ref, y_ref):
-    """Grid (slots, blocks of head rows). a, u: [1, hb x lanes] rows (decay
-    and dt x of the block's heads); bc: [N, 2] (B and C as columns); s: the
-    block [hb, N, lanes] of the slot's state in this layer."""
-    del layer_ref
-    bcol = bc_ref[:, 0:1]
-    ccol = bc_ref[:, 1:2]
-    for j in range(hb):
-        at = slice(j * lanes, (j + 1) * lanes)
-        s = s_ref[j] * a_ref[:, at] + bcol * u_ref[:, at]
-        so_ref[j] = s
-        y_ref[:, at] = jnp.sum(s * ccol, axis=0, keepdims=True)
+class LiveSlots(NamedTuple):
+    """The slots of the state pool a decode launch touches: ``mask`` [slots]
+    bool, ``order`` [slots] int32 (the live slots in rising order, then the
+    last of them again in every place past ``n``) and ``n`` int32."""
+    mask: jax.Array
+    order: jax.Array
+    n: jax.Array
 
 
-def ssm_state_step(pool: jax.Array, layer, decay: jax.Array, dtx: jax.Array,
-                   bm: jax.Array, cm: jax.Array, interpret: bool = False):
-    """One position of every slot in layer ``layer`` of the state pool
+def live_slots(mask: jax.Array) -> LiveSlots:
+    """The list ``ssm_state_step`` prefetches, from ``mask`` [slots] bool.
+    Made once a launch: the layers of a step all take the same one."""
+    order, n = hit_list(mask, mask.shape[0])
+    return LiveSlots(mask, order, n)
+
+
+def _step_kernel(hb, lanes, layer_ref, order_ref, n_ref, a_ref, u_ref, bc_ref,
+                 s_ref, so_ref, y_ref):
+    """Grid (places, blocks of head rows): place g is slot ``order[g]``.
+    a, u: [1, hb x lanes] rows (decay and dt x of the block's heads); bc:
+    [N, 2] (B and C as columns); s: the block [hb, N, lanes] of the slot's
+    state in this layer. A place past ``n`` does nothing: its blocks are the
+    ones the place before it named, still in VMEM and written back once."""
+    del layer_ref, order_ref
+    g, n = pl.program_id(0), n_ref[0]
+
+    @pl.when(g < n)
+    def _live():
+        bcol = bc_ref[:, 0:1]
+        ccol = bc_ref[:, 1:2]
+        for j in range(hb):
+            at = slice(j * lanes, (j + 1) * lanes)
+            s = s_ref[j] * a_ref[:, at] + bcol * u_ref[:, at]
+            so_ref[j] = s
+            y_ref[:, at] = jnp.sum(s * ccol, axis=0, keepdims=True)
+
+    @pl.when((n == 0) & (g == 0) & (pl.program_id(1) == 0))
+    def _nothing_listed():
+        # every place names ONE block, and the grid's end writes it back:
+        # from what was read, not from a buffer nothing wrote
+        so_ref[...] = s_ref[...]
+
+
+def ssm_state_step(pool: jax.Array, layer, live: LiveSlots, decay: jax.Array,
+                   dtx: jax.Array, bm: jax.Array, cm: jax.Array, interpret: bool = False):
+    """One position of the ``live`` slots in layer ``layer`` of the state pool
     ``[slots, L, H / k, N, k P]`` f32, in place (the pool is aliased onto the
-    result: donate it). ``decay`` [slots, H] = exp(dt A) (1 for a row that must
-    keep its state), ``dtx`` [slots, H, P] = dt x (0 likewise), ``bm``, ``cm``
-    [slots, N]. Returns (pool, y [slots, H, P] f32 = C . S after the update)."""
+    result: donate it). ``decay`` [slots, H] = exp(dt A) (1 for a live row that
+    must keep its state), ``dtx`` [slots, H, P] = dt x (0 likewise), ``bm``,
+    ``cm`` [slots, N]. Returns (pool, y [slots, H, P] f32 = C . S after the
+    update; zeros for a slot that is not live, whose state is not touched)."""
     slots, _, rows, n, lanes = pool.shape
     h, p = dtx.shape[1], dtx.shape[2]
     hb = _heads_block(rows, n, lanes)
+    nj = rows // hb
     a_row = jnp.repeat(decay.astype(jnp.float32), p, axis=1).reshape(slots, 1, h * p)
     u_row = dtx.astype(jnp.float32).reshape(slots, 1, h * p)
     bc = jnp.stack([bm, cm], axis=-1).astype(jnp.float32)  # [slots, N, 2]
 
-    def row_map(b, j, layer_ref):
-        return (b, 0, j)
+    def at(g, j, order_ref, n_ref):  # a place past the list stays on the last block
+        return order_ref[g], jnp.where(g < n_ref[0], j, nj - 1)
 
-    def state_map(b, j, layer_ref):
-        return (b, layer_ref[0], j, 0, 0)
+    def row_map(g, j, layer_ref, order_ref, n_ref):
+        slot, j = at(g, j, order_ref, n_ref)
+        return (slot, 0, j)
+
+    def bc_map(g, j, layer_ref, order_ref, n_ref):
+        return (order_ref[g], 0, 0)
+
+    def state_map(g, j, layer_ref, order_ref, n_ref):
+        slot, j = at(g, j, order_ref, n_ref)
+        return (slot, layer_ref[0], j, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(slots, rows // hb),
+        num_scalar_prefetch=3,
+        grid=(slots, nj),
         in_specs=[pl.BlockSpec((None, 1, hb * lanes), row_map),
                   pl.BlockSpec((None, 1, hb * lanes), row_map),
-                  pl.BlockSpec((None, n, 2), lambda b, j, layer_ref: (b, 0, 0)),
+                  pl.BlockSpec((None, n, 2), bc_map),
                   pl.BlockSpec((None, None, hb, n, lanes), state_map)],
         out_specs=[pl.BlockSpec((None, None, hb, n, lanes), state_map),
                    pl.BlockSpec((None, 1, hb * lanes), row_map)],
@@ -215,18 +263,22 @@ def ssm_state_step(pool: jax.Array, layer, decay: jax.Array, dtx: jax.Array,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                    jax.ShapeDtypeStruct((slots, 1, h * p), jnp.float32)],
-        # operand 4 (after the prefetched layer) is the pool; result 0 is it again
-        input_output_aliases={4: 0},
+        # operand 6 (after the prefetched layer and list) is the pool; result
+        # 0 is it again
+        input_output_aliases={6: 0},
+        # a block is revisited along both axes: neither may be split
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         # a constant: the custom call's name in a device trace
         name="ssm_state_step",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), a_row, u_row, bc, pool)
-    return pool, y.reshape(slots, h, p)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), live.order,
+      jnp.asarray(live.n, jnp.int32).reshape(1), a_row, u_row, bc, pool)
+    # the rows of y no place named hold whatever the buffer held
+    return pool, jnp.where(live.mask[:, None, None], y.reshape(slots, h, p), 0.0)
 
 
-def ssm_state_step_auto(pool, layer, decay, dtx, bm, cm):
+def ssm_state_step_auto(pool, layer, live, decay, dtx, bm, cm):
     """The kernel, through the Pallas interpreter off-TPU."""
-    return ssm_state_step(pool, layer, decay, dtx, bm, cm,
+    return ssm_state_step(pool, layer, live, decay, dtx, bm, cm,
                           interpret=jax.default_backend() != "tpu")

@@ -59,6 +59,7 @@ from ..ops.kvcache import (
     kv_pool_write_row,
     kv_pool_zeros,
     state_row,
+    table_rows_in_use,
 )
 from .block_pool import BlockPool, StatePool
 from .brownout import LEVEL_NAMES, SHED_ONLY, BrownoutConfig, BrownoutController
@@ -301,11 +302,15 @@ class BatcherStats:
     expert_rows: int = 0
     expert_steps: int = 0
     # state-space layers (models/ssm_hybrid.py): rows whose recurrent state a
-    # decode step advanced (live rows x steps of every burst), the steps, and
-    # the admits by how their state was made (from zeros in one dispatch, or
-    # carried from chunk to chunk of a prompt over one chunk)
+    # decode step advanced (live rows x steps of every burst), the steps, the
+    # slots whose state a step moved (the slots the launch's block table
+    # lists x steps: over slots x steps it is the share of the state pool a
+    # step reads and writes), and the admits by how their state was made (from
+    # zeros in one dispatch, or carried from chunk to chunk of a prompt over
+    # one chunk)
     state_rows: int = 0
     state_steps: int = 0
+    state_slots_moved: int = 0
     state_admits_fresh: int = 0
     state_admits_carried: int = 0
     # the form the expert layers of a decode burst take: "hit_list" (only the
@@ -466,17 +471,21 @@ class BatcherStats:
             setattr(self, k, getattr(self, k) + v)
         return burst | ({"expert_path": self.expert_path} if self.expert_path else {})
 
-    def record_state(self, rows: int, steps: int) -> dict[str, int]:
+    def record_state(self, rows: int, steps: int, listed: int) -> dict[str, int]:
         """One decode burst of a family with a recurrent state: ``rows`` live
-        rows advanced their state ``steps`` times. Returns what the readback
-        span carries."""
-        self.state_rows += rows * steps
-        self.state_steps += steps
-        return {"state_rows": rows * steps, "state_steps": steps}
+        rows advanced their state ``steps`` times, and the launch's block
+        table listed ``listed`` slots for the state kernel to move. Returns
+        what the readback span carries."""
+        burst = {"state_rows": rows * steps, "state_steps": steps,
+                 "state_slots_moved": listed * steps}
+        for k, v in burst.items():
+            setattr(self, k, getattr(self, k) + v)
+        return burst
 
     def state_counters(self) -> dict[str, int]:
         """Exposed by serve/worker.py as lmstudio_ssm_*_total."""
         return {"state_rows": self.state_rows, "state_steps": self.state_steps,
+                "state_slots_moved": self.state_slots_moved,
                 "state_admits_fresh": self.state_admits_fresh,
                 "state_admits_carried": self.state_admits_carried}
 
@@ -1934,6 +1943,11 @@ class ContinuousBatcher:
         # past a slot's allocation are 0 (the null block).
         tables: list[list[int]] = [[] for _ in range(B)]
         tbl_dev = jnp.zeros((B, max(MB, 1)), jnp.int32)
+        # rows of tbl_dev that name a block: the slots a decode launch of a
+        # family with a recurrent state lists for its state kernel
+        # (models/ssm_hybrid.py makes its list from the same table by the
+        # same rule)
+        tbl_rows_in_use = 0
         table_dirty = False
 
         # hierarchical KV tiers + slot suspend (owner-thread handles)
@@ -2101,13 +2115,14 @@ class ContinuousBatcher:
         def refresh_tables() -> None:
             """Mirror the host block tables to the device [B, MB] array the
             paged decode/verify programs gather through."""
-            nonlocal tbl_dev, table_dirty
+            nonlocal tbl_dev, tbl_rows_in_use, table_dirty
             if not table_dirty:
                 return
             arr = np.zeros((B, max(MB, 1)), np.int32)
             for i, t in enumerate(tables):
                 arr[i, : len(t)] = t
             tbl_dev = jnp.asarray(arr)
+            tbl_rows_in_use = int(table_rows_in_use(arr).sum())
             table_dirty = False
 
         def paged_window(top: int) -> int:
@@ -2142,8 +2157,8 @@ class ContinuousBatcher:
         host_seed = [0] * B
 
         # in-flight dispatches whose results have not been read back:
-        # ("decode", toks_ref, n, [(slot, req), ...]) |
-        # ("ext", toks, lps, top_ids, top_lps, [(slot, req), ...], t) |
+        # ("decode", toks_ref, n, [(slot, req), ...], t, slots listed) |
+        # ("ext", toks, lps, top_ids, top_lps, [(slot, req), ...], t, listed) |
         # ("admit", firsts_ref, [(row_in_firsts, slot, req), ...])
         inflight: collections.deque = collections.deque()
 
@@ -2216,7 +2231,7 @@ class ContinuousBatcher:
             readback errors mean poisoned device state)."""
             nonlocal tok_dev, dirty
             if rec[0] == "decode":
-                _, toks_ref, n, rows, t_disp = rec
+                _, toks_ref, n, rows, t_disp, listed = rec
                 with obs_spans.span("batcher.readback", program="decode") as spn:
                     ids = np.asarray(toks_ref)  # ONE [B, n] readback per burst
                     if ids.shape[0] > B:
@@ -2224,7 +2239,7 @@ class ContinuousBatcher:
                         # counters (decode_pos_moe)
                         spn.attrs.update(self.stats.record_moe(ids[B:]))
                     if self._state_pool is not None:
-                        spn.attrs.update(self.stats.record_state(len(rows), n))
+                        spn.attrs.update(self.stats.record_state(len(rows), n, listed))
                 # observed per-step latency (dispatch -> tokens readable);
                 # includes pipeline wait, i.e. what a stream experiences
                 now = time.monotonic()
@@ -2348,10 +2363,10 @@ class ContinuousBatcher:
                             finish_slot(slot)
                     spn.attrs["tokens"] = self.stats.tokens - tok0
             elif rec[0] == "ext":
-                _, toks_ref, lp_ref, topids_ref, toplps_ref, rows, t_disp = rec
+                _, toks_ref, lp_ref, topids_ref, toplps_ref, rows, t_disp, listed = rec
                 with obs_spans.span("batcher.readback", program="ext") as spn:
                     if self._state_pool is not None:
-                        spn.attrs.update(self.stats.record_state(len(rows), 1))
+                        spn.attrs.update(self.stats.record_state(len(rows), 1, listed))
                     ids = np.asarray(toks_ref)  # [B]
                     lps = np.asarray(lp_ref)  # [B]
                     tis = np.asarray(topids_ref)  # [B, LOGPROBS_K]
@@ -2643,7 +2658,8 @@ class ContinuousBatcher:
                     host_pos[i] += n
                     host_steps[i] += n
                 inflight.append(
-                    ("decode", toks, n, [(i, self._slots[i]) for i in act], time.monotonic())
+                    ("decode", toks, n, [(i, self._slots[i]) for i in act], time.monotonic(),
+                     tbl_rows_in_use)
                 )
                 self._charge_ctx = prev_ctx
 
@@ -2712,7 +2728,8 @@ class ContinuousBatcher:
                     host_steps[i] += 1
                 inflight.append(
                     ("ext", toks, lps, top_ids, top_lps,
-                     [(i, self._slots[i]) for i in act], time.monotonic())
+                     [(i, self._slots[i]) for i in act], time.monotonic(),
+                     tbl_rows_in_use)
                 )
                 self._charge_ctx = prev_ctx
 

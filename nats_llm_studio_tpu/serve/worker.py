@@ -1900,8 +1900,10 @@ class Worker:
             state_pool = ((pool_fn() if pool_fn else None) or {}).get("state")
             if ssm is not None and state_pool:
                 # state-space layers (models/ssm_hybrid.py): rows / steps is
-                # the live rows whose recurrent state a decode step advanced;
-                # the pool is indexed by slot, beside the paged KV pool
+                # the live rows whose recurrent state a decode step advanced,
+                # slots_moved / steps the slots whose state it read and wrote
+                # (the state kernel skips a slot that holds no request); the
+                # pool is indexed by slot, beside the paged KV pool
                 for name, v in ssm().items():
                     r.counter(f"lmstudio_ssm_{name}_total", v, labels=labels)
                 r.gauge("lmstudio_ssm_state_pool_bytes", state_pool["bytes"],

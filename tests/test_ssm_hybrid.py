@@ -25,7 +25,7 @@ from nats_llm_studio_tpu.models import llama, ssm_hybrid
 from nats_llm_studio_tpu.models.config import ModelConfig
 from nats_llm_studio_tpu.ops import ssm_scan
 from nats_llm_studio_tpu.ops.kvcache import (
-    WithState, kv_pool_write_row, kv_pool_zeros, state_row, state_write_row)
+    WithState, kv_pool_write_row, kv_pool_zeros, state_row, state_write_row, table_rows_in_use)
 
 ROOT = Path(__file__).resolve().parents[1]
 CONF = json.loads((ROOT / "benchmark/tests/rehearsal/configs/tiny-ssm.json").read_text())
@@ -228,13 +228,58 @@ def test_the_state_kernel_is_the_xla_step_and_writes_one_layer_in_place():
     decay = jax.nn.sigmoid(jax.random.normal(ks[1], (slots, h)))
     dtx = jax.random.normal(ks[2], (slots, h, p))
     bm, cm = jax.random.normal(ks[3], (slots, n)), jax.random.normal(ks[4], (slots, n))
-    got, y = ssm_scan.ssm_state_step_auto(pool, 1, decay, dtx, bm, cm)
+    every = ssm_scan.live_slots(jnp.ones((slots,), bool))
+    got, y = ssm_scan.ssm_state_step_auto(pool, 1, every, decay, dtx, bm, cm)
     want, y_want = state_step_xla(pool, 1, decay, dtx, bm, cm)
     np.testing.assert_allclose(got, want, atol=1e-5)
     np.testing.assert_allclose(y, y_want, atol=1e-4)
     np.testing.assert_array_equal(got[:, 0], pool[:, 0])  # the other layer untouched
     s = jax.random.normal(ks[0], (2, h, p, n))
     np.testing.assert_array_equal(ssm_scan.unpack_state(ssm_scan.pack_state(s, k), k), s)
+
+
+# one jitted step for every case below: the list is an argument
+_STATE_STEP = jax.jit(ssm_scan.ssm_state_step_auto)
+LIVE_SETS = {"none": [], "slot 0 only": [0], "the last slot only": [5],
+             "every other slot": [0, 2, 4], "all but one": [0, 1, 2, 4, 5],
+             "all": [0, 1, 2, 3, 4, 5]}
+
+
+@pytest.mark.parametrize("name", list(LIVE_SETS))
+def test_the_state_kernel_moves_the_listed_slots_and_no_other(name):
+    """The list is data: a listed slot is the XLA step, a slot that is not
+    listed keeps its state bit for bit and gives zeros, whatever its row of
+    the operands holds (NaN here). The first listed row replays a position
+    (decay 1, dt x 0): it reads C . S and keeps its state. Every live set
+    runs the one compiled program."""
+    slots, layers, h, p, n = 6, 2, 8, 16, 16
+    live = LIVE_SETS[name]
+    dead = [i for i in range(slots) if i not in live]
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    pool = jax.random.normal(ks[0], (slots, layers) + ssm_scan.state_plane(h, p, n))
+    decay = jax.nn.sigmoid(jax.random.normal(ks[1], (slots, h)))
+    dtx = jax.random.normal(ks[2], (slots, h, p))
+    bm, cm = jax.random.normal(ks[3], (slots, n)), jax.random.normal(ks[4], (slots, n))
+    if live:
+        decay, dtx = decay.at[live[0]].set(1.0), dtx.at[live[0]].set(0.0)
+    want, y_want = state_step_xla(pool, 1, decay, dtx, bm, cm)
+    nan = jnp.asarray(dead, jnp.int32)
+    decay, dtx, bm, cm = (z.at[nan].set(jnp.nan) for z in (decay, dtx, bm, cm))
+    listed = ssm_scan.live_slots(jnp.zeros((slots,), bool).at[jnp.asarray(live, jnp.int32)].set(True))
+    assert int(listed.n) == len(live)
+    assert listed.order.tolist() == live + [live[-1] if live else 0] * len(dead)
+    got, y = _STATE_STEP(pool, 1, listed, decay, dtx, bm, cm)
+    assert _STATE_STEP._cache_size() == 1
+    for i in live:
+        np.testing.assert_allclose(got[i], want[i], atol=1e-5)
+        np.testing.assert_allclose(y[i], y_want[i], atol=1e-4)
+    for i in dead:
+        np.testing.assert_array_equal(got[i], pool[i])
+        np.testing.assert_array_equal(y[i], np.zeros((h, p), np.float32))
+    if live:
+        np.testing.assert_array_equal(got[live[0]], pool[live[0]])
+        assert float(jnp.abs(y[live[0]]).max()) > 0.1
+    np.testing.assert_array_equal(got[:, 0], pool[:, 0])  # the other layer untouched
 
 
 def test_a_group_admit_of_prompts_of_unequal_length_is_each_alone(model):
@@ -436,6 +481,97 @@ async def test_two_slots_finish_and_refill_at_different_steps_through_the_live_b
 
 
 @async_test
+async def test_a_finished_and_a_reserved_slot_keep_their_state_across_a_burst(model):
+    """Three slots. A decodes throughout; B and E finish early and leave their
+    last state in slots 1 and 2; C, a prompt of three chunks, then reserves
+    slot 1 while A's bursts go on between its chunks. Every decode launch is
+    held to: the rows of its table that name a block are the slots that hold
+    a decoding request (the device lists by that rule, the host counts by it),
+    and the state, tail and ``seen`` of every other slot come out bit for bit
+    as they went in. C then decodes in the freed slot as the reference does."""
+    import time
+
+    from nats_llm_studio_tpu.engine.generator import SamplingParams
+    from nats_llm_studio_tpu.obs import spans
+    from nats_llm_studio_tpu.serve import batcher as bt
+
+    cfg, params = model
+    pa, pb, pe, pc = tokens(60, 9), tokens(61, 11), tokens(62, 10), tokens(63, 40)
+    b = bt.ContinuousBatcher(params, cfg, max_slots=3, max_seq_len=SEQ, buckets=[16, 32, 64],
+                             prefill_chunk=16)
+    # (what each slot held, its rows before, its rows after, traces so far)
+    launches = []
+    traced = []     # the burst program's traces
+    held = {"released": False}
+
+    def rows_of(kp, vp):
+        return [[np.array(leaf, copy=True) for leaf in state_row(kp, i) + state_row(vp, i)]
+                for i in range(3)]
+
+    def watched(fn):
+        def run(*args, **kwargs):
+            slots = list(b._slots)
+            if not held["released"] and slots[1] is None and slots[2] is None:
+                end = time.monotonic() + 30.0   # C arrives while A still decodes
+                while b._inbox.qsize() == 0 and time.monotonic() < end:
+                    time.sleep(0.001)
+                held["released"] = True
+            kinds = ["live" if isinstance(r, bt._Request) else
+                     "reserved" if r is bt._RESERVED else "empty" for r in slots]
+            assert table_rows_in_use(np.asarray(args[4])).tolist() == [k == "live" for k in kinds]
+            before = rows_of(args[2], args[3])   # the pools are donated: copies
+            out = fn(*args, **kwargs)
+            launches.append((kinds, before, rows_of(out[1], out[2]), len(traced)))
+            return out
+        return run
+
+    b._decode_pallas = watched(b._decode_pallas)
+
+    def on_duration(event, seconds, **kw):
+        if event.endswith("jaxpr_trace_duration") and kw.get("fun_name") == "decode_pos_pallas":
+            traced.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    t0 = time.perf_counter()
+    try:
+        async def one(p, m):
+            return [t async for t in b.submit(p, SamplingParams(temperature=0.0, max_tokens=m))]
+
+        ta = asyncio.ensure_future(one(pa, 70))
+        got_b, got_e = await asyncio.gather(one(pb, 3), one(pe, 4))
+        got_c = await one(pc, 6)
+        got_a = await ta
+        for p, toks, m in ((pa, got_a, 70), (pb, got_b, 3), (pe, got_e, 4), (pc, got_c, 6)):
+            assert len(toks) == m
+            _held_to_the_reference(params, p, toks)
+        kept = {"reserved": 0, "empty": 0}
+        for kinds, before, after, _ in launches:
+            for i, kind in enumerate(kinds):
+                if kind != "live":
+                    for x, y in zip(before[i], after[i]):
+                        np.testing.assert_array_equal(x, y)
+                    # a state some request left there, not the pool's zeros
+                    kept[kind] += bool(np.any(before[i][-1] != 0))
+        sets = [tuple(k) for k, *_ in launches]
+        assert kept["reserved"] >= 1 and kept["empty"] >= 1, sets
+        # one program for every live set: the first two launches (all three
+        # slots live; their position carries uploaded, then carried) build
+        # what there is to build, and no other live set builds anything
+        assert sets[0] == sets[1] and len(set(sets)) >= 4
+        assert len(traced) == launches[1][3] <= 2, [n for *_, n in launches]
+        burst = [a for _, _, _, a in spans.records(t0, float("inf"), "batcher.readback")
+                 if a and "state_steps" in a]
+        assert len(burst) == len(launches)
+        assert all(a["state_slots_moved"] == a["state_rows"] for a in burst)
+        assert any(a["state_slots_moved"] < 3 * a["state_steps"] for a in burst)
+        st = b.stats.state_counters()
+        assert 0 < st["state_slots_moved"] == st["state_rows"] < 3 * st["state_steps"]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+        b.stop()
+
+
+@async_test
 async def test_a_request_with_logprobs_replays_its_last_prompt_position(model, prompt):
     """The ext path: the admit's token is dropped and the last prompt position
     decoded again under the mask; the state must not consume it twice."""
@@ -578,9 +714,14 @@ def test_the_refusals_and_the_state_pool_are_on_the_metrics_page():
     from nats_llm_studio_tpu.serve.batcher import BatcherStats
 
     st = BatcherStats()
-    assert st.record_state(27, 8) == {"state_rows": 216, "state_steps": 8}
-    assert st.record_state(3, 1) == {"state_rows": 3, "state_steps": 1}
-    assert st.state_counters()["state_rows"] == 219 and st.state_counters()["state_steps"] == 9
+    assert st.record_state(24, 8, 27) == {"state_rows": 192, "state_steps": 8,
+                                          "state_slots_moved": 216}
+    assert st.record_state(3, 1, 3) == {"state_rows": 3, "state_steps": 1, "state_slots_moved": 3}
+    counters = st.state_counters()
+    assert counters["state_rows"] == 195 and counters["state_steps"] == 9
+    assert counters["state_slots_moved"] == 219
+    # the page shows every key of state_counters() as lmstudio_ssm_<key>_total
+    assert "state_slots_moved" in (ROOT / "README.md").read_text()
     text = (ROOT / "nats_llm_studio_tpu/serve/worker.py").read_text()
     for name in ("lmstudio_ssm_{name}_total", "lmstudio_ssm_state_pool_bytes",
                  "lmstudio_feature_refused"):

@@ -356,9 +356,10 @@ def _ssm_pools(cfg, sharding):
 
 
 def test_ssm_state_step_at_the_benchmark_cells_shapes(one_chip, no_cache, ssm_cell):
-    """One layer's step over the whole state pool [32, 36, 32, 128, 128] f32
-    (2.4 GB): Mosaic tiles it, and the pool is the result's own buffer (the
-    alias holds: no second pool among the temporaries)."""
+    """One layer's step over the state pool [32, 36, 32, 128, 128] f32
+    (2.4 GB), the live slots a traced mask: Mosaic tiles it, the list rides
+    in as scalars, and the pool is the result's own buffer (the alias holds:
+    no second pool among the temporaries)."""
     from nats_llm_studio_tpu.ops import ssm_scan
 
     cfg, _ = ssm_cell
@@ -367,10 +368,11 @@ def test_ssm_state_step_at_the_benchmark_cells_shapes(one_chip, no_cache, ssm_ce
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
     h, p, n = cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_d_state
     compiled = jax.jit(
-        lambda pool, layer, decay, dtx, bm, cm: ssm_scan.ssm_state_step(
-            pool, layer, decay, dtx, bm, cm),
+        lambda pool, layer, mask, decay, dtx, bm, cm: ssm_scan.ssm_state_step(
+            pool, layer, ssm_scan.live_slots(mask), decay, dtx, bm, cm),
         donate_argnums=(0,)).lower(
         pool, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((SSM_SLOTS,), jnp.bool_, sharding=one_chip),
         f32(SSM_SLOTS, h), f32(SSM_SLOTS, h, p), f32(SSM_SLOTS, n), f32(SSM_SLOTS, n)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ssm_state_step" in text
@@ -379,38 +381,44 @@ def test_ssm_state_step_at_the_benchmark_cells_shapes(one_chip, no_cache, ssm_ce
     assert ma.alias_size_in_bytes >= pool_bytes and ma.temp_size_in_bytes < pool_bytes // 8
 
 
-def test_a_decode_burst_of_the_state_space_family_copies_no_pool(one_chip, no_cache, ssm_cell):
-    """Four steps of all 40 layers over the cell's pools, donated: one scan
-    over the 4 periods and one over each run of layers inside it (compiled
-    in seconds, not 40 unrolled layers), both kernels in it, the pools
-    aliased onto the results, and no ``copy`` of the float32 state pool, of
-    the convolution tails or of a KV pool anywhere in the program."""
-    from nats_llm_studio_tpu.models import llama
+@pytest.mark.parametrize("program", ["decode_pallas", "decode_pallas_ext"],
+                         ids=["the burst", "the single step"])
+def test_a_decode_launch_of_the_state_space_family_copies_no_pool(one_chip, no_cache, ssm_cell,
+                                                                  program):
+    """The family's two decode programs as ``serve/programs.py`` builds them
+    (eight steps and their sampling; one masked step with its
+    log-probabilities), all 40 layers over the cell's pools, donated: one
+    scan over the 4 periods and one over each run of layers inside it
+    (compiled in seconds, not 40 unrolled layers), both kernels in it under
+    their names, the pools aliased onto the results, and no ``copy`` of the
+    float32 state pool, of the convolution tails or of a KV pool anywhere in
+    the program. The live slots are listed from the block table, an argument
+    of the program like the positions: launches with other slots live, or
+    none, are this one program."""
+    from nats_llm_studio_tpu.engine.sampling import sample_rows
+    from nats_llm_studio_tpu.serve.programs import build_programs
 
     cfg, shapes = ssm_cell
     kp, vp = _ssm_pools(cfg, one_chip)
     sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
-    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
-
-    def burst(params, tok, kp, vp, tbl, pos):
-        def step(c, i):
-            tok, kp, vp = c
-            logits, kp, vp = llama.forward_decode_paged(
-                params, cfg, tok[:, None], kp, vp, tbl, pos + i)
-            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            return (nxt, kp, vp), nxt
-
-        (_, kp, vp), toks = jax.lax.scan(step, (tok, kp, vp), jnp.arange(4, dtype=jnp.int32))
-        return toks, kp, vp
-
+    row = lambda dt, *more: jax.ShapeDtypeStruct(  # noqa: E731
+        (SSM_SLOTS,) + more, dt, sharding=one_chip)
+    ints, floats = row(jnp.int32), row(jnp.float32)
+    table = build_programs(cfg, None, max_seq=SEQ, paged=True, kv_block_tokens=T,
+                           sample_rows=sample_rows)
+    # (params, tok, KP, VP, tbl, pos, seeds, steps, temp, topk, topp, n | mask)
+    last = 8 if program == "decode_pallas" else row(jnp.bool_, cfg.vocab_size)
     orig = jax.default_backend
     jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
     try:
-        compiled = jax.jit(burst, donate_argnums=(2, 3)).lower(
-            jax.tree.map(sds, shapes), ints(SSM_SLOTS), kp, vp,
-            ints(SSM_SLOTS, SEQ // T), ints(SSM_SLOTS)).compile()
+        lowered = table[program].lower(
+            jax.tree.map(sds, shapes), ints, kp, vp, row(jnp.int32, SEQ // T), ints, ints, ints,
+            floats, ints, floats, last)
+        compiled = lowered.compile()
     finally:
         jax.default_backend = orig
+    tbl = lowered.args_info[0][4]   # traced, not static: the list is data
+    assert tbl.shape == (SSM_SLOTS, SEQ // T) and tbl.dtype == jnp.int32
     text = compiled.as_text()
     assert "ssm_state_step" in text and "paged_decode_attention" in text
     state, kv, tails = vp.st[0], kp.kv, kp.st[0]
@@ -423,3 +431,24 @@ def test_a_decode_burst_of_the_state_space_family_copies_no_pool(one_chip, no_ca
     state_bytes = int(np.prod(state.shape)) * 4
     assert ma.alias_size_in_bytes >= state_bytes + 2 * int(np.prod(kv.shape)) * 2
     assert ma.temp_size_in_bytes < state_bytes // 8
+
+
+def test_the_live_list_is_the_state_space_familys_alone():
+    """The list is made from the block table inside ``models/ssm_hybrid.py``:
+    the program table hands every family's decode forward the arguments it
+    handed it before, and knows of no list. So the lowered text of a
+    Granite-3.1 and of a Xing decode burst, and with it their compile-cache
+    keys, are the parent's (compared by hash on this change's tree, PERF.md
+    PR 36: a check that needs the parent's checkout)."""
+    import inspect
+
+    from nats_llm_studio_tpu.models import llama, mla_moe, ssm_hybrid
+    from nats_llm_studio_tpu.serve import programs
+
+    names = lambda fn: list(inspect.signature(fn).parameters)  # noqa: E731
+    family = ["params", "cfg", "tokens", "k_pool", "v_pool", "tbl", "start_pos", "mesh"]
+    assert names(llama.forward_decode_paged) == family + ["moe_stats"]
+    assert names(mla_moe.forward_decode_paged) == family
+    assert names(ssm_hybrid.forward_decode_paged) == family
+    src = inspect.getsource(programs)
+    assert "ssm_scan" not in src and "live_slots" not in src and "table_rows_in_use" not in src
