@@ -22,6 +22,15 @@ each refuses", has the causes each refusal gives):
   one chip, with a per-slot state pool beside it; the prefix cache,
   speculation, int8 KV, the KV tiers, KVX1 export and a real GGUF's tensors
   are refused.
+* ``attention.sliding_window`` present with ``attention.head_count`` a list
+  (one entry a layer): window-attention layers beside full-attention layers
+  with their own head count and rotary table, a sigmoid gate a head on the
+  attention output, a leading dense layer then sigmoid-routed experts beside
+  a shared one (``laguna``): ``models/swa_moe.py``. Served on the paged pool
+  of one chip: the pool holds the full layers' KV, a per-slot ring of
+  ``window`` tokens beside it the window layers'; the prefix cache,
+  speculation, int8 KV, the KV tiers, KVX1 export, a mesh and a real GGUF's
+  tensors are refused.
 * ``gemma2``, ``gemma3``, ``qwen2moe``: rejected here (post-norms,
   soft-capping, a softmax-gated shared expert).
 """
@@ -126,6 +135,22 @@ class ModelConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 256
     use_rope: bool = True
+    # -- window attention beside full attention (models/swa_moe.py) ----------
+    # layer_types names every layer "full" or "window". A window layer sees
+    # the last ``window`` keys, its own among them, and keeps them in a ring a
+    # slot beside the pool, so only the full layers hold paged KV; it has its
+    # own head count (n_heads is the full layers') and its own plain rotary
+    # table over ``win_rope_dim`` dims of a head. A full layer rotates the
+    # first ``rope_dim`` dims (0 = all) with the YaRN fields above, cos and sin
+    # times ``rope_attn_factor``. attn_gate: a sigmoid gate a head, from the
+    # layer's normed input, on the attention output.
+    window: int = 0
+    win_n_heads: int = 0
+    win_rope_theta: float = 10000.0
+    win_rope_dim: int = 0
+    rope_dim: int = 0
+    rope_attn_factor: float = 1.0
+    attn_gate: bool = False
 
     @property
     def is_mla(self) -> bool:
@@ -136,9 +161,21 @@ class ModelConfig:
         return sum(t == "mamba" for t in self.layer_types)
 
     @property
+    def n_win_layers(self) -> int:
+        return sum(t == "window" for t in self.layer_types)
+
+    @property
     def n_kv_layers(self) -> int:
-        """Layers that hold KV: the pool's layer axis."""
-        return self.n_layers - self.n_ssm_layers
+        """Layers that hold paged KV: the pool's layer axis (a state-space
+        layer keeps a state, a window layer a ring, both by slot)."""
+        return self.n_layers - self.n_ssm_layers - self.n_win_layers
+
+    @property
+    def slot_state(self) -> bool:
+        """Whether a slot keeps something beside its KV blocks that no block
+        table describes (``ops.kvcache.WithState``): a recurrent state, or
+        the window layers' ring."""
+        return bool(self.n_ssm_layers or self.n_win_layers)
 
     @property
     def ssm_d_inner(self) -> int:
@@ -164,13 +201,17 @@ class ModelConfig:
         """The model file that runs this configuration (models/<family>.py)."""
         if self.n_ssm_layers:
             return "ssm_hybrid"
+        if self.n_win_layers:
+            return "swa_moe"
         return "mla_moe" if self.is_mla else "llama"
 
     @property
     def n_moe_layers(self) -> int:
-        """Layers whose FFN is the routed-expert form (MLA family only: the
-        Mixtral family routes in every layer and keeps no dense stack)."""
-        return self.n_layers - self.n_dense_layers if self.is_mla and self.is_moe else 0
+        """Layers whose FFN is the routed-expert form of ``models/experts.py``
+        (the latent-attention and the window-attention families: the Mixtral
+        family routes in every layer and keeps no dense stack)."""
+        routed = (self.is_mla or self.n_win_layers) and self.is_moe
+        return self.n_layers - self.n_dense_layers if routed else 0
 
     @property
     def rope_mscale_sq(self) -> float:
@@ -217,7 +258,12 @@ class ModelConfig:
         def g(key: str, default: Any = None) -> Any:
             return md.get(f"{arch}.{key}", default)
 
-        n_heads = int(g("attention.head_count", 32))
+        heads = g("attention.head_count", 32)
+        if hasattr(heads, "tolist"):
+            heads = heads.tolist()
+        # a list: one entry a layer (window layers have their own count)
+        heads_by_layer = [int(h) for h in heads] if isinstance(heads, (list, tuple)) else None
+        n_heads = heads_by_layer[0] if heads_by_layer else int(heads)
         kv_heads = g("attention.head_count_kv", n_heads)
         if hasattr(kv_heads, "tolist"):
             kv_heads = kv_heads.tolist()
@@ -319,6 +365,30 @@ class ModelConfig:
                 ssm_conv=int(g("ssm.conv_kernel", 4)),
                 ssm_chunk=int(g("ssm.chunk_size", 256)),
                 use_rope=bool(g("rope.scaling.finetuned", False)),
+            )
+        if g("attention.sliding_window") is not None and heads_by_layer:
+            # window layers beside full layers, experts after a leading dense
+            # layer: the keys models/export.config_metadata writes
+            is_win = [bool(x) for x in g("attention.sliding_window_pattern")]
+            family |= dict(
+                layer_types=tuple("window" if w else "full" for w in is_win),
+                n_heads=next(h for h, w in zip(heads_by_layer, is_win) if not w),
+                win_n_heads=next(h for h, w in zip(heads_by_layer, is_win) if w),
+                window=int(g("attention.sliding_window")),
+                win_rope_theta=float(g("rope.freq_base_swa", 10000.0)),
+                win_rope_dim=int(g("rope.dimension_count_swa", 0)),
+                rope_dim=int(g("rope.dimension_count", 0)),
+                rope_factor=float(g("rope.scaling.factor", 1.0)),
+                rope_orig_ctx=int(g("rope.scaling.original_context_length", 0)),
+                rope_beta_fast=float(g("rope.scaling.yarn_beta_fast", 32.0)),
+                rope_beta_slow=float(g("rope.scaling.yarn_beta_slow", 1.0)),
+                rope_attn_factor=float(g("rope.scaling.attn_factor", 1.0)),
+                attn_gate=bool(g("attention.output_gate", False)),
+                moe_d_ff=int(g("expert_feed_forward_length", 0)),
+                n_shared_experts=int(g("expert_shared_count", 0)),
+                n_dense_layers=int(g("leading_dense_block_count", 0)),
+                router_scoring="sigmoid" if int(g("expert_gating_func", 1)) == 2 else "softmax",
+                routed_scaling=float(g("expert_weights_scale", 1.0)),
             )
         kwargs.update(family)  # family quirks win over absent metadata keys
         return cls(**kwargs)

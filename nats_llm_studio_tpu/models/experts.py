@@ -1,0 +1,140 @@
+"""The routed-expert layer two model files share: sigmoid-scored experts with
+a selection bias beside always-on shared ones, dropless (``models/mla_moe.py``
+with latent attention, ``models/swa_moe.py`` with window attention).
+
+A stack of such layers holds ``router`` [L, d, E], ``e_bias`` [L, E], the
+expert stacks ``w_gate_e`` / ``w_up_e`` [L, E, d, f], ``w_down_e`` [L, E, f,
+d] and the shared expert's ``w_gate_s`` / ``w_up_s`` / ``w_down_s``.
+``expert_path`` names the form a call takes from its shapes and leaf types
+alone; ``moe_ffn`` computes it (``ops/moe_experts.py`` has the two kernels).
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.layers import swiglu
+from ..ops.wquant import q_einsum
+from .config import ModelConfig
+
+Params = dict[str, Any]
+
+_HI = jax.lax.Precision.HIGHEST
+# what the [rows, experts, width] activations of one group of experts in the
+# dense dispatch may take: a prefill of many rows computes its experts in
+# several groups, a decode step in one
+_EXPERT_ACT_BYTES = 256 << 20
+
+
+def route(h: jax.Array, p: Params, cfg: ModelConfig):
+    """(idx [B, T, k], gate [B, T, k] f32): the k experts with the largest
+    sigmoid score + selection bias, gated by their normalised scores times
+    ``routed_scaling`` (the bias picks, it does not weigh)."""
+    logits = jnp.einsum("btd,de->bte", h.astype(jnp.float32),
+                        p["router"].astype(jnp.float32), precision=_HI)
+    score = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(score + p["e_bias"].astype(jnp.float32), cfg.n_experts_used)
+    chosen = jnp.take_along_axis(score, idx, axis=-1)
+    gate = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling
+    return idx, gate
+
+
+EXPERT_LEAVES = ("w_gate_e", "w_up_e", "w_down_e")
+
+
+def expert_path(cfg: ModelConfig, rows: int, stack: Params, mesh=None) -> str:
+    """``"hit_list"``, ``"grouped"`` or ``"dense"``: the form the routed
+    experts of a call of ``rows`` rows take, from what the call can see and
+    nothing else. With plain expert leaves on one device: the hit list where
+    the (row, expert) picks are fewer than the layer's experts (a decode step
+    of 8 rows x top-4 under 64: some experts are certainly not hit, and
+    reading is all a few rows cost), the grouped form from there up (a
+    prefill chunk, a chunk group, a verify bundle: the picks sorted by expert
+    and each computed on its own expert only). There is no row count under
+    which dense dispatch is the better of the two: it reads all 64 experts
+    whatever was picked, 2.0-2.1 ms a layer on a v5e, where the grouped form
+    reads and computes what was picked (PERF.md, PR 32: the layer alone at
+    16-1,024 rows). Quantised stacks (``WQUANT`` makes ``w_*_e`` QTensors)
+    and meshes of more than one chip keep the dense dispatch until a cell
+    measures them."""
+    plain = all(isinstance(stack[k], jax.Array) for k in EXPERT_LEAVES)
+    one_device = mesh is None or mesh.size == 1
+    if not (plain and one_device):
+        return "dense"
+    return "hit_list" if rows * cfg.n_experts_used < cfg.n_experts else "grouped"
+
+
+def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = None,
+            form: str = "dense", stacks=None, place=None):
+    """Routed experts + the shared expert(s), dropless, in the ``form`` that
+    ``expert_path`` named. Returns (y, stats): ``stats`` is int32 [3] =
+    (distinct experts the ``live`` rows hit, most rows on one expert, live
+    rows) when ``live`` [B] is given, else None.
+
+    Every form is the same sum: each row's k picked experts, weighted by
+    their gates, in float32 on top of the shared expert's output. The hit
+    list and the grouped form take ``stacks``, the three WHOLE expert stacks
+    [L, E, ., .], and ``place``, this layer's place in them
+    (``ops/moe_experts.py``).
+
+    **Hit list**: the experts the live rows hit are listed on the device and
+    only those are read, each once, every row gated by its own weight on that
+    expert (0 for a row that did not pick it): the dense dispatch's sums
+    without the experts whose terms are all zero. A row of a slot that holds
+    no request adds nothing to the list; its output is whatever the listed
+    experts give it, and the batcher discards it.
+
+    **Grouped**: the rows x k (row, pick) pairs are sorted by expert, each
+    pair's row is multiplied by its own expert's matrices only, scaled by
+    its gate, and a row's k results are gathered back and summed: the dense
+    dispatch's sums without ANY term that is zero, rows x k products where
+    it makes rows x E. Every row is computed, live or not.
+
+    **Dense dispatch** (``p`` holds the layer's own expert leaves): every
+    expert computes every row, in groups of experts that are STATIC slices of
+    the layer's stacks: a slice of a scan's slice still fuses into the
+    product that reads it, where an inner ``lax.scan`` over the groups made
+    XLA copy a layer's 1.4 GB of experts into the loop's operand every step
+    (46 ms a decode step for 15: PERF.md, PR 29). A prefill splits so that
+    [rows, group, width] stays under ``_EXPERT_ACT_BYTES``."""
+    e, k = cfg.n_experts, cfg.n_experts_used
+    idx, gate = route(h, p, cfg)
+    picked = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # [B, T, k, E]
+    combine = jnp.sum(picked * gate[..., None], axis=-2)  # [B, T, E] f32
+    on = jnp.sum(picked, axis=-2)  # [B, T, E]: 1 where a row picked the expert
+    rows_on = jnp.sum(on if live is None else on * live[:, None, None], axis=(0, 1))
+    stats = None if live is None else jnp.stack(
+        [jnp.sum(rows_on > 0), jnp.max(rows_on), jnp.sum(live) * h.shape[1]]).astype(jnp.int32)
+    rows = h.shape[0] * h.shape[1]
+    acc = swiglu(h, p["w_gate_s"], p["w_up_s"], p["w_down_s"], cfg.mlp_act).astype(jnp.float32)
+    if form != "dense":
+        from ..ops import moe_experts
+    if form == "hit_list":
+        ids, n_hit = moe_experts.hit_list(rows_on, min(e, rows * k))
+        gates = jnp.take(combine.reshape(rows, e).T, ids, axis=0)  # [places, rows]
+        acc = moe_experts.moe_hit_experts_auto(
+            h.reshape(rows, -1), gates, ids, n_hit, place, *stacks,
+            acc.reshape(rows, -1)).reshape(acc.shape)
+        return acc.astype(h.dtype), stats
+    if form == "grouped":
+        order, at = moe_experts.sort_by_expert(idx.reshape(rows, k))
+        y = moe_experts.moe_grouped_experts_auto(
+            jnp.take(h.reshape(rows, -1), order // k, axis=0), gate.reshape(-1)[order],
+            jnp.sum(on, axis=(0, 1)), place, *stacks)  # [rows x k, d] f32, sorted
+        acc = acc + jnp.sum(y[at], axis=1).reshape(acc.shape)
+        return acc.astype(h.dtype), stats
+    combine = combine.astype(h.dtype)
+    act_bytes = rows * e * cfg.moe_d_ff * h.dtype.itemsize
+    groups = next(g for g in range(1, e + 1)
+                  if e % g == 0 and act_bytes // g <= _EXPERT_ACT_BYTES or g == e)
+    size = e // groups
+    for g in range(groups):
+        wg, wu, wd = (jax.tree.map(lambda x: x[g * size: (g + 1) * size], p[k_])
+                      for k_ in EXPERT_LEAVES)
+        act = jax.nn.silu(q_einsum("btd,gdf->btgf", h, wg)) * q_einsum("btd,gdf->btgf", h, wu)
+        act = act * combine[..., g * size: (g + 1) * size, None]
+        acc = acc + q_einsum("btgf,gfd->btd", act, wd).astype(jnp.float32)
+    return acc.astype(h.dtype), stats

@@ -96,6 +96,32 @@ def config_metadata(cfg: ModelConfig, name: str) -> dict[str, Any]:
             f"{a}.ssm.chunk_size": cfg.ssm_chunk,
             f"{a}.rope.scaling.finetuned": cfg.use_rope,
         }
+    if cfg.n_win_layers:
+        # window layers beside full layers: llama.cpp's keys where it has one
+        # (a head count a layer, sliding_window and its pattern, freq_base_swa,
+        # the yarn set), ours for the gate and the window layers' rotary dims
+        a = cfg.arch
+        win = [int(t == "window") for t in cfg.layer_types]
+        md |= {
+            f"{a}.attention.head_count": [cfg.win_n_heads if w else cfg.n_heads for w in win],
+            f"{a}.attention.sliding_window": cfg.window,
+            f"{a}.attention.sliding_window_pattern": win,
+            f"{a}.attention.output_gate": cfg.attn_gate,
+            f"{a}.rope.dimension_count": cfg.rope_dim,
+            f"{a}.rope.dimension_count_swa": cfg.win_rope_dim,
+            f"{a}.rope.freq_base_swa": cfg.win_rope_theta,
+            f"{a}.rope.scaling.type": "yarn" if cfg.rope_factor > 1.0 else "none",
+            f"{a}.rope.scaling.factor": cfg.rope_factor,
+            f"{a}.rope.scaling.original_context_length": cfg.rope_orig_ctx,
+            f"{a}.rope.scaling.yarn_beta_fast": cfg.rope_beta_fast,
+            f"{a}.rope.scaling.yarn_beta_slow": cfg.rope_beta_slow,
+            f"{a}.rope.scaling.attn_factor": cfg.rope_attn_factor,
+            f"{a}.expert_feed_forward_length": cfg.moe_d_ff,
+            f"{a}.expert_shared_count": cfg.n_shared_experts,
+            f"{a}.leading_dense_block_count": cfg.n_dense_layers,
+            f"{a}.expert_gating_func": 2 if cfg.router_scoring == "sigmoid" else 1,
+            f"{a}.expert_weights_scale": cfg.routed_scaling,
+        }
     if cfg.arch in ("granite", "granitehybrid"):
         md[f"{cfg.arch}.embedding_scale"] = cfg.embedding_scale
         md[f"{cfg.arch}.residual_scale"] = cfg.residual_scale

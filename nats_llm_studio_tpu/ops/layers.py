@@ -7,8 +7,11 @@ batch-major so XLA tiles matmuls onto the MXU without relayout.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def rms_norm(
@@ -35,6 +38,29 @@ def rope_cos_sin(
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions.astype(jnp.float32)[..., None] * freqs
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float = 1.0, orig_ctx: int = 0,
+                     beta_fast: float = 32.0, beta_slow: float = 1.0) -> np.ndarray:
+    """Inverse frequencies [dim/2] of ``dim`` rotary dims. Factor 1 is plain
+    rope; otherwise YaRN blends the scaled and the unscaled frequency by a
+    ramp over the correction range of ``beta_fast`` / ``beta_slow``."""
+    i = np.arange(0, dim, 2, dtype=np.float64)
+    extra = 1.0 / (theta ** (i / dim))
+    if factor <= 1.0 or not orig_ctx:
+        return extra.astype(np.float32)
+    inter = extra / factor
+
+    def corr_dim(rotations: float) -> float:
+        return dim * math.log(orig_ctx / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    m = 1.0 - ramp
+    return (inter * (1 - m) + extra * m).astype(np.float32)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -31,6 +31,10 @@ one compiled program per width. Off-TPU the kernel runs in interpreter mode
 it is started, so only a chip run orders the double buffer);
 ``paged_decode_eligible`` gates the auto-downshift to the XLA path for
 shapes Mosaic cannot tile.
+
+At the end of the file: ``window_decode_attention``, the decode kernel of a
+window-attention layer over a slot's ring (no table, no walk), under a name
+of its own.
 """
 
 from __future__ import annotations
@@ -313,3 +317,110 @@ def paged_decode_attention_auto(q, k_pool, v_pool, tbl, pos, layer,
     interpret = jax.default_backend() != "tpu"
     return paged_decode_attention(q, k_pool, v_pool, tbl, pos, layer, scale,
                                   interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# window layers: decode over a slot's ring
+# ---------------------------------------------------------------------------
+#
+# A window-attention layer (models/swa_moe.py) sees the last R = ``window``
+# keys, its own among them. They live in a ring a slot, [Lw, slots, Hkv, R, D]
+# beside the pool: the key of position p at place p mod R, so a slot's keys
+# of a layer are ONE contiguous [Hkv, R, D] slab that no table describes, and
+# the key at place i belongs to position pos - ((pos - i) mod R): known from
+# ``pos`` alone, and in the window by construction (the write of position p
+# overwrites p - R). Only a position below 0 is masked.
+
+
+def window_decode_eligible(r: int, d: int, itemsize: int) -> bool:
+    """Whether the ring kernel can serve this layout on a real TPU: the ring
+    is the sublane dim of the K/V tile, the head the lane dim."""
+    return r % (8 if itemsize >= 4 else 16) == 0 and d % 128 == 0
+
+
+def _window_kernel(pos_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float, r: int):
+    """One grid step = one SLOT, every kv head inside it: the slot's whole
+    ring of the layer arrives as one block (the pipeline fetches slot b+1's
+    while slot b is attended to), a head's scores are ONE [rows, D] x [D, R]
+    product and the softmax is taken at once. A slot that holds no request
+    reads its ring as it lies and gives finite junk the caller discards."""
+    del layer_ref  # the ring's block index reads it
+    pos = pos_ref[pl.program_id(0)]
+    q = q_ref[0]  # [Hkv, rows, D]
+    rows = q.shape[1]
+    s = jax.lax.dot_general(
+        q, k_ref[0, 0], (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+    ) * scale  # [Hkv, rows, R] f32
+    # place i holds the position ``age`` behind pos: age = (pos - i) mod R,
+    # without a vector remainder (the place of pos itself is a scalar)
+    at = jax.lax.rem(pos, r)
+    place = jax.lax.broadcasted_iota(jnp.int32, (rows, r), 1)
+    age = jnp.where(place <= at, at - place, at - place + r)
+    s = jnp.where((age <= pos)[None], s, _NEG_INF)
+    m = jnp.max(s, axis=2, keepdims=True)
+    p = jnp.exp(s - m)
+    out = jax.lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[0, 0], (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) / jnp.sum(p, axis=2, keepdims=True)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def window_decode_attention(
+    q: jax.Array,       # [B, 1, Hq, D]: the query at position pos
+    k_ring: jax.Array,  # [Lw, B, Hkv, R, D]: row b is slot b's ring
+    v_ring: jax.Array,
+    pos: jax.Array,     # [B] int32
+    layer,              # int32 scalar: the layer's place among the window layers
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """Attention of one new token a slot over the slot's ring of the last R
+    keys (the caller has written the token's own key at place pos mod R:
+    write-then-attend, as on the pool). One compiled program for every
+    context length; its time is the rings', whatever the contexts.
+    Returns [B, 1, Hq, D] in q.dtype."""
+    b, w, hq, d = q.shape
+    if w != 1:
+        raise NotImplementedError("the ring kernel decodes one position a slot")
+    hkv, r = k_ring.shape[2], k_ring.shape[3]
+    group = hq // hkv
+    mult = 8 if q.dtype.itemsize >= 4 else 16
+    rows_p = -(-group // mult) * mult
+    qh = q.reshape(b, hkv, group, d)
+    if rows_p != group:
+        qh = jnp.pad(qh, ((0, 0), (0, 0), (0, rows_p - group), (0, 0)))
+
+    def q_map(bi, pos_ref, layer_ref):
+        return (bi, 0, 0, 0)
+
+    def ring_map(bi, pos_ref, layer_ref):
+        return (layer_ref[0], bi, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, hkv, rows_p, d), q_map),
+                  pl.BlockSpec((1, 1, hkv, r, d), ring_map),
+                  pl.BlockSpec((1, 1, hkv, r, d), ring_map)],
+        out_specs=pl.BlockSpec((1, hkv, rows_p, d), q_map),
+    )
+    out = pl.pallas_call(
+        functools.partial(_window_kernel, scale=scale, r=r),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows_p, d), q.dtype),
+        interpret=interpret,
+        # its own constant name: a device trace tells the window layers'
+        # calls from the full layers' (paged_decode_attention) by it
+        name="window_decode_attention",
+    )(
+        jnp.asarray(pos, jnp.int32).reshape(b),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        qh, k_ring, v_ring,
+    )
+    return out[:, :, :group].reshape(b, 1, hq, d)
+
+
+def window_decode_attention_auto(q, k_ring, v_ring, pos, layer, scale: float) -> jax.Array:
+    interpret = jax.default_backend() != "tpu"
+    return window_decode_attention(q, k_ring, v_ring, pos, layer, scale, interpret=interpret)

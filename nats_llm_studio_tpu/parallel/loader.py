@@ -83,6 +83,12 @@ def load_params_sharded(
             f"{cfg.arch}: no GGUF tensor-name map for state-space models yet; "
             "the tree to build is models.ssm_hybrid.init_params' (two stacks, "
             "blocks.mamba and blocks.attn), placed by param_sharding_rules")
+    if cfg.n_win_layers:
+        raise NotImplementedError(
+            f"{cfg.arch}: no GGUF tensor-name map for window-attention models "
+            "yet; the tree to build is models.swa_moe.init_params' (attention "
+            "leaves in blocks.full and blocks.win, MLP leaves in blocks.dense "
+            "and blocks.moe), placed by param_sharding_rules")
     rules = param_sharding_rules(mesh, cfg)
 
     def t(name: str) -> np.ndarray:

@@ -31,6 +31,8 @@ def _leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
         return _mla_leaves(cfg, dtype_bytes)
     if cfg.n_ssm_layers:
         return _ssm_leaves(cfg, dtype_bytes)
+    if cfg.n_win_layers:
+        return _swa_leaves(cfg, dtype_bytes)
     out: dict[str, _Leaf] = {
         "embed": _Leaf((V, d), (), dtype_bytes),
         "out_norm": _Leaf((d,), (), dtype_bytes),
@@ -118,15 +120,48 @@ def _ssm_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
     return out
 
 
-def state_slot_bytes(cfg: ModelConfig) -> int:
-    """Device bytes of ONE slot of the recurrent-state pool beside the KV
-    pool (0 for a family that keeps none): what admission prices a slot at
-    whatever its context, where KV is priced by the block."""
-    if not cfg.n_ssm_layers:
-        return 0
-    from ..models.ssm_hybrid import state_bytes_per_slot
+def _swa_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
+    """The leaves of ``models.swa_moe.init_params``, unsharded (the family
+    serves on one chip a replica); norms and the selection bias are left out."""
+    from ..ops.wquant import quantizable
 
-    return state_bytes_per_slot(cfg)
+    d, V, hd, hkv = cfg.d_model, cfg.vocab_size, cfg.head_dim, cfg.n_kv_heads
+    e, fe, fs = cfg.n_experts, cfg.moe_d_ff, cfg.n_shared_experts * cfg.moe_d_ff
+
+    def attn(heads: int) -> dict:
+        return {"wq": (d, heads * hd), "wk": (d, hkv * hd), "wv": (d, hkv * hd),
+                "wo": (heads * hd, d)} | ({"wg": (d, heads)} if cfg.attn_gate else {})
+
+    stacks = (("full", cfg.n_kv_layers, attn(cfg.n_heads)),
+              ("win", cfg.n_win_layers, attn(cfg.win_n_heads)),
+              ("dense", cfg.n_dense_layers, {"w_gate": (d, cfg.d_ff), "w_up": (d, cfg.d_ff),
+                                             "w_down": (cfg.d_ff, d)}),
+              ("moe", cfg.n_moe_layers, {
+                  "router": (d, e), "w_gate_e": (e, d, fe), "w_up_e": (e, d, fe),
+                  "w_down_e": (e, fe, d), "w_gate_s": (d, fs), "w_up_s": (d, fs),
+                  "w_down_s": (fs, d)}))
+    out = {"embed": _Leaf((V, d), (), dtype_bytes),
+           "lm_head": _Leaf((d, V), (), dtype_bytes, True)}
+    for name, L, leaves in stacks:
+        for k, shape in leaves.items() if L else ():
+            out[f"blocks.{name}.{k}"] = _Leaf((L,) + shape, (), dtype_bytes, quantizable(k))
+    return out
+
+
+def state_slot_bytes(cfg: ModelConfig) -> int:
+    """Device bytes of what ONE slot keeps beside its KV blocks (0 for a
+    family that keeps nothing there): a state-space family's recurrent
+    state, a window-attention family's rings. It is what admission prices a
+    slot at whatever its context, where the pool's KV is priced by the block."""
+    if cfg.n_ssm_layers:
+        from ..models.ssm_hybrid import state_bytes_per_slot
+
+        return state_bytes_per_slot(cfg)
+    if cfg.n_win_layers:
+        from ..models.swa_moe import ring_bytes_per_slot
+
+        return ring_bytes_per_slot(cfg)
+    return 0
 
 
 def kv_token_values(cfg: ModelConfig) -> int:
@@ -195,7 +230,7 @@ def estimate_device_bytes(
 
     cb = cache_dtype_bytes or dtype_bytes
     kv = cfg.n_kv_layers * batch * seq * kv_token_values(cfg) * cb
-    kv += batch * state_slot_bytes(cfg)  # a slot's recurrent state, whole
+    kv += batch * state_slot_bytes(cfg)  # a slot's state or rings, whole
     # dp is served as independent batcher REPLICAS over disjoint device
     # slices (mesh.dp_submeshes): each replica holds its own full-``batch``
     # cache, so per-DEVICE kv bytes do not divide by dp — only the kv-head

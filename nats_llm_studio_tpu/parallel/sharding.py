@@ -56,6 +56,8 @@ def param_sharding_rules(mesh: Mesh, cfg: ModelConfig | None = None) -> dict[str
         return _mla_rules(cfg, ep, pp)
     if cfg is not None and cfg.n_ssm_layers:
         return _ssm_rules(pp)
+    if cfg is not None and cfg.n_win_layers:
+        return _swa_rules(ep, pp)
     return {
         "embed": P(None, None),  # replicated: read once per token, cheap
         "out_norm": P(None),
@@ -109,6 +111,22 @@ def _ssm_rules(pp) -> dict[str, P]:
     rules = {"embed": P(None, None), "out_norm": P(None), "lm_head": P(None, None)}
     rules |= {f"blocks.mamba.{k}": P(pp, *[None] * r) for k, r in mamba.items()}
     rules |= {f"blocks.attn.{k}": P(pp, *[None] * r) for k, r in attn.items()}
+    return rules
+
+
+def _swa_rules(ep, pp) -> dict[str, P]:
+    """A rule for every leaf ``models.swa_moe.init_params`` makes: the four
+    stacks' layer axis on pp, the routed experts on ep, everything else whole
+    (the family is served on one chip a replica: ``validate_mesh_for_config``
+    refuses a mesh over it)."""
+    attn = {"attn_norm": 1, "wq": 2, "wk": 2, "wv": 2, "wg": 2, "wo": 2}
+    dense = {"ffn_norm": 1, "w_gate": 2, "w_up": 2, "w_down": 2}
+    moe = {"ffn_norm": 1, "router": 2, "e_bias": 1, "w_gate_s": 2, "w_up_s": 2, "w_down_s": 2}
+    rules = {"embed": P(None, None), "out_norm": P(None), "lm_head": P(None, None)}
+    for name, leaves in (("full", attn), ("win", attn), ("dense", dense), ("moe", moe)):
+        rules |= {f"blocks.{name}.{k}": P(pp, *[None] * r) for k, r in leaves.items()}
+    rules |= {f"blocks.moe.{k}": P(pp, ep, None, None)
+              for k in ("w_gate_e", "w_up_e", "w_down_e")}
     return rules
 
 
@@ -264,6 +282,13 @@ def validate_mesh_for_config(mesh: Mesh, cfg: ModelConfig,
             f"state-space models ({cfg.arch}) serve on one chip a replica "
             "(MESH_SHAPE=off): the scan's heads and the per-slot state pool "
             "have no mesh split yet"
+        )
+    if cfg.n_win_layers and mesh.size > 1:
+        raise ValueError(
+            f"window-attention models ({cfg.arch}) serve on one chip a replica "
+            "(MESH_SHAPE=off): the per-slot ring and its kernel have no tp "
+            "split of the kv heads yet, and the dropless expert layer no ep "
+            "exchange"
         )
     # every message names the FULL axis factoring, not just the failing
     # axis — a multi-axis mesh ("dp=2,ep=2,tp=2") read back as bare "tp=2"

@@ -92,6 +92,63 @@ _RESERVED = object()
 _TOKEN_HANDOVERS_A_BURST = 64
 
 
+# why a family whose slots keep something beside their KV blocks does not get
+# a serving feature, by what the slot keeps: a recurrent state
+# (models/ssm_hybrid.py) or the window layers' ring (models/swa_moe.py)
+_SLOT_STATE_CAUSES = {
+    "state": {
+        "paged": "state-space models are served on the paged pool only (unset "
+                 "KV_PAGED=0): the shared ring rolls and shifts rows "
+                 "(compact_ring), and a recurrent state cannot be shifted",
+        "kv_quant": "TPU_KV_QUANT=int8 is not implemented for state-space models "
+                    "(two kv heads share a cache row; one scale a row cannot "
+                    "serve both): unset TPU_KV_QUANT",
+        "kv_tiers": "the host/Object-Store KV tiers hold KV blocks and no state: "
+                    "a prefix promoted from a tier could not be decoded from: "
+                    "set KV_HOST_POOL_BYTES=0",
+        "prefix_cache": "off: the cache holds KV blocks and no snapshot of the "
+                        "recurrent state at a block's end, so a hit could not be "
+                        "decoded from",
+        "spec_decode": "off: a verify would advance the state past rejected "
+                       "drafts and the pool keeps no snapshot to go back to",
+        "decode_xla": "DECODE_KERNEL=xla gathers a slot's blocks into a view and "
+                      "scatters them back; state-space models decode on the pool "
+                      "in place only: unset DECODE_KERNEL",
+        "kv_transfer": "KVX1 carries KV blocks and no recurrent state: a prefix "
+                       "transferred without its state could not be decoded from "
+                       "(state-space models prefill where they decode)",
+    },
+    "ring": {
+        "paged": "window-attention models are served on the paged pool only "
+                 "(unset KV_PAGED=0): the shared-ring cache holds every layer's "
+                 "whole context, and a window layer keeps a ring of its window "
+                 "a slot",
+        "kv_quant": "TPU_KV_QUANT=int8 is not implemented for window-attention "
+                    "models (the ring has no scale leaf and its kernel reads plain "
+                    "rows): unset TPU_KV_QUANT",
+        "kv_tiers": "the host/Object-Store KV tiers hold KV blocks and no ring: a "
+                    "prefix promoted from a tier would lack the window layers' "
+                    "keys: set KV_HOST_POOL_BYTES=0",
+        "prefix_cache": "off: the cache shares KV blocks, and a slot's ring of the "
+                        "window layers' last keys cannot be shared by block nor "
+                        "rebuilt from the full layers' blocks",
+        "spec_decode": "off: a verify would write its drafts over ring places "
+                       "whose keys are still in the window if a draft is "
+                       "rejected, and the ring keeps nothing to go back to",
+        "decode_xla": "DECODE_KERNEL=xla gathers a slot's blocks into a view; "
+                      "window-attention models decode on the pool and the ring in "
+                      "place only: unset DECODE_KERNEL",
+        "kv_transfer": "KVX1 carries KV blocks and no ring: a prefix transferred "
+                       "without the window layers' keys could not be decoded from "
+                       "(window-attention models prefill where they decode)",
+    },
+}
+
+
+def _slot_state_causes(cfg: ModelConfig) -> dict[str, str]:
+    return _SLOT_STATE_CAUSES["state" if cfg.n_ssm_layers else "ring"]
+
+
 class BatcherStopped(RuntimeError):
     """Submit raced a shutdown (drain, or idle-eviction by the registry's
     HBM admission): the request was never queued. Callers map this to a
@@ -313,6 +370,14 @@ class BatcherStats:
     state_slots_moved: int = 0
     state_admits_fresh: int = 0
     state_admits_carried: int = 0
+    # window layers beside full ones (models/swa_moe.py): the keys the live
+    # rows of every decode burst attended to in ONE window layer and in ONE
+    # full layer, summed over its steps, the steps, and the tokens admits
+    # left in the rings of one window layer
+    win_tokens: int = 0
+    full_tokens: int = 0
+    win_steps: int = 0
+    ring_tokens: int = 0
     # the form the expert layers of a decode burst take: "hit_list" (only the
     # experts the live rows hit are read), "grouped" (a burst of 16 slots and
     # more: each pick computed on its own expert) or "dense" (models/mla_moe.py
@@ -481,6 +546,24 @@ class BatcherStats:
         for k, v in burst.items():
             setattr(self, k, getattr(self, k) + v)
         return burst
+
+    def record_window(self, starts: list[int], steps: int, window: int) -> dict[str, int]:
+        """One decode burst of a family with window layers beside full ones:
+        its live rows begin at positions ``starts`` and take ``steps`` steps.
+        Counts the keys they attend to in ONE layer of each kind (the row at
+        position p sees p + 1 keys in a full layer and at most ``window`` of
+        them in a window layer). Returns what the readback span carries."""
+        full = sum(steps * (p + 1) + steps * (steps - 1) // 2 for p in starts)
+        win = sum(min(p + j + 1, window) for p in starts for j in range(steps))
+        burst = {"win_tokens": win, "full_tokens": full, "win_steps": steps}
+        for k, v in burst.items():
+            setattr(self, k, getattr(self, k) + v)
+        return burst
+
+    def window_counters(self) -> dict[str, int]:
+        """Exposed by serve/worker.py as lmstudio_swa_*_total."""
+        return {"win_tokens": self.win_tokens, "full_tokens": self.full_tokens,
+                "win_steps": self.win_steps, "ring_tokens": self.ring_tokens}
 
     def state_counters(self) -> dict[str, int]:
         """Exposed by serve/worker.py as lmstudio_ssm_*_total."""
@@ -689,40 +772,31 @@ class ContinuousBatcher:
         # what was asked for and this family does not serve, by feature: the
         # cause an operator reads on the metrics page and in health
         self.refusals: dict[str, str] = {}
-        if cfg.n_ssm_layers:
-            # state-space layers keep a recurrent state a slot beside the KV
-            # (models/ssm_hybrid.py). What cannot be served is refused with
-            # its cause: an error where the knob contradicts the family, a
-            # feature turned off (and said so) where it is an optimisation
+        if cfg.slot_state:
+            # a slot keeps something beside its KV blocks that no table
+            # describes: a recurrent state (models/ssm_hybrid.py) or the
+            # window layers' ring (models/swa_moe.py). What cannot be served
+            # is refused with its cause: an error where the knob contradicts
+            # the family, a feature turned off (and said so) where it is an
+            # optimisation
+            why = _slot_state_causes(cfg)
             if not self.paged:
-                raise ValueError(
-                    f"{cfg.arch}: state-space models are served on the paged "
-                    "pool only (unset KV_PAGED=0): the shared ring rolls and "
-                    "shifts rows (compact_ring), and a recurrent state cannot "
-                    "be shifted")
+                raise ValueError(f"{cfg.arch}: {why['paged']}")
             if cfg.kv_quant == "int8":
-                raise ValueError(
-                    f"{cfg.arch}: TPU_KV_QUANT=int8 is not implemented for "
-                    "state-space models (two kv heads share a cache row; one "
-                    "scale a row cannot serve both): unset TPU_KV_QUANT")
+                raise ValueError(f"{cfg.arch}: {why['kv_quant']}")
             if kv_tiers is not None:
-                raise ValueError(
-                    f"{cfg.arch}: the host/Object-Store KV tiers hold KV "
-                    "blocks and no state: a prefix promoted from a tier could "
-                    "not be decoded from: set KV_HOST_POOL_BYTES=0")
+                raise ValueError(f"{cfg.arch}: {why['kv_tiers']}")
             if prefix_cache_blocks > 0:
-                self.refusals["prefix_cache"] = (
-                    "off: the cache holds KV blocks and no snapshot of the "
-                    "recurrent state at a block's end, so a hit could not be "
-                    "decoded from")
+                self.refusals["prefix_cache"] = why["prefix_cache"]
                 prefix_cache_blocks = 0
             if spec_decode_k > 0:
-                self.refusals["spec_decode"] = (
-                    "off: a verify would advance the state past rejected "
-                    "drafts and the pool keeps no snapshot to go back to")
+                self.refusals["spec_decode"] = why["spec_decode"]
                 spec_decode_k = 0
         self._pool: BlockPool | None = None
+        # the books of what a slot keeps beside its blocks: a state-space
+        # family's state pool, a window-attention family's rings
         self._state_pool = None
+        self._window_pool = None
         if self.paged:
             # block size: the requested tokens-per-block snapped down (pow2
             # halving) until it divides the prefill chunk — cached chunks
@@ -745,12 +819,16 @@ class ContinuousBatcher:
                 else max_slots * self.blocks_per_row + max(0, prefix_cache_blocks)
             )
             self._pool = BlockPool(usable + 1, T)
-            if cfg.n_ssm_layers:
+            if cfg.slot_state:
                 from ..parallel.memory import state_slot_bytes
 
-                self._state_pool = StatePool(
+                books = StatePool(
                     max_slots, state_slot_bytes(cfg),
                     lambda: sum(r is not None for r in self._slots))
+                if cfg.n_ssm_layers:
+                    self._state_pool = books
+                else:
+                    self._window_pool = books
         else:
             self.kv_block_tokens = 0
             self.blocks_per_row = 0
@@ -852,7 +930,7 @@ class ContinuousBatcher:
         self._spec_accept_ewma = 0.0
         self.stats = BatcherStats()
         if cfg.n_moe_layers:
-            from ..models.mla_moe import expert_path
+            from ..models.experts import expert_path
 
             # the form the expert layers of a call of ``rows`` rows take
             self._expert_form = lambda rows: expert_path(
@@ -1046,6 +1124,12 @@ class ContinuousBatcher:
                 self.stats.state_admits_carried += attrs.get("width", 1)
             else:
                 self.stats.state_admits_fresh += attrs.get("width", 1)
+        if self._window_pool is not None:
+            # tokens the admit leaves in the rings of ONE window layer: a
+            # ring holds a prompt's last ``window`` positions
+            attrs["ring"] = attrs.get("width", 1) * min(
+                attrs.get("tokens", 0), self.cfg.window)
+            self.stats.ring_tokens += attrs["ring"]
         with obs_spans.span("batcher.admit", **attrs) as spn:
             try:
                 yield spn
@@ -1398,6 +1482,14 @@ class ContinuousBatcher:
         out = self._pool.stats()
         if self._state_pool is not None:
             out["state"] = self._state_pool.stats()
+        if self._window_pool is not None:
+            from ..parallel.memory import kv_pool_block_bytes
+
+            # the rings' books, and beside them what the full layers' paged
+            # pool takes: the two kinds of cache priced apart
+            out["window"] = self._window_pool.stats() | {
+                "kv_pool_bytes": self._pool.n_blocks * kv_pool_block_bytes(
+                    self.cfg, self.kv_block_tokens)}
         return out
 
     def drop_prefix_cache(self) -> int:
@@ -1732,11 +1824,9 @@ class ContinuousBatcher:
         return self._control(_ControlOp("import", {"export": export}), timeout)
 
     def _refuse_kv_transfer(self) -> None:
-        if self.cfg.n_ssm_layers:
-            raise ValueError(
-                f"{self.cfg.arch}: KVX1 carries KV blocks and no recurrent "
-                "state: a prefix transferred without its state could not be "
-                "decoded from (state-space models prefill where they decode)")
+        cfg = self.cfg
+        if cfg.slot_state:
+            raise ValueError(f"{cfg.arch}: {_slot_state_causes(cfg)['kv_transfer']}")
 
     def _control(self, op: _ControlOp, timeout: float):
         if not self._started:
@@ -1794,22 +1884,27 @@ class ContinuousBatcher:
         itemsize = 4 if cfg.dtype == "float32" else 2
         # off-TPU the interpreter runs any layout; Mosaic's tiling rules
         # only bind on the chip
-        if cfg.n_ssm_layers:
-            # the family has one decode path: the kernel over packed rows
-            # (models/ssm_hybrid.py), through the interpreter off the chip
+        if cfg.slot_state:
+            # the family has one decode path: the kernel over the pool
+            # (packed rows in models/ssm_hybrid.py) and, for window layers,
+            # the kernel over the ring; through the interpreter off the chip
             if mode == "xla":
-                raise ValueError(
-                    f"{cfg.arch}: DECODE_KERNEL=xla gathers a slot's blocks "
-                    "into a view and scatters them back; state-space models "
-                    "decode on the pool in place only: unset DECODE_KERNEL")
+                raise ValueError(f"{cfg.arch}: {_slot_state_causes(cfg)['decode_xla']}")
             (hp, width), _ = cfg.kv_cache_dims()
             if on_tpu and not paged_decode_eligible(
                     self.kv_block_tokens, width, itemsize, False, hp, 1):
                 raise ValueError(
                     f"{cfg.arch}: the paged decode kernel cannot serve this "
-                    f"layout (packed rows of {width} lanes, T="
-                    f"{self.kv_block_tokens}) and state-space models have no "
-                    "gather-view decode path")
+                    f"layout (rows of {width} lanes, T={self.kv_block_tokens}) "
+                    "and the family has no gather-view decode path")
+            if cfg.n_win_layers and on_tpu:
+                from ..ops.paged_attention import window_decode_eligible
+
+                if not window_decode_eligible(cfg.window, cfg.head_dim, itemsize):
+                    raise ValueError(
+                        f"{cfg.arch}: the ring kernel cannot serve a window of "
+                        f"{cfg.window} keys of {cfg.head_dim} lanes, and window "
+                        "layers have no other decode path")
             return "pallas"
         if mode == "xla":
             return "xla"
@@ -1913,13 +2008,14 @@ class ContinuousBatcher:
                               dtype=dt, quant=quant)
                 for h, width in cfg.kv_cache_dims()
             )
-            if cfg.n_ssm_layers:
-                # the state pool beside the blocks: row i is slot i's
-                from ..models.ssm_hybrid import make_state
+            if cfg.slot_state:
+                # the state pool (or the rings) beside the blocks: row i is
+                # slot i's
+                from ..models.llama import family_module
                 from ..ops.kvcache import WithState
 
                 KP, VP = (WithState(p, st, ax) for p, (st, ax) in zip(
-                    (KP, VP), make_state(cfg, B)))
+                    (KP, VP), family_module(cfg).make_state(cfg, B)))
             if self.mesh is not None:
                 from ..parallel.sharding import pool_spec, shard_cache
 
@@ -2240,6 +2336,9 @@ class ContinuousBatcher:
                         spn.attrs.update(self.stats.record_moe(ids[B:]))
                     if self._state_pool is not None:
                         spn.attrs.update(self.stats.record_state(len(rows), n, listed))
+                    if self._window_pool is not None:
+                        spn.attrs.update(self.stats.record_window(
+                            [req.pos for _, req in rows], n, cfg.window))
                 # observed per-step latency (dispatch -> tokens readable);
                 # includes pipeline wait, i.e. what a stream experiences
                 now = time.monotonic()
@@ -2367,6 +2466,9 @@ class ContinuousBatcher:
                 with obs_spans.span("batcher.readback", program="ext") as spn:
                     if self._state_pool is not None:
                         spn.attrs.update(self.stats.record_state(len(rows), 1, listed))
+                    if self._window_pool is not None:
+                        spn.attrs.update(self.stats.record_window(
+                            [req.pos for _, req in rows], 1, cfg.window))
                     ids = np.asarray(toks_ref)  # [B]
                     lps = np.asarray(lp_ref)  # [B]
                     tis = np.asarray(topids_ref)  # [B, LOGPROBS_K]
@@ -3889,7 +3991,7 @@ class ContinuousBatcher:
                     # position 0; a family with a recurrent state is told so
                     # (-1: none of this chunk's positions is real), or the
                     # padding would run through the row's state
-                    lo = -1 if cfg.n_ssm_layers else 0
+                    lo = -1 if cfg.slot_state else 0
                     last_pos = [
                         min(max(ns[i] - 1 - start, lo), C - 1) for i in idx
                     ]

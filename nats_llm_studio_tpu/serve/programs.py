@@ -847,12 +847,13 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             return (out, n_emit, pin_pool(KP), pin_pool(VP), new_tok,
                     pos + n_emit, steps + width)
 
-        if cfg.n_ssm_layers:
+        if cfg.slot_state:
             @partial(jax.jit, donate_argnums=(0, 1))
             def state_restore(KP, VP, krow, vrow, bids, st, slot):
-                """Resume of a suspended slot of a family with a recurrent
-                state: the host copies of its KV into fresh blocks and of
-                its state into the slot's row, pools donated."""
+                """Resume of a suspended slot of a family whose slots keep a
+                state or a ring beside their KV: the host copies of its KV
+                into fresh blocks and of that into the slot's row, pools
+                donated."""
                 return (pool_write(KP, WithState(krow, st[0], KP.axes), bids, slot),
                         pool_write(VP, WithState(vrow, st[1], VP.axes), bids, slot))
 

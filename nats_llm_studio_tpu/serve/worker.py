@@ -1897,7 +1897,8 @@ class Worker:
                              "computes each pick on its own expert, dense all")
             ssm = getattr(stats, "state_counters", None)
             pool_fn = getattr(rb, "pool_stats", None)
-            state_pool = ((pool_fn() if pool_fn else None) or {}).get("state")
+            pools = (pool_fn() if pool_fn else None) or {}
+            state_pool = pools.get("state")
             if ssm is not None and state_pool:
                 # state-space layers (models/ssm_hybrid.py): rows / steps is
                 # the live rows whose recurrent state a decode step advanced,
@@ -1913,6 +1914,21 @@ class Worker:
                         state_pool["slots_live"], labels=labels)
                 r.gauge("lmstudio_ssm_state_pool_slots_total",
                         state_pool["slots_total"], labels=labels)
+            swa = getattr(stats, "window_counters", None)
+            if swa is not None and pools.get("window"):
+                # window layers beside full ones (models/swa_moe.py): win /
+                # (win + full) tokens is the share of a decode step's keys the
+                # window layers read, a layer of each kind; the full layers'
+                # KV is the paged pool's, the window layers' a ring a slot
+                for name, v in swa().items():
+                    r.counter(f"lmstudio_swa_{name}_total", v, labels=labels)
+                ring = pools["window"]
+                r.gauge("lmstudio_swa_ring_pool_bytes", ring["bytes"], labels=labels,
+                        help="device bytes of the window layers' per-slot rings")
+                r.gauge("lmstudio_swa_full_pool_bytes", ring["kv_pool_bytes"], labels=labels,
+                        help="device bytes of the full layers' paged KV pool")
+                r.gauge("lmstudio_swa_ring_pool_slots_live", ring["slots_live"], labels=labels)
+                r.gauge("lmstudio_swa_ring_pool_slots_total", ring["slots_total"], labels=labels)
             for feature, cause in sorted(getattr(rb, "refusals", {}).items()):
                 # what was asked for and this model's family does not serve
                 r.gauge("lmstudio_feature_refused", 1,

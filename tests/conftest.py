@@ -37,14 +37,19 @@ import functools
 import pytest
 
 
-def async_test(fn):
-    """Run an async test via asyncio.run (no pytest-asyncio in this image)."""
+def async_test(fn=None, *, timeout: float = 60.0):
+    """Run an async test via asyncio.run (no pytest-asyncio in this image);
+    ``@async_test(timeout=...)`` for a test that builds more programs than
+    60 s of a loaded host with an empty compile cache allow."""
 
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        asyncio.run(asyncio.wait_for(fn(*args, **kwargs), timeout=60.0))
+    def wrap(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            asyncio.run(asyncio.wait_for(fn(*args, **kwargs), timeout=timeout))
 
-    return wrapper
+        return wrapper
+
+    return wrap if fn is None else wrap(fn)
 
 
 def hold_decodes_until_queued(b, after: int = 3, timeout_s: float = 30.0) -> None:

@@ -8,4 +8,5 @@ from benchmark.tests.test_moe_prefill_chunk_ms import (  # noqa: F401
 )
 from benchmark.tests.test_reduce_trace import *  # noqa: F401,F403
 from benchmark.tests.test_ssm_readers import *  # noqa: F401,F403
+from benchmark.tests.test_swa_readers import *  # noqa: F401,F403
 from benchmark.tests.test_traffic import *  # noqa: F401,F403

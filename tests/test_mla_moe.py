@@ -18,7 +18,7 @@ import pytest
 
 from benchmark import run
 from benchmark.lib import correct, weights
-from nats_llm_studio_tpu.models import llama, mla_moe
+from nats_llm_studio_tpu.models import experts, llama, mla_moe
 from nats_llm_studio_tpu.models.config import ModelConfig
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -215,12 +215,12 @@ def test_the_hit_list_is_the_dense_dispatch_over_the_experts_hit(case, dtype, mo
     h = rand(8, 1, d)
     idx = jnp.asarray(picks, jnp.int32)[:, None]
     gate = jax.random.uniform(next(ks), idx.shape, minval=0.1, maxval=1.0)
-    monkeypatch.setattr(mla_moe, "route", lambda *_: (idx, gate))
+    monkeypatch.setattr(experts, "route", lambda *_: (idx, gate))
     live = jnp.asarray(live, jnp.float32)
     assert mla_moe.expert_path(cfg, 8, stacks) == "hit_list"
     dense, _ = mla_moe.moe_ffn(h, p | {k: v[place] for k, v in stacks.items()}, cfg, live)
     got, stats = jax.jit(lambda: mla_moe.moe_ffn(
-        h, p, cfg, live, "hit_list", tuple(stacks[k] for k in mla_moe._EXPERT_LEAVES), place))()
+        h, p, cfg, live, "hit_list", tuple(stacks[k] for k in mla_moe.EXPERT_LEAVES), place))()
     assert stats.tolist() == [n_hit, rows_max, int(sum(live))]
     from nats_llm_studio_tpu.ops.layers import swiglu
 
@@ -264,12 +264,12 @@ def test_the_grouped_form_is_the_dense_dispatch_without_its_zero_terms(how, rows
     h = rand(b, rows // b, d)
     idx = jnp.asarray(_picks(how, rows, e, k), jnp.int32).reshape(b, rows // b, k)
     gate = jax.random.uniform(next(ks), idx.shape, minval=0.1, maxval=1.0)
-    monkeypatch.setattr(mla_moe, "route", lambda *_: (idx, gate))
+    monkeypatch.setattr(experts, "route", lambda *_: (idx, gate))
     assert mla_moe.expert_path(cfg, rows, stacks) == "grouped"
     dense, _ = jax.jit(lambda: mla_moe.moe_ffn(
         h, p | {k_: v[place] for k_, v in stacks.items()}, cfg))()
     got, stats = jax.jit(lambda: mla_moe.moe_ffn(
-        h, p, cfg, None, "grouped", tuple(stacks[k_] for k_ in mla_moe._EXPERT_LEAVES), place))()
+        h, p, cfg, None, "grouped", tuple(stacks[k_] for k_ in mla_moe.EXPERT_LEAVES), place))()
     assert stats is None and got.shape == dense.shape
     scale = float(np.abs(np.asarray(dense)).max())
     np.testing.assert_allclose(np.asarray(got), np.asarray(dense), rtol=0, atol=2e-5 * scale)
@@ -296,7 +296,7 @@ def test_the_expert_path_is_chosen_from_shapes_leaf_types_and_devices(rows, leav
 
     cfg = REF.model_config(CONF, SEQ).with_(n_experts=64, n_experts_used=4)
     w = jnp.ones((1, 64, 8, 8), jnp.bfloat16)
-    stack = {k: w if leaves == "plain" else quantize_weight(w) for k in mla_moe._EXPERT_LEAVES}
+    stack = {k: w if leaves == "plain" else quantize_weight(w) for k in mla_moe.EXPERT_LEAVES}
     mesh = build_mesh({"tp": devices}, devices=jax.local_devices()[:devices])
     assert mla_moe.expert_path(cfg, rows, stack, mesh) == path
     if devices > 1:   # the mesh alone made it dense

@@ -92,7 +92,10 @@ async def test_a_prompt_of_three_chunks_through_the_grouped_form_is_dense_dispat
     assert spec_tokens == tokens
 
     # the same engine, every call answered "dense"
-    monkeypatch.setattr(mla_moe, "expert_path", lambda *a, **k: "dense")
+    from nats_llm_studio_tpu.models import experts
+
+    for module in (experts, mla_moe):   # the batcher asks the first, the model file the second
+        monkeypatch.setattr(module, "expert_path", lambda *a, **k: "dense")
     dense_tokens, dense_stats, dense_admits, _, _ = await serve(model)
     assert dense_stats.expert_path == "dense"
     assert all(a["experts"] == "dense" for a in dense_admits)

@@ -452,3 +452,102 @@ def test_the_live_list_is_the_state_space_familys_alone():
     assert names(ssm_hybrid.forward_decode_paged) == family
     src = inspect.getsource(programs)
     assert "ssm_scan" not in src and "live_slots" not in src and "table_rows_in_use" not in src
+
+
+# -- the window / full attention family at lagunaxs2.code_closed's shapes --------
+
+
+SWA_SLOTS, SWA_SEQ = 16, 18432
+
+
+@pytest.fixture(scope="module")
+def swa_cell():
+    """(cfg, the served tree's shapes) of ``benchmark/configs/laguna-xs.2.json``."""
+    import json
+    from pathlib import Path
+
+    from benchmark import run
+
+    root = Path(__file__).resolve().parents[1]
+    ref = run.load_module(root / "benchmark/references/swa_gated_moe.py")
+    conf = json.loads((root / "benchmark/configs/laguna-xs.2.json").read_text())
+    cfg = ref.model_config(conf, SWA_SEQ)
+    return cfg, ref.param_shapes(cfg), int(conf["serving"]["env"]["KV_BLOCK_TOKENS"])
+
+
+def _swa_pools(cfg, sharding, t):
+    """The cell's pools: 16 x 18,432 / t + 1 blocks of the 2 full layers, each
+    with the 16 slots' rings of the 3 window layers beside it."""
+    from nats_llm_studio_tpu.models import swa_moe
+    from nats_llm_studio_tpu.ops.kvcache import WithState
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+
+    nb = SWA_SLOTS * (SWA_SEQ // t) + 1
+    return tuple(WithState(sds((nb, cfg.n_kv_layers, cfg.n_kv_heads, t, cfg.head_dim)),
+                           (sds(swa_moe.ring_shape(cfg, SWA_SLOTS)),), swa_moe.RING_AXES)
+                 for _ in range(2))
+
+
+def test_the_ring_kernel_at_the_benchmark_cells_shapes(one_chip, no_cache, swa_cell):
+    """16 slots, 64 query heads over 8 kv heads (group 8), a ring of 512 keys
+    of 128 lanes, bf16, the layer a traced scalar: Mosaic tiles it and the
+    call carries its own name."""
+    from nats_llm_studio_tpu.ops.paged_attention import (
+        window_decode_attention, window_decode_eligible)
+
+    cfg, _, _ = swa_cell
+    assert window_decode_eligible(cfg.window, cfg.head_dim, 2)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    ring = sds((cfg.n_win_layers, SWA_SLOTS, cfg.n_kv_heads, cfg.window, cfg.head_dim), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, rk, rv, pos, layer: window_decode_attention(q, rk, rv, pos, layer, SCALE),
+        sds((SWA_SLOTS, 1, cfg.win_n_heads, cfg.head_dim), jnp.bfloat16), ring, ring,
+        sds((SWA_SLOTS,), jnp.int32), sds((), jnp.int32))
+    assert "window_decode_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode_pallas", "decode_pallas_ext"],
+                         ids=["the burst", "the single step"])
+def test_a_decode_launch_of_the_window_family_copies_no_pool_and_no_ring(
+        one_chip, no_cache, swa_cell, program):
+    """The family's two decode programs as ``serve/programs.py`` builds them,
+    all 5 layers over the cell's pools and rings, donated: the slot table of
+    16 x 1,152 entries (72 KiB) fits the kernel's scalar memory, both
+    attention kernels and the hit-list expert kernel are in it under their
+    names, the pools and the rings are aliased onto the results, and the
+    program holds no ``copy`` and no ``dynamic-update-slice`` of a whole pool
+    or ring (a row's key goes in by a scatter of one row)."""
+    from nats_llm_studio_tpu.engine.sampling import sample_rows
+    from nats_llm_studio_tpu.serve.programs import build_programs
+
+    cfg, shapes, t = swa_cell
+    kp, vp = _swa_pools(cfg, one_chip, t)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    row = lambda dt, *more: jax.ShapeDtypeStruct(  # noqa: E731
+        (SWA_SLOTS,) + more, dt, sharding=one_chip)
+    ints, floats = row(jnp.int32), row(jnp.float32)
+    table = build_programs(cfg, None, max_seq=SWA_SEQ, paged=True, kv_block_tokens=t,
+                           sample_rows=sample_rows)
+    last = 8 if program == "decode_pallas" else row(jnp.bool_, cfg.vocab_size)
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
+    try:
+        compiled = table[program].lower(
+            jax.tree.map(sds, shapes), ints, kp, vp, row(jnp.int32, SWA_SEQ // t), ints, ints,
+            ints, floats, ints, floats, last).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    for name in ("window_decode_attention", "paged_decode_attention", "moe_hit_experts"):
+        assert name in text, name
+    kv, ring = kp.kv, kp.st[0]
+    whole = (f"bf16[{','.join(map(str, kv.shape))}]", f"bf16[{','.join(map(str, ring.shape))}]")
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if any(op in ln for op in (" copy(", "copy-start(", " dynamic-update-slice("))
+             and any(ln.split("=", 1)[-1].strip().startswith(p) for p in whole)]
+    assert not moved, moved
+    ma = compiled.memory_analysis()
+    held = 2 * (int(np.prod(kv.shape)) + int(np.prod(ring.shape))) * 2
+    assert ma.alias_size_in_bytes >= held and ma.temp_size_in_bytes < held // 4
