@@ -12,17 +12,25 @@ number; the recurrent state float32): every weight once, the head included
 state-space layer once in and once out, and its convolution tail likewise;
 the keys and values of every live token in the attention layers. A kernel
 that also moves the state of slots that hold no request moves more than this
-bound, and ``ssm_decode_step_roofline`` shows by how much.
+bound, and ``ssm_decode_step_roofline`` shows by how much (since PR 36 the
+state kernel moves the slots that hold a request, and no others).
+
+The rows a device time is priced against are the TRACED SPAN's own (the
+``batcher.readback`` spans that end inside it: ``span_live_rows``), not the
+window's mean: since PR 36 the state kernel's time follows the live rows, and
+the window's 24.2 rows over the mean call of a span that held 21.9 read 87 %
+where the span's own rows give 79 %; a span at 18 rows under a window at 24
+would have read 105 % (PERF.md, PR 36 and PR 39). ``ssm_rows_live_avg``, a
+counter and no share of a peak, stays the window's.
 """
 
 from __future__ import annotations
 
 from benchmark.lib.roofline_mla_moe import (  # noqa: F401 — the readers' one import
     bandwidth, decode_step_seconds, kernel_durations_ns, live_tokens)
-from benchmark.lib.spans import window_records
+from benchmark.lib.spans import readback_sums, traced_span
 
 STATE_KERNEL = "ssm_state_step"
-STATE_KEYS = ("state_rows", "state_steps")
 # the programs that prefill prompts of this family, as the trace names them
 PREFILL_PROGRAMS = ("prefill_chunk_group", "prefill1", "admit_fused_paged",
                     "admit_many_fused_paged")
@@ -90,22 +98,23 @@ def state_step_call_ops(hf: dict, live_rows: float) -> float:
     return 4.0 * live_rows * elements
 
 
-def window_state_counters(src) -> dict | None:
-    """``state_rows`` (live rows x steps) and ``state_steps`` of the decode
-    bursts read back inside the window, summed: each ``batcher.readback``
-    span of such a burst carries its own (``BatcherStats.record_state``)."""
-    w0, w1 = src["window"]
-    tot = dict.fromkeys(STATE_KEYS, 0)
-    for _, _, t1, attrs in window_records(src, "batcher.readback") or []:
-        if attrs and "state_steps" in attrs and w0 <= t1 < w1:
-            for k in STATE_KEYS:
-                tot[k] += attrs[k]
-    return tot if tot["state_steps"] else None
+def rows_a_step(src, lo: float, hi: float) -> float | None:
+    """``state_rows`` (live rows x steps) over ``state_steps`` of the decode
+    bursts read back in [lo, hi): each ``batcher.readback`` span of such a
+    burst carries its own (``BatcherStats.record_state``)."""
+    c = readback_sums(src, lo, hi)
+    return c["state_rows"] / c["state_steps"] if c.get("state_steps") else None
 
 
 def live_rows(src) -> float | None:
-    c = window_state_counters(src)
-    return c["state_rows"] / c["state_steps"] if c else None
+    """Live rows a step, the window's mean."""
+    return rows_a_step(src, *src["window"])
+
+
+def span_live_rows(src) -> float | None:
+    """Live rows a step of the bursts read back inside the traced span: what
+    the span's device times are priced against."""
+    return rows_a_step(src, *traced_span(src))
 
 
 def prefill_launches(src) -> tuple[float, int] | None:

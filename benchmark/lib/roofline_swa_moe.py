@@ -24,7 +24,7 @@ from benchmark.lib.roofline_mla_moe import (  # noqa: F401 — the readers' one 
     bandwidth, decode_step_seconds, kernel_durations_ns, window_moe_counters)
 # the same prefill programs under the same names as the state-space family's
 from benchmark.lib.roofline_ssm_hybrid import prefill_launches  # noqa: F401
-from benchmark.lib.spans import window_records
+from benchmark.lib.spans import traced_span, window_records
 
 WINDOW_KERNEL = "window_decode_attention"
 FULL_KERNEL = "paged_decode_attention"
@@ -86,15 +86,6 @@ def kernel_call_bytes(hf: dict, tokens: float) -> float:
     """What one call of either attention kernel (one layer, every slot) must
     read: the keys and values of the tokens its live rows see."""
     return tokens * kv_token_bytes(hf)
-
-
-def traced_span(src) -> tuple[float, float]:
-    """The traced span on the host's clock, as ``run.py``'s ``_traced_window``
-    places it: up to 4 s in the middle of the window."""
-    w0, w1 = src["window"]
-    span = min(4.0, (w1 - w0) / 3.0)
-    t_on = w0 + (w1 - w0 - span) / 2.0
-    return t_on, t_on + span
 
 
 def burst_counters(src, lo: float, hi: float) -> dict | None:

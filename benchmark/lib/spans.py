@@ -7,11 +7,15 @@ metric is left out of the line. A test hands a recorded fixture in under
 
 from __future__ import annotations
 
+import os
 from pathlib import Path
 
 from benchmark.lib import reduce_trace
 
-TRACE_DIR = Path(__file__).resolve().parent.parent / ".cache" / "run" / "trace"
+# a run's scratch (the model's header, the store, the trace): a directory a
+# process, which ``run.py`` removes at its end
+RUN_DIR = Path(__file__).resolve().parent.parent / ".cache" / f"run-{os.getpid()}"
+TRACE_DIR = RUN_DIR / "trace"
 # host events under these prefixes are the program's own spans
 SPAN_PREFIXES = ("batcher.", "worker.")
 _planes_cache: dict = {}
@@ -28,6 +32,31 @@ def window_records(src, name: str) -> list[tuple] | None:
     except ImportError:
         return None
     return spans.records(w0, w1, name)
+
+
+def traced_span(src) -> tuple[float, float]:
+    """The traced span on the host's clock: as ``run.py`` started and stopped
+    the profiler (``src["span"]``), else as its ``_traced_window`` places it:
+    up to 4 s in the middle of the window."""
+    if src.get("span") and None not in src["span"]:
+        return tuple(src["span"])
+    w0, w1 = src["window"]
+    span = min(4.0, (w1 - w0) / 3.0)
+    t_on = w0 + (w1 - w0 - span) / 2.0
+    return t_on, t_on + span
+
+
+def readback_sums(src, lo: float, hi: float) -> dict:
+    """Every counter the ``batcher.readback`` spans that end in [lo, hi)
+    carry, summed: what the bursts of the window, or of the traced span,
+    counted (rows, steps, experts hit, keys), for the run's ``trace`` line."""
+    tot: dict = {}
+    for _, _, t1, attrs in window_records(src, "batcher.readback") or []:
+        if lo <= t1 < hi:
+            for k, v in (attrs or {}).items():
+                if isinstance(v, (int, float)) and not isinstance(v, bool):
+                    tot[k] = tot.get(k, 0) + v
+    return tot
 
 
 def ledger_at_window_start(src) -> dict | None:

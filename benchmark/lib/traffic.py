@@ -29,16 +29,39 @@ Parameters of a mix:
                     seed agreed to 0.1-0.3 % (PR 23, call 6)
     warmup          {"concurrency": [..], "background": k, "max_tokens": n,
                      "quiet_s": s, "min_settle_s": s, "max_settle_s": s,
-                     "settle_requests": n}: the sweep's widths, how many long
-                    streams keep the engine live under it, and when the
-                    settle phase may end: after ``settle_requests`` sends of
-                    the mix and ``quiet_s`` without a new program, at the next
-                    send (so the window always starts at the same point of the
-                    mix's sequence and at the same phase of the decode bursts).
-                    ``settle_requests`` is sized to be the LAST condition met:
-                    a count fixes the point of the sequence, a time does not
-                    (with the time binding, one run in five started a send
-                    early or late and read tokens/s 0.5-1.4 % off; PR 28)
+                     "settle_requests": n, "sends_in_min_settle": m}: the
+                    sweep's widths, how many long streams keep the engine live
+                    under it, and where the settle phase ends and the window
+                    starts (``Settle``).
+
+Where the window starts (``Settle``; the rule every mix's author has to size):
+the window's first send is send number ``settle_requests`` of the mix (that
+many went out before it), decided AT that send and not by a clock that polls,
+so the window starts at a fixed point of the mix's sequence and at the same
+phase of the decode bursts in every run. A count fixes the point of the
+sequence, a time does not: with a time binding, one run in five started a
+send early or late and read tokens/s 0.5-1.4 % off (PR 28). The send still
+has to come after ``min_settle_s`` and after ``quiet_s`` without a new
+program; where it comes too early (the program got faster since the count was
+sized), the first send after both times starts the window, and timing picks
+it. There is no stepping on by whole decks: the decks' orders differ, and a
+start a deck on read 2.3 % lower on each of two seeds (PR 39), which between a
+parent and a change would be a false loss where a picked start is only noise.
+
+A run says what happened on its ``warmup_settle`` line and in its result
+line: ``sends`` / ``settle_sends`` (the index of the window's first send) and
+``closed_by`` / ``settle_closed_by``: ``count`` (the count came last: the ONLY
+reading of a sound run, with ``sends`` equal to the file's count), ``clock``
+or ``quiet`` (that condition came after the count, and timing picked the
+start), ``max`` (``max_settle_s`` ran out). ``sends_in_min_settle`` on the
+line is how many sends the replay had made when ``min_settle_s`` passed; the
+file keeps the figure it was sized from beside the count, and
+``benchmark/tests/test_traffic.py`` holds every committed mix to
+``settle_requests >= 1.05 x sends_in_min_settle``. To re-size a count after a
+gain (a ``benchmark`` PR: the file is the yardstick's): read
+``sends_in_min_settle`` off a few runs, set ``settle_requests`` to about 1.4 x
+that (a whole number of decks where that is near), write the new figure
+beside it, and re-measure the cell: its window is another 30 s of the mix.
 
 A <dist> is {"dist": "loguniform", "min": a, "max": b} or
 {"dist": "fixed", "value": v}.
@@ -183,8 +206,7 @@ class Client:
         self.temperature = temperature
         self.timeout_s = timeout_s
         self.records: list[Record] = []
-        self.send_event: asyncio.Event | None = None  # set at the next send
-        self.send_time = 0.0                          # ... with its time here
+        self.settle: Settle | None = None  # told of every send while the phase lasts
 
     async def chat(self, req: Request, logprobs: int = 0,
                    temperature: float | None = None) -> Record:
@@ -204,9 +226,8 @@ class Client:
         rec = Record(req.idx, req.prompt_tokens, req.max_tokens, now,
                      temperature=temperature, prompt=req.prompt)
         self.records.append(rec)
-        if self.send_event is not None and not self.send_event.is_set():
-            self.send_time = now
-            self.send_event.set()
+        if self.settle is not None:
+            self.settle.sent(now)
         stream = self.nc.request_stream(
             "lmstudio.chat_model", json.dumps(body).encode(),
             timeout=self.timeout_s, idle_timeout=self.timeout_s)
@@ -247,6 +268,61 @@ class Client:
         if got != want:
             rec.mismatch = (f"request {rec.idx}: prompt/completion/streamed tokens "
                             f"{got}, expected {want}")
+
+
+class Settle:
+    """Where the settle phase ends and the window starts (the module's
+    docstring has the rule). ``Client.chat`` calls ``sent`` at every send of
+    the mix; the send at which it returns True is the window's first.
+    ``last_program()`` is the time the newest program was built or fetched."""
+
+    def __init__(self, warmup: dict, t0: float, last_program=lambda: 0.0):
+        self.count = int(warmup.get("settle_requests", 0))
+        self.quiet_s = float(warmup.get("quiet_s", 5.0))
+        self.min_s = float(warmup.get("min_settle_s", self.quiet_s))
+        self.max_s = float(warmup.get("max_settle_s", 60.0))
+        self.t0 = t0
+        self.last_program = last_program
+        self.sends = 0                      # sends of the mix before the window's first
+        self.sends_in_min_settle: int | None = None
+        self.closed_by: str | None = None
+        self.w0: float | None = None
+        self.started = asyncio.Event()
+
+    def sent(self, now: float) -> bool:
+        if self.w0 is not None:
+            return False
+        if self.sends_in_min_settle is None and now - self.t0 >= self.min_s:
+            self.sends_in_min_settle = self.sends
+        clock_at, quiet_at = self.t0 + self.min_s, self.last_program() + self.quiet_s
+        if now - self.t0 >= self.max_s:
+            self._start(now, "max")
+        elif self.sends >= self.count and now >= clock_at and now >= quiet_at:
+            self._start(now, self.closed_by or "count")
+        else:
+            if self.sends >= self.count:
+                # the count came before a time: that time closes the phase
+                self.closed_by = "clock" if clock_at >= quiet_at else "quiet"
+            self.sends += 1
+        return self.w0 is not None
+
+    def _start(self, now: float, closed_by: str) -> None:
+        self.w0, self.closed_by = now, closed_by
+        self.started.set()
+
+    async def wait(self) -> float:
+        """The window's start. Where nothing is sent for 30 s past
+        ``max_settle_s`` the window starts there, as before PR 39."""
+        try:
+            await asyncio.wait_for(
+                self.started.wait(), max(0.0, self.t0 + self.max_s - time.perf_counter()) + 30.0)
+        except asyncio.TimeoutError:
+            self._start(time.perf_counter(), "max")
+        return self.w0
+
+    def line(self) -> dict:
+        return {"sends": self.sends, "closed_by": self.closed_by,
+                "sends_in_min_settle": self.sends_in_min_settle}
 
 
 class Load:

@@ -160,25 +160,35 @@ def draw_numbers(paths: list[str]) -> dict[str, int]:
     return out
 
 
-def resolve_gains(gain: dict | None, paths: list[str]) -> dict[str, float]:
-    """{path: gain}; a key is a leaf's dotted path or its last name. A key
-    that names no leaf of the tree is an error."""
-    asked = DEFAULT_GAIN | dict(gain or {})
+def by_leaf(asked: dict, paths: list[str], what: str, optional=()) -> dict:
+    """{path: value}; a key is a leaf's dotted path or its last name. A key
+    that names no leaf of the tree is an error (but for the ``optional``)."""
     out = {}
-    for key, g in asked.items():
+    for key, v in asked.items():
         hits = [p for p in paths if p == key or p.rsplit(".", 1)[-1] == key]
-        if not hits and key not in DEFAULT_GAIN:
-            raise ValueError(f"weight_gains names {key!r}, which is no leaf of {sorted(paths)}")
-        out.update({p: float(g) for p in hits})
+        if not hits and key not in optional:
+            raise ValueError(f"{what} names {key!r}, which is no leaf of {sorted(paths)}")
+        out.update(dict.fromkeys(hits, v))
     return out
 
 
-def make_seeded_params(seed: int, family=None):
+def resolve_gains(gain: dict | None, paths: list[str]) -> dict[str, float]:
+    asked = {k: float(g) for k, g in (DEFAULT_GAIN | dict(gain or {})).items()}
+    return by_leaf(asked, paths, "weight_gains", optional=DEFAULT_GAIN)
+
+
+def make_seeded_params(seed: int, family=None, fixed_draws: dict | None = None):
     """Returns a function with the loader's signature that ignores the
     reader's tensors and builds the tree from ``seed``. ``family`` is the
     configuration's reference module (or anything with its two optional
     names): ``param_shapes(cfg)`` gives the schema (default:
     ``program_param_shapes``), ``weight_gains`` a {leaf: factor} beside it.
+    ``fixed_draws`` is the configuration's own {leaf: number}: such a leaf is
+    drawn from that number and not from ``seed``, so every seed serves the
+    SAME leaf. For the leaves that decide how much work a step is (a router
+    and its selection bias pick how many experts a step streams: six seeds
+    spread 2.5 % in tokens/s by that alone, PERF.md PR 39); every other leaf,
+    the texts and the sampling seeds stay the seed's.
     Its ``last_build`` attribute holds the seconds and bytes of its last
     call."""
     param_shapes = getattr(family, "param_shapes", None)
@@ -212,6 +222,7 @@ def make_seeded_params(seed: int, family=None):
         stacked = [p for p in schema if p.startswith(STACKED)]
         draws = draw_numbers(stacked)
         gains = resolve_gains(gain, list(schema))
+        fixed = by_leaf(dict(fixed_draws or {}), list(schema), "fixed_draws")
         col = ascii_column_scale(cfg)
 
         def q8(w):
@@ -243,12 +254,15 @@ def make_seeded_params(seed: int, family=None):
             return randn(k, shape).astype(jnp.float32) * loud[None, :]
 
         def build(key):
-            k_embed, k_head, k_blocks = jax.random.split(key, 3)
+            k_embed, k_head, k_seed_blocks = jax.random.split(key, 3)
             top_keys = {"embed": k_embed, "lm_head": k_head}
             out = {}
             for path, x in schema.items():
                 rule = leaf_rule(path, x.shape)
                 g = gains.get(path, 1.0)
+                # a leaf the configuration pins: the blocks' key of ITS number
+                k_blocks = (jax.random.split(_seed_key(int(fixed[path])), 3)[2]
+                            if path in fixed else k_seed_blocks)
                 if rule == "ones":
                     out[path] = jnp.ones(x.shape, dt)
                 elif path in draws:
@@ -288,7 +302,7 @@ def make_seeded_params(seed: int, family=None):
     return seeded_params
 
 
-def install(seed: int, family=None):
+def install(seed: int, family=None, fixed_draws: dict | None = None):
     """Point ``parallel.loader.load_params_sharded`` at the seeded builder
     and return it. The only program name the benchmark overrides."""
     from nats_llm_studio_tpu.parallel import loader
@@ -303,5 +317,5 @@ def install(seed: int, family=None):
         raise RuntimeError(
             f"parallel.loader.load_params_sharded{names} no longer matches "
             f"{EXPECTED_SIGNATURE}: refusing to substitute seeded weights")
-    loader.load_params_sharded = builder = make_seeded_params(seed, family)
+    loader.load_params_sharded = builder = make_seeded_params(seed, family, fixed_draws)
     return builder

@@ -116,8 +116,9 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
 
     from benchmark.lib import correct, model_files, weights
     from benchmark.lib.sources import CompileClock, memory_by_device
+    from benchmark.lib.spans import RUN_DIR
     from benchmark.lib.stats import finite_ms
-    from benchmark.lib.traffic import (Client, Generator, Load, dist_bounds, quantiles,
+    from benchmark.lib.traffic import (Client, Generator, Load, Settle, dist_bounds, quantiles,
                                        reduce_client, warmup_lengths)
     from nats_llm_studio_tpu.main import start_serve
     from nats_llm_studio_tpu.transport import connect
@@ -128,7 +129,9 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
     reference = load_module(man.find("references", conf["reference"], (".py",)))
     mcfg = reference.model_config(conf, int(serving["env"]["MAX_SEQ_LEN"]))
 
-    scratch = BENCH / ".cache" / "run"
+    # a scratch directory a process, removed at its end: two runs in one
+    # checkout share the compile cache and nothing else
+    scratch = RUN_DIR
     shutil.rmtree(scratch, ignore_errors=True)
     models_dir = scratch / "models"
     model_files.write_header_gguf(mcfg, model_id, models_dir)
@@ -137,7 +140,7 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
     clock = CompileClock()
     # the schema is the program's own initialiser, or the one the reference
     # names for its family (``param_shapes``, with ``weight_gains`` beside it)
-    builder = weights.install(args.seed, reference)
+    builder = weights.install(args.seed, reference, conf.get("fixed_draws"))
     worker, shutdown = await start_serve(embedded_broker=True, port=0,
                                          store_dir=str(scratch / "store"))
     nc = await connect(worker.config.nats_url, name="benchmark")
@@ -161,7 +164,7 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
              decode_kernel=getattr(batcher, "decode_kernel", None),
              max_slots=getattr(batcher, "max_slots", None),
              prefill_chunk=getattr(batcher, "prefill_chunk", None),
-             compile_cache_dir=jax.config.jax_compilation_cache_dir,
+             compile_cache_dir=jax.config.jax_compilation_cache_dir, scratch=str(scratch),
              memory=memory_by_device())
 
         # -- probes (set-up): what the reference check will compare ----------
@@ -231,35 +234,17 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
              lengths=lengths, programs=len(clock.events))
 
         # -- warm-up 2: the mix itself, until no new program appears ---------
+        # the window starts AT a send of the mix that ``Settle`` picks by its
+        # number (lib/traffic.py): the same point of the mix's sequence and
+        # the same phase of the decode bursts in every run
         t0 = begin("warmup_settle")
+        settle = client.settle = Settle(
+            wu, t0, lambda: clock.events[-1][0] if clock.events else 0.0)
         load = Load(client, gen)
         load.start()
-        quiet_s = float(wu.get("quiet_s", 5.0))
-        settle_min = float(wu.get("min_settle_s", quiet_s))
-        settle_max = float(wu.get("max_settle_s", 60.0))
-        sent_before = len(client.records)
-        settle_sends = sent_before + int(wu.get("settle_requests", 0))
-        while True:
-            await asyncio.sleep(0.05)
-            now = time.perf_counter()
-            last = clock.events[-1][0] if clock.events else 0.0
-            if now - t0 >= settle_max or (
-                    len(client.records) >= settle_sends and now - t0 >= settle_min
-                    and now - last >= quiet_s):
-                break
-        # the window starts at the next send: the same point of the mix's
-        # sequence and the same phase of the decode bursts in every run
-        client.send_event = asyncio.Event()
-        try:
-            await asyncio.wait_for(client.send_event.wait(), timeout=30.0)
-            w0 = client.send_time
-        except asyncio.TimeoutError:
-            w0 = time.perf_counter()
-        client.send_event = None
-        # ``sends``: how many requests of the mix went out before the window's
-        # first, i.e. the point of the mix's sequence the window starts at
-        emit(phase="warmup_settle", seconds=time.perf_counter() - t0,
-             sends=sum(r.t_sent < w0 for r in client.records[sent_before:]),
+        w0 = await settle.wait()
+        client.settle = None
+        emit(phase="warmup_settle", seconds=time.perf_counter() - t0, **settle.line(),
              programs=len(clock.events), compile=clock.summary())
 
         # -- the window ------------------------------------------------------
@@ -282,8 +267,8 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
 
         # -- reduce ----------------------------------------------------------
         cm = reduce_client(client.records, w0, w1)
-        emit(phase="window", setup_s=setup_s, programs_in_window=in_window, **{
-            k: v for k, v in cm.items() if k != "mismatches"})
+        emit(phase="window", setup_s=setup_s, programs_in_window=in_window,
+             work=_work(stats0, stats1), **{k: v for k, v in cm.items() if k != "mismatches"})
 
         # -- reference check: once the window has closed and the peak is read -
         # one float32 forward per probe, and per request of a sample of the
@@ -356,6 +341,9 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
         # last lines of standard error, where the driver's record of a run ends
         result = {"correct": not problems, "attempted": cm["attempted"],
                   "failed": cm["failed"], "metrics": {}, "device": dev_out,
+                  # where the window started and which condition closed the
+                  # settle phase: the file's count and ``count`` in a sound run
+                  "settle_sends": settle.sends, "settle_closed_by": settle.closed_by,
                   "compared": correct.compared(ref_check, win_check) + [
                       f"problem: {p}" for p in problems]}
         if not args.trace:
@@ -365,18 +353,16 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
                     raise RuntimeError(f"no value for end-to-end metric {m['name']}")
                 result["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
         else:
-            from benchmark.lib import reduce_trace
+            from benchmark.lib import reduce_trace, spans
 
             begin("trace")
             trace = reduce_trace.reduce(
                 reduce_trace.load_planes(reduce_trace.find_xplane(str(trace_dir))))
-            emit(phase="trace", span=trace_span, **{
-                k: trace.get(k) for k in ("device_planes", "window_s", "busy_s",
-                                          "programs", "longest_gap_s")})
             if not trace.get("busy_s") and not args.rehearse:
                 raise RuntimeError("the trace holds no device operation")
             src = {
                 "client": cm, "records": client.records, "window": (w0, w1),
+                "span": (trace_span["t_on"], trace_span["t_off"]),
                 "stats_before": stats0, "stats_after": stats1, "samples": samples,
                 "trace": trace, "config": conf, "traffic": mix, "cell": cell,
                 "device": device, "env": dict(serving["env"]),
@@ -384,6 +370,13 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
                            "decode_kernel": getattr(batcher, "decode_kernel", None),
                            "max_slots": getattr(batcher, "max_slots", None)},
             }
+            # ``bursts``: what the decode bursts counted over the window and
+            # over the traced span, whose device times the rooflines price
+            emit(phase="trace", span=trace_span, bursts={
+                "window": spans.readback_sums(src, w0, w1),
+                "span": spans.readback_sums(src, *spans.traced_span(src))}, **{
+                k: trace.get(k) for k in ("device_planes", "window_s", "busy_s",
+                                          "programs", "longest_gap_s")})
             unread = []
             for m in man.metrics("per_layer", cell["name"]):
                 reader = load_module(man.find("layer_metrics", m["name"], (".py",)))
@@ -403,6 +396,7 @@ async def run_cell(args, man: Manifest, cell: dict, conf: dict, mix: dict) -> di
         t0 = begin("shutdown")
         await nc.close()
         await asyncio.wait_for(shutdown(), timeout=60.0)
+        shutil.rmtree(scratch, ignore_errors=True)
         emit(phase="shutdown", seconds=time.perf_counter() - t0,
              run_s=time.perf_counter() - T_START)
     return result
@@ -413,6 +407,22 @@ def _rendered(prompt: str) -> str:
     return f"<|user|>{prompt}<|assistant|>"
 
 
+WORK_COUNTERS = ("experts_hit", "expert_steps", "state_rows", "state_steps")
+
+
+def _work(before: dict, after: dict) -> dict:
+    """The window's own work by the program's counters: steps, tokens a step,
+    and per step the experts a layer streamed and the rows whose state moved."""
+    d = {k: after[k] - before[k] for k in ("tokens", "steps")}
+    d |= {k: v - before["work"].get(k, 0) for k, v in after["work"].items()}
+    out = {"steps": d["steps"], "tokens_per_step": d["tokens"] / d["steps"] if d["steps"] else None}
+    if d.get("expert_steps"):
+        out["experts_hit_avg"] = d["experts_hit"] / d["expert_steps"]
+    if d.get("state_steps"):
+        out["rows_live_avg"] = d["state_rows"] / d["state_steps"]
+    return out
+
+
 def _stat_snapshot(batcher) -> dict:
     """The program's counters at one instant: ``BatcherStats`` counts and
     histograms (host clock around work that ends in a readback)."""
@@ -420,6 +430,9 @@ def _stat_snapshot(batcher) -> dict:
     return {
         "tokens": st.tokens, "steps": st.steps, "requests": st.requests,
         "shed": st.shed,
+        # what a family's decode bursts counted, where it counts: whether two
+        # seeds did the same work shows in an untraced run too
+        "work": {k: getattr(st, k) for k in WORK_COUNTERS if hasattr(st, k)},
         "hist": {name: getattr(st, name).snapshot()
                  for name in ("decode_step_ms", "prefill_ms", "admit_delay_ms")},
     }
@@ -436,7 +449,7 @@ async def _traced_window(w0: float, w1: float, trace_dir: Path, batcher, samples
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
     loop = asyncio.get_running_loop()
-    started = stopped = None
+    started = stopped = t_off = None
     while time.perf_counter() < w1:
         now = time.perf_counter()
         samples.append({"t": now, "pool": batcher.pool_stats()})
@@ -450,13 +463,18 @@ async def _traced_window(w0: float, w1: float, trace_dir: Path, batcher, samples
         await asyncio.sleep(max(0.0, min(wake) - time.perf_counter()))
         if started is not None and stopped is None and time.perf_counter() >= started + span:
             # stop_trace writes the file: seconds of host work, so off the loop
+            t_off = time.perf_counter()
             await loop.run_in_executor(None, jax.profiler.stop_trace)
             stopped = time.perf_counter()
     if started is not None and stopped is None:
+        t_off = time.perf_counter()
         await loop.run_in_executor(None, jax.profiler.stop_trace)
         stopped = time.perf_counter()
+    # ``t_on`` .. ``t_off``: the traced span on the host's clock, for the
+    # readers that price a device time by the span's own bursts
     return {"trace_on_s": started - w0 if started else None,
-            "trace_len_s": (stopped - started) if started else None}
+            "trace_len_s": (stopped - started) if started else None,
+            "t_on": started, "t_off": t_off}
 
 
 def main(argv: list[str] | None = None) -> int:

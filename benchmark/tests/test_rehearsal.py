@@ -90,6 +90,30 @@ def test_every_phase_says_when_it_begins_and_where_the_run_stands(sound):
     window = next(x for x in phases if x["phase"] == "window" and not x.get("begin"))
     ref_begin = next(x for x in phases if x["phase"] == "reference" and x.get("begin"))
     assert window["setup_s"] < ref_begin["t_s"]
+    # ... and where the window started (also a test of its own, below: tier 1
+    # imports this module's tests by name)
+    the_run_says_where_its_window_started(lines)
+
+
+def the_run_says_where_its_window_started(lines):
+    settle = next(x for x in lines if x.get("phase") == "warmup_settle" and not x.get("begin"))
+    # the toy's count is 6 and it has no ``min_settle_s``: whichever of the
+    # count and ``quiet_s`` came last is named
+    assert settle["closed_by"] in ("count", "quiet") and settle["sends"] >= 6
+    assert (settle["closed_by"] == "count") == (settle["sends"] == 6)
+    assert 0 <= settle["sends_in_min_settle"] <= settle["sends"]
+    line = lines[-1]["would_print"]
+    assert line["settle_sends"] == settle["sends"]
+    assert line["settle_closed_by"] == settle["closed_by"]
+    # what was compared stays the line's last key
+    assert list(line)[-1] == "compared"
+    # the run's scratch directory, one a process, went with it
+    load = next(x for x in lines if x.get("phase") == "load" and not x.get("begin"))
+    assert Path(load["scratch"]).name.startswith("run-") and not Path(load["scratch"]).exists()
+
+
+def test_the_result_line_says_where_the_window_started_and_what_closed_the_settle(sound):
+    the_run_says_where_its_window_started(sound[1])
 
 
 def test_the_control_is_read_beside_the_reference_and_decides_nothing(sound):

@@ -75,7 +75,7 @@ def test_the_trace_readers_divide_whole_launches_and_the_kernels_own_events():
     from benchmark.lib import reduce_trace as rt
 
     src = {"config": CONF, "device": DEVICE, "planes": planes, "trace": rt.reduce(planes),
-           "engine": {"decode_burst": 8}, "window": (10.0, 20.0), "spans": spans([(12.0, 27, 8)]),
+           "engine": {"decode_burst": 8}, "window": (10.0, 20.0), "spans": spans([(15.0, 27, 8)]),
            "samples": [{"pool": {"blocks_live": 1000, "block_tokens": 16}}]}
     # a step is 160 ms / 8 = 20 ms of the two whole launches
     need = rl.decode_step_bytes(CONF, 27.0, 16000.0)
@@ -89,3 +89,36 @@ def test_the_trace_readers_divide_whole_launches_and_the_kernels_own_events():
     for name in ("ssm_decode_step_roofline", "ssm_prefill_chunk_ms", "ssm_state_step_roofline"):
         assert reader(name).read(dict(src, config=DENSE)) is None   # another family's cell
         assert reader(name).read(dict(src, planes={}, trace={"device_planes": 0})) is None
+
+
+def test_the_roofline_readers_divide_rows_and_seconds_of_the_same_span():
+    """Since PR 36 the state kernel's time follows the live rows, so a share
+    prices the traced span's own bursts: the window's mean over the span's
+    seconds read 87 % where the span's rows give 79 % (PERF.md, PR 36)."""
+    planes = {"/device:TPU:0": {
+        "XLA Modules": [("jit_decode_pos_pallas(1)", s * MS, 130 * MS) for s in (0, 130, 260, 390)],
+        "XLA Ops": [("%ssm_state_step.6 = f32[32,36,32,128,128]{4,3,2,1,0} custom-call(...)",
+                     140 * MS, 141_900)]}}
+    from benchmark.lib import reduce_trace as rt
+
+    # a window of 30 s at 24.2 live rows; the bursts read back inside the
+    # traced span [23, 27) held 21.9, and one more ends on its far edge
+    bursts = [(12.0, 25, 8), (18.0, 26, 8), (24.0, 22, 8), (26.0, 21.8, 8), (27.0, 26, 8),
+              (35.0, 24.4, 8)]
+    src = {"config": CONF, "device": DEVICE, "planes": planes, "trace": rt.reduce(planes),
+           "engine": {"decode_burst": 8}, "window": (10.0, 40.0), "spans": spans(bursts),
+           "samples": [{"pool": {"blocks_live": 1000, "block_tokens": 16}}]}
+    assert rl.live_rows(src) == pytest.approx(24.2)
+    assert rl.span_live_rows(src) == pytest.approx(21.9)
+    assert reader("ssm_rows_live_avg").read(src) == pytest.approx(24.2)    # the window's, as before
+    new = reader("ssm_state_step_roofline").read(src)
+    assert new == pytest.approx(100.0 * 2 * 21.9 * 2097152 / 819e9 / 141.9e-6)
+    assert new == pytest.approx(79.0, abs=0.1) and new * 24.2 / 21.9 == pytest.approx(87.3, abs=0.1)
+    assert reader("ssm_decode_step_roofline").read(src) == pytest.approx(
+        100.0 * rl.decode_step_bytes(CONF, 21.9, 16000.0) / 819e9 / (0.130 / 8))
+    # the span as run.py started and stopped the profiler, where it says so
+    assert rl.span_live_rows(dict(src, span=(17.5, 18.5))) == pytest.approx(26.0)
+    assert rl.span_live_rows(dict(src, span=(None, None))) == pytest.approx(21.9)
+    # no burst read back inside the span: nothing to price a time against
+    for name in ("ssm_state_step_roofline", "ssm_decode_step_roofline"):
+        assert reader(name).read(dict(src, spans=spans(bursts[:2]))) is None

@@ -164,3 +164,109 @@ def test_every_kth_request_of_the_sequence_is_greedy_whatever_the_seed():
     # warm-up and probe requests (``make``) are never marked
     assert traffic.Generator(mix, 1).make(64, 4).temperature is None
     assert all(r.temperature is None for r in take(traffic.Generator(CLOSED, 1), 8))
+
+
+# -- where the window starts (``traffic.Settle``) -----------------------------
+
+WARMUP = {"settle_requests": 6, "min_settle_s": 10, "quiet_s": 7, "max_settle_s": 60}
+
+
+def replay(warmup, send_times, last_program=0.0, t0=100.0):
+    """Drive ``Settle`` with a fake clock and a fake client: ``send_times``
+    are the mix's sends, seconds after the phase began, in order. Returns the
+    settle line and the index of the window's first send."""
+    settle = traffic.Settle(warmup, t0, lambda: t0 + last_program)
+    for i, t in enumerate(send_times):
+        if settle.sent(t0 + t):
+            assert settle.w0 == t0 + t and settle.started.is_set()
+            assert not settle.sent(t0 + t + 1.0)        # the phase is over: nothing moves
+            return settle.line(), i
+    return settle.line(), None
+
+
+def every(dt, n=200):
+    return [dt * (i + 1) for i in range(n)]
+
+
+def test_the_settle_phase_says_which_condition_came_last():
+    import pytest
+
+    # the count last (sends 2 s apart: the 7th, index 6, comes at 14 s): sound
+    line, first = replay(WARMUP, every(2.0), last_program=1.0)
+    assert (line["closed_by"], line["sends"], first) == ("count", 6, 6)
+    assert line["sends_in_min_settle"] == 4             # sends at 2, 4, 6, 8 s
+    # the clock last (a send a second: the count is reached at 7 s)
+    line, first = replay(WARMUP, every(1.0), last_program=1.0)
+    assert (line["closed_by"], line["sends"], first) == ("clock", 9, 9)
+    # quiet last (a program built 9 s into the phase: quiet from 16 s)
+    line, first = replay(WARMUP, every(1.0), last_program=9.0)
+    assert (line["closed_by"], first) == ("quiet", 15)
+    # neither comes (a program every few seconds): ``max_settle_s`` runs out
+    settle = traffic.Settle(WARMUP, 100.0, lambda: now[0] - 1.0)
+    now = [100.0]
+    for i in range(100):
+        now[0] = 100.0 + i + 1
+        if settle.sent(now[0]):
+            break
+    assert settle.closed_by == "max" and now[0] - 100.0 == pytest.approx(60.0)
+
+
+def test_a_count_that_comes_last_fixes_the_windows_first_send_whatever_the_timing():
+    import random
+
+    def jittered(seed, dt):
+        rng = random.Random(seed)
+        return sorted(dt * (i + 1) + rng.uniform(-0.4, 0.4) * dt for i in range(200))
+
+    sized = dict(WARMUP, settle_requests=24)
+    # sized with room (a send every 0.5 s: 20 in ``min_settle_s``, the count
+    # 24): the same send opens the window under +-40 % of jitter on every
+    # send's time, and at 0.85 x and 1.15 x the send rate
+    for dt in (0.5 / 1.15, 0.5, 0.5 / 0.85):
+        runs = [replay(sized, jittered(s, dt), last_program=0.5) for s in range(20)]
+        assert {(line["closed_by"], first) for line, first in runs} == {("count", 24)}
+    # overtaken (twice the rate): the clock closes the phase, timing picks among
+    # several sends, and every such run says so
+    runs = [replay(sized, jittered(s, 0.25), last_program=0.5) for s in range(20)]
+    assert {line["closed_by"] for line, _ in runs} == {"clock"}
+    assert len({first for _, first in runs}) > 1 and all(38 <= f <= 42 for _, f in runs)
+    assert all(line["sends_in_min_settle"] == first for line, first in runs)
+
+
+def test_every_committed_mix_keeps_its_count_last_with_room():
+    """``settle_requests`` has to be the LAST condition met; the file keeps
+    beside it how many sends the replay made in ``min_settle_s`` when it was
+    last sized (a run prints the figure on its settle line)."""
+    import json
+    from pathlib import Path
+
+    files = sorted((Path(traffic.__file__).parent.parent / "traffic").glob("*.json"))
+    assert len(files) >= 5
+    for f in files:
+        mix = json.loads(f.read_text())
+        wu = mix["warmup"]
+        assert wu["settle_requests"] >= 1.05 * wu["sends_in_min_settle"], f.name
+        assert wu["settle_requests"] >= mix["callers"], f.name
+
+
+def test_the_client_tells_the_settle_phase_of_every_send_and_the_window_starts_at_one():
+    mix = dict(CLOSED, output_tokens={"dist": "fixed", "value": 2})
+
+    async def go():
+        client = traffic.Client(FakeNC(delay=0.002), "m", 0.8)
+        t0 = time.perf_counter()
+        settle = client.settle = traffic.Settle(
+            {"settle_requests": 6, "quiet_s": 0.0, "min_settle_s": 0.0}, t0)
+        load = traffic.Load(client, traffic.Generator(mix, 4))
+        load.start()
+        w0 = await settle.wait()
+        client.settle = None
+        await asyncio.sleep(0.05)
+        await load.stop(w0)
+        return client, settle, w0
+
+    client, settle, w0 = asyncio.run(go())
+    assert (settle.sends, settle.closed_by) == (6, "count")
+    # the window's first send is the mix's 7th, to the clock's last digit
+    assert client.records[6].t_sent == w0
+    assert sum(r.t_sent < w0 for r in client.records) == 6

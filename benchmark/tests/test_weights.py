@@ -189,3 +189,34 @@ def test_install_passes_the_schema_and_the_gains_on(monkeypatch):
     plain = weights.make_seeded_params(5)(None, cfg, mesh)
     assert float(np.std(np.asarray(build(None, cfg, mesh)["blocks"]["wo"]))) == pytest.approx(
         2.0 * float(np.std(np.asarray(plain["blocks"]["wo"]))), rel=1e-3)
+
+
+def test_a_leaf_the_configuration_pins_is_the_same_for_every_seed():
+    """``fixed_draws``: a leaf that decides how much work a step is (a router,
+    its selection bias) is drawn from the configuration's number, every other
+    leaf from the seed; a pinned leaf is what some seed would have drawn."""
+    cfg, mesh = tiny_cfg(), mesh1()
+    arr = lambda x: np.asarray(x, np.float32)
+    pins = {"wo": 29, "blocks.w_up": 29}
+    a = weights.make_seeded_params(3, fixed_draws=pins)(None, cfg, mesh)
+    b = weights.make_seeded_params(2**31 + 11, fixed_draws=pins)(None, cfg, mesh)
+    plain = weights.make_seeded_params(3)(None, cfg, mesh)
+    for name in ("wo", "w_up"):
+        assert np.array_equal(arr(a["blocks"][name]), arr(b["blocks"][name]))
+        assert not np.array_equal(arr(a["blocks"][name]), arr(plain["blocks"][name]))
+        # one draw of the same distribution: the seed 29 would have made it
+        assert np.array_equal(arr(a["blocks"][name]), arr(
+            weights.make_seeded_params(29)(None, cfg, mesh)["blocks"][name]))
+    for name in ("wq", "wv", "w_down"):
+        assert np.array_equal(arr(a["blocks"][name]), arr(plain["blocks"][name]))
+        assert not np.array_equal(arr(a["blocks"][name]), arr(b["blocks"][name]))
+    assert np.array_equal(arr(a["embed"]), arr(plain["embed"]))
+    with pytest.raises(ValueError, match="fixed_draws names 'router'"):
+        weights.make_seeded_params(3, fixed_draws={"router": 29})(None, cfg, mesh)
+
+
+def test_the_one_configuration_that_pins_leaves_pins_its_routing():
+    confs = {p.stem: json.loads(p.read_text())
+             for p in (Path(weights.__file__).parent.parent / "configs").glob("*.json")}
+    assert {k: v["fixed_draws"] for k, v in confs.items() if "fixed_draws" in v} == {
+        "xing4.0-29b-a4b": {"router": 29, "e_bias": 29}}
