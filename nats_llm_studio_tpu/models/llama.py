@@ -21,6 +21,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from ..ops.flash_attention import (
@@ -118,8 +119,6 @@ def _attention_block(
             # the per-row scatter pins a different cache layout and the same
             # constraint backfires into full-cache relayouts (~16x slower —
             # caught by scripts/ablate_decode.py).
-            from jax.experimental.layout import Layout, with_layout_constraint
-
             sl = with_layout_constraint(
                 sl, Layout(major_to_minor=(1, 0, 2, 4, 3))
             )
@@ -176,21 +175,43 @@ def _attention_block(
     # alias xs, which would copy the whole cache every step). The fresh
     # rows scatter into the full array at (b, layer, :, pos, :); the carry
     # buffer's last use in the loop body is this scatter, so XLA performs
-    # it in place. Batch is the LEADING cache axis so the vmapped scatter's
-    # preferred batch-outermost physical layout IS the default layout — any
-    # other order inserts a full-cache relayout copy per layer (measured:
-    # 344 ms/step vs 5 ms). The ragged scatter itself lowers to a
-    # serialized row loop (~4.5 ms/step at batch 8 — the reason serving
-    # uses the ring path), but it also pins the cache layout, which keeps
-    # the attention dot reading the cache IN PLACE at ~400 GB/s; every
-    # structure that removed the scatter made XLA materialize+relayout the
-    # slab per layer and lost more than the scatter costs.
+    # it in place. Batch is the LEADING cache axis so that, FROM TWO ROWS
+    # UP, the vmapped scatter's preferred batch-outermost physical layout
+    # IS the default layout — any other order inserts a full-cache relayout
+    # copy per layer (measured: 344 ms/step vs 5 ms). The ragged scatter
+    # itself lowers to a serialized row loop (~4.5 ms/step at batch 8 — the
+    # reason serving uses the ring path), but it also pins the cache layout,
+    # which keeps the attention dot reading the cache IN PLACE at ~400 GB/s;
+    # every structure that removed the scatter made XLA materialize+relayout
+    # the slab per layer and lost more than the scatter costs.
     def write_row(cache_b, rows_b, s):  # cache_b [L,Hkv,S,D]; rows_b [Hkv,T,D]
         return kv_update_slice(cache_b, rows_b[None], (layer, zero, s, zero))
 
     write = jax.vmap(write_row)
     k_all = write(k_all, k.transpose(0, 2, 1, 3), start_pos)
     v_all = write(v_all, v.transpose(0, 2, 1, 3), start_pos)
+    if b == 1 and t > 1 and jax.default_backend() == "tpu":
+        # ONE row pins nothing: at batch 1 the compiled text shows the
+        # scan's carry as {3,4,2,1,0} (S minor), XLA:TPU's own choice. The
+        # chunk kernel's slab (_continue below) is sliced D minor, so the
+        # while body then relayouts the WHOLE [1, L, Hkv, S, D] pair in
+        # every layer of a continuation chunk, and every chunk once more on
+        # entry and exit: two copies of 0.51 ms a layer at 40 x 8 x 2048 x
+        # 128 bf16, 41 of a continuation launch's 75 ms (PERF.md section 6,
+        # PR 40). Holding the carry, where it leaves the write, to the
+        # layout it has at rest (the default, D minor) leaves no copy of the
+        # pair anywhere in the program; the fresh-prefill programs compile
+        # to the text they had. From two rows up the carry is {4,3,2,1,0}
+        # unasked (tests/test_tpu_compile.py reads the compiled text at both
+        # widths).
+        rest = Layout(major_to_minor=(0, 1, 2, 3, 4))
+
+        def at_rest(cache):  # int8 KV: the codes; the scales [1, L, Hkv, S] stay put
+            if kv_is_quantized(cache):
+                return KVQ(q=with_layout_constraint(cache.q, rest), s=cache.s)
+            return with_layout_constraint(cache, rest)
+
+        k_all, v_all = at_rest(k_all), at_rest(v_all)
 
     sp_ring = False
     if mesh is not None and t > 1:

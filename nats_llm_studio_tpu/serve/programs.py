@@ -152,8 +152,12 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             return WithState(row_of(c.kv, i), state_row(c, i), c.axes)
         return kv_slice(c, (i, zero, zero, zero, zero), (1,) + tuple(c.shape[1:]))
 
-    @partial(jax.jit, static_argnums=(6,))
+    @partial(jax.jit, donate_argnums=(2, 3), static_argnums=(6,))
     def prefill1(params, tokens, k1, v1, start, last_pos, window):
+        # One [1, C] chunk of a single prompt's chunked admit. Donates the
+        # row-cache pair like prefill_chunk_group: the pair is updated in
+        # place (every caller rebinds k1, v1 from the result; an undonated
+        # pair was copied whole on entry, 335 MB at 40 x 8 x 2048 x 128).
         # lm_head at one position only ([1,1,vocab]); non-final chunks
         # ignore the logits, the final chunk's last_pos is the prompt end.
         # uniform_start: all rows share `start`, so chunk continuations

@@ -148,3 +148,83 @@ async def test_the_exposition_names_every_dispatched_program(tmp_path):
                  "lmstudio_program_bytes_total"):
         assert gone not in prom
     assert "lmstudio_device_ms_total" in prom  # the ledger stays
+
+
+def _row_cache_args(cfg, chunk=8):
+    from nats_llm_studio_tpu.models.llama import make_cache
+
+    k1, v1 = jax.eval_shape(lambda: make_cache(cfg, 1, 64))
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    one = jax.ShapeDtypeStruct((1,), jnp.int32)
+    return params, jax.ShapeDtypeStruct((1, chunk), jnp.int32), k1, v1, one, one, 16
+
+
+@pytest.mark.parametrize("family,program", [
+    ("dense", "prefill1"), ("latent", "prefill1"),
+    ("dense", "prefill_chunk_group"),   # the group's chunk always did: the two stay alike
+])
+def test_a_prefill_chunk_donates_its_row_cache_pair(family, program):
+    """``prefill1(params, tokens, k1, v1, start, last_pos, window)`` updates
+    the pair in place: arguments 2 and 3, and no other, are the program's to
+    overwrite, read from the lowered program's own aliasing (on the chip a
+    donated buffer is gone; every caller rebinds ``k1, v1`` from the result)."""
+    cfg = _cfg(family)
+    table = build_programs(cfg, None, max_seq=64, paged=True, kv_block_tokens=16,
+                           sample_rows=sample_rows)
+    lowered = table[program].lower(*_row_cache_args(cfg))
+    donated = [[leaf.donated for leaf in jax.tree.leaves(arg)] for arg in lowered.args_info[0]]
+    assert all(donated[2]) and all(donated[3])
+    assert not any(flag for i, arg in enumerate(donated) if i not in (2, 3) for flag in arg)
+    # each donated leaf is aliased onto a result (or left to the compiler as a donor)
+    main = next(ln for ln in lowered.as_text().splitlines() if "func.func public @main" in ln)
+    n_pair = len(jax.tree.leaves(lowered.args_info[0][2:4]))
+    assert main.count("tf.aliasing_output") + main.count("jax.buffer_donor") == n_pair
+
+
+def _watch_prefill1(b) -> list:
+    """Every pair handed to the batcher's ``prefill1``, in order."""
+    pairs = []
+    inner = b._prefill1
+
+    def prefill1(params, tokens, k1, v1, *rest, **kw):
+        pairs.append((k1, v1))
+        return inner(params, tokens, k1, v1, *rest, **kw)
+
+    b._prefill1 = prefill1
+    return pairs
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "ring"])
+@pytest.mark.parametrize("kv_quant", ["none", "int8"], ids=["bf16kv", "int8kv"])
+@async_test
+async def test_a_chunked_single_admit_never_touches_a_donated_pair(paged, kv_quant):
+    """A fresh prompt over two chunks, then a prompt that shares its first two
+    chunks (prefix hit, the cached blocks filled into a new row cache, then
+    ``prefill1`` continuations, then ``harvest_prefix`` and the finish): the
+    tokens are the single-stream reference's, every pair handed to
+    ``prefill1`` was donated (the CPU deletes a donated buffer as the chip
+    does, so a second use anywhere in the admit would have raised)."""
+    from nats_llm_studio_tpu.engine.generator import Generator, SamplingParams
+
+    chunk = 16
+    cfg = ModelConfig.tiny(n_layers=2, max_seq_len=64, kv_quant=kv_quant)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    fresh = [(i * 7 + 3) % cfg.vocab_size for i in range(2 * chunk + 5)]
+    shared = fresh[:2 * chunk] + [(i * 5 + 1) % cfg.vocab_size for i in range(chunk + 3)]
+    sp = SamplingParams(temperature=0.0, max_tokens=5)
+    gen = Generator(params, cfg, max_seq_len=64, buckets=[8, 16, 32, 64])
+    want = {name: [t for t, _ in gen.generate(p, sp)]
+            for name, p in (("fresh", fresh), ("shared", shared))}
+    b = ContinuousBatcher(params, cfg, max_slots=2, max_seq_len=64, buckets=[8, 64],
+                          prefill_chunk=chunk, prefix_cache_blocks=8, paged=paged,
+                          kv_block_tokens=16)
+    pairs = _watch_prefill1(b)
+    try:
+        assert [t async for t in b.submit(fresh, sp)] == want["fresh"]
+        assert len(pairs) == 3           # chunks at 0, 16 and 32: no group, no full prefill
+        assert [t async for t in b.submit(shared, sp)] == want["shared"]
+        assert len(pairs) == 5           # the hit covers two chunks; continuations at 32, 48
+        assert b.prefix_cache.counters()["hit_tokens"] == 2 * chunk
+    finally:
+        b.stop()
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(pairs))

@@ -317,6 +317,159 @@ def test_a_prefill_chunk_at_the_benchmark_cells_shapes_copies_no_expert_stack(
     assert compiled.memory_analysis().temp_size_in_bytes < stack_slice
 
 
+# -- a prefill chunk of the dense family at granite8b.chat_closed's shapes --
+
+
+def _whole_array_copies(text: str, shape: str) -> list[str]:
+    """The instructions of a compiled program that write a relayouted copy of
+    a whole array of ``shape`` ("bf16[1,40,8,2048,128]") to memory: a ``copy``
+    or ``copy-start`` outside any fusion, or a fusion with that result whose
+    body holds one. A copy fused into a smaller result (the layer's
+    ``dynamic-slice`` of the group path) moves the slice only and is not
+    counted."""
+    bodies: dict[str, list[str]] = {}
+    name = None
+    for ln in text.splitlines():
+        head = ln.split(" ", 2)
+        if ln.endswith("{") and not ln.startswith(" ") and len(head) > 1:
+            name = head[1] if head[0] == "ENTRY" else head[0]
+            bodies[name] = []
+        elif name is not None and ln.startswith("  "):
+            bodies[name].append(ln.strip())
+
+    def copies_whole(ln: str) -> bool:
+        return (" copy(" in ln or "copy-start(" in ln) and shape in ln.split(" copy", 1)[0]
+
+    found = []
+    for name, lines in bodies.items():
+        if name.startswith("%fused_computation"):
+            continue
+        for ln in lines:
+            result = ln.split(" fusion(", 1)[0]
+            if copies_whole(ln):
+                found.append(f"{name}: {ln[:200]}")
+            elif " fusion(" in ln and shape in result and "calls=" in ln:
+                callee = ln.split("calls=", 1)[1].split(",", 1)[0].split(" ", 1)[0]
+                if any(copies_whole(inner) for inner in bodies.get(callee, ())):
+                    found.append(f"{name}: {ln[:200]}")
+    return found
+
+
+def test_whole_array_copies_tells_a_fused_slice_from_a_relayout():
+    text = """HloModule m
+%fused_computation.1 (p: bf16[4,8]) -> bf16[4,8] {
+  %p = bf16[4,8]{1,0} parameter(0)
+  ROOT %copy.1 = bf16[4,8]{0,1} copy(%p)
+}
+
+%fused_computation.2 (p: bf16[4,8]) -> bf16[1,8] {
+  %p = bf16[4,8]{1,0} parameter(0)
+  %fusion.9 = bf16[4,8]{0,1} fusion(%p), kind=kLoop, calls=%fused_computation.1
+  ROOT %ds = bf16[1,8]{1,0} dynamic-slice(%fusion.9), dynamic_slice_sizes={1,8}
+}
+
+%body (t: (bf16[4,8])) -> (bf16[4,8]) {
+  %g = bf16[4,8]{1,0} get-tuple-element(%t), index=0
+  %fusion.2 = bf16[1,8]{1,0} fusion(%g), kind=kLoop, calls=%fused_computation.2
+  %copy.7 = bf16[4,8]{0,1} copy(%g)
+  %fusion.3 = bf16[4,8]{0,1} fusion(%g), kind=kLoop, calls=%fused_computation.1
+}
+
+ENTRY %main (a: bf16[4,8]) -> bf16[4,8] {
+  %a = bf16[4,8]{1,0} parameter(0)
+  %copy-start = (bf16[4,8]{0,1}, bf16[4,8]{1,0}, u32[]) copy-start(%a)
+}
+"""
+    found = _whole_array_copies(text, "bf16[4,8]")
+    assert [f.split(" = ")[0] for f in found] == [
+        "%body: %copy.7", "%body: %fusion.3", "%main: %copy-start"], found
+
+
+@pytest.fixture(scope="module")
+def dense_cell():
+    """(cfg, the served tree's shapes) of ``benchmark/configs/granite-3.1-8b.json``
+    as both Granite-8B cells serve it: int8 weights (WQUANT=int8), MAX_SEQ_LEN
+    2048, the flash kernels on."""
+    import json
+    from pathlib import Path
+
+    from benchmark import run
+    from benchmark.lib.weights import program_param_shapes
+    from nats_llm_studio_tpu.ops.wquant import QTensor, quantizable
+
+    root = Path(__file__).resolve().parents[1]
+    ref = run.load_module(root / "benchmark/references/granite_dense.py")
+    conf = json.loads((root / "benchmark/configs/granite-3.1-8b.json").read_text())
+    cfg = ref.model_config(conf, 2048).with_(use_flash_attention=True)
+
+    def served(node, prefix=""):
+        out = {}
+        for k, v in node.items():
+            if isinstance(v, dict):
+                out[k] = served(v, f"{prefix}{k}.")
+            elif quantizable(prefix + k):
+                out[k] = QTensor(q=jax.ShapeDtypeStruct(v.shape, jnp.int8),
+                                 s=jax.ShapeDtypeStruct(v.shape[:-2] + (1, v.shape[-1]),
+                                                        jnp.float32))
+            else:
+                out[k] = v
+        return out
+
+    return cfg, served(program_param_shapes(cfg))
+
+
+@pytest.mark.parametrize("width,window,kv", [
+    (1, 512, "bf16"), (1, 1024, "bf16"), (1, 2048, "bf16"), (1, 512, "int8"), (4, 512, "bf16"),
+], ids=["prefill1-512", "prefill1-1024", "prefill1-2048", "prefill1-512-int8kv",
+        "chunk_group_of_4-512"])
+def test_a_prefill_chunk_of_the_dense_family_copies_no_row_cache(one_chip, no_cache, dense_cell,
+                                                                 width, window, kv):
+    """A chunk of 256 tokens x ``width`` prompts of ``granite8b.chat_closed``
+    (40 layers, 8 kv heads of 128, row caches [width, 40, 8, 2048, 128]: 168 MB
+    each a row) through ``prefill1`` / ``prefill_chunk_group`` as
+    ``serve/programs.py`` builds them, over the ladder of attention windows:
+    the chunk kernel is in the layer scan, the donated pair is the results'
+    own buffers, and no relayouted copy of a whole row cache is written
+    anywhere: not in the while body (at width 1 the parent's carry was
+    ``{3,4,2,1,0}`` and ``%copy.17`` / ``%copy.18`` put it back to
+    ``{4,3,2,1,0}`` in every layer of a continuation chunk, 41 ms of its
+    75 ms launch on the chip), not on entry and not at the exit. From two rows up the carry is
+    the default layout unasked; that case guards the group path."""
+    from nats_llm_studio_tpu.engine.sampling import sample_rows
+    from nats_llm_studio_tpu.serve.programs import build_programs
+
+    cfg, shapes = dense_cell
+    seq = cfg.max_seq_len
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    row = (width, cfg.n_layers, cfg.n_kv_heads, seq, cfg.head_dim)
+    if kv == "int8":
+        cache = KVQ(q=jax.ShapeDtypeStruct(row, jnp.int8, sharding=one_chip),
+                    s=jax.ShapeDtypeStruct(row[:-1], jnp.float32, sharding=one_chip))
+        codes, code_bytes = f"s8[{','.join(map(str, row))}]", 1
+    else:
+        cache = jax.ShapeDtypeStruct(row, jnp.bfloat16, sharding=one_chip)
+        codes, code_bytes = f"bf16[{','.join(map(str, row))}]", 2
+    table = build_programs(cfg, None, max_seq=seq, paged=True, kv_block_tokens=T,
+                           sample_rows=sample_rows)
+    program = table["prefill1" if width == 1 else "prefill_chunk_group"]
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
+    try:
+        compiled = program.lower(jax.tree.map(sds, shapes), ints(width, CHUNK), cache, cache,
+                                 ints(width), ints(width), window).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "flash_attention_chunk" in text
+    copies = _whole_array_copies(text, codes)
+    assert not copies, copies
+    pair = 2 * int(np.prod(row)) * code_bytes
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= pair           # the donated pair, no entry copy
+    assert ma.temp_size_in_bytes < pair // 2 // 8   # and no second row cache among the temporaries
+
+
 # -- the state-space / attention hybrid family at granite4hmicro.chat32_closed's shapes --
 
 
