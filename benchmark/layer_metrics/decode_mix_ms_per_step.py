@@ -1,0 +1,18 @@
+"""Device milliseconds of one decode step under the scope ``mix``: the
+four-stream maps, read, write and ``hc_sinkhorn`` of ``models/mla_moe.py``.
+A family without residual streams has nothing under it.
+Over the whole launches of the ``decode``-kind programs in the traced span
+(``obs/roofline.py program_kind``), per step as their ``batcher.dispatch``
+spans count the steps; the five ``decode_*_ms_per_step`` sum to the decode
+program's device time a step (``benchmark/lib/scopes.py``, which gives None
+where operations and launches part by more than 2 %). Nothing to read from a
+program without the scope vocabulary."""
+
+METRIC = {"name": "decode_mix_ms_per_step", "unit": "ms", "better": "lower",
+          "source": "device_trace", "layer": "model step", "moves": "gap_p95_ms"}
+
+
+def read(src):
+    from benchmark.lib import scopes
+
+    return scopes.decode_ms_per_step(src, "mix")

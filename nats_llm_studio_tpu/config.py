@@ -472,6 +472,12 @@ class WorkerConfig:
         lose nothing but the cache."""
         import jax
 
+        from .obs.compile_cache import install_compile_cache_listener
+
+        # the build ledger and the cache counters see every program from the
+        # first one on (idempotent; Worker.start asks again for a worker
+        # wired by hand)
+        install_compile_cache_listener()
         if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
         # the serving grid is many sub-second programs (per-bucket

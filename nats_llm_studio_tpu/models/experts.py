@@ -70,9 +70,10 @@ def expert_path(cfg: ModelConfig, rows: int, stack: Params, mesh=None) -> str:
 def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = None,
             form: str = "dense", stacks=None, place=None):
     """Routed experts + the shared expert(s), dropless, in the ``form`` that
-    ``expert_path`` named. Returns (y, stats): ``stats`` is int32 [3] =
-    (distinct experts the ``live`` rows hit, most rows on one expert, live
-    rows) when ``live`` [B] is given, else None.
+    ``expert_path`` named, called inside the layer's ``ffn`` scope (its words
+    here: ``router``, ``shared``, ``experts``). Returns (y, stats): ``stats``
+    is int32 [3] = (distinct experts the ``live`` rows hit, most rows on one
+    expert, live rows) when ``live`` [B] is given, else None.
 
     Every form is the same sum: each row's k picked experts, weighted by
     their gates, in float32 on top of the shared expert's output. The hit
@@ -101,40 +102,43 @@ def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = 
     (46 ms a decode step for 15: PERF.md, PR 29). A prefill splits so that
     [rows, group, width] stays under ``_EXPERT_ACT_BYTES``."""
     e, k = cfg.n_experts, cfg.n_experts_used
-    idx, gate = route(h, p, cfg)
-    picked = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # [B, T, k, E]
-    combine = jnp.sum(picked * gate[..., None], axis=-2)  # [B, T, E] f32
-    on = jnp.sum(picked, axis=-2)  # [B, T, E]: 1 where a row picked the expert
-    rows_on = jnp.sum(on if live is None else on * live[:, None, None], axis=(0, 1))
-    stats = None if live is None else jnp.stack(
-        [jnp.sum(rows_on > 0), jnp.max(rows_on), jnp.sum(live) * h.shape[1]]).astype(jnp.int32)
+    with jax.named_scope("router"):
+        idx, gate = route(h, p, cfg)
+        picked = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # [B, T, k, E]
+        combine = jnp.sum(picked * gate[..., None], axis=-2)  # [B, T, E] f32
+        on = jnp.sum(picked, axis=-2)  # [B, T, E]: 1 where a row picked the expert
+        rows_on = jnp.sum(on if live is None else on * live[:, None, None], axis=(0, 1))
+        stats = None if live is None else jnp.stack(
+            [jnp.sum(rows_on > 0), jnp.max(rows_on), jnp.sum(live) * h.shape[1]]).astype(jnp.int32)
     rows = h.shape[0] * h.shape[1]
-    acc = swiglu(h, p["w_gate_s"], p["w_up_s"], p["w_down_s"], cfg.mlp_act).astype(jnp.float32)
-    if form != "dense":
-        from ..ops import moe_experts
-    if form == "hit_list":
-        ids, n_hit = moe_experts.hit_list(rows_on, min(e, rows * k))
-        gates = jnp.take(combine.reshape(rows, e).T, ids, axis=0)  # [places, rows]
-        acc = moe_experts.moe_hit_experts_auto(
-            h.reshape(rows, -1), gates, ids, n_hit, place, *stacks,
-            acc.reshape(rows, -1)).reshape(acc.shape)
+    with jax.named_scope("shared"):
+        acc = swiglu(h, p["w_gate_s"], p["w_up_s"], p["w_down_s"], cfg.mlp_act).astype(jnp.float32)
+    with jax.named_scope("experts"):
+        if form != "dense":
+            from ..ops import moe_experts
+        if form == "hit_list":
+            ids, n_hit = moe_experts.hit_list(rows_on, min(e, rows * k))
+            gates = jnp.take(combine.reshape(rows, e).T, ids, axis=0)  # [places, rows]
+            acc = moe_experts.moe_hit_experts_auto(
+                h.reshape(rows, -1), gates, ids, n_hit, place, *stacks,
+                acc.reshape(rows, -1)).reshape(acc.shape)
+            return acc.astype(h.dtype), stats
+        if form == "grouped":
+            order, at = moe_experts.sort_by_expert(idx.reshape(rows, k))
+            y = moe_experts.moe_grouped_experts_auto(
+                jnp.take(h.reshape(rows, -1), order // k, axis=0), gate.reshape(-1)[order],
+                jnp.sum(on, axis=(0, 1)), place, *stacks)  # [rows x k, d] f32, sorted
+            acc = acc + jnp.sum(y[at], axis=1).reshape(acc.shape)
+            return acc.astype(h.dtype), stats
+        combine = combine.astype(h.dtype)
+        act_bytes = rows * e * cfg.moe_d_ff * h.dtype.itemsize
+        groups = next(g for g in range(1, e + 1)
+                      if e % g == 0 and act_bytes // g <= _EXPERT_ACT_BYTES or g == e)
+        size = e // groups
+        for g in range(groups):
+            wg, wu, wd = (jax.tree.map(lambda x: x[g * size: (g + 1) * size], p[k_])
+                          for k_ in EXPERT_LEAVES)
+            act = jax.nn.silu(q_einsum("btd,gdf->btgf", h, wg)) * q_einsum("btd,gdf->btgf", h, wu)
+            act = act * combine[..., g * size: (g + 1) * size, None]
+            acc = acc + q_einsum("btgf,gfd->btd", act, wd).astype(jnp.float32)
         return acc.astype(h.dtype), stats
-    if form == "grouped":
-        order, at = moe_experts.sort_by_expert(idx.reshape(rows, k))
-        y = moe_experts.moe_grouped_experts_auto(
-            jnp.take(h.reshape(rows, -1), order // k, axis=0), gate.reshape(-1)[order],
-            jnp.sum(on, axis=(0, 1)), place, *stacks)  # [rows x k, d] f32, sorted
-        acc = acc + jnp.sum(y[at], axis=1).reshape(acc.shape)
-        return acc.astype(h.dtype), stats
-    combine = combine.astype(h.dtype)
-    act_bytes = rows * e * cfg.moe_d_ff * h.dtype.itemsize
-    groups = next(g for g in range(1, e + 1)
-                  if e % g == 0 and act_bytes // g <= _EXPERT_ACT_BYTES or g == e)
-    size = e // groups
-    for g in range(groups):
-        wg, wu, wd = (jax.tree.map(lambda x: x[g * size: (g + 1) * size], p[k_])
-                      for k_ in EXPERT_LEAVES)
-        act = jax.nn.silu(q_einsum("btd,gdf->btgf", h, wg)) * q_einsum("btd,gdf->btgf", h, wu)
-        act = act * combine[..., g * size: (g + 1) * size, None]
-        acc = acc + q_einsum("btgf,gfd->btd", act, wd).astype(jnp.float32)
-    return acc.astype(h.dtype), stats

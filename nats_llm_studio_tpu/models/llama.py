@@ -320,17 +320,49 @@ def _moe_ffn(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
     """Mixtral top-k routed FFN, dense-dispatch form (every expert computes
     every token; routing weights zero the unused ones). Correct everywhere;
     the expert-parallel ``shard_map`` path in parallel/ replaces this on a
-    mesh with an ``expert`` axis."""
-    router_logits = (x @ p["router"]).astype(jnp.float32)  # [B,T,E]
-    top_w, top_idx = jax.lax.top_k(router_logits, cfg.n_experts_used)
-    top_w = jax.nn.softmax(top_w, axis=-1)  # normalize over the selected k
-    combine = jnp.sum(
-        jax.nn.one_hot(top_idx, cfg.n_experts, dtype=jnp.float32) * top_w[..., None], axis=-2
-    )  # dense combine weights [B,T,E]
-    gate = jax.nn.silu(q_einsum("btd,edf->btef", x, p["w_gate_e"]))
-    up = q_einsum("btd,edf->btef", x, p["w_up_e"])
-    expert_out = q_einsum("btef,efd->bted", gate * up, p["w_down_e"])
-    return jnp.einsum("bted,bte->btd", expert_out, combine.astype(x.dtype))
+    mesh with an ``expert`` axis. Called inside the layer's ``ffn`` scope."""
+    with jax.named_scope("router"):
+        router_logits = (x @ p["router"]).astype(jnp.float32)  # [B,T,E]
+        top_w, top_idx = jax.lax.top_k(router_logits, cfg.n_experts_used)
+        top_w = jax.nn.softmax(top_w, axis=-1)  # normalize over the selected k
+        combine = jnp.sum(
+            jax.nn.one_hot(top_idx, cfg.n_experts, dtype=jnp.float32) * top_w[..., None], axis=-2
+        )  # dense combine weights [B,T,E]
+    with jax.named_scope("experts"):
+        gate = jax.nn.silu(q_einsum("btd,edf->btef", x, p["w_gate_e"]))
+        up = q_einsum("btd,edf->btef", x, p["w_up_e"])
+        expert_out = q_einsum("btef,efd->bted", gate * up, p["w_down_e"])
+        return jnp.einsum("bted,bte->btd", expert_out, combine.astype(x.dtype))
+
+
+def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
+    with jax.named_scope("embed"):
+        return params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
+
+
+def _ffn_block(x: jax.Array, p: Params, cfg: ModelConfig, mesh, overlap: bool = False):
+    """The layer's second half: norm, the dense MLP or the experts, residual
+    add. ``overlap``: the tp ring form of the dense MLP (forward_decode_paged)."""
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, p["ffn_norm"], cfg.rms_eps, cfg.norm_plus_one)
+        if cfg.is_moe:
+            if cfg.use_routed_moe:
+                from ..parallel.moe import routed_moe_ffn
+
+                with jax.named_scope("experts"):
+                    ffn_out = routed_moe_ffn(h, p, cfg, mesh, cfg.moe_capacity_factor)
+            else:
+                ffn_out = _moe_ffn(h, p, cfg)
+        else:
+            with jax.named_scope("mlp"):
+                if overlap:
+                    from ..parallel.overlap import overlap_ffn
+
+                    ffn_out = overlap_ffn(h, p["w_gate"], p["w_up"], p["w_down"],
+                                          cfg.mlp_act, mesh)
+                else:
+                    ffn_out = swiglu(h, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act)
+        return x + ffn_out * cfg.residual_scale
 
 
 def forward(
@@ -382,39 +414,30 @@ def forward(
             ring_slot, logit_positions, fresh_prefill, uniform_start)
     b, t = tokens.shape
     s_max = k_cache.shape[3]
-    positions = start_pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # [B,T]
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
-    key_pos = jnp.arange(s_max, dtype=jnp.int32)
-    if t == 1 and ring_slot is not None:
-        # ring validity: slot j holds row b's token iff it is one of the
-        # start_pos+1 most recent ring slots (ending at ring_slot, wrapped)
-        age = jnp.mod(ring_slot - key_pos, s_max)  # [S]
-        mask = age[None, None, :] <= start_pos[:, None, None]  # [B,1,S]
-    else:
-        mask = key_pos[None, None, :] <= positions[:, :, None]  # [B,T,S]
+    with jax.named_scope("seq/attn"):  # the rotary tables and the mask, once a pass
+        positions = start_pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # [B,T]
+        cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
+        key_pos = jnp.arange(s_max, dtype=jnp.int32)
+        if t == 1 and ring_slot is not None:
+            # ring validity: slot j holds row b's token iff it is one of the
+            # start_pos+1 most recent ring slots (ending at ring_slot, wrapped)
+            age = jnp.mod(ring_slot - key_pos, s_max)  # [S]
+            mask = age[None, None, :] <= start_pos[:, None, None]  # [B,1,S]
+        else:
+            mask = key_pos[None, None, :] <= positions[:, :, None]  # [B,T,S]
 
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
+    x = _embed(params, cfg, tokens)
 
     def block_body(x, k_all, v_all, p, layer, allow_flash=True):
-        attn_out, k_all, v_all = _attention_block(
-            rms_norm(x, p["attn_norm"], cfg.rms_eps, cfg.norm_plus_one),
-            p, cfg, k_all, v_all, layer,
-            start_pos, cos, sin, mask, attn_window, allow_flash,
-            ring_slot if t == 1 else None, mesh, fresh_prefill, uniform_start,
-        )
-        x = x + attn_out * cfg.residual_scale
-        h = rms_norm(x, p["ffn_norm"], cfg.rms_eps, cfg.norm_plus_one)
-        if cfg.is_moe:
-            if cfg.use_routed_moe:
-                from ..parallel.moe import routed_moe_ffn
-
-                ffn_out = routed_moe_ffn(h, p, cfg, mesh, cfg.moe_capacity_factor)
-            else:
-                ffn_out = _moe_ffn(h, p, cfg)
-        else:
-            ffn_out = swiglu(h, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act)
-        x = x + ffn_out * cfg.residual_scale
-        return x, k_all, v_all
+        with jax.named_scope("seq/attn"):
+            attn_out, k_all, v_all = _attention_block(
+                rms_norm(x, p["attn_norm"], cfg.rms_eps, cfg.norm_plus_one),
+                p, cfg, k_all, v_all, layer,
+                start_pos, cos, sin, mask, attn_window, allow_flash,
+                ring_slot if t == 1 else None, mesh, fresh_prefill, uniform_start,
+            )
+            x = x + attn_out * cfg.residual_scale
+        return _ffn_block(x, p, cfg, mesh), k_all, v_all
 
     if cfg.decode_unroll and t == 1:
         # Unrolled decode: static layer indices make every cache access a
@@ -530,9 +553,10 @@ def forward_decode_paged(
         return out if moe_stats else out[:3]
     b, w = tokens.shape
     hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    positions = start_pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
+    with jax.named_scope("seq/attn"):
+        positions = start_pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
+        cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
+    x = _embed(params, cfg, tokens)
 
     # TP_OVERLAP: the row-sharded projections' all-reduce runs as a
     # ppermute ring (parallel/overlap.py) instead of one blocking psum —
@@ -548,46 +572,31 @@ def forward_decode_paged(
                    and cfg.n_kv_heads % mesh.shape.get(AXIS_TP, 1) == 0)
 
     def block_body(x, kp, vp, p, layer):
-        h = rms_norm(x, p["attn_norm"], cfg.rms_eps, cfg.norm_plus_one)
-        q = mm(h, p["wq"])
-        k = mm(h, p["wk"])
-        v = mm(h, p["wv"])
-        if cfg.attn_bias:
-            q = q + p["bq"]
-            k = k + p["bk"]
-            v = v + p["bv"]
-        q = apply_rope(q.reshape(b, w, hq, d), cos, sin)
-        k = apply_rope(k.reshape(b, w, hkv, d), cos, sin)
-        v = v.reshape(b, w, hkv, d)
-        kp = kv_pool_write_rows(kp, k, tbl, start_pos, layer)
-        vp = kv_pool_write_rows(vp, v, tbl, start_pos, layer)
-        out = _paged_attn_dispatch(q, kp, vp, tbl, start_pos, layer,
-                                   cfg.attn_scale, mesh)
-        attn_in = out.reshape(b, w, hq * d)
-        if overlap:
-            from ..parallel.overlap import overlap_row_proj
+        with jax.named_scope("seq/attn"):
+            h = rms_norm(x, p["attn_norm"], cfg.rms_eps, cfg.norm_plus_one)
+            q = mm(h, p["wq"])
+            k = mm(h, p["wk"])
+            v = mm(h, p["wv"])
+            if cfg.attn_bias:
+                q = q + p["bq"]
+                k = k + p["bk"]
+                v = v + p["bv"]
+            q = apply_rope(q.reshape(b, w, hq, d), cos, sin)
+            k = apply_rope(k.reshape(b, w, hkv, d), cos, sin)
+            v = v.reshape(b, w, hkv, d)
+            kp = kv_pool_write_rows(kp, k, tbl, start_pos, layer)
+            vp = kv_pool_write_rows(vp, v, tbl, start_pos, layer)
+            out = _paged_attn_dispatch(q, kp, vp, tbl, start_pos, layer,
+                                       cfg.attn_scale, mesh)
+            attn_in = out.reshape(b, w, hq * d)
+            if overlap:
+                from ..parallel.overlap import overlap_row_proj
 
-            proj = overlap_row_proj(attn_in, p["wo"], mesh)
-        else:
-            proj = mm(attn_in, p["wo"])
-        x = x + proj * cfg.residual_scale
-        hh = rms_norm(x, p["ffn_norm"], cfg.rms_eps, cfg.norm_plus_one)
-        if cfg.is_moe:
-            if cfg.use_routed_moe:
-                from ..parallel.moe import routed_moe_ffn
-
-                ffn_out = routed_moe_ffn(hh, p, cfg, mesh, cfg.moe_capacity_factor)
+                proj = overlap_row_proj(attn_in, p["wo"], mesh)
             else:
-                ffn_out = _moe_ffn(hh, p, cfg)
-        elif overlap:
-            from ..parallel.overlap import overlap_ffn
-
-            ffn_out = overlap_ffn(hh, p["w_gate"], p["w_up"], p["w_down"],
-                                  cfg.mlp_act, mesh)
-        else:
-            ffn_out = swiglu(hh, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act)
-        x = x + ffn_out * cfg.residual_scale
-        return x, kp, vp
+                proj = mm(attn_in, p["wo"])
+            x = x + proj * cfg.residual_scale
+        return _ffn_block(x, p, cfg, mesh, overlap), kp, vp
 
     def block(carry, inputs):
         x, kp, vp = carry
@@ -607,13 +616,14 @@ def lm_head_logits(params: Params, cfg: ModelConfig, x: jax.Array,
     """Shared output head (norm + lm_head, tied-embedding fallback,
     logit_positions gather): the dense forward and the pipeline-parallel
     forward (parallel/pipeline.py) must never diverge here."""
-    if logit_positions is not None and t > 1:
-        x = jnp.take_along_axis(x, logit_positions[:, None, None], axis=1)  # [B,1,d]
-    x = rms_norm(x, params["out_norm"], cfg.rms_eps, cfg.norm_plus_one)
-    lm_head = params.get("lm_head")
-    if lm_head is None:
-        lm_head = params["embed"].T
-    return mm(x, lm_head).astype(jnp.float32) * cfg.logit_scale
+    with jax.named_scope("head/logits"):
+        if logit_positions is not None and t > 1:
+            x = jnp.take_along_axis(x, logit_positions[:, None, None], axis=1)  # [B,1,d]
+        x = rms_norm(x, params["out_norm"], cfg.rms_eps, cfg.norm_plus_one)
+        lm_head = params.get("lm_head")
+        if lm_head is None:
+            lm_head = params["embed"].T
+        return mm(x, lm_head).astype(jnp.float32) * cfg.logit_scale
 
 
 def ensure_lm_head(params: Params) -> Params:

@@ -153,13 +153,19 @@ def hc_write(X: jax.Array, post: jax.Array, res: jax.Array, y: jax.Array) -> jax
 
 def _residual(X, p, which: str, cfg: ModelConfig, f):
     """One sublayer ("attn" or "ffn") around the streams: y = f(RMSNorm(H_pre
-    X)). ``f`` may return (y, aux); aux is passed through."""
-    pre, post, res = hc_maps(X, p[f"hc_{which}_w"], p[f"hc_{which}_a"],
-                             p[f"hc_{which}_b"], cfg)
-    u = rms_norm(hc_read(X, pre), p[f"{which}_norm"].astype(jnp.float32), cfg.rms_eps)
-    out = f(u.astype(jnp.dtype(cfg.dtype)))
+    X)). ``f`` may return (y, aux); aux is passed through. The maps, the read
+    and the write are the ``mix`` scope; the norm and ``f`` the sublayer's
+    (``seq/mla``, or ``ffn`` with ``f``'s own word inside it)."""
+    with jax.named_scope("mix"):
+        pre, post, res = hc_maps(X, p[f"hc_{which}_w"], p[f"hc_{which}_a"],
+                                 p[f"hc_{which}_b"], cfg)
+        u = hc_read(X, pre)
+    with jax.named_scope("seq/mla" if which == "attn" else "ffn"):
+        u = rms_norm(u, p[f"{which}_norm"].astype(jnp.float32), cfg.rms_eps)
+        out = f(u.astype(jnp.dtype(cfg.dtype)))
     y, aux = out if isinstance(out, tuple) else (out, None)
-    return hc_write(X, post, res, y), aux
+    with jax.named_scope("mix"):
+        return hc_write(X, post, res, y), aux
 
 
 # ---------------------------------------------------------------------------
@@ -259,16 +265,23 @@ def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
     token in 7 layers, and that noise is what flips a token's 4th and 5th
     expert against the reference. A sublayer's input is cast to ``cfg.dtype``
     after its norm, so every product runs as in the other families."""
-    x = params["embed"][tokens].astype(jnp.float32) * cfg.embedding_scale
-    return jnp.broadcast_to(x[None], (cfg.hc_mult,) + x.shape)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(jnp.float32) * cfg.embedding_scale
+        return jnp.broadcast_to(x[None], (cfg.hc_mult,) + x.shape)
 
 
 def _head(params: Params, cfg: ModelConfig, X, logit_positions, t: int) -> jax.Array:
     """The final norm and head read the SUM of the streams."""
     from .llama import lm_head_logits
 
-    x = jnp.sum(X, axis=0).astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("head/logits"):
+        x = jnp.sum(X, axis=0).astype(jnp.dtype(cfg.dtype))
     return lm_head_logits(params, cfg, x, logit_positions, t)
+
+
+def _mlp(h, p: Params, cfg: ModelConfig):
+    with jax.named_scope("mlp"):
+        return swiglu(h, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act)
 
 
 def _layers(params: Params, cfg: ModelConfig, X, caches, attention, live=None, mesh=None):
@@ -297,8 +310,7 @@ def _layers(params: Params, cfg: ModelConfig, X, caches, attention, live=None, m
             p, layer, place = inputs
             X, caches = _residual(X, p, "attn", cfg, lambda h: attention(h, p, caches, layer))
             if kind == "dense":
-                X, st = _residual(X, p, "ffn", cfg, lambda h: swiglu(
-                    h, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act))
+                X, st = _residual(X, p, "ffn", cfg, lambda h: _mlp(h, p, cfg))
             else:
                 X, st = _residual(X, p, "ffn", cfg, lambda h: moe_ffn(
                     h, p, cfg, live, form, whole, place))
@@ -331,7 +343,8 @@ def forward(
     s_max = k_cache.shape[3]
     win = attn_window if (attn_window is not None and attn_window < s_max) else s_max
     positions = start_pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    cos, sin = rope_tables(cfg, positions)
+    with jax.named_scope("seq/mla"):
+        cos, sin = rope_tables(cfg, positions)
     zero = jnp.zeros((), jnp.int32)
     X = _embed(params, cfg, tokens)
 
@@ -380,7 +393,8 @@ def forward_decode_paged(
 
     b, w = tokens.shape
     positions = start_pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-    cos, sin = rope_tables(cfg, positions)
+    with jax.named_scope("seq/mla"):
+        cos, sin = rope_tables(cfg, positions)
     live = (tbl[:, 0] > 0).astype(jnp.float32)
     X = _embed(params, cfg, tokens)
 

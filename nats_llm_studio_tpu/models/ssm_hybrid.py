@@ -240,6 +240,11 @@ def unpack_o(o: jax.Array, cfg: ModelConfig) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
+    with jax.named_scope("embed"):
+        return params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
+
+
 def _layers(params: Params, cfg: ModelConfig, x, carry, mamba, attention):
     """All layers in model order: one scan over the periods, and inside a
     period one scan over each run of layers of one kind (granite-4.0-h: 5
@@ -268,10 +273,12 @@ def _layers(params: Params, cfg: ModelConfig, x, carry, mamba, attention):
         p = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, layer, axis=0, keepdims=False),
             stacks[kind])
-        out, carry = mixers[kind](rms_norm(x, p["mix_norm"], cfg.rms_eps), p, carry, layer)
-        x = x + out * cfg.residual_scale
-        h = rms_norm(x, p["ffn_norm"], cfg.rms_eps)
-        x = x + swiglu(h, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act) * cfg.residual_scale
+        with jax.named_scope("seq/ssm" if kind == "mamba" else "seq/attn"):
+            out, carry = mixers[kind](rms_norm(x, p["mix_norm"], cfg.rms_eps), p, carry, layer)
+            x = x + out * cfg.residual_scale
+        with jax.named_scope("ffn/mlp"):
+            h = rms_norm(x, p["ffn_norm"], cfg.rms_eps)
+            x = x + swiglu(h, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act) * cfg.residual_scale
         return x, carry
 
     def period(c, i):
@@ -314,9 +321,10 @@ def forward(
     valid = (jnp.full((b,), t, jnp.int32) if logit_positions is None
              else jnp.clip(logit_positions.astype(jnp.int32) + 1, 0, t))
     zero = jnp.zeros((), jnp.int32)
-    key_pos = jnp.arange(t if fresh_prefill else win, dtype=jnp.int32)
-    mask = key_pos[None, None, :] <= positions[:, :, None]
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
+    with jax.named_scope("seq/attn"):
+        key_pos = jnp.arange(t if fresh_prefill else win, dtype=jnp.int32)
+        mask = key_pos[None, None, :] <= positions[:, :, None]
+    x = _embed(params, cfg, tokens)
     (tails, seen), (states,) = k_cache.st, v_cache.st
 
     def mamba(h, p, carry, layer):
@@ -355,7 +363,8 @@ def forward(
     logits = lm_head_logits(params, cfg, x, at, t)
     # a row with no real position here (its prompt ended in an earlier chunk
     # of its group) has consumed nothing more
-    seen = jnp.where(valid > 0, start_pos + valid, seen).astype(jnp.int32)
+    with jax.named_scope("seq/ssm"):
+        seen = jnp.where(valid > 0, start_pos + valid, seen).astype(jnp.int32)
     return logits, WithState(kc, (tails, seen), K_AXES), WithState(vc, (states,), V_AXES)
 
 
@@ -381,10 +390,11 @@ def forward_decode_paged(
     (tails, seen), (states,) = k_pool.st, v_pool.st
     # one list for all the layers of the step (and of the burst: ``tbl`` is
     # the launch's, and no step changes it)
-    live = ssm_scan.live_slots(table_rows_in_use(tbl))
-    fresh = live.mask & (start_pos >= seen)
+    with jax.named_scope("seq/ssm"):
+        live = ssm_scan.live_slots(table_rows_in_use(tbl))
+        fresh = live.mask & (start_pos >= seen)
     positions = start_pos[:, None]
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
+    x = _embed(params, cfg, tokens)
 
     def mamba(h, p, carry, layer):
         kp, vp, tails, states = carry
@@ -405,7 +415,8 @@ def forward_decode_paged(
     from .llama import lm_head_logits
 
     logits = lm_head_logits(params, cfg, x, None, w)
-    seen = jnp.where(fresh, start_pos + 1, seen).astype(jnp.int32)
+    with jax.named_scope("seq/ssm"):
+        seen = jnp.where(fresh, start_pos + 1, seen).astype(jnp.int32)
     return logits, WithState(kp, (tails, seen), K_AXES), WithState(vp, (states,), V_AXES)
 
 

@@ -279,18 +279,22 @@ def _layers(params: Params, cfg: ModelConfig, x, carry, attend, live=None, mesh=
     def one(c, kind, a_place, f_place, dense: bool):
         x, carry, stats = c
         pa = take(blocks[_ATTN[kind]], a_place)
-        out, carry = attend[kind](rms_norm(x, pa["attn_norm"], cfg.rms_eps), pa, carry, a_place)
-        x = x + out
+        with jax.named_scope("seq/attn" if kind == "full" else "seq/window"):
+            out, carry = attend[kind](rms_norm(x, pa["attn_norm"], cfg.rms_eps), pa, carry, a_place)
+            x = x + out
         pf = take(blocks["dense"] if dense else moe, f_place)
-        h = rms_norm(x, pf["ffn_norm"], cfg.rms_eps)
-        if dense:
-            y = swiglu(h, pf["w_gate"], pf["w_up"], pf["w_down"], cfg.mlp_act)
-        else:
-            y, st = moe_ffn(h, pf, cfg, live, form, whole, f_place)
-            if stats is not None:
-                stats = jax.lax.dynamic_update_slice(
-                    stats, st[None], (f_place, jnp.zeros((), jnp.int32)))
-        return x + y, carry, stats
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, pf["ffn_norm"], cfg.rms_eps)
+            if dense:
+                with jax.named_scope("mlp"):
+                    y = swiglu(h, pf["w_gate"], pf["w_up"], pf["w_down"], cfg.mlp_act)
+            else:
+                y, st = moe_ffn(h, pf, cfg, live, form, whole, f_place)
+                if stats is not None:
+                    with jax.named_scope("router"):  # the layer's counters, beside the others
+                        stats = jax.lax.dynamic_update_slice(
+                            stats, st[None], (f_place, jnp.zeros((), jnp.int32)))
+            return x + y, carry, stats
 
     c = (x, carry, stats)
     for kind, a_place, f_place in plan["leading"]:
@@ -317,7 +321,8 @@ def _layers(params: Params, cfg: ModelConfig, x, carry, attend, live=None, mesh=
 
 
 def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
-    return params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
+    with jax.named_scope("embed"):
+        return params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
 
 
 def forward(
@@ -344,14 +349,16 @@ def forward(
     positions = start_pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     valid = (jnp.full((b,), t, jnp.int32) if logit_positions is None
              else jnp.clip(logit_positions.astype(jnp.int32) + 1, 0, t))
-    tables = rope_tables(cfg, positions)
+    with jax.named_scope("seq"):  # both kinds' rotary tables
+        tables = rope_tables(cfg, positions)
     zero = jnp.zeros((), jnp.int32)
     flash = cfg.use_flash_attention and t > 1
     # the chunk kernel tiles the cache window: its extent must divide
     on_cache = flash and uniform_start and not fresh_prefill and (
         win % chunk_block_multiple(False, jnp.dtype(cfg.dtype).itemsize) == 0)
-    key_pos = jnp.arange(t if fresh_prefill else win, dtype=jnp.int32)
-    mask = key_pos[None, None, :] <= positions[:, :, None]
+    with jax.named_scope("seq/attn"):
+        key_pos = jnp.arange(t if fresh_prefill else win, dtype=jnp.int32)
+        mask = key_pos[None, None, :] <= positions[:, :, None]
     (rk,), (rv,) = k_cache.st, v_cache.st
 
     def full(h, p, carry, place):
@@ -423,11 +430,13 @@ def forward_decode_paged(
             "window-attention models decode one position a step: a speculative "
             "bundle would overwrite ring places that rejected drafts leave "
             "wrong, and the ring keeps nothing to go back to (SPEC_DECODE=0)")
-    tables = rope_tables(cfg, start_pos[:, None])
+    with jax.named_scope("seq"):  # both kinds' rotary tables
+        tables = rope_tables(cfg, start_pos[:, None])
     live = (tbl[:, 0] > 0).astype(jnp.float32)
-    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-    heads = jnp.arange(cfg.n_kv_heads, dtype=jnp.int32)[None, :]
-    at = jnp.mod(start_pos, cfg.window)[:, None]
+    with jax.named_scope("seq/window"):  # a row's place in its rings
+        rows = jnp.arange(b, dtype=jnp.int32)[:, None]
+        heads = jnp.arange(cfg.n_kv_heads, dtype=jnp.int32)[None, :]
+        at = jnp.mod(start_pos, cfg.window)[:, None]
     (rk,), (rv,) = k_pool.st, v_pool.st
 
     def full(h, p, carry, place):

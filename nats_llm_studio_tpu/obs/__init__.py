@@ -38,6 +38,8 @@ from .roofline import (
     classify_program,
     dispatch_shape_key,
     efficiency_enabled,
+    program_kind,
+    program_kinds,
 )
 from .trace import (
     STAGES,
@@ -75,6 +77,8 @@ __all__ = [
     "WASTE_CATEGORIES",
     "HbmLedger",
     "classify_program",
+    "program_kind",
+    "program_kinds",
     "dispatch_shape_key",
     "efficiency_enabled",
     "STAGES",

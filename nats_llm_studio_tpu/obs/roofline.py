@@ -30,6 +30,10 @@ __all__ = [
     "SPEC_PROGRAMS",
     "WASTE_CATEGORIES",
     "classify_program",
+    "table_kind",
+    "note_programs",
+    "program_kind",
+    "program_kinds",
     "program_base",
     "program_family",
     "efficiency_enabled",
@@ -139,6 +143,50 @@ def classify_program(name: str) -> str:
     if name in DECODE_PROGRAMS:
         return "decode"
     return "other"
+
+
+# -- the kinds of the jitted programs ------------------------------------------
+#
+# One program has three names: its table name (serve/programs.py, what
+# ``lmstudio_program_ms`` and the sets above key on), and its jitted
+# function's ``__name__``, under which a device trace (``jit_<name>``) and the
+# build ledger (``fun_name``) know it. The two are one word but for the Pallas
+# decode entries below; ``program_kind`` is the bridge, for a reader that has
+# the trace's name alone (tests/test_scopes.py holds it against every
+# family's table).
+
+_TABLE_NAME_OF = {
+    "decode_pos_pallas": "decode_pallas",
+    "decode_pos_moe": "decode_pallas",
+    "decode_pos_pallas_ext": "decode_pallas_ext",
+}
+_noted: dict[str, str] = {}
+
+
+def table_kind(name: str) -> str:
+    """``prefill`` (chunks, fused admits, finishes, the prefix copies),
+    ``decode``, ``spec`` (a verify: ``SPEC_PROGRAMS``, which the device-time
+    ledger counts under decode) or ``other``, of a table name."""
+    return "spec" if program_base(name) in SPEC_PROGRAMS else classify_program(name)
+
+
+def program_kind(jit_name: str) -> str:
+    """The kind of the program a device trace calls ``jit_<jit_name>``
+    (``other`` too for what no table holds: XLA's own small programs)."""
+    return table_kind(_TABLE_NAME_OF.get(jit_name, jit_name))
+
+
+def note_programs(table: dict[str, Callable]) -> None:
+    """The jitted programs of a ``build_programs`` table, for the worker's
+    page (``lmstudio_program_kind``); idempotent."""
+    for name, fn in table.items():
+        jit_name = getattr(fn, "__name__", name)
+        _noted[jit_name] = program_kind(jit_name)
+
+
+def program_kinds() -> dict[str, str]:
+    """{jit name: kind} of the programs this process built."""
+    return dict(_noted)
 
 
 def efficiency_enabled() -> bool:

@@ -1,4 +1,6 @@
-"""Host spans on the device trace's clock, and the one ring that keeps them.
+"""Host spans on the device trace's clock, the one ring that keeps them, and
+the names inside the jitted programs (``SCOPE_NAMES``, further down: the other
+half of one vocabulary, on the device's side of the same trace).
 
 A span does two things and nothing else:
 
@@ -33,6 +35,48 @@ SPAN_NAMES = (
     "batcher.deliver",     # the row loop after a readback, through _deliver and req.emit
     "worker.publish",      # serialising a chunk + await nc.publish on the loop thread
 )
+
+# every ``jax.named_scope`` the four model files, ``models/experts.py`` and
+# the step programs open, as the path it leaves in an operation's ``op_name``
+# (``jit(decode_pos_pallas)/while/body/closed_call/seq/attn/dot_general``).
+# Two levels at most, the same words in every family. A top-level word is
+# opened in the layer body, around the norm that feeds the block and the
+# residual add that takes it; a second-level word inside it. What lies under
+# none of them is glue (the readers' word, not a scope): layout copies on
+# entry to a program, casts, table and position updates, scan plumbing, the
+# pool writes of the admits. A scope is metadata on the lowered operations: it
+# changes no operation of any compiled program (tests/test_scopes.py)
+SCOPE_NAMES = (
+    "embed",         # token embedding: the residual stream's (or streams') start
+    "seq",           # everything that mixes positions, with the norm before and the add after
+    "seq/attn",      # GQA: q/k/v/o, rotary, the KV write, the paged / flash kernel or its XLA form
+    "seq/window",    # the same over a ring of the last ``window`` keys (models/swa_moe.py)
+    "seq/mla",       # latent attention: down and up projections, the latent write, absorbed or expanded
+    "seq/ssm",       # Mamba-2: in-projection, convolution, scan or ssm_state_step, gated norm, out
+    "ffn",           # the position-wise block, with the norm before and the add after
+    "ffn/mlp",       # the dense SwiGLU
+    "ffn/router",    # scores, top-k, gates, the expert counters
+    "ffn/experts",   # the routed experts in every form (hit_list, grouped, dense)
+    "ffn/shared",    # the always-on expert(s)
+    "mix",           # the four-stream maps, read, write and hc_sinkhorn of models/mla_moe.py
+    "head",          # what turns the last hidden state into a token
+    "head/logits",   # final norm and lm_head (a prefill's: one row a prompt)
+    "head/sample",   # temperature, top-k, top-p, seeds, acceptance, the token write
+)
+_SCOPE_TOPS = frozenset(s for s in SCOPE_NAMES if "/" not in s)
+
+
+def scope_of(op_name: str) -> str | None:
+    """The entry of ``SCOPE_NAMES`` an operation's ``op_name`` lies under:
+    its first top-level word and, where the next word makes a listed path
+    with it, that path. None for glue."""
+    parts = op_name.split("/")
+    for i, word in enumerate(parts):
+        if word in _SCOPE_TOPS:
+            path = f"{word}/{parts[i + 1]}" if i + 1 < len(parts) else word
+            return path if path in SCOPE_NAMES else word
+    return None
+
 
 # ~200 records a second at 8 slots and a 50 ms burst (5 owner-thread spans a
 # burst, a publish a stream): ten minutes. A traced benchmark run reads its

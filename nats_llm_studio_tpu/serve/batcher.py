@@ -47,6 +47,7 @@ from ..obs.roofline import (
     classify_program,
     dispatch_shape_key,
     efficiency_enabled,
+    note_programs,
     program_base,
 )
 from ..transport import faults as _faults
@@ -1009,10 +1010,12 @@ class ContinuousBatcher:
 
         # the device programs (serve/programs.py), each behind the dispatch
         # timer as ``self._<table name>``
-        for name, fn in build_programs(
+        table = build_programs(
             cfg, mesh, max_seq=self.max_seq, paged=self.paged,
             kv_block_tokens=self.kv_block_tokens, sample_rows=sample_rows,
-        ).items():
+        )
+        note_programs(table)  # the page's lmstudio_program_kind lines
+        for name, fn in table.items():
             setattr(self, "_" + name, self._timed(name, fn))
         # per-dispatch ``_name=`` override: the ``_ring`` tag of a prefill
         # whose width takes the sp ring-attention path

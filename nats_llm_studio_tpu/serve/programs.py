@@ -11,7 +11,15 @@ a scheduler edit from re-keying them.
 
 The table's keys are the names dispatches are recorded under
 (``lmstudio_program_ms{program=...}``); ``recorded_name`` and ``ring_name``
-add the ``_moe`` / ``_ring`` family tags.
+add the ``_moe`` / ``_ring`` family tags. A device trace and the build ledger
+know a program by its jitted function's ``__name__`` instead
+(``decode_pos_pallas`` for the table's ``decode_pallas``): obs/roofline.py
+``program_kind`` is the bridge, by the name alone (``_TABLE_NAME_OF`` holds
+the entries whose function is named otherwise: tests/test_scopes.py).
+
+Inside the programs, what draws and writes a token lies under the scope
+``head/sample`` (obs/spans.py ``SCOPE_NAMES``; the model files open the
+others); pool and ring writes, rolls and table slices are glue.
 """
 
 from __future__ import annotations
@@ -106,6 +114,37 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
     to show that ``correct`` catches a wrong token."""
     fwd = partial(forward, cfg=cfg, mesh=mesh)
 
+    def draw(*args, **kw):
+        with jax.named_scope("head/sample"):
+            return sample_rows(*args, **kw)
+
+    def put_token(tok, first, slot):
+        """A row's first token into the device-resident next-token carry."""
+        with jax.named_scope("head/sample"):
+            return jax.lax.dynamic_update_slice(tok, first, (slot,))
+
+    def draw_ext(logits, seeds, steps, temp, topk, topp, mask):
+        """The masked step of the "ext" programs: the token, its log
+        probability and the top ``LOGPROBS_K`` (id, logprob) pairs."""
+        with jax.named_scope("head/sample"):
+            raw = logits[:, -1, :]
+            nxt = sample_rows(raw, seeds, steps, temp, topk, topp, mask=mask)
+            logp = jax.nn.log_softmax(raw.astype(jnp.float32), axis=-1)
+            chosen = jnp.take_along_axis(logp, nxt[:, None], axis=-1)[:, 0]
+            kk = min(LOGPROBS_K, raw.shape[-1])
+            top_lp, top_ids = jax.lax.top_k(logp, kk)
+            return nxt, chosen, top_ids, top_lp
+
+    def accept(logits, drafts, dlen, seeds, steps, temp, topk, topp):
+        """A verify's acceptance rule: the emitted tokens, their count and
+        the next carry token."""
+        with jax.named_scope("head/sample"):
+            out, n_emit = spec_accept_rows(
+                logits, drafts, dlen, seeds, steps, temp, topk, topp
+            )
+            new_tok = jnp.take_along_axis(out, (n_emit - 1)[:, None], axis=1)[:, 0]
+            return out, n_emit, new_tok
+
     # -- explicit cache shardings (tensor-parallel serving) --------------
     # With a mesh, the serving K/V ring arrives in every jit already
     # sharded (heads on tp — shard_cache in _run), but values *created
@@ -190,11 +229,11 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
         v1 = kv_roll_s(v1, shift, s_axis=3)
         K = pin_cache(kv_copy_slice(K, k1, (slot, zero, zero, zero, zero)))
         V = pin_cache(kv_copy_slice(V, v1, (slot, zero, zero, zero, zero)))
-        first = sample_rows(
+        first = draw(
             logits[:, 0], seed[None], jnp.zeros((1,), jnp.int32),
             temp[None], topk[None], topp[None],
         )
-        tok = jax.lax.dynamic_update_slice(tok, first, (slot,))
+        tok = put_token(tok, first, slot)
         return first, K, V, tok
 
     @partial(jax.jit, donate_argnums=(1, 2, 3))
@@ -244,7 +283,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             fresh_prefill=True,
         )
         zero = jnp.zeros((), jnp.int32)
-        firsts = sample_rows(
+        firsts = draw(
             logits[:, 0], seeds, jnp.zeros((m,), jnp.int32), temps, topks, topps
         )
 
@@ -253,9 +292,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             k1, v1 = row_of(km, i), row_of(vm, i)
             K = kv_copy_slice(K, k1, (slots[i], zero, zero, offsets[i], zero))
             V = kv_copy_slice(V, v1, (slots[i], zero, zero, offsets[i], zero))
-            tok = jax.lax.dynamic_update_slice(
-                tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1), (slots[i],)
-            )
+            tok = put_token(
+                tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1), slots[i])
             return (K, V, tok), None
 
         (K, V, tok), _ = jax.lax.scan(
@@ -333,7 +371,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
         configs whose real peak fits comfortably."""
         m = final_logits.shape[0]
         zero = jnp.zeros((), jnp.int32)
-        firsts = sample_rows(
+        firsts = draw(
             final_logits[:, 0], seeds, jnp.zeros((m,), jnp.int32),
             temps, topks, topps,
         )
@@ -344,9 +382,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             v1 = kv_roll_s(row_of(vm, i), shifts[i], s_axis=3)
             K = kv_copy_slice(K, k1, (slots[i], zero, zero, zero, zero))
             V = kv_copy_slice(V, v1, (slots[i], zero, zero, zero, zero))
-            tok = jax.lax.dynamic_update_slice(
-                tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1), (slots[i],)
-            )
+            tok = put_token(
+                tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1), slots[i])
             return (K, V, tok), None
 
         (K, V, tok), _ = jax.lax.scan(
@@ -387,7 +424,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                 start_pos=pos + i, ring_slot=(ring + i) % max_seq,
                 attn_window=window,
             )
-            nxt = sample_rows(logits[:, -1, :], seeds, steps + i, temp, topk, topp)
+            nxt = draw(logits[:, -1, :], seeds, steps + i, temp, topk, topp)
             return (nxt, K, V), nxt
 
         (tok, K, V), toks = jax.lax.scan(
@@ -411,7 +448,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                 params, tokens=tok[:, None], k_cache=K, v_cache=V,
                 start_pos=pos + i, attn_window=window,
             )
-            nxt = sample_rows(logits[:, -1, :], seeds, steps + i, temp, topk, topp)
+            nxt = draw(logits[:, -1, :], seeds, steps + i, temp, topk, topp)
             return (nxt, K, V), nxt
 
         (tok, K, V), toks = jax.lax.scan(
@@ -433,12 +470,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             params, tokens=tok[:, None], k_cache=pin_cache(K),
             v_cache=pin_cache(V), start_pos=pos, attn_window=window,
         )
-        raw = logits[:, -1, :]
-        nxt = sample_rows(raw, seeds, steps, temp, topk, topp, mask=mask)
-        logp = jax.nn.log_softmax(raw.astype(jnp.float32), axis=-1)
-        chosen = jnp.take_along_axis(logp, nxt[:, None], axis=-1)[:, 0]
-        kk = min(LOGPROBS_K, raw.shape[-1])
-        top_lp, top_ids = jax.lax.top_k(logp, kk)
+        nxt, chosen, top_ids, top_lp = draw_ext(
+            logits, seeds, steps, temp, topk, topp, mask)
         return (nxt, chosen, top_ids, top_lp, pin_cache(K), pin_cache(V),
                 nxt, pos + 1, steps + 1)
 
@@ -460,10 +493,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             start_pos=pos, attn_window=window,
         )
         K, V = pin_cache(K), pin_cache(V)
-        out, n_emit = spec_accept_rows(
-            logits, drafts, dlen, seeds, steps, temp, topk, topp
-        )
-        new_tok = jnp.take_along_axis(out, (n_emit - 1)[:, None], axis=1)[:, 0]
+        out, n_emit, new_tok = accept(
+            logits, drafts, dlen, seeds, steps, temp, topk, topp)
         width = toks_in.shape[1]
         return out, n_emit, K, V, new_tok, pos + n_emit, steps + width
 
@@ -511,22 +542,22 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             table already references the cached blocks, so all that is
             left on device is sampling token 0 from the stored
             prompt-end logits into the carry."""
-            first = sample_rows(
+            first = draw(
                 logits[:, 0], seed[None], jnp.zeros((1,), jnp.int32),
                 temp[None], topk[None], topp[None],
             )
-            tok = jax.lax.dynamic_update_slice(tok, first, (slot,))
+            tok = put_token(tok, first, slot)
             return first, tok
 
         def _write_and_sample(KP, VP, tok, k1, v1, logits, bids, slot,
                               seed, temp, topk, topp):
             KP = pin_pool(pool_write(KP, k1, bids, slot))
             VP = pin_pool(pool_write(VP, v1, bids, slot))
-            first = sample_rows(
+            first = draw(
                 logits[:, 0], seed[None], jnp.zeros((1,), jnp.int32),
                 temp[None], topk[None], topp[None],
             )
-            tok = jax.lax.dynamic_update_slice(tok, first, (slot,))
+            tok = put_token(tok, first, slot)
             return first, KP, VP, tok
 
         @partial(jax.jit, donate_argnums=(1, 2, 3))
@@ -566,7 +597,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                 fresh_prefill=True,
             )
             zero = jnp.zeros((), jnp.int32)
-            firsts = sample_rows(
+            firsts = draw(
                 logits[:, 0], seeds, jnp.zeros((m,), jnp.int32), temps,
                 topks, topps,
             )
@@ -575,10 +606,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                 k1, v1 = row_of(km, i), row_of(vm, i)
                 KP = pool_write(KP, k1, bids[i], slots[i])
                 VP = pool_write(VP, v1, bids[i], slots[i])
-                tok = jax.lax.dynamic_update_slice(
-                    tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1),
-                    (slots[i],),
-                )
+                tok = put_token(
+                    tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1), slots[i])
                 return (KP, VP, tok), None
 
             (KP, VP, tok), _ = jax.lax.scan(
@@ -608,7 +637,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
             """Batched chunked tail, paged. km/vm NOT donated — same
             AOT double-count reasoning as finish_admit_group."""
             m = final_logits.shape[0]
-            firsts = sample_rows(
+            firsts = draw(
                 final_logits[:, 0], seeds, jnp.zeros((m,), jnp.int32),
                 temps, topks, topps,
             )
@@ -618,10 +647,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                 k1, v1 = row_of(km, i), row_of(vm, i)
                 KP = pool_write(KP, k1, bids[i], slots[i])
                 VP = pool_write(VP, v1, bids[i], slots[i])
-                tok = jax.lax.dynamic_update_slice(
-                    tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1),
-                    (slots[i],),
-                )
+                tok = put_token(
+                    tok, jax.lax.dynamic_slice_in_dim(firsts, i, 1), slots[i])
                 return (KP, VP, tok), None
 
             (KP, VP, tok), _ = jax.lax.scan(
@@ -674,7 +701,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                     params, tokens=tok[:, None], k_cache=Kc, v_cache=Vc,
                     start_pos=pos + i,
                 )
-                nxt = sample_rows(
+                nxt = draw(
                     logits[:, -1, :], seeds, steps + i, temp, topk, topp
                 )
                 return (nxt, Kc, Vc), nxt
@@ -700,13 +727,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                 params, tokens=tok[:, None], k_cache=Kv, v_cache=Vv,
                 start_pos=pos,
             )
-            raw = logits[:, -1, :]
-            nxt = sample_rows(raw, seeds, steps, temp, topk, topp,
-                              mask=mask)
-            logp = jax.nn.log_softmax(raw.astype(jnp.float32), axis=-1)
-            chosen = jnp.take_along_axis(logp, nxt[:, None], axis=-1)[:, 0]
-            kk = min(LOGPROBS_K, raw.shape[-1])
-            top_lp, top_ids = jax.lax.top_k(logp, kk)
+            nxt, chosen, top_ids, top_lp = draw_ext(
+                logits, seeds, steps, temp, topk, topp, mask)
             vb = _touched(pos, 1, nb)
             KP = pin_pool(kv_pool_scatter_view(KP, Kv, tbl_n, vb))
             VP = pin_pool(kv_pool_scatter_view(VP, Vv, tbl_n, vb))
@@ -728,12 +750,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                 params, tokens=toks_in, k_cache=Kv, v_cache=Vv,
                 start_pos=pos,
             )
-            out, n_emit = spec_accept_rows(
-                logits, drafts, dlen, seeds, steps, temp, topk, topp
-            )
-            new_tok = jnp.take_along_axis(
-                out, (n_emit - 1)[:, None], axis=1
-            )[:, 0]
+            out, n_emit, new_tok = accept(
+                logits, drafts, dlen, seeds, steps, temp, topk, topp)
             width = toks_in.shape[1]
             vb = _touched(pos, width, nb)
             KP = pin_pool(kv_pool_scatter_view(KP, Kv, tbl_n, vb))
@@ -776,7 +794,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                     params, tokens=tok[:, None], k_pool=KP, v_pool=VP,
                     tbl=tbl, start_pos=pos + i, moe_stats=True,
                 )
-                nxt = sample_rows(
+                nxt = draw(
                     logits[:, -1, :], seeds, steps + i, temp, topk, topp
                 )
                 return (nxt, KP, VP), (nxt, st.reshape(-1))
@@ -799,7 +817,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                     params, tokens=tok[:, None], k_pool=KP, v_pool=VP,
                     tbl=tbl, start_pos=pos + i,
                 )
-                nxt = sample_rows(
+                nxt = draw(
                     logits[:, -1, :], seeds, steps + i, temp, topk, topp
                 )
                 return (nxt, KP, VP), nxt
@@ -819,13 +837,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                 params, tokens=tok[:, None], k_pool=KP, v_pool=VP,
                 tbl=tbl, start_pos=pos,
             )
-            raw = logits[:, -1, :]
-            nxt = sample_rows(raw, seeds, steps, temp, topk, topp,
-                              mask=mask)
-            logp = jax.nn.log_softmax(raw.astype(jnp.float32), axis=-1)
-            chosen = jnp.take_along_axis(logp, nxt[:, None], axis=-1)[:, 0]
-            kk = min(LOGPROBS_K, raw.shape[-1])
-            top_lp, top_ids = jax.lax.top_k(logp, kk)
+            nxt, chosen, top_ids, top_lp = draw_ext(
+                logits, seeds, steps, temp, topk, topp, mask)
             return (nxt, chosen, top_ids, top_lp, pin_pool(KP),
                     pin_pool(VP), nxt, pos + 1, steps + 1)
 
@@ -841,12 +854,8 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
                 params, tokens=toks_in, k_pool=KP, v_pool=VP,
                 tbl=tbl, start_pos=pos,
             )
-            out, n_emit = spec_accept_rows(
-                logits, drafts, dlen, seeds, steps, temp, topk, topp
-            )
-            new_tok = jnp.take_along_axis(
-                out, (n_emit - 1)[:, None], axis=1
-            )[:, 0]
+            out, n_emit, new_tok = accept(
+                logits, drafts, dlen, seeds, steps, temp, topk, topp)
             width = toks_in.shape[1]
             return (out, n_emit, pin_pool(KP), pin_pool(VP), new_tok,
                     pos + n_emit, steps + width)

@@ -46,6 +46,7 @@ from ..obs import (
     new_span_id,
     new_trace_id,
     parse_span_context,
+    program_kinds,
     span_context_value,
 )
 from ..transport.client import Msg, NatsClient, connect
@@ -1774,6 +1775,12 @@ class Worker:
                       labels={"kind": kind},
                       help="seconds this process spent building programs "
                            "(trace, lower, compile; cache_load lies inside compile)")
+        # a device trace (lmstudio.profile) names a program by its jitted
+        # function, this page by its table name: the kinds are the bridge
+        for program, kind in sorted(program_kinds().items()):
+            r.gauge("lmstudio_program_kind", 1, labels={"program": program, "kind": kind},
+                    help="what a device trace's module jit_<program> is: "
+                         "prefill, decode, spec or other")
         # fault-tolerance families — ALWAYS present (zero-valued when
         # nothing has failed) so dashboards and the chaos tests can assert
         # their existence, not just their increments
