@@ -11,19 +11,152 @@ model weights). Instead:
 * greedy and unrestricted temperature sampling use ``argmax`` /
   Gumbel-max over the full vocab — exact, no sort;
 * top-k / top-p restricted rows draw from the top ``CANDIDATES`` logits
-  (``lax.top_k``, cheap at fixed small k). top-k above the cap and top-p
+  (``top_candidates``, cheap at fixed small k). top-k above the cap and top-p
   nuclei wider than the cap are truncated to the cap — for peaked LLM
   distributions the mass beyond the top 64 is negligible, and serving
   engines routinely apply the same candidate cap.
+
+What a row costs in ``sample_rows`` (the served path), by what it asks for:
+
+* a greedy row (temperature <= 0): one pass over its logits, the candidates'
+  first id where a restricted row shares the batch, one ``argmax`` where none;
+* a restricted row (0 < top_k < V or top_p < 1): one streaming pass for the
+  maxima of its groups of 128 logits, then ``top_k`` over a thousand values
+  three times (``top_candidates``: the maxima, the maxima of the groups of 16
+  in the 64 groups kept, the 1,024 logits left), and 64 Gumbel values: the
+  noise is computed at the candidates' ids alone (``gumbel_at``), bit for
+  bit what the whole-vocabulary draw holds there;
+* an unrestricted sampled row (temperature > 0, no top-k, top_p 1): V Gumbel
+  values and an ``argmax`` over V, run (``lax.cond``) only on a step whose
+  batch holds such a row; the candidates only on a step that holds a
+  restricted sampled row.
+
+A row's token depends on its own inputs alone, whichever branches its batch
+ran, and is the token ``_pick`` gives over the row's whole draw: a request
+replayed under its seed keeps its completion.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.extend.random import threefry_2x32
 
 CANDIDATES = 64  # static candidate cap for restricted (top-k/top-p) rows
+# the candidates' funnel keeps the CANDIDATES groups of largest maximum, of
+# 128 consecutive ids (a vector of lanes), then of 16 among those; a stage is
+# skipped where it would keep the whole row. On a v5e at 16 x 100,352, beside
+# the 18 us a pass over the rows costs: lax.top_k over the row 327 us, one
+# stage 98 us, both 43 us (PERF.md section 6, PR 43)
+FUNNEL = (128, 16)
 _NEG_INF = jnp.float32(-jnp.inf)
+
+
+def _at(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``take_along_axis(table, idx, -1)`` for a table of a few dozen columns,
+    as a compare and a sum: a gather of a thousand scalars costs the TPU more
+    than the candidates' whole softmax."""
+    hit = idx[:, :, None] == jnp.arange(table.shape[1])[None, None, :]
+    return jnp.sum(jnp.where(hit, table[:, None, :], 0), axis=-1)
+
+
+def top_candidates(logits: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``lax.top_k(logits, min(CANDIDATES, V))`` over the last axis, value for
+    value and id for id, through a funnel where the vocabulary is wide: the
+    row is cut into groups of consecutive ids, the C groups of largest maximum
+    are kept in id order (``top_k`` over the maxima, one streaming pass to
+    make them), and the same is done to what is left with narrower groups;
+    ``top_k`` then sorts 1,024 logits and not 100,352. Each of the C largest
+    logits lies in one of the C groups of largest maximum (a group ahead of
+    its own holds a logit ahead of it), and every stage gives equals to the
+    lower id, as ``lax.top_k`` does: kept groups stay in id order, so a lower
+    position is a lower id. The shape alone decides the stages."""
+    b, v = logits.shape
+    c = min(CANDIDATES, v)
+    stages = [g for g in FUNNEL if v > c * g]
+    if not stages:
+        return jax.lax.top_k(logits, c)
+    pool = jnp.pad(logits, ((0, 0), (0, -v % stages[0])), constant_values=_NEG_INF)
+    # position p of the pool holds id starts[p // run] + p % run
+    starts, run = jnp.zeros((b, 1), jnp.int32), pool.shape[1]
+    for g in stages:
+        grouped = pool.reshape(b, -1, g)
+        _, kept = jax.lax.top_k(grouped.max(axis=-1), c)
+        kept = jnp.sort(kept, axis=-1)
+        pool = jnp.take_along_axis(grouped, kept[:, :, None], axis=1).reshape(b, c * g)
+        starts, run = _at(starts, kept // (run // g)) + kept % (run // g) * g, g
+    cand, at = jax.lax.top_k(pool, c)
+    return cand, _at(starts, at // run) + at % run
+
+
+def require_partitionable_threefry() -> None:
+    """``gumbel_at`` reproduces ONE layout of ``jax.random.gumbel``'s bits:
+    element i of a draw is ``threefry_2x32(key, (0, i))``. With
+    ``jax_threefry_partitionable`` off, or the high-dynamic-range Gumbel on,
+    the whole draw is laid out otherwise and a replayed request would draw
+    other tokens: refuse to build a program then."""
+    cfg = jax.config
+    if (not cfg.jax_threefry_partitionable or cfg.jax_default_prng_impl != "threefry2x32"
+            or cfg.jax_high_dynamic_range_gumbel):
+        raise RuntimeError("the sampler needs jax_threefry_partitionable, threefry2x32 keys "
+                           "and the low-dynamic-range Gumbel draw: another layout of the "
+                           "draw's bits would change the tokens a seed gives")
+
+
+def gumbel_at(key: jax.Array, ids: jax.Array) -> jax.Array:
+    """``jax.random.gumbel(key, (V,), float32)[ids]`` for any V above the ids,
+    bit for bit, at the cost of ``ids.size`` values: the bits of element i are
+    the two words of ``threefry_2x32(key, (0, i))`` xor'd, and the rest is
+    ``jax.random``'s own arithmetic (``_uniform`` over [tiny, 1), then
+    ``-log(-log(u))``), operation for operation."""
+    require_partitionable_threefry()
+    lo = ids.astype(jnp.uint32).ravel()
+    words = threefry_2x32(jax.random.key_data(key), jnp.concatenate([jnp.zeros_like(lo), lo]))
+    bits = (words[: lo.size] ^ words[lo.size:]).reshape(ids.shape)
+    info = jnp.finfo(jnp.float32)
+    one = np.float32(1.0)
+    mantissa = jax.lax.shift_right_logical(bits, jnp.uint32(info.bits - info.nmant))
+    floats = jax.lax.bitcast_convert_type(mantissa | one.view(np.uint32), jnp.float32) - one
+    u = jnp.maximum(info.tiny, floats * (one - info.tiny) + info.tiny)
+    return -jnp.log(-jnp.log(u))
+
+
+def _params(b, temperature, top_k, top_p):
+    temperature = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (b,))
+    top_k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (b,))
+    top_p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (b,))
+    return temperature, top_k, top_p, jnp.maximum(temperature, 1e-6)[:, None]
+
+
+def _candidate_pick(cand, cand_idx, g_cand, safe_t, top_k, top_p) -> jax.Array:
+    """A restricted row's draw among its candidates (sorted desc, [B, C]),
+    ``g_cand`` the Gumbel noise at their ids."""
+    c = cand.shape[-1]
+    ranks = jnp.arange(c)[None, :]
+    k_eff = jnp.where(top_k <= 0, c, jnp.minimum(top_k, c))[:, None]
+    keep = ranks < k_eff
+    # top-p over the candidate softmax; always keep the first token that
+    # crosses p (so the nucleus is never empty)
+    probs = jax.nn.softmax(cand / safe_t, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep &= (cum - probs) < top_p[:, None]
+    masked = jnp.where(keep, cand / safe_t, _NEG_INF)
+    drawn = jnp.argmax(masked + g_cand, axis=-1)
+    return jnp.take_along_axis(cand_idx, drawn[:, None], axis=-1)[:, 0]
+
+
+def _restricted(top_k, top_p, v):
+    return ((top_k > 0) & (top_k < v)) | (top_p < 1.0)
+
+
+def row_class(temperature: float, top_k: int, top_p: float, vocab_size: int) -> str:
+    """What a row asks of ``sample_rows``, on the host: "greedy", "restricted"
+    (it picks among its candidates) or "unrestricted" (it needs the
+    whole-vocabulary draw)."""
+    if temperature <= 0.0:
+        return "greedy"
+    return "restricted" if _restricted(top_k, top_p, vocab_size) else "unrestricted"
 
 
 def _pick(logits, gumbel, temperature, top_k, top_p, mask=None) -> jax.Array:
@@ -38,32 +171,17 @@ def _pick(logits, gumbel, temperature, top_k, top_p, mask=None) -> jax.Array:
     if mask is not None:
         logits = jnp.where(mask, logits, _NEG_INF)
     b, v = logits.shape
-    temperature = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (b,))
-    top_k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (b,))
-    top_p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (b,))
-    safe_t = jnp.maximum(temperature, 1e-6)[:, None]
+    temperature, top_k, top_p, safe_t = _params(b, temperature, top_k, top_p)
 
     greedy = jnp.argmax(logits, axis=-1)
     # exact unrestricted sampling: argmax(logits/T + G) ~ softmax(logits/T)
     full_pick = jnp.argmax(logits / safe_t + gumbel, axis=-1)
 
-    c = min(CANDIDATES, v)
-    cand, cand_idx = jax.lax.top_k(logits, c)  # sorted desc [B, C]
-    ranks = jnp.arange(c)[None, :]
-    k_eff = jnp.where(top_k <= 0, c, jnp.minimum(top_k, c))[:, None]
-    keep = ranks < k_eff
-    # top-p over the candidate softmax; always keep the first token that
-    # crosses p (so the nucleus is never empty)
-    probs = jax.nn.softmax(cand / safe_t, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep &= (cum - probs) < top_p[:, None]
+    cand, cand_idx = top_candidates(logits)  # sorted desc [B, C]
     g_cand = jnp.take_along_axis(gumbel, cand_idx, axis=-1)
-    masked = jnp.where(keep, cand / safe_t, _NEG_INF)
-    drawn = jnp.argmax(masked + g_cand, axis=-1)
-    cand_pick = jnp.take_along_axis(cand_idx, drawn[:, None], axis=-1)[:, 0]
+    cand_pick = _candidate_pick(cand, cand_idx, g_cand, safe_t, top_k, top_p)
 
-    restricted = ((top_k > 0) & (top_k < v)) | (top_p < 1.0)
-    pick = jnp.where(restricted, cand_pick, full_pick)
+    pick = jnp.where(_restricted(top_k, top_p, v), cand_pick, full_pick)
     return jnp.where(temperature <= 0.0, greedy, pick).astype(jnp.int32)
 
 
@@ -76,7 +194,9 @@ def sample(
     mask: jax.Array | None = None,  # [B, V] bool — False bans the token
 ) -> jax.Array:
     """Returns sampled token ids [B] int32. temperature <= 0 means greedy
-    (per row). top-k and top-p are per-row arrays, not static."""
+    (per row). top-k and top-p are per-row arrays, not static. One key for
+    the batch and every path for every row (``_pick`` over a whole draw):
+    the reference loops' sampler, not the served path's."""
     gumbel = jax.random.gumbel(key, logits.shape, jnp.float32)
     return _pick(logits, gumbel, temperature, top_k, top_p, mask=mask)
 
@@ -93,14 +213,44 @@ def sample_rows(
     """Per-row deterministic sampling: row i's randomness depends only on
     (seeds[i], steps[i]), never on batch composition — a request replayed
     with the same seed reproduces its completion regardless of what else is
-    running in the continuous batch."""
+    running in the continuous batch.
 
-    def row_gumbel(seed, step):
-        k = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-        return jax.random.gumbel(k, (logits.shape[1],), jnp.float32)
+    Row i's token is ``_pick``'s over the row's whole draw
+    ``jax.random.gumbel(fold_in(PRNGKey(seeds[i]), steps[i]), (V,))``; what
+    is computed follows what the batch's rows ask for (module docstring):
+    the candidates and their noise under one ``lax.cond`` on "a restricted
+    sampled row is here", the whole draw under one on "an unrestricted
+    sampled row is here". Not for use under ``vmap``, which would turn the
+    conditionals into selects of both branches."""
+    if mask is not None:
+        logits = jnp.where(mask, logits, _NEG_INF)
+    b, v = logits.shape
+    temperature, top_k, top_p, safe_t = _params(b, temperature, top_k, top_p)
+    keys = jax.vmap(lambda seed, step: jax.random.fold_in(jax.random.PRNGKey(seed), step))(
+        seeds, steps)
+    sampled = ~(temperature <= 0.0)
+    restricted = _restricted(top_k, top_p, v)
+    nobody = jnp.zeros((b,), jnp.int32)
 
-    gumbel = jax.vmap(row_gumbel)(seeds, steps)
-    return _pick(logits, gumbel, temperature, top_k, top_p, mask=mask)
+    def among_candidates():
+        cand, cand_idx = top_candidates(logits)
+        g_cand = jax.vmap(gumbel_at)(keys, cand_idx)
+        pick = _candidate_pick(cand, cand_idx, g_cand, safe_t, top_k, top_p)
+        # lax.top_k and argmax both give equals to the lower id
+        return pick.astype(jnp.int32), cand_idx[:, 0].astype(jnp.int32)
+
+    def over_the_vocabulary():
+        gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (v,), jnp.float32))(keys)
+        # exact unrestricted sampling: argmax(logits/T + G) ~ softmax(logits/T)
+        return jnp.argmax(logits / safe_t + gumbel, axis=-1).astype(jnp.int32)
+
+    cand_pick, greedy = jax.lax.cond(
+        jnp.any(sampled & restricted), among_candidates,
+        lambda: (nobody, jnp.argmax(logits, axis=-1).astype(jnp.int32)))
+    full_pick = jax.lax.cond(
+        jnp.any(sampled & ~restricted), over_the_vocabulary, lambda: nobody)
+    pick = jnp.where(restricted, cand_pick, full_pick)
+    return jnp.where(sampled, pick, greedy)
 
 
 # ---------------------------------------------------------------------------

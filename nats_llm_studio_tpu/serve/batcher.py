@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..engine.generator import SamplingParams, default_buckets
-from ..engine.sampling import sample_rows
+from ..engine.sampling import row_class, sample_rows
 from ..models.config import ModelConfig
 from ..models.llama import make_cache
 from ..obs import LogHistogram, Trace
@@ -352,6 +352,13 @@ class BatcherStats:
     spec_verifies: int = 0  # width-(k+1) verify dispatches
     spec_drafted: int = 0
     spec_accepted: int = 0
+    # rows admitted to a slot by what they ask of the sampler
+    # (engine/sampling.py row_class): a greedy or a restricted row never
+    # needs the whole-vocabulary draw, and a decode step runs it only while
+    # an unrestricted row is live
+    rows_greedy: int = 0
+    rows_restricted: int = 0
+    rows_unrestricted: int = 0
     # routed-expert layers (models/mla_moe.py), summed over decode steps and
     # expert layers: distinct experts the live rows hit, the most rows on one
     # expert, the live rows, and how many (step, layer) samples that is
@@ -600,6 +607,18 @@ class BatcherStats:
             "accepted": self.spec_accepted,
         }
 
+    def count_admitted(self, sp, vocab_size: int) -> None:
+        """One more request in a slot, and its row's class for the sampler."""
+        self.requests += 1
+        name = "rows_" + row_class(sp.temperature, sp.top_k, sp.top_p, vocab_size)
+        setattr(self, name, getattr(self, name) + 1)
+
+    def sampler_counters(self) -> dict[str, int]:
+        """Rows admitted by sampler class, exposed by serve/worker.py as
+        lmstudio_sampler_rows_total{class}."""
+        return {"greedy": self.rows_greedy, "restricted": self.rows_restricted,
+                "unrestricted": self.rows_unrestricted}
+
     def counters(self) -> dict[str, int]:
         """Monotonic counters, for Prometheus exposition."""
         return {
@@ -637,6 +656,9 @@ class BatcherStats:
             "spec_verifies": self.spec_verifies,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,
+            "rows_greedy": self.rows_greedy,
+            "rows_restricted": self.rows_restricted,
+            "rows_unrestricted": self.rows_unrestricted,
             "shed_causes": shed_causes,
             "tokens_per_step_avg": round(self.tokens / self.steps, 2) if self.steps else 0.0,
             "admit_queue_delay_p50_ms": round(adm.percentile(0.5), 1),
@@ -3774,7 +3796,7 @@ class ContinuousBatcher:
             req.slot = slot
             req.pos = n
             self._slots[slot] = req
-            self.stats.requests += 1
+            self.stats.count_admitted(req.sp, cfg.vocab_size)
             dirty = True
             host_pos[slot] = n
             host_steps[slot] = 1  # the admit program sampled at rng step 0
@@ -3904,7 +3926,7 @@ class ContinuousBatcher:
                 r.slot = s
                 r.pos = ns[j]
                 r.t_admit = t_admit
-                self.stats.requests += 1
+                self.stats.count_admitted(r.sp, cfg.vocab_size)
                 self.stats.record_admit_delay((t_admit - r.t_enq) * 1e3)
                 if r.trace is not None:
                     r.trace.mark("admit", t_admit)
@@ -4085,7 +4107,7 @@ class ContinuousBatcher:
                 r.slot = s
                 r.pos = ns[j]
                 self._slots[s] = r
-                self.stats.requests += 1
+                self.stats.count_admitted(r.sp, cfg.vocab_size)
                 if r.trace is not None:
                     r.trace.mark("prefill")  # chunk loop + finish dispatched
                 host_pos[s] = ns[j]

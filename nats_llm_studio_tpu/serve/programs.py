@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..engine.sampling import spec_accept_rows
+from ..engine.sampling import require_partitionable_threefry, spec_accept_rows
 from ..models.config import ModelConfig
 from ..models.llama import forward, forward_decode_paged, make_cache
 from ..ops.kvcache import (
@@ -112,6 +112,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
     caller hands it in because the benchmark's rehearsal of a broken path
     (benchmark/tests/test_rehearsal.py) replaces it in the batcher's module
     to show that ``correct`` catches a wrong token."""
+    require_partitionable_threefry()  # the one layout of a draw's bits the sampler reproduces
     fwd = partial(forward, cfg=cfg, mesh=mesh)
 
     def draw(*args, **kw):

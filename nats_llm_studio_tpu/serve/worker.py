@@ -1888,6 +1888,14 @@ class Worker:
                 # rides the generic histograms() loop below
                 for name, v in stats.spec_counters().items():
                     r.counter(f"lmstudio_spec_{name}_total", v, labels=labels)
+            if hasattr(stats, "sampler_counters"):
+                for name, v in stats.sampler_counters().items():
+                    r.counter("lmstudio_sampler_rows_total", v,
+                              labels={**labels, "class": name},
+                              help="rows admitted by what they ask of the sampler: "
+                                   "only an unrestricted row (temperature > 0, no "
+                                   "top-k, top_p 1) makes a decode step draw noise "
+                                   "for the whole vocabulary")
             moe = getattr(stats, "moe_counters", None)
             if moe is not None and moe()["expert_steps"]:
                 # routed-expert layers (models/mla_moe.py), summed over

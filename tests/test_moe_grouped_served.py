@@ -70,7 +70,7 @@ def page_of(b) -> str:
     return Worker(WorkerConfig(), Registry()).render_prometheus()
 
 
-@async_test
+@async_test(timeout=240.0)  # 59 s beside five workers on an empty compile cache
 async def test_a_prompt_of_three_chunks_through_the_grouped_form_is_dense_dispatch(
         model, monkeypatch):
     tokens, stats, admits, programs, page = await serve(model)

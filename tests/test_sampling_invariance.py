@@ -19,10 +19,15 @@ import pytest
 
 from nats_llm_studio_tpu.engine.generator import SamplingParams
 from nats_llm_studio_tpu.engine.sampling import (
+    FUNNEL,
     _log_weights,
     _pick,
+    gumbel_at,
+    require_partitionable_threefry,
+    row_class,
     sample_rows,
     spec_accept_rows,
+    top_candidates,
 )
 from nats_llm_studio_tpu.models.config import ModelConfig
 from nats_llm_studio_tpu.models.llama import (
@@ -322,5 +327,172 @@ async def test_batcher_logprobs_greedy_top_entry_is_chosen_token(model):
             assert top_ids[0] == tok
             assert abs(top_lps[0] - lp) < 1e-5
             assert all(a >= b2 for a, b2 in zip(top_lps, top_lps[1:]))
+    finally:
+        b.stop()
+
+
+# -- a row's cost follows what it asks for: same tokens, less work ----------
+#
+# ``sample_rows`` draws noise at a restricted row's candidates only, finds
+# the candidates through a funnel, and runs each branch only while a row of
+# its class is in the batch. Every token must stay ``_pick_ref``'s over the
+# row's WHOLE draw.
+
+@pytest.mark.parametrize("v", [49_155, 100_352, 131_072, 20_001])
+def test_candidates_noise_is_the_whole_draws_at_every_id(v):
+    """ALL ids, not a sample: a JAX whose draw lays its bits out otherwise
+    fails here and not in a served token."""
+    key = jax.random.fold_in(jax.random.PRNGKey(1234), 56)
+    want = jax.random.gumbel(key, (v,), jnp.float32)
+    got = jax.jit(gumbel_at)(key, jnp.arange(v, dtype=jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and in the candidates' own shape, ids in any order, under vmap
+    ids = jax.random.randint(jax.random.PRNGKey(v), (3, CANDIDATES), 0, v)
+    keys = jax.vmap(lambda s: jax.random.fold_in(jax.random.PRNGKey(s), 9))(jnp.arange(3))
+    rows = jax.vmap(lambda k: jax.random.gumbel(k, (v,), jnp.float32))(keys)
+    np.testing.assert_array_equal(
+        np.asarray(jax.vmap(gumbel_at)(keys, ids)),
+        np.asarray(jnp.take_along_axis(rows, ids, axis=-1)))
+
+
+def test_the_sampler_refuses_another_layout_of_the_draw():
+    require_partitionable_threefry()
+    jax.config.update("jax_threefry_partitionable", False)
+    try:
+        with pytest.raises(RuntimeError, match="jax_threefry_partitionable"):
+            require_partitionable_threefry()
+    finally:
+        jax.config.update("jax_threefry_partitionable", True)
+
+
+def _funnel_rows(case: str, v: int) -> jax.Array:
+    GROUP = FUNNEL[0]
+    rng = np.random.default_rng(len(case) * 1000 + v)
+    x = rng.normal(size=(5, v)).astype(np.float32) * 3.0
+    if case == "runs_of_equals":
+        # few distinct values, so equals run across every group's edge (of
+        # both stages) and the 64th rank falls inside a run; one row all equal
+        x = np.round(x)
+        x[1, GROUP - 3:GROUP + 3] = 50.0  # the top run straddles groups 0 and 1
+        x[2, :] = 0.25
+        # 70 equal maxima, one a group: the 64 groups kept are the LOWER ones
+        x[3, (np.arange(70) * GROUP + 5) % v] = 40.0
+        # and 70 in the narrower groups of one wide group's neighbourhood
+        x[4, (np.arange(70) * FUNNEL[1] + 3) % v] = 40.0
+    elif case == "masked_to_few":
+        keep = np.zeros((5, v), bool)
+        for i, n in enumerate((1, 17, 40, 63, 64)):
+            keep[i, rng.choice(v, size=n, replace=False)] = True
+        keep[0, :] = False
+        keep[0, v - 1] = True  # the one finite logit is the last id
+        x = np.where(keep, x, -np.inf).astype(np.float32)
+    return jnp.asarray(x)
+
+
+@pytest.mark.parametrize("case,v", [
+    ("random", 100_352), ("random", 49_155), ("random", 20_001),
+    ("runs_of_equals", 100_352), ("runs_of_equals", 20_001),
+    ("masked_to_few", 49_155), ("masked_to_few", 9_000),
+    ("random", 8_192), ("runs_of_equals", 8_192), ("masked_to_few", 200),
+    ("random", 1_024), ("runs_of_equals", 1_031), ("masked_to_few", 1_500), ("random", 63),
+])
+def test_the_funnel_is_top_k_value_for_value_and_id_for_id(case, v):
+    logits = _funnel_rows(case, v)
+    want_v, want_i = jax.lax.top_k(logits, min(CANDIDATES, v))
+    got_v, got_i = jax.jit(top_candidates)(logits)
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+
+
+_CLASS_ROWS = {  # rows to stand beside the one under test
+    "alone": [],
+    "beside_greedy": [(0.0, 0, 1.0), (0.0, 7, 0.5)],
+    "beside_restricted": [(0.8, 40, 1.0), (1.1, 0, 0.6)],
+    "beside_unrestricted": [(0.9, 0, 1.0)],
+    "mixed": [(0.0, 0, 1.0), (0.8, 40, 1.0), (0.9, 0, 1.0), (1.0, 8, 0.75)],
+}
+
+
+@pytest.mark.parametrize("temp,tk,tp", SETTINGS)
+def test_a_rows_token_is_its_own_whichever_branches_the_batch_ran(temp, tk, tp):
+    """The row alone, beside greedy rows only, beside restricted rows only,
+    beside an unrestricted row and in a mixed batch: every combination of
+    the two conditionals, at a vocabulary wide enough for the funnel (and
+    not a multiple of 128). The token is ``_pick_ref``'s over the row's
+    whole draw each time."""
+    v = 9_001
+    logits_all = jax.random.normal(jax.random.PRNGKey(3), (8, v), jnp.float32) * 3.0
+    draw = jax.jit(sample_rows)
+    for seed in (11, 12, 13):
+        row_logits = logits_all[seed % 8]
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), 4)
+        gumbel = jax.random.gumbel(key, (v,), jnp.float32)
+        want = int(_pick_ref(row_logits[None], gumbel[None], temp, tk, tp)[0])
+        for name, others in _CLASS_ROWS.items():
+            rows = [(temp, tk, tp)] + others
+            n = len(rows)
+            got = draw(
+                jnp.concatenate([row_logits[None], logits_all[1:n]]),
+                jnp.asarray([seed] + [90 + i for i in range(n - 1)], jnp.int32),
+                jnp.asarray([4] + [i for i in range(n - 1)], jnp.int32),
+                jnp.asarray([r[0] for r in rows], jnp.float32),
+                jnp.asarray([r[1] for r in rows], jnp.int32),
+                jnp.asarray([r[2] for r in rows], jnp.float32))
+            assert int(got[0]) == want, (name, seed)
+
+
+def test_sample_rows_with_a_mask_is_pick_ref_over_the_masked_logits():
+    """The constrained-decoding mask goes in before everything, as before:
+    rows of every class in one batch, fewer allowed ids than candidates."""
+    v = 9_001
+    logits = jax.random.normal(jax.random.PRNGKey(8), (5, v), jnp.float32) * 3.0
+    mask = np.zeros((5, v), bool)
+    rng = np.random.default_rng(5)
+    for i in range(5):
+        mask[i, rng.choice(v, size=17 + 30 * i, replace=False)] = True
+    seeds = jnp.arange(40, 45, dtype=jnp.int32)
+    steps = jnp.arange(5, dtype=jnp.int32)
+    temp, tk, tp = (jnp.asarray(c) for c in zip(*SETTINGS))
+    gumbel = jax.vmap(lambda s, t: jax.random.gumbel(
+        jax.random.fold_in(jax.random.PRNGKey(s), t), (v,), jnp.float32))(seeds, steps)
+    got = sample_rows(logits, seeds, steps, temp, tk, tp, mask=jnp.asarray(mask))
+    want = _pick_ref(jnp.where(jnp.asarray(mask), logits, _NEG_INF), gumbel, temp, tk, tp)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert all(mask[i, int(t)] for i, t in enumerate(np.asarray(got)))
+
+
+@pytest.mark.parametrize("temp,tk,tp,want", [
+    (0.0, 0, 1.0, "greedy"), (0.0, 40, 0.9, "greedy"), (0.8, 40, 1.0, "restricted"),
+    (0.8, 0, 0.95, "restricted"), (0.8, 0, 1.0, "unrestricted"),
+    (0.8, 300, 1.0, "unrestricted"),  # a top-k of the whole vocabulary restricts nothing
+])
+def test_row_class_is_the_samplers_own_split(temp, tk, tp, want):
+    assert row_class(temp, tk, tp, 256) == want
+
+
+@async_test
+async def test_batcher_counts_admitted_rows_by_sampler_class(model):
+    """Three integers in the batcher's stats: what share of rows never need
+    the whole-vocabulary draw."""
+    cfg, params = model
+    b = ContinuousBatcher(params, cfg, max_slots=4, max_seq_len=64, buckets=[8, 64])
+    try:
+        async def run(prompt, **kw):
+            sp = SamplingParams(max_tokens=3, seed=1, **kw)
+            return [t async for t in b.submit(prompt, sp)]
+
+        await asyncio.gather(
+            run([1, 2, 3], temperature=0.0),
+            run([4, 5, 6], temperature=0.0, top_k=5),
+            run([7, 8], temperature=0.8, top_k=5),
+            run([9, 8, 7], temperature=0.8, top_p=0.9),
+            run([3, 3], temperature=0.8),
+        )
+        await run([2, 2, 2], temperature=0.7, top_k=cfg.vocab_size)
+        snap = b.stats.snapshot()
+        assert (snap["rows_greedy"], snap["rows_restricted"], snap["rows_unrestricted"]) \
+            == (2, 2, 2)
+        assert snap["requests"] == 6
+        assert b.stats.sampler_counters() == {"greedy": 2, "restricted": 2, "unrestricted": 2}
     finally:
         b.stop()

@@ -438,7 +438,7 @@ def _held_to_the_reference(params, prompt, served):
     assert max(gaps) < 1e-3, gaps
 
 
-@async_test
+@async_test(timeout=240.0)  # every admit and decode program: 44 s beside five workers on an empty compile cache
 async def test_two_slots_finish_and_refill_at_different_steps_through_the_live_batcher(model):
     """Five requests of unequal prompts and lengths over two slots: group
     admits, a chunked admit (prompts over the chunk of 16), slots that finish
@@ -480,7 +480,7 @@ async def test_two_slots_finish_and_refill_at_different_steps_through_the_live_b
         b.stop()
 
 
-@async_test
+@async_test(timeout=240.0)  # 67 s with its fixture beside five workers on an empty compile cache
 async def test_a_finished_and_a_reserved_slot_keep_their_state_across_a_burst(model):
     """Three slots. A decodes throughout; B and E finish early and leave their
     last state in slots 1 and 2; C, a prompt of three chunks, then reserves
@@ -589,7 +589,7 @@ async def test_a_request_with_logprobs_replays_its_last_prompt_position(model, p
         b.stop()
 
 
-@async_test
+@async_test(timeout=240.0)  # two batchers' programs: 46 s alone on an empty compile cache
 async def test_a_preempted_slot_resumes_on_its_own_state_and_kv(model):
     """QoS preempt-and-resume (``tests/test_qos.py``'s geometry: a pool of
     three blocks of 32, one step a dispatch): a premium admit parks the batch

@@ -22,7 +22,7 @@ def _held_to_the_reference(params, prompt, served):
     assert max(gaps) < 1e-3, gaps
 
 
-@async_test
+@async_test(timeout=240.0)  # every admit and decode program: 28 s alone on an empty compile cache, 64 s beside five workers
 async def test_two_slots_finish_and_refill_at_different_steps_through_the_live_batcher(model):
     """Five requests of unequal prompts and lengths over two slots: group
     admits, chunked admits that carry the ring across the window's edge

@@ -661,17 +661,13 @@ def test_the_ring_kernel_at_the_benchmark_cells_shapes(one_chip, no_cache, swa_c
     assert "window_decode_attention" in compiled.as_text()
 
 
-@pytest.mark.parametrize("program", ["decode_pallas", "decode_pallas_ext"],
-                         ids=["the burst", "the single step"])
-def test_a_decode_launch_of_the_window_family_copies_no_pool_and_no_ring(
-        one_chip, no_cache, swa_cell, program):
-    """The family's two decode programs as ``serve/programs.py`` builds them,
-    all 5 layers over the cell's pools and rings, donated: the slot table of
-    16 x 1,152 entries (72 KiB) fits the kernel's scalar memory, both
-    attention kernels and the hit-list expert kernel are in it under their
-    names, the pools and the rings are aliased onto the results, and the
-    program holds no ``copy`` and no ``dynamic-update-slice`` of a whole pool
-    or ring (a row's key goes in by a scatter of one row)."""
+@pytest.fixture(scope="module")
+def swa_decode_launch(one_chip, no_cache, swa_cell):
+    """The window family's decode programs as ``serve/programs.py`` builds
+    them, compiled once a program at the cell's shapes: all 5 layers over
+    the cell's pools and rings, donated. ``(compiled, kp)`` by program name."""
+    from functools import cache
+
     from nats_llm_studio_tpu.engine.sampling import sample_rows
     from nats_llm_studio_tpu.serve.programs import build_programs
 
@@ -683,15 +679,34 @@ def test_a_decode_launch_of_the_window_family_copies_no_pool_and_no_ring(
     ints, floats = row(jnp.int32), row(jnp.float32)
     table = build_programs(cfg, None, max_seq=SWA_SEQ, paged=True, kv_block_tokens=t,
                            sample_rows=sample_rows)
-    last = 8 if program == "decode_pallas" else row(jnp.bool_, cfg.vocab_size)
-    orig = jax.default_backend
-    jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
-    try:
-        compiled = table[program].lower(
-            jax.tree.map(sds, shapes), ints, kp, vp, row(jnp.int32, SWA_SEQ // t), ints, ints,
-            ints, floats, ints, floats, last).compile()
-    finally:
-        jax.default_backend = orig
+
+    @cache
+    def launch(program):
+        last = 8 if program == "decode_pallas" else row(jnp.bool_, cfg.vocab_size)
+        orig = jax.default_backend
+        jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
+        try:
+            return table[program].lower(
+                jax.tree.map(sds, shapes), ints, kp, vp, row(jnp.int32, SWA_SEQ // t), ints,
+                ints, ints, floats, ints, floats, last).compile(), kp
+        finally:
+            jax.default_backend = orig
+
+    return launch
+
+
+@pytest.mark.parametrize("program", ["decode_pallas", "decode_pallas_ext"],
+                         ids=["the burst", "the single step"])
+def test_a_decode_launch_of_the_window_family_copies_no_pool_and_no_ring(
+        swa_decode_launch, program):
+    """The family's two decode programs as ``serve/programs.py`` builds them,
+    all 5 layers over the cell's pools and rings, donated: the slot table of
+    16 x 1,152 entries (72 KiB) fits the kernel's scalar memory, both
+    attention kernels and the hit-list expert kernel are in it under their
+    names, the pools and the rings are aliased onto the results, and the
+    program holds no ``copy`` and no ``dynamic-update-slice`` of a whole pool
+    or ring (a row's key goes in by a scatter of one row)."""
+    compiled, kp = swa_decode_launch(program)
     text = compiled.as_text()
     for name in ("window_decode_attention", "paged_decode_attention", "moe_hit_experts"):
         assert name in text, name
@@ -704,3 +719,88 @@ def test_a_decode_launch_of_the_window_family_copies_no_pool_and_no_ring(
     ma = compiled.memory_analysis()
     held = 2 * (int(np.prod(kv.shape)) + int(np.prod(ring.shape))) * 2
     assert ma.alias_size_in_bytes >= held and ma.temp_size_in_bytes < held // 4
+
+
+def _outside_conditionals(text: str) -> list[str]:
+    """The instruction lines of an optimised HLO module that run on every
+    pass through the program: those of no computation that a ``conditional``
+    names as a branch, nor of one such a computation calls (a fusion's body,
+    a nested loop's)."""
+    import re
+
+    def named(attributes: str, lines: list[str]) -> set[str]:
+        found = re.findall(rf"(?:{attributes})=(\{{[^}}]*\}}|%[\w.\-]+)", " ".join(lines))
+        return set(re.findall(r"%([\w.\-]+)", " ".join(found)))
+
+    bodies: dict[str, list[str]] = {}
+    name = None
+    for ln in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", ln)
+        if m and not ln.startswith(" "):
+            name = m.group(1)
+            bodies[name] = []
+        elif name is not None and ln.startswith("}"):
+            name = None
+        elif name is not None:
+            bodies[name].append(ln)
+    branches = "true_computation|false_computation|branch_computations"
+    under = set().union(*(named(branches, [ln for ln in lines if " conditional(" in ln])
+                          for lines in bodies.values()))
+    todo = list(under)
+    while todo:
+        for n in named(f"calls|to_apply|body|condition|{branches}", bodies.get(todo.pop(), [])):
+            if n not in under:
+                under.add(n)
+                todo.append(n)
+    return [ln for n, lines in bodies.items() if n not in under for ln in lines]
+
+
+def test_outside_conditionals_reads_branches_and_what_they_call():
+    text = """HloModule m
+
+%fused_noise (p: u32[4,9]) -> f32[4,9] {
+  %p = u32[4,9]{1,0} parameter(0)
+  ROOT %l = f32[4,9]{1,0} log(%c)
+}
+
+%branch_a (q: f32[4,9]) -> s32[4] {
+  %f = f32[4,9]{1,0} fusion(%q), kind=kLoop, calls=%fused_noise
+}
+
+%branch_b (q: f32[4,9]) -> s32[4] {
+  %z = s32[4]{0} constant(0)
+}
+
+%fused_always (p: f32[4,9]) -> f32[4,9] {
+  ROOT %e = f32[4,9]{1,0} exponential(%p)
+}
+
+ENTRY %main (x: f32[4,9]) -> s32[4] {
+  %g = f32[4,9]{1,0} fusion(%x), kind=kLoop, calls=%fused_always
+  ROOT %c = s32[4]{0} conditional(%p, %g, %g), true_computation=%branch_a, false_computation=%branch_b
+}
+"""
+    outside = "\n".join(_outside_conditionals(text))
+    assert "exponential(" in outside and "conditional(" in outside
+    assert "log(" not in outside and "u32[4,9]" not in outside and "constant(0)" not in outside
+
+
+def test_a_decode_burst_draws_whole_vocabulary_noise_only_inside_a_conditional(
+        swa_decode_launch, swa_cell):
+    """``lagunaxs2.code_closed``'s burst (16 rows x 100,352 logits): outside
+    the sampler's conditionals no operation makes random bits (``u32``) or
+    takes a logarithm over [rows, V], so a step whose rows are all greedy or
+    restricted pays for no whole-vocabulary draw; and the conditionals are
+    there (nothing turned them into selects of both branches)."""
+    cfg, _, _ = swa_cell
+    compiled, _ = swa_decode_launch("decode_pallas")
+    text = compiled.as_text()
+    whole = f"[{SWA_SLOTS},{cfg.vocab_size}]"
+
+    def draws(ln: str) -> bool:
+        return f"u32{whole}" in ln or (" log(" in ln and f"f32{whole}" in ln)
+
+    assert text.count(" conditional(") >= 2
+    assert any(map(draws, text.splitlines())), "the whole-vocabulary draw is in no branch either"
+    always = [ln.strip()[:160] for ln in _outside_conditionals(text) if draws(ln)]
+    assert not always, always
