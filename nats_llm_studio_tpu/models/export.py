@@ -60,7 +60,6 @@ def config_metadata(cfg: ModelConfig, name: str) -> dict[str, Any]:
             f"{a}.attention.key_length": cfg.head_dim,
             f"{a}.attention.key_length_nope": cfg.qk_nope_head_dim,
             f"{a}.attention.value_length": cfg.v_head_dim,
-            f"{a}.attention.q_lora_rank": cfg.q_lora_rank,
             f"{a}.attention.kv_lora_rank": cfg.kv_lora_rank,
             f"{a}.rope.dimension_count": cfg.qk_rope_head_dim,
             f"{a}.rope.scaling.type": "yarn" if cfg.rope_factor > 1.0 else "none",
@@ -75,12 +74,19 @@ def config_metadata(cfg: ModelConfig, name: str) -> dict[str, Any]:
             f"{a}.leading_dense_block_count": cfg.n_dense_layers,
             f"{a}.expert_gating_func": 2 if cfg.router_scoring == "sigmoid" else 1,
             f"{a}.expert_weights_scale": cfg.routed_scaling,
-            f"{a}.hyper_connection.count": cfg.hc_mult,
-            f"{a}.hyper_connection.sinkhorn_iterations": cfg.hc_sinkhorn_iters,
-            f"{a}.hyper_connection.epsilon": cfg.hc_eps,
-            f"{a}.hyper_connection.res_clamp_min": cfg.hc_res_clamp_min,
-            f"{a}.hyper_connection.res_clamp_max": cfg.hc_res_clamp_max,
         }
+        # what a configuration does not have it does not write, as llama.cpp's
+        # deepseek2 leaves q_lora_rank out for a model with one query matrix
+        if cfg.q_lora_rank:
+            md[f"{a}.attention.q_lora_rank"] = cfg.q_lora_rank
+        if cfg.hc_mult > 1:
+            md |= {
+                f"{a}.hyper_connection.count": cfg.hc_mult,
+                f"{a}.hyper_connection.sinkhorn_iterations": cfg.hc_sinkhorn_iters,
+                f"{a}.hyper_connection.epsilon": cfg.hc_eps,
+                f"{a}.hyper_connection.res_clamp_min": cfg.hc_res_clamp_min,
+                f"{a}.hyper_connection.res_clamp_max": cfg.hc_res_clamp_max,
+            }
     if cfg.n_ssm_layers:
         # llama.cpp's granitehybrid keys: kv heads a layer (0 = a state-space
         # layer), the Mamba-2 sizes, and no rotary embedding unless finetuned

@@ -15,9 +15,12 @@ table and the sampling are the ones every other family uses. What differs:
   tiles, as the device lays them out anyway) the shared key AFTER rotation —
   576 numbers a token a layer where GQA would hold 2 x Hkv x D. ``ops/kvcache.py``
   and the batcher treat the pair as they treat K and V.
-* **Attention.** T > ``_ABSORB_MAX_T`` (prefill, chunks): the window's latents
-  are expanded to per-head keys and values (qk 192 / v 128 at the published
-  widths) and attended in XLA, queries in blocks. T small (decode, the draft
+* **Attention.** T > ``_ABSORB_MAX_T`` (prefill, chunks): latents are
+  expanded to per-head keys and values (qk 192 / v 128 at the published
+  widths) and attended in XLA, queries in blocks, KEYS IN BLOCKS too: a loop
+  over the row cache whose trip count is the furthest block a query of the
+  call can see, so a chunk costs what its live prefix costs and no plane
+  over the program's whole window exists. T small (decode, the draft
   bundle of a verify): absorbed — ``W_uk`` folds into the query, ``W_uv`` into
   the output, and the scores read the latents themselves
   (``ops/mla_attention.py``: a Pallas kernel over the pool and the table, or
@@ -38,7 +41,14 @@ table and the sampling are the ones every other family uses. What differs:
   step streams) and the most rows on one expert.
 * **The residual is n streams** (``hc_mult``): ``X <- H_res X + H_post^T f(norm(H_pre X))``
   with the three maps made from the streams themselves, ``H_res`` projected
-  onto doubly stochastic matrices by Sinkhorn rounds (rows first).
+  onto doubly stochastic matrices by Sinkhorn rounds (rows first). At
+  ``hc_mult == 1`` it is the plain residual ``h + f(norm(h))``: one stream in
+  ``cfg.dtype``, no mixer leaves, no ``mix`` scope.
+* **The query** goes through a rank-``q_lora_rank`` pair with a norm between
+  (``w_dq``, ``q_norm``, ``w_uq``), or at ``q_lora_rank == 0`` through one
+  matrix ``wq [d, H x (nope + rope)]``. Both choices are the configuration's,
+  made once in Python like ``expert_path``: a tree holds the leaves of its
+  form only.
 
 Norms, the router, softmax and the stream mixers run in float32; the small
 float32 products (router, mixers) at ``Precision.HIGHEST``.
@@ -66,8 +76,14 @@ _HI = jax.lax.Precision.HIGHEST
 # query widths up to this take the absorbed form (decode is 1, a speculative
 # verify k+1); anything wider is a prefill and expands the window's latents
 _ABSORB_MAX_T = 16
-# queries attended together in the expanded form: [B, H, block, S] f32 scores
+# queries attended together in the expanded form
 _Q_BLOCK = 512
+# keys expanded and scored together by ``blocked_attention``: [B, H, block of
+# queries, _K_BLOCK] f32 scores, 34 MB for a group of four at 256 queries. Small
+# enough that the scores of a block do not travel through HBM four times: a
+# group of four at 12k keys took 65 ms at 1,024, 40 ms at 512, 39 ms at 256
+# (PERF.md, PR 44)
+_K_BLOCK = 256
 
 
 # ---------------------------------------------------------------------------
@@ -155,7 +171,13 @@ def _residual(X, p, which: str, cfg: ModelConfig, f):
     """One sublayer ("attn" or "ffn") around the streams: y = f(RMSNorm(H_pre
     X)). ``f`` may return (y, aux); aux is passed through. The maps, the read
     and the write are the ``mix`` scope; the norm and ``f`` the sublayer's
-    (``seq/mla``, or ``ffn`` with ``f``'s own word inside it)."""
+    (``seq/mla``, or ``ffn`` with ``f``'s own word inside it). One stream
+    (``hc_mult`` 1) is the plain residual: X [B, T, d] in ``cfg.dtype``."""
+    if cfg.hc_mult == 1:
+        with jax.named_scope("seq/mla" if which == "attn" else "ffn"):
+            out = f(rms_norm(X, p[f"{which}_norm"], cfg.rms_eps))
+            y, aux = out if isinstance(out, tuple) else (out, None)
+            return X + y.astype(X.dtype), aux
     with jax.named_scope("mix"):
         pre, post, res = hc_maps(X, p[f"hc_{which}_w"], p[f"hc_{which}_a"],
                                  p[f"hc_{which}_b"], cfg)
@@ -179,8 +201,11 @@ def mla_project(h: jax.Array, p: Params, cfg: ModelConfig, cos, sin):
     exactly (c, kr)."""
     b, t, _ = h.shape
     dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
-    cq = rms_norm(mm(h, p["w_dq"]), p["q_norm"], cfg.rms_eps)
-    q = mm(cq, p["w_uq"]).reshape(b, t, cfg.n_heads, dn + dr)
+    if cfg.q_lora_rank:
+        q = mm(rms_norm(mm(h, p["w_dq"]), p["q_norm"], cfg.rms_eps), p["w_uq"])
+    else:
+        q = mm(h, p["wq"])
+    q = q.reshape(b, t, cfg.n_heads, dn + dr)
     q_rope = apply_rope(q[..., dn:], cos, sin)
     ckr = mm(h, p["w_dkv"])
     c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.rms_eps)
@@ -201,25 +226,68 @@ def _w_ukv(p: Params, cfg: ModelConfig):
     return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
-def expanded_attention(q_nope, q_rope, c_win, kr_win, p, cfg: ModelConfig,
-                       positions: jax.Array) -> jax.Array:
-    """Attention of T queries over a window of S cached tokens in the
+def blocked_attention(q_nope, q_rope, c_all, r_all, layer, win: int, p, cfg: ModelConfig,
+                      positions: jax.Array) -> jax.Array:
+    """Attention of T queries over the first ``win`` tokens of layer
+    ``layer`` of the row caches ``c_all`` / ``r_all`` [B, L, 1, S, .] in the
     EXPANDED form: every latent becomes a key [H, dn] (+ the shared rotary
-    key) and a value [H, dv]. ``positions`` [B, T]: query t sees keys at
-    index <= positions[b, t]. Returns [B, T, H*dv]."""
+    key) and a value [H, dv]; ``positions`` [B, T]: query t sees keys at
+    index <= positions[b, t]. A block of keys at a time: a block's latents
+    are expanded, scored and folded into a running maximum, sum and output,
+    and the loop stops at the furthest block a query of the call can see
+    (``max(positions) // block``). So the work follows the live prefix, not
+    ``win``, and nothing [B, H, T, win] exists. Rows of a group with
+    different starts share the trip count; a row's blocks past its own
+    frontier are masked whole and add nothing (block 0 holds key 0, which
+    every query sees, so the running maximum is finite from the first block
+    on). Returns [B, T, H*dv]. ``tests/test_mla_moe_plain.py`` holds it to
+    the one-plane definition and to the absorbed form."""
     b, t, hq, _ = q_nope.shape
     w_uk, w_uv = _w_ukv(p, cfg)
-    k_nope = jnp.einsum("bsr,rhd->bshd", c_win, w_uk)
-    v = jnp.einsum("bsr,rhd->bshd", c_win, w_uv)
-    key_pos = jnp.arange(c_win.shape[1], dtype=jnp.int32)
+    dr, dv = cfg.qk_rope_head_dim, cfg.v_head_dim
+    kb = math.gcd(win, _K_BLOCK)
+    kb = kb if kb >= min(128, _K_BLOCK) else win  # an odd window: one block
+    zero = jnp.zeros((), jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
 
-    def block(qn, qr, pos):  # [B, t', H, .], [B, t']
-        s = jnp.einsum("bthd,bshd->bhts", qn, k_nope, preferred_element_type=jnp.float32)
-        s = s + jnp.einsum("bthd,bsd->bhts", qr, kr_win, preferred_element_type=jnp.float32)
-        s = jnp.where((key_pos[None, None, :] <= pos[:, :, None])[:, None],
-                      s * cfg.attn_scale, jnp.float32(-1e30))
-        pr = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        return jnp.einsum("bhts,bshd->bthd", pr, v)
+    def keys(cache, at):  # [B, kb, W] of the layer's rows at..at+kb
+        sl = jax.lax.dynamic_slice(cache, (zero, layer, zero, at, zero),
+                                   (b, 1, 1, kb, cache.shape[-1]))
+        return sl[:, 0, 0].astype(q_nope.dtype)
+
+    def block(qn, qr, pos):  # [B, t', H, .], [B, t'] -> [B, t', H, dv]
+        tq = qn.shape[1]
+        q = jnp.concatenate([qn, qr], axis=-1)
+
+        def fold(j, carry):
+            m, l, acc = carry
+            at = j * kb
+            c_blk, kr_blk = keys(c_all, at), keys(r_all, at)[..., :dr]
+            k_nope = jnp.einsum("bsr,rhd->bshd", c_blk, w_uk)
+            v = jnp.einsum("bsr,rhd->bshd", c_blk, w_uv)
+            # a head's whole key [nope | the shared rotary key], so that ONE
+            # product writes the float32 scores once: two products wrote the
+            # plane, read it back and wrote it again (91 -> 65 ms a group of
+            # four at 12k keys; PERF.md, PR 44)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                kr_blk[:, :, None, :], k_nope.shape[:3] + (dr,))], axis=-1)
+            s = jnp.einsum("bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32)
+            key_pos = at + jnp.arange(kb, dtype=jnp.int32)
+            s = jnp.where((key_pos[None, None, :] <= pos[:, :, None])[:, None],
+                          s * cfg.attn_scale, jnp.float32(-1e30))
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            pr = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            l = l * corr + jnp.sum(pr, axis=-1)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "bhts,bshd->bhtd", pr.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        n_blocks = jnp.minimum(jnp.max(pos) // kb + 1, win // kb).astype(jnp.int32)
+        m0 = jnp.full((b, hq, tq), -1e30, jnp.float32)
+        _, l, acc = jax.lax.fori_loop(
+            0, n_blocks, fold, (m0, jnp.zeros_like(m0), jnp.zeros((b, hq, tq, dv), jnp.float32)))
+        return jnp.swapaxes(acc / l[..., None], 1, 2).astype(q_nope.dtype)
 
     if t <= _Q_BLOCK or t % _Q_BLOCK:
         out = block(q_nope, q_rope, positions)
@@ -264,8 +332,11 @@ def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
     them in float32 anyway): carried in bf16 they would be rounded 14 times a
     token in 7 layers, and that noise is what flips a token's 4th and 5th
     expert against the reference. A sublayer's input is cast to ``cfg.dtype``
-    after its norm, so every product runs as in the other families."""
+    after its norm, so every product runs as in the other families. One
+    stream is [B, T, d] in ``cfg.dtype``, as in the other families."""
     with jax.named_scope("embed"):
+        if cfg.hc_mult == 1:
+            return params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
         x = params["embed"][tokens].astype(jnp.float32) * cfg.embedding_scale
         return jnp.broadcast_to(x[None], (cfg.hc_mult,) + x.shape)
 
@@ -274,6 +345,8 @@ def _head(params: Params, cfg: ModelConfig, X, logit_positions, t: int) -> jax.A
     """The final norm and head read the SUM of the streams."""
     from .llama import lm_head_logits
 
+    if cfg.hc_mult == 1:
+        return lm_head_logits(params, cfg, X, logit_positions, t)
     with jax.named_scope("head/logits"):
         x = jnp.sum(X, axis=0).astype(jnp.dtype(cfg.dtype))
     return lm_head_logits(params, cfg, x, logit_positions, t)
@@ -296,7 +369,7 @@ def _layers(params: Params, cfg: ModelConfig, X, caches, attention, live=None, m
     scan's slice of a stack handed to a kernel or a loop as an operand would
     be copied, 1.4 GB a layer."""
     stats = None
-    rows = X.shape[1] * X.shape[2]
+    rows = X.shape[-3] * X.shape[-2]
     for stack, first, kind in _stacks(params, cfg):
         form, whole = "dense", None
         if kind == "moe":
@@ -363,15 +436,14 @@ def forward(
             sl = jax.lax.dynamic_slice(cache, (zero, layer, zero, zero, zero), (b, 1, 1, win, w))
             return sl[:, 0, 0].astype(h.dtype)
 
-        c_win, kr_win = window(c_all), window(r_all)[..., : cfg.qk_rope_head_dim]
         if t <= _ABSORB_MAX_T:
             from ..ops.mla_attention import mla_absorbed_attention
 
             o = absorbed_output(mla_absorbed_attention(
-                absorbed_queries(q_nope, p, cfg), q_rope, c_win, kr_win,
-                positions, cfg.attn_scale), p, cfg)
+                absorbed_queries(q_nope, p, cfg), q_rope, window(c_all),
+                window(r_all)[..., : cfg.qk_rope_head_dim], positions, cfg.attn_scale), p, cfg)
         else:
-            o = expanded_attention(q_nope, q_rope, c_win, kr_win, p, cfg, positions)
+            o = blocked_attention(q_nope, q_rope, c_all, r_all, layer, win, p, cfg, positions)
         return mm(o, p["wo"]), (c_all, r_all)
 
     X, caches, _ = _layers(params, cfg, X, (k_cache, v_cache), attention, mesh=mesh)
@@ -434,7 +506,9 @@ def make_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Random small-scale init; the tree is what a loader of the family would
-    build (``benchmark/references/mla_moe_mhc.py param_shapes`` names it)."""
+    build (``benchmark/references/mla_moe_mhc.py param_shapes`` names it):
+    the mixers' leaves only with more than one stream, the query's pair or its
+    one matrix by ``q_lora_rank``."""
     dt = jnp.dtype(cfg.dtype)
     keys = iter(jax.random.split(key, 64))
 
@@ -446,16 +520,19 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     rq, rkv, maps = cfg.q_lora_rank, cfg.kv_lora_rank, n * n + 2 * n
 
     def stack(L: int, ffn: dict) -> Params:
-        out: Params = {
-            "attn_norm": jnp.ones((L, d), dt), "ffn_norm": jnp.ones((L, d), dt),
-            "q_norm": jnp.ones((L, rq), dt), "kv_norm": jnp.ones((L, rkv), dt),
-            "w_dq": rand(L, d, rq), "w_uq": rand(L, rq, hq * (dn + dr)),
-            "w_dkv": rand(L, d, rkv + dr), "w_ukv": rand(L, rkv, hq * (dn + dv)),
-            "wo": rand(L, hq * dv, d),
-        }
-        for which in ("attn", "ffn"):
-            out |= {f"hc_{which}_w": rand(L, n * d, maps), f"hc_{which}_a": rand(L, 3),
-                    f"hc_{which}_b": rand(L, maps)}
+        out: Params = {"attn_norm": jnp.ones((L, d), dt), "ffn_norm": jnp.ones((L, d), dt),
+                       "kv_norm": jnp.ones((L, rkv), dt)}
+        if rq:  # the query through a low-rank pair, or one matrix
+            out |= {"q_norm": jnp.ones((L, rq), dt),
+                    "w_dq": rand(L, d, rq), "w_uq": rand(L, rq, hq * (dn + dr))}
+        else:
+            out["wq"] = rand(L, d, hq * (dn + dr))
+        out |= {"w_dkv": rand(L, d, rkv + dr), "w_ukv": rand(L, rkv, hq * (dn + dv)),
+                "wo": rand(L, hq * dv, d)}
+        if n > 1:  # one stream has no mixers
+            for which in ("attn", "ffn"):
+                out |= {f"hc_{which}_w": rand(L, n * d, maps), f"hc_{which}_a": rand(L, 3),
+                        f"hc_{which}_b": rand(L, maps)}
         return out | ffn
 
     blocks: Params = {}

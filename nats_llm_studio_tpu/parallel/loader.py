@@ -77,7 +77,11 @@ def load_params_sharded(
         raise NotImplementedError(
             f"{cfg.arch}: no GGUF tensor-name map for latent-attention models "
             "yet; the tree to build is models.mla_moe.init_params' (two stacks, "
-            "blocks.dense and blocks.moe), placed by param_sharding_rules")
+            "blocks.dense and blocks.moe; "
+            + ("w_dq / q_norm / w_uq" if cfg.q_lora_rank else "one wq")
+            + (f", mixers of {cfg.hc_mult} streams" if cfg.hc_mult > 1 else ", no mixers")
+            + "; the checkpoint's interleaved rotary pairs permuted to (first half, "
+            "second half)), placed by param_sharding_rules")
     if cfg.n_ssm_layers:
         raise NotImplementedError(
             f"{cfg.arch}: no GGUF tensor-name map for state-space models yet; "

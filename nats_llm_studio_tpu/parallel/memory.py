@@ -70,7 +70,9 @@ def _leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
 
 def _mla_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
     """The leaves of ``models.mla_moe.init_params``, unsharded (the family
-    serves on one chip a replica); the mixers' small leaves are left out."""
+    serves on one chip a replica), by the form the configuration has: the
+    query's pair or its one matrix, the mixers' maps only with more than one
+    stream (their small leaves are left out)."""
     d, hq, V = cfg.d_model, cfg.n_heads, cfg.vocab_size
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     rq, rkv, maps = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.hc_mult * (cfg.hc_mult + 2)
@@ -91,9 +93,11 @@ def _mla_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
     for name, L, ffn in stacks:
         if not L:
             continue
-        attn = {"w_dq": (d, rq), "w_uq": (rq, hq * (dn + dr)), "w_dkv": (d, rkv + dr),
-                "w_ukv": (rkv, hq * (dn + dv)), "wo": (hq * dv, d),
-                "hc_attn_w": (cfg.hc_mult * d, maps), "hc_ffn_w": (cfg.hc_mult * d, maps)}
+        attn = {"w_dkv": (d, rkv + dr), "w_ukv": (rkv, hq * (dn + dv)), "wo": (hq * dv, d)}
+        attn |= ({"w_dq": (d, rq), "w_uq": (rq, hq * (dn + dr))} if rq
+                 else {"wq": (d, hq * (dn + dr))})
+        if cfg.hc_mult > 1:
+            attn |= {"hc_attn_w": (cfg.hc_mult * d, maps), "hc_ffn_w": (cfg.hc_mult * d, maps)}
         for k, shape in (attn | ffn).items():
             out[f"blocks.{name}.{k}"] = _Leaf((L,) + shape, (), dtype_bytes, quantizable(k))
     return out

@@ -82,14 +82,16 @@ def param_sharding_rules(mesh: Mesh, cfg: ModelConfig | None = None) -> dict[str
 
 
 def _mla_rules(cfg: ModelConfig, ep, pp) -> dict[str, P]:
-    """A rule for every leaf ``models.mla_moe.init_params`` makes: the two
+    """A rule for every leaf ``models.mla_moe.init_params`` makes for ``cfg``
+    (the query's pair or its one matrix, mixers only with streams): the two
     stacks' layer axis on pp, the routed experts on ep, everything else whole
     (the family is served on one chip a replica: ``validate_mesh_for_config``
     refuses a tp split of the latent cache)."""
-    rank = {"attn_norm": 1, "ffn_norm": 1, "q_norm": 1, "kv_norm": 1,
-            "w_dq": 2, "w_uq": 2, "w_dkv": 2, "w_ukv": 2, "wo": 2,
-            "hc_attn_w": 2, "hc_attn_a": 1, "hc_attn_b": 1,
-            "hc_ffn_w": 2, "hc_ffn_a": 1, "hc_ffn_b": 1}
+    rank = {"attn_norm": 1, "ffn_norm": 1, "kv_norm": 1, "w_dkv": 2, "w_ukv": 2, "wo": 2}
+    rank |= {"q_norm": 1, "w_dq": 2, "w_uq": 2} if cfg.q_lora_rank else {"wq": 2}
+    if cfg.hc_mult > 1:
+        rank |= {"hc_attn_w": 2, "hc_attn_a": 1, "hc_attn_b": 1,
+                 "hc_ffn_w": 2, "hc_ffn_a": 1, "hc_ffn_b": 1}
     dense = rank | {"w_gate": 2, "w_up": 2, "w_down": 2}
     moe = rank | {"router": 2, "e_bias": 1, "w_gate_s": 2, "w_up_s": 2, "w_down_s": 2}
     rules = {"embed": P(None, None), "out_norm": P(None), "lm_head": P(None, None)}

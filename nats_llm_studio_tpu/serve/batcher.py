@@ -1092,8 +1092,9 @@ class ContinuousBatcher:
         # where a program of an expert family takes its [B, T] prompt tokens
         tokens_at = prompt_tokens_arg(fn) if self.cfg.n_moe_layers else None
 
-        def run(*args, _tokens=None, _name=None, **kwargs):
+        def run(*args, _tokens=None, _name=None, _chunk=None, **kwargs):
             key = dispatch_shape_key(args, kwargs)
+            form = None
             if tokens_at is not None:
                 rows = math.prod(args[tokens_at].shape)
                 form = self._expert_form(rows)
@@ -1114,6 +1115,14 @@ class ContinuousBatcher:
             # this bucket's program takes the sp ring path) — same jit, same
             # classification, distinct metrics row
             stats.record_program(_name or name, ms, _tokens)
+            if _chunk is not None:
+                # a chunk launch of a latent family: a record of its own in the
+                # ring (no annotation: the admit's span is open around it), so
+                # that a reader prices the launches of a traced span by their
+                # own rows and keys
+                p1 = time.perf_counter()
+                obs_spans.record("batcher.admit", p1 - (t1 - t0), p1, dict(
+                    _chunk, program="chunk", **({"experts": form} if form else {})))
             if ledger:
                 ctx = self._charge_ctx
                 if ctx:
@@ -1161,6 +1170,21 @@ class ContinuousBatcher:
             finally:
                 if self._admit_experts:
                     spn.attrs["experts"] = "+".join(sorted(self._admit_experts))
+
+    def _chunk_attrs(self, start: int, lens: list[int]) -> dict | None:
+        """What a chunk launch's ``batcher.admit`` record carries, for a
+        latent family (None otherwise). ``lens``: each row's real tokens from
+        ``start`` on. ``rows`` are those that hold tokens of their prompt in
+        this chunk, ``tokens`` theirs (at most a chunk a row), ``live_keys``
+        the keys a row's chunk attends over (its prefix through this chunk)
+        summed over the rows, ``pairs`` the (query, key) pairs of their
+        causal attention."""
+        if not self.cfg.is_mla:
+            return None
+        real = [min(n, self.prefill_chunk) for n in lens if n > 0]
+        return {"rows": len(real), "tokens": sum(real),
+                "live_keys": sum(start + t for t in real),
+                "pairs": sum(t * start + t * (t + 1) // 2 for t in real)}
 
     def _ledger_finalize(self, req, category: str) -> None:
         """Resolve a request's accrued device time into an outcome category.
@@ -2364,6 +2388,10 @@ class ContinuousBatcher:
                     if self._window_pool is not None:
                         spn.attrs.update(self.stats.record_window(
                             [req.pos for _, req in rows], n, cfg.window))
+                    if cfg.is_mla:
+                        # the live rows' positions at the burst's first step:
+                        # the latents the absorbed kernel reads, by row
+                        spn.attrs["live_tokens"] = sum(req.pos for _, req in rows)
                 # observed per-step latency (dispatch -> tokens readable);
                 # includes pipeline wait, i.e. what a stream experiences
                 now = time.monotonic()
@@ -3550,6 +3578,7 @@ class ContinuousBatcher:
                             ),
                             self._win_bucket(start + C),
                             _tokens=min(C, n - start),
+                            _chunk=self._chunk_attrs(start, [n - start]),
                         )
                         if start + C <= n:
                             chunk_logits[start // C] = logits
@@ -3584,6 +3613,7 @@ class ContinuousBatcher:
                             ),
                             self._win_bucket(start + C),
                             _tokens=min(C, n - start),
+                            _chunk=self._chunk_attrs(start, [n - start]),
                         )
                         if chunk_logits is not None and start + C <= n:
                             chunk_logits[start // C] = logits
@@ -3733,6 +3763,7 @@ class ContinuousBatcher:
                                     ),
                                     self._win_bucket(start + C),
                                     _tokens=min(C, n - start),
+                                    _chunk=self._chunk_attrs(start, [n - start]),
                                 )
                                 if start + C <= n:
                                     chunk_logits[start // C] = logits
@@ -3772,6 +3803,7 @@ class ContinuousBatcher:
                                 jnp.asarray([min(n - 1 - start, C - 1)], jnp.int32),
                                 self._win_bucket(start + C),
                                 _tokens=min(C, n - start),
+                                _chunk=self._chunk_attrs(start, [n - start]),
                             )
                             if chunk_logits is not None and start + C <= n:
                                 chunk_logits[start // C] = logits
@@ -4026,6 +4058,7 @@ class ContinuousBatcher:
                         jnp.asarray(last_pos, jnp.int32),
                         self._win_bucket(start + C),
                         _tokens=mpad * C,
+                        _chunk=self._chunk_attrs(start, [n - start for n in ns]),
                     )
                     final = self._select_end(
                         final, logits,

@@ -20,6 +20,7 @@ from benchmark import run
 from benchmark.lib import correct, weights
 from nats_llm_studio_tpu.models import experts, llama, mla_moe
 from nats_llm_studio_tpu.models.config import ModelConfig
+from test_mla_moe_plain import expanded_attention  # the one-plane definition
 
 ROOT = Path(__file__).resolve().parents[1]
 CONF = json.loads((ROOT / "benchmark/tests/rehearsal/configs/tiny-mla.json").read_text())
@@ -336,7 +337,7 @@ def test_absorbed_attention_is_expanded_attention():
     from nats_llm_studio_tpu.ops.mla_attention import mla_absorbed_attention
 
     with jax.default_matmul_precision("highest"):
-        want = mla_moe.expanded_attention(q_nope, q_rope, c_win, kr_win, p, cfg, positions)
+        want = expanded_attention(q_nope, q_rope, c_win, kr_win, p, cfg, positions)
         got = mla_moe.absorbed_output(mla_absorbed_attention(
             mla_moe.absorbed_queries(q_nope, p, cfg), q_rope, c_win, kr_win, positions,
             cfg.attn_scale), p, cfg)

@@ -24,6 +24,7 @@ from nats_llm_studio_tpu.serve.programs import build_programs
 
 ROOT = Path(__file__).resolve().parent.parent
 FAMILIES = {"dense": None, "latent": ("mla_moe_mhc", "tiny-mla"),
+            "latent_plain": ("mla_moe_plain", "tiny-mla-plain"),
             "state": ("ssm_hybrid", "tiny-ssm"), "window": ("swa_gated_moe", "tiny-swa")}
 SEQ, BLOCK, SLOTS, CHUNK, BURST = 64, 16, 2, 32, 2
 SCOPED_FILES = ("models/llama.py", "models/mla_moe.py", "models/ssm_hybrid.py",
@@ -132,6 +133,8 @@ def test_every_product_and_kernel_lies_under_a_scope(family, program):
         assert "head/sample" in seen
     if family == "latent":
         assert "mix" in seen and "seq/mla" in seen
+    if family == "latent_plain":   # one stream: the same words, never the mixers'
+        assert "mix" not in seen and {"seq/mla", "ffn/experts", "ffn/shared"} <= seen
     if family == "state":
         assert "seq/ssm" in seen and "seq/attn" in seen
     if family == "window":

@@ -804,3 +804,104 @@ def test_a_decode_burst_draws_whole_vocabulary_noise_only_inside_a_conditional(
     assert any(map(draws, text.splitlines())), "the whole-vocabulary draw is in no branch either"
     always = [ln.strip()[:160] for ln in _outside_conditionals(text) if draws(ln)]
     assert not always, always
+
+
+# -- the plain latent-attention form at kanana2.longctx_closed's shapes --------
+
+
+@pytest.fixture(scope="module")
+def mla_plain_cell():
+    """(cfg, the served tree's shapes, block tokens, slots, context) of
+    ``benchmark/configs/kanana-2-30b-a3b-instruct-2601.json``."""
+    import json
+    from pathlib import Path
+
+    from benchmark import run
+
+    root = Path(__file__).resolve().parents[1]
+    ref = run.load_module(root / "benchmark/references/mla_moe_plain.py")
+    conf = json.loads(
+        (root / "benchmark/configs/kanana-2-30b-a3b-instruct-2601.json").read_text())
+    env = conf["serving"]["env"]
+    seq = int(env["MAX_SEQ_LEN"])
+    cfg = ref.model_config(conf, seq)
+    return cfg, ref.param_shapes(cfg), int(env["KV_BLOCK_TOKENS"]), int(env["MAX_BATCH_SLOTS"]), seq
+
+
+def _mla_plain_table(cfg, t, seq):
+    from nats_llm_studio_tpu.engine.sampling import sample_rows
+    from nats_llm_studio_tpu.serve.programs import build_programs
+
+    return build_programs(cfg, None, max_seq=seq, paged=True, kv_block_tokens=t,
+                          sample_rows=sample_rows)
+
+
+@pytest.mark.parametrize("program", ["decode_pallas", "decode_pallas_ext"],
+                         ids=["the burst", "the single step"])
+def test_a_decode_launch_of_the_plain_latent_form_at_16_slots_of_32768(
+        one_chip, no_cache, mla_plain_cell, program):
+    """The two decode programs as ``serve/programs.py`` builds them, all 6
+    layers over the cell's pools (16 x 32,768 tokens in blocks of the
+    configuration's ``KV_BLOCK_TOKENS``, 4 GB), donated: Mosaic takes the slot
+    table the absorbed kernel walks, the kernel and the hit-list expert kernel
+    are in the program under their names, the pools are aliased onto the
+    results and nothing the size of a pool is a temporary."""
+    from nats_llm_studio_tpu.ops.mla_attention import mla_paged_decode_eligible
+
+    cfg, shapes, t, slots, seq = mla_plain_cell
+    assert mla_paged_decode_eligible(t, cfg.kv_lora_rank, 2)
+    nb = slots * (seq // t) + 64 + 1
+    kp, vp = (jax.ShapeDtypeStruct((nb, cfg.n_layers, h, t, w), jnp.bfloat16, sharding=one_chip)
+              for h, w in cfg.kv_cache_dims())
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    row = lambda dt, *more: jax.ShapeDtypeStruct(  # noqa: E731
+        (slots,) + more, dt, sharding=one_chip)
+    ints, floats = row(jnp.int32), row(jnp.float32)
+    last = 8 if program == "decode_pallas" else row(jnp.bool_, cfg.vocab_size)
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
+    try:
+        compiled = _mla_plain_table(cfg, t, seq)[program].lower(
+            jax.tree.map(sds, shapes), ints, kp, vp, row(jnp.int32, seq // t), ints,
+            ints, ints, floats, ints, floats, last).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    for name in ("mla_paged_decode_attention", "moe_hit_experts"):
+        assert name in text, name
+    assert "hc_sinkhorn" not in text   # one stream: no mixer in the program
+    ma = compiled.memory_analysis()
+    held = sum(int(np.prod(p.shape)) * 2 for p in (kp, vp))
+    assert ma.alias_size_in_bytes >= held and ma.temp_size_in_bytes < held // 4
+
+
+@pytest.mark.parametrize("width", [1, 2, 4], ids=["prefill1", "group_of_2", "group_of_4"])
+def test_a_chunk_of_the_plain_latent_form_holds_no_plane_over_its_32768_window(
+        one_chip, no_cache, mla_plain_cell, width):
+    """A chunk of 256 tokens x ``width`` prompts through the whole cut model
+    into row caches of 32,768 tokens, the window the program is built for
+    (``DECODE_LADDER_RUNGS=1``): the float32 score plane [B, 32, 256, 32768]
+    would be 1.07 GB a row and the window's expanded keys and values 0.54 GB;
+    what the program holds beside its donated row caches stays under 1.5 GB
+    at every width, because the attention walks key blocks of 256."""
+    cfg, shapes, _, _, seq = mla_plain_cell
+    table = _mla_plain_table(cfg, 64, seq)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    caches = [jax.ShapeDtypeStruct((width, cfg.n_layers, h, seq, w), jnp.bfloat16,
+                                   sharding=one_chip) for h, w in cfg.kv_cache_dims()]
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        compiled = table["prefill1" if width == 1 else "prefill_chunk_group"].lower(
+            jax.tree.map(sds, shapes), ints(width, CHUNK), *caches, ints(width), ints(width),
+            seq).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    assert "moe_grouped_experts" in text and "hc_sinkhorn" not in text
+    ma = compiled.memory_analysis()
+    plane = width * cfg.n_heads * CHUNK * seq * 4
+    assert ma.temp_size_in_bytes < min(1.5e9, plane + 0.5e9 * (width == 1)), ma.temp_size_in_bytes
+    print(f"\nwidth {width}: temp {ma.temp_size_in_bytes / 1e6:.0f} MB, "
+          f"alias {ma.alias_size_in_bytes / 1e6:.0f} MB")
