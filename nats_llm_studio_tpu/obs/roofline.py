@@ -57,6 +57,7 @@ PREFILL_PROGRAMS = frozenset(
         "finish_admit",
         "prefill_chunk_group",
         "select_end",
+        "take_rows",
         "finish_admit_group",
         "write_prefix_block",
         "sample_first",

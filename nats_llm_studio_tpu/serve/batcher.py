@@ -335,6 +335,14 @@ class BatcherStats:
     peak_active: int = 0
     grouped_admits: int = 0  # requests admitted via the batched-admit path
     chunked_group_admits: int = 0  # long prompts admitted via batched chunking
+    # a chunked group's launches: the rows each computed (its width), and
+    # those of them that held tokens of their prompt; the times a group
+    # went on at a smaller width, and the rows finished before their
+    # group's last chunk
+    chunk_rows_computed: int = 0
+    chunk_rows_real: int = 0
+    chunked_group_narrowings: int = 0
+    chunked_group_early_finishes: int = 0
     ring_compactions: int = 0  # wrapped ring re-rolled to restore windows
     cancelled: int = 0  # consumer-gone requests whose slot/queue entry was freed
     shed: int = 0  # requests rejected at the depth bound or dropped at the age bound
@@ -627,6 +635,10 @@ class BatcherStats:
             "decode_steps": self.steps,
             "grouped_admits": self.grouped_admits,
             "chunked_group_admits": self.chunked_group_admits,
+            "chunk_rows_computed": self.chunk_rows_computed,
+            "chunk_rows_real": self.chunk_rows_real,
+            "chunked_group_narrowings": self.chunked_group_narrowings,
+            "chunked_group_early_finishes": self.chunked_group_early_finishes,
             "ring_compactions": self.ring_compactions,
             "cancelled": self.cancelled,
             "shed": self.shed,
@@ -648,6 +660,10 @@ class BatcherStats:
             "peak_active_slots": self.peak_active,
             "grouped_admits": self.grouped_admits,
             "chunked_group_admits": self.chunked_group_admits,
+            "chunk_rows_computed": self.chunk_rows_computed,
+            "chunk_rows_real": self.chunk_rows_real,
+            "chunked_group_narrowings": self.chunked_group_narrowings,
+            "chunked_group_early_finishes": self.chunked_group_early_finishes,
             "ring_compactions": self.ring_compactions,
             "cancelled": self.cancelled,
             "shed": self.shed,
@@ -971,6 +987,8 @@ class ContinuousBatcher:
         # dispatches with no context are ledgered as "other" (warmup,
         # compaction, CoW copies)
         self._charge_ctx: tuple | None = None
+        # the group widths whose row takes are built (_build_takes)
+        self._takes_built: set[int] = set()
         # flight recorder (obs/recorder.py): the owner loop samples one
         # frame per interval and the anomaly paths (crash, pool
         # exhaustion, SHED_ONLY entry) dump through it; None = off
@@ -1171,18 +1189,18 @@ class ContinuousBatcher:
                 if self._admit_experts:
                     spn.attrs["experts"] = "+".join(sorted(self._admit_experts))
 
-    def _chunk_attrs(self, start: int, lens: list[int]) -> dict | None:
+    def _chunk_attrs(self, start: int, lens: list[int], width: int = 1) -> dict | None:
         """What a chunk launch's ``batcher.admit`` record carries, for a
         latent family (None otherwise). ``lens``: each row's real tokens from
-        ``start`` on. ``rows`` are those that hold tokens of their prompt in
-        this chunk, ``tokens`` theirs (at most a chunk a row), ``live_keys``
-        the keys a row's chunk attends over (its prefix through this chunk)
-        summed over the rows, ``pairs`` the (query, key) pairs of their
-        causal attention."""
+        ``start`` on; ``width``: the rows the launch computes. ``rows`` are
+        those that hold tokens of their prompt in this chunk, ``tokens``
+        theirs (at most a chunk a row), ``live_keys`` the keys a row's chunk
+        attends over (its prefix through this chunk) summed over the rows,
+        ``pairs`` the (query, key) pairs of their causal attention."""
         if not self.cfg.is_mla:
             return None
         real = [min(n, self.prefill_chunk) for n in lens if n > 0]
-        return {"rows": len(real), "tokens": sum(real),
+        return {"rows": len(real), "width": width, "tokens": sum(real),
                 "live_keys": sum(start + t for t in real),
                 "pairs": sum(t * start + t * (t + 1) // 2 for t in real)}
 
@@ -1520,8 +1538,27 @@ class ContinuousBatcher:
                         jnp.zeros((m,), jnp.int32), jnp.zeros((m,), jnp.int32), w,
                     )
                     n += 1
+                final = self._select_end(
+                    jnp.zeros_like(logits, jnp.float32), logits,
+                    jnp.asarray([False] * m, jnp.bool_),
+                )
+                n += self._build_takes(km, vm, final)
             jax.block_until_ready(logits)
         return n
+
+    def _build_takes(self, km, vm, final) -> int:
+        """Build the row takes from this group width to every narrower one
+        (``take_rows``'s shapes depend on the two widths alone), on a cache
+        pair and end logits as a chunk launch of the width leaves them: a
+        group that narrows later, perhaps inside a measured window, then
+        builds nothing. Called where a width's group program first runs and
+        by ``warm_chunk_programs``. Returns the number of programs."""
+        m = final.shape[0]
+        widths = [m >> i for i in range(1, m.bit_length())]
+        for w in widths:
+            self._take_rows(km, vm, final, jnp.asarray([0] * w, jnp.int32))
+        self._takes_built.add(m)
+        return len(widths)
 
     def pool_stats(self) -> dict | None:
         """Paged-KV block pool counters for metrics/bench (None when the
@@ -3973,18 +4010,26 @@ class ContinuousBatcher:
 
         def admit_group_chunked(reqs: list[_Request]) -> None:
             """Admit m LONG prompts (each > prefill_chunk) through SHARED
-            [m, C] chunk dispatches + one batched finish. Serial chunked
-            admits at B=1 leave most of the MXU idle and, worse, make
-            waiting long prompts queue a whole prefill each; batching
-            divides the chunk-pass count by m. A shared decode step still
-            interleaves between chunk dispatches, so live streams' inter-
-            token gap stays bounded by ~one [m, C] chunk.
+            [w, C] chunk dispatches. Serial chunked admits at B=1 leave most
+            of the MXU idle and, worse, make waiting long prompts queue a
+            whole prefill each; batching divides the chunk-pass count by m.
+            A shared decode step still interleaves between chunk dispatches,
+            so live streams' inter-token gap stays bounded by ~one [w, C]
+            chunk.
 
-            Reserved slots hold the _RESERVED placeholder during the loop:
-            the fixed-width decode program computes their rows as masked
-            junk (same as empty slots) and nothing is delivered; the
-            finish dispatch overwrites the full rows and installs the
-            requests atomically."""
+            The group narrows as its prompts end. After the chunk in which a
+            row's prompt ends, that row is finished (written to the pool or
+            the ring, its first token sampled at rng step 0 from the logits
+            select_end kept for it) and installed in its slot, so the next
+            decode burst of this loop decodes it. The rows still prefilling
+            go on at the smallest built width that holds them (4 -> 2 -> 1;
+            three live rows stay in the width-4 program), their cache taken
+            out of the wider one by take_rows; a last lone row runs the
+            program a lone long prompt runs (prefill1).
+
+            The slot of a row not installed yet holds the _RESERVED
+            placeholder: the fixed-width decode program computes its row as
+            masked junk (same as an empty slot) and nothing is delivered."""
             nonlocal K, V, tok_dev, dirty, table_dirty
             if paged:
                 # all-or-nothing capacity check up front; a shortfall routes
@@ -4004,7 +4049,6 @@ class ContinuousBatcher:
                             r.emit("err", e)
                     return
             prev_ctx = self._charge_ctx
-            self._charge_ctx = tuple(reqs)
             # queue delay = enqueue -> admission START (scheduling only;
             # the chunk loop's seconds are prefill, not queueing)
             t_start = time.monotonic()
@@ -4016,138 +4060,239 @@ class ContinuousBatcher:
             C = self.prefill_chunk
             ns = [len(r.prompt_ids) for r in reqs]
             note_admit(max(ns))
+            m = len(reqs)
+            end_chunk = [(n - 1) // C for n in ns]
             slots: list[int] = []
+            # the requests still prefilling, by their place in ``reqs``: a
+            # launch's device time is theirs, and their slots are _RESERVED
+            waiting = list(range(m))
             try:
                 for r in reqs:
                     s = self._slots.index(None)
                     self._slots[s] = _RESERVED
                     slots.append(s)
-                m = len(reqs)
-                mpad = 1 << (m - 1).bit_length()
-                idx = list(range(m)) + [0] * (mpad - m)  # pad rows repeat row 0
                 seeds = [
                     r.sp.seed if r.sp.seed is not None else random.getrandbits(31)
                     for r in reqs
                 ]
+                mpad = 1 << (m - 1).bit_length()
+                # the request of each cache row; a pad row repeats a live one
+                cur = waiting + [0] * (mpad - m)
                 km, vm = self._make_row_cache(mpad, self.max_seq)
                 final = jnp.zeros((mpad, 1, cfg.vocab_size), jnp.float32)
-                n_chunks = -(-max(ns) // C)
-                end_chunk = [(ns[i] - 1) // C for i in idx]
-                # per-chunk [mpad, 1, vocab] logits, kept only while the
-                # prefix cache is on: full-chunk END rows become the cached
-                # nodes' first-token logits (transient cost ~n_chunks x
-                # mpad x vocab f32, freed right after harvest below)
+                # per-chunk [w, 1, vocab] logits with the rows they belong
+                # to, kept only while the prefix cache is on: full-chunk END
+                # rows become the cached nodes' first-token logits (transient
+                # cost ~n_chunks x w x vocab f32, freed with the last harvest)
                 glogits: list = [] if pc is not None else None
-                for j in range(n_chunks):
+                # a row whose prompt ended in an earlier chunk (three live
+                # rows keep the width-4 cache) reads position 0; a family
+                # with a recurrent state is told so (-1: none of this chunk's
+                # positions is real), or the padding would run through the
+                # row's state
+                lo = -1 if cfg.slot_state else 0
+                unharvested: list[int] = []
+
+                def harvest(i: int) -> None:
+                    """Row i's full-chunk blocks into the prefix cache (the
+                    paged harvest records its slot's block ids and reads no
+                    row of the pair); jnp.copy detaches each [1, 1, vocab]
+                    end row so the [w, ...] chunk buffers can free."""
+                    cl = []
+                    for lg, at in glogits[: ns[i] // C]:
+                        k = at.index(i)
+                        cl.append(lg if len(at) == 1 else jnp.copy(lg[k : k + 1]))
+                    harvest_prefix(
+                        reqs[i].prompt_ids, km, vm,
+                        0 if paged else cur.index(i), cl, slot=slots[i],
+                    )
+
+                j = 0
+                while waiting:
                     start = j * C
+                    width = len(cur)
+                    self._charge_ctx = tuple(reqs[i] for i in waiting)
                     rows = []
-                    for i in idx:
+                    for i in cur:
                         chunk = reqs[i].prompt_ids[start : start + C]
                         rows.append(chunk + [0] * (C - len(chunk)))
-                    # a row whose prompt ended in an earlier chunk reads
-                    # position 0; a family with a recurrent state is told so
-                    # (-1: none of this chunk's positions is real), or the
-                    # padding would run through the row's state
-                    lo = -1 if cfg.slot_state else 0
-                    last_pos = [
-                        min(max(ns[i] - 1 - start, lo), C - 1) for i in idx
-                    ]
-                    logits, km, vm = self._prefill_chunk_group(
-                        self.params, jnp.asarray(rows, jnp.int32), km, vm,
-                        jnp.full((mpad,), start, jnp.int32),
-                        jnp.asarray(last_pos, jnp.int32),
-                        self._win_bucket(start + C),
-                        _tokens=mpad * C,
-                        _chunk=self._chunk_attrs(start, [n - start for n in ns]),
+                    last_pos = jnp.asarray(
+                        [min(max(ns[i] - 1 - start, lo), C - 1) for i in cur],
+                        jnp.int32,
                     )
-                    final = self._select_end(
-                        final, logits,
-                        jnp.asarray([e == j for e in end_chunk], jnp.bool_),
-                    )
+                    attrs = self._chunk_attrs(
+                        start, [ns[i] - start for i in waiting], width)
+                    if width == 1:
+                        logits, km, vm = self._prefill1(
+                            self.params, jnp.asarray(rows, jnp.int32), km, vm,
+                            jnp.full((1,), start, jnp.int32), last_pos,
+                            self._win_bucket(start + C),
+                            _tokens=min(C, ns[cur[0]] - start), _chunk=attrs,
+                        )
+                        final = logits  # read once, after the row's last chunk
+                    else:
+                        logits, km, vm = self._prefill_chunk_group(
+                            self.params, jnp.asarray(rows, jnp.int32), km, vm,
+                            jnp.full((width,), start, jnp.int32), last_pos,
+                            self._win_bucket(start + C),
+                            _tokens=width * C, _chunk=attrs,
+                        )
+                        final = self._select_end(
+                            final, logits,
+                            jnp.asarray([end_chunk[i] == j for i in cur], jnp.bool_),
+                        )
+                        if width not in self._takes_built:
+                            self._build_takes(km, vm, final)
+                    self.stats.chunk_rows_computed += width
+                    self.stats.chunk_rows_real += len(waiting)
                     if glogits is not None:
-                        glogits.append(logits)
-                    if start + C < max(ns) and not ext_live():
+                        glogits.append((logits, cur))
+                    ending = [i for i in waiting if end_chunk[i] == j]
+                    j += 1
+                    if ending:
+                        self._charge_ctx = tuple(reqs[i] for i in ending)
+                        if paged:
+                            # tables BEFORE harvest (the paged harvest records
+                            # the rows' pool block ids, not device copies)
+                            for i in ending:
+                                tables[slots[i]] = alloc_blocks(
+                                    -(-ns[i] // T), for_req=reqs[i]
+                                )
+                            table_dirty = True
+                        if glogits is not None:
+                            if paged:
+                                unharvested.extend(ending)
+                            else:
+                                # the ring layout's harvest gathers the row's
+                                # blocks out of the pair: BEFORE a finish
+                                # that takes the pair
+                                for i in ending:
+                                    harvest(i)
+                        if width > 1 and all(i in ending for i in cur):
+                            # every row of the cache ends here (a pad row
+                            # repeats one that does): one batched finish
+                            samp = (
+                                jnp.asarray([seeds[i] for i in cur], jnp.int32),
+                                jnp.asarray(
+                                    [reqs[i].sp.temperature for i in cur], jnp.float32
+                                ),
+                                jnp.asarray([reqs[i].sp.top_k for i in cur], jnp.int32),
+                                jnp.asarray([reqs[i].sp.top_p for i in cur], jnp.float32),
+                            )
+                            if paged:
+                                bid_rows = np.zeros((width, max(MB, 1)), np.int32)
+                                for i in ending:
+                                    t = tables[slots[i]]
+                                    bid_rows[cur.index(i), : len(t)] = t
+                                firsts, K, V, tok_dev = self._finish_admit_group_paged(
+                                    self.params, K, V, tok_dev, km, vm, final,
+                                    jnp.asarray(bid_rows),
+                                    jnp.asarray([slots[i] for i in cur], jnp.int32),
+                                    *samp,
+                                )
+                            else:
+                                # shifts AFTER the loop's decodes moved the head
+                                shifts = [
+                                    0 if positional
+                                    else (self._ring_next - ns[i]) % self.max_seq
+                                    for i in cur
+                                ]
+                                firsts, K, V, tok_dev = self._finish_admit_group(
+                                    self.params, K, V, tok_dev, km, vm, final,
+                                    jnp.asarray([slots[i] for i in cur], jnp.int32),
+                                    jnp.asarray(shifts, jnp.int32),
+                                    *samp,
+                                )
+                            records = [("admit", firsts, [
+                                (cur.index(i), slots[i], reqs[i]) for i in ending])]
+                        else:
+                            # the others go on (or a row that ended earlier
+                            # still lies in this cache): each ending row
+                            # through the finish of a lone long prompt
+                            records = []
+                            for i in ending:
+                                k1, v1, l1 = (km, vm, final) if width == 1 else (
+                                    self._take_rows(
+                                        km, vm, final,
+                                        jnp.asarray([cur.index(i)], jnp.int32)))
+                                sp = reqs[i].sp
+                                samp = (
+                                    jnp.int32(seeds[i]), jnp.float32(sp.temperature),
+                                    jnp.int32(sp.top_k), jnp.float32(sp.top_p),
+                                )
+                                if paged:
+                                    t = tables[slots[i]]
+                                    first, K, V, tok_dev = self._finish_admit_paged(
+                                        self.params, K, V, tok_dev, k1, v1, l1,
+                                        jnp.asarray(t + [0] * (MB - len(t)), jnp.int32),
+                                        jnp.int32(slots[i]), *samp,
+                                    )
+                                else:
+                                    shift = jnp.int32(
+                                        0 if positional
+                                        else (self._ring_next - ns[i]) % self.max_seq
+                                    )
+                                    first, K, V, tok_dev = self._finish_admit(
+                                        self.params, K, V, tok_dev, k1, v1, l1,
+                                        jnp.int32(slots[i]), shift, *samp,
+                                    )
+                                records.append(
+                                    ("admit", first, [(0, slots[i], reqs[i])]))
+                        dirty = True
+                        for i in ending:
+                            r, s = reqs[i], slots[i]
+                            r.slot = s
+                            r.pos = ns[i]
+                            self._slots[s] = r
+                            self.stats.count_admitted(r.sp, cfg.vocab_size)
+                            if r.trace is not None:
+                                r.trace.mark("prefill")  # its chunks + finish dispatched
+                            host_pos[s] = ns[i]
+                            host_steps[s] = 1  # the finish program sampled at rng step 0
+                            host_seed[s] = seeds[i]
+                        inflight.extend(records)
+                        self.stats.chunked_group_admits += len(ending)
+                        waiting = [i for i in waiting if i not in ending]
+                        if waiting:
+                            self.stats.chunked_group_early_finishes += len(ending)
+                        w = 1 << max(0, len(waiting) - 1).bit_length()
+                        if waiting and w < width:
+                            # the wide pair is dropped as the narrow one is
+                            # bound: nothing else is allocated between
+                            self._charge_ctx = tuple(reqs[i] for i in waiting)
+                            kept = waiting + [waiting[0]] * (w - len(waiting))
+                            km, vm, final = self._take_rows(
+                                km, vm, final,
+                                jnp.asarray([cur.index(i) for i in kept], jnp.int32),
+                            )
+                            cur = kept
+                            self.stats.chunked_group_narrowings += 1
+                    if waiting and not ext_live():
                         decode_once()
                         pump()
-                if paged:
-                    # tables BEFORE harvest (the paged harvest records the
-                    # rows' pool block ids, not device copies)
-                    for j, s in enumerate(slots):
-                        tables[s] = alloc_blocks(
-                            -(-ns[j] // T), for_req=reqs[j]
-                        )
-                    table_dirty = True
-                if glogits is not None:
-                    # harvest each real row's full-chunk blocks BEFORE the
-                    # finish dispatch; jnp.copy detaches each [1, 1, vocab]
-                    # end row so the [mpad, ...] chunk buffers can free
-                    for j in range(m):
-                        cl = [
-                            jnp.copy(glogits[t][j : j + 1])
-                            if (t + 1) * C <= ns[j]
-                            else None
-                            for t in range(ns[j] // C)
-                        ]
-                        harvest_prefix(
-                            reqs[j].prompt_ids, km, vm, j, cl, slot=slots[j]
-                        )
-                    glogits = None
-                if paged:
-                    bid_rows = np.zeros((mpad, max(MB, 1)), np.int32)
-                    for j in range(m):
-                        t = tables[slots[j]]
-                        bid_rows[j, : len(t)] = t
-                    firsts, K, V, tok_dev = self._finish_admit_group_paged(
-                        self.params, K, V, tok_dev, km, vm, final,
-                        jnp.asarray(bid_rows),
-                        jnp.asarray([slots[i] for i in idx], jnp.int32),
-                        jnp.asarray([seeds[i] for i in idx], jnp.int32),
-                        jnp.asarray(
-                            [reqs[i].sp.temperature for i in idx], jnp.float32
-                        ),
-                        jnp.asarray([reqs[i].sp.top_k for i in idx], jnp.int32),
-                        jnp.asarray([reqs[i].sp.top_p for i in idx], jnp.float32),
-                    )
-                else:
-                    # shifts AFTER the loop: interleaved decodes moved the head
-                    shifts = [
-                        0 if positional else (self._ring_next - ns[i]) % self.max_seq
-                        for i in idx
-                    ]
-                    firsts, K, V, tok_dev = self._finish_admit_group(
-                        self.params, K, V, tok_dev, km, vm, final,
-                        jnp.asarray([slots[i] for i in idx], jnp.int32),
-                        jnp.asarray(shifts, jnp.int32),
-                        jnp.asarray([seeds[i] for i in idx], jnp.int32),
-                        jnp.asarray([reqs[i].sp.temperature for i in idx], jnp.float32),
-                        jnp.asarray([reqs[i].sp.top_k for i in idx], jnp.int32),
-                        jnp.asarray([reqs[i].sp.top_p for i in idx], jnp.float32),
-                    )
+                    # the paged harvest records block ids and cuts each row's
+                    # end logits out of the chunks' buffers, an un-jitted
+                    # slice and copy a chunk: behind a running launch that
+                    # many small programs make the host wait for the device,
+                    # so they go out once the burst is dispatched and what
+                    # was ready is delivered (a live stream's tokens stood
+                    # ready for a launch's time otherwise)
+                    for i in unharvested:
+                        harvest(i)
+                    unharvested.clear()
             except BaseException:
-                for s in slots:  # release reservations; caller emits the error
-                    self._slots[s] = None
-                    if paged and tables[s]:
-                        pool.decref(tables[s])
-                        tables[s] = []
-                        table_dirty = True
+                # release the reservations of the rows not installed (the
+                # caller emits their error); a row installed is a live
+                # request like any other
+                for s in slots:
+                    if self._slots[s] is _RESERVED:
+                        self._slots[s] = None
+                        if paged and tables[s]:
+                            pool.decref(tables[s])
+                            tables[s] = []
+                            table_dirty = True
                 self._charge_ctx = prev_ctx
                 raise
-            dirty = True
-            self.stats.chunked_group_admits += len(reqs)
-            out_rows = []
-            for j, r in enumerate(reqs):
-                s = slots[j]
-                r.slot = s
-                r.pos = ns[j]
-                self._slots[s] = r
-                self.stats.count_admitted(r.sp, cfg.vocab_size)
-                if r.trace is not None:
-                    r.trace.mark("prefill")  # chunk loop + finish dispatched
-                host_pos[s] = ns[j]
-                host_steps[s] = 1  # the finish program sampled at rng step 0
-                host_seed[s] = seeds[j]
-                out_rows.append((j, s, r))
-            inflight.append(("admit", firsts, out_rows))
             self._charge_ctx = prev_ctx
 
         def reset_after_failed_dispatch() -> None:
@@ -4611,14 +4756,17 @@ class ContinuousBatcher:
                                 admit_group_chunked(group)
                         except _PoolExhausted as e:
                             # raised pre-dispatch: the device pool is intact,
-                            # shed the group without the cache reset
+                            # shed the rows not installed (a row whose prompt
+                            # ended earlier decodes on) without the cache reset
                             for req in group:
-                                self._ledger_finalize(req, "shed_after_prefill")
-                                req.emit("err", e)
+                                if req.slot < 0:
+                                    self._ledger_finalize(req, "shed_after_prefill")
+                                    req.emit("err", e)
                         except Exception as e:  # noqa: BLE001 — surface to callers
                             for req in group:
-                                self._ledger_finalize(req, "failed")
-                                req.emit("err", e)
+                                if req.slot < 0:  # the reset fails the installed
+                                    self._ledger_finalize(req, "failed")
+                                    req.emit("err", e)
                             reset_after_failed_dispatch()
                         continue
                 elif head_bucket is not None:

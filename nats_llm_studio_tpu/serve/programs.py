@@ -361,6 +361,41 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
         """Keep each row's logits from the chunk its prompt ENDS in."""
         return jnp.where(is_end[:, None, None], logits, final)
 
+    def rows_of(c, rows):
+        """The rows ``rows`` ([w], traced) of a transient row cache as a
+        [w, ...] cache of their own; what belongs to a row goes with it (a
+        quantised cache's scales, a family's state and rings)."""
+        def take(a, axis=0):
+            # a slice a row, each written into its place of the result: XLA:TPU
+            # cuts a gather of whole rows into hundreds of loops of its own, and
+            # holds every slice of a concatenation as a temporary
+            w = rows.shape[0]
+            if w == 1:
+                return jax.lax.dynamic_slice_in_dim(a, rows[0], 1, axis=axis)
+            def put(t, out):
+                return jax.lax.dynamic_update_slice_in_dim(
+                    out, jax.lax.dynamic_slice_in_dim(a, rows[t], 1, axis=axis), t, axis=axis)
+
+            out = jnp.zeros(a.shape[:axis] + (w,) + a.shape[axis + 1:], a.dtype)
+            return jax.lax.fori_loop(0, w, put, out)
+
+        if has_state(c):
+            st = tuple(take(a, ax) for a, ax in zip(c.st, c.axes))
+            return WithState(rows_of(c.kv, rows), st, c.axes)
+        if is_quantized(c):
+            return KVQ(q=take(c.q), s=take(c.s))
+        return take(c)
+
+    @jax.jit
+    def take_rows(km, vm, final, rows):
+        """A chunked group admit narrows: the rows ``rows`` of its [m, ...]
+        cache pair, and of the end logits kept for them, as a [w, ...] group
+        of their own (w = 1: one row, for the single-row finish or
+        ``prefill1``). km/vm are NOT donated: no [w, ...] result can take an
+        [m, ...] buffer, and the caller drops the wide pair at once."""
+        return (pin_row(rows_of(km, rows)), pin_row(rows_of(vm, rows)),
+                rows_of(final, rows))
+
     @partial(jax.jit, donate_argnums=(1, 2, 3))
     def finish_admit_group(params, K, V, tok, km, vm, final_logits,
                            slots, shifts, seeds, temps, topks, topps):
@@ -508,6 +543,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
         "finish_admit": finish_admit,
         "prefill_chunk_group": prefill_chunk_group,
         "select_end": select_end,
+        "take_rows": take_rows,
         "finish_admit_group": finish_admit_group,
         "decode": decode,
         "decode_pos": decode_pos,

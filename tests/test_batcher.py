@@ -529,3 +529,151 @@ def test_a_wide_bursts_tokens_of_a_row_reach_its_stream_in_one_hand_over(model, 
     # the admit's first token alone, then three bursts of four, then the end
     burst = [("toks", 4)] if wide else [("tok", 1)] * 4
     assert kinds == [("tok", 1), *burst * 3, ("end", 1)]
+
+
+# -- a chunked group admit narrows as its prompts end --------------------------
+#
+# Four prompts of 2, 3, 5 and 9 chunks in ONE group of four: the launches run
+# 4, 4, 4 (three live rows keep the width-4 program), 2, 2, 1, 1, 1, 1 rows
+# wide, each row is finished and decodes once its own prompt has ended, and
+# its tokens are those it gets when admitted alone.
+
+NARROW_CHUNK, NARROW_SEQ = 16, 160
+NARROW_LENS = (21, 40, 70, 140)
+NARROW_WIDTHS = [4, 4, 4, 2, 2, 1, 1, 1, 1]
+NARROW_CASES = [  # family, paged, prefix_cache_blocks
+    ("dense", True, 0), ("dense", False, 0), ("dense", True, 16), ("dense", False, 16),
+    ("latent", True, 0), ("window", True, 0), ("state", True, 0),
+]
+
+
+def _narrow_id(case):
+    family, paged, cache = case
+    return f"{family}-{'paged' if paged else 'ring'}{'-prefix_cache' if cache else ''}"
+
+
+@pytest.fixture(scope="module", params=NARROW_CASES, ids=_narrow_id)
+def narrow_case(request):
+    from test_scopes import _cfg  # the families' toys
+
+    family, paged, cache = request.param
+    cfg = _cfg(family, NARROW_SEQ)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+
+    def batcher():
+        return ContinuousBatcher(
+            params, cfg, max_slots=4, max_seq_len=NARROW_SEQ, buckets=[16, 32],
+            prefill_chunk=NARROW_CHUNK, max_group_long=4, paged=paged, kv_block_tokens=16,
+            prefix_cache_blocks=cache)
+
+    prompts = [[(i * (7 + 2 * k) + 3 + k) % 95 + 32 for i in range(n)]
+               for k, n in enumerate(NARROW_LENS)]
+    # two greedy rows and two that draw under their own seed
+    sps = [SamplingParams(temperature=0.0 if k % 2 else 0.9, max_tokens=6, seed=100 + k)
+           for k in range(len(prompts))]
+    return batcher, prompts, sps
+
+
+def _watch_chunk_launches(b) -> list:
+    """(rows wide, start, {prompt length: tokens streamed so far}) of every
+    chunk launch of ``b``, group program and ``prefill1`` alike, in order. The
+    streamed counts are the owner thread's own, read as it launches."""
+    from nats_llm_studio_tpu.serve.batcher import _Request
+
+    launches: list = []
+    streamed: dict[int, int] = {}
+
+    def watch(inner):
+        def run(params, tokens, km, vm, start, *rest, **kw):
+            for r in b._slots:
+                if isinstance(r, _Request):
+                    streamed[len(r.prompt_ids)] = r.generated
+            launches.append((tokens.shape[0], int(start[0]), dict(streamed)))
+            return inner(params, tokens, km, vm, start, *rest, **kw)
+
+        return run
+
+    b._prefill_chunk_group = watch(b._prefill_chunk_group)
+    b._prefill1 = watch(b._prefill1)
+    return launches
+
+
+async def _one_group(b, prompts, sps):
+    tasks = [asyncio.create_task(_collect(b, p, sp)) for p, sp in zip(prompts, sps)]
+    await asyncio.sleep(0)  # all enqueued before the owner thread starts
+    return await asyncio.gather(*tasks, return_exceptions=True)
+
+
+@async_test(timeout=300.0)  # a family's admit, chunk and burst programs on an empty compile cache
+async def test_a_chunked_group_narrows_as_its_prompts_end(narrow_case):
+    from nats_llm_studio_tpu.obs import spans
+
+    batcher, prompts, sps = narrow_case
+    b = batcher()
+    launches = _watch_chunk_launches(b)
+    spans.clear()
+    try:
+        got = await _one_group(b, prompts, sps)
+        group = list(launches)
+        records = [a for _, _, _, a in spans.records(0.0, float("inf"), "batcher.admit")
+                   if a and a.get("program") == "chunk"]
+        snap = b.stats.snapshot()
+        b.drop_prefix_cache()  # alone means alone: no row of the group to hit
+        alone = [await _collect(b, p, sp) for p, sp in zip(prompts, sps)]
+    finally:
+        b.stop()
+    assert got == alone and all(len(t) == 6 for t in alone)
+    assert [w for w, _, _ in group] == NARROW_WIDTHS
+    assert [s for _, s, _ in group] == [j * NARROW_CHUNK for j in range(len(NARROW_WIDTHS))]
+    # the two short rows have streamed tokens before the longest row's last chunk
+    before_last = group[-1][2]
+    assert before_last.get(NARROW_LENS[0], 0) > 0 and before_last.get(NARROW_LENS[1], 0) > 0
+    assert NARROW_LENS[3] not in before_last
+    real = [4, 4, 3, 2, 2, 1, 1, 1, 1]
+    assert snap["chunked_group_admits"] == 4
+    assert snap["chunk_rows_computed"] == sum(NARROW_WIDTHS)
+    assert snap["chunk_rows_real"] == sum(real)
+    assert snap["chunked_group_narrowings"] == 2 and snap["chunked_group_early_finishes"] == 3
+    if b.cfg.is_mla:  # the latent families' chunk records say it too
+        assert [a["width"] for a in records] == NARROW_WIDTHS
+        assert [a["rows"] for a in records] == real
+    # alone, every launch is one row wide
+    assert {w for w, _, _ in launches[len(group):]} == {1}
+
+
+@async_test(timeout=300.0)
+async def test_a_failed_launch_after_an_early_finish_fails_every_row_once(narrow_case):
+    """The third launch fails (the 2-chunk row is installed and decoding by
+    then): the rows still prefilling get the launch's error and give their
+    slots back, the installed row fails through the reset like any live
+    request, and the batcher serves the next request."""
+    from nats_llm_studio_tpu.serve.batcher import _RESERVED
+
+    batcher, prompts, sps = narrow_case
+    b = batcher()
+    inner, calls = b._prefill_chunk_group, []
+
+    def failing(*args, **kw):
+        calls.append(1)
+        if len(calls) == 3:
+            raise RuntimeError("injected launch failure")
+        return inner(*args, **kw)
+
+    b._prefill_chunk_group = failing
+    try:
+        got = await _one_group(b, prompts, sps)
+        assert all(isinstance(e, RuntimeError) for e in got), got
+        assert "cache reset after a failed device dispatch" in str(got[0])
+        assert all("injected launch failure" in str(e) for e in got[1:])
+        assert b.stats.chunked_group_admits == 1 and b.stats.chunked_group_early_finishes == 1
+        for _ in range(500):  # the reset gives the slots back after it has failed their rows
+            if all(s is None for s in b._slots):
+                break
+            await asyncio.sleep(0.01)
+        assert all(s is None for s in b._slots) and _RESERVED not in b._slots
+        if b.paged:
+            assert b.pool_stats()["blocks_free"] == b.pool_stats()["blocks_total"]
+        again = await _collect(b, prompts[0], sps[0])
+        assert len(again) == 6
+    finally:
+        b.stop()

@@ -4,6 +4,7 @@ one seam every dispatch crosses (``ContinuousBatcher._timed``), which builds a
 shape once and feeds ``lmstudio_program_ms``."""
 
 import ast
+import asyncio
 import json
 from pathlib import Path
 
@@ -28,8 +29,8 @@ from test_serve_e2e import build_tiny_gguf
 ROOT = Path(__file__).resolve().parent.parent
 
 RING = {"prefill1", "prefill_full", "write_prefix_block", "admit_fused", "admit_many_fused",
-        "finish_admit", "prefill_chunk_group", "select_end", "finish_admit_group", "decode",
-        "decode_pos", "decode_pos_ext", "spec_verify", "compact_ring"}
+        "finish_admit", "prefill_chunk_group", "select_end", "take_rows", "finish_admit_group",
+        "decode", "decode_pos", "decode_pos_ext", "spec_verify", "compact_ring"}
 PAGED = {"sample_first", "admit_fused_paged", "admit_many_fused_paged", "finish_admit_paged",
          "finish_admit_group_paged", "fill_row_chunk", "decode_pos_paged",
          "decode_pos_paged_ext", "spec_verify_paged", "pool_copy_block", "decode_pallas",
@@ -228,3 +229,61 @@ async def test_a_chunked_single_admit_never_touches_a_donated_pair(paged, kv_qua
     finally:
         b.stop()
     assert all(leaf.is_deleted() for leaf in jax.tree.leaves(pairs))
+
+
+@pytest.mark.parametrize("cache", [0, 64], ids=["no_prefix_cache", "prefix_cache"])
+@pytest.mark.parametrize("warm", ["warm_chunk_programs", "groups_of_one_length"])
+@async_test(timeout=300.0)
+async def test_a_group_that_narrows_builds_no_program_once_its_widths_are_warm(warm, cache):
+    """What a benchmark's sweep serves (groups of ONE length at every width,
+    which never narrow), with or without ``warm_chunk_programs()`` before it,
+    leaves nothing for a group of mixed lengths to build: the row takes of a
+    width are built where its group program first runs, and the narrowing
+    runs no operation outside the table (every program XLA builds or fetches
+    is an event; the benchmark refuses one inside its window). With the prefix
+    cache on, every row's chunks are harvested on the way."""
+    from nats_llm_studio_tpu.engine.generator import SamplingParams
+
+    chunk, lens = 16, (21, 40, 70, 140)
+    cfg = ModelConfig.tiny(n_layers=2, max_seq_len=160)
+    b = ContinuousBatcher(init_params(cfg, jax.random.PRNGKey(0)), cfg, max_slots=4,
+                          max_seq_len=160, buckets=[16, 32], prefill_chunk=chunk,
+                          max_group_long=4, kv_block_tokens=16, prefix_cache_blocks=cache)
+    sp = SamplingParams(temperature=0.0, max_tokens=6)
+    built = []
+    serial = iter(range(1000))  # no two prompts share a chunk: every admit is a miss
+
+    def on_duration(event, seconds, **kw):
+        if event.endswith("backend_compile_duration"):
+            built.append(str(kw.get("fun_name", "?")))
+
+    async def group(lengths):
+        async def one(n):
+            k = next(serial)
+            prompt = [(i * (7 + 2 * (k % 9)) + 3 * k) % 95 + 32 for i in range(n)]
+            return [t async for t in b.submit(prompt, sp)]
+
+        tasks = [asyncio.create_task(one(n)) for n in lengths]
+        await asyncio.sleep(0)  # one intake, one group
+        return await asyncio.gather(*tasks)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        if warm == "warm_chunk_programs":
+            assert await asyncio.to_thread(b.warm_chunk_programs) > 0
+            assert b._takes_built == {2, 4}
+        for width in (4, 2, 1):
+            for n in lens:
+                await group([n] * width)
+        assert b._takes_built == {2, 4} and b.stats.chunked_group_narrowings == 0
+        assert "take_rows" in " ".join(built)
+        del built[:]
+        got = await group(lens)
+        assert built == [], built
+        assert b.stats.chunked_group_narrowings == 2 and all(len(t) == 6 for t in got)
+        if cache:
+            assert b.prefix_cache.counters()["hits"] == 0
+            assert b.prefix_cache.counters()["inserted_blocks"] > 0
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+        b.stop()

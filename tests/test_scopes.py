@@ -35,15 +35,15 @@ HEAVY = re.compile(r"stablehlo\.(dot_general|custom_call|convolution)\b")
 ANNOTATION = re.compile(r"call_target_name = \"(Sharding|LayoutConstraint|annotate_device_placement)\"")
 
 
-def _cfg(family: str) -> ModelConfig:
+def _cfg(family: str, seq: int = SEQ) -> ModelConfig:
     if FAMILIES[family] is None:
-        return ModelConfig.tiny(n_layers=2, max_seq_len=SEQ)
+        return ModelConfig.tiny(n_layers=2, max_seq_len=seq)
     from benchmark import run
 
     reference, toy = FAMILIES[family]
     ref = run.load_module(ROOT / f"benchmark/references/{reference}.py")
     conf = json.loads((ROOT / f"benchmark/tests/rehearsal/configs/{toy}.json").read_text())
-    return ref.model_config(conf, SEQ).with_(dtype="float32")
+    return ref.model_config(conf, seq).with_(dtype="float32")
 
 
 def _table(cfg: ModelConfig) -> dict:

@@ -13,6 +13,8 @@ this file), everything built from it is built in fixtures or tests, and the
 whole family lives in this one file so one worker owns the library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -905,3 +907,38 @@ def test_a_chunk_of_the_plain_latent_form_holds_no_plane_over_its_32768_window(
     assert ma.temp_size_in_bytes < min(1.5e9, plane + 0.5e9 * (width == 1)), ma.temp_size_in_bytes
     print(f"\nwidth {width}: temp {ma.temp_size_in_bytes / 1e6:.0f} MB, "
           f"alias {ma.alias_size_in_bytes / 1e6:.0f} MB")
+
+
+@pytest.mark.parametrize("wide,narrow", [(4, 2), (2, 1), (4, 1)],
+                         ids=["4_to_2", "2_to_1", "4_to_1"])
+def test_the_row_take_of_a_narrowing_group_writes_its_narrow_pair_and_nothing_else(
+        one_chip, no_cache, mla_plain_cell, wide, narrow):
+    """``take_rows`` at the cell's shapes (rows of a 32,768-token latent pair,
+    252 MB each): what the program holds is the narrow pair it returns, with
+    no temporary the size of a row and no relayouted copy of the wide or the
+    narrow cache on the way (``jnp.take`` became 16,000 lines of loops over
+    128-token pieces, a concatenation of slices held every slice: either
+    would double the narrowing's footprint, which HBM admission does not
+    price). The one copy XLA schedules moves the 128-wide rotary plane
+    between memory spaces in the layout it has."""
+    cfg, _, _, _, seq = mla_plain_cell
+    table = _mla_plain_table(cfg, 64, seq)
+    pair = lambda b: [jax.ShapeDtypeStruct(  # noqa: E731
+        (b, cfg.n_layers, h, seq, w), jnp.bfloat16, sharding=one_chip)
+        for h, w in cfg.kv_cache_dims()]
+    final = jax.ShapeDtypeStruct((wide, 1, cfg.vocab_size), jnp.float32, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((narrow,), jnp.int32, sharding=one_chip)
+    compiled = table["take_rows"].lower(*pair(wide), final, rows).compile()
+    text, ma = compiled.as_text(), compiled.memory_analysis()
+    row = sum(int(np.prod(p.shape)) * 2 for p in pair(1))
+    assert ma.output_size_in_bytes >= narrow * row
+    assert ma.temp_size_in_bytes < row // 8, ma.temp_size_in_bytes
+    for b in (wide, narrow):
+        for p in pair(b):
+            shape = f"bf16[{','.join(map(str, p.shape))}]"
+            for found in _whole_array_copies(text, shape):
+                layouts = {m.replace("S(1)", "") for m in re.findall(
+                    re.escape(shape) + r"(\{[^}]*\})", found)}
+                assert "copy-start(" in found and len(layouts) == 1, found
+    print(f"\n{wide} -> {narrow}: out {ma.output_size_in_bytes / 1e6:.0f} MB, "
+          f"temp {ma.temp_size_in_bytes / 1e6:.0f} MB")
