@@ -31,6 +31,14 @@ each refuses", has the causes each refusal gives):
   ``window`` tokens beside it the window layers'; the prefix cache,
   speculation, int8 KV, the KV tiers, KVX1 export, a mesh and a real GGUF's
   tensors are refused.
+* ``linear_attention.*`` keys present (``models/export.py`` writes them, with
+  ``attention.head_count_kv`` a list, 0 for a layer that keeps a recurrent
+  state): gated-delta-rule linear-attention layers beside gated softmax
+  attention layers with q/k norms and a partly rotated head, softmax-routed
+  experts with a sigmoid-gated shared one in every layer, of which the
+  chip may hold a strided share (``qwen3next``): ``models/gdn_moe.py``.
+  Served on the paged pool of one chip with a per-slot state pool beside
+  it; refused as for the state-space family.
 * ``gemma2``, ``gemma3``, ``qwen2moe``: rejected here (post-norms,
   soft-capping, a softmax-gated shared expert).
 """
@@ -151,6 +159,31 @@ class ModelConfig:
     rope_dim: int = 0
     rope_attn_factor: float = 1.0
     attn_gate: bool = False
+    # -- gated-delta-rule linear attention beside gated attention -------------
+    # (models/gdn_moe.py) layer_types names every layer "linear" or
+    # "attention". A linear layer keeps, a slot, a float32 state [lin_v_heads,
+    # lin_k_dim, lin_v_dim] and the convolution's last ``ssm_conv`` raw inputs
+    # over its q, k and v channels; lin_k_heads key heads serve lin_v_heads
+    # value heads. An attention layer's wq also makes an elementwise sigmoid
+    # gate on the attention output (attn_out_gate), q and k are normalised a
+    # head (qk_norm) and the first ``rope_dim`` dims of a head are rotated.
+    # Every layer's FFN is the routed form of models/experts.py with a softmax
+    # router and a sigmoid gate on the shared expert (shared_gate). The norms'
+    # published gains are zero-centred, x (1 + w): the loader folds 1 + w into
+    # the leaf, so the program multiplies by a plain gain.
+    lin_k_heads: int = 0
+    lin_v_heads: int = 0
+    lin_k_dim: int = 0
+    lin_v_dim: int = 0
+    attn_out_gate: bool = False
+    qk_norm: bool = False
+    shared_gate: bool = False
+    # the chips that share a layer's experts and this chip's rank among them:
+    # expert e lives on chip e mod moe_ep_size at place e // moe_ep_size of
+    # that chip's stacks. The router keeps n_experts outputs; a pick of an
+    # expert that is not held here adds nothing here.
+    moe_ep_size: int = 1
+    moe_ep_rank: int = 0
 
     @property
     def is_mla(self) -> bool:
@@ -165,17 +198,38 @@ class ModelConfig:
         return sum(t == "window" for t in self.layer_types)
 
     @property
+    def n_lin_layers(self) -> int:
+        return sum(t == "linear" for t in self.layer_types)
+
+    @property
     def n_kv_layers(self) -> int:
-        """Layers that hold paged KV: the pool's layer axis (a state-space
-        layer keeps a state, a window layer a ring, both by slot)."""
-        return self.n_layers - self.n_ssm_layers - self.n_win_layers
+        """Layers that hold paged KV: the pool's layer axis (a state-space or
+        linear-attention layer keeps a state, a window layer a ring, all by
+        slot)."""
+        return self.n_layers - self.n_ssm_layers - self.n_win_layers - self.n_lin_layers
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether a slot keeps a recurrent state (Mamba-2's or the gated
+        delta rule's) that a decode step advances in place."""
+        return bool(self.n_ssm_layers or self.n_lin_layers)
 
     @property
     def slot_state(self) -> bool:
         """Whether a slot keeps something beside its KV blocks that no block
         table describes (``ops.kvcache.WithState``): a recurrent state, or
         the window layers' ring."""
-        return bool(self.n_ssm_layers or self.n_win_layers)
+        return bool(self.recurrent or self.n_win_layers)
+
+    @property
+    def lin_conv_dim(self) -> int:
+        """Channels the linear layers' causal convolution runs over: q, k, v."""
+        return 2 * self.lin_k_heads * self.lin_k_dim + self.lin_v_heads * self.lin_v_dim
+
+    @property
+    def n_experts_held(self) -> int:
+        """Experts of a layer this chip's stacks hold (all without a share)."""
+        return self.n_experts // self.moe_ep_size
 
     @property
     def ssm_d_inner(self) -> int:
@@ -203,14 +257,17 @@ class ModelConfig:
             return "ssm_hybrid"
         if self.n_win_layers:
             return "swa_moe"
+        if self.n_lin_layers:
+            return "gdn_moe"
         return "mla_moe" if self.is_mla else "llama"
 
     @property
     def n_moe_layers(self) -> int:
         """Layers whose FFN is the routed-expert form of ``models/experts.py``
-        (the latent-attention and the window-attention families: the Mixtral
-        family routes in every layer and keeps no dense stack)."""
-        routed = (self.is_mla or self.n_win_layers) and self.is_moe
+        (the latent-attention, window-attention and linear-attention
+        families: the Mixtral family routes in every layer and keeps no dense
+        stack)."""
+        routed = (self.is_mla or self.n_win_layers or self.n_lin_layers) and self.is_moe
         return self.n_layers - self.n_dense_layers if routed else 0
 
     @property
@@ -389,6 +446,27 @@ class ModelConfig:
                 n_dense_layers=int(g("leading_dense_block_count", 0)),
                 router_scoring="sigmoid" if int(g("expert_gating_func", 1)) == 2 else "softmax",
                 routed_scaling=float(g("expert_weights_scale", 1.0)),
+            )
+        if g("linear_attention.key_head_count") is not None and kv_by_layer:
+            # gated-delta-rule layers beside gated attention, experts in every
+            # layer: the keys models/export.config_metadata writes
+            family |= dict(
+                layer_types=tuple("attention" if h else "linear" for h in kv_by_layer),
+                lin_k_heads=int(g("linear_attention.key_head_count")),
+                lin_v_heads=int(g("linear_attention.value_head_count")),
+                lin_k_dim=int(g("linear_attention.key_length")),
+                lin_v_dim=int(g("linear_attention.value_length")),
+                ssm_conv=int(g("linear_attention.conv_kernel", 4)),
+                rope_dim=int(g("rope.dimension_count", 0)),
+                attn_out_gate=bool(g("attention.output_gate", False)),
+                qk_norm=bool(g("attention.qk_norm", False)),
+                moe_d_ff=int(g("expert_feed_forward_length", 0)),
+                n_shared_experts=int(g("expert_shared_count", 0)),
+                shared_gate=bool(g("expert_shared_gate", False)),
+                router_scoring="sigmoid" if int(g("expert_gating_func", 1)) == 2 else "softmax",
+                routed_scaling=float(g("expert_weights_scale", 1.0)),
+                moe_ep_size=int(g("expert_parallel.count", 1)),
+                moe_ep_rank=int(g("expert_parallel.rank", 0)),
             )
         kwargs.update(family)  # family quirks win over absent metadata keys
         return cls(**kwargs)

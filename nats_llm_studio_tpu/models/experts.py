@@ -1,12 +1,23 @@
-"""The routed-expert layer two model files share: sigmoid-scored experts with
-a selection bias beside always-on shared ones, dropless (``models/mla_moe.py``
-with latent attention, ``models/swa_moe.py`` with window attention).
+"""The routed-expert layer three model files share: routed experts beside
+always-on shared ones, dropless (``models/mla_moe.py`` with latent attention,
+``models/swa_moe.py`` with window attention: sigmoid scores with a selection
+bias; ``models/gdn_moe.py`` with linear attention: softmax scores, a sigmoid
+gate on the shared expert, and a share of the experts on this chip).
 
-A stack of such layers holds ``router`` [L, d, E], ``e_bias`` [L, E], the
-expert stacks ``w_gate_e`` / ``w_up_e`` [L, E, d, f], ``w_down_e`` [L, E, f,
-d] and the shared expert's ``w_gate_s`` / ``w_up_s`` / ``w_down_s``.
+A stack of such layers holds ``router`` [L, d, E], ``e_bias`` [L, E] (sigmoid
+scoring only), the expert stacks ``w_gate_e`` / ``w_up_e`` [L, E_held, d, f],
+``w_down_e`` [L, E_held, f, d], the shared expert's ``w_gate_s`` / ``w_up_s``
+/ ``w_down_s`` and, with ``cfg.shared_gate``, its gate ``shared_gate`` [L, d].
 ``expert_path`` names the form a call takes from its shapes and leaf types
 alone; ``moe_ffn`` computes it (``ops/moe_experts.py`` has the two kernels).
+
+**A share of the experts** (``cfg.moe_ep_size`` chips share a layer, this one
+is ``cfg.moe_ep_rank``): the router keeps its E outputs and its top-k over
+all of them, the gates are normalised over all k picks, and the stacks hold
+the E / size experts e with e mod size == rank, expert e at place e // size.
+A pick of an expert that lives elsewhere adds nothing here: what comes back
+is this chip's partial sum (its picks + the shared expert), and nothing
+stands in for the other chips or their exchange.
 """
 
 from __future__ import annotations
@@ -32,9 +43,15 @@ _EXPERT_ACT_BYTES = 256 << 20
 def route(h: jax.Array, p: Params, cfg: ModelConfig):
     """(idx [B, T, k], gate [B, T, k] f32): the k experts with the largest
     sigmoid score + selection bias, gated by their normalised scores times
-    ``routed_scaling`` (the bias picks, it does not weigh)."""
+    ``routed_scaling`` (the bias picks, it does not weigh); or, with
+    ``cfg.router_scoring == "softmax"`` and no bias leaf, the k largest of a
+    softmax over all the experts, renormalised over the k."""
     logits = jnp.einsum("btd,de->bte", h.astype(jnp.float32),
                         p["router"].astype(jnp.float32), precision=_HI)
+    if cfg.router_scoring == "softmax" and "e_bias" not in p:
+        chosen, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.n_experts_used)
+        gate = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * cfg.routed_scaling
+        return idx, gate
     score = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(score + p["e_bias"].astype(jnp.float32), cfg.n_experts_used)
     chosen = jnp.take_along_axis(score, idx, axis=-1)
@@ -43,6 +60,21 @@ def route(h: jax.Array, p: Params, cfg: ModelConfig):
 
 
 EXPERT_LEAVES = ("w_gate_e", "w_up_e", "w_down_e")
+
+
+def stats_width(cfg: ModelConfig) -> int:
+    """Counters a layer a step ``moe_ffn`` gives: (experts hit, most rows on
+    one, live rows) and, where the chip holds a share of the experts, the
+    live rows' picks that landed on an expert held here."""
+    return 4 if cfg.moe_ep_size > 1 else 3
+
+
+def held_place(idx: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Picks ``idx`` (expert ids of the whole layer) as places in this chip's
+    stacks: e // size where e mod size is this chip's rank, else
+    ``n_experts_held``, one past the last place (an absent expert)."""
+    size = cfg.moe_ep_size
+    return jnp.where(idx % size == cfg.moe_ep_rank, idx // size, cfg.n_experts_held)
 
 
 def expert_path(cfg: ModelConfig, rows: int, stack: Params, mesh=None) -> str:
@@ -64,6 +96,8 @@ def expert_path(cfg: ModelConfig, rows: int, stack: Params, mesh=None) -> str:
     one_device = mesh is None or mesh.size == 1
     if not (plain and one_device):
         return "dense"
+    # a chip that holds 1 / size of the experts sees 1 / size of the picks
+    # under a balanced router: the same comparison, both sides over size
     return "hit_list" if rows * cfg.n_experts_used < cfg.n_experts else "grouped"
 
 
@@ -101,18 +135,28 @@ def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = 
     XLA copy a layer's 1.4 GB of experts into the loop's operand every step
     (46 ms a decode step for 15: PERF.md, PR 29). A prefill splits so that
     [rows, group, width] stays under ``_EXPERT_ACT_BYTES``."""
-    e, k = cfg.n_experts, cfg.n_experts_used
+    e, k = cfg.n_experts_held, cfg.n_experts_used
+    share = cfg.moe_ep_size > 1
     with jax.named_scope("router"):
         idx, gate = route(h, p, cfg)
+        if share:
+            # places in this chip's stacks; an absent expert's one-hot is all
+            # zeros, so it is in no sum below
+            idx = held_place(idx, cfg)
         picked = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # [B, T, k, E]
         combine = jnp.sum(picked * gate[..., None], axis=-2)  # [B, T, E] f32
         on = jnp.sum(picked, axis=-2)  # [B, T, E]: 1 where a row picked the expert
         rows_on = jnp.sum(on if live is None else on * live[:, None, None], axis=(0, 1))
         stats = None if live is None else jnp.stack(
-            [jnp.sum(rows_on > 0), jnp.max(rows_on), jnp.sum(live) * h.shape[1]]).astype(jnp.int32)
+            [jnp.sum(rows_on > 0), jnp.max(rows_on), jnp.sum(live) * h.shape[1]]
+            + ([jnp.sum(rows_on)] if share else [])).astype(jnp.int32)
     rows = h.shape[0] * h.shape[1]
     with jax.named_scope("shared"):
         acc = swiglu(h, p["w_gate_s"], p["w_up_s"], p["w_down_s"], cfg.mlp_act).astype(jnp.float32)
+        if cfg.shared_gate:
+            acc = acc * jax.nn.sigmoid(jnp.einsum(
+                "btd,d->bt", h.astype(jnp.float32), p["shared_gate"].astype(jnp.float32),
+                precision=_HI))[..., None]
     with jax.named_scope("experts"):
         if form != "dense":
             from ..ops import moe_experts
@@ -128,7 +172,12 @@ def moe_ffn(h: jax.Array, p: Params, cfg: ModelConfig, live: jax.Array | None = 
             y = moe_experts.moe_grouped_experts_auto(
                 jnp.take(h.reshape(rows, -1), order // k, axis=0), gate.reshape(-1)[order],
                 jnp.sum(on, axis=(0, 1)), place, *stacks)  # [rows x k, d] f32, sorted
-            acc = acc + jnp.sum(y[at], axis=1).reshape(acc.shape)
+            y = y[at]  # [rows, k, d]: each row's picks
+            if share:
+                # an absent expert's pairs sort last, past every expert's
+                # rows: no visit of the kernel wrote them
+                y = jnp.where((idx.reshape(rows, k) < e)[..., None], y, 0.0)
+            acc = acc + jnp.sum(y, axis=1).reshape(acc.shape)
             return acc.astype(h.dtype), stats
         combine = combine.astype(h.dtype)
         act_bytes = rows * e * cfg.moe_d_ff * h.dtype.itemsize

@@ -128,6 +128,35 @@ def config_metadata(cfg: ModelConfig, name: str) -> dict[str, Any]:
             f"{a}.expert_gating_func": 2 if cfg.router_scoring == "sigmoid" else 1,
             f"{a}.expert_weights_scale": cfg.routed_scaling,
         }
+    if cfg.n_lin_layers:
+        # gated-delta-rule layers beside gated attention (qwen3next): kv heads
+        # a layer as granitehybrid writes them (0 = a layer with a state), the
+        # linear layers' five sizes, the attention gate and norms, the router's
+        # form, the shared expert's gate, and the share of a layer's experts
+        # this chip holds. norm_zero_centered says the published gains are w
+        # of x (1 + w): a loader folds 1 + w into the leaf
+        a = cfg.arch
+        md |= {
+            f"{a}.attention.head_count_kv": [
+                cfg.n_kv_heads if t == "attention" else 0 for t in cfg.layer_types],
+            f"{a}.linear_attention.key_head_count": cfg.lin_k_heads,
+            f"{a}.linear_attention.value_head_count": cfg.lin_v_heads,
+            f"{a}.linear_attention.key_length": cfg.lin_k_dim,
+            f"{a}.linear_attention.value_length": cfg.lin_v_dim,
+            f"{a}.linear_attention.conv_kernel": cfg.ssm_conv,
+            f"{a}.full_attention_interval": cfg.layer_types.index("attention") + 1,
+            f"{a}.rope.dimension_count": cfg.rope_dim,
+            f"{a}.attention.output_gate": cfg.attn_out_gate,
+            f"{a}.attention.qk_norm": cfg.qk_norm,
+            f"{a}.attention.norm_zero_centered": True,
+            f"{a}.expert_feed_forward_length": cfg.moe_d_ff,
+            f"{a}.expert_shared_count": cfg.n_shared_experts,
+            f"{a}.expert_shared_gate": cfg.shared_gate,
+            f"{a}.expert_gating_func": 2 if cfg.router_scoring == "sigmoid" else 1,
+            f"{a}.expert_weights_scale": cfg.routed_scaling,
+            f"{a}.expert_parallel.count": cfg.moe_ep_size,
+            f"{a}.expert_parallel.rank": cfg.moe_ep_rank,
+        }
     if cfg.arch in ("granite", "granitehybrid"):
         md[f"{cfg.arch}.embedding_scale"] = cfg.embedding_scale
         md[f"{cfg.arch}.residual_scale"] = cfg.residual_scale

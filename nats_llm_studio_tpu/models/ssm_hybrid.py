@@ -87,18 +87,31 @@ def state_shapes(cfg: ModelConfig, rows: int) -> tuple[tuple, tuple]:
     return (((lm, rows, cfg.ssm_conv, cfg.ssm_conv_dim), (rows,)), ((rows, lm) + plane,))
 
 
-def make_state(cfg: ModelConfig, rows: int):
-    """Zeroed state for ``rows`` rows: (K's ``st``, its axes), (V's, its)."""
-    (tail, seen), (plane,) = state_shapes(cfg, rows)
+def zeroed_state(cfg: ModelConfig, shapes):
+    """Zeroed state of ``shapes`` (a family's ``state_shapes``): (K's ``st``,
+    its axes), (V's, its): the tail in the serving dtype and ``seen`` beside
+    K, the float32 state beside V."""
+    (tail, seen), (plane,) = shapes
     return (((jnp.zeros(tail, jnp.dtype(cfg.dtype)), jnp.zeros(seen, jnp.int32)), K_AXES),
             ((jnp.zeros(plane, jnp.float32),), V_AXES))
+
+
+def state_bytes(cfg: ModelConfig, shapes) -> int:
+    """Device bytes of a state of ``shapes``."""
+    (tail, seen), (plane,) = shapes
+    return (math.prod(tail) * jnp.dtype(cfg.dtype).itemsize + 4 * math.prod(seen)
+            + math.prod(plane) * 4)
+
+
+def make_state(cfg: ModelConfig, rows: int):
+    """Zeroed state for ``rows`` rows: (K's ``st``, its axes), (V's, its)."""
+    return zeroed_state(cfg, state_shapes(cfg, rows))
 
 
 def state_bytes_per_slot(cfg: ModelConfig) -> int:
     """Device bytes one slot's state takes (what admission prices a slot at
     beside its KV blocks)."""
-    (tail, _), (plane,) = state_shapes(cfg, 1)
-    return math.prod(tail) * jnp.dtype(cfg.dtype).itemsize + 4 + math.prod(plane) * 4
+    return state_bytes(cfg, state_shapes(cfg, 1))
 
 
 def make_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
@@ -245,26 +258,35 @@ def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
         return params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
 
 
-def _layers(params: Params, cfg: ModelConfig, x, carry, mamba, attention):
+# a layer kind's stack under ``blocks``
+STACK = {"mamba": "mamba", "attention": "attn", "linear": "linear"}
+
+
+def _layers(params: Params, cfg: ModelConfig, x, carry, mixers, ffn=None):
     """All layers in model order: one scan over the periods, and inside a
     period one scan over each run of layers of one kind (granite-4.0-h: 5
     mamba, 1 attention, 4 mamba), so a program's text holds one layer of a
-    run and not the period's ten. ``mamba(h, p, carry, layer) -> (out,
-    carry)`` and ``attention(h, p, carry, layer) -> (out, carry)`` are the
-    caller's; a ``layer`` is the layer's place in its own stack."""
+    run and not the period's ten. ``mixers[kind](h, p, carry, layer) -> (out,
+    carry)`` are the caller's, one a kind of ``cfg.layer_types``; a ``layer``
+    is the layer's place in its own kind's stack (``STACK``).
+
+    The FFN half is the dense SwiGLU whose leaves lie in the mixer's stack,
+    or the caller's: ``ffn(x, carry, place) -> (x, carry)`` with ``place`` the
+    layer's place in the model (``models/gdn_moe.py``: routed experts in a
+    stack of their own, one entry a layer)."""
     periods, kinds = period_plan(cfg)
-    per = {"mamba": kinds.count("mamba"), "attention": kinds.count("attention")}
-    stacks = {"mamba": params["blocks"].get("mamba"), "attention": params["blocks"].get("attn")}
-    mixers = {"mamba": mamba, "attention": attention}
-    runs, at = [], {"mamba": 0, "attention": 0}   # [kind, first of its kind in the period, layers]
-    for kind in kinds:
+    per = {kind: kinds.count(kind) for kind in mixers}
+    stacks = {kind: params["blocks"].get(STACK[kind]) for kind in mixers}
+    # [kind, first of its kind in the period, layers, first layer of the run in the period]
+    runs, at = [], {kind: 0 for kind in mixers}
+    for j, kind in enumerate(kinds):
         if runs and runs[-1][0] == kind:
             runs[-1][2] += 1
         else:
-            runs.append([kind, at[kind], 1])
+            runs.append([kind, at[kind], 1, j])
         at[kind] += 1
 
-    def one(c, kind, layer):
+    def one(c, kind, layer, place):
         x, carry = c
         # ONE slice a weight, out of the whole stack at the layer's own
         # place (as a scan over the stack would take it): the dot reads it
@@ -273,22 +295,27 @@ def _layers(params: Params, cfg: ModelConfig, x, carry, mamba, attention):
         p = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, layer, axis=0, keepdims=False),
             stacks[kind])
-        with jax.named_scope("seq/ssm" if kind == "mamba" else "seq/attn"):
+        with jax.named_scope("seq/ssm" if kind == "mamba" else
+                             "seq/linear" if kind == "linear" else "seq/attn"):
             out, carry = mixers[kind](rms_norm(x, p["mix_norm"], cfg.rms_eps), p, carry, layer)
             x = x + out * cfg.residual_scale
+        if ffn is not None:
+            return ffn(x, carry, place())
         with jax.named_scope("ffn/mlp"):
             h = rms_norm(x, p["ffn_norm"], cfg.rms_eps)
             x = x + swiglu(h, p["w_gate"], p["w_up"], p["w_down"], cfg.mlp_act) * cfg.residual_scale
         return x, carry
 
     def period(c, i):
-        for kind, first, count in runs:
+        for kind, first, count, start in runs:
             base = i * per[kind] + first
             if count == 1:
-                c = one(c, kind, base)
+                c = one(c, kind, base, lambda: i * len(kinds) + start)
             else:
-                c, _ = jax.lax.scan(lambda c, j, kind=kind, base=base: (one(c, kind, base + j), None),
-                                    c, jnp.arange(count, dtype=jnp.int32))
+                c, _ = jax.lax.scan(
+                    lambda c, j, kind=kind, base=base, start=start: (
+                        one(c, kind, base + j, lambda: i * len(kinds) + start + j), None),
+                    c, jnp.arange(count, dtype=jnp.int32))
         return c, None
 
     (x, carry), _ = jax.lax.scan(period, (x, carry), jnp.arange(periods, dtype=jnp.int32))
@@ -356,7 +383,8 @@ def forward(
         return mm(o.reshape(b, t, -1), p["wo"]), (kc, vc, tails, states)
 
     x, (kc, vc, tails, states) = _layers(
-        params, cfg, x, (k_cache.kv, v_cache.kv, tails, states), mamba, attention)
+        params, cfg, x, (k_cache.kv, v_cache.kv, tails, states),
+        {"mamba": mamba, "attention": attention})
     from .llama import lm_head_logits
 
     at = None if logit_positions is None else jnp.maximum(logit_positions, 0)
@@ -411,7 +439,8 @@ def forward_decode_paged(
         return mm(unpack_o(o, cfg).reshape(b, w, -1), p["wo"]), (kp, vp, tails, states)
 
     x, (kp, vp, tails, states) = _layers(
-        params, cfg, x, (k_pool.kv, v_pool.kv, tails, states), mamba, attention)
+        params, cfg, x, (k_pool.kv, v_pool.kv, tails, states),
+        {"mamba": mamba, "attention": attention})
     from .llama import lm_head_logits
 
     logits = lm_head_logits(params, cfg, x, None, w)

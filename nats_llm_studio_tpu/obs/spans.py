@@ -36,7 +36,7 @@ SPAN_NAMES = (
     "worker.publish",      # serialising a chunk + await nc.publish on the loop thread
 )
 
-# every ``jax.named_scope`` the four model files, ``models/experts.py`` and
+# every ``jax.named_scope`` the five model files, ``models/experts.py`` and
 # the step programs open, as the path it leaves in an operation's ``op_name``
 # (``jit(decode_pos_pallas)/while/body/closed_call/seq/attn/dot_general``).
 # Two levels at most, the same words in every family. A top-level word is
@@ -53,6 +53,7 @@ SCOPE_NAMES = (
     "seq/window",    # the same over a ring of the last ``window`` keys (models/swa_moe.py)
     "seq/mla",       # latent attention: down and up projections, the latent write, absorbed or expanded
     "seq/ssm",       # Mamba-2: in-projection, convolution, scan or ssm_state_step, gated norm, out
+    "seq/linear",    # gated delta rule: in-projections, convolution, chunked rule or gated_delta_step, gated norm, out
     "ffn",           # the position-wise block, with the norm before and the add after
     "ffn/mlp",       # the dense SwiGLU
     "ffn/router",    # scores, top-k, gates, the expert counters

@@ -93,6 +93,15 @@ def load_params_sharded(
             "yet; the tree to build is models.swa_moe.init_params' (attention "
             "leaves in blocks.full and blocks.win, MLP leaves in blocks.dense "
             "and blocks.moe), placed by param_sharding_rules")
+    if cfg.n_lin_layers:
+        raise NotImplementedError(
+            f"{cfg.arch}: no GGUF tensor-name map for linear-attention models "
+            "yet; the tree to build is models.gdn_moe.init_params' (mixers in "
+            "blocks.linear and blocks.attn, every layer's experts in blocks.moe: "
+            "the experts e with e mod expert_parallel.count == rank, expert e at "
+            "place e // count; the in-projections' interleaved heads laid out "
+            "plainly as [q | k | v | z], [b | a] and [q | gate]; 1 + w folded "
+            "into every zero-centred norm gain), placed by param_sharding_rules")
     rules = param_sharding_rules(mesh, cfg)
 
     def t(name: str) -> np.ndarray:

@@ -33,6 +33,8 @@ def _leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
         return _ssm_leaves(cfg, dtype_bytes)
     if cfg.n_win_layers:
         return _swa_leaves(cfg, dtype_bytes)
+    if cfg.n_lin_layers:
+        return _gdn_leaves(cfg, dtype_bytes)
     out: dict[str, _Leaf] = {
         "embed": _Leaf((V, d), (), dtype_bytes),
         "out_norm": _Leaf((d,), (), dtype_bytes),
@@ -152,6 +154,34 @@ def _swa_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
     return out
 
 
+def _gdn_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
+    """The leaves of ``models.gdn_moe.init_params``, unsharded (the family
+    serves on one chip a replica, which may be one chip's share of a layer's
+    experts: ``n_experts_held``); norms and the small decay leaves are left
+    out."""
+    from ..ops.wquant import quantizable
+
+    d, V, hd, hq, hkv = cfg.d_model, cfg.vocab_size, cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+    e, eh, fe = cfg.n_experts, cfg.n_experts_held, cfg.moe_d_ff
+    fs, vd = cfg.n_shared_experts * cfg.moe_d_ff, cfg.lin_v_heads * cfg.lin_v_dim
+    stacks = (("linear", cfg.n_lin_layers, {
+                  "w_qkvz": (d, cfg.lin_conv_dim + vd), "w_ba": (d, 2 * cfg.lin_v_heads),
+                  "conv_w": (cfg.ssm_conv, cfg.lin_conv_dim), "w_out": (vd, d)}),
+              ("attn", cfg.n_kv_layers, {
+                  "wq": (d, (2 if cfg.attn_out_gate else 1) * hq * hd), "wk": (d, hkv * hd),
+                  "wv": (d, hkv * hd), "wo": (hq * hd, d)}),
+              ("moe", cfg.n_moe_layers, {
+                  "router": (d, e), "w_gate_e": (eh, d, fe), "w_up_e": (eh, d, fe),
+                  "w_down_e": (eh, fe, d), "w_gate_s": (d, fs), "w_up_s": (d, fs),
+                  "w_down_s": (fs, d)}))
+    out = {"embed": _Leaf((V, d), (), dtype_bytes),
+           "lm_head": _Leaf((d, V), (), dtype_bytes, True)}
+    for name, L, leaves in stacks:
+        for k, shape in leaves.items() if L else ():
+            out[f"blocks.{name}.{k}"] = _Leaf((L,) + shape, (), dtype_bytes, quantizable(k))
+    return out
+
+
 def state_slot_bytes(cfg: ModelConfig) -> int:
     """Device bytes of what ONE slot keeps beside its KV blocks (0 for a
     family that keeps nothing there): a state-space family's recurrent
@@ -165,6 +195,10 @@ def state_slot_bytes(cfg: ModelConfig) -> int:
         from ..models.swa_moe import ring_bytes_per_slot
 
         return ring_bytes_per_slot(cfg)
+    if cfg.n_lin_layers:
+        from ..models.gdn_moe import state_bytes_per_slot
+
+        return state_bytes_per_slot(cfg)
     return 0
 
 

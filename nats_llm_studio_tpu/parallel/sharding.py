@@ -58,6 +58,8 @@ def param_sharding_rules(mesh: Mesh, cfg: ModelConfig | None = None) -> dict[str
         return _ssm_rules(pp)
     if cfg is not None and cfg.n_win_layers:
         return _swa_rules(ep, pp)
+    if cfg is not None and cfg.n_lin_layers:
+        return _gdn_rules(pp)
     return {
         "embed": P(None, None),  # replicated: read once per token, cheap
         "out_norm": P(None),
@@ -129,6 +131,23 @@ def _swa_rules(ep, pp) -> dict[str, P]:
         rules |= {f"blocks.{name}.{k}": P(pp, *[None] * r) for k, r in leaves.items()}
     rules |= {f"blocks.moe.{k}": P(pp, ep, None, None)
               for k in ("w_gate_e", "w_up_e", "w_down_e")}
+    return rules
+
+
+def _gdn_rules(pp) -> dict[str, P]:
+    """A rule for every leaf ``models.gdn_moe.init_params`` makes: the three
+    stacks' layer axis on pp, everything else whole. The experts a chip holds
+    of a layer are the model's own (``moe_ep_size`` / ``moe_ep_rank`` in its
+    header), not a mesh axis: the family is served on one chip a replica
+    (``validate_mesh_for_config`` refuses a mesh over it)."""
+    linear = {"mix_norm": 1, "w_qkvz": 2, "w_ba": 2, "conv_w": 2, "dt_bias": 1, "a_log": 1,
+              "gate_norm": 1, "w_out": 2}
+    attn = {"mix_norm": 1, "wq": 2, "wk": 2, "wv": 2, "wo": 2, "q_norm": 1, "k_norm": 1}
+    moe = {"ffn_norm": 1, "router": 2, "e_bias": 1, "shared_gate": 1, "w_gate_e": 3,
+           "w_up_e": 3, "w_down_e": 3, "w_gate_s": 2, "w_up_s": 2, "w_down_s": 2}
+    rules = {"embed": P(None, None), "out_norm": P(None), "lm_head": P(None, None)}
+    for name, leaves in (("linear", linear), ("attn", attn), ("moe", moe)):
+        rules |= {f"blocks.{name}.{k}": P(pp, *[None] * r) for k, r in leaves.items()}
     return rules
 
 
@@ -284,6 +303,13 @@ def validate_mesh_for_config(mesh: Mesh, cfg: ModelConfig,
             f"state-space models ({cfg.arch}) serve on one chip a replica "
             "(MESH_SHAPE=off): the scan's heads and the per-slot state pool "
             "have no mesh split yet"
+        )
+    if cfg.n_lin_layers and mesh.size > 1:
+        raise ValueError(
+            f"linear-attention models ({cfg.arch}) serve on one chip a replica "
+            "(MESH_SHAPE=off): the per-slot state pool has no mesh split yet, and "
+            "the chip's share of a layer's experts is the model's own "
+            "(expert_parallel.count / rank in its header), with no exchange"
         )
     if cfg.n_win_layers and mesh.size > 1:
         raise ValueError(

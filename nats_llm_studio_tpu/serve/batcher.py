@@ -146,8 +146,18 @@ _SLOT_STATE_CAUSES = {
 }
 
 
+# a linear-attention family (models/gdn_moe.py) keeps a recurrent state as the
+# state-space family does: the same causes, but for what holds int8 KV back
+_SLOT_STATE_CAUSES["linear"] = _SLOT_STATE_CAUSES["state"] | {
+    "kv_quant": "TPU_KV_QUANT=int8 is not implemented for linear-attention models "
+                "(the caches ride with a float32 state that has no scale leaf): "
+                "unset TPU_KV_QUANT",
+}
+
+
 def _slot_state_causes(cfg: ModelConfig) -> dict[str, str]:
-    return _SLOT_STATE_CAUSES["state" if cfg.n_ssm_layers else "ring"]
+    return _SLOT_STATE_CAUSES[
+        "state" if cfg.n_ssm_layers else "linear" if cfg.n_lin_layers else "ring"]
 
 
 class BatcherStopped(RuntimeError):
@@ -374,7 +384,13 @@ class BatcherStats:
     expert_rows_max: int = 0
     expert_rows: int = 0
     expert_steps: int = 0
-    # state-space layers (models/ssm_hybrid.py): rows whose recurrent state a
+    # the (row, pick) pairs the live rows routed, and those whose expert this
+    # chip holds (all of them unless the chip holds a share of a layer's
+    # experts: models/experts.py)
+    moe_picks: int = 0
+    moe_picks_held: int = 0
+    # state-space layers (models/ssm_hybrid.py) and linear-attention layers
+    # (models/gdn_moe.py): rows whose recurrent state a
     # decode step advanced (live rows x steps of every burst), the steps, the
     # slots whose state a step moved (the slots the launch's block table
     # lists x steps: over slots x steps it is the share of the state pool a
@@ -551,6 +567,22 @@ class BatcherStats:
         for k, v in burst.items():
             setattr(self, k, getattr(self, k) + v)
         return burst | ({"expert_path": self.expert_path} if self.expert_path else {})
+
+    def record_picks(self, rows: int, used: int, held=None) -> dict[str, int]:
+        """One burst's (row, pick) pairs: its live rows over steps and expert
+        layers (``expert_rows``) routed ``used`` picks each, and ``held``
+        [layers, steps] of them landed on an expert this chip holds (None:
+        the chip holds every expert). Returns what the readback span carries."""
+        picks = rows * used
+        burst = {"moe_picks": picks,
+                 "moe_picks_held": picks if held is None else int(np.asarray(held).sum())}
+        for k, v in burst.items():
+            setattr(self, k, getattr(self, k) + v)
+        return burst
+
+    def picks_counters(self) -> dict[str, int]:
+        """Exposed by serve/worker.py as lmstudio_moe_picks{,_held}_total."""
+        return {"picks": self.moe_picks, "picks_held": self.moe_picks_held}
 
     def record_state(self, rows: int, steps: int, listed: int) -> dict[str, int]:
         """One decode burst of a family with a recurrent state: ``rows`` live
@@ -864,7 +896,7 @@ class ContinuousBatcher:
                 books = StatePool(
                     max_slots, state_slot_bytes(cfg),
                     lambda: sum(r is not None for r in self._slots))
-                if cfg.n_ssm_layers:
+                if cfg.recurrent:
                     self._state_pool = books
                 else:
                     self._window_pool = books
@@ -969,7 +1001,9 @@ class ContinuousBatcher:
         self._spec_accept_ewma = 0.0
         self.stats = BatcherStats()
         if cfg.n_moe_layers:
-            from ..models.experts import expert_path
+            from ..models.experts import expert_path, stats_width
+
+            self._moe_stats_width = stats_width(cfg)
 
             # the form the expert layers of a call of ``rows`` rows take
             self._expert_form = lambda rows: expert_path(
@@ -1134,10 +1168,10 @@ class ContinuousBatcher:
             # classification, distinct metrics row
             stats.record_program(_name or name, ms, _tokens)
             if _chunk is not None:
-                # a chunk launch of a latent family: a record of its own in the
-                # ring (no annotation: the admit's span is open around it), so
-                # that a reader prices the launches of a traced span by their
-                # own rows and keys
+                # a chunk launch of a latent or a linear-attention family: a
+                # record of its own in the ring (no annotation: the admit's
+                # span is open around it), so that a reader prices the launches
+                # of a traced span by their own rows and keys
                 p1 = time.perf_counter()
                 obs_spans.record("batcher.admit", p1 - (t1 - t0), p1, dict(
                     _chunk, program="chunk", **({"experts": form} if form else {})))
@@ -1191,13 +1225,14 @@ class ContinuousBatcher:
 
     def _chunk_attrs(self, start: int, lens: list[int], width: int = 1) -> dict | None:
         """What a chunk launch's ``batcher.admit`` record carries, for a
-        latent family (None otherwise). ``lens``: each row's real tokens from
-        ``start`` on; ``width``: the rows the launch computes. ``rows`` are
+        latent or a linear-attention family (None otherwise). ``lens``: each
+        row's real tokens from ``start`` on; ``width``: the rows the launch
+        computes. ``rows`` are
         those that hold tokens of their prompt in this chunk, ``tokens``
         theirs (at most a chunk a row), ``live_keys`` the keys a row's chunk
         attends over (its prefix through this chunk) summed over the rows,
         ``pairs`` the (query, key) pairs of their causal attention."""
-        if not self.cfg.is_mla:
+        if not (self.cfg.is_mla or self.cfg.n_lin_layers):
             return None
         real = [min(n, self.prefill_chunk) for n in lens if n > 0]
         return {"rows": len(real), "width": width, "tokens": sum(real),
@@ -2419,7 +2454,14 @@ class ContinuousBatcher:
                     if ids.shape[0] > B:
                         # an expert family's burst: the rows past B are its
                         # counters (decode_pos_moe)
-                        spn.attrs.update(self.stats.record_moe(ids[B:]))
+                        # (at width 4, a chip with a share of the experts,
+                        # each layer's fourth row is the picks held here)
+                        c = ids[B:].reshape(-1, self._moe_stats_width, ids.shape[-1])
+                        burst = self.stats.record_moe(c[:, :3].reshape(-1, ids.shape[-1]))
+                        spn.attrs.update(burst)
+                        spn.attrs.update(self.stats.record_picks(
+                            burst["expert_rows"], cfg.n_experts_used,
+                            c[:, 3] if c.shape[1] > 3 else None))
                     if self._state_pool is not None:
                         spn.attrs.update(self.stats.record_state(len(rows), n, listed))
                     if self._window_pool is not None:

@@ -1905,6 +1905,11 @@ class Worker:
                 # rows x k / experts is the imbalance
                 for name, v in moe().items():
                     r.counter(f"lmstudio_moe_{name}_total", v, labels=labels)
+                for name, v in getattr(stats, "picks_counters", dict)().items():
+                    # the (row, pick) pairs the live rows routed, and those
+                    # whose expert this chip holds: held / picks is 1 unless
+                    # the chip holds a share of a layer's experts
+                    r.counter(f"lmstudio_moe_{name}_total", v, labels=labels)
                 r.gauge("lmstudio_moe_expert_path", 1,
                         labels={**labels, "path": getattr(stats, "expert_path", "")},
                         help="the form a decode burst's expert layers take: "
@@ -1915,7 +1920,8 @@ class Worker:
             pools = (pool_fn() if pool_fn else None) or {}
             state_pool = pools.get("state")
             if ssm is not None and state_pool:
-                # state-space layers (models/ssm_hybrid.py): rows / steps is
+                # state-space layers (models/ssm_hybrid.py) and linear-
+                # attention layers (models/gdn_moe.py): rows / steps is
                 # the live rows whose recurrent state a decode step advanced,
                 # slots_moved / steps the slots whose state it read and wrote
                 # (the state kernel skips a slot that holds no request); the

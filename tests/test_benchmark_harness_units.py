@@ -3,6 +3,7 @@ trace reduction against its recorded fixture, the traffic generator (see
 ``test_benchmark_harness.py``; no two of the three share a name)."""
 
 from benchmark.tests.test_discovery import *  # noqa: F401,F403
+from benchmark.tests.test_gdn_readers import *  # noqa: F401,F403
 from benchmark.tests.test_mla_long_readers import *  # noqa: F401,F403
 from benchmark.tests.test_moe_prefill_chunk_ms import (  # noqa: F401
     test_the_chunk_group_reader_divides_whole_launches_only,

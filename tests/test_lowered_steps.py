@@ -1,0 +1,78 @@
+"""The lowered programs of the families that were there before the
+linear-attention family came in are, operation for operation, what they were.
+
+``models/ssm_hybrid.py _layers`` takes its mixers by kind and an FFN half from
+its caller, and ``models/experts.py`` routes by a softmax, holds a share of the
+experts and gates the shared expert: code that ``tiny-ssm``, ``tiny-swa``,
+``tiny-mla`` and ``tiny-mla-plain`` run too. Each digest below is the sha256 of
+the operations (locations taken out) of a toy's paged decode step, of the same
+step with the expert counters, and of a two-row prefill chunk, as the parent of
+PR 47 lowered them and as the tree lowers them since. A deliberate change to
+one of these paths records its new digest here and says so; a change made for
+another family must never move one (``tests/test_mla_moe_plain.py`` holds the
+first of them the same way)."""
+import hashlib
+import json
+import re
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark import run
+from nats_llm_studio_tpu.models import llama
+from nats_llm_studio_tpu.ops.kvcache import WithState
+
+ROOT = Path(__file__).resolve().parents[1]
+T, SEQ, B = 16, 128, 2
+REFERENCE = {"tiny-ssm": "ssm_hybrid", "tiny-swa": "swa_gated_moe",
+             "tiny-mla-plain": "mla_moe_plain", "tiny-mla": "mla_moe_mhc"}
+LOWERED_SHA = {
+    ("tiny-ssm", "decode"): "1319c28ec4b315cd599c857829fd3d1347ca5fee94174f711fef83e05eb63d99",
+    ("tiny-ssm", "prefill"): "dbdb7b5b22920b406eabb79a1e9db38bbf9d40d196d0cd7d7718b7387d9f763a",
+    ("tiny-swa", "decode"): "545103ca9ea56b7e38c69146209d316d4dc6242520fd8733966d676aefe4759c",
+    ("tiny-swa", "decode_counted"): "caabf44f6d5504437f784bd32e2a93b5634aa0234fe4c05900dcb4bb2a6aa7f3",
+    ("tiny-swa", "prefill"): "e43b687298415726f978fdab68b742b5aa2685e69ad467d8c0c1ad5c02b9f7cc",
+    ("tiny-mla-plain", "decode"): "8b2e7ba2a6f7e19b195b367722b821ec79bafa132cf616c025c0920e38cd8f32",
+    ("tiny-mla-plain", "decode_counted"): "2ca8d735f54f6e4d13ffbb9d8b19538ff6f2ad605fd201dd5edef9f158df2b09",
+    ("tiny-mla-plain", "prefill"): "2a83d2fd7d0f477154a85b0a3095a703d8e832a57ae9b1f8f9d806354b2adb09",
+    ("tiny-mla", "decode"): "c4b93cc0a2669643a6ca0aa71a39b95843f61905352d3f9b48964c90aca0d40d",
+    ("tiny-mla", "decode_counted"): "1da08d0e95c61bc072bd9b231b521ec78d76985bef87a7d4073a80496f88a6df",
+    ("tiny-mla", "prefill"): "6c33a1196acfc7ee8ffbea0d7919a0a7d80bb61eca02ac3d9b196deb8fa0a876",
+}
+
+
+def _operations(text: str) -> list[str]:
+    text = re.sub(r"\s*loc\((?:[^()]|\((?:[^()]|\([^()]*\))*\))*\)", "", text)
+    return [l for l in text.splitlines() if l.strip() and not l.startswith("#loc")]
+
+
+def _ints(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _lowered(toy: str, program: str) -> str:
+    conf = json.loads((ROOT / f"benchmark/tests/rehearsal/configs/{toy}.json").read_text())
+    ref = run.load_module(ROOT / f"benchmark/references/{REFERENCE[toy]}.py")
+    cfg = ref.model_config(conf, SEQ).with_(dtype="float32")
+    params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    if program == "prefill":
+        caches = jax.eval_shape(lambda: llama.make_cache(cfg, 2, SEQ))
+        return jax.jit(lambda p, tok, kc, vc, pos, lp: llama.forward(
+            p, cfg, tok, kc, vc, pos, logit_positions=lp)).lower(
+                params, _ints(2, 32), *caches, _ints(2), _ints(2)).as_text()
+    pools = [jax.ShapeDtypeStruct((9, cfg.n_kv_layers, h, T, w), jnp.float32)
+             for h, w in cfg.kv_cache_dims()]
+    if cfg.slot_state:
+        state = jax.eval_shape(lambda: llama.family_module(cfg).make_state(cfg, B))
+        pools = [WithState(p, s, axes) for p, (s, axes) in zip(pools, state)]
+    return jax.jit(lambda p, tok, kp, vp, tbl, pos: llama.forward_decode_paged(
+        p, cfg, tok, kp, vp, tbl, pos, moe_stats=program == "decode_counted")).lower(
+            params, _ints(B, 1), *pools, _ints(B, SEQ // T), _ints(B)).as_text()
+
+
+@pytest.mark.parametrize("toy,program", list(LOWERED_SHA), ids=lambda v: v)
+def test_an_earlier_familys_lowered_program_is_what_it_was(toy, program):
+    ops = _operations(_lowered(toy, program))
+    assert hashlib.sha256("\n".join(ops).encode()).hexdigest() == LOWERED_SHA[toy, program]

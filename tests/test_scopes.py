@@ -25,10 +25,12 @@ from nats_llm_studio_tpu.serve.programs import build_programs
 ROOT = Path(__file__).resolve().parent.parent
 FAMILIES = {"dense": None, "latent": ("mla_moe_mhc", "tiny-mla"),
             "latent_plain": ("mla_moe_plain", "tiny-mla-plain"),
-            "state": ("ssm_hybrid", "tiny-ssm"), "window": ("swa_gated_moe", "tiny-swa")}
+            "state": ("ssm_hybrid", "tiny-ssm"), "window": ("swa_gated_moe", "tiny-swa"),
+            "linear": ("gdn_moe", "tiny-gdn")}
 SEQ, BLOCK, SLOTS, CHUNK, BURST = 64, 16, 2, 32, 2
 SCOPED_FILES = ("models/llama.py", "models/mla_moe.py", "models/ssm_hybrid.py",
-                "models/swa_moe.py", "models/experts.py", "serve/programs.py")
+                "models/swa_moe.py", "models/gdn_moe.py", "models/experts.py",
+                "serve/programs.py")
 # what the acceptance counts: the operations that carry a step's time
 HEAVY = re.compile(r"stablehlo\.(dot_general|custom_call|convolution)\b")
 # a custom call that computes nothing: a sharding or layout annotation
@@ -139,6 +141,8 @@ def test_every_product_and_kernel_lies_under_a_scope(family, program):
         assert "seq/ssm" in seen and "seq/attn" in seen
     if family == "window":
         assert "seq/window" in seen and "ffn/experts" in seen
+    if family == "linear":
+        assert {"seq/linear", "seq/attn", "ffn/router", "ffn/experts", "ffn/shared"} <= seen
 
 
 def _operations(text: str) -> list[str]:

@@ -11,6 +11,7 @@ published widths. Faults put in on purpose must each fail the toy limits."""
 
 import asyncio
 import json
+import time
 from pathlib import Path
 
 import jax
@@ -449,6 +450,7 @@ async def test_two_slots_finish_and_refill_at_different_steps_through_the_live_b
     from nats_llm_studio_tpu.obs import spans
 
     cfg, params = model
+    t0 = time.perf_counter()  # the span ring is the process's: other files' bursts lie before
     reqs = [(tokens(30 + i, n), m) for i, (n, m) in enumerate(
         [(9, 12), (40, 5), (21, 9), (37, 4), (12, 7)])]
     b = bt.ContinuousBatcher(params, cfg, max_slots=2, max_seq_len=SEQ, buckets=[16, 32, 64],
@@ -471,10 +473,10 @@ async def test_two_slots_finish_and_refill_at_different_steps_through_the_live_b
         assert st["state_admits_carried"] == 3  # the prompts over one chunk of 16
         pool = b.pool_stats()["state"]
         assert pool["slots_total"] == 2 and pool["bytes"] == 2 * ssm_hybrid.state_bytes_per_slot(cfg)
-        burst = [a for _, _, _, a in spans.records(0.0, float("inf"), "batcher.readback")
+        burst = [a for _, _, _, a in spans.records(t0, float("inf"), "batcher.readback")
                  if a and "state_steps" in a]
         assert burst and all(0 < a["state_rows"] <= 2 * a["state_steps"] for a in burst)
-        admits = [a for _, _, _, a in spans.records(0.0, float("inf"), "batcher.admit") if a]
+        admits = [a for _, _, _, a in spans.records(t0, float("inf"), "batcher.admit") if a]
         assert {a["state"] for a in admits if "state" in a} == {"fresh", "carried"}
     finally:
         b.stop()
@@ -489,7 +491,6 @@ async def test_a_finished_and_a_reserved_slot_keep_their_state_across_a_burst(mo
     a decoding request (the device lists by that rule, the host counts by it),
     and the state, tail and ``seen`` of every other slot come out bit for bit
     as they went in. C then decodes in the freed slot as the reference does."""
-    import time
 
     from nats_llm_studio_tpu.engine.generator import SamplingParams
     from nats_llm_studio_tpu.obs import spans

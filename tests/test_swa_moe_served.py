@@ -5,6 +5,7 @@ resume with the rings, against the plain reference at the toy of
 gives it a worker of its own."""
 
 import asyncio
+import time
 
 from conftest import async_test, hold_decodes_until_queued
 from test_swa_moe import CONF, REF, SEQ, T, WINDOW, model, prompt, tokens  # noqa: F401 — fixtures
@@ -34,6 +35,7 @@ async def test_two_slots_finish_and_refill_at_different_steps_through_the_live_b
     from nats_llm_studio_tpu.serve import batcher as bt
 
     cfg, params = model
+    t0 = time.perf_counter()  # the span ring is the process's: other files' bursts lie before
     reqs = [(tokens(30 + i, n), m) for i, (n, m) in enumerate(
         [(9, 22), (40, 5), (21, 19), (37, 4), (12, 7)])]
     b = bt.ContinuousBatcher(params, cfg, max_slots=2, max_seq_len=SEQ, buckets=[16, 32, 64],
@@ -59,12 +61,12 @@ async def test_two_slots_finish_and_refill_at_different_steps_through_the_live_b
         pool = b.pool_stats()["window"]
         assert pool["slots_total"] == 2 and pool["bytes"] == 2 * swa_moe.ring_bytes_per_slot(cfg)
         assert pool["kv_pool_bytes"] == b._pool.n_blocks * 2 * 2 * 2 * T * 32 * 4
-        burst = [a for _, _, _, a in spans.records(0.0, float("inf"), "batcher.readback")
+        burst = [a for _, _, _, a in spans.records(t0, float("inf"), "batcher.readback")
                  if a and "win_steps" in a]
         assert burst and all(0 < a["win_tokens"] <= a["full_tokens"] for a in burst)
         # the expert counters ride the same span (``record_moe``)
         assert all(a["expert_steps"] == 4 * a["win_steps"] and a["experts_hit"] > 0 for a in burst)
-        admits = [a for _, _, _, a in spans.records(0.0, float("inf"), "batcher.admit") if a]
+        admits = [a for _, _, _, a in spans.records(t0, float("inf"), "batcher.admit") if a]
         assert sum(a["ring"] for a in admits if "ring" in a) == st["ring_tokens"]
         # the worker's page: the two kinds of cache priced apart, the
         # counters, each refusal with its cause

@@ -942,3 +942,151 @@ def test_the_row_take_of_a_narrowing_group_writes_its_narrow_pair_and_nothing_el
                 assert "copy-start(" in found and len(layouts) == 1, found
     print(f"\n{wide} -> {narrow}: out {ma.output_size_in_bytes / 1e6:.0f} MB, "
           f"temp {ma.temp_size_in_bytes / 1e6:.0f} MB")
+
+
+# -- the linear-attention family at qwen3next.longanswer_closed's shapes --------
+
+
+@pytest.fixture(scope="module")
+def gdn_cell():
+    """(cfg as the registry makes it on the chip, the served tree's shapes,
+    block tokens, slots, context) of
+    ``benchmark/configs/qwen3-next-80b-a3b-instruct.json``."""
+    import json
+    from pathlib import Path
+
+    from benchmark import run
+
+    root = Path(__file__).resolve().parents[1]
+    ref = run.load_module(root / "benchmark/references/gdn_moe.py")
+    conf = json.loads((root / "benchmark/configs/qwen3-next-80b-a3b-instruct.json").read_text())
+    env = conf["serving"]["env"]
+    seq = int(env["MAX_SEQ_LEN"])
+    cfg = ref.model_config(conf, seq).with_(use_flash_attention=True)
+    return cfg, ref.param_shapes(cfg), int(env["KV_BLOCK_TOKENS"]), int(env["MAX_BATCH_SLOTS"]), seq
+
+
+def _gdn_pools(cfg, sharding, t, slots, seq):
+    """The cell's pools: slots x seq / t + 1 blocks of the full layers' rows of
+    head 256, each with the slots' state beside it."""
+    from nats_llm_studio_tpu.models import gdn_moe
+    from nats_llm_studio_tpu.ops.kvcache import WithState
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    (h, w), _ = cfg.kv_cache_dims()
+    nb = slots * (seq // t) + 1
+    (tail, seen), (plane,) = gdn_moe.state_shapes(cfg, slots)
+    kv = lambda: sds((nb, cfg.n_kv_layers, h, t, w), jnp.bfloat16)  # noqa: E731
+    return (WithState(kv(), (sds(tail, jnp.bfloat16), sds(seen, jnp.int32)), gdn_moe.K_AXES),
+            WithState(kv(), (sds(plane, jnp.float32),), gdn_moe.V_AXES))
+
+
+def test_gated_delta_step_at_the_benchmark_cells_shapes(one_chip, no_cache, gdn_cell):
+    """One layer's step over the state pool [32, 9, 32, 128, 128] f32 (0.6 GB
+    at 12 layers), the live slots a traced mask: Mosaic tiles it, the list
+    rides in as scalars, and the pool is the result's own buffer."""
+    from nats_llm_studio_tpu.ops import gated_delta, ssm_scan
+
+    cfg, _, t, slots, seq = gdn_cell
+    _, vp = _gdn_pools(cfg, one_chip, t, slots, seq)
+    pool = vp.st[0]
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    h, dk, dv = cfg.lin_v_heads, cfg.lin_k_dim, cfg.lin_v_dim
+    compiled = jax.jit(
+        lambda pool, layer, mask, decay, beta, q, k, v: gated_delta.gated_delta_step(
+            pool, layer, ssm_scan.live_slots(mask), decay, beta, q, k, v),
+        donate_argnums=(0,)).lower(
+        pool, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+        f32(slots, h), f32(slots, h), f32(slots, h, dk), f32(slots, h, dk),
+        f32(slots, h, dv)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_delta_step" in text
+    ma = compiled.memory_analysis()
+    pool_bytes = int(np.prod(pool.shape)) * 4
+    assert ma.alias_size_in_bytes >= pool_bytes and ma.temp_size_in_bytes < pool_bytes // 8
+
+
+def _gdn_table(cfg, t, seq):
+    from nats_llm_studio_tpu.engine.sampling import sample_rows
+    from nats_llm_studio_tpu.serve.programs import build_programs
+
+    return build_programs(cfg, None, max_seq=seq, paged=True, kv_block_tokens=t,
+                          sample_rows=sample_rows)
+
+
+@pytest.mark.parametrize("program", ["decode_pallas", "decode_pallas_ext"],
+                         ids=["the burst", "the single step"])
+def test_a_decode_launch_of_the_linear_attention_family_copies_no_pool(
+        one_chip, no_cache, gdn_cell, program):
+    """The family's two decode programs as ``serve/programs.py`` builds them,
+    all the cut's layers over the cell's pools (32 slots x 8,192 tokens),
+    donated: the state kernel, the paged attention kernel at head 256 and the
+    hit-list expert kernel at 128 held of 512 are in the program under their
+    names, the pools are aliased onto the results, and no ``copy`` of the
+    float32 state pool, of the convolution tails or of a KV pool is anywhere
+    in it."""
+    cfg, shapes, t, slots, seq = gdn_cell
+    assert paged_decode_eligible(t, cfg.head_dim, 2, False, cfg.n_kv_heads, 1)
+    kp, vp = _gdn_pools(cfg, one_chip, t, slots, seq)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    row = lambda dt, *more: jax.ShapeDtypeStruct(  # noqa: E731
+        (slots,) + more, dt, sharding=one_chip)
+    ints, floats = row(jnp.int32), row(jnp.float32)
+    last = 8 if program == "decode_pallas" else row(jnp.bool_, cfg.vocab_size)
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
+    try:
+        compiled = _gdn_table(cfg, t, seq)[program].lower(
+            jax.tree.map(sds, shapes), ints, kp, vp, row(jnp.int32, seq // t), ints, ints, ints,
+            floats, ints, floats, last).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    for name in ("gated_delta_step", "paged_decode_attention", "moe_hit_experts"):
+        assert name in text, name
+    state, kv, tails = vp.st[0], kp.kv, kp.st[0]
+    pools = (f"f32[{','.join(map(str, state.shape))}]", f"bf16[{','.join(map(str, kv.shape))}]",
+             f"bf16[{','.join(map(str, tails.shape))}]")
+    copies = [ln.strip()[:160] for ln in text.splitlines()
+              if (" copy(" in ln or "copy-start(" in ln) and any(p in ln for p in pools)]
+    assert not copies, copies
+    ma = compiled.memory_analysis()
+    state_bytes = int(np.prod(state.shape)) * 4
+    assert ma.alias_size_in_bytes >= state_bytes + 2 * int(np.prod(kv.shape)) * 2
+    assert ma.temp_size_in_bytes < state_bytes // 2, ma.temp_size_in_bytes
+    print(f"\n{program}: temp {ma.temp_size_in_bytes / 1e6:.0f} MB, "
+          f"alias {ma.alias_size_in_bytes / 1e6:.0f} MB, args {ma.argument_size_in_bytes / 1e9:.2f} GB")
+
+
+@pytest.mark.parametrize("width", [1, 4], ids=["prefill1", "group_of_4"])
+def test_a_chunk_of_the_linear_attention_family_at_the_cells_shapes(
+        one_chip, no_cache, gdn_cell, width):
+    """A chunk of 256 tokens x ``width`` prompts through the whole cut model
+    into row caches of 8,192 tokens with their state beside them: the chunked
+    rule (its triangular solve lowers for the chip), the flash chunk kernel at
+    head 256 and the grouped expert kernel at 128 held of 512, and what the
+    program holds beside its donated row caches stays under 1.5 GB."""
+    cfg, shapes, _, _, seq = gdn_cell
+    table = _gdn_table(cfg, 16, seq)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    from nats_llm_studio_tpu.models import gdn_moe
+
+    caches = jax.tree.map(sds, jax.eval_shape(lambda: gdn_moe.make_cache(cfg, width, seq)))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        compiled = table["prefill1" if width == 1 else "prefill_chunk_group"].lower(
+            jax.tree.map(sds, shapes), ints(width, CHUNK), *caches, ints(width), ints(width),
+            seq).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    assert "moe_grouped_experts" in text and "flash" in text
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 1.5e9, ma.temp_size_in_bytes
+    print(f"\nwidth {width}: temp {ma.temp_size_in_bytes / 1e6:.0f} MB, "
+          f"alias {ma.alias_size_in_bytes / 1e6:.0f} MB")
