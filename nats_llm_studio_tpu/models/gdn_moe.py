@@ -22,12 +22,18 @@ state's layout and its convolution as they are. What differs:
   serving ``lin_v_heads / lin_k_heads`` value heads; beta = sigmoid(b),
   alpha = exp(-exp(A_log) softplus(a + dt_bias)) a value head; the gated delta
   rule over a float32 state [H_v, d_k, d_v] a slot (``ops/gated_delta.py``:
-  chunked in prefill, one Pallas call a layer in decode that moves only the
-  slots that hold a request); the output normalised a head, times its gain
-  and SiLU(z), then ``w_out``. The state and the convolution's last
-  ``ssm_conv`` raw inputs ride beside the caches exactly as Mamba-2's do
+  chunked in prefill); the output normalised a head, times its gain and
+  SiLU(z), then ``w_out``. A DECODE step of the layer is three products
+  (``w_qkvz``, ``w_ba``, ``w_out``) and two Pallas calls between them:
+  ``step_inputs`` (convolution step, L2 norms, gates, on the raw output of the
+  products) and ``gated_delta_step`` (the rule on the slots that hold a
+  request, a key head read once for its value heads, and the gated norm of
+  what it reads out); ``linear_step_xla`` is the same step as the equations
+  are written, which only the tests run. The state and the convolution's last
+  ``ssm_conv`` raw inputs ride beside the caches as Mamba-2's do
   (``ops.kvcache.WithState``; ``ssm_hybrid``'s docstring has the rules for
-  padding, for a slot without a request and for a replayed position).
+  padding, for a slot without a request and for a replayed position), the
+  inputs a tap a plane ([Ll, K, rows, C]: ``state_shapes`` says why).
 * **An attention layer**: ``wq`` makes the queries AND an elementwise gate
   ([q | gate], each n_heads x head_dim; published interleaved by head); q and
   k are RMS-normalised a head (gains ``q_norm`` / ``k_norm``), the first
@@ -60,22 +66,32 @@ from ..ops.layers import gqa_attention, gqa_attention_hmajor, rms_norm, rope_cos
 from ..ops.wquant import mm
 from .config import ModelConfig
 from .experts import EXPERT_LEAVES, expert_path, moe_ffn, stats_width
-from .ssm_hybrid import K_AXES, V_AXES, _embed, _layers, state_bytes, zeroed_state
+from .ssm_hybrid import V_AXES, _embed, _layers, state_bytes
 from .swa_moe import _rotate
 
 Params = dict[str, Any]
 
 
+# the rows' axis of K's state leaves (the tails, ``seen``); V's is ``ssm_hybrid``'s
+K_AXES = (2, 0)
+
+
 def state_shapes(cfg: ModelConfig, rows: int) -> tuple[tuple, tuple]:
-    """((tail shape, seen shape), (state shape,)) for ``rows`` rows."""
+    """((tail shape, seen shape), (state shape,)) for ``rows`` rows. The tails
+    lie a tap a plane, [Ll, K, rows, C], not ``ssm_hybrid``'s [Ll, rows, K, C]:
+    a decode step shifts and weighs a tap of ALL the slots at once, the slots
+    on the sublanes, and with the taps there instead (K = 4 of a bf16 tile's
+    rows) both XLA and a kernel gather row by row (PERF.md section 6, PR 48)."""
     ll = cfg.n_lin_layers
-    return (((ll, rows, cfg.ssm_conv, cfg.lin_conv_dim), (rows,)),
+    return (((ll, cfg.ssm_conv, rows, cfg.lin_conv_dim), (rows,)),
             ((rows, ll, cfg.lin_v_heads, cfg.lin_k_dim, cfg.lin_v_dim),))
 
 
 def make_state(cfg: ModelConfig, rows: int):
     """Zeroed state for ``rows`` rows: (K's ``st``, its axes), (V's, its)."""
-    return zeroed_state(cfg, state_shapes(cfg, rows))
+    (tail, seen), (plane,) = state_shapes(cfg, rows)
+    return (((jnp.zeros(tail, jnp.dtype(cfg.dtype)), jnp.zeros(seen, jnp.int32)), K_AXES),
+            ((jnp.zeros(plane, jnp.float32),), V_AXES))
 
 
 def state_bytes_per_slot(cfg: ModelConfig) -> int:
@@ -133,22 +149,21 @@ def _gates(b: jax.Array, a: jax.Array, p: Params):
 def _mixer_out(o: jax.Array, z: jax.Array, p: Params, cfg: ModelConfig):
     """o [.., H_v, d_v] f32 -> the mixer's output: normalised a head, times
     the gain and silu(z), then the output projection."""
-    y = rms_norm(o, p["gate_norm"].astype(jnp.float32), cfg.rms_eps)
-    y = y * jax.nn.silu(z.astype(jnp.float32).reshape(y.shape))
-    return mm(y.reshape(y.shape[:-2] + (-1,)).astype(z.dtype), p["w_out"])
+    return mm(gated_delta.gated_norm(o, z, p["gate_norm"], cfg.rms_eps), p["w_out"])
 
 
 def linear_prefill(h, p: Params, cfg: ModelConfig, tails, states, layer, valid):
-    """The mixer over T positions of B rows: ``tails`` [Ll, B, K, C] and
+    """The mixer over T positions of B rows: ``tails`` [Ll, K, B, C] and
     ``states`` [B, Ll, H_v, d_k, d_v] are the rows' state of all layers, this
     one's slice read and written at ``layer``. ``valid`` [B]: real positions
     of each row."""
     t = h.shape[1]
     zero = jnp.zeros((), jnp.int32)
     qkv, z, b, a = _project_in(h, p, cfg)
-    tail = jax.lax.dynamic_index_in_dim(tails, layer, axis=0, keepdims=False)
+    tail = jnp.swapaxes(jax.lax.dynamic_index_in_dim(tails, layer, axis=0, keepdims=False), 0, 1)
     qkv, tail = ssm_scan.causal_conv(qkv, tail, p["conv_w"], None, valid)
-    tails = jax.lax.dynamic_update_slice(tails, tail[None], (layer, zero, zero, zero))
+    tails = jax.lax.dynamic_update_slice(
+        tails, jnp.swapaxes(tail, 0, 1)[None], (layer, zero, zero, zero))
     q, k, v = _split_conv(qkv, cfg)
     real = (jnp.arange(t, dtype=jnp.int32)[None, :] < valid[:, None])[..., None]
     beta, log_alpha = _gates(b, a, p)
@@ -159,22 +174,47 @@ def linear_prefill(h, p: Params, cfg: ModelConfig, tails, states, layer, valid):
     return _mixer_out(o, z, p, cfg), tails, states
 
 
-def linear_step(h, p: Params, cfg: ModelConfig, tails, states, layer, live, fresh):
-    """The mixer over ONE position of the ``live`` slots (``ssm_scan.
-    LiveSlots``), their state updated in place in the pool. ``fresh`` [B]
-    bool: live rows that consume their position (the other live rows read
-    their state as it is; a row that is not live gives zeros)."""
+def linear_step_xla(h, p: Params, cfg: ModelConfig, tails, states, layer, live, fresh):
+    """``linear_step`` as the equations are written, in plain XLA over every
+    slot (what the step's kernels are held to in the tests; no program runs
+    it). ``fresh`` [B] bool."""
     zero = jnp.zeros((), jnp.int32)
     qkv, z, b, a = _project_in(h[:, 0], p, cfg)
-    tail = jax.lax.dynamic_index_in_dim(tails, layer, axis=0, keepdims=False)
+    tail = jnp.swapaxes(jax.lax.dynamic_index_in_dim(tails, layer, axis=0, keepdims=False), 0, 1)
     qkv, tail = ssm_scan.conv_step(qkv, tail, p["conv_w"], None, fresh)
-    tails = jax.lax.dynamic_update_slice(tails, tail[None], (layer, zero, zero, zero))
+    tails = jax.lax.dynamic_update_slice(
+        tails, jnp.swapaxes(tail, 0, 1)[None], (layer, zero, zero, zero))
     q, k, v = _split_conv(qkv, cfg)
     beta, log_alpha = _gates(b, a, p)
     decay = jnp.where(fresh[:, None], jnp.exp(log_alpha), 1.0)
     beta = jnp.where(fresh[:, None], beta, 0.0)
-    states, o = gated_delta.gated_delta_step_auto(states, layer, live, decay, beta, q, k, v)
+    s = jax.lax.dynamic_index_in_dim(states, layer, axis=1, keepdims=False)
+    o, s1 = gated_delta.gated_delta_recurrent(
+        q[:, None], k[:, None], v[:, None], jnp.log(decay)[:, None], beta[:, None], s)
+    on = live.mask[:, None, None, None]
+    states = jax.lax.dynamic_update_index_in_dim(states, jnp.where(on, s1, s), layer, axis=1)
+    o = jnp.where(live.mask[:, None, None], o[:, 0], 0.0)
     return _mixer_out(o, z, p, cfg)[:, None], tails, states
+
+
+def linear_step(h, p: Params, cfg: ModelConfig, tails, states, layer, live, consts):
+    """The mixer over ONE position of the ``live`` slots (``ssm_scan.
+    LiveSlots``), their state updated in place in the pool: three products
+    and two Pallas calls. ``consts`` (``gated_delta.step_consts``, once a
+    step) holds the small leaves' whole stacks and the ``fresh`` flags: live
+    rows that consume their position (the other live rows read their state as
+    it is; a row that is not live gives zeros). Of ``p`` only the three
+    projections are read."""
+    x = h[:, 0]
+    qkvz, ba = mm(x, p["w_qkvz"]), mm(x, p["w_ba"])
+    heads = (cfg.lin_k_heads, cfg.lin_k_dim, cfg.lin_v_heads, cfg.lin_v_dim)
+    tail = jax.lax.dynamic_index_in_dim(tails, layer, axis=0, keepdims=False)
+    tail, q, k, v, decay, beta = gated_delta.step_inputs_auto(qkvz, ba, tail, layer, consts, heads)
+    tails = jax.lax.dynamic_update_index_in_dim(tails, tail, layer, axis=0)
+    states, y = gated_delta.gated_delta_step_auto(
+        states, layer, live, decay, beta, q, k,
+        gated_delta.Values(v, qkvz, consts.gain, consts.eps))
+    return mm(y, p["w_out"])[:, None], tails, states
 
 
 # ---------------------------------------------------------------------------
@@ -347,12 +387,15 @@ def forward_decode_paged(
     with jax.named_scope("seq/linear"):
         live = ssm_scan.live_slots(table_rows_in_use(tbl))
         fresh = live.mask & (start_pos >= seen)
+        # once a step, not once a layer: the small leaves as the kernels read them
+        consts = (gated_delta.step_consts(params["blocks"]["linear"], cfg.rms_eps, fresh)
+                  if cfg.n_lin_layers else None)
     with jax.named_scope("seq/attn"):
         table = _rope_table(cfg, start_pos[:, None])
 
     def linear(h, p, carry, layer):
         kp, vp, tails, states, stats = carry
-        out, tails, states = linear_step(h, p, cfg, tails, states, layer, live, fresh)
+        out, tails, states = linear_step(h, p, cfg, tails, states, layer, live, consts)
         return out, (kp, vp, tails, states, stats)
 
     def attention(h, p, carry, layer):

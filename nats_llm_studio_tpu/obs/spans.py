@@ -53,7 +53,7 @@ SCOPE_NAMES = (
     "seq/window",    # the same over a ring of the last ``window`` keys (models/swa_moe.py)
     "seq/mla",       # latent attention: down and up projections, the latent write, absorbed or expanded
     "seq/ssm",       # Mamba-2: in-projection, convolution, scan or ssm_state_step, gated norm, out
-    "seq/linear",    # gated delta rule: in-projections, convolution, chunked rule or gated_delta_step, gated norm, out
+    "seq/linear",    # gated delta rule: in-projections, convolution, chunked rule or gdn_step_inputs + gated_delta_step, gated norm, out
     "ffn",           # the position-wise block, with the norm before and the add after
     "ffn/mlp",       # the dense SwiGLU
     "ffn/router",    # scores, top-k, gates, the expert counters

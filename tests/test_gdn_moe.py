@@ -251,40 +251,134 @@ LIVE_SETS = {"none": [], "slot 0 only": [0], "the last slot only": [5],
              "every other slot": [0, 2, 4], "all": [0, 1, 2, 3, 4, 5]}
 
 
+def _listed(slots, live):
+    return ssm_scan.live_slots(
+        jnp.zeros((slots,), bool).at[jnp.asarray(live, jnp.int32)].set(True))
+
+
 @pytest.mark.parametrize("name", list(LIVE_SETS))
 def test_the_state_kernel_is_the_xla_step_on_the_listed_slots_and_no_other(name):
-    """The list is data: a listed slot is the XLA step (the rule as written),
-    a slot that is not listed keeps its state bit for bit and gives zeros,
-    whatever its row of the operands holds (NaN here). The first listed row
-    replays a position (alpha 1, beta 0): it reads S^T q and keeps its state.
-    Every live set runs the one compiled program, and only layer 1 moves."""
-    slots, layers, h, dk, dv = 6, 2, 4, 16, 128
+    """The list is data: a listed slot is the XLA step (the rule as written,
+    a key head read for the two value heads it serves, the read-out through
+    the gated norm), a slot that is not listed keeps its state bit for bit and
+    gives zeros, whatever its row of the operands holds (NaN here, z too). The
+    first listed row replays a position (alpha 1, beta 0): it reads S^T q and
+    keeps its state. Every live set runs the one compiled program, and only
+    layer 1 moves."""
+    slots, layers, hk, h, dk, dv = 6, 2, 2, 4, 16, 128
     live = LIVE_SETS[name]
     dead = [i for i in range(slots) if i not in live]
     q, k, v, beta, _, ks = _rule_inputs(3, 1, slots, h, dk, dv)
-    q, k, v, beta = q[0], k[0], v[0], beta[0]
+    q, k, v, beta = q[0, :, :hk], k[0, :, :hk], v[0], beta[0]
     pool = jax.random.normal(ks[0], (slots, layers, h, dk, dv))
     decay = jax.nn.sigmoid(jax.random.normal(ks[1], (slots, h)) * 3)
+    # z behind the convolution's channels, as the in-projection leaves it
+    zs = jax.random.normal(jax.random.PRNGKey(9), (slots, 2 * hk * dk + 2 * h * dv))
+    gain = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(10), (layers, 1, dv))
     if live:
         decay, beta = decay.at[live[0]].set(1.0), beta.at[live[0]].set(0.0)
     every = ssm_scan.live_slots(jnp.ones((slots,), bool))
-    want, o_want = gated_delta.gated_delta_step_xla(pool, 1, every, decay, beta, q, k, v)
+    eps = jnp.full((1,), 1e-6, jnp.float32)
+    want, y_want = gated_delta.gated_delta_step_xla(
+        pool, 1, every, decay, beta, q, k, gated_delta.Values(v, zs, gain, eps))
     nan = jnp.asarray(dead, jnp.int32)
-    decay, beta, q, k, v = (z.at[nan].set(jnp.nan) for z in (decay, beta, q, k, v))
-    listed = ssm_scan.live_slots(
-        jnp.zeros((slots,), bool).at[jnp.asarray(live, jnp.int32)].set(True))
-    got, o = _STATE_STEP(pool, 1, listed, decay, beta, q, k, v)
+    decay, beta, q, k, v, zs = (z.at[nan].set(jnp.nan) for z in (decay, beta, q, k, v, zs))
+    got, y = _STATE_STEP(pool, 1, _listed(slots, live), decay, beta, q, k,
+                         gated_delta.Values(v, zs, gain, eps))
     assert _STATE_STEP._cache_size() == 1
+    assert y.shape == (slots, h * dv)
     for i in live:
         np.testing.assert_allclose(got[i], want[i], atol=1e-5)
-        np.testing.assert_allclose(o[i], o_want[i], atol=1e-5)
+        np.testing.assert_allclose(y[i], y_want[i], atol=1e-4)
     for i in dead:
         np.testing.assert_array_equal(got[i], pool[i])
-        np.testing.assert_array_equal(o[i], np.zeros((h, dv), np.float32))
+        np.testing.assert_array_equal(y[i], np.zeros((h * dv,), np.float32))
     if live:
         np.testing.assert_array_equal(got[live[0]], pool[live[0]])
-        assert float(jnp.abs(o[live[0]]).max()) > 0.05
+        assert float(jnp.abs(y[live[0]]).max()) > 0.05
     np.testing.assert_array_equal(got[:, 0], pool[:, 0])  # the other layer untouched
+
+
+def _one_linear_layer(dtype):
+    """A linear layer's decode step at toy size, from the raw input of the
+    projections: (cfg, the layer's leaves out of a stack of two, the stack's
+    small leaves, h, tails, states); layer 1 is the one that runs."""
+    cfg = REF.model_config(CONF, SEQ).with_(dtype=dtype)
+    slots, ll = 6, 2
+    ks = iter(jax.random.split(jax.random.PRNGKey(17), 12))
+    hv, c, vd = cfg.lin_v_heads, cfg.lin_conv_dim, cfg.lin_v_heads * cfg.lin_v_dim
+    dt = jnp.dtype(dtype)
+    rand = lambda *shape: jax.random.normal(next(ks), shape, jnp.float32)  # noqa: E731
+    lin = {"w_qkvz": (rand(ll, cfg.d_model, c + vd) * 0.2).astype(dt),
+           "w_ba": rand(ll, cfg.d_model, 2 * hv).astype(dt),
+           "conv_w": (rand(ll, cfg.ssm_conv, c) * 0.5).astype(dt),
+           "dt_bias": rand(ll, hv).astype(dt), "a_log": (rand(ll, hv) * 0.5).astype(dt),
+           "gate_norm": (1 + 0.1 * rand(ll, cfg.lin_v_dim)).astype(dt),
+           "w_out": (rand(ll, vd, cfg.d_model) * 0.1).astype(dt)}
+    h = rand(slots, 1, cfg.d_model).astype(dt)
+    tails = rand(ll, cfg.ssm_conv, slots, c).astype(dt)
+    states = rand(slots, ll, hv, cfg.lin_k_dim, cfg.lin_v_dim)
+    return cfg, lin, h, tails, states
+
+
+@jax.jit
+def _fused_step(lin, h, tails, states, live, fresh):
+    cfg = REF.model_config(CONF, SEQ).with_(dtype=str(h.dtype))
+    p = jax.tree.map(lambda a: a[1], lin)
+    consts = gated_delta.step_consts(lin, cfg.rms_eps, fresh)
+    return gdn_moe.linear_step(h, p, cfg, tails, states, 1, live, consts)
+
+
+@jax.jit
+def _plain_step(lin, h, tails, states, live, fresh):
+    cfg = REF.model_config(CONF, SEQ).with_(dtype=str(h.dtype))
+    p = jax.tree.map(lambda a: a[1], lin)
+    return gdn_moe.linear_step_xla(h, p, cfg, tails, states, 1, live, fresh)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", list(LIVE_SETS))
+def test_the_fused_decode_step_of_a_linear_layer_is_the_plain_step(name, dtype):
+    """One layer's decode step through ``step_inputs`` and ``gated_delta_step``
+    against the plain composition (``conv_step``, ``_split_conv``, ``_gates``,
+    the rule a token at a time, the gated norm), from the raw input of the
+    projections: the layer's output, the tails and the state agree to float32
+    rounding for live and fresh rows (the output to one bf16 step where the
+    activations are bf16: both sides round in the same two places); the first
+    live row is not fresh: it keeps its tail and its state bit for bit and
+    still reads; a slot that is not live keeps both bit for bit and gives
+    what ``w_out`` makes of zeros; the other layer's rows do not move."""
+    cfg, lin, h, tails, states = _one_linear_layer(dtype)
+    slots = h.shape[0]
+    live = LIVE_SETS[name]
+    dead = [i for i in range(slots) if i not in live]
+    mask = jnp.zeros((slots,), bool).at[jnp.asarray(live, jnp.int32)].set(True)
+    fresh = mask.at[jnp.asarray(live[:1], jnp.int32)].set(False)
+    want, tails_want, states_want = _plain_step(lin, h, tails, states, _listed(slots, live), fresh)
+    got, tails_got, states_got = _fused_step(lin, h, tails, states, _listed(slots, live), fresh)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    tol = 1e-4 if dtype == "float32" else 0.05
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    # a tail row is a copy of a raw input: bit for bit on every row
+    np.testing.assert_array_equal(np.asarray(tails_got, np.float32),
+                                  np.asarray(tails_want, np.float32))
+    np.testing.assert_allclose(states_got, states_want, atol=2e-2 if dtype != "float32" else 1e-4)
+    for i in dead + live[:1]:
+        np.testing.assert_array_equal(np.asarray(tails_got[:, :, i], np.float32),
+                                      np.asarray(tails[:, :, i], np.float32))
+        np.testing.assert_array_equal(states_got[i], states[i])
+    for i in dead:
+        assert not np.asarray(got[i], np.float32).any()
+    if live:
+        assert float(jnp.abs(got[live[0]].astype(jnp.float32)).max()) > 1e-3  # it still reads
+        for i in live[1:]:
+            assert np.abs(np.asarray(states_got[i, 1] - states[i, 1])).max() > 1e-3
+            assert (np.asarray(tails_got[1, :, i], np.float32)
+                    != np.asarray(tails[1, :, i], np.float32)).any()
+    np.testing.assert_array_equal(states_got[:, 0], states[:, 0])
+    np.testing.assert_array_equal(np.asarray(tails_got[0], np.float32),
+                                  np.asarray(tails[0], np.float32))
 
 
 def test_a_group_admit_of_prompts_of_unequal_length_is_each_alone(model):

@@ -29,11 +29,15 @@ def _rule(step):
         return jnp.moveaxis(o, 0, 1), s
 
     def decode(pool, layer, live, decay, beta, q, k, v):
+        # the kernel's operands: a key head once for the value heads it
+        # serves, the read-out through the gated norm (``gated_delta.Values``)
         s = pool[:, layer]
-        s1, o = step(s, q, k, v.astype(jnp.float32), decay, beta)
+        rep = s.shape[1] // q.shape[1]
+        s1, o = step(s, jnp.repeat(q, rep, axis=1), jnp.repeat(k, rep, axis=1), v.v, decay, beta)
         on = live.mask[:, None, None, None]
+        y = gated_delta.gated_norm(o, v.zs[:, -o.shape[1] * o.shape[2]:], v.gain[layer], v.eps[0])
         return (pool.at[:, layer].set(jnp.where(on, s1, s)),
-                jnp.where(live.mask[:, None, None], o, 0.0))
+                jnp.where(live.mask[:, None], y, jnp.zeros((), y.dtype)))
 
     return chunked, decode
 

@@ -322,6 +322,27 @@ def test_a_prefill_chunk_at_the_benchmark_cells_shapes_copies_no_expert_stack(
 # -- a prefill chunk of the dense family at granite8b.chat_closed's shapes --
 
 
+_NO_OPERATION = {"get-tuple-element", "bitcast", "parameter", "constant", "tuple"}
+
+
+def _scoped_operations(text: str, scope: str) -> dict[str, list[tuple[str, str]]]:
+    """{computation: [(opcode, line)]} of a compiled program's device
+    operations whose ``op_name`` holds ``scope``: every instruction outside
+    the fused computations that is not a tuple's plumbing, a bitcast, a
+    parameter or a constant (a fusion counts once, its body not at all)."""
+    found: dict[str, list[tuple[str, str]]] = {}
+    name = None
+    for ln in text.splitlines():
+        head = ln.split(" ", 2)
+        if ln.endswith("{") and not ln.startswith(" ") and len(head) > 1:
+            name = head[1] if head[0] == "ENTRY" else head[0]
+        elif name is not None and ln.startswith("  ") and "fused_computation" not in name:
+            m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = .*?\s([a-z][a-z\-]*)\(", ln)
+            if m and m.group(1) not in _NO_OPERATION and scope in ln.split("op_name=", 1)[-1]:
+                found.setdefault(name, []).append((m.group(1), ln.strip()))
+    return found
+
+
 def _whole_array_copies(text: str, shape: str) -> list[str]:
     """The instructions of a compiled program that write a relayouted copy of
     a whole array of ``shape`` ("bf16[1,40,8,2048,128]") to memory: a ``copy``
@@ -983,30 +1004,70 @@ def _gdn_pools(cfg, sharding, t, slots, seq):
             WithState(kv(), (sds(plane, jnp.float32),), gdn_moe.V_AXES))
 
 
+def _gdn_step_shapes(cfg, one_chip, slots):
+    """(heads, consts, qkvz, ba) of one linear layer's decode step at the cell's shapes."""
+    from nats_llm_studio_tpu.ops import gated_delta
+
+    sds = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hk, dk, h, dv = cfg.lin_k_heads, cfg.lin_k_dim, cfg.lin_v_heads, cfg.lin_v_dim
+    ll, c = cfg.n_lin_layers, cfg.lin_conv_dim
+    consts = gated_delta.StepConsts(
+        sds(f32, ll, cfg.ssm_conv, c), sds(f32, ll, 1, h), sds(f32, ll, 1, h),
+        sds(f32, ll, 1, dv), sds(f32, 1), sds(jnp.int32, slots, 1))
+    return (hk, dk, h, dv), consts, sds(bf16, slots, c + h * dv), sds(bf16, slots, 2 * h)
+
+
 def test_gated_delta_step_at_the_benchmark_cells_shapes(one_chip, no_cache, gdn_cell):
     """One layer's step over the state pool [32, 9, 32, 128, 128] f32 (0.6 GB
-    at 12 layers), the live slots a traced mask: Mosaic tiles it, the list
-    rides in as scalars, and the pool is the result's own buffer."""
+    at 12 layers), the live slots a traced mask, the operands as
+    ``step_inputs`` leaves them (16 key heads for 32 value heads, decay and
+    beta as scalars, z out of the in-projection's own output): Mosaic tiles it
+    (the key heads' transposition to columns, the read-out rows kept by slot,
+    the gated norm in the last cell), the list rides in as scalars, and the
+    pool is the result's own buffer."""
     from nats_llm_studio_tpu.ops import gated_delta, ssm_scan
 
     cfg, _, t, slots, seq = gdn_cell
     _, vp = _gdn_pools(cfg, one_chip, t, slots, seq)
     pool = vp.st[0]
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
-    h, dk, dv = cfg.lin_v_heads, cfg.lin_k_dim, cfg.lin_v_dim
+    (hk, dk, h, dv), consts, qkvz, _ = _gdn_step_shapes(cfg, one_chip, slots)
     compiled = jax.jit(
-        lambda pool, layer, mask, decay, beta, q, k, v: gated_delta.gated_delta_step(
-            pool, layer, ssm_scan.live_slots(mask), decay, beta, q, k, v),
+        lambda pool, layer, mask, decay, beta, q, k, v, zs, gain, eps: gated_delta.gated_delta_step(
+            pool, layer, ssm_scan.live_slots(mask), decay, beta, q, k,
+            gated_delta.Values(v, zs, gain, eps)),
         donate_argnums=(0,)).lower(
         pool, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
-        f32(slots, h), f32(slots, h), f32(slots, h, dk), f32(slots, h, dk),
-        f32(slots, h, dv)).compile()
+        f32(slots, h), f32(slots, h), f32(slots, hk, dk), f32(slots, hk, dk),
+        f32(slots, h, dv), qkvz, consts.gain, consts.eps).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "gated_delta_step" in text
     ma = compiled.memory_analysis()
     pool_bytes = int(np.prod(pool.shape)) * 4
     assert ma.alias_size_in_bytes >= pool_bytes and ma.temp_size_in_bytes < pool_bytes // 8
+
+
+def test_the_linear_layers_step_inputs_at_the_benchmark_cells_shapes(one_chip, no_cache, gdn_cell):
+    """The call before the state kernel at the cell's shapes (4 taps x 32 slots
+    x 8,192 channels, bf16 in, float32 out): Mosaic takes a head's 128 channels
+    at a traced offset out of the tap planes and puts its rows into the [16,
+    heads, 128] blocks, and the tail is the result's own buffer."""
+    from nats_llm_studio_tpu.ops import gated_delta
+
+    cfg, _, _, slots, _ = gdn_cell
+    heads, consts, qkvz, ba = _gdn_step_shapes(cfg, one_chip, slots)
+    tail = jax.ShapeDtypeStruct((cfg.ssm_conv, slots, cfg.lin_conv_dim), jnp.bfloat16,
+                                sharding=one_chip)
+    compiled = jax.jit(
+        lambda qkvz, ba, tail, layer, consts: gated_delta.step_inputs(
+            qkvz, ba, tail, layer, consts, heads),
+        donate_argnums=(2,)).lower(
+        qkvz, ba, tail, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip), consts).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gdn_step_inputs" in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= int(np.prod(tail.shape)) * 2
 
 
 def _gdn_table(cfg, t, seq):
@@ -1027,7 +1088,9 @@ def test_a_decode_launch_of_the_linear_attention_family_copies_no_pool(
     hit-list expert kernel at 128 held of 512 are in the program under their
     names, the pools are aliased onto the results, and no ``copy`` of the
     float32 state pool, of the convolution tails or of a KV pool is anywhere
-    in it."""
+    in it. A linear layer's body holds at most 10 device operations under
+    ``seq/linear``, two of them Pallas calls, and one call alone carries the
+    state kernel's name (the benchmark finds it by that substring)."""
     cfg, shapes, t, slots, seq = gdn_cell
     assert paged_decode_eligible(t, cfg.head_dim, 2, False, cfg.n_kv_heads, 1)
     kp, vp = _gdn_pools(cfg, one_chip, t, slots, seq)
@@ -1045,8 +1108,19 @@ def test_a_decode_launch_of_the_linear_attention_family_copies_no_pool(
     finally:
         jax.default_backend = orig
     text = compiled.as_text()
-    for name in ("gated_delta_step", "paged_decode_attention", "moe_hit_experts"):
+    for name in ("gated_delta_step", "gdn_step_inputs", "paged_decode_attention",
+                 "moe_hit_experts"):
         assert name in text, name
+    # a linear layer's body: three products, the mix norm's two, the tail's
+    # slice and its write-back, and the two Pallas calls (40 before the step's
+    # small operations moved into the calls)
+    bodies = [ops for ops in _scoped_operations(text, "seq/linear").values()
+              if any("gated_delta_step" in ln for _, ln in ops)]
+    assert len(bodies) == 1, [len(b) for b in bodies]
+    (ops,) = bodies
+    assert len(ops) <= 10, [ln[:100] for _, ln in ops]
+    calls = [ln.split(" = ", 1)[0] for code, ln in ops if code == "custom-call"]
+    assert sum("gated_delta_step" in c for c in calls) == 1 and len(calls) == 2, calls
     state, kv, tails = vp.st[0], kp.kv, kp.st[0]
     pools = (f"f32[{','.join(map(str, state.shape))}]", f"bf16[{','.join(map(str, kv.shape))}]",
              f"bf16[{','.join(map(str, tails.shape))}]")
