@@ -462,12 +462,14 @@ def forward_decode_paged(
     request (table entry 0 is a real block), the distinct experts hit, the
     most rows on one expert and the live rows in each expert layer."""
     from ..ops.mla_attention import mla_paged_decode_attention_auto
+    from ..ops.ssm_scan import live_slots
 
     b, w = tokens.shape
     positions = start_pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
     with jax.named_scope("seq/mla"):
         cos, sin = rope_tables(cfg, positions)
-    live = (tbl[:, 0] > 0).astype(jnp.float32)
+    live = tbl[:, 0] > 0
+    listed = live_slots(live)  # once a launch: every layer's kernel walks the same list
     X = _embed(params, cfg, tokens)
 
     def attention(h, p, pools, layer):
@@ -477,10 +479,11 @@ def forward_decode_paged(
         rp = kv_pool_write_rows(rp, _lane_padded(kr, cfg)[:, :, None], tbl, start_pos, layer)
         o_lat = mla_paged_decode_attention_auto(
             absorbed_queries(q_nope, p, cfg), _lane_padded(q_rope, cfg), cp, rp, tbl,
-            start_pos, layer, cfg.attn_scale)
+            start_pos, listed, layer, cfg.attn_scale)
         return mm(absorbed_output(o_lat, p, cfg), p["wo"]), (cp, rp)
 
-    X, pools, stats = _layers(params, cfg, X, (k_pool, v_pool), attention, live, mesh)
+    X, pools, stats = _layers(params, cfg, X, (k_pool, v_pool), attention,
+                              live.astype(jnp.float32), mesh)
     if stats is None:
         stats = jnp.zeros((0, 3), jnp.int32)
     return _head(params, cfg, X, None, w), pools[0], pools[1], stats

@@ -74,6 +74,9 @@ def paged_decode_eligible(
 # full 2048-token table at 87 % of HBM bandwidth where 128 reads it at 71 %
 # (a run's copies cost a fixed issue time), 512 is no better and re-reads more
 # of the last block, and at ~400 tokens a slot all three are within 5 %.
+# That is 1 MiB a run at 4,096 B a token (8 kv heads x 128, K and V, bf16). The
+# latent kernel no longer takes it (``ops/mla_attention._run_entries``, PR 49:
+# its rows are 1,280 B); a dense cache under 4 KiB a token still does.
 _RUN_TOKENS = 256
 # What the landing buffers of one run (K and V, two halves each) may take of
 # VMEM: a quarter of a v5e core's 16 MiB scoped default.

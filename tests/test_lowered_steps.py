@@ -10,7 +10,11 @@ step with the expert counters, and of a two-row prefill chunk, as the parent of
 PR 47 lowered them and as the tree lowers them since. A deliberate change to
 one of these paths records its new digest here and says so; a change made for
 another family must never move one (``tests/test_mla_moe_plain.py`` holds the
-first of them the same way)."""
+first of them the same way). PR 49 recorded the four decode digests of
+``tiny-mla`` and ``tiny-mla-plain`` anew: the latent decode kernel's walk
+changed (``ops/mla_attention.py``: its own run length, the live list, a tail
+that copies live blocks only), and the interpreter lowers the kernel's body
+into the step. Their prefill digests did not move."""
 import hashlib
 import json
 import re
@@ -34,11 +38,11 @@ LOWERED_SHA = {
     ("tiny-swa", "decode"): "545103ca9ea56b7e38c69146209d316d4dc6242520fd8733966d676aefe4759c",
     ("tiny-swa", "decode_counted"): "caabf44f6d5504437f784bd32e2a93b5634aa0234fe4c05900dcb4bb2a6aa7f3",
     ("tiny-swa", "prefill"): "e43b687298415726f978fdab68b742b5aa2685e69ad467d8c0c1ad5c02b9f7cc",
-    ("tiny-mla-plain", "decode"): "8b2e7ba2a6f7e19b195b367722b821ec79bafa132cf616c025c0920e38cd8f32",
-    ("tiny-mla-plain", "decode_counted"): "2ca8d735f54f6e4d13ffbb9d8b19538ff6f2ad605fd201dd5edef9f158df2b09",
+    ("tiny-mla-plain", "decode"): "54c9c74669ea7d89983ecef38ee75159e0e08e338abed56179a0e77b1bbdf034",
+    ("tiny-mla-plain", "decode_counted"): "d9db576855330906ca3878524a3348ddaad25ef992f237f45c308b2bae2ab74f",
     ("tiny-mla-plain", "prefill"): "2a83d2fd7d0f477154a85b0a3095a703d8e832a57ae9b1f8f9d806354b2adb09",
-    ("tiny-mla", "decode"): "c4b93cc0a2669643a6ca0aa71a39b95843f61905352d3f9b48964c90aca0d40d",
-    ("tiny-mla", "decode_counted"): "1da08d0e95c61bc072bd9b231b521ec78d76985bef87a7d4073a80496f88a6df",
+    ("tiny-mla", "decode"): "9149ebb86ea6b54e1325400ba4802369f6a72bea8b8d30c5f4b757c4f2996082",
+    ("tiny-mla", "decode_counted"): "9147ef682c95e5fecb95ad433915bfeba2a0f3380597d878c50c6f2a0685bba5",
     ("tiny-mla", "prefill"): "6c33a1196acfc7ee8ffbea0d7919a0a7d80bb61eca02ac3d9b196deb8fa0a876",
 }
 

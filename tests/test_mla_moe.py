@@ -344,29 +344,92 @@ def test_absorbed_attention_is_expanded_attention():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("w", [1, 3])
-def test_the_pallas_kernel_is_the_xla_path_at_the_cells_widths_ratio(w):
-    """32 heads over one latent head, latent : rotary key = 8 : 1, ragged
-    contexts, a table in no order of the pool, an empty slot."""
-    from nats_llm_studio_tpu.ops.mla_attention import (
-        mla_absorbed_attention, mla_paged_decode_attention)
+_KERNEL_SLOTS, _KERNEL_RUNS = 6, 3.2  # the table holds three runs and a fifth
 
-    b, hq, r, dr, t, nb, layers = 3, 32, 128, 16, 16, 8, 2
+
+@pytest.fixture(scope="module")
+def kernel_case():
+    """``_kernel_case`` made once a (T, W) and dropped with the module: the
+    pools are 50 MB a T."""
+    made = {}
+    yield lambda t, w: made.get((t, w)) or made.setdefault((t, w), _kernel_case(t, w))
+    made.clear()
+
+
+def _kernel_case(t, w):
+    """Shapes at which ``_run_entries`` gives a run well inside the table (32
+    heads over one latent head of 384 + 128 in float32: 2,048 B a token, 640
+    tokens a run), the pools, and the two jitted paths: one compile a (T, W)
+    for every pattern of contexts."""
+    from nats_llm_studio_tpu.ops.mla_attention import (
+        _run_entries, mla_absorbed_attention, mla_paged_decode_attention)
+    from nats_llm_studio_tpu.ops.ssm_scan import live_slots
+
+    b, hq, r, dr, layers = _KERNEL_SLOTS, 32, 384, 128, 2
+    k = _run_entries(t, 1 << 20, (r + dr) * 4)
+    nb = int(k * _KERNEL_RUNS)
+    assert _run_entries(t, nb, (r + dr) * 4) == k and 1 < k < nb
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
     qt = jax.random.normal(ks[0], (b, w, hq, r), jnp.float32)
     qr = jax.random.normal(ks[1], (b, w, hq, dr), jnp.float32)
     c_pool = jax.random.normal(ks[2], (1 + b * nb, layers, 1, t, r), jnp.float32)
     r_pool = jax.random.normal(ks[3], (1 + b * nb, layers, 1, t, dr), jnp.float32)
-    tbl = np.zeros((b, nb), np.int32)
-    tbl[0] = np.random.default_rng(0).permutation(np.arange(1, 1 + nb))
-    tbl[1, :3] = [20, 11, 17]
-    pos = jnp.asarray([100, 37, 0], jnp.int32)
-    got = mla_paged_decode_attention(qt, qr, c_pool, r_pool, jnp.asarray(tbl), pos, 1, 0.11,
-                                     interpret=True)
-    view = lambda pool: pool[jnp.asarray(tbl), 1, 0].reshape(b, nb * t, -1)  # noqa: E731
-    positions = pos[:, None] + jnp.arange(w)[None]
-    want = mla_absorbed_attention(qt, qr, view(c_pool), view(r_pool), positions, 0.11)
-    np.testing.assert_allclose(np.asarray(got[:2]), np.asarray(want[:2]), rtol=2e-4, atol=2e-4)
+
+    @jax.jit
+    def kernel(c_pool, r_pool, tbl, pos):
+        return mla_paged_decode_attention(qt, qr, c_pool, r_pool, tbl, pos,
+                                          live_slots(tbl[:, 0] > 0), 1, 0.11, interpret=True)
+
+    @jax.jit
+    def xla(c_pool, r_pool, tbl, pos):
+        view = lambda pool: pool[tbl, 1, 0].reshape(b, nb * t, -1)  # noqa: E731
+        return mla_absorbed_attention(qt, qr, view(c_pool), view(r_pool),
+                                      pos[:, None] + jnp.arange(w)[None], 0.11)
+
+    return k, nb, c_pool, r_pool, kernel, xla
+
+
+# the last key a slot sees, in runs (n), blocks (t) and tokens; None: no request
+_KERNEL_PATTERNS = {
+    "empty_first_then_a_runs_edge": lambda n, t, end: [None, 5, n - 2, n - 1, n, n - t // 2],
+    "empty_between_and_last": lambda n, t, end: [2 * n + n // 3, n + 1, None, t - 1, 3, None],
+    "all_but_one_empty": lambda n, t, end: [None, None, None, 2 * n + 5, None, None],
+    "none_live": lambda n, t, end: [None] * 6,
+    "all_live_to_the_tables_end": lambda n, t, end: [end - 1, n, 2 * n - 1, 2, 3 * n, t],
+}
+
+
+@pytest.mark.parametrize("pattern", list(_KERNEL_PATTERNS))
+@pytest.mark.parametrize("w", [1, 3])
+@pytest.mark.parametrize("t", [16, 64])
+def test_the_pallas_kernel_is_the_xla_path_over_its_live_slots(kernel_case, t, w, pattern):
+    """The kernel through the Pallas interpreter against the XLA form over
+    the gathered view: tables in no order of the pool, contexts that end
+    inside the first block, one token before / at / after a run's edge, inside
+    a run's last block and several runs on, slots without a request before,
+    between and after the others. Every block the walk has no business with
+    (the null block, a live slot's entries past its last live block, all of an
+    empty slot's) holds NaN in the pools the kernel gets: a live row comes out
+    as the XLA path's over the same pools without them, an empty row as exact
+    zeros."""
+    k, nb, c_pool, r_pool, kernel, xla = kernel_case(t, w)
+    last_keys = _KERNEL_PATTERNS[pattern](k * t, t, nb * t)
+    assert len(last_keys) == _KERNEL_SLOTS
+    rng = np.random.default_rng(len(pattern))
+    ids = rng.permutation(np.arange(1, 1 + _KERNEL_SLOTS * nb)).reshape(_KERNEL_SLOTS, nb)
+    live = np.array([x is not None for x in last_keys])
+    tbl = np.where(live[:, None], ids, 0).astype(np.int32)
+    pos = np.array([77 if x is None else max(x - (w - 1), 0) for x in last_keys], np.int32)
+    read = np.zeros(c_pool.shape[0], bool)
+    for slot in np.flatnonzero(live):
+        read[tbl[slot, : (pos[slot] + w - 1) // t + 1]] = True
+    unread = jnp.asarray(~read)[:, None, None, None, None]
+    got = np.asarray(kernel(jnp.where(unread, jnp.nan, c_pool), jnp.where(unread, jnp.nan, r_pool),
+                            jnp.asarray(tbl), jnp.asarray(pos)))
+    want = np.asarray(xla(c_pool, r_pool, jnp.asarray(tbl), jnp.asarray(pos)))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-4, atol=2e-4)
+    assert not got[~live].any()
 
 
 def test_yarn_is_its_closed_form_below_and_above_the_original_context():

@@ -219,30 +219,59 @@ def test_paged_decode_shard_map_tp4(tp4, no_cache):
     assert "all-gather" not in compiled.as_text()
 
 
+# (slots, block tokens, table width, pool layers) of the two cells that run the
+# latent kernel: 32 query heads over ONE latent head of 512 + a rotary key of
+# 64 in rows padded to the 128 lanes (a copy out of the pool cannot slice
+# inside a lane tile: 64-wide rows are refused)
+MLA_CELLS = {"xing29b.answer_closed": (SLOTS, T, SEQ // T, 7),
+             "kanana2.longctx_closed": (16, 64, 32768 // 64, 6)}
+
+
 @pytest.mark.parametrize("w", [1, SPEC_W], ids=["decode", "spec_verify"])
-def test_mla_paged_decode_at_the_benchmark_cells_shapes(one_chip, no_cache, w):
-    """Xing4.0-29B-A4B as ``xing29b.answer_closed`` serves it: 8 slots, 32
-    query heads over ONE latent head of 512 + a rotary key of 64 in rows
-    padded to the 128 lanes (a copy out of the pool cannot slice inside a lane
-    tile: 64-wide rows are refused), MAX_SEQ_LEN 4096 = table width 256, a
-    7-layer pool pair."""
+@pytest.mark.parametrize("cell", list(MLA_CELLS))
+def test_mla_paged_decode_at_the_benchmark_cells_shapes(one_chip, no_cache, cell, w):
+    """Xing4.0-29B-A4B as ``xing29b.answer_closed`` serves it (8 slots,
+    MAX_SEQ_LEN 4096 in blocks of 16, a 7-layer pool pair) and kanana-2 as
+    ``kanana2.longctx_closed`` does (16 slots of 32,768 in blocks of 64, 6
+    layers): the table, the launch's live list and the landing buffers of a
+    run of the rule's length fit the scalar memory and the VMEM Mosaic gives
+    unasked."""
     from nats_llm_studio_tpu.ops.mla_attention import (
         mla_paged_decode_attention,
         mla_paged_decode_eligible,
     )
+    from nats_llm_studio_tpu.ops.ssm_scan import LiveSlots
 
-    layers, width, r, dr = 7, SEQ // T, 512, 128
-    assert mla_paged_decode_eligible(T, r, 2)
+    slots, t, width, layers = MLA_CELLS[cell]
+    r, dr = 512, 128
+    assert mla_paged_decode_eligible(t, r, 2)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    blocks = SLOTS * width + 64 + 1
+    blocks = slots * width + 64 + 1
     _compile(
-        lambda qt, qr, cp, rp, tbl, pos, layer: mla_paged_decode_attention(
-            qt, qr, cp, rp, tbl, pos, layer, 0.1447),
-        sds((SLOTS, w, HQ, r), jnp.bfloat16), sds((SLOTS, w, HQ, dr), jnp.bfloat16),
-        sds((blocks, layers, 1, T, r), jnp.bfloat16),
-        sds((blocks, layers, 1, T, dr), jnp.bfloat16),
-        sds((SLOTS, width), jnp.int32), sds((SLOTS,), jnp.int32), sds((), jnp.int32),
+        lambda qt, qr, cp, rp, tbl, pos, mask, order, n, layer: mla_paged_decode_attention(
+            qt, qr, cp, rp, tbl, pos, LiveSlots(mask, order, n), layer, 0.1447),
+        sds((slots, w, HQ, r), jnp.bfloat16), sds((slots, w, HQ, dr), jnp.bfloat16),
+        sds((blocks, layers, 1, t, r), jnp.bfloat16),
+        sds((blocks, layers, 1, t, dr), jnp.bfloat16),
+        sds((slots, width), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots,), jnp.bool_), sds((slots,), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.int32),
     )
+
+
+def test_the_latent_kernels_run_is_sized_by_the_latent_caches_bytes():
+    """What ``_run_entries`` gives at the two cells' shapes: 1,280 KiB of
+    1,280 B rows = 1,024 tokens a run, 16 blocks of 64 and 64 blocks of 16;
+    a narrow table bounds it, wider rows shorten it, and the dense kernel's
+    rule is not asked."""
+    from nats_llm_studio_tpu.ops.mla_attention import _run_entries
+
+    row = (512 + 128) * 2
+    got = {cell: _run_entries(t, width, row) for cell, (_, t, width, _) in MLA_CELLS.items()}
+    assert got == {"xing29b.answer_closed": 64, "kanana2.longctx_closed": 16}
+    assert _run_entries(64, 8, row) == 8
+    assert _run_entries(64, 512, 2 * row) == 8
+    assert _run_entries(256, 128, 8 * row) == 1
 
 
 def test_moe_hit_experts_at_the_benchmark_cells_shapes(one_chip, no_cache):
