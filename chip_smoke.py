@@ -32,6 +32,21 @@ from pathlib import Path
 
 REPO = Path(__file__).resolve().parent
 MODEL_ID = "smoke/llama3-8b-q8_0"
+# Meta-Llama-3-8B-Instruct's published geometry (ModelConfig's fields): 32
+# layers, d=4096, ff=14336, GQA 32q/8kv, head_dim 128, vocab 128256, rope 500k
+LLAMA3_8B = dict(
+    arch="llama",
+    vocab_size=128256,
+    d_model=4096,
+    n_layers=32,
+    n_heads=32,
+    n_kv_heads=8,
+    head_dim=128,
+    d_ff=14336,
+    rope_theta=500000.0,
+    max_seq_len=8192,
+    dtype="bfloat16",
+)
 MAX_NEW = 32
 # first-token top-k logprobs of tp=4 vs one chip: the same int8 weights and
 # bf16 activations, but tp turns every row-sharded contraction (wo, w_down)
@@ -79,7 +94,7 @@ def generate_gguf(cfg, path: Path, seed: int) -> dict:
     embedding, and the file is the real size."""
     import numpy as np
 
-    from bench import byte_level_tokenizer_md
+    from benchmark.lib.model_files import byte_level_tokenizer_md
     from nats_llm_studio_tpu.gguf.constants import GGMLType
     from nats_llm_studio_tpu.gguf.quants import quantize
     from nats_llm_studio_tpu.gguf.writer import GGUFWriter
@@ -478,14 +493,14 @@ def main(argv: list[str] | None = None) -> None:
 
     import jax
 
-    from bench import LLAMA3_8B
+    from nats_llm_studio_tpu.models.config import ModelConfig
 
     devs = jax.devices()
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)}
     if device["platform"] != "tpu" or device["count"] != args.chips:
         sys.exit(f"chip_smoke: needs {args.chips} TPU device(s), JAX found {device}")
     # the published context is 8192; MAX_SEQ_LEN (default 4096) clamps serving
-    cfg = LLAMA3_8B
+    cfg = ModelConfig(**LLAMA3_8B)
     try:
         if args.chips == 1:
             run = asyncio.run(serve_and_drive(cfg, args.out, args.seed))

@@ -117,8 +117,8 @@ def _attention_block(
             # Constraining the slice's layout merges both into one pass:
             # 19.3 -> 15.6 ms/step (granite-2b b32). In the POSITIONAL path
             # the per-row scatter pins a different cache layout and the same
-            # constraint backfires into full-cache relayouts (~16x slower —
-            # caught by scripts/ablate_decode.py).
+            # constraint backfires into full-cache relayouts (~16x slower,
+            # seen in a per-layer ablation of the decode step on the chip).
             sl = with_layout_constraint(
                 sl, Layout(major_to_minor=(1, 0, 2, 4, 3))
             )

@@ -12,7 +12,8 @@ One collector per cluster (or a queue group of them) does three jobs:
   subject on an interval, and serves one cluster-level Prometheus
   exposition on ``{prefix}.cluster.metrics.prom``: counters/gauges sum
   across workers, histograms merge delta-first through
-  :func:`obs.histogram.merge` (the same code path bench.py uses), and
+  :func:`obs.histogram.merge` (delta first: a counter reset on one
+  worker never reads as negative traffic), and
   the ``worker_id`` label is dropped from merged families.
 * **SLO burn-rate alerts** — objectives (cluster TTFT p95,
   served-or-retryable ratio, shed rate) are evaluated over a fast and a
@@ -693,7 +694,7 @@ class Aggregator:
         r.gauge("lmstudio_cluster_ttft_p95_ms",
                 round(self._cluster_ttft_p95, 3),
                 help="cluster TTFT p95 merged delta-first across the last "
-                     "scrape (upper bucket edge, same code path as bench.py)")
+                     "scrape (upper bucket edge, obs.histogram.merge)")
         r.counter("lmstudio_cluster_slo_alerts_total", self.alerts_total,
                   help="slo_burn events published")
         for objective, burns in sorted(self.slo.last_burns.items()):

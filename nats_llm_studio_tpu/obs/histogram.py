@@ -162,8 +162,8 @@ class LogHistogram:
 
 
 # ---------------------------------------------------------------------------
-# Cross-worker histogram merging (promoted from bench.py's cluster/disagg
-# phases so bench and the fleet aggregator share one tested code path).
+# Cross-worker histogram merging: the fleet aggregator and any other reader
+# of several workers' expositions share this one tested code path.
 # ---------------------------------------------------------------------------
 
 _INF = float("inf")

@@ -424,8 +424,8 @@ class BatcherStats:
     expert_prefill_rows_hit_list: int = 0
     # bounded log-bucket histograms (obs/histogram.py): O(1) record on the
     # batcher owner thread, O(buckets) snapshot from the asyncio metrics
-    # handlers, fixed memory for the life of the worker. Phase deltas come
-    # from snapshot subtraction (bench.py), not index bookkeeping.
+    # handlers, fixed memory for the life of the worker. A reader that
+    # wants one phase's samples subtracts two snapshots (``s1 - s0``).
     admit_delay_ms: LogHistogram = field(default_factory=LogHistogram)
     ttft_ms: LogHistogram = field(default_factory=LogHistogram)  # enqueue -> first token
     prefill_ms: LogHistogram = field(default_factory=LogHistogram)  # admit -> first token
@@ -456,8 +456,8 @@ class BatcherStats:
     device_ms: dict = field(default_factory=dict)
     device_tokens: dict = field(default_factory=dict)
     # exact sum of every dispatch's ms (the same samples program_ms buckets
-    # approximately): reconciliation denominator for the ledger — the bench
-    # `efficiency` phase asserts category sums match this within 10%
+    # approximately): reconciliation denominator for the ledger
+    # (tests/test_efficiency.py holds the category sums to it within 10%)
     dispatch_ms_total: float = 0.0
     _lock: threading.Lock = field(default_factory=threading.Lock)
 
@@ -802,7 +802,7 @@ class ContinuousBatcher:
         # into (HBM: m x 2 full-length rows) and the compiled widths.
         # Concurrent long prompts otherwise serialize one full chunked
         # prefill each — B=1 chunks at poor MXU utilization, measured ~4x
-        # the wall time of one [4, C]-chunked pass in the r4 bench.
+        # the wall time of one [4, C]-chunked pass on the chip (round 4).
         self.max_group_long = max(1, max_group_long)
         # overload bounds (0 = off). Depth: submit fails fast past this many
         # queued-not-yet-admitted requests. Age: the owner thread sheds
@@ -3792,7 +3792,7 @@ class ContinuousBatcher:
                 # engine: the whole prompt in ONE fresh flash dispatch at a
                 # pow2 token bucket — chunking only exists to bound live
                 # streams' inter-token gap, and with nothing else decoding
-                # it costs ~2x the wall time (scripts/ablate_chunk_one.py);
+                # it costs ~2x the wall time (measured on the chip at 16k);
                 # a hit covering less than half the prompt is released in
                 # favor of it. Otherwise: chunked prefill, fixed [1, C]
                 # chunks with a shared decode step between chunks, so

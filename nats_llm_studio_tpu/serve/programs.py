@@ -329,7 +329,7 @@ def build_programs(cfg: ModelConfig, mesh, *, max_seq: int, paged: bool,
         admits). Chunking exists to bound live streams' inter-token
         gap; with nothing else decoding it is pure overhead — measured
         on-chip at 16k: ~110-180 ms per chunk of structural cost
-        beyond the matmuls (scripts/ablate_chunk_one.py), 5.2 s
+        beyond the matmuls, 5.2 s
         chunked vs 2.3 s for this path. Tokens are right-padded to a
         pow2 bucket (pad keys sit at positions only pad queries can
         see; the rolled-in junk above ``n`` lands on future ring slots

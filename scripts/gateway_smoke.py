@@ -13,11 +13,16 @@ import sys
 import tempfile
 from pathlib import Path
 
+import jax
+
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from bench import _export_tiny_gguf  # noqa: E402
+from benchmark.lib.model_files import byte_level_tokenizer_md  # noqa: E402
 from nats_llm_studio_tpu.config import WorkerConfig  # noqa: E402
 from nats_llm_studio_tpu.gateway import Gateway  # noqa: E402
+from nats_llm_studio_tpu.models.config import ModelConfig  # noqa: E402
+from nats_llm_studio_tpu.models.export import export_params_to_gguf  # noqa: E402
+from nats_llm_studio_tpu.models.llama import init_params  # noqa: E402
 from nats_llm_studio_tpu.serve import Worker  # noqa: E402
 from nats_llm_studio_tpu.serve.registry import LocalRegistry  # noqa: E402
 from nats_llm_studio_tpu.store.manager import ModelStore  # noqa: E402
@@ -72,7 +77,11 @@ async def post_chat(port: int, body: dict) -> tuple[int, dict, bytes]:
 async def main() -> None:
     with tempfile.TemporaryDirectory() as td:
         models_dir = Path(td) / "models"
-        _export_tiny_gguf(models_dir, MODEL)
+        cfg = ModelConfig.tiny(n_layers=2, max_seq_len=64)
+        (models_dir / MODEL).mkdir(parents=True)
+        export_params_to_gguf(
+            models_dir / MODEL / "m.gguf", init_params(cfg, jax.random.PRNGKey(5)), cfg,
+            name=MODEL, tokenizer_md=byte_level_tokenizer_md(cfg.vocab_size))
         broker = await EmbeddedBroker().start()
         worker = Worker(
             WorkerConfig(nats_url=broker.url),

@@ -84,30 +84,3 @@ def tmp_models_dir(tmp_path):
     d = tmp_path / "models"
     d.mkdir()
     return d
-
-
-@pytest.fixture(scope="module", autouse=True)
-def one_rehearsal_at_a_time(request):
-    """Every ``benchmark/run.py`` run empties and refills the one directory
-    ``benchmark/.cache/run``; two rehearsals on two xdist workers can so take
-    the model's header from under each other ("model not found": three of eight
-    started at once, PR 32; the MLA rehearsal failed once in the driver's run
-    of that PR and passes alone). The
-    ``test_benchmark_harness_rehearsal*`` modules and
-    ``test_moe_hit_list_served`` hold a file lock for their duration; nothing
-    else waits."""
-    # test_moe_hit_list_served runs a whole rehearsal too: with a fourth
-    # rehearsal module in the suite (PR 34) it lost the directory to one of
-    # them ("model not found: bench/tiny-mla", the first whole run of PR 34)
-    name = request.module.__name__
-    if "benchmark_harness_rehearsal" not in name and "moe_hit_list_served" not in name:
-        yield
-        return
-    import fcntl
-    from pathlib import Path
-
-    lock = Path(__file__).resolve().parents[1] / "benchmark" / ".cache" / "rehearsal.lock"
-    lock.parent.mkdir(parents=True, exist_ok=True)
-    with open(lock, "w") as f:
-        fcntl.flock(f, fcntl.LOCK_EX)
-        yield

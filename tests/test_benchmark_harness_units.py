@@ -1,6 +1,9 @@
 """The benchmark's quick unit tests under tier 1: file discovery by name, the
-trace reduction against its recorded fixture, the traffic generator (see
-``test_benchmark_harness.py``; no two of the three share a name)."""
+trace reduction against its recorded fixture, the traffic generator, the
+families' readers (see ``test_benchmark_harness.py``). The state-space readers
+come by name: their test of a step's bytes shares its name with the window
+family's, and a second star import would keep the later of the two only
+(``test_benchmark_harness_collection.py`` holds both rules)."""
 
 from benchmark.tests.test_discovery import *  # noqa: F401,F403
 from benchmark.tests.test_gdn_readers import *  # noqa: F401,F403
@@ -9,7 +12,12 @@ from benchmark.tests.test_moe_prefill_chunk_ms import (  # noqa: F401
     test_the_chunk_group_reader_divides_whole_launches_only,
 )
 from benchmark.tests.test_reduce_trace import *  # noqa: F401,F403
-from benchmark.tests.test_ssm_readers import *  # noqa: F401,F403
+from benchmark.tests.test_ssm_readers import (  # noqa: F401
+    test_the_bytes_of_a_step_at_the_published_widths as test_the_bytes_of_a_state_space_step_at_the_published_widths,
+    test_the_counters_readers_sum_the_windows_own_bursts,
+    test_the_roofline_readers_divide_rows_and_seconds_of_the_same_span,
+    test_the_trace_readers_divide_whole_launches_and_the_kernels_own_events,
+)
 from benchmark.tests.test_swa_readers import *  # noqa: F401,F403
 from benchmark.tests.test_traffic import *  # noqa: F401,F403
 

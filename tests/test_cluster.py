@@ -15,6 +15,7 @@ from nats_llm_studio_tpu.serve.api import EngineError
 from nats_llm_studio_tpu.serve.router import RecentHeads, RouterProcess
 from nats_llm_studio_tpu.transport import EmbeddedBroker, RetryPolicy, connect
 from nats_llm_studio_tpu.transport import protocol as p
+from nats_llm_studio_tpu.transport.envelope import deadline_header_value
 
 from conftest import async_test
 from fakes import FakeRegistry
@@ -458,3 +459,39 @@ async def test_retry_stops_when_deadline_budget_exhausted():
         assert resp["retryable"] is True
         assert elapsed < 3.0
         assert reg.sheds + h.workers[0]._excluded_bounce_total <= 5
+
+
+@async_test
+async def test_a_worker_killed_mid_wave_costs_no_request_and_the_survivor_counts_the_hops():
+    """One of two workers loses its connection, with no drain and no goodbye,
+    while a wave is in flight: every request is served inside its budget (an
+    attempt stuck on the dead worker times out and hops), and the survivor's
+    own exposition, scraped on its directed subject, accounts for what it
+    served and carries the bounce counter a rerouted hop would land in."""
+    async with ClusterHarness(n_workers=2) as h:
+        survivor = h.workers[1]
+
+        async def one(i):
+            # asyncio.TimeoutError out of gather() would be a budget that ran out
+            resp, _ = await h.req(
+                "chat_model", h.chat(f"r{i}"), timeout=1.0,
+                headers={p.DEADLINE_HEADER: deadline_header_value(20.0)},
+                retry=RetryPolicy(max_attempts=40, backoff_s=0.05, jitter=0.0,
+                                  retry_on_timeout=True),
+            )
+            return resp
+
+        wave = [asyncio.ensure_future(one(i)) for i in range(12)]
+        await asyncio.sleep(0)
+        await h.workers[0].nc.close()   # the kill
+        results = await asyncio.gather(*wave)
+        assert all(r["ok"] for r in results), results
+        more = await asyncio.gather(*[one(i) for i in range(12, 16)])
+        assert all(r["ok"] for r in more), more
+
+        msg = await h.nc.request(
+            f"lmstudio.worker.{survivor.worker_id}.metrics.prom", b"", timeout=5.0)
+        rows = {line.split("{")[0].split()[0]: float(line.rsplit(None, 1)[1])
+                for line in msg.payload.decode().splitlines() if not line.startswith("#")}
+        assert rows["lmstudio_requests_total"] >= 4
+        assert rows["lmstudio_excluded_bounce_total"] == survivor._excluded_bounce_total

@@ -4,8 +4,8 @@ and the merged cluster exposition carrying the fleet ledger families.
 
 The batcher tests drive real served / cancelled / deadline-aborted /
 speculative requests and assert the ledger's per-category device-ms reconcile
-with the measured dispatch time within 10% — the same invariant bench.py's
-``efficiency`` phase enforces.
+with the measured dispatch time within 10%; nothing else holds that
+invariant.
 """
 
 import asyncio
@@ -153,7 +153,7 @@ def test_hbm_ledger_broken_component_prices_zero():
 
 def _reconcile(stats):
     """Assert the ledger's attributed ms sum to the measured dispatch time
-    within 10% (the bench.py efficiency-phase invariant), and return the
+    within 10%, and return the
     per-category snapshot."""
     dt = stats.device_time_snapshot()
     ledger_ms = sum(dt["ms"].values())

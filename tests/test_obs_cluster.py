@@ -313,8 +313,8 @@ async def test_two_hop_trace_assembly_p95_parity_and_slo_e2e(tmp_path):
     gateway yields ONE assembled tree on ``lmstudio.debug.trace.<id>`` with
     gateway.request -> router.attempt -> worker.serve(decode) ->
     worker.kv_pull -> worker.kv_export(prefill) parent links; the
-    aggregator's cluster TTFT p95 equals bench.py's merge on the same
-    scrape; a deliberately impossible TTFT objective fires slo_burn on the
+    aggregator's cluster TTFT p95 equals this test's own delta-first merge
+    of the same scrape; a deliberately impossible TTFT objective fires slo_burn on the
     events subject; the merged cluster exposition and the gateway's
     /metrics both pass the strict checker."""
     from nats_llm_studio_tpu.config import WorkerConfig
