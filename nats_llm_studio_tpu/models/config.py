@@ -39,6 +39,15 @@ each refuses", has the causes each refusal gives):
   chip may hold a strided share (``qwen3next``): ``models/gdn_moe.py``.
   Served on the paged pool of one chip with a per-slot state pool beside
   it; refused as for the state-space family.
+* ``attention.sparse.*`` keys present (``models/export.py`` writes them, with
+  ``attention.head_count_kv`` a list, 0 for a layer that keeps a recurrent
+  state): Lightning linear-attention layers (a constant decay a head, rotary
+  q and k, an output norm and a sigmoid output gate) beside block-sparse
+  attention layers without rotary embedding that, past ``dense_len`` keys,
+  attend over ``topk`` picked blocks of keys a kv head, a dense SwiGLU in
+  every layer, muP scalings (``minicpm_sala``): ``models/sala.py``. Served on
+  the paged pool of one chip with a per-slot state pool and a per-slot cache
+  of pooled keys beside it; refused as for the state-space family.
 * ``gemma2``, ``gemma3``, ``qwen2moe``: rejected here (post-norms,
   soft-capping, a softmax-gated shared expert).
 """
@@ -184,6 +193,30 @@ class ModelConfig:
     # expert that is not held here adds nothing here.
     moe_ep_size: int = 1
     moe_ep_rank: int = 0
+    # -- Lightning linear attention beside block-sparse attention -------------
+    # (models/sala.py) layer_types names every layer "lightning" or "sparse".
+    # A lightning layer keeps, a slot, a float32 state [lin_v_heads, lin_k_dim,
+    # lin_v_dim] (lin_k_heads == lin_v_heads) that decays by a constant of
+    # (layer, head); q and k are normalised a head (qk_norm) and rotated over
+    # the whole head. A sparse layer (no rotary: use_rope False; qk_norm;
+    # attn_out_gate) attends plainly while a query sees at most
+    # sparse_dense_len keys, and past that over sparse_topk blocks of
+    # sparse_block keys a kv head: the first sparse_init_blocks, those that
+    # meet the last sparse_window keys, and the best by the group's summed
+    # softmax scores against pooled keys (the mean of sparse_kernel keys every
+    # sparse_stride), kept a slot beside the pool. stage_first_layer /
+    # stage_depth: where this file's layers lie in the published stack (a
+    # pipeline stage; 0 / 0 = the whole model), which the initialiser's decay
+    # table reads.
+    sparse_kernel: int = 0
+    sparse_stride: int = 0
+    sparse_block: int = 0
+    sparse_window: int = 0
+    sparse_init_blocks: int = 0
+    sparse_topk: int = 0
+    sparse_dense_len: int = 0
+    stage_first_layer: int = 0
+    stage_depth: int = 0
 
     @property
     def is_mla(self) -> bool:
@@ -199,7 +232,29 @@ class ModelConfig:
 
     @property
     def n_lin_layers(self) -> int:
-        return sum(t == "linear" for t in self.layer_types)
+        """Layers that keep a linear-attention state a slot: the gated delta
+        rule's ("linear") or Lightning's ("lightning")."""
+        return sum(t in ("linear", "lightning") for t in self.layer_types)
+
+    @property
+    def is_sala(self) -> bool:
+        """Lightning layers beside block-sparse attention (models/sala.py)."""
+        return "lightning" in self.layer_types or "sparse" in self.layer_types
+
+    @property
+    def whole_prompt_prefill(self) -> bool:
+        """Whether an idle engine may prefill a prompt over one chunk in ONE
+        dispatch (the flash kernel bounds its scores). A block-sparse layer's
+        masked prefill holds a chunk's queries against every pooled key and a
+        turn of keys at once: a whole prompt of them does not fit, so the
+        family prefills in chunks always."""
+        return self.use_flash_attention and not self.is_sala
+
+    @property
+    def sparse_pooled_len(self) -> int:
+        """Pooled keys a slot a kv head a sparse layer keeps: one every
+        ``sparse_stride`` tokens of ``max_seq_len``."""
+        return self.max_seq_len // self.sparse_stride
 
     @property
     def n_kv_layers(self) -> int:
@@ -210,8 +265,8 @@ class ModelConfig:
 
     @property
     def recurrent(self) -> bool:
-        """Whether a slot keeps a recurrent state (Mamba-2's or the gated
-        delta rule's) that a decode step advances in place."""
+        """Whether a slot keeps a recurrent state (Mamba-2's, the gated delta
+        rule's or Lightning's) that a decode step advances in place."""
         return bool(self.n_ssm_layers or self.n_lin_layers)
 
     @property
@@ -257,6 +312,8 @@ class ModelConfig:
             return "ssm_hybrid"
         if self.n_win_layers:
             return "swa_moe"
+        if self.is_sala:
+            return "sala"
         if self.n_lin_layers:
             return "gdn_moe"
         return "mla_moe" if self.is_mla else "llama"
@@ -447,7 +504,36 @@ class ModelConfig:
                 router_scoring="sigmoid" if int(g("expert_gating_func", 1)) == 2 else "softmax",
                 routed_scaling=float(g("expert_weights_scale", 1.0)),
             )
-        if g("linear_attention.key_head_count") is not None and kv_by_layer:
+        if g("attention.sparse.block_size") is not None and kv_by_layer:
+            # Lightning layers beside block-sparse attention, a dense SwiGLU
+            # in every layer: the keys models/export.config_metadata writes.
+            # The family runs the published combination of switches only
+            for key, want in (("attention.qk_norm", True), ("attention.output_gate", True),
+                              ("attention.use_rope", False), ("linear_attention.use_rope", True),
+                              ("linear_attention.output_gate", True),
+                              ("linear_attention.output_norm", True)):
+                if bool(g(key, want)) != want:
+                    raise NotImplementedError(
+                        f"{arch}: {key} = {g(key)!r} is not the published model's "
+                        f"({want}), and models/sala.py computes that one only")
+            family |= dict(
+                layer_types=tuple("sparse" if h else "lightning" for h in kv_by_layer),
+                lin_k_heads=int(g("linear_attention.key_head_count")),
+                lin_v_heads=int(g("linear_attention.value_head_count")),
+                lin_k_dim=int(g("linear_attention.key_length")),
+                lin_v_dim=int(g("linear_attention.value_length")),
+                attn_out_gate=True, qk_norm=True, use_rope=False,
+                sparse_kernel=int(g("attention.sparse.kernel_size")),
+                sparse_stride=int(g("attention.sparse.kernel_stride")),
+                sparse_block=int(g("attention.sparse.block_size")),
+                sparse_window=int(g("attention.sparse.window_size")),
+                sparse_init_blocks=int(g("attention.sparse.init_blocks")),
+                sparse_topk=int(g("attention.sparse.topk")),
+                sparse_dense_len=int(g("attention.sparse.dense_len")),
+                stage_first_layer=int(g("pipeline.first_layer", 0)),
+                stage_depth=int(g("pipeline.depth", 0)),
+            )
+        elif g("linear_attention.key_head_count") is not None and kv_by_layer:
             # gated-delta-rule layers beside gated attention, experts in every
             # layer: the keys models/export.config_metadata writes
             family |= dict(

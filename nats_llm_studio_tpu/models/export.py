@@ -128,7 +128,37 @@ def config_metadata(cfg: ModelConfig, name: str) -> dict[str, Any]:
             f"{a}.expert_gating_func": 2 if cfg.router_scoring == "sigmoid" else 1,
             f"{a}.expert_weights_scale": cfg.routed_scaling,
         }
-    if cfg.n_lin_layers:
+    if cfg.is_sala:
+        # Lightning layers beside block-sparse attention (minicpm_sala): kv
+        # heads a layer (0 = a layer with a state), the lightning heads, the
+        # switches the published config sets (the family runs that
+        # combination only), the sparse layers' seven sizes, and where the
+        # file's layers lie in the published stack
+        a = cfg.arch
+        md |= {
+            f"{a}.attention.head_count_kv": [
+                cfg.n_kv_heads if t == "sparse" else 0 for t in cfg.layer_types],
+            f"{a}.linear_attention.key_head_count": cfg.lin_k_heads,
+            f"{a}.linear_attention.value_head_count": cfg.lin_v_heads,
+            f"{a}.linear_attention.key_length": cfg.lin_k_dim,
+            f"{a}.linear_attention.value_length": cfg.lin_v_dim,
+            f"{a}.linear_attention.use_rope": True,
+            f"{a}.linear_attention.output_gate": True,
+            f"{a}.linear_attention.output_norm": True,
+            f"{a}.attention.qk_norm": True,
+            f"{a}.attention.output_gate": True,
+            f"{a}.attention.use_rope": False,
+            f"{a}.attention.sparse.kernel_size": cfg.sparse_kernel,
+            f"{a}.attention.sparse.kernel_stride": cfg.sparse_stride,
+            f"{a}.attention.sparse.block_size": cfg.sparse_block,
+            f"{a}.attention.sparse.window_size": cfg.sparse_window,
+            f"{a}.attention.sparse.init_blocks": cfg.sparse_init_blocks,
+            f"{a}.attention.sparse.topk": cfg.sparse_topk,
+            f"{a}.attention.sparse.dense_len": cfg.sparse_dense_len,
+            f"{a}.pipeline.first_layer": cfg.stage_first_layer,
+            f"{a}.pipeline.depth": cfg.stage_depth,
+        }
+    elif cfg.n_lin_layers:
         # gated-delta-rule layers beside gated attention (qwen3next): kv heads
         # a layer as granitehybrid writes them (0 = a layer with a state), the
         # linear layers' five sizes, the attention gate and norms, the router's
@@ -157,7 +187,7 @@ def config_metadata(cfg: ModelConfig, name: str) -> dict[str, Any]:
             f"{a}.expert_parallel.count": cfg.moe_ep_size,
             f"{a}.expert_parallel.rank": cfg.moe_ep_rank,
         }
-    if cfg.arch in ("granite", "granitehybrid"):
+    if cfg.arch in ("granite", "granitehybrid", "minicpm_sala"):
         md[f"{cfg.arch}.embedding_scale"] = cfg.embedding_scale
         md[f"{cfg.arch}.residual_scale"] = cfg.residual_scale
         md[f"{cfg.arch}.logit_scale"] = 1.0 / cfg.logit_scale  # stored as divisor

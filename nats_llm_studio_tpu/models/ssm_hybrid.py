@@ -258,8 +258,11 @@ def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
         return params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
 
 
-# a layer kind's stack under ``blocks``
-STACK = {"mamba": "mamba", "attention": "attn", "linear": "linear"}
+# a layer kind's stack under ``blocks``, and the scope its mixer runs in
+STACK = {"mamba": "mamba", "attention": "attn", "linear": "linear",
+         "lightning": "linear", "sparse": "attn"}
+SCOPE = {"mamba": "seq/ssm", "attention": "seq/attn", "linear": "seq/linear",
+         "lightning": "seq/linear", "sparse": "seq/sparse"}
 
 
 def _layers(params: Params, cfg: ModelConfig, x, carry, mixers, ffn=None):
@@ -295,8 +298,7 @@ def _layers(params: Params, cfg: ModelConfig, x, carry, mixers, ffn=None):
         p = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, layer, axis=0, keepdims=False),
             stacks[kind])
-        with jax.named_scope("seq/ssm" if kind == "mamba" else
-                             "seq/linear" if kind == "linear" else "seq/attn"):
+        with jax.named_scope(SCOPE[kind]):
             out, carry = mixers[kind](rms_norm(x, p["mix_norm"], cfg.rms_eps), p, carry, layer)
             x = x + out * cfg.residual_scale
         if ffn is not None:

@@ -53,7 +53,10 @@ SCOPE_NAMES = (
     "seq/window",    # the same over a ring of the last ``window`` keys (models/swa_moe.py)
     "seq/mla",       # latent attention: down and up projections, the latent write, absorbed or expanded
     "seq/ssm",       # Mamba-2: in-projection, convolution, scan or ssm_state_step, gated norm, out
-    "seq/linear",    # gated delta rule: in-projections, convolution, chunked rule or gdn_step_inputs + gated_delta_step, gated norm, out
+    "seq/linear",    # gated delta rule: in-projections, convolution, chunked rule or gdn_step_inputs + gated_delta_step, gated norm, out; Lightning: projections, norms, rotary, chunked rule or lightning_step, output norm, gate, out
+    "seq/sparse",    # block-sparse attention (models/sala.py): projections, norms, the KV write, the picked walk or masked attention, gate, out
+    "seq/sparse/pool",    # ... the pooled keys a chunk or a step completes, written beside the cache
+    "seq/sparse/select",  # ... scores against the pooled keys, the group's softmax, block maxima, top-k, the picked table
     "ffn",           # the position-wise block, with the norm before and the add after
     "ffn/mlp",       # the dense SwiGLU
     "ffn/router",    # scores, top-k, gates, the expert counters
@@ -69,13 +72,16 @@ _SCOPE_TOPS = frozenset(s for s in SCOPE_NAMES if "/" not in s)
 
 def scope_of(op_name: str) -> str | None:
     """The entry of ``SCOPE_NAMES`` an operation's ``op_name`` lies under:
-    its first top-level word and, where the next word makes a listed path
-    with it, that path. None for glue."""
+    its first top-level word and, where the next word (or two) makes a listed
+    path with it, the longest such path. None for glue."""
     parts = op_name.split("/")
     for i, word in enumerate(parts):
         if word in _SCOPE_TOPS:
-            path = f"{word}/{parts[i + 1]}" if i + 1 < len(parts) else word
-            return path if path in SCOPE_NAMES else word
+            for depth in (3, 2):
+                path = "/".join(parts[i:i + depth])
+                if path in SCOPE_NAMES:
+                    return path
+            return word
     return None
 
 

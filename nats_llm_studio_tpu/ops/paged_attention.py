@@ -32,7 +32,10 @@ it is started, so only a chip run orders the double buffer);
 ``paged_decode_eligible`` gates the auto-downshift to the XLA path for
 shapes Mosaic cannot tile.
 
-At the end of the file: ``window_decode_attention``, the decode kernel of a
+After it: ``paged_decode_attention_picked``, the same walk over the blocks
+each kv head of a block-sparse layer picked for the step (a table a slot and
+kv head, one head's slab a copy), under a name of its own. At the end of the
+file: ``window_decode_attention``, the decode kernel of a
 window-attention layer over a slot's ring (no table, no walk), under a name
 of its own.
 """
@@ -320,6 +323,200 @@ def paged_decode_attention_auto(q, k_pool, v_pool, tbl, pos, layer,
     interpret = jax.default_backend() != "tpu"
     return paged_decode_attention(q, k_pool, v_pool, tbl, pos, layer, scale,
                                   interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# block-sparse layers: decode over the blocks a kv head picked
+# ---------------------------------------------------------------------------
+#
+# A block-sparse layer (models/sala.py) attends, past its dense length, over
+# the blocks of keys each KV HEAD picked for the step: a table a (slot, kv
+# head) of pool block ids, of which the first ``count`` are walked. Every
+# entry but the last is a full block; the last (the frontier block, which the
+# selection always takes) holds ``last_len`` keys. The keys carry no rotary
+# position, so where a block stands in the walk does not matter: the walk of
+# ``_paged_kernel`` with a head axis on the table, one head's [T, D] slab a
+# copy, and a mask that counts keys from the table's start.
+
+# the keys one run of a head's walk attends to: 16 KiB a block and pool at
+# T = 64 (bf16), so a run of 256 would be four small copies a pool
+_PICKED_RUN_TOKENS = 512
+
+
+def _picked_kernel(
+    ent_ref, cnt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
+    kbuf, vbuf, acc_ref, m_ref, l_ref, sem, parity,
+    *, scale: float, t: int, k: int, hkv: int
+):
+    """One grid step = one (slot, kv head): cell c is head ``c % hkv`` of slot
+    ``c // hkv``. It walks the first ``cnt[c]`` entries of its row of the
+    picked table in runs of k, double-buffered as ``_paged_kernel``'s walk is
+    (the first run of cell c + 1 is started behind this cell's last one; the
+    parity word says which half it landed in). An entry past the count
+    re-reads the last one and is masked: key i of the walk is real iff
+    i < (cnt - 1) t + last_len."""
+    c, cells = pl.program_id(0), pl.num_programs(0)
+    layer = layer_ref[0]
+    rows = q_ref.shape[-2]
+
+    def run_copies(ci, run, half, real: bool):
+        """The async copies of run ``run`` of cell ``ci`` into ``half``; not
+        ``real`` builds them for a wait, which needs the shapes and the
+        semaphore only."""
+        out = []
+        for i in range(k):
+            blk = ent_ref[ci, jnp.minimum(run * k + i, cnt_ref[ci] - 1)] if real else 0
+            head = ci % hkv if real else 0
+            for src, dst in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                out.append(pltpu.make_async_copy(
+                    src.at[blk, layer, head], dst.at[half, i], sem.at[half]))
+        return out
+
+    def start(ci, run, half):
+        for cp in run_copies(ci, run, half, True):
+            cp.start()
+
+    @pl.when(c == 0)
+    def _first():
+        parity[0] = 0
+        start(c, 0, 0)
+
+    cnt = cnt_ref[c]
+    live = (cnt - 1) * t + len_ref[c]
+    runs = (cnt + k - 1) // k
+    first = parity[0]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def attend(r, carry):
+        half = (first + r) % 2
+        more = r + 1 < runs
+
+        @pl.when(jnp.logical_or(more, c + 1 < cells))
+        def _prefetch():
+            start(jnp.where(more, c, jnp.minimum(c + 1, cells - 1)),
+                  jnp.where(more, r + 1, 0), 1 - half)
+
+        for cp in run_copies(c, r, half, False):
+            cp.wait()
+        q = q_ref[0]  # [rows, D]
+        kk = kbuf[half].reshape(k * t, kbuf.shape[-1]).astype(q.dtype)
+        vv = vbuf[half].reshape(k * t, vbuf.shape[-1]).astype(q.dtype)
+        s = jax.lax.dot_general(
+            q, kk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [rows, k*T] f32
+        at = r * (k * t) + jax.lax.broadcasted_iota(jnp.int32, (rows, k * t), 1)
+        s = jnp.where(at < live, s, _NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        return carry
+
+    jax.lax.fori_loop(0, runs, attend, 0)
+    parity[0] = (first + runs) % 2
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_decode_attention_picked(
+    q: jax.Array,        # [B, 1, Hq, D]: the query of each slot
+    k_pool: jax.Array,   # [NBp, L, Hkv, T, D]
+    v_pool: jax.Array,
+    entries: jax.Array,  # [B, Hkv, P] int32 pool block ids, a kv head's picks
+    count: jax.Array,    # [B, Hkv] int32: entries to walk, >= 1
+    last_len: jax.Array,  # [B] int32: keys the LAST walked entry holds, 1..T
+    layer,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """Attention of one new token a slot over the pool blocks each kv head
+    picked: head h of slot b reads ``entries[b, h, :count[b, h]]``, every
+    block whole but the last. One compiled program for every count; its time
+    follows the picked keys. Returns [B, 1, Hq, D] in q.dtype. (The order of
+    the entries is the caller's: softmax attention over keys without a rotary
+    position does not care.)"""
+    b, w, hq, d = q.shape
+    if w != 1:
+        raise NotImplementedError("the picked walk decodes one position a slot")
+    hkv, t = k_pool.shape[2], k_pool.shape[3]
+    group, width = hq // hkv, entries.shape[-1]
+    k = max(1, min(_PICKED_RUN_TOKENS // t, width))
+    mult = 8 if q.dtype.itemsize >= 4 else 16
+    rows_p = -(-group // mult) * mult
+    qh = q.reshape(b * hkv, group, d)
+    if rows_p != group:
+        qh = jnp.pad(qh, ((0, 0), (0, rows_p - group), (0, 0)))
+
+    def q_map(c, *_):
+        return (c, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b * hkv,),
+        in_specs=[pl.BlockSpec((1, rows_p, d), q_map)] + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+        out_specs=pl.BlockSpec((1, rows_p, d), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, k, t, d), k_pool.dtype),
+            pltpu.VMEM((2, k, t, d), v_pool.dtype),
+            pltpu.VMEM((rows_p, d), jnp.float32),
+            pltpu.VMEM((rows_p, 128), jnp.float32),
+            pltpu.VMEM((rows_p, 128), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_picked_kernel, scale=scale, t=t, k=k, hkv=hkv),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * hkv, rows_p, d), q.dtype),
+        # cell c+1's first run is started by cell c: the grid runs in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        # its own constant name: a device trace tells the picked walk from the
+        # whole-table walk (paged_decode_attention) by it
+        name="paged_decode_attention_picked",
+    )(
+        entries.astype(jnp.int32).reshape(b * hkv, width),
+        jnp.maximum(count.astype(jnp.int32), 1).reshape(b * hkv),
+        jnp.repeat(jnp.asarray(last_len, jnp.int32).reshape(b), hkv),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        qh, k_pool, v_pool,
+    )
+    return out[:, :group].reshape(b, 1, hq, d)
+
+
+def paged_decode_attention_picked_auto(q, k_pool, v_pool, entries, count, last_len, layer,
+                                       scale: float) -> jax.Array:
+    interpret = jax.default_backend() != "tpu"
+    return paged_decode_attention_picked(q, k_pool, v_pool, entries, count, last_len, layer,
+                                         scale, interpret=interpret)
+
+
+def paged_decode_attention_picked_xla(q, k_pool, v_pool, entries, count, last_len, layer,
+                                      scale: float) -> jax.Array:
+    """The picked walk in plain XLA (what the kernel is held to in the tests;
+    no program runs it): every entry gathered, the keys past the walk masked."""
+    b, _, hq, d = q.shape
+    hkv, t = k_pool.shape[2], k_pool.shape[3]
+    width = entries.shape[-1]
+    heads = jnp.arange(hkv)[None, :, None]
+    ks = k_pool[entries, layer, heads].reshape(b, hkv, width * t, d)  # [B, Hkv, P*T, D]
+    vs = v_pool[entries, layer, heads].reshape(b, hkv, width * t, d)
+    live = (jnp.maximum(count, 1) - 1) * t + last_len[:, None]        # [B, Hkv]
+    qg = q.reshape(b, hkv, hq // hkv, d)
+    s = jnp.einsum("bhgd,bhkd->bhgk", qg, ks.astype(q.dtype),
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where((jnp.arange(width * t) < live[..., None])[:, :, None], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhgk,bhkd->bhgd", p.astype(q.dtype), vs.astype(q.dtype))
+    return o.reshape(b, 1, hq, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

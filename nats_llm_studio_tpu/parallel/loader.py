@@ -93,6 +93,14 @@ def load_params_sharded(
             "yet; the tree to build is models.swa_moe.init_params' (attention "
             "leaves in blocks.full and blocks.win, MLP leaves in blocks.dense "
             "and blocks.moe), placed by param_sharding_rules")
+    if cfg.is_sala:
+        raise NotImplementedError(
+            f"{cfg.arch}: no GGUF tensor-name map for lightning / block-sparse "
+            "models yet; the tree to build is models.sala.init_params' (mixers and "
+            "each layer's SwiGLU in blocks.linear and blocks.attn by kind; a sparse "
+            "layer's in-projection laid out plainly as [q | gate]; the rotary pairs "
+            "permuted to (first half, second half); blocks.linear.decay the LOGITS of "
+            "the checkpoint's rates a (layer, head)), placed by param_sharding_rules")
     if cfg.n_lin_layers:
         raise NotImplementedError(
             f"{cfg.arch}: no GGUF tensor-name map for linear-attention models "

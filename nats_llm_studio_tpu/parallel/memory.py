@@ -33,6 +33,8 @@ def _leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
         return _ssm_leaves(cfg, dtype_bytes)
     if cfg.n_win_layers:
         return _swa_leaves(cfg, dtype_bytes)
+    if cfg.is_sala:
+        return _sala_leaves(cfg, dtype_bytes)
     if cfg.n_lin_layers:
         return _gdn_leaves(cfg, dtype_bytes)
     out: dict[str, _Leaf] = {
@@ -182,6 +184,28 @@ def _gdn_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
     return out
 
 
+def _sala_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
+    """The leaves of ``models.sala.init_params``, unsharded (the family serves
+    on one chip a replica); norms and the decay leaf are left out."""
+    from ..ops.wquant import quantizable
+
+    d, V, ff, hd = cfg.d_model, cfg.vocab_size, cfg.d_ff, cfg.head_dim
+    hq, hkv = cfg.n_heads, cfg.n_kv_heads
+    kd, vd = cfg.lin_v_heads * cfg.lin_k_dim, cfg.lin_v_heads * cfg.lin_v_dim
+    ffn = {"w_gate": (d, ff), "w_up": (d, ff), "w_down": (ff, d)}
+    stacks = (("linear", cfg.n_lin_layers, ffn | {
+                  "wq": (d, kd), "wk": (d, kd), "wv": (d, vd), "wg": (d, vd), "wo": (vd, d)}),
+              ("attn", cfg.n_kv_layers, ffn | {
+                  "wq": (d, 2 * hq * hd), "wk": (d, hkv * hd), "wv": (d, hkv * hd),
+                  "wo": (hq * hd, d)}))
+    out = {"embed": _Leaf((V, d), (), dtype_bytes),
+           "lm_head": _Leaf((d, V), (), dtype_bytes, True)}
+    for name, L, leaves in stacks:
+        for k, shape in leaves.items() if L else ():
+            out[f"blocks.{name}.{k}"] = _Leaf((L,) + shape, (), dtype_bytes, quantizable(k))
+    return out
+
+
 def state_slot_bytes(cfg: ModelConfig) -> int:
     """Device bytes of what ONE slot keeps beside its KV blocks (0 for a
     family that keeps nothing there): a state-space family's recurrent
@@ -196,9 +220,9 @@ def state_slot_bytes(cfg: ModelConfig) -> int:
 
         return ring_bytes_per_slot(cfg)
     if cfg.n_lin_layers:
-        from ..models.gdn_moe import state_bytes_per_slot
+        from ..models.llama import family_module
 
-        return state_bytes_per_slot(cfg)
+        return family_module(cfg).state_bytes_per_slot(cfg)
     return 0
 
 

@@ -58,6 +58,8 @@ def param_sharding_rules(mesh: Mesh, cfg: ModelConfig | None = None) -> dict[str
         return _ssm_rules(pp)
     if cfg is not None and cfg.n_win_layers:
         return _swa_rules(ep, pp)
+    if cfg is not None and cfg.is_sala:
+        return _sala_rules(pp)
     if cfg is not None and cfg.n_lin_layers:
         return _gdn_rules(pp)
     return {
@@ -147,6 +149,20 @@ def _gdn_rules(pp) -> dict[str, P]:
            "w_up_e": 3, "w_down_e": 3, "w_gate_s": 2, "w_up_s": 2, "w_down_s": 2}
     rules = {"embed": P(None, None), "out_norm": P(None), "lm_head": P(None, None)}
     for name, leaves in (("linear", linear), ("attn", attn), ("moe", moe)):
+        rules |= {f"blocks.{name}.{k}": P(pp, *[None] * r) for k, r in leaves.items()}
+    return rules
+
+
+def _sala_rules(pp) -> dict[str, P]:
+    """A rule for every leaf ``models.sala.init_params`` makes: the two
+    stacks' layer axis on pp, everything else whole (the family is served on
+    one chip a replica: ``validate_mesh_for_config`` refuses a mesh over it)."""
+    ffn = {"mix_norm": 1, "ffn_norm": 1, "w_gate": 2, "w_up": 2, "w_down": 2}
+    linear = ffn | {"wq": 2, "wk": 2, "wv": 2, "wg": 2, "wo": 2, "q_norm": 1, "k_norm": 1,
+                    "out_norm": 1, "decay": 1}
+    attn = ffn | {"wq": 2, "wk": 2, "wv": 2, "wo": 2, "q_norm": 1, "k_norm": 1}
+    rules = {"embed": P(None, None), "out_norm": P(None), "lm_head": P(None, None)}
+    for name, leaves in (("linear", linear), ("attn", attn)):
         rules |= {f"blocks.{name}.{k}": P(pp, *[None] * r) for k, r in leaves.items()}
     return rules
 
@@ -303,6 +319,12 @@ def validate_mesh_for_config(mesh: Mesh, cfg: ModelConfig,
             f"state-space models ({cfg.arch}) serve on one chip a replica "
             "(MESH_SHAPE=off): the scan's heads and the per-slot state pool "
             "have no mesh split yet"
+        )
+    if cfg.is_sala and mesh.size > 1:
+        raise ValueError(
+            f"lightning / block-sparse models ({cfg.arch}) serve on one chip a replica "
+            "(MESH_SHAPE=off): the per-slot state pool and the per-slot pooled keys "
+            "have no mesh split yet, and the picked walk no tp split of the kv heads"
         )
     if cfg.n_lin_layers and mesh.size > 1:
         raise ValueError(

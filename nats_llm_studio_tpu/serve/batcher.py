@@ -155,9 +155,23 @@ _SLOT_STATE_CAUSES["linear"] = _SLOT_STATE_CAUSES["state"] | {
 }
 
 
+# a lightning / block-sparse family (models/sala.py) keeps a recurrent state
+# and, for its sparse layers, a slot's pooled keys: the linear family's causes,
+# with the pooled keys named where KV blocks alone would not do either
+_SLOT_STATE_CAUSES["sparse"] = _SLOT_STATE_CAUSES["linear"] | {
+    "kv_tiers": "the host/Object-Store KV tiers hold KV blocks, no state and no "
+                "pooled keys: a prefix promoted from a tier could not be decoded "
+                "from: set KV_HOST_POOL_BYTES=0",
+    "prefix_cache": "off: the cache holds KV blocks and no snapshot of the "
+                    "recurrent state at a block's end nor the sparse layers' "
+                    "pooled keys, so a hit could not be decoded from",
+}
+
+
 def _slot_state_causes(cfg: ModelConfig) -> dict[str, str]:
     return _SLOT_STATE_CAUSES[
-        "state" if cfg.n_ssm_layers else "linear" if cfg.n_lin_layers else "ring"]
+        "state" if cfg.n_ssm_layers else "sparse" if cfg.is_sala
+        else "linear" if cfg.n_lin_layers else "ring"]
 
 
 class BatcherStopped(RuntimeError):
@@ -398,6 +412,13 @@ class BatcherStats:
     # zeros in one dispatch, or carried from chunk to chunk of a prompt over
     # one chunk)
     state_rows: int = 0
+    # block-sparse layers (models/sala.py), summed over decode steps, live
+    # rows and sparse layers: the keys a row could see (live), the keys of the
+    # blocks it picked and walked (picked: all of them while it is under the
+    # dense length), and the rows still under it
+    sparse_tokens_live: int = 0
+    sparse_tokens_picked: int = 0
+    sparse_rows_dense: int = 0
     state_steps: int = 0
     state_slots_moved: int = 0
     state_admits_fresh: int = 0
@@ -607,6 +628,32 @@ class BatcherStats:
         for k, v in burst.items():
             setattr(self, k, getattr(self, k) + v)
         return burst
+
+    def record_sparse(self, starts: list[int], steps: int, cfg) -> dict[str, int]:
+        """One decode burst of a family with block-sparse layers: its live
+        rows begin at positions ``starts`` and take ``steps`` steps. A row at
+        position p sees p + 1 keys a sparse layer; past the dense length it
+        walks ``sparse_topk`` blocks, the last up to its own key. Returns what
+        the readback span carries."""
+        blk, layers = cfg.sparse_block, cfg.n_kv_layers
+        live = picked = dense = 0
+        for p in starts:
+            for n in range(p + 1, p + steps + 1):
+                live += n
+                if n <= cfg.sparse_dense_len:
+                    picked, dense = picked + n, dense + 1
+                else:
+                    picked += (cfg.sparse_topk - 1) * blk + (n - 1) % blk + 1
+        burst = {"sparse_tokens_live": live * layers, "sparse_tokens_picked": picked * layers,
+                 "sparse_rows_dense": dense * layers}
+        for k, v in burst.items():
+            setattr(self, k, getattr(self, k) + v)
+        return burst
+
+    def sparse_counters(self) -> dict[str, int]:
+        """Exposed by serve/worker.py as lmstudio_sparse_*_total."""
+        return {"tokens_live": self.sparse_tokens_live, "tokens_picked": self.sparse_tokens_picked,
+                "rows_dense": self.sparse_rows_dense}
 
     def window_counters(self) -> dict[str, int]:
         """Exposed by serve/worker.py as lmstudio_swa_*_total."""
@@ -1554,7 +1601,7 @@ class ContinuousBatcher:
                 # Flash-gated like the serving shortcut itself: without the
                 # kernel these programs are the dense-score blowup the
                 # chunked path exists to avoid, and serving never runs them
-                if self.cfg.use_flash_attention:
+                if self.cfg.whole_prompt_prefill:
                     full_buckets = sorted(
                         {self._win_bucket(x) for x in range(C + 1, self.max_seq + 1, C)}
                     )
@@ -2467,6 +2514,9 @@ class ContinuousBatcher:
                     if self._window_pool is not None:
                         spn.attrs.update(self.stats.record_window(
                             [req.pos for _, req in rows], n, cfg.window))
+                    if cfg.is_sala:
+                        spn.attrs.update(self.stats.record_sparse(
+                            [req.pos for _, req in rows], n, cfg))
                     if cfg.is_mla:
                         # the live rows' positions at the burst's first step:
                         # the latents the absorbed kernel reads, by row
@@ -2601,6 +2651,9 @@ class ContinuousBatcher:
                     if self._window_pool is not None:
                         spn.attrs.update(self.stats.record_window(
                             [req.pos for _, req in rows], 1, cfg.window))
+                    if cfg.is_sala:
+                        spn.attrs.update(self.stats.record_sparse(
+                            [req.pos for _, req in rows], 1, cfg))
                     ids = np.asarray(toks_ref)  # [B]
                     lps = np.asarray(lp_ref)  # [B]
                     tis = np.asarray(topids_ref)  # [B, LOGPROBS_K]
@@ -3665,7 +3718,7 @@ class ContinuousBatcher:
                             decode_once()
                             pump()
                     skip = p // C
-                elif not active() and cfg.use_flash_attention:
+                elif not active() and cfg.whole_prompt_prefill:
                     k1, v1 = self._make_row_cache(1, self.max_seq)
                     wb = self._win_bucket(n)
                     toks = req.prompt_ids + [0] * (wb - n)
@@ -3853,7 +3906,7 @@ class ContinuousBatcher:
                             req.prompt_ids, k1, v1, 0, chunk_logits,
                             skip_chunks=p // C,
                         )
-                    elif not active() and cfg.use_flash_attention:
+                    elif not active() and cfg.whole_prompt_prefill:
                         # the shortcut needs the fresh FLASH path: through the
                         # dense fallback a full-bucket prefill would materialize
                         # the [Hq, bucket, S] f32 scores the chunked path exists

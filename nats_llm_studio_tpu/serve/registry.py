@@ -1312,7 +1312,7 @@ class LocalRegistry(Registry):
             # yet, so none of the idle-engine single-dispatch shortcuts)
             use_flash_attention=(
                 jax.default_backend() == "tpu" and self._kv_tp(cfg) == tp
-                and cfg.family in ("llama", "swa_moe", "gdn_moe")
+                and cfg.family in ("llama", "swa_moe", "gdn_moe", "sala")
             ),
             use_routed_moe=True,  # sparse dispatch (parallel/moe.py)
             kv_quant=self.kv_quant,
@@ -1399,7 +1399,8 @@ class LocalRegistry(Registry):
             if cfg.slot_state and self.kv_host_pool_bytes > 0:
                 b.refusals["kv_tiers"] = (
                     "off: the host/Object-Store tiers hold KV blocks and no "
-                    + ("recurrent state" if cfg.recurrent else "ring of the window layers")
+                    + ("recurrent state" + (" nor pooled keys" if cfg.is_sala else "")
+                       if cfg.recurrent else "ring of the window layers")
                     + " (KV_HOST_POOL_BYTES=0 says the same)")
             if (
                 self.kv_host_pool_bytes > 0

@@ -1935,6 +1935,13 @@ class Worker:
                         state_pool["slots_live"], labels=labels)
                 r.gauge("lmstudio_ssm_state_pool_slots_total",
                         state_pool["slots_total"], labels=labels)
+            sparse = getattr(stats, "sparse_counters", None)
+            if sparse is not None and sparse()["tokens_live"]:
+                # block-sparse layers (models/sala.py), over decode steps, live
+                # rows and sparse layers: picked / live is the share of the
+                # keys a row could see that its picked walk read
+                for name, v in sparse().items():
+                    r.counter(f"lmstudio_sparse_{name}_total", v, labels=labels)
             swa = getattr(stats, "window_counters", None)
             if swa is not None and pools.get("window"):
                 # window layers beside full ones (models/swa_moe.py): win /

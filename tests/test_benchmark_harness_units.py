@@ -12,6 +12,7 @@ from benchmark.tests.test_moe_prefill_chunk_ms import (  # noqa: F401
     test_the_chunk_group_reader_divides_whole_launches_only,
 )
 from benchmark.tests.test_reduce_trace import *  # noqa: F401,F403
+from benchmark.tests.test_sala_readers import *  # noqa: F401,F403
 from benchmark.tests.test_ssm_readers import (  # noqa: F401
     test_the_bytes_of_a_step_at_the_published_widths as test_the_bytes_of_a_state_space_step_at_the_published_widths,
     test_the_counters_readers_sum_the_windows_own_bursts,

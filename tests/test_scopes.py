@@ -26,10 +26,10 @@ ROOT = Path(__file__).resolve().parent.parent
 FAMILIES = {"dense": None, "latent": ("mla_moe_mhc", "tiny-mla"),
             "latent_plain": ("mla_moe_plain", "tiny-mla-plain"),
             "state": ("ssm_hybrid", "tiny-ssm"), "window": ("swa_gated_moe", "tiny-swa"),
-            "linear": ("gdn_moe", "tiny-gdn")}
+            "linear": ("gdn_moe", "tiny-gdn"), "lightning": ("sala", "tiny-sala")}
 SEQ, BLOCK, SLOTS, CHUNK, BURST = 64, 16, 2, 32, 2
 SCOPED_FILES = ("models/llama.py", "models/mla_moe.py", "models/ssm_hybrid.py",
-                "models/swa_moe.py", "models/gdn_moe.py", "models/experts.py",
+                "models/swa_moe.py", "models/gdn_moe.py", "models/sala.py", "models/experts.py",
                 "serve/programs.py")
 # what the acceptance counts: the operations that carry a step's time
 HEAVY = re.compile(r"stablehlo\.(dot_general|custom_call|convolution)\b")
@@ -143,6 +143,11 @@ def test_every_product_and_kernel_lies_under_a_scope(family, program):
         assert "seq/window" in seen and "ffn/experts" in seen
     if family == "linear":
         assert {"seq/linear", "seq/attn", "ffn/router", "ffn/experts", "ffn/shared"} <= seen
+    if family == "lightning":
+        assert {"seq/linear", "seq/sparse", "seq/sparse/pool", "ffn/mlp"} <= seen
+        # a decode step always scores and picks; a chunk of 32 into a context
+        # of 64 can never pass the toy's dense length of 96, and holds no selection
+        assert ("seq/sparse/select" in seen) == (program == "decode")
 
 
 def _operations(text: str) -> list[str]:
@@ -163,14 +168,21 @@ def test_a_scope_changes_no_operation(family, program, monkeypatch):
 def test_the_scopes_the_code_opens_are_the_vocabulary():
     words = {w for s in SCOPE_NAMES for w in (s, *s.split("/")[1:])}
 
-    def strings(arg):  # "a" or ("a" if ... else "b")
+    def strings(arg):  # "a", ("a" if ... else "b") or TABLE[key] of the file's own table of literals
         if isinstance(arg, ast.IfExp):
             return strings(arg.body) | strings(arg.orelse)
+        if isinstance(arg, ast.Subscript) and isinstance(arg.value, ast.Name):
+            return tables.get(arg.value.id, set())
         return {arg.value} if isinstance(arg, ast.Constant) else set()
 
     opened = set()
     for rel in SCOPED_FILES:
         tree = ast.parse((ROOT / "nats_llm_studio_tpu" / rel).read_text())
+        tables = {t.id: {v.value for v in node.value.values}
+                  for node in tree.body if isinstance(node, ast.Assign)
+                  and isinstance(node.value, ast.Dict)
+                  and all(isinstance(v, ast.Constant) for v in node.value.values)
+                  for t in node.targets if isinstance(t, ast.Name)}
         for node in ast.walk(tree):
             if not (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)):
                 continue

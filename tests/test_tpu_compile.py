@@ -1193,3 +1193,154 @@ def test_a_chunk_of_the_linear_attention_family_at_the_cells_shapes(
     assert ma.temp_size_in_bytes < 1.5e9, ma.temp_size_in_bytes
     print(f"\nwidth {width}: temp {ma.temp_size_in_bytes / 1e6:.0f} MB, "
           f"alias {ma.alias_size_in_bytes / 1e6:.0f} MB")
+
+
+# -- the lightning / block-sparse family at minicpmsala.longdoc_closed's shapes ---
+
+
+@pytest.fixture(scope="module")
+def sala_cell():
+    """(cfg as the registry makes it on the chip, the served tree's shapes,
+    block tokens, slots, context) of ``benchmark/configs/minicpm-sala.json``."""
+    import json
+    from pathlib import Path
+
+    from benchmark import run
+
+    root = Path(__file__).resolve().parents[1]
+    ref = run.load_module(root / "benchmark/references/sala.py")
+    conf = json.loads((root / "benchmark/configs/minicpm-sala.json").read_text())
+    env = conf["serving"]["env"]
+    seq = int(env["MAX_SEQ_LEN"])
+    cfg = ref.model_config(conf, seq).with_(use_flash_attention=True)
+    return cfg, ref.param_shapes(cfg), int(env["KV_BLOCK_TOKENS"]), int(env["MAX_BATCH_SLOTS"]), seq
+
+
+def _sala_pools(cfg, sharding, t, slots, seq):
+    """The cell's pools: slots x seq / t + 1 blocks of the sparse layers' rows,
+    the slots' pooled keys beside K's and their state beside V's."""
+    from nats_llm_studio_tpu.models import sala
+    from nats_llm_studio_tpu.ops.kvcache import WithState
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    nb = slots * (seq // t) + 1
+    (pooled, seen), (plane,) = sala.state_shapes(cfg, slots)
+    kv = lambda: sds((nb, cfg.n_kv_layers, cfg.n_kv_heads, t, cfg.head_dim), jnp.bfloat16)  # noqa: E731
+    return (WithState(kv(), (sds(pooled, jnp.bfloat16), sds(seen, jnp.int32)), sala.K_AXES),
+            WithState(kv(), (sds(plane, jnp.float32),), sala.V_AXES))
+
+
+def test_lightning_step_at_the_benchmark_cells_shapes(one_chip, no_cache, sala_cell):
+    """One layer's step over the state pool [16, 6, 32, 128, 128] f32 (0.2 GB),
+    the live slots a traced list: Mosaic takes the blocks of heads, and the
+    pool is aliased onto the result."""
+    from nats_llm_studio_tpu.ops import lightning, ssm_scan
+
+    cfg, _, t, slots, seq = sala_cell
+    _, vp = _sala_pools(cfg, one_chip, t, slots, seq)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    h, d = cfg.lin_v_heads, cfg.lin_k_dim
+    live = ssm_scan.LiveSlots(jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+                              jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+                              jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    compiled = _compile(
+        lambda pool, layer, live, decay, q, k, v: lightning.lightning_step(
+            pool, layer, live, decay, q, k, v),
+        vp.st[0], jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip), live,
+        f32(slots, h), f32(slots, h, d), f32(slots, h, d), f32(slots, h, cfg.lin_v_dim))
+    assert "lightning_step" in compiled.as_text()
+
+
+def test_the_picked_walk_at_the_benchmark_cells_shapes(one_chip, no_cache, sala_cell):
+    """The picked walk at 16 slots x 2 kv heads, a table of 128 entries a (slot,
+    kv head) of blocks of 64 tokens: one head's [64, 128] slab a copy, runs of 8."""
+    from nats_llm_studio_tpu.ops.paged_attention import paged_decode_attention_picked
+
+    cfg, _, t, slots, seq = sala_cell
+    kp, vp = _sala_pools(cfg, one_chip, t, slots, seq)
+    sds = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    width = max(cfg.sparse_topk, cfg.sparse_dense_len // cfg.sparse_block)
+    compiled = _compile(
+        lambda q, k, v, e, c, ll, layer: paged_decode_attention_picked(
+            q, k, v, e, c, ll, layer, cfg.attn_scale),
+        sds(jnp.bfloat16, slots, 1, cfg.n_heads, cfg.head_dim), kp.kv, vp.kv,
+        sds(jnp.int32, slots, cfg.n_kv_heads, width), sds(jnp.int32, slots, cfg.n_kv_heads),
+        sds(jnp.int32, slots), sds(jnp.int32))
+    assert "paged_decode_attention_picked" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode_pallas", "decode_pallas_ext"],
+                         ids=["the burst", "the single step"])
+def test_a_decode_launch_of_the_lightning_family_copies_no_pool(
+        one_chip, no_cache, sala_cell, program):
+    """The family's two decode programs as ``serve/programs.py`` builds them,
+    the cut's 8 layers over the cell's pools (16 slots x 28,672 tokens),
+    donated: the state kernel and the picked walk are in the program under
+    their names, the pools are aliased onto the results, and no ``copy`` of the
+    float32 state pool, of the pooled keys or of a KV pool is anywhere in it.
+    (What it does hold, once a launch and outside the steps' loop: the lightning
+    stack's wq, wk and wv transposed, 0.6 GB, because their products' output is
+    cut into heads right away; PERF.md section 7 has it as an open item.)"""
+    cfg, shapes, t, slots, seq = sala_cell
+    kp, vp = _sala_pools(cfg, one_chip, t, slots, seq)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    row = lambda dt, *more: jax.ShapeDtypeStruct(  # noqa: E731
+        (slots,) + more, dt, sharding=one_chip)
+    ints, floats = row(jnp.int32), row(jnp.float32)
+    last = 8 if program == "decode_pallas" else row(jnp.bool_, cfg.vocab_size)
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
+    try:
+        compiled = _gdn_table(cfg, t, seq)[program].lower(
+            jax.tree.map(sds, shapes), ints, kp, vp, row(jnp.int32, seq // t), ints, ints, ints,
+            floats, ints, floats, last).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    for name in ("lightning_step", "paged_decode_attention_picked"):
+        assert name in text, name
+    state, kv, pooled = vp.st[0], kp.kv, kp.st[0]
+    pools = (f"f32[{','.join(map(str, state.shape))}]", f"bf16[{','.join(map(str, kv.shape))}]",
+             f"bf16[{','.join(map(str, pooled.shape))}]")
+    copies = [ln.strip()[:160] for ln in text.splitlines()
+              if (" copy(" in ln or "copy-start(" in ln) and any(p in ln for p in pools)]
+    assert not copies, copies
+    ma = compiled.memory_analysis()
+    state_bytes = int(np.prod(state.shape)) * 4
+    assert ma.alias_size_in_bytes >= state_bytes + 2 * int(np.prod(kv.shape)) * 2
+    assert ma.temp_size_in_bytes < 1e9, ma.temp_size_in_bytes
+    print(f"\n{program}: temp {ma.temp_size_in_bytes / 1e6:.0f} MB, "
+          f"alias {ma.alias_size_in_bytes / 1e6:.0f} MB, args {ma.argument_size_in_bytes / 1e9:.2f} GB")
+
+
+@pytest.mark.parametrize("width", [1, 4], ids=["prefill1", "group_of_4"])
+def test_a_chunk_of_the_lightning_family_at_the_cells_shapes(
+        one_chip, no_cache, sala_cell, width):
+    """A chunk of 256 tokens x ``width`` prompts through the cut model into row
+    caches of 28,672 tokens with their state and pooled keys beside them: the
+    chunked rule, the flash chunk kernel under the dense length and the masked
+    attention past it in one program, and what the program holds beside its
+    donated row caches stays under 2.5 GB."""
+    cfg, shapes, t, _, seq = sala_cell
+    table = _gdn_table(cfg, t, seq)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    from nats_llm_studio_tpu.models import sala
+
+    caches = jax.tree.map(sds, jax.eval_shape(lambda: sala.make_cache(cfg, width, seq)))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        compiled = table["prefill1" if width == 1 else "prefill_chunk_group"].lower(
+            jax.tree.map(sds, shapes), ints(width, CHUNK), *caches, ints(width), ints(width),
+            seq).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    assert "flash" in text and "conditional" in text
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 2.5e9, ma.temp_size_in_bytes
+    print(f"\nwidth {width}: temp {ma.temp_size_in_bytes / 1e6:.0f} MB, "
+          f"alias {ma.alias_size_in_bytes / 1e6:.0f} MB")
