@@ -14,7 +14,14 @@ first of them the same way). PR 49 recorded the four decode digests of
 ``tiny-mla`` and ``tiny-mla-plain`` anew: the latent decode kernel's walk
 changed (``ops/mla_attention.py``: its own run length, the live list, a tail
 that copies live blocks only), and the interpreter lowers the kernel's body
-into the step. Their prefill digests did not move."""
+into the step. Their prefill digests did not move.
+
+PR 52 put the last two families in (``tiny-gdn``, ``tiny-sala``: the parent's
+digests, which its change to the dense family's file did not move) and the
+dense family itself, ``tiny-granite`` and ``tiny-bias`` (q / k / v biases):
+``models/llama.py _qkv_rows`` holds the three products apart from the split
+into heads, so the dense step and chunk gained one ``optimization_barrier``
+a layer and their digests are PR 52's (the parent's are in ``CHANGES.md``)."""
 import hashlib
 import json
 import re
@@ -30,8 +37,8 @@ from nats_llm_studio_tpu.ops.kvcache import WithState
 
 ROOT = Path(__file__).resolve().parents[1]
 T, SEQ, B = 16, 128, 2
-REFERENCE = {"tiny-ssm": "ssm_hybrid", "tiny-swa": "swa_gated_moe",
-             "tiny-mla-plain": "mla_moe_plain", "tiny-mla": "mla_moe_mhc"}
+# the toys' references by name: the benchmark's own, then the rehearsal's
+REHEARSAL = run.Manifest(ROOT / "benchmark/tests/rehearsal/manifest.json")
 LOWERED_SHA = {
     ("tiny-ssm", "decode"): "1319c28ec4b315cd599c857829fd3d1347ca5fee94174f711fef83e05eb63d99",
     ("tiny-ssm", "prefill"): "dbdb7b5b22920b406eabb79a1e9db38bbf9d40d196d0cd7d7718b7387d9f763a",
@@ -44,6 +51,15 @@ LOWERED_SHA = {
     ("tiny-mla", "decode"): "9149ebb86ea6b54e1325400ba4802369f6a72bea8b8d30c5f4b757c4f2996082",
     ("tiny-mla", "decode_counted"): "9147ef682c95e5fecb95ad433915bfeba2a0f3380597d878c50c6f2a0685bba5",
     ("tiny-mla", "prefill"): "6c33a1196acfc7ee8ffbea0d7919a0a7d80bb61eca02ac3d9b196deb8fa0a876",
+    ("tiny-gdn", "decode"): "656aa831da43c3e695c8f31a862f123b134ff72981bb1afe5b755b6161d5db0c",
+    ("tiny-gdn", "decode_counted"): "b2983607e1b53c43ee7e25a3c26e406b3863a398605d1703dd606ecacbb06789",
+    ("tiny-gdn", "prefill"): "220c1cbba1892b16668bf2f5a4d24adf009723a618f80df307203854913ff653",
+    ("tiny-sala", "decode"): "623f40cdc1ce6eb86c131782e7d9214409841a04388cb734dbc3ad4eee06ddea",
+    ("tiny-sala", "prefill"): "f146f6fa3e995900ee5641ce71b0e48ec4ab45f73bd18e8ec1f419ce03ff59aa",
+    ("tiny-granite", "decode"): "4d3a0abc45bc4b9d95e5d2ca7eaba9822dacf8f5f632a44883d9e2a154649e17",
+    ("tiny-granite", "prefill"): "4fad5d90689e3b2428d669cd5fbfc29370f0a76ea11f1cde122fccf5907a83d8",
+    ("tiny-bias", "decode"): "fbf069912e6e976a88066bf91e754088b5849234f493369ab3f97df205094285",
+    ("tiny-bias", "prefill"): "68c6e487c621d9c3a7cdc731ad36f0f4be66871daf0b5ae3b6e4b154566a53a7",
 }
 
 
@@ -58,7 +74,7 @@ def _ints(*shape):
 
 def _lowered(toy: str, program: str) -> str:
     conf = json.loads((ROOT / f"benchmark/tests/rehearsal/configs/{toy}.json").read_text())
-    ref = run.load_module(ROOT / f"benchmark/references/{REFERENCE[toy]}.py")
+    ref = run.load_module(REHEARSAL.find("references", conf["reference"], (".py",)))
     cfg = ref.model_config(conf, SEQ).with_(dtype="float32")
     params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
     if program == "prefill":

@@ -153,6 +153,43 @@ def test_prefill_decode_consistency(tiny):
         np.testing.assert_allclose(logits[0, 0], ref_logits[0, t], rtol=0.02, atol=5e-3)
 
 
+@pytest.mark.parametrize("bias", [False, True], ids=["plain", "qkv_biases"])
+@pytest.mark.parametrize("program", ["prefill", "decode", "paged_step", "paged_verify"])
+def test_holding_the_qkv_products_apart_is_the_identity_in_value(monkeypatch, program, bias):
+    """``_qkv_rows`` keeps the split into heads out of the q / k / v products
+    with an ``optimization_barrier``; the programs' logits and caches are,
+    to the last bit in float32, those of the same programs without it (the
+    parent of PR 52's), through ``forward`` and ``forward_decode_paged``."""
+    from nats_llm_studio_tpu.models.llama import forward_decode_paged
+
+    cfg = ModelConfig.tiny(arch="qwen2", attn_bias=True) if bias else ModelConfig.tiny()
+    params = init_params(cfg, jax.random.PRNGKey(5))
+    rng = np.random.default_rng(0)
+    noise = lambda shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)  # noqa: E731
+    if program in ("prefill", "decode"):
+        t, pos = (8, [0, 0]) if program == "prefill" else (1, [5, 9])
+        fn = forward
+        args = [noise(c.shape) for c in make_cache(cfg, 2, 32)] + [jnp.array(pos, jnp.int32)]
+    else:
+        t = 1 if program == "paged_step" else 3
+        fn = forward_decode_paged
+        pool = (9, cfg.n_layers, cfg.n_kv_heads, 16, cfg.head_dim)
+        args = [noise(pool), noise(pool), jnp.array([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32),
+                jnp.array([20, 37], jnp.int32)]
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, t)), jnp.int32)
+
+    def run():  # a new function a call: traced anew
+        return jax.jit(lambda p, tok, *rest: fn(p, cfg, tok, *rest))(params, tokens, *args)
+
+    held = run()
+    passed = []
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: passed.append(x) or x)
+    plain = run()
+    assert len(passed) == 1  # one a traced layer: the scan's body
+    for a, b in zip(held, plain):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_right_padded_batch_matches_unpadded(tiny):
     """Right-padded rows must produce identical logits at real positions."""
     cfg, params = tiny

@@ -65,9 +65,14 @@ def tp4(topo):
 def no_cache():
     """A compile for a described device is written to the persistent cache
     but cannot be read back without a chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # whether the cache is used is decided once a process: ask again, both ways
     jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
     yield
     jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
 
 
 def _compile(fn, *args):
@@ -437,37 +442,52 @@ ENTRY %main (a: bf16[4,8]) -> bf16[4,8] {
         "%body: %copy.7", "%body: %fusion.3", "%main: %copy-start"], found
 
 
+def _served_tree(shapes: dict, wquant: str, prefix: str = "") -> dict:
+    """``program_param_shapes``' tree as the loader would rest it under
+    WQUANT ``wquant``: int8 codes with a scale a column, packed int4 codes with
+    a scale and a zero point a group of 128 rows, or ("none") as it is."""
+    from nats_llm_studio_tpu.ops.wquant import QTensor, QTensor4, quantizable
+
+    out = {}
+    for k, v in shapes.items():
+        if isinstance(v, dict):
+            out[k] = _served_tree(v, wquant, f"{prefix}{k}.")
+            continue
+        if wquant == "none" or not quantizable(prefix + k):
+            out[k] = v
+            continue
+        lead, (rows, cols) = v.shape[:-2], v.shape[-2:]
+        sds = lambda dt, *tail: jax.ShapeDtypeStruct(lead + tail, dt)  # noqa: E731
+        if wquant == "int8":
+            out[k] = QTensor(q=sds(jnp.int8, rows, cols), s=sds(jnp.float32, 1, cols))
+        else:
+            out[k] = QTensor4(q=sds(jnp.uint8, rows // 2, cols), s=sds(jnp.float32, rows // 128, cols),
+                              z=sds(jnp.float32, rows // 128, cols), group=128)
+    return out
+
+
 @pytest.fixture(scope="module")
-def dense_cell():
-    """(cfg, the served tree's shapes) of ``benchmark/configs/granite-3.1-8b.json``
-    as both Granite-8B cells serve it: int8 weights (WQUANT=int8), MAX_SEQ_LEN
-    2048, the flash kernels on."""
+def dense_cfg():
+    """``benchmark/configs/granite-3.1-8b.json`` as both Granite-8B cells serve
+    it: MAX_SEQ_LEN 2048, the flash kernels on."""
     import json
     from pathlib import Path
 
     from benchmark import run
-    from benchmark.lib.weights import program_param_shapes
-    from nats_llm_studio_tpu.ops.wquant import QTensor, quantizable
 
     root = Path(__file__).resolve().parents[1]
     ref = run.load_module(root / "benchmark/references/granite_dense.py")
     conf = json.loads((root / "benchmark/configs/granite-3.1-8b.json").read_text())
-    cfg = ref.model_config(conf, 2048).with_(use_flash_attention=True)
+    return ref.model_config(conf, 2048).with_(use_flash_attention=True)
 
-    def served(node, prefix=""):
-        out = {}
-        for k, v in node.items():
-            if isinstance(v, dict):
-                out[k] = served(v, f"{prefix}{k}.")
-            elif quantizable(prefix + k):
-                out[k] = QTensor(q=jax.ShapeDtypeStruct(v.shape, jnp.int8),
-                                 s=jax.ShapeDtypeStruct(v.shape[:-2] + (1, v.shape[-1]),
-                                                        jnp.float32))
-            else:
-                out[k] = v
-        return out
 
-    return cfg, served(program_param_shapes(cfg))
+@pytest.fixture(scope="module")
+def dense_cell(dense_cfg):
+    """(cfg, the served tree's shapes) of the Granite-8B cells: int8 weights
+    (WQUANT=int8)."""
+    from benchmark.lib.weights import program_param_shapes
+
+    return dense_cfg, _served_tree(program_param_shapes(dense_cfg), "int8")
 
 
 @pytest.mark.parametrize("width,window,kv", [
@@ -520,6 +540,97 @@ def test_a_prefill_chunk_of_the_dense_family_copies_no_row_cache(one_chip, no_ca
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= pair           # the donated pair, no entry copy
     assert ma.temp_size_in_bytes < pair // 2 // 8   # and no second row cache among the temporaries
+
+
+def _unfused_results(text: str, *shapes: str) -> list[str]:
+    """The device operations of a compiled program, outside its fused
+    computations, whose result is one of ``shapes`` ("s8[1,4096,1024]")."""
+    return [ln[:200] for ops in _scoped_operations(text, "").values() for _, ln in ops
+            if ln.split(" = ", 1)[1].startswith(shapes)]
+
+
+@pytest.mark.parametrize("program,wquant", [
+    ("decode_pallas", "int8"), ("spec_verify_pallas", "int8"), ("prefill1", "int8"),
+    ("prefill_chunk_group", "int8"), ("admit_fused_paged", "int8"),
+    ("decode_pallas", "none"), ("decode_pallas", "int4"),
+], ids=["burst", "spec_verify", "prefill1", "chunk_group_of_4", "admit_fused_paged",
+        "burst-bf16-of-16-layers-reads-its-slices-at-rest-too",
+        "burst-int4-unpacks-a-bf16-slice-a-layer-as-the-parent-did"])
+def test_the_dense_familys_qkv_products_read_their_slice_out_of_the_stack_at_rest(
+        one_chip, no_cache, dense_cfg, program, wquant):
+    """The Granite-8B cells' programs as ``serve/programs.py`` builds them (40
+    layers, 8 slots x 2,048, block 16; the burst is 8 steps, the verify 7 wide,
+    the chunks 256 tokens over a window of 1,024, the admit a bucket of 256):
+    ``wq`` / ``wk`` / ``wv`` are read where they rest, by the product itself,
+    as the MLP's stacks and ``wo`` are. ``llama._qkv_rows`` keeps the split into
+    heads out of the three products; with it folded in (the parent of PR 52)
+    the compiler bitcast each layer's slice to [4096, heads, 128], could then
+    no longer fuse the slice as the product's operand, and wanted the
+    contraction axis minor. So the burst began with ``%copy.86``
+    ``s8[40,4096,4096]{1,2,0}`` and ``.85`` / ``.87`` ``s8[40,4096,1024]{1,2,0}``
+    (1.0 GB: its ``temp_size_in_bytes`` was 1,015,474,176, or 1,016,927,744 as a
+    bare scan of 8 steps), every layer of every step ran
+    ``%constant_dynamic-slice_fusion.6/.7/.8`` over those copies, and the one-row
+    programs (``prefill1``, the admit, the one-step burst) a slice fusion and a
+    ``{1,2,0}`` copy a stack a layer. None of that is left: no copy of a whole
+    stack, no operation outside a fusion that yields one layer's slice of one,
+    and a burst whose temporaries are under 64 MB. (The parent's verify and
+    group of 4, with more rows a product, had neither; they are held too.)
+
+    The same burst over the other trees the worker rests, ids as found: bf16
+    stacks (16 of the 40 layers, what fits 16 GB) compiled to the parent's
+    form and now to this one, 808,554,496 bytes of temporaries to 3 MB; packed
+    int4 stacks never took the parent's form (``_mm4`` unpacks each layer's
+    slice into bf16 [2048, 2, cols] and copies it, on both sides of this
+    change: what is held is that the stacks themselves are copied nowhere and
+    that the unpacked slices are as many as they were)."""
+    from benchmark.lib.weights import program_param_shapes
+    from nats_llm_studio_tpu.engine.sampling import sample_rows
+    from nats_llm_studio_tpu.serve.programs import build_programs
+
+    cfg = dense_cfg.with_(n_layers=16) if wquant == "none" else dense_cfg
+    seq, layers, d, kv_cols = cfg.max_seq_len, cfg.n_layers, cfg.d_model, cfg.n_kv_heads * cfg.head_dim
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(sds, _served_tree(program_param_shapes(cfg), wquant))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    floats = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)  # noqa: E731
+    pool = jax.ShapeDtypeStruct((SLOTS * (seq // T) + 64 + 1, layers, cfg.n_kv_heads, T, cfg.head_dim),
+                                jnp.bfloat16, sharding=one_chip)
+    rows = lambda w: jax.ShapeDtypeStruct(  # noqa: E731
+        (w, layers, cfg.n_kv_heads, seq, cfg.head_dim), jnp.bfloat16, sharding=one_chip)
+    slot = (ints(SLOTS), ints(SLOTS), floats(SLOTS), ints(SLOTS), floats(SLOTS))  # seeds ... topp
+    args = {
+        "decode_pallas": (params, ints(SLOTS), pool, pool, ints(SLOTS, seq // T), ints(SLOTS), *slot, 8),
+        "spec_verify_pallas": (params, ints(SLOTS), pool, pool, ints(SLOTS, seq // T), ints(SLOTS),
+                               ints(SLOTS, SPEC_W - 1), ints(SLOTS), *slot),
+        "prefill1": (params, ints(1, CHUNK), rows(1), rows(1), ints(1), ints(1), 4 * CHUNK),
+        "prefill_chunk_group": (params, ints(4, CHUNK), rows(4), rows(4), ints(4), ints(4), 4 * CHUNK),
+        "admit_fused_paged": (params, pool, pool, ints(SLOTS), ints(1, CHUNK), ints(), ints(CHUNK // T),
+                              ints(), ints(), floats(), ints(), floats()),
+    }[program]
+    table = build_programs(cfg, None, max_seq=seq, paged=True, kv_block_tokens=T,
+                           sample_rows=sample_rows)
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
+    try:
+        compiled = table[program].lower(*args).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    codes = {"int8": "s8", "none": "bf16", "int4": "u8"}[wquant]
+    held_rows = d // 2 if wquant == "int4" else d   # two codes a byte
+    for cols in (d, kv_cols):
+        copies = _whole_array_copies(text, f"{codes}[{layers},{held_rows},{cols}]")
+        assert not copies, copies
+    if wquant == "int4":
+        unpacked = _unfused_results(text, f"bf16[{d // 2},2,{kv_cols}]")
+        assert len(unpacked) == 4, unpacked   # wk and wv: the unpacking fusion and its copy
+        return
+    sliced = _unfused_results(text, f"{codes}[1,{d},{d}]", f"{codes}[1,{d},{kv_cols}]")
+    assert not sliced, sliced
+    if program == "decode_pallas":
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 # -- the state-space / attention hybrid family at granite4hmicro.chat32_closed's shapes --
