@@ -63,7 +63,7 @@ from ..ops.flash_attention import (
 )
 from ..ops.kvcache import WithState, kv_pool_write_rows, kv_update_slice, table_rows_in_use
 from ..ops.layers import gqa_attention, gqa_attention_hmajor, rms_norm, rope_cos_sin
-from ..ops.wquant import mm
+from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
 from .experts import EXPERT_LEAVES, expert_path, moe_ffn, stats_width
 from .ssm_hybrid import V_AXES, _embed, _layers, state_bytes
@@ -227,11 +227,11 @@ def _qkvg(h, p: Params, cfg: ModelConfig, table):
     [B,T,H x D] f32 (None without ``attn_out_gate``)."""
     b, t, _ = h.shape
     hd = cfg.n_heads * cfg.head_dim
-    qg = mm(h, p["wq"])
+    qg, k, v = flat_rows(mm(h, p["wq"]), mm(h, p["wk"]), mm(h, p["wv"]))
     q = qg[..., :hd].reshape(b, t, cfg.n_heads, cfg.head_dim)
     gate = jax.nn.sigmoid(qg[..., hd:].astype(jnp.float32)) if cfg.attn_out_gate else None
-    k = mm(h, p["wk"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    v = mm(h, p["wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     if cfg.qk_norm:
         q, k = rms_norm(q, p["q_norm"], cfg.rms_eps), rms_norm(k, p["k_norm"], cfg.rms_eps)
     return _rotate(q, table), _rotate(k, table), v, gate

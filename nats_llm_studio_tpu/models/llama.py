@@ -39,7 +39,7 @@ from ..ops.layers import (
     rope_cos_sin,
     swiglu,
 )
-from ..ops.wquant import mm, q_einsum
+from ..ops.wquant import flat_rows, mm, q_einsum
 from .config import ModelConfig
 
 Params = dict[str, Any]
@@ -66,22 +66,7 @@ def family_module(cfg: ModelConfig):
 
 def _qkv_rows(x: jax.Array, p: Params, cfg: ModelConfig) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The layer's q / k / v products as flat rows [B, T, H * D], biases in,
-    held apart from the split into heads that follows them.
-
-    Left to itself XLA:TPU folds ``q.reshape(b, t, hq, d)`` into the product
-    (``%bitcast_multiply_fusion = bf16[8,1,32,128]{3,0,2,1}`` in the compiled
-    burst): the weight operand becomes a bitcast of the layer's slice to
-    [d_model, heads, head_dim], which cannot be fused as the slice of the stack
-    that the MLP's and ``wo``'s products read in place, and that form wants
-    the contraction axis minor. At Granite-8B's widths a decode burst then
-    began by copying the whole ``wq`` / ``wk`` / ``wv`` stacks to ``{1,2,0}``
-    (1.0 GB of temporaries, int8) and every layer of every step copied its
-    slice of those copies, 2.04 ms of a 14.3 ms step; the one-row prefill and
-    admit programs held a slice and a relayout a stack a layer (PERF.md
-    section 6, PR 52; tests/test_tpu_compile.py reads the compiled text). The
-    barrier costs nothing and changes no value: behind it the products are
-    plain [rows, d_model] x [d_model, cols], each streaming its slice from
-    the stack at rest, bf16 and int8 alike (packed int4 never took that form)."""
+    held apart from the split into heads that follows them (``flat_rows``)."""
     q = mm(x, p["wq"])
     k = mm(x, p["wk"])
     v = mm(x, p["wv"])
@@ -89,7 +74,7 @@ def _qkv_rows(x: jax.Array, p: Params, cfg: ModelConfig) -> tuple[jax.Array, jax
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    return jax.lax.optimization_barrier((q, k, v))
+    return flat_rows(q, k, v)
 
 
 def _attention_block(

@@ -66,7 +66,7 @@ import numpy as np
 from ..ops import sinkhorn
 from ..ops.kvcache import kv_pool_write_rows, kv_update_slice
 from ..ops.layers import apply_rope, rms_norm, swiglu, yarn_frequencies
-from ..ops.wquant import mm
+from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
 from .experts import EXPERT_LEAVES, expert_path, moe_ffn
 
@@ -205,7 +205,7 @@ def mla_project(h: jax.Array, p: Params, cfg: ModelConfig, cos, sin):
         q = mm(rms_norm(mm(h, p["w_dq"]), p["q_norm"], cfg.rms_eps), p["w_uq"])
     else:
         q = mm(h, p["wq"])
-    q = q.reshape(b, t, cfg.n_heads, dn + dr)
+    q = flat_rows(q).reshape(b, t, cfg.n_heads, dn + dr)
     q_rope = apply_rope(q[..., dn:], cos, sin)
     ckr = mm(h, p["w_dkv"])
     c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.rms_eps)

@@ -71,7 +71,7 @@ from ..ops.flash_attention import (
 )
 from ..ops.kvcache import WithState, kv_pool_write_rows, kv_update_slice, table_rows_in_use
 from ..ops.layers import apply_rope, gqa_attention, gqa_attention_hmajor, rms_norm, rope_cos_sin
-from ..ops.wquant import mm
+from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
 from .gdn_moe import _attn_out
 from .ssm_hybrid import K_AXES, V_AXES, _embed, _layers, state_bytes, zeroed_state
@@ -143,8 +143,8 @@ def _lightning_in(h, p: Params, cfg: ModelConfig, positions):
     gate's input [.., H d]) of positions ``positions`` [B, T]."""
     b, t, _ = h.shape
     hd = (b, t, cfg.lin_v_heads, cfg.lin_k_dim)
-    q, k = mm(h, p["wq"]).reshape(hd), mm(h, p["wk"]).reshape(hd)
-    v = mm(h, p["wv"]).reshape(b, t, cfg.lin_v_heads, cfg.lin_v_dim)
+    q, k, v = flat_rows(mm(h, p["wq"]), mm(h, p["wk"]), mm(h, p["wv"]))
+    q, k, v = q.reshape(hd), k.reshape(hd), v.reshape(b, t, cfg.lin_v_heads, cfg.lin_v_dim)
     cos, sin = rope_cos_sin(positions, cfg.lin_k_dim, cfg.rope_theta)
     f32 = jnp.float32
     q = apply_rope(rms_norm(q.astype(f32), p["q_norm"].astype(f32), cfg.rms_eps), cos, sin)
@@ -197,11 +197,11 @@ def _qkvg(h, p: Params, cfg: ModelConfig):
     the gate [B,T,H x D] f32."""
     b, t, _ = h.shape
     hd = cfg.n_heads * cfg.head_dim
-    qg = mm(h, p["wq"])
+    qg, k, v = flat_rows(mm(h, p["wq"]), mm(h, p["wk"]), mm(h, p["wv"]))
     q = qg[..., :hd].reshape(b, t, cfg.n_heads, cfg.head_dim)
     gate = jax.nn.sigmoid(qg[..., hd:].astype(jnp.float32))
-    k = mm(h, p["wk"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    v = mm(h, p["wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     return rms_norm(q, p["q_norm"], cfg.rms_eps), rms_norm(k, p["k_norm"], cfg.rms_eps), v, gate
 
 

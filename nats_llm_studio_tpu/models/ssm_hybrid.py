@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from ..ops import ssm_scan
 from ..ops.kvcache import WithState, kv_pool_write_rows, kv_update_slice, table_rows_in_use
 from ..ops.layers import apply_rope, gqa_attention_hmajor, rms_norm, rope_cos_sin, swiglu
-from ..ops.wquant import mm
+from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
 
 Params = dict[str, Any]
@@ -209,9 +209,10 @@ def mamba_step(h, p: Params, cfg: ModelConfig, tails, states, layer, live, fresh
 
 def _qkv(h, p: Params, cfg: ModelConfig, positions):
     b, t, _ = h.shape
-    q = mm(h, p["wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
-    k = mm(h, p["wk"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    v = mm(h, p["wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    q, k, v = flat_rows(mm(h, p["wq"]), mm(h, p["wk"]), mm(h, p["wv"]))
+    q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
+    k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     if cfg.use_rope:
         cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)

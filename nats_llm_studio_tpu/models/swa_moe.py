@@ -68,7 +68,7 @@ from ..ops.layers import (
     swiglu,
     yarn_frequencies,
 )
-from ..ops.wquant import mm
+from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
 from .experts import EXPERT_LEAVES, expert_path, moe_ffn
 
@@ -185,9 +185,10 @@ def _qkvg(h, p: Params, cfg: ModelConfig, kind: str, tables):
     (None without ``attn_gate``); H is the kind's head count."""
     b, t, _ = h.shape
     heads = cfg.win_n_heads if kind == "window" else cfg.n_heads
-    q = mm(h, p["wq"]).reshape(b, t, heads, cfg.head_dim)
-    k = mm(h, p["wk"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    v = mm(h, p["wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    q, k, v = flat_rows(mm(h, p["wq"]), mm(h, p["wk"]), mm(h, p["wv"]))
+    q = q.reshape(b, t, heads, cfg.head_dim)
+    k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     gate = jax.nn.sigmoid(mm(h, p["wg"]).astype(jnp.float32)) if cfg.attn_gate else None
     return _rotate(q, tables[kind]), _rotate(k, tables[kind]), v, gate
 

@@ -184,6 +184,34 @@ def mm(x: jax.Array, w) -> jax.Array:
     return x @ w
 
 
+def flat_rows(*rows: jax.Array):
+    """Products of ``mm`` held as the flat rows [B, T, H * D] they are, apart
+    from the cut into heads that follows them: ``q, k, v = flat_rows(mm(h,
+    p["wq"]), ...)`` and only then ``q.reshape(b, t, heads, d)``. One array
+    in, that array out; several, their tuple.
+
+    Left to itself XLA:TPU folds the reshape into the product
+    (``%bitcast_multiply_fusion = bf16[8,1,32,128]{3,0,2,1}`` in a compiled
+    burst): the weight operand becomes a bitcast of the layer's slice to
+    [d_model, heads, head_dim], which cannot be fused as the slice of the stack
+    that the MLP's and ``wo``'s products read in place, and that form wants
+    the contraction axis minor. A decode burst then begins by copying the
+    whole ``wq`` / ``wk`` / ``wv`` stacks to ``{1,2,0}`` and every layer of
+    every step copies its slice of those copies
+    (``%constant_dynamic-slice_fusion``), and the one-row prefill and admit
+    programs hold a slice and a relayout a stack a layer: 2.04 ms of Granite-8B's
+    14.3 ms step (PERF.md section 6, PR 52), three copies of 33.5 MB a Lightning
+    layer and 0.6 GB of temporaries a launch in MiniCPM-SALA (PR 53);
+    tests/test_tpu_compile.py reads the compiled text of every family. The
+    barrier costs nothing and changes no value: behind it the products are
+    plain [rows, d_model] x [d_model, cols], each streaming its slice from
+    the stack at rest, bf16 and int8 alike (packed int4 never took that
+    form). It cures a reshape of a product's OUTPUT only: a weight that is
+    itself cut into heads (``mla_moe._w_ukv``) is still copied a layer."""
+    held = jax.lax.optimization_barrier(rows)
+    return held[0] if len(rows) == 1 else held
+
+
 def q_einsum(spec: str, x: jax.Array, w) -> jax.Array:
     """``einsum(spec, x, w)`` with QTensor/QTensor4 support.
 

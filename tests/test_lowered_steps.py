@@ -21,7 +21,16 @@ digests, which its change to the dense family's file did not move) and the
 dense family itself, ``tiny-granite`` and ``tiny-bias`` (q / k / v biases):
 ``models/llama.py _qkv_rows`` holds the three products apart from the split
 into heads, so the dense step and chunk gained one ``optimization_barrier``
-a layer and their digests are PR 52's (the parent's are in ``CHANGES.md``)."""
+a layer and their digests are PR 52's (the parent's are in ``CHANGES.md``).
+
+PR 53 wrote that barrier once (``ops/wquant.py flat_rows``) and put it between
+the flat q / k / v products and the cut into heads of every other family:
+the sixteen digests of ``tiny-ssm``, ``tiny-swa``, ``tiny-mla-plain``,
+``tiny-mla``, ``tiny-gdn`` and ``tiny-sala`` are PR 53's (each program gained
+one ``optimization_barrier`` a kind of layer that projects q / k / v; the
+parent's are in ``CHANGES.md``), the four of the dense family did not move,
+and ``test_holding_the_products_apart_changes_no_value`` runs every moved
+program with the barrier and without it."""
 import hashlib
 import json
 import re
@@ -29,6 +38,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from benchmark import run
@@ -40,22 +50,22 @@ T, SEQ, B = 16, 128, 2
 # the toys' references by name: the benchmark's own, then the rehearsal's
 REHEARSAL = run.Manifest(ROOT / "benchmark/tests/rehearsal/manifest.json")
 LOWERED_SHA = {
-    ("tiny-ssm", "decode"): "1319c28ec4b315cd599c857829fd3d1347ca5fee94174f711fef83e05eb63d99",
-    ("tiny-ssm", "prefill"): "dbdb7b5b22920b406eabb79a1e9db38bbf9d40d196d0cd7d7718b7387d9f763a",
-    ("tiny-swa", "decode"): "545103ca9ea56b7e38c69146209d316d4dc6242520fd8733966d676aefe4759c",
-    ("tiny-swa", "decode_counted"): "caabf44f6d5504437f784bd32e2a93b5634aa0234fe4c05900dcb4bb2a6aa7f3",
-    ("tiny-swa", "prefill"): "e43b687298415726f978fdab68b742b5aa2685e69ad467d8c0c1ad5c02b9f7cc",
-    ("tiny-mla-plain", "decode"): "54c9c74669ea7d89983ecef38ee75159e0e08e338abed56179a0e77b1bbdf034",
-    ("tiny-mla-plain", "decode_counted"): "d9db576855330906ca3878524a3348ddaad25ef992f237f45c308b2bae2ab74f",
-    ("tiny-mla-plain", "prefill"): "2a83d2fd7d0f477154a85b0a3095a703d8e832a57ae9b1f8f9d806354b2adb09",
-    ("tiny-mla", "decode"): "9149ebb86ea6b54e1325400ba4802369f6a72bea8b8d30c5f4b757c4f2996082",
-    ("tiny-mla", "decode_counted"): "9147ef682c95e5fecb95ad433915bfeba2a0f3380597d878c50c6f2a0685bba5",
-    ("tiny-mla", "prefill"): "6c33a1196acfc7ee8ffbea0d7919a0a7d80bb61eca02ac3d9b196deb8fa0a876",
-    ("tiny-gdn", "decode"): "656aa831da43c3e695c8f31a862f123b134ff72981bb1afe5b755b6161d5db0c",
-    ("tiny-gdn", "decode_counted"): "b2983607e1b53c43ee7e25a3c26e406b3863a398605d1703dd606ecacbb06789",
-    ("tiny-gdn", "prefill"): "220c1cbba1892b16668bf2f5a4d24adf009723a618f80df307203854913ff653",
-    ("tiny-sala", "decode"): "623f40cdc1ce6eb86c131782e7d9214409841a04388cb734dbc3ad4eee06ddea",
-    ("tiny-sala", "prefill"): "f146f6fa3e995900ee5641ce71b0e48ec4ab45f73bd18e8ec1f419ce03ff59aa",
+    ("tiny-ssm", "decode"): "a219fe75757fadf74eb84dbb1ccd54cca9a798e2034aa028c3e72a4480405331",
+    ("tiny-ssm", "prefill"): "14bb40291119d010c0f5dab02058109cf4532f8a218951542967a7217a996031",
+    ("tiny-swa", "decode"): "693c8e351f5caa379d41cf9372395060b02666c04527753014801572318b4aa8",
+    ("tiny-swa", "decode_counted"): "15a440d80007c6fd1a517cd524438c85d27a8a5988e52367394cd13de3fe0635",
+    ("tiny-swa", "prefill"): "e6636ec1d835c54ed1e556bd37a1ae85cf89f0503ec82d6313a5a6ad2e1cacab",
+    ("tiny-mla-plain", "decode"): "96ee0ca16c46204fad63a1fbfe3b7f459304d67a6f535b61c7a3b2cadb4f5a91",
+    ("tiny-mla-plain", "decode_counted"): "e2b599439899c46961297813a2502ed3278f070fd0077311299ddcd122bf436a",
+    ("tiny-mla-plain", "prefill"): "e7a33dbddb7926498d1079ee72fc0ba7765c4f8d9e82e46549472f51c65fc783",
+    ("tiny-mla", "decode"): "8b1713151898696af571536a0790e71dcbc88d6c27c5b1edd32729d6b6d71d2b",
+    ("tiny-mla", "decode_counted"): "b850c6396a49d51510166e6343c54b7eb7317422d77c5afcc8454785f3158fca",
+    ("tiny-mla", "prefill"): "374776daf2483687dda6a92831f62d86a52edb8ef63af19c7fe70b728b3a6daa",
+    ("tiny-gdn", "decode"): "4311d3242d157f2dfc781f3bc38f71091d25489e96ab3e96e4ea48ad05bdfe1e",
+    ("tiny-gdn", "decode_counted"): "b1fe07b14d238b80cba7e787fff0c51ac7eaa044c6881634da153e15a45abb61",
+    ("tiny-gdn", "prefill"): "4051ff8cbfb3a1be423346caaad8bd74f3089c781806f067868115f3583ce601",
+    ("tiny-sala", "decode"): "fbcb55dc371bdbf6422c04f608feaa95b92715fe4f39692465244288688aeb83",
+    ("tiny-sala", "prefill"): "f80b9b2ca2eeef6e0625c11d429679520cc4d21b9537924828919f2c47f47d61",
     ("tiny-granite", "decode"): "4d3a0abc45bc4b9d95e5d2ca7eaba9822dacf8f5f632a44883d9e2a154649e17",
     ("tiny-granite", "prefill"): "4fad5d90689e3b2428d669cd5fbfc29370f0a76ea11f1cde122fccf5907a83d8",
     ("tiny-bias", "decode"): "fbf069912e6e976a88066bf91e754088b5849234f493369ab3f97df205094285",
@@ -72,27 +82,72 @@ def _ints(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.int32)
 
 
-def _lowered(toy: str, program: str) -> str:
+def _program(toy: str, program: str):
+    """(cfg, the function of (params, *arguments), the arguments' shapes) of
+    a toy's two-row prefill chunk or paged decode step in float32."""
     conf = json.loads((ROOT / f"benchmark/tests/rehearsal/configs/{toy}.json").read_text())
     ref = run.load_module(REHEARSAL.find("references", conf["reference"], (".py",)))
     cfg = ref.model_config(conf, SEQ).with_(dtype="float32")
-    params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
     if program == "prefill":
         caches = jax.eval_shape(lambda: llama.make_cache(cfg, 2, SEQ))
-        return jax.jit(lambda p, tok, kc, vc, pos, lp: llama.forward(
-            p, cfg, tok, kc, vc, pos, logit_positions=lp)).lower(
-                params, _ints(2, 32), *caches, _ints(2), _ints(2)).as_text()
+        return cfg, (lambda p, tok, kc, vc, pos, lp: llama.forward(
+            p, cfg, tok, kc, vc, pos, logit_positions=lp)), (_ints(2, 32), *caches, _ints(2), _ints(2))
     pools = [jax.ShapeDtypeStruct((9, cfg.n_kv_layers, h, T, w), jnp.float32)
              for h, w in cfg.kv_cache_dims()]
     if cfg.slot_state:
         state = jax.eval_shape(lambda: llama.family_module(cfg).make_state(cfg, B))
         pools = [WithState(p, s, axes) for p, (s, axes) in zip(pools, state)]
-    return jax.jit(lambda p, tok, kp, vp, tbl, pos: llama.forward_decode_paged(
-        p, cfg, tok, kp, vp, tbl, pos, moe_stats=program == "decode_counted")).lower(
-            params, _ints(B, 1), *pools, _ints(B, SEQ // T), _ints(B)).as_text()
+    return cfg, (lambda p, tok, kp, vp, tbl, pos: llama.forward_decode_paged(
+        p, cfg, tok, kp, vp, tbl, pos, moe_stats=program == "decode_counted")), (
+            _ints(B, 1), *pools, _ints(B, SEQ // T), _ints(B))
+
+
+def _lowered(toy: str, program: str) -> str:
+    cfg, fn, args = _program(toy, program)
+    params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    return jax.jit(fn).lower(params, *args).as_text()
 
 
 @pytest.mark.parametrize("toy,program", list(LOWERED_SHA), ids=lambda v: v)
 def test_an_earlier_familys_lowered_program_is_what_it_was(toy, program):
     ops = _operations(_lowered(toy, program))
     assert hashlib.sha256("\n".join(ops).encode()).hexdigest() == LOWERED_SHA[toy, program]
+
+
+@pytest.mark.parametrize("toy,program", [
+    (toy, program) for toy, program in LOWERED_SHA
+    if toy not in ("tiny-granite", "tiny-bias") and program != "decode_counted"], ids=lambda v: v)
+def test_holding_the_products_apart_changes_no_value(monkeypatch, toy, program):
+    """``flat_rows`` is an ``optimization_barrier`` and nothing else: each
+    family's chunk and decode step over drawn weights, tokens and pools give,
+    to the last bit in float32, the logits, caches and states of the same
+    program traced with the barrier taken out (the parent of PR 53's products;
+    ``tests/test_models.py`` holds the dense family the same way)."""
+    cfg, fn, shapes = _program(toy, program)
+    params = jax.jit(lambda: llama.init_params(cfg, jax.random.PRNGKey(5)))()
+    rng = np.random.default_rng(0)
+
+    def drawn(x):
+        if x.dtype != jnp.int32:
+            return jnp.asarray(0.5 * rng.standard_normal(x.shape), x.dtype)
+        return jnp.zeros(x.shape, jnp.int32)   # a state's row counts: nothing seen yet
+
+    tokens, *held, a, b = jax.tree.map(drawn, shapes)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, tokens.shape), jnp.int32)
+    if program == "prefill":   # two fresh rows, the logits of their last positions
+        a, b = jnp.zeros(a.shape, jnp.int32), jnp.full(b.shape, tokens.shape[1] - 1, jnp.int32)
+    else:                      # block tables of 4 blocks a row, positions inside them
+        a = jnp.asarray([[1, 2, 3, 4, 0, 0, 0, 0], [5, 6, 7, 8, 0, 0, 0, 0]], jnp.int32)
+        b = jnp.asarray([20, 37], jnp.int32)
+
+    def run():  # a new function a call: traced anew
+        return jax.jit(lambda *args: fn(*args))(params, tokens, *held, a, b)
+
+    with_barrier = run()
+    passed = []
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: passed.append(x) or x)
+    without = run()
+    assert passed   # the barrier was in the trace, and is out of this one
+    assert np.isfinite(np.asarray(with_barrier[0])).all()   # logits, not NaN against NaN
+    for x, y in zip(jax.tree.leaves(with_barrier), jax.tree.leaves(without)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))

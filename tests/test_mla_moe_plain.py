@@ -266,8 +266,10 @@ def _operations(text: str) -> list[str]:
 # form came in). A deliberate change to the Xing form's decode path records
 # the new digest here and says so; the plain form must never move it.
 # Recorded anew at PR 49, which changed the latent decode kernel's walk
-# (``ops/mla_attention.py``) for both forms.
-XING_DECODE_SHA = "9147ef682c95e5fecb95ad433915bfeba2a0f3380597d878c50c6f2a0685bba5"
+# (``ops/mla_attention.py``) for both forms, and at PR 53, which holds the
+# query product apart from its cut into heads (``ops/wquant.py flat_rows``:
+# one ``optimization_barrier`` a layer, in both forms).
+XING_DECODE_SHA = "b850c6396a49d51510166e6343c54b7eb7317422d77c5afcc8454785f3158fca"
 
 
 def test_the_xing_forms_lowered_decode_step_is_what_it_was():

@@ -546,38 +546,129 @@ def _unfused_results(text: str, *shapes: str) -> list[str]:
     """The device operations of a compiled program, outside its fused
     computations, whose result is one of ``shapes`` ("s8[1,4096,1024]")."""
     return [ln[:200] for ops in _scoped_operations(text, "").values() for _, ln in ops
-            if ln.split(" = ", 1)[1].startswith(shapes)]
+            if ln.split(" = ", 1)[1].lstrip("(").startswith(shapes)]
 
 
-@pytest.mark.parametrize("program,wquant", [
-    ("decode_pallas", "int8"), ("spec_verify_pallas", "int8"), ("prefill1", "int8"),
-    ("prefill_chunk_group", "int8"), ("admit_fused_paged", "int8"),
-    ("decode_pallas", "none"), ("decode_pallas", "int4"),
+_PREFETCH = ("copy-start", "copy-done", "slice-start", "slice-done")
+
+
+def _moved_from_rest(text: str, *shapes: str) -> list[str]:
+    """Of ``_unfused_results``, what writes an array of ``shapes`` anew: every
+    such operation but the compiler's own prefetch of an array into fast
+    memory as it rests (``copy-start`` / ``slice-start`` and their ``-done``
+    into ``S(1)`` in the row-major layout, and the ``ConcatBitcast`` that joins
+    a prefetch made in slices): that one overlaps the operations before it and
+    reads HBM once, where a slice fusion or a ``{1,2,0}`` copy stands in the
+    step's way and writes what it read."""
+    def prefetch(ln: str) -> bool:
+        result = ln.split(" = ", 1)[1].lstrip("(").split(" ", 1)[0]
+        return "{2,1,0" in result and ("ConcatBitcast" in ln or any(f" {p}(" in ln for p in _PREFETCH))
+    return [ln for ln in _unfused_results(text, *shapes) if not prefetch(ln)]
+
+
+def test_moved_from_rest_tells_a_prefetch_from_a_relayout():
+    text = """HloModule m
+%body (t: (bf16[6,64,64])) -> (bf16[6,64,64]) {
+  %g = bf16[6,64,64]{2,1,0} get-tuple-element(%t), index=0
+  %constant_dynamic-slice_fusion.9 = bf16[1,64,64]{1,2,0:T(8,128)(2,1)S(1)} fusion(%g), kind=kLoop, calls=%fused_computation.1
+  %slice-start = ((bf16[6,64,64]{2,1,0}), bf16[1,64,64]{2,1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) slice-start(%g), slice={[0:1], [0:64], [0:64]}
+  %slice-done = bf16[1,64,64]{2,1,0:T(8,128)(2,1)S(1)} slice-done(%slice-start)
+  %copy-start.1 = (bf16[1,64,64]{1,2,0:T(8,128)(2,1)S(1)}, bf16[1,64,64]{2,1,0}, u32[]{:S(2)}) copy-start(%slice-done)
+  %copy-done.1 = bf16[1,64,64]{1,2,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.1)
+  %custom-call.4 = bf16[6,64,64]{2,1,0:T(8,128)(2,1)S(1)} custom-call(%slice-done), custom_call_target="ConcatBitcast"
+}
+
+ENTRY %main (a: bf16[6,64,64]) -> bf16[6,64,64] {
+  %a = bf16[6,64,64]{2,1,0} parameter(0)
+  %copy.281 = bf16[6,64,64]{1,2,0:T(8,128)(2,1)} copy(%a)
+  %copy-start = (bf16[6,64,64]{2,1,0:T(8,128)(2,1)S(1)}, bf16[6,64,64]{2,1,0}, u32[]{:S(2)}) copy-start(%a)
+}
+"""
+    moved = _moved_from_rest(text, "bf16[6,64,64]", "bf16[1,64,64]")
+    assert [ln.split(" = ")[0] for ln in moved] == [
+        "%constant_dynamic-slice_fusion.9", "%copy-start.1", "%copy-done.1", "%copy.281"], moved
+
+
+@pytest.fixture(scope="module")
+def mla_cell():
+    """(cfg, the served tree's shapes, block tokens, slots, context) of
+    ``benchmark/configs/xing4.0-29b-a4b.json``: the latent form with a query
+    latent (``q_lora_rank``: ``w_dq``, then ``w_uq`` cut into heads)."""
+    import json
+    from pathlib import Path
+
+    from benchmark import run
+
+    root = Path(__file__).resolve().parents[1]
+    ref = run.load_module(root / "benchmark/references/mla_moe_mhc.py")
+    conf = json.loads((root / "benchmark/configs/xing4.0-29b-a4b.json").read_text())
+    env = conf["serving"]["env"]
+    seq = int(env["MAX_SEQ_LEN"])
+    cfg = ref.model_config(conf, seq)
+    return cfg, ref.param_shapes(cfg), int(env["KV_BLOCK_TOKENS"]), int(env["MAX_BATCH_SLOTS"]), seq
+
+
+# family -> (its cell's fixture, (cfg, shapes, block tokens, slots, context) of
+# what the fixture gives, the blocks its prefix cache adds to the pool)
+_QKV_CELLS = {
+    "lightning": ("sala_cell", lambda c: c, 0),
+    "window": ("swa_cell", lambda c: (c[0].with_(use_flash_attention=True), *c[1:], SWA_SLOTS, SWA_SEQ), 0),
+    "latent-plain": ("mla_plain_cell", lambda c: c, 64),
+    "latent-q-lora": ("mla_cell", lambda c: c, 64),
+    "gated-delta": ("gdn_cell", lambda c: c, 0),
+    "state-space": ("ssm_cell", lambda c: (*c, T, SSM_SLOTS, SEQ), 0),
+}
+_QKV_STACKS = {"wq", "wk", "wv", "w_uq"}
+_HLO_TYPES = {"bfloat16": "bf16", "int8": "s8", "uint8": "u8"}
+
+
+def _qkv_stacks(params) -> list[tuple[str, tuple[int, ...]]]:
+    """(HLO element type, shape) of every q / k / v projection stack [layers,
+    rows, cols] in a tree of shapes (a quantised leaf's codes; never its
+    scales)."""
+    found = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        keys = {getattr(k, "key", None) for k in path}
+        if keys & _QKV_STACKS and leaf.ndim == 3 and leaf.dtype.name in _HLO_TYPES:
+            found.add((_HLO_TYPES[leaf.dtype.name], tuple(leaf.shape)))
+    return sorted(found)
+
+
+@pytest.mark.parametrize("family,program,wquant", [
+    ("dense", "decode_pallas", "int8"), ("dense", "spec_verify_pallas", "int8"),
+    ("dense", "prefill1", "int8"), ("dense", "prefill_chunk_group", "int8"),
+    ("dense", "admit_fused_paged", "int8"), ("dense", "decode_pallas", "none"),
+    ("dense", "decode_pallas", "int4"),
+    *[(family, program, "none") for family in _QKV_CELLS for program in ("decode_pallas", "prefill1")],
 ], ids=["burst", "spec_verify", "prefill1", "chunk_group_of_4", "admit_fused_paged",
         "burst-bf16-of-16-layers-reads-its-slices-at-rest-too",
-        "burst-int4-unpacks-a-bf16-slice-a-layer-as-the-parent-did"])
-def test_the_dense_familys_qkv_products_read_their_slice_out_of_the_stack_at_rest(
-        one_chip, no_cache, dense_cfg, program, wquant):
-    """The Granite-8B cells' programs as ``serve/programs.py`` builds them (40
-    layers, 8 slots x 2,048, block 16; the burst is 8 steps, the verify 7 wide,
-    the chunks 256 tokens over a window of 1,024, the admit a bucket of 256):
-    ``wq`` / ``wk`` / ``wv`` are read where they rest, by the product itself,
-    as the MLP's stacks and ``wo`` are. ``llama._qkv_rows`` keeps the split into
-    heads out of the three products; with it folded in (the parent of PR 52)
-    the compiler bitcast each layer's slice to [4096, heads, 128], could then
-    no longer fuse the slice as the product's operand, and wanted the
-    contraction axis minor. So the burst began with ``%copy.86``
-    ``s8[40,4096,4096]{1,2,0}`` and ``.85`` / ``.87`` ``s8[40,4096,1024]{1,2,0}``
-    (1.0 GB: its ``temp_size_in_bytes`` was 1,015,474,176, or 1,016,927,744 as a
-    bare scan of 8 steps), every layer of every step ran
-    ``%constant_dynamic-slice_fusion.6/.7/.8`` over those copies, and the one-row
-    programs (``prefill1``, the admit, the one-step burst) a slice fusion and a
-    ``{1,2,0}`` copy a stack a layer. None of that is left: no copy of a whole
-    stack, no operation outside a fusion that yields one layer's slice of one,
-    and a burst whose temporaries are under 64 MB. (The parent's verify and
-    group of 4, with more rows a product, had neither; they are held too.)
+        "burst-int4-unpacks-a-bf16-slice-a-layer-as-the-parent-did",
+        *[f"{family}-{name}" for family in _QKV_CELLS for name in ("burst", "prefill1")]])
+def test_the_qkv_products_read_their_slice_out_of_the_stack_at_rest(
+        request, one_chip, no_cache, family, program, wquant):
+    """Every family's programs as ``serve/programs.py`` builds them, at its
+    cell's shapes (the burst is 8 steps, the dense verify 7 wide, the chunks
+    256 tokens, the dense admit a bucket of 256): ``wq`` / ``wk`` / ``wv`` (and
+    the latent form's ``w_uq``) are read where they rest, by the product
+    itself, as the MLP's stacks and ``wo`` are. ``ops/wquant.py flat_rows``
+    keeps the cut into heads out of the products; with it folded in the
+    compiler bitcast each layer's slice to [d_model, heads, head_dim], could
+    then no longer fuse the slice as the product's operand, and wanted the
+    contraction axis minor. So the dense burst (the parent of PR 52) began with
+    ``%copy.86`` ``s8[40,4096,4096]{1,2,0}`` and ``.85`` / ``.87``
+    ``s8[40,4096,1024]{1,2,0}`` (``temp_size_in_bytes`` 1,015,474,176), every
+    layer of every step ran ``%constant_dynamic-slice_fusion.6/.7/.8`` over
+    those copies, and the one-row programs a slice fusion and a ``{1,2,0}`` copy
+    a stack a layer; the parent of PR 53 held the same in every other family
+    (the Lightning burst three ``bf16[1,4096,4096]{1,2,0}`` a layer over three
+    copied ``bf16[6,4096,4096]`` stacks, 620 MB of temporaries; the slices each
+    case found are in ``CHANGES.md``, PR 53). None of that is left: no copy of
+    a whole stack and no operation outside a fusion that yields one layer's
+    slice of one. What is NOT held: the latent form's ``w_ukv``, a weight that
+    is itself cut into heads, whose slice a layer is still copied (PERF.md
+    section 7).
 
-    The same burst over the other trees the worker rests, ids as found: bf16
+    The dense burst over the other trees the worker rests, ids as found: bf16
     stacks (16 of the 40 layers, what fits 16 GB) compiled to the parent's
     form and now to this one, 808,554,496 bytes of temporaries to 3 MB; packed
     int4 stacks never took the parent's form (``_mm4`` unpacks each layer's
@@ -586,29 +677,54 @@ def test_the_dense_familys_qkv_products_read_their_slice_out_of_the_stack_at_res
     that the unpacked slices are as many as they were)."""
     from benchmark.lib.weights import program_param_shapes
     from nats_llm_studio_tpu.engine.sampling import sample_rows
+    from nats_llm_studio_tpu.models import llama
+    from nats_llm_studio_tpu.ops.kvcache import WithState
     from nats_llm_studio_tpu.serve.programs import build_programs
 
-    cfg = dense_cfg.with_(n_layers=16) if wquant == "none" else dense_cfg
-    seq, layers, d, kv_cols = cfg.max_seq_len, cfg.n_layers, cfg.d_model, cfg.n_kv_heads * cfg.head_dim
+    if family == "dense":
+        cfg = request.getfixturevalue("dense_cfg")
+        cfg = cfg.with_(n_layers=16) if wquant == "none" else cfg
+        shapes, t, slots, seq, spare = (_served_tree(program_param_shapes(cfg), wquant), T, SLOTS,
+                                        cfg.max_seq_len, 64)
+        window = 4 * CHUNK
+    else:
+        fixture, cell, spare = _QKV_CELLS[family]
+        cfg, shapes, t, slots, seq = cell(request.getfixturevalue(fixture))
+        window = seq
     sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
-    params = jax.tree.map(sds, _served_tree(program_param_shapes(cfg), wquant))
+    params = jax.tree.map(sds, shapes)
     ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
     floats = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)  # noqa: E731
-    pool = jax.ShapeDtypeStruct((SLOTS * (seq // T) + 64 + 1, layers, cfg.n_kv_heads, T, cfg.head_dim),
-                                jnp.bfloat16, sharding=one_chip)
-    rows = lambda w: jax.ShapeDtypeStruct(  # noqa: E731
-        (w, layers, cfg.n_kv_heads, seq, cfg.head_dim), jnp.bfloat16, sharding=one_chip)
-    slot = (ints(SLOTS), ints(SLOTS), floats(SLOTS), ints(SLOTS), floats(SLOTS))  # seeds ... topp
+
+    def pools():
+        pair = [jax.ShapeDtypeStruct((slots * (seq // t) + spare + 1, cfg.n_kv_layers, h, t, w),
+                                     jnp.bfloat16, sharding=one_chip) for h, w in cfg.kv_cache_dims()]
+        if not cfg.slot_state:
+            return pair
+        axes = []   # static: taken as the state is made, not as shapes
+
+        def states():
+            made = llama.family_module(cfg).make_state(cfg, slots)
+            axes.extend(ax for _, ax in made)
+            return [st for st, _ in made]
+
+        state = jax.tree.map(sds, jax.eval_shape(states))
+        return [WithState(p, st, ax) for p, st, ax in zip(pair, state, axes)]
+
+    rows = lambda w: jax.tree.map(sds, jax.eval_shape(  # noqa: E731
+        lambda: llama.make_cache(cfg, w, seq, "bfloat16")))
+    slot = (ints(slots), ints(slots), floats(slots), ints(slots), floats(slots))  # seeds ... topp
     args = {
-        "decode_pallas": (params, ints(SLOTS), pool, pool, ints(SLOTS, seq // T), ints(SLOTS), *slot, 8),
-        "spec_verify_pallas": (params, ints(SLOTS), pool, pool, ints(SLOTS, seq // T), ints(SLOTS),
-                               ints(SLOTS, SPEC_W - 1), ints(SLOTS), *slot),
-        "prefill1": (params, ints(1, CHUNK), rows(1), rows(1), ints(1), ints(1), 4 * CHUNK),
-        "prefill_chunk_group": (params, ints(4, CHUNK), rows(4), rows(4), ints(4), ints(4), 4 * CHUNK),
-        "admit_fused_paged": (params, pool, pool, ints(SLOTS), ints(1, CHUNK), ints(), ints(CHUNK // T),
-                              ints(), ints(), floats(), ints(), floats()),
-    }[program]
-    table = build_programs(cfg, None, max_seq=seq, paged=True, kv_block_tokens=T,
+        "decode_pallas": lambda: (params, ints(slots), *pools(), ints(slots, seq // t), ints(slots),
+                                  *slot, 8),
+        "spec_verify_pallas": lambda: (params, ints(slots), *pools(), ints(slots, seq // t),
+                                       ints(slots), ints(slots, SPEC_W - 1), ints(slots), *slot),
+        "prefill1": lambda: (params, ints(1, CHUNK), *rows(1), ints(1), ints(1), window),
+        "prefill_chunk_group": lambda: (params, ints(4, CHUNK), *rows(4), ints(4), ints(4), window),
+        "admit_fused_paged": lambda: (params, *pools(), ints(slots), ints(1, CHUNK), ints(),
+                                      ints(CHUNK // t), ints(), ints(), floats(), ints(), floats()),
+    }[program]()
+    table = build_programs(cfg, None, max_seq=seq, paged=True, kv_block_tokens=t,
                            sample_rows=sample_rows)
     orig = jax.default_backend
     jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
@@ -617,20 +733,24 @@ def test_the_dense_familys_qkv_products_read_their_slice_out_of_the_stack_at_res
     finally:
         jax.default_backend = orig
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    codes = {"int8": "s8", "none": "bf16", "int4": "u8"}[wquant]
-    held_rows = d // 2 if wquant == "int4" else d   # two codes a byte
-    for cols in (d, kv_cols):
-        copies = _whole_array_copies(text, f"{codes}[{layers},{held_rows},{cols}]")
-        assert not copies, copies
+    assert "tpu_custom_call" in text or (family, program) == ("state-space", "prefill1")  # no kernel in it
+    stacks = _qkv_stacks(shapes)
+    assert stacks
+    name = lambda code, shape: f"{code}[{','.join(map(str, shape))}]"  # noqa: E731
+    held = [name(code, shape) for code, shape in stacks]
+    if wquant != "int4":   # and a layer's slice of each (int4 unpacks its slices: below)
+        held += [name(code, (1,) + shape[1:]) for code, shape in stacks]
+    moved = _moved_from_rest(text, *held)
+    assert not moved, moved
     if wquant == "int4":
-        unpacked = _unfused_results(text, f"bf16[{d // 2},2,{kv_cols}]")
+        kv_cols = cfg.n_kv_heads * cfg.head_dim
+        unpacked = _unfused_results(text, f"bf16[{cfg.d_model // 2},2,{kv_cols}]")
         assert len(unpacked) == 4, unpacked   # wk and wv: the unpacking fusion and its copy
         return
-    sliced = _unfused_results(text, f"{codes}[1,{d},{d}]", f"{codes}[1,{d},{kv_cols}]")
-    assert not sliced, sliced
-    if program == "decode_pallas":
-        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    ma = compiled.memory_analysis()
+    if family == "dense" and program == "decode_pallas":
+        assert ma.temp_size_in_bytes < 64 << 20
+    print(f"\n{family} {program}: temp {ma.temp_size_in_bytes / 1e6:.0f} MB")
 
 
 # -- the state-space / attention hybrid family at granite4hmicro.chat32_closed's shapes --
@@ -1390,10 +1510,10 @@ def test_a_decode_launch_of_the_lightning_family_copies_no_pool(
     the cut's 8 layers over the cell's pools (16 slots x 28,672 tokens),
     donated: the state kernel and the picked walk are in the program under
     their names, the pools are aliased onto the results, and no ``copy`` of the
-    float32 state pool, of the pooled keys or of a KV pool is anywhere in it.
-    (What it does hold, once a launch and outside the steps' loop: the lightning
-    stack's wq, wk and wv transposed, 0.6 GB, because their products' output is
-    cut into heads right away; PERF.md section 7 has it as an open item.)"""
+    float32 state pool, of the pooled keys or of a KV pool is anywhere in it,
+    and what it holds beside its pools is under 100 MB (8 MB as compiled; the
+    parent of PR 53 held the Lightning stack's wq, wk and wv transposed, 0.6 GB,
+    once a launch)."""
     cfg, shapes, t, slots, seq = sala_cell
     kp, vp = _sala_pools(cfg, one_chip, t, slots, seq)
     sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
@@ -1421,7 +1541,7 @@ def test_a_decode_launch_of_the_lightning_family_copies_no_pool(
     ma = compiled.memory_analysis()
     state_bytes = int(np.prod(state.shape)) * 4
     assert ma.alias_size_in_bytes >= state_bytes + 2 * int(np.prod(kv.shape)) * 2
-    assert ma.temp_size_in_bytes < 1e9, ma.temp_size_in_bytes
+    assert ma.temp_size_in_bytes < 100e6, ma.temp_size_in_bytes
     print(f"\n{program}: temp {ma.temp_size_in_bytes / 1e6:.0f} MB, "
           f"alias {ma.alias_size_in_bytes / 1e6:.0f} MB, args {ma.argument_size_in_bytes / 1e9:.2f} GB")
 
