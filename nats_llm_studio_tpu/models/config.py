@@ -21,7 +21,10 @@ each refuses", has the causes each refusal gives):
   (``granitehybrid``): ``models/ssm_hybrid.py``. Served on the paged pool of
   one chip, with a per-slot state pool beside it; the prefix cache,
   speculation, int8 KV, the KV tiers, KVX1 export and a real GGUF's tensors
-  are refused.
+  are refused. With ``<arch>.feed_forward_length`` a list as well (a width
+  at the layers that are an FFN alone, 0 elsewhere: ``nemotron_h_moe``) a
+  layer is ONE sublayer: a mixer, or routed two-matrix relu^2 experts in a
+  latent (``models/experts.py``) of which the chip may hold a share.
 * ``attention.sliding_window`` present with ``attention.head_count`` a list
   (one entry a layer): window-attention layers beside full-attention layers
   with their own head count and rotary table, a sigmoid gate a head on the
@@ -83,8 +86,9 @@ class ModelConfig:
     logit_scale: float = 1.0
     # Qwen2-family: QKV projections carry biases
     attn_bias: bool = False
-    # Gemma-family: GELU MLP and RMSNorm computing x * (1 + w)
-    mlp_act: str = "silu"  # "silu" | "gelu"
+    # Gemma-family: GELU MLP and RMSNorm computing x * (1 + w); "relu2": an
+    # MLP of TWO matrices, relu(x W_up)^2 W_down (nemotron_h: no gate matrix)
+    mlp_act: str = "silu"  # "silu" | "gelu" | "relu2"
     norm_plus_one: bool = False
     dtype: str = "bfloat16"  # compute/weight dtype name (tests use float32)
     # KV cache storage: "none" (cache in `dtype`) or "int8" (codes + per-
@@ -144,7 +148,14 @@ class ModelConfig:
     # ssm_d_state] and the convolution's last inputs in place of KV, so only
     # the attention layers hold KV (n_kv_layers). use_rope False = no
     # positional embedding at all (NoPE): the state layers carry order.
+    # A third kind, "experts", makes every layer ONE sublayer (nemotron_h): a
+    # mixer without an MLP behind it, or the routed experts of
+    # models/experts.py alone, which work in a latent of ``moe_latent``
+    # columns between one down- and one up-projection a layer (0 = at
+    # d_model); ssm_n_groups > 1: B and C a group of heads, the gated norm a
+    # group of channels.
     layer_types: tuple[str, ...] = ()
+    moe_latent: int = 0
     ssm_n_heads: int = 0
     ssm_head_dim: int = 0
     ssm_d_state: int = 0
@@ -257,11 +268,18 @@ class ModelConfig:
         return self.max_seq_len // self.sparse_stride
 
     @property
+    def n_expert_only_layers(self) -> int:
+        """Layers that are routed experts and nothing else (one sublayer a
+        layer: models/ssm_hybrid.py)."""
+        return sum(t == "experts" for t in self.layer_types)
+
+    @property
     def n_kv_layers(self) -> int:
         """Layers that hold paged KV: the pool's layer axis (a state-space or
         linear-attention layer keeps a state, a window layer a ring, all by
-        slot)."""
-        return self.n_layers - self.n_ssm_layers - self.n_win_layers - self.n_lin_layers
+        slot; a layer of experts alone keeps nothing)."""
+        return (self.n_layers - self.n_ssm_layers - self.n_win_layers - self.n_lin_layers
+                - self.n_expert_only_layers)
 
     @property
     def recurrent(self) -> bool:
@@ -322,8 +340,10 @@ class ModelConfig:
     def n_moe_layers(self) -> int:
         """Layers whose FFN is the routed-expert form of ``models/experts.py``
         (the latent-attention, window-attention and linear-attention
-        families: the Mixtral family routes in every layer and keeps no dense
-        stack)."""
+        families, and the state-space family's layers of experts alone: the
+        Mixtral family routes in every layer and keeps no dense stack)."""
+        if self.n_expert_only_layers:
+            return self.n_expert_only_layers
         routed = (self.is_mla or self.n_win_layers or self.n_lin_layers) and self.is_moe
         return self.n_layers - self.n_dense_layers if routed else 0
 
@@ -384,6 +404,11 @@ class ModelConfig:
         # a list: one entry a layer, 0 for a layer that keeps no KV
         kv_by_layer = [int(h) for h in kv_heads] if isinstance(kv_heads, (list, tuple)) else None
         d_model = int(g("embedding_length", 4096))
+        d_ff = g("feed_forward_length", 4 * d_model)
+        if hasattr(d_ff, "tolist"):
+            d_ff = d_ff.tolist()
+        # a list: one entry a layer, 0 for a layer that is no FFN
+        ff_by_layer = [int(f) for f in d_ff] if isinstance(d_ff, (list, tuple)) else None
         head_dim = int(g("attention.key_length", d_model // n_heads))
         vocab = md.get(f"{arch}.vocab_size")
         if vocab is None:
@@ -422,7 +447,7 @@ class ModelConfig:
             n_heads=n_heads,
             n_kv_heads=max(kv_by_layer) if kv_by_layer else int(kv_heads),
             head_dim=head_dim,
-            d_ff=int(g("feed_forward_length", 4 * d_model)),
+            d_ff=max(ff_by_layer) if ff_by_layer else int(d_ff),
             rope_theta=float(g("rope.freq_base", 10000.0)),
             rms_eps=float(g("attention.layer_norm_rms_epsilon", 1e-5)),
             max_seq_len=int(g("context_length", 8192)),
@@ -480,6 +505,23 @@ class ModelConfig:
                 ssm_chunk=int(g("ssm.chunk_size", 256)),
                 use_rope=bool(g("rope.scaling.finetuned", False)),
             )
+            if ff_by_layer:
+                # one sublayer a layer (llama.cpp's nemotron_h_moe: a layer
+                # with neither a kv head nor an FFN width is recurrent), the
+                # FFN layers two-matrix relu^2 experts in a latent
+                fe = int(g("expert_feed_forward_length", 0))
+                family |= dict(
+                    layer_types=tuple("attention" if h else "experts" if f else "mamba"
+                                      for h, f in zip(kv_by_layer, ff_by_layer)),
+                    mlp_act="relu2",
+                    moe_d_ff=fe,
+                    n_shared_experts=int(g("expert_shared_feed_forward_length", 0)) // max(1, fe),
+                    moe_latent=int(g("moe_latent_size", 0)),
+                    router_scoring="sigmoid" if int(g("expert_gating_func", 2)) == 2 else "softmax",
+                    routed_scaling=float(g("expert_weights_scale", 1.0)),
+                    moe_ep_size=int(g("expert_parallel.count", 1)),
+                    moe_ep_rank=int(g("expert_parallel.rank", 0)),
+                )
         if g("attention.sliding_window") is not None and heads_by_layer:
             # window layers beside full layers, experts after a leading dense
             # layer: the keys models/export.config_metadata writes

@@ -102,6 +102,23 @@ def config_metadata(cfg: ModelConfig, name: str) -> dict[str, Any]:
             f"{a}.ssm.chunk_size": cfg.ssm_chunk,
             f"{a}.rope.scaling.finetuned": cfg.use_rope,
         }
+        if cfg.n_expert_only_layers:
+            # one sublayer a layer, as llama.cpp's nemotron_h_moe says it: an
+            # FFN width at the layers that are experts alone and 0 elsewhere,
+            # the routed and the shared experts' widths, the router's form,
+            # the latent the routed experts work in, and (this repo's) the
+            # share of a layer's experts this chip holds
+            md |= {
+                f"{a}.feed_forward_length": [
+                    cfg.moe_d_ff if t == "experts" else 0 for t in cfg.layer_types],
+                f"{a}.expert_feed_forward_length": cfg.moe_d_ff,
+                f"{a}.expert_shared_feed_forward_length": cfg.n_shared_experts * cfg.moe_d_ff,
+                f"{a}.moe_latent_size": cfg.moe_latent,
+                f"{a}.expert_gating_func": 2 if cfg.router_scoring == "sigmoid" else 1,
+                f"{a}.expert_weights_scale": cfg.routed_scaling,
+                f"{a}.expert_parallel.count": cfg.moe_ep_size,
+                f"{a}.expert_parallel.rank": cfg.moe_ep_rank,
+            }
     if cfg.n_win_layers:
         # window layers beside full layers: llama.cpp's keys where it has one
         # (a head count a layer, sliding_window and its pattern, freq_base_swa,

@@ -65,7 +65,7 @@ from ..ops.kvcache import WithState, kv_pool_write_rows, kv_update_slice, table_
 from ..ops.layers import gqa_attention, gqa_attention_hmajor, rms_norm, rope_cos_sin
 from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
-from .experts import EXPERT_LEAVES, expert_path, moe_ffn, stats_width
+from .experts import expert_path, moe_ffn, split_stacks, stats_width
 from .ssm_hybrid import V_AXES, _embed, _layers, state_bytes
 from .swa_moe import _rotate
 
@@ -264,8 +264,7 @@ def _experts(params: Params, cfg: ModelConfig, rows: int, live, mesh):
     moe = params["blocks"]["moe"]
     form, whole = expert_path(cfg, rows, moe, mesh), None
     if form != "dense":
-        whole = tuple(moe[k] for k in EXPERT_LEAVES)
-        moe = {k: v for k, v in moe.items() if k not in EXPERT_LEAVES}
+        whole, moe = split_stacks(moe)
 
     def ffn(x, carry, place):
         pf = jax.tree.map(

@@ -68,7 +68,7 @@ from ..ops.kvcache import kv_pool_write_rows, kv_update_slice
 from ..ops.layers import apply_rope, rms_norm, swiglu, yarn_frequencies
 from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
-from .experts import EXPERT_LEAVES, expert_path, moe_ffn
+from .experts import expert_path, moe_ffn, split_stacks
 
 Params = dict[str, Any]
 
@@ -375,8 +375,7 @@ def _layers(params: Params, cfg: ModelConfig, X, caches, attention, live=None, m
         if kind == "moe":
             form = expert_path(cfg, rows, stack, mesh)
         if form != "dense":
-            whole = tuple(stack[k] for k in EXPERT_LEAVES)
-            stack = {k: v for k, v in stack.items() if k not in EXPERT_LEAVES}
+            whole, stack = split_stacks(stack)
 
         def block(carry, inputs, kind=kind, form=form, whole=whole):
             X, caches = carry

@@ -1,5 +1,6 @@
 """State-space (Mamba-2) layers beside grouped-query attention layers without
-positional embedding (``granitehybrid``), next to ``models/llama.py`` and
+positional embedding (``granitehybrid``; ``nemotron_h`` with a layer ONE
+sublayer, a mixer or routed experts), next to ``models/llama.py`` and
 ``models/mla_moe.py``.
 
 ``models.llama.forward`` / ``forward_decode_paged`` / ``make_cache`` /
@@ -44,8 +45,20 @@ every other family uses. What differs:
   wants its first token masked or with log-probabilities) reads its state
   and does not advance it.
 
-The state, dt, the decays and the gated norm run in float32; products take
-the weights' dtype as in the other families.
+* **A layer may be one sublayer** (``"experts"`` among ``cfg.layer_types``:
+  ``nemotron_h``): a mamba or an attention layer then has no MLP behind it,
+  and a layer of the third kind is the routed-expert block of
+  ``models/experts.py`` alone, from a third stack ``blocks.moe``
+  [n_moe_layers, ...]: a sigmoid router over ``n_experts``, two-matrix relu^2
+  experts in a latent of ``cfg.moe_latent`` columns, of which the chip holds
+  ``n_experts_held``, a shared expert at the hidden width. The plan scans a
+  pair of kinds that repeats (mamba, experts) as it scans a run of one kind.
+  With ``cfg.ssm_n_groups`` > 1, B and C are a group's ([G, N] a token, head
+  h reading group h // (H / G)) and the gated norm normalises each group's
+  ``d_inner / G`` channels alone.
+
+The state, dt, the decays, the gated norm and the router run in float32;
+products take the weights' dtype as in the other families.
 """
 
 from __future__ import annotations
@@ -61,6 +74,7 @@ from ..ops.kvcache import WithState, kv_pool_write_rows, kv_update_slice, table_
 from ..ops.layers import apply_rope, gqa_attention_hmajor, rms_norm, rope_cos_sin, swiglu
 from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
+from .experts import expert_path, moe_ffn, split_stacks, stats_width
 
 Params = dict[str, Any]
 
@@ -72,6 +86,10 @@ def period_plan(cfg: ModelConfig) -> tuple[int, tuple[str, ...]]:
     n = len(kinds)
     if n != cfg.n_layers:
         raise ValueError(f"layer_types names {n} layers, n_layers is {cfg.n_layers}")
+    if "experts" in kinds and not cfg.moe_latent:
+        raise NotImplementedError(
+            "layers of routed experts alone work in a latent (moe_latent > 0): "
+            "two-matrix experts at the hidden width are not implemented")
     p = next(p for p in range(1, n + 1) if n % p == 0 and kinds == kinds[:p] * (n // p))
     return n // p, kinds[:p]
 
@@ -145,9 +163,14 @@ def _project_in(h: jax.Array, p: Params, cfg: ModelConfig):
 
 
 def _split_conv(xbc: jax.Array, cfg: ModelConfig):
-    di, n = cfg.ssm_d_inner, cfg.ssm_n_groups * cfg.ssm_d_state
+    """x [.., H, P] and B, C [.., N] (one group) or [.., G, N]."""
+    di, g = cfg.ssm_d_inner, cfg.ssm_n_groups
+    n = g * cfg.ssm_d_state
     x = xbc[..., :di].reshape(xbc.shape[:-1] + (cfg.ssm_n_heads, cfg.ssm_head_dim))
-    return x, xbc[..., di: di + n], xbc[..., di + n:]
+    bm, cm = xbc[..., di: di + n], xbc[..., di + n:]
+    if g > 1:
+        bm, cm = (z.reshape(z.shape[:-1] + (g, cfg.ssm_d_state)) for z in (bm, cm))
+    return x, bm, cm
 
 
 def _dt(dt_raw: jax.Array, p: Params) -> jax.Array:
@@ -160,10 +183,16 @@ def _a(p: Params) -> jax.Array:
 
 def _mixer_out(y: jax.Array, x: jax.Array, z: jax.Array, p: Params, cfg: ModelConfig):
     """y [.., H, P] f32 (C . S) -> the mixer's output: + D x, gated by silu(z)
-    BEFORE the norm over all of d_inner, then the output projection."""
+    BEFORE the norm over all of d_inner (over each group's channels alone
+    where there are groups), then the output projection."""
     y = y + p["d_skip"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
     y = y.reshape(y.shape[:-2] + (cfg.ssm_d_inner,)) * jax.nn.silu(z.astype(jnp.float32))
-    y = rms_norm(y, p["gate_norm"].astype(jnp.float32), cfg.rms_eps)
+    gain, g = p["gate_norm"].astype(jnp.float32), cfg.ssm_n_groups
+    if g > 1:
+        y = rms_norm(y.reshape(y.shape[:-1] + (g, -1)), gain.reshape(g, -1),
+                     cfg.rms_eps).reshape(y.shape)
+    else:
+        y = rms_norm(y, gain, cfg.rms_eps)
     return mm(y.astype(z.dtype), p["w_out"])
 
 
@@ -259,36 +288,52 @@ def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
         return params["embed"][tokens].astype(jnp.dtype(cfg.dtype)) * cfg.embedding_scale
 
 
-# a layer kind's stack under ``blocks``, and the scope its mixer runs in
+# a layer kind's stack under ``blocks``, and the scope its sublayer runs in
 STACK = {"mamba": "mamba", "attention": "attn", "linear": "linear",
-         "lightning": "linear", "sparse": "attn"}
+         "lightning": "linear", "sparse": "attn", "experts": "moe"}
 SCOPE = {"mamba": "seq/ssm", "attention": "seq/attn", "linear": "seq/linear",
-         "lightning": "seq/linear", "sparse": "seq/sparse"}
+         "lightning": "seq/linear", "sparse": "seq/sparse", "experts": "ffn"}
 
 
-def _layers(params: Params, cfg: ModelConfig, x, carry, mixers, ffn=None):
+def period_runs(kinds: tuple[str, ...]) -> list[tuple[tuple[str, ...], int, int]]:
+    """One period's layers as runs (unit, times, first layer of the run in
+    the period): a kind that repeats, or a PAIR of kinds that repeats at least
+    twice ((mamba, experts) x 3), whichever covers more layers from where the
+    run starts; a scan's text holds the unit once."""
+    runs, j = [], 0
+    while j < len(kinds):
+        def times(u):
+            c = 1
+            while kinds[j + c * u: j + (c + 1) * u] == kinds[j: j + u]:
+                c += 1
+            return c
+
+        one, two = times(1), times(2)
+        u, c = (2, two) if two >= 2 and kinds[j] != kinds[j + 1] and 2 * two > one else (1, one)
+        runs.append((kinds[j: j + u], c, j))
+        j += u * c
+    return runs
+
+
+def _layers(params: Params, cfg: ModelConfig, x, carry, mixers, ffn=None, stacks=None):
     """All layers in model order: one scan over the periods, and inside a
-    period one scan over each run of layers of one kind (granite-4.0-h: 5
-    mamba, 1 attention, 4 mamba), so a program's text holds one layer of a
+    period one scan over each run of ``period_runs`` (granite-4.0-h: 5
+    mamba, 1 attention, 4 mamba), so a program's text holds one unit of a
     run and not the period's ten. ``mixers[kind](h, p, carry, layer) -> (out,
     carry)`` are the caller's, one a kind of ``cfg.layer_types``; a ``layer``
-    is the layer's place in its own kind's stack (``STACK``).
+    is the layer's place in its own kind's stack (``STACK``; ``stacks`` gives
+    a kind another tree than the whole stack: the experts' without the
+    leaves their kernels index themselves).
 
     The FFN half is the dense SwiGLU whose leaves lie in the mixer's stack,
     or the caller's: ``ffn(x, carry, place) -> (x, carry)`` with ``place`` the
     layer's place in the model (``models/gdn_moe.py``: routed experts in a
-    stack of their own, one entry a layer)."""
+    stack of their own, one entry a layer); or none at all where a layer is
+    one sublayer (``"experts"`` among the kinds)."""
     periods, kinds = period_plan(cfg)
+    single = "experts" in kinds
     per = {kind: kinds.count(kind) for kind in mixers}
-    stacks = {kind: params["blocks"].get(STACK[kind]) for kind in mixers}
-    # [kind, first of its kind in the period, layers, first layer of the run in the period]
-    runs, at = [], {kind: 0 for kind in mixers}
-    for j, kind in enumerate(kinds):
-        if runs and runs[-1][0] == kind:
-            runs[-1][2] += 1
-        else:
-            runs.append([kind, at[kind], 1, j])
-        at[kind] += 1
+    stacks = {kind: params["blocks"].get(STACK[kind]) for kind in mixers} | (stacks or {})
 
     def one(c, kind, layer, place):
         x, carry = c
@@ -302,6 +347,8 @@ def _layers(params: Params, cfg: ModelConfig, x, carry, mixers, ffn=None):
         with jax.named_scope(SCOPE[kind]):
             out, carry = mixers[kind](rms_norm(x, p["mix_norm"], cfg.rms_eps), p, carry, layer)
             x = x + out * cfg.residual_scale
+        if single:
+            return x, carry
         if ffn is not None:
             return ffn(x, carry, place())
         with jax.named_scope("ffn/mlp"):
@@ -310,19 +357,59 @@ def _layers(params: Params, cfg: ModelConfig, x, carry, mixers, ffn=None):
         return x, carry
 
     def period(c, i):
-        for kind, first, count, start in runs:
-            base = i * per[kind] + first
-            if count == 1:
-                c = one(c, kind, base, lambda: i * len(kinds) + start)
+        for unit, times, start in period_runs(kinds):
+            # each kind's first layer of the run among its kind: the period's,
+            # then the run's (a unit names a kind once)
+            base = {kind: i * per[kind] + kinds[:start].count(kind) for kind in unit}
+
+            def units(c, j, unit=unit, base=base, start=start):
+                """The unit's layers at turn ``j`` of its run (None: a run of
+                one turn, of one kind). ``place`` is read by a caller's ``ffn``
+                alone; a unit of one kind adds no ``j * 1 + 0`` to it, which
+                would be two more operations in every such program's text."""
+                def place(k):
+                    first = i * len(kinds) + start
+                    if len(unit) == 1:
+                        return first if j is None else first + j
+                    return first + j * len(unit) + k
+
+                for k, kind in enumerate(unit):
+                    c = one(c, kind, base[kind] if j is None else base[kind] + j,
+                            lambda k=k: place(k))
+                return c
+
+            if times == 1:
+                c = units(c, None)
             else:
-                c, _ = jax.lax.scan(
-                    lambda c, j, kind=kind, base=base, start=start: (
-                        one(c, kind, base + j, lambda: i * len(kinds) + start + j), None),
-                    c, jnp.arange(count, dtype=jnp.int32))
+                c, _ = jax.lax.scan(lambda c, j, units=units: (units(c, j), None),
+                                    c, jnp.arange(times, dtype=jnp.int32))
         return c, None
 
     (x, carry), _ = jax.lax.scan(period, (x, carry), jnp.arange(periods, dtype=jnp.int32))
     return x, carry
+
+
+def _experts(params: Params, cfg: ModelConfig, rows: int, live, mesh):
+    """(the ``"experts"`` kind's sublayer for ``_layers``, its ``stacks``
+    entry): the routed experts of ``blocks.moe`` at the layer's place in that
+    stack. Where they take the hit list or the grouped form the expert stacks
+    are closed over WHOLE and a layer passes its place in them
+    (``mla_moe._layers`` says why). The carry's last entry collects the
+    layers' counters (None without ``live``)."""
+    moe = params["blocks"]["moe"]
+    form, whole = expert_path(cfg, rows, moe, mesh), None
+    if form != "dense":
+        whole, moe = split_stacks(moe)
+
+    def experts(h, p, carry, layer):
+        y, st = moe_ffn(h, p, cfg, live, form, whole, layer)
+        if st is not None:
+            with jax.named_scope("router"):  # the layer's counters, beside the others
+                carry = carry[:-1] + (jax.lax.dynamic_update_slice(
+                    carry[-1], st[None], (layer, jnp.zeros((), jnp.int32))),)
+        return y, carry
+
+    return {"experts": experts}, {"experts": moe}
 
 
 def forward(
@@ -343,7 +430,7 @@ def forward(
         raise NotImplementedError(
             "state-space models are served on the paged pool (KV_PAGED=1): the "
             "shared-ring cache layout rolls rows, and a state cannot be rolled")
-    del uniform_start, mesh
+    del uniform_start
     b, t = tokens.shape
     s_max = k_cache.shape[3]
     win = attn_window if (attn_window is not None and attn_window < s_max) else s_max
@@ -358,12 +445,12 @@ def forward(
     (tails, seen), (states,) = k_cache.st, v_cache.st
 
     def mamba(h, p, carry, layer):
-        kc, vc, tails, states = carry
+        kc, vc, tails, states, stats = carry
         out, tails, states = mamba_prefill(h, p, cfg, tails, states, layer, valid)
-        return out, (kc, vc, tails, states)
+        return out, (kc, vc, tails, states, stats)
 
     def attention(h, p, carry, layer):
-        kc, vc, tails, states = carry
+        kc, vc, tails, states, stats = carry
         q, k, v = _qkv(h, p, cfg, positions)
 
         def write(cache_b, rows_b, s):  # [L, H', S, D'] <- [H', T, D'] at (layer, 0, s, 0)
@@ -383,11 +470,12 @@ def forward(
 
             ks, vs = window(kc), window(vc)
         o = gqa_attention_hmajor(q, ks, vs, mask, cfg.attn_scale)
-        return mm(o.reshape(b, t, -1), p["wo"]), (kc, vc, tails, states)
+        return mm(o.reshape(b, t, -1), p["wo"]), (kc, vc, tails, states, stats)
 
-    x, (kc, vc, tails, states) = _layers(
-        params, cfg, x, (k_cache.kv, v_cache.kv, tails, states),
-        {"mamba": mamba, "attention": attention})
+    experts, stacks = _experts(params, cfg, b * t, None, mesh) if cfg.n_moe_layers else ({}, None)
+    x, (kc, vc, tails, states, _) = _layers(
+        params, cfg, x, (k_cache.kv, v_cache.kv, tails, states, None),
+        {"mamba": mamba, "attention": attention} | experts, stacks=stacks)
     from .llama import lm_head_logits
 
     at = None if logit_positions is None else jnp.maximum(logit_positions, 0)
@@ -408,10 +496,11 @@ def forward_decode_paged(
     the attention layers write their packed row into the pool and attend over
     the slot's table (the paged decode kernel), the mamba layers update the
     state in place of the slots that hold a request, those whose row of
-    ``tbl`` names a block. Row i of the batch IS slot i of the state."""
+    ``tbl`` names a block. Row i of the batch IS slot i of the state. With
+    layers of experts also returns their counters [n_moe_layers,
+    ``experts.stats_width``] over those slots."""
     from ..ops.paged_attention import paged_decode_attention_auto
 
-    del mesh
     b, w = tokens.shape
     if w != 1:
         raise NotImplementedError(
@@ -428,28 +517,33 @@ def forward_decode_paged(
     x = _embed(params, cfg, tokens)
 
     def mamba(h, p, carry, layer):
-        kp, vp, tails, states = carry
+        kp, vp, tails, states, stats = carry
         out, tails, states = mamba_step(h, p, cfg, tails, states, layer, live, fresh)
-        return out, (kp, vp, tails, states)
+        return out, (kp, vp, tails, states, stats)
 
     def attention(h, p, carry, layer):
-        kp, vp, tails, states = carry
+        kp, vp, tails, states, stats = carry
         q, k, v = _qkv(h, p, cfg, positions)
         kp = kv_pool_write_rows(kp, pack_kv(k, cfg), tbl, start_pos, layer)
         vp = kv_pool_write_rows(vp, pack_kv(v, cfg), tbl, start_pos, layer)
         o = paged_decode_attention_auto(pack_q(q, cfg), kp, vp, tbl, start_pos, layer,
                                         cfg.attn_scale)
-        return mm(unpack_o(o, cfg).reshape(b, w, -1), p["wo"]), (kp, vp, tails, states)
+        return mm(unpack_o(o, cfg).reshape(b, w, -1), p["wo"]), (kp, vp, tails, states, stats)
 
-    x, (kp, vp, tails, states) = _layers(
-        params, cfg, x, (k_pool.kv, v_pool.kv, tails, states),
-        {"mamba": mamba, "attention": attention})
+    experts, stacks, stats = {}, None, None
+    if cfg.n_moe_layers:
+        experts, stacks = _experts(params, cfg, b * w, live.mask.astype(jnp.float32), mesh)
+        stats = jnp.zeros((cfg.n_moe_layers, stats_width(cfg)), jnp.int32)
+    x, (kp, vp, tails, states, stats) = _layers(
+        params, cfg, x, (k_pool.kv, v_pool.kv, tails, states, stats),
+        {"mamba": mamba, "attention": attention} | experts, stacks=stacks)
     from .llama import lm_head_logits
 
     logits = lm_head_logits(params, cfg, x, None, w)
     with jax.named_scope("seq/ssm"):
         seen = jnp.where(fresh, start_pos + 1, seen).astype(jnp.int32)
-    return logits, WithState(kp, (tails, seen), K_AXES), WithState(vp, (states,), V_AXES)
+    pools = (WithState(kp, (tails, seen), K_AXES), WithState(vp, (states,), V_AXES))
+    return (logits,) + pools + (() if stats is None else (stats,))
 
 
 # ---------------------------------------------------------------------------
@@ -474,6 +568,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     h, di, c = cfg.ssm_n_heads, cfg.ssm_d_inner, cfg.ssm_conv_dim
 
     def common(L: int) -> Params:
+        if cfg.n_moe_layers:  # one sublayer a layer: no MLP behind a mixer
+            return {"mix_norm": jnp.ones((L, d), dt)}
         return {"mix_norm": jnp.ones((L, d), dt), "ffn_norm": jnp.ones((L, d), dt),
                 "w_gate": rand(L, d, ff), "w_up": rand(L, d, ff), "w_down": rand(L, ff, d)}
 
@@ -495,6 +591,16 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         blocks["attn"] = common(la) | {
             "wq": rand(la, d, cfg.n_heads * hd), "wk": rand(la, d, cfg.n_kv_heads * hd),
             "wv": rand(la, d, cfg.n_kv_heads * hd), "wo": rand(la, cfg.n_heads * hd, d)}
+    if cfg.n_moe_layers:
+        # two-matrix experts in a latent (models/experts.py); the shared one
+        # at the hidden width
+        le, e, eh = cfg.n_moe_layers, cfg.n_experts, cfg.n_experts_held
+        w, fe, fs = cfg.moe_latent, cfg.moe_d_ff, cfg.n_shared_experts * cfg.moe_d_ff
+        blocks["moe"] = {
+            "mix_norm": jnp.ones((le, d), dt), "router": rand(le, d, e), "e_bias": rand(le, e),
+            "w_up_e": rand(le, eh, w, fe), "w_down_e": rand(le, eh, fe, w),
+            "w_up_s": rand(le, d, fs), "w_down_s": rand(le, fs, d),
+            "w_lat_down": rand(le, d, w), "w_lat_up": rand(le, w, d)}
     params: Params = {"embed": rand(cfg.vocab_size, d), "out_norm": jnp.ones((d,), dt),
                       "blocks": blocks}
     if not cfg.tie_embeddings:

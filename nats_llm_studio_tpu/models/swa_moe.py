@@ -70,7 +70,7 @@ from ..ops.layers import (
 )
 from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
-from .experts import EXPERT_LEAVES, expert_path, moe_ffn
+from .experts import expert_path, moe_ffn, split_stacks
 
 Params = dict[str, Any]
 
@@ -268,8 +268,7 @@ def _layers(params: Params, cfg: ModelConfig, x, carry, attend, live=None, mesh=
     if moe is not None:
         form = expert_path(cfg, rows, moe, mesh)
         if form != "dense":
-            whole = tuple(moe[k] for k in EXPERT_LEAVES)
-            moe = {k: v for k, v in moe.items() if k not in EXPERT_LEAVES}
+            whole, moe = split_stacks(moe)
     stats = None if live is None else jnp.zeros((cfg.n_moe_layers, 3), jnp.int32)
 
     def take(stack, place):

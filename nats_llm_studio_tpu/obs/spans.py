@@ -62,6 +62,8 @@ SCOPE_NAMES = (
     "ffn/router",    # scores, top-k, gates, the expert counters
     "ffn/experts",   # the routed experts in every form (hit_list, grouped, dense)
     "ffn/shared",    # the always-on expert(s)
+    "ffn/latent_down",  # hidden -> the latent the routed experts work in (models/experts.py, cfg.moe_latent)
+    "ffn/latent_up",    # ... the routed sum back to the hidden width, added to the shared expert's
     "mix",           # the four-stream maps, read, write and hc_sinkhorn of models/mla_moe.py
     "head",          # what turns the last hidden state into a token
     "head/logits",   # final norm and lm_head (a prefill's: one row a prompt)

@@ -19,6 +19,10 @@ once a run of tiles and not at all where no row picked it.
 Both take the WHOLE stacks ``[L, E, d, f]`` with (layer, expert) as indices:
 a layer's slice handed over as an operand would be copied first (1.4 GB a
 layer at the published widths).
+
+An expert is three matrices, ``silu(x W_gate) * (x W_up)`` then ``W_down``, or
+TWO where ``w_gate`` is None: ``relu(x W_up)^2`` then ``W_down`` (the
+``nemotron_h`` experts; ``d`` is then whatever width they work at).
 """
 
 from __future__ import annotations
@@ -60,20 +64,31 @@ def hit_list(rows_on: jax.Array, places: int) -> tuple[jax.Array, jax.Array]:
     return jnp.where(jnp.arange(places) < n_hit, ids, last).astype(jnp.int32), n_hit
 
 
-def _f_tile(d: int, f: int, itemsize: int) -> int:
+def _f_tile(d: int, f: int, itemsize: int, matrices: int = 3) -> int:
     """Columns of ``f`` a grid step takes: the widest lane-aligned divisor of
-    ``f`` whose three tiles, double-buffered, stay under ``_TILE_BYTES``."""
+    ``f`` whose tiles (one a matrix), double-buffered, stay under
+    ``_TILE_BYTES``."""
     fits = [t for t in range(128, f + 1, 128)
-            if f % t == 0 and 6 * d * t * itemsize <= _TILE_BYTES]
+            if f % t == 0 and 2 * matrices * d * t * itemsize <= _TILE_BYTES]
     return max(fits) if fits else f
 
 
-def _hit_kernel(ids_ref, n_ref, layer_ref, h_ref, gate_ref, init_ref,
-                wg_ref, wu_ref, wd_ref, o_ref):
+def _activation(x, w_refs, at=slice(None)):
+    """An expert's activation of rows ``x`` over the columns ``at`` of f, in
+    float32: ``w_refs`` is (gate, up) or, without a gate matrix, (up,)."""
+    dots = [jnp.dot(x, w[:, at], preferred_element_type=jnp.float32) for w in w_refs]
+    if len(dots) == 1:
+        return jnp.square(jnp.maximum(dots[0], 0.0))
+    return jax.nn.silu(dots[0]) * dots[1]
+
+
+def _hit_kernel(ids_ref, n_ref, layer_ref, h_ref, gate_ref, init_ref, *refs):
     """Grid (places, tiles of f): place i is expert ``ids[i]``; its rows are
     gated by ``gate[i]`` (0 for a row that did not pick it) and summed into
     the float32 output block, which stays in VMEM across the whole grid and
-    starts from ``init`` (the shared expert's output)."""
+    starts from ``init`` (the shared expert's output). ``refs``: the expert's
+    (gate,) up and down tiles, then the output."""
+    *w_refs, wd_ref, o_ref = refs
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((i == 0) & (j == 0))
@@ -83,9 +98,7 @@ def _hit_kernel(ids_ref, n_ref, layer_ref, h_ref, gate_ref, init_ref,
     @pl.when(i < n_ref[0])
     def _expert():
         h = h_ref[...]
-        g = jnp.dot(h, wg_ref[...], preferred_element_type=jnp.float32)
-        u = jnp.dot(h, wu_ref[...], preferred_element_type=jnp.float32)
-        act = (jax.nn.silu(g) * u * gate_ref[i]).astype(h.dtype)
+        act = (_activation(h, w_refs) * gate_ref[i]).astype(h.dtype)
         o_ref[...] += jnp.dot(act, wd_ref[...], preferred_element_type=jnp.float32)
 
 
@@ -95,17 +108,19 @@ def moe_hit_experts(
     ids: jax.Array,     # [P] int32 (hit_list)
     n_hit: jax.Array,   # int32 scalar
     layer,              # int32 scalar: the layer's place in the stacks
-    w_gate: jax.Array,  # [L, E, d, f]
+    w_gate: jax.Array | None,  # [L, E, d, f]; None: two-matrix relu^2 experts
     w_up: jax.Array,    # [L, E, d, f]
     w_down: jax.Array,  # [L, E, f, d]
     init: jax.Array,    # [R, d] f32
     interpret: bool = False,
 ) -> jax.Array:
-    """init + sum over the listed experts of gate x SwiGLU(rows): [R, d] f32."""
+    """init + sum over the listed experts of gate x expert(rows): [R, d] f32."""
     r, d = h.shape
     p = ids.shape[0]
-    f = w_gate.shape[-1]
-    tf = _f_tile(d, f, w_gate.dtype.itemsize)
+    f = w_up.shape[-1]
+    ups = (w_up,) if w_gate is None else (w_gate, w_up)
+    isz = w_up.dtype.itemsize
+    tf = _f_tile(d, f, isz, len(ups) + 1)
     nt = f // tf
     mult = 8 if h.dtype.itemsize >= 4 else 16
     rp = -(-r // mult) * mult
@@ -131,10 +146,9 @@ def moe_hit_experts(
         grid=(p, nt),
         in_specs=[pl.BlockSpec((rp, d), whole),
                   pl.BlockSpec((p, rp, 1), lambda i, j, *_: (0, 0, 0)),
-                  pl.BlockSpec((rp, d), whole),
-                  pl.BlockSpec((None, None, d, tf), up_map),
-                  pl.BlockSpec((None, None, d, tf), up_map),
-                  pl.BlockSpec((None, None, tf, d), down_map)],
+                  pl.BlockSpec((rp, d), whole)]
+                 + [pl.BlockSpec((None, None, d, tf), up_map)] * len(ups)
+                 + [pl.BlockSpec((None, None, tf, d), down_map)],
         out_specs=pl.BlockSpec((rp, d), whole),
     )
     out = pl.pallas_call(
@@ -143,7 +157,7 @@ def moe_hit_experts(
         out_shape=jax.ShapeDtypeStruct((rp, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=6 * d * tf * w_gate.dtype.itemsize + (16 << 20)),
+            vmem_limit_bytes=2 * (len(ups) + 1) * d * tf * isz + (16 << 20)),
         interpret=interpret,
         # a constant: the custom call's name in a device trace
         name="moe_hit_experts",
@@ -151,7 +165,7 @@ def moe_hit_experts(
         ids.astype(jnp.int32), jnp.asarray(n_hit, jnp.int32).reshape(1),
         jnp.asarray(layer, jnp.int32).reshape(1),
         h, gates.astype(jnp.float32)[..., None], init.astype(jnp.float32),
-        w_gate, w_up, w_down,
+        *ups, w_down,
     )
     return out[:r]
 
@@ -192,26 +206,26 @@ def group_visits(sizes: jax.Array, pairs: int, tm: int):
 
 
 def _grouped_kernel(tm, fc, expert_ref, tile_ref, starts_ref, ends_ref, layer_ref,
-                    x_ref, gate_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
+                    x_ref, gate_ref, *refs):
     """Grid (visits,): visit i is tile ``tile[i]`` of the sorted rows on
-    expert ``expert[i]``, whose three matrices are the step's blocks WHOLE (a
+    expert ``expert[i]``, whose matrices ((gate,) up, down) are the step's
+    blocks WHOLE (a
     run of visits on one expert fetches them once). The whole tile is
     computed, ``fc`` columns of f a trip of one loop; only the rows of that
     expert are kept, the rest of the output block staying what the tile's
     earlier visits made it (it is held in VMEM until the tile changes)."""
+    *w_refs, wd_ref, o_ref, acc_ref = refs
     i = pl.program_id(0)
     x = x_ref[...]
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def columns(c, carry):
         at = pl.ds(pl.multiple_of(c * fc, fc), fc)
-        g = jnp.dot(x, wg_ref[:, at], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, wu_ref[:, at], preferred_element_type=jnp.float32)
-        acc_ref[...] += jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), wd_ref[at, :],
-                                preferred_element_type=jnp.float32)
+        act = _activation(x, w_refs, at).astype(x.dtype)
+        acc_ref[...] += jnp.dot(act, wd_ref[at, :], preferred_element_type=jnp.float32)
         return carry
 
-    jax.lax.fori_loop(0, wg_ref.shape[1] // fc, columns, 0)
+    jax.lax.fori_loop(0, wd_ref.shape[0] // fc, columns, 0)
     row = tile_ref[i] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
     mine = (row >= starts_ref[expert_ref[i]]) & (row < ends_ref[expert_ref[i]])
     o_ref[...] = jnp.where(mine, acc_ref[...] * gate_ref[...], o_ref[...])
@@ -222,17 +236,18 @@ def moe_grouped_experts(
     gate: jax.Array,    # [P] f32: each pair's gate
     sizes: jax.Array,   # [E] int: rows of each expert (they sum to the real pairs)
     layer,              # int32 scalar: the layer's place in the stacks
-    w_gate: jax.Array,  # [L, E, d, f]
+    w_gate: jax.Array | None,  # [L, E, d, f]; None: two-matrix relu^2 experts
     w_up: jax.Array,    # [L, E, d, f]
     w_down: jax.Array,  # [L, E, f, d]
     interpret: bool = False,
 ) -> jax.Array:
-    """gate x SwiGLU of each sorted row on its own expert: [P, d] f32. An
-    expert's three matrices, double-buffered, have to fit VMEM beside the row
-    tiles (44 of 128 MB at [3,584, 1,024] in bf16)."""
+    """gate x each sorted row's own expert of it: [P, d] f32. An
+    expert's matrices, double-buffered, have to fit VMEM beside the row
+    tiles (44 of 128 MB for three of [3,584, 1,024] in bf16)."""
     p, d = x.shape
-    f = w_gate.shape[-1]
-    isz = w_gate.dtype.itemsize
+    f = w_up.shape[-1]
+    ups = (w_up,) if w_gate is None else (w_gate, w_up)
+    isz = w_up.dtype.itemsize
     mult = 8 if x.dtype.itemsize >= 4 else 16
     tm = min(_ROW_TILE, -(-p // mult) * mult)
     pp = -(-p // tm) * tm
@@ -251,10 +266,9 @@ def moe_grouped_experts(
         num_scalar_prefetch=5,
         grid=(n,),
         in_specs=[pl.BlockSpec((tm, d), rows_map),
-                  pl.BlockSpec((tm, 1), rows_map),
-                  pl.BlockSpec((None, None, d, f), expert_map),
-                  pl.BlockSpec((None, None, d, f), expert_map),
-                  pl.BlockSpec((None, None, f, d), expert_map)],
+                  pl.BlockSpec((tm, 1), rows_map)]
+                 + [pl.BlockSpec((None, None, d, f), expert_map)] * len(ups)
+                 + [pl.BlockSpec((None, None, f, d), expert_map)],
         out_specs=pl.BlockSpec((tm, d), rows_map),
         scratch_shapes=[pltpu.VMEM((tm, d), jnp.float32)],
     )
@@ -265,14 +279,15 @@ def moe_grouped_experts(
         out_shape=jax.ShapeDtypeStruct((pp, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=(6 * d * f * isz + tm * d * (2 * x.dtype.itemsize + 12)
+            vmem_limit_bytes=(2 * (len(ups) + 1) * d * f * isz
+                              + tm * d * (2 * x.dtype.itemsize + 12)
                               + 4 * tm * fc * 4 + (8 << 20))),
         interpret=interpret,
         # a constant: the custom call's name in a device trace
         name="moe_grouped_experts",
     )(
         expert, tile, starts, ends, jnp.asarray(layer, jnp.int32).reshape(1),
-        x, gate.astype(jnp.float32)[:, None], w_gate, w_up, w_down,
+        x, gate.astype(jnp.float32)[:, None], *ups, w_down,
     )
     return out[:p]
 

@@ -1,10 +1,13 @@
 """The state-space (Mamba-2 / SSD) scan: its chunked form for prefill, its
 one-step form for decode, and the causal convolution with its carried tail.
 
-One layer, one token, head h of width P, state width N (one group):
+One layer, one token, head h of width P, state width N:
 
     a = exp(dt_h A_h)            S[h, p, n] <- a S[h, p, n] + dt_h x[h, p] B[n]
     y[h, p] = sum_n C[n] S[h, p, n]              (+ D_h x[h, p], the caller's)
+
+B and C are one group's, [N] a token, or G groups', [G, N] a token: head h
+then reads group h // (H / G).
 
 **Prefill** (``ssd_chunked``) computes the same in chunks of Q tokens: inside
 a chunk the outputs are one masked [Q, Q] product a head (the decays between
@@ -129,10 +132,21 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array, cm: ja
     """The recurrence over T positions in chunks of ``chunk``.
 
     x [B, T, H, P]; dt [B, T, H] f32 >= 0 (0 at a position that is not
-    real); a [H] f32 < 0; bm, cm [B, T, N]; s0 [B, H, P, N] f32, the state
-    before position 0. Returns (y [B, T, H, P] f32, the state after the last
-    position [B, H, P, N] f32). T is padded to whole chunks with dt = 0."""
+    real); a [H] f32 < 0; bm, cm [B, T, N], or [B, T, G, N] for G groups of
+    H / G heads each; s0 [B, H, P, N] f32, the state before position 0.
+    Returns (y [B, T, H, P] f32, the state after the last position
+    [B, H, P, N] f32). T is padded to whole chunks with dt = 0."""
     b, t, h, p = x.shape
+    if bm.ndim == 4:  # a group is the one-group recurrence over its own heads
+        g = bm.shape[2]
+
+        def split(z, axis):  # heads -> [G, H / G]
+            return z.reshape(z.shape[:axis] + (g, h // g) + z.shape[axis + 1:])
+
+        y, s = jax.vmap(lambda *group: ssd_chunked(*group, chunk),
+                        in_axes=(2, 2, 0, 2, 2, 1), out_axes=(2, 1))(
+            split(x, 2), split(dt, 2), split(a, 0), bm, cm, split(s0, 1))
+        return y.reshape(b, t, h, p), s.reshape(s0.shape)
     q = min(chunk, t)
     pad = -t % q
     if pad:
@@ -174,8 +188,9 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array, cm: ja
 
 
 def _heads_block(rows: int, n: int, lanes: int) -> int:
-    """Rows of heads one grid cell takes: the largest divisor of ``rows``
-    whose [rows, N, lanes] f32 block stays under ``_STATE_BLOCK_BYTES``."""
+    """Rows of heads one grid cell takes: the largest divisor of ``rows`` (the
+    rows of ONE group: a cell reads one B and one C) whose [rows, N, lanes]
+    f32 block stays under ``_STATE_BLOCK_BYTES``."""
     cap = max(1, _STATE_BLOCK_BYTES // (n * lanes * 4))
     return next(r for r in range(min(cap, rows), 0, -1) if rows % r == 0)
 
@@ -229,15 +244,17 @@ def ssm_state_step(pool: jax.Array, layer, live: LiveSlots, decay: jax.Array,
     ``[slots, L, H / k, N, k P]`` f32, in place (the pool is aliased onto the
     result: donate it). ``decay`` [slots, H] = exp(dt A) (1 for a live row that
     must keep its state), ``dtx`` [slots, H, P] = dt x (0 likewise), ``bm``,
-    ``cm`` [slots, N]. Returns (pool, y [slots, H, P] f32 = C . S after the
+    ``cm`` [slots, N], or [slots, G, N] for G groups of H / G heads each.
+    Returns (pool, y [slots, H, P] f32 = C . S after the
     update; zeros for a slot that is not live, whose state is not touched)."""
     slots, _, rows, n, lanes = pool.shape
     h, p = dtx.shape[1], dtx.shape[2]
-    hb = _heads_block(rows, n, lanes)
+    groups = bm.shape[1] if bm.ndim == 3 else 1
+    hb = _heads_block(rows // groups, n, lanes)
     nj = rows // hb
     a_row = jnp.repeat(decay.astype(jnp.float32), p, axis=1).reshape(slots, 1, h * p)
     u_row = dtx.astype(jnp.float32).reshape(slots, 1, h * p)
-    bc = jnp.stack([bm, cm], axis=-1).astype(jnp.float32)  # [slots, N, 2]
+    bc = jnp.stack([bm, cm], axis=-1).astype(jnp.float32)  # [slots, (G,) N, 2]
 
     def at(g, j, order_ref, n_ref):  # a place past the list stays on the last block
         return order_ref[g], jnp.where(g < n_ref[0], j, nj - 1)
@@ -247,7 +264,10 @@ def ssm_state_step(pool: jax.Array, layer, live: LiveSlots, decay: jax.Array,
         return (slot, 0, j)
 
     def bc_map(g, j, layer_ref, order_ref, n_ref):
-        return (order_ref[g], 0, 0)
+        if groups == 1:
+            return (order_ref[g], 0, 0)
+        slot, j = at(g, j, order_ref, n_ref)
+        return (slot, j * hb * groups // rows, 0, 0)  # the block's own group
 
     def state_map(g, j, layer_ref, order_ref, n_ref):
         slot, j = at(g, j, order_ref, n_ref)
@@ -258,7 +278,7 @@ def ssm_state_step(pool: jax.Array, layer, live: LiveSlots, decay: jax.Array,
         grid=(slots, nj),
         in_specs=[pl.BlockSpec((None, 1, hb * lanes), row_map),
                   pl.BlockSpec((None, 1, hb * lanes), row_map),
-                  pl.BlockSpec((None, n, 2), bc_map),
+                  pl.BlockSpec((None,) * (bc.ndim - 2) + (n, 2), bc_map),
                   pl.BlockSpec((None, None, hb, n, lanes), state_map)],
         out_specs=[pl.BlockSpec((None, None, hb, n, lanes), state_map),
                    pl.BlockSpec((None, 1, hb * lanes), row_map)],

@@ -86,7 +86,11 @@ def load_params_sharded(
         raise NotImplementedError(
             f"{cfg.arch}: no GGUF tensor-name map for state-space models yet; "
             "the tree to build is models.ssm_hybrid.init_params' (two stacks, "
-            "blocks.mamba and blocks.attn), placed by param_sharding_rules")
+            "blocks.mamba and blocks.attn"
+            + ("; a third, blocks.moe, for the layers of experts alone: the experts e "
+               "with e mod expert_parallel.count == rank, expert e at place e // count; "
+               "W_in's columns as w_in [z | xBC] and w_dt" if cfg.n_moe_layers else "")
+            + "), placed by param_sharding_rules")
     if cfg.n_win_layers:
         raise NotImplementedError(
             f"{cfg.arch}: no GGUF tensor-name map for window-attention models "

@@ -117,11 +117,21 @@ def _ssm_leaves(cfg: ModelConfig, dtype_bytes: int) -> dict[str, _Leaf]:
     out = {"embed": _Leaf((V, d), (), dtype_bytes),
            "lm_head": _Leaf((d, V), (), dtype_bytes, True)}
     ffn = {"w_gate": (d, ff), "w_up": (d, ff), "w_down": (ff, d)}
-    stacks = (("mamba", cfg.n_ssm_layers, {
+    stacks = [("mamba", cfg.n_ssm_layers, {
                   "w_in": (d, di + cfg.ssm_conv_dim), "w_dt": (d, cfg.ssm_n_heads),
                   "w_out": (di, d)}),
               ("attn", cfg.n_kv_layers, {"wq": (d, hq * hd), "wk": (d, hkv * hd),
-                                         "wv": (d, hkv * hd), "wo": (hq * hd, d)}))
+                                         "wv": (d, hkv * hd), "wo": (hq * hd, d)})]
+    if cfg.n_moe_layers:
+        # one sublayer a layer: no MLP behind a mixer, and a third stack of
+        # two-matrix experts (those held here) in a latent
+        e, eh, w = cfg.n_experts, cfg.n_experts_held, cfg.moe_latent
+        fe, fs = cfg.moe_d_ff, cfg.n_shared_experts * cfg.moe_d_ff
+        ffn = {}
+        stacks.append(("moe", cfg.n_moe_layers, {
+            "router": (d, e), "w_lat_down": (d, w), "w_lat_up": (w, d),
+            "w_up_e": (eh, w, fe), "w_down_e": (eh, fe, w),
+            "w_up_s": (d, fs), "w_down_s": (fs, d)}))
     for name, L, mixer in stacks:
         for k, shape in (mixer | ffn).items() if L else ():
             out[f"blocks.{name}.{k}"] = _Leaf((L,) + shape, (), dtype_bytes, quantizable(k))

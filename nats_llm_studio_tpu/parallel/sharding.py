@@ -107,9 +107,11 @@ def _mla_rules(cfg: ModelConfig, ep, pp) -> dict[str, P]:
 
 
 def _ssm_rules(pp) -> dict[str, P]:
-    """A rule for every leaf ``models.ssm_hybrid.init_params`` makes: the two
+    """A rule for every leaf ``models.ssm_hybrid.init_params`` makes: the
     stacks' layer axis on pp, everything else whole (the family is served on
-    one chip a replica: ``validate_mesh_for_config`` refuses a mesh over it)."""
+    one chip a replica: ``validate_mesh_for_config`` refuses a mesh over it;
+    the experts a chip holds of a layer are the model's own, as in
+    ``_gdn_rules``)."""
     ffn = {"mix_norm": 1, "ffn_norm": 1, "w_gate": 2, "w_up": 2, "w_down": 2}
     mamba = ffn | {"w_in": 2, "w_dt": 2, "conv_w": 2, "conv_b": 1, "dt_bias": 1, "a_log": 1,
                    "d_skip": 1, "gate_norm": 1, "w_out": 2}
@@ -117,6 +119,10 @@ def _ssm_rules(pp) -> dict[str, P]:
     rules = {"embed": P(None, None), "out_norm": P(None), "lm_head": P(None, None)}
     rules |= {f"blocks.mamba.{k}": P(pp, *[None] * r) for k, r in mamba.items()}
     rules |= {f"blocks.attn.{k}": P(pp, *[None] * r) for k, r in attn.items()}
+    # a layer of experts alone: two-matrix experts in a latent
+    moe = {"mix_norm": 1, "router": 2, "e_bias": 1, "w_lat_down": 2, "w_lat_up": 2,
+           "w_up_e": 3, "w_down_e": 3, "w_up_s": 2, "w_down_s": 2}
+    rules |= {f"blocks.moe.{k}": P(pp, *[None] * r) for k, r in moe.items()}
     return rules
 
 
@@ -319,6 +325,9 @@ def validate_mesh_for_config(mesh: Mesh, cfg: ModelConfig,
             f"state-space models ({cfg.arch}) serve on one chip a replica "
             "(MESH_SHAPE=off): the scan's heads and the per-slot state pool "
             "have no mesh split yet"
+            + (", and the chip's share of a layer's latent experts is the model's own "
+               "(expert_parallel.count / rank in its header), with no exchange"
+               if cfg.n_moe_layers else "")
         )
     if cfg.is_sala and mesh.size > 1:
         raise ValueError(

@@ -910,6 +910,14 @@ class ContinuousBatcher:
             if spec_decode_k > 0:
                 self.refusals["spec_decode"] = why["spec_decode"]
                 spec_decode_k = 0
+        if cfg.n_expert_only_layers and not all(
+                isinstance(params["blocks"]["moe"][k], jax.Array)
+                for k in ("w_up_e", "w_down_e")):
+            raise ValueError(
+                f"{cfg.arch}: WQUANT=int8 is not implemented for two-matrix experts "
+                "in a latent (the hit-list and the grouped kernel read plain stacks, "
+                "and the dense dispatch would stream every held expert every step): "
+                "unset WQUANT")
         self._pool: BlockPool | None = None
         # the books of what a slot keeps beside its blocks: a state-space
         # family's state pool, a window-attention family's rings
@@ -1272,14 +1280,15 @@ class ContinuousBatcher:
 
     def _chunk_attrs(self, start: int, lens: list[int], width: int = 1) -> dict | None:
         """What a chunk launch's ``batcher.admit`` record carries, for a
-        latent or a linear-attention family (None otherwise). ``lens``: each
+        latent or a linear-attention family or a state-space family with
+        layers of experts (None otherwise). ``lens``: each
         row's real tokens from ``start`` on; ``width``: the rows the launch
         computes. ``rows`` are
         those that hold tokens of their prompt in this chunk, ``tokens``
         theirs (at most a chunk a row), ``live_keys`` the keys a row's chunk
         attends over (its prefix through this chunk) summed over the rows,
         ``pairs`` the (query, key) pairs of their causal attention."""
-        if not (self.cfg.is_mla or self.cfg.n_lin_layers):
+        if not (self.cfg.is_mla or self.cfg.n_lin_layers or self.cfg.n_expert_only_layers):
             return None
         real = [min(n, self.prefill_chunk) for n in lens if n > 0]
         return {"rows": len(real), "width": width, "tokens": sum(real),

@@ -7,6 +7,7 @@ family's, and a second star import would keep the later of the two only
 
 from benchmark.tests.test_discovery import *  # noqa: F401,F403
 from benchmark.tests.test_gdn_readers import *  # noqa: F401,F403
+from benchmark.tests.test_lmoe_readers import *  # noqa: F401,F403
 from benchmark.tests.test_mla_long_readers import *  # noqa: F401,F403
 from benchmark.tests.test_moe_prefill_chunk_ms import (  # noqa: F401
     test_the_chunk_group_reader_divides_whole_launches_only,

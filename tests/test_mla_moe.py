@@ -221,7 +221,7 @@ def test_the_hit_list_is_the_dense_dispatch_over_the_experts_hit(case, dtype, mo
     assert mla_moe.expert_path(cfg, 8, stacks) == "hit_list"
     dense, _ = mla_moe.moe_ffn(h, p | {k: v[place] for k, v in stacks.items()}, cfg, live)
     got, stats = jax.jit(lambda: mla_moe.moe_ffn(
-        h, p, cfg, live, "hit_list", tuple(stacks[k] for k in mla_moe.EXPERT_LEAVES), place))()
+        h, p, cfg, live, "hit_list", tuple(stacks[k] for k in experts.EXPERT_LEAVES), place))()
     assert stats.tolist() == [n_hit, rows_max, int(sum(live))]
     from nats_llm_studio_tpu.ops.layers import swiglu
 
@@ -270,7 +270,7 @@ def test_the_grouped_form_is_the_dense_dispatch_without_its_zero_terms(how, rows
     dense, _ = jax.jit(lambda: mla_moe.moe_ffn(
         h, p | {k_: v[place] for k_, v in stacks.items()}, cfg))()
     got, stats = jax.jit(lambda: mla_moe.moe_ffn(
-        h, p, cfg, None, "grouped", tuple(stacks[k_] for k_ in mla_moe.EXPERT_LEAVES), place))()
+        h, p, cfg, None, "grouped", tuple(stacks[k_] for k_ in experts.EXPERT_LEAVES), place))()
     assert stats is None and got.shape == dense.shape
     scale = float(np.abs(np.asarray(dense)).max())
     np.testing.assert_allclose(np.asarray(got), np.asarray(dense), rtol=0, atol=2e-5 * scale)
@@ -297,7 +297,7 @@ def test_the_expert_path_is_chosen_from_shapes_leaf_types_and_devices(rows, leav
 
     cfg = REF.model_config(CONF, SEQ).with_(n_experts=64, n_experts_used=4)
     w = jnp.ones((1, 64, 8, 8), jnp.bfloat16)
-    stack = {k: w if leaves == "plain" else quantize_weight(w) for k in mla_moe.EXPERT_LEAVES}
+    stack = {k: w if leaves == "plain" else quantize_weight(w) for k in experts.EXPERT_LEAVES}
     mesh = build_mesh({"tp": devices}, devices=jax.local_devices()[:devices])
     assert mla_moe.expert_path(cfg, rows, stack, mesh) == path
     if devices > 1:   # the mesh alone made it dense

@@ -26,7 +26,8 @@ ROOT = Path(__file__).resolve().parent.parent
 FAMILIES = {"dense": None, "latent": ("mla_moe_mhc", "tiny-mla"),
             "latent_plain": ("mla_moe_plain", "tiny-mla-plain"),
             "state": ("ssm_hybrid", "tiny-ssm"), "window": ("swa_gated_moe", "tiny-swa"),
-            "linear": ("gdn_moe", "tiny-gdn"), "lightning": ("sala", "tiny-sala")}
+            "linear": ("gdn_moe", "tiny-gdn"), "lightning": ("sala", "tiny-sala"),
+            "latent_experts": ("ssm_latent_moe", "tiny-nemotron")}
 SEQ, BLOCK, SLOTS, CHUNK, BURST = 64, 16, 2, 32, 2
 SCOPED_FILES = ("models/llama.py", "models/mla_moe.py", "models/ssm_hybrid.py",
                 "models/swa_moe.py", "models/gdn_moe.py", "models/sala.py", "models/experts.py",
@@ -143,6 +144,9 @@ def test_every_product_and_kernel_lies_under_a_scope(family, program):
         assert "seq/window" in seen and "ffn/experts" in seen
     if family == "linear":
         assert {"seq/linear", "seq/attn", "ffn/router", "ffn/experts", "ffn/shared"} <= seen
+    if family == "latent_experts":   # one sublayer a layer: no dense MLP behind a mixer
+        assert {"seq/ssm", "seq/attn", "ffn/router", "ffn/experts", "ffn/shared",
+                "ffn/latent_down", "ffn/latent_up"} <= seen and "ffn/mlp" not in seen
     if family == "lightning":
         assert {"seq/linear", "seq/sparse", "seq/sparse/pool", "ffn/mlp"} <= seen
         # a decode step always scores and picks; a chunk of 32 into a context

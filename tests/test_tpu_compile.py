@@ -1575,3 +1575,139 @@ def test_a_chunk_of_the_lightning_family_at_the_cells_shapes(
     assert ma.temp_size_in_bytes < 2.5e9, ma.temp_size_in_bytes
     print(f"\nwidth {width}: temp {ma.temp_size_in_bytes / 1e6:.0f} MB, "
           f"alias {ma.alias_size_in_bytes / 1e6:.0f} MB")
+
+
+# -- the state-space family with layers of latent experts at nemotron3super.agent64_closed's shapes --
+
+
+LMOE_SLOTS = 64
+
+
+@pytest.fixture(scope="module")
+def lmoe_cell():
+    """(cfg, the served tree's shapes) of ``benchmark/configs/nemotron-3-super-120b-a12b.json``."""
+    import json
+    from pathlib import Path
+
+    from benchmark import run
+
+    root = Path(__file__).resolve().parents[1]
+    ref = run.load_module(root / "benchmark/references/ssm_latent_moe.py")
+    conf = json.loads((root / "benchmark/configs/nemotron-3-super-120b-a12b.json").read_text())
+    cfg = ref.model_config(conf, SEQ)
+    return cfg, ref.param_shapes(cfg)
+
+
+def _lmoe_pools(cfg, sharding):
+    """The cell's pools: 64 x 256 + 1 blocks of the one attention layer, each
+    with the 64 slots' state of the five Mamba-2 layers beside it."""
+    from nats_llm_studio_tpu.models import ssm_hybrid
+    from nats_llm_studio_tpu.ops.kvcache import WithState
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    (h, w), _ = cfg.kv_cache_dims()
+    nb = LMOE_SLOTS * (SEQ // T) + 1
+    (tail, seen), (plane,) = ssm_hybrid.state_shapes(cfg, LMOE_SLOTS)
+    kv = lambda: sds((nb, cfg.n_kv_layers, h, T, w), jnp.bfloat16)  # noqa: E731
+    return (WithState(kv(), (sds(tail, jnp.bfloat16), sds(seen, jnp.int32)), ssm_hybrid.K_AXES),
+            WithState(kv(), (sds(plane, jnp.float32),), ssm_hybrid.V_AXES))
+
+
+def test_a_decode_burst_of_the_latent_expert_family_copies_no_pool_and_no_stack(
+        one_chip, no_cache, lmoe_cell):
+    """The burst decode program as ``serve/programs.py`` builds it for a
+    family with expert layers (eight steps, their sampling and the expert
+    counters), all 11 layers over the cell's pools, donated: the (mamba,
+    experts) pair scanned three times and the five layers after it on their
+    own, the state kernel at 8 groups, the paged attention kernel and a
+    two-matrix expert kernel in it under their names, the pools aliased onto
+    the results, no ``copy`` of the float32 state pool, of the convolution
+    tails or of the KV pool, and no layer's slice of the expert stacks (1.4 GB)
+    among the temporaries. It fits the chip beside 9.3 GB of weights."""
+    from nats_llm_studio_tpu.engine.sampling import sample_rows
+    from nats_llm_studio_tpu.serve.programs import build_programs
+
+    cfg, shapes = lmoe_cell
+    assert (cfg.n_ssm_layers, cfg.n_moe_layers, cfg.n_kv_layers) == (5, 5, 1)
+    kp, vp = _lmoe_pools(cfg, one_chip)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    row = lambda dt, *more: jax.ShapeDtypeStruct(  # noqa: E731
+        (LMOE_SLOTS,) + more, dt, sharding=one_chip)
+    ints, floats = row(jnp.int32), row(jnp.float32)
+    table = build_programs(cfg, None, max_seq=SEQ, paged=True, kv_block_tokens=T,
+                           sample_rows=sample_rows)
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"   # the kernels themselves, not the interpreter
+    try:
+        compiled = table["decode_pallas"].lower(
+            jax.tree.map(sds, shapes), ints, kp, vp, row(jnp.int32, SEQ // T), ints, ints, ints,
+            floats, ints, floats, 8).compile()
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    assert "ssm_state_step" in text and "paged_decode_attention" in text
+    assert "moe_hit_experts" in text or "moe_grouped_experts" in text
+    state, kv, tails = vp.st[0], kp.kv, kp.st[0]
+    assert state.shape == (LMOE_SLOTS, 5, 64, 128, 128)
+    pools = (f"f32[{','.join(map(str, state.shape))}]", f"bf16[{','.join(map(str, kv.shape))}]",
+             f"bf16[{','.join(map(str, tails.shape))}]")
+    # a copy-start whose target lies in another memory space (S(1)) is the
+    # compiler's prefetch of the 26 MB of tails, not a second pool in HBM
+    copies = [ln.strip()[:160] for ln in text.splitlines()
+              if (" copy(" in ln or "copy-start(" in ln) and any(p in ln for p in pools)
+              and "S(1)" not in ln]
+    assert not copies, copies
+    assert not _expert_stack_copies(text, cfg)
+    ma = compiled.memory_analysis()
+    state_bytes = int(np.prod(state.shape)) * 4
+    assert ma.alias_size_in_bytes >= state_bytes + 2 * int(np.prod(kv.shape)) * 2
+    assert ma.temp_size_in_bytes < state_bytes // 8
+    assert (ma.argument_size_in_bytes + ma.temp_size_in_bytes) < 15 * 2**30
+
+
+def _expert_stack_copies(text: str, cfg) -> list[str]:
+    """Lines that make a layer's slice (or all) of an expert stack anew."""
+    e, w, f = cfg.n_experts_held, cfg.moe_latent, cfg.moe_d_ff
+    shapes = [f"{e},{w},{f}]", f"{e},{f},{w}]"]
+    made = (" copy(", "copy-start(", " dynamic-slice(")
+    return [ln.strip()[:160] for ln in text.splitlines()
+            if any(m in ln for m in made)
+            and any(x in ln.partition(" = ")[2][:80] for x in shapes)]
+
+
+@pytest.mark.parametrize("width", [1, 4], ids=["prefill1", "chunk_group_of_4"])
+def test_a_prefill_chunk_of_the_latent_expert_family_fits_and_copies_no_stack(
+        one_chip, no_cache, lmoe_cell, width):
+    """A chunk of 256 tokens x ``width`` prompts through the cut model: the
+    chunked scan at 8 groups of 16 heads in chunks of 128, the grouped
+    two-matrix kernel between the latent pair, the expert stacks read where
+    they lie."""
+    from nats_llm_studio_tpu.models import llama, ssm_hybrid
+    from nats_llm_studio_tpu.ops.kvcache import WithState
+
+    cfg, shapes = lmoe_cell
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    (h, w), _ = cfg.kv_cache_dims()
+    (tail, seen), (plane,) = ssm_hybrid.state_shapes(cfg, width)
+    kv = lambda: s((width, cfg.n_kv_layers, h, SEQ, w), jnp.bfloat16)  # noqa: E731
+    caches = (WithState(kv(), (s(tail, jnp.bfloat16), s(seen, jnp.int32)), ssm_hybrid.K_AXES),
+              WithState(kv(), (s(plane, jnp.float32),), ssm_hybrid.V_AXES))
+    ints = lambda *shape: s(shape, jnp.int32)  # noqa: E731
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        compiled = _compile(
+            lambda params, tokens, k, v, start, last: llama.forward(
+                params, cfg, tokens, k, v, start, logit_positions=last, uniform_start=True),
+            jax.tree.map(sds, shapes), ints(width, CHUNK), *caches, ints(width), ints(width))
+    finally:
+        jax.default_backend = orig
+    text = compiled.as_text()
+    assert text.count("moe_grouped_experts") >= 1 and "moe_hit_experts" not in text
+    assert not _expert_stack_copies(text, cfg)
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 2 * 2**30   # 1.3 GB at width 4: the chunk's activations
+    assert (ma.argument_size_in_bytes + ma.temp_size_in_bytes) < 15 * 2**30
