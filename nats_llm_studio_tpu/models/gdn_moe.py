@@ -66,22 +66,19 @@ from ..ops.layers import gqa_attention, gqa_attention_hmajor, rms_norm, rope_cos
 from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
 from .experts import expert_path, moe_ffn, split_stacks, stats_width
-from .ssm_hybrid import V_AXES, _embed, _layers, state_bytes
+from .ssm_hybrid import K_AXES, V_AXES, _embed, _layers, state_bytes, zeroed_state
 from .swa_moe import _rotate
 
 Params = dict[str, Any]
 
 
-# the rows' axis of K's state leaves (the tails, ``seen``); V's is ``ssm_hybrid``'s
-K_AXES = (2, 0)
-
-
 def state_shapes(cfg: ModelConfig, rows: int) -> tuple[tuple, tuple]:
     """((tail shape, seen shape), (state shape,)) for ``rows`` rows. The tails
-    lie a tap a plane, [Ll, K, rows, C], not ``ssm_hybrid``'s [Ll, rows, K, C]:
-    a decode step shifts and weighs a tap of ALL the slots at once, the slots
-    on the sublanes, and with the taps there instead (K = 4 of a bf16 tile's
-    rows) both XLA and a kernel gather row by row (PERF.md section 6, PR 48)."""
+    lie a tap a plane, [Ll, K, rows, C], as ``ssm_hybrid``'s do (its ``K_AXES``
+    are this family's): a decode step shifts and weighs a tap of ALL the slots
+    at once, the slots on the sublanes, and with the taps there instead (K = 4
+    of a bf16 tile's rows) both XLA and a kernel gather row by row (PERF.md
+    section 6, PR 48)."""
     ll = cfg.n_lin_layers
     return (((ll, cfg.ssm_conv, rows, cfg.lin_conv_dim), (rows,)),
             ((rows, ll, cfg.lin_v_heads, cfg.lin_k_dim, cfg.lin_v_dim),))
@@ -89,9 +86,7 @@ def state_shapes(cfg: ModelConfig, rows: int) -> tuple[tuple, tuple]:
 
 def make_state(cfg: ModelConfig, rows: int):
     """Zeroed state for ``rows`` rows: (K's ``st``, its axes), (V's, its)."""
-    (tail, seen), (plane,) = state_shapes(cfg, rows)
-    return (((jnp.zeros(tail, jnp.dtype(cfg.dtype)), jnp.zeros(seen, jnp.int32)), K_AXES),
-            ((jnp.zeros(plane, jnp.float32),), V_AXES))
+    return zeroed_state(cfg, state_shapes(cfg, rows))
 
 
 def state_bytes_per_slot(cfg: ModelConfig) -> int:
@@ -160,10 +155,9 @@ def linear_prefill(h, p: Params, cfg: ModelConfig, tails, states, layer, valid):
     t = h.shape[1]
     zero = jnp.zeros((), jnp.int32)
     qkv, z, b, a = _project_in(h, p, cfg)
-    tail = jnp.swapaxes(jax.lax.dynamic_index_in_dim(tails, layer, axis=0, keepdims=False), 0, 1)
+    tail = jax.lax.dynamic_index_in_dim(tails, layer, axis=0, keepdims=False)
     qkv, tail = ssm_scan.causal_conv(qkv, tail, p["conv_w"], None, valid)
-    tails = jax.lax.dynamic_update_slice(
-        tails, jnp.swapaxes(tail, 0, 1)[None], (layer, zero, zero, zero))
+    tails = jax.lax.dynamic_update_slice(tails, tail[None], (layer, zero, zero, zero))
     q, k, v = _split_conv(qkv, cfg)
     real = (jnp.arange(t, dtype=jnp.int32)[None, :] < valid[:, None])[..., None]
     beta, log_alpha = _gates(b, a, p)
@@ -180,10 +174,9 @@ def linear_step_xla(h, p: Params, cfg: ModelConfig, tails, states, layer, live, 
     it). ``fresh`` [B] bool."""
     zero = jnp.zeros((), jnp.int32)
     qkv, z, b, a = _project_in(h[:, 0], p, cfg)
-    tail = jnp.swapaxes(jax.lax.dynamic_index_in_dim(tails, layer, axis=0, keepdims=False), 0, 1)
+    tail = jax.lax.dynamic_index_in_dim(tails, layer, axis=0, keepdims=False)
     qkv, tail = ssm_scan.conv_step(qkv, tail, p["conv_w"], None, fresh)
-    tails = jax.lax.dynamic_update_slice(
-        tails, jnp.swapaxes(tail, 0, 1)[None], (layer, zero, zero, zero))
+    tails = jax.lax.dynamic_update_slice(tails, tail[None], (layer, zero, zero, zero))
     q, k, v = _split_conv(qkv, cfg)
     beta, log_alpha = _gates(b, a, p)
     decay = jnp.where(fresh[:, None], jnp.exp(log_alpha), 1.0)

@@ -74,9 +74,12 @@ from ..ops.layers import apply_rope, gqa_attention, gqa_attention_hmajor, rms_no
 from ..ops.wquant import flat_rows, mm
 from .config import ModelConfig
 from .gdn_moe import _attn_out
-from .ssm_hybrid import K_AXES, V_AXES, _embed, _layers, state_bytes, zeroed_state
+from .ssm_hybrid import V_AXES, _embed, _layers, state_bytes, zeroed_state
 
 Params = dict[str, Any]
+
+# the rows' axis of K's state leaves (the pooled keys, layer-major; ``seen``)
+K_AXES = (1, 0)
 
 _NEG = -1e30
 # keys one turn of ``masked_attention``'s loop takes, in sparse blocks
@@ -109,7 +112,7 @@ def state_shapes(cfg: ModelConfig, rows: int) -> tuple[tuple, tuple]:
 
 def make_state(cfg: ModelConfig, rows: int):
     """Zeroed state for ``rows`` rows: (K's ``st``, its axes), (V's, its)."""
-    return zeroed_state(cfg, state_shapes(cfg, rows))
+    return zeroed_state(cfg, state_shapes(cfg, rows), K_AXES)
 
 
 def state_bytes_per_slot(cfg: ModelConfig) -> int:

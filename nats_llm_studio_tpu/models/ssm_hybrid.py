@@ -26,8 +26,9 @@ every other family uses. What differs:
 * **A mamba layer keeps a state in place of KV**, indexed by slot and not by
   table: ``ops.kvcache.WithState`` carries it beside each cache of the pair.
   K's ``st`` is (the convolution's last ``ssm_conv`` raw inputs
-  [n_ssm_layers, rows, K, conv_dim], layer-major as the layer scan writes
-  them, ``seen`` [rows] int32: how many positions the state has consumed),
+  [n_ssm_layers, K, rows, conv_dim], layer-major as the layer scan writes
+  them and a tap a plane with the rows on the sublanes (``state_shapes``),
+  ``seen`` [rows] int32: how many positions the state has consumed),
   V's ``st`` is (the state [rows, n_ssm_layers, H / k, N, k P] float32,
   ``ops/ssm_scan.py``'s plane).
   Prefill runs the chunked scan and returns the state after the last REAL
@@ -95,22 +96,26 @@ def period_plan(cfg: ModelConfig) -> tuple[int, tuple[str, ...]]:
 
 
 # where the leaves of K's and V's ``st`` have their row axis
-K_AXES, V_AXES = (1, 0), (0,)
+K_AXES, V_AXES = (2, 0), (0,)
 
 
 def state_shapes(cfg: ModelConfig, rows: int) -> tuple[tuple, tuple]:
-    """((tail shape, seen shape), (state shape,)) for ``rows`` rows."""
+    """((tail shape, seen shape), (state shape,)) for ``rows`` rows. The tails
+    lie a tap a plane, [Lm, K, rows, C]: a decode step shifts and weighs a tap
+    of ALL the slots at once, the slots on the sublanes. With the taps there
+    instead (K = 4 of a bf16 tile's 16 rows) the device gathers them row by
+    row in every layer (PERF.md section 6, PR 48 and PR 57)."""
     lm = cfg.n_ssm_layers
     plane = ssm_scan.state_plane(cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_d_state)
-    return (((lm, rows, cfg.ssm_conv, cfg.ssm_conv_dim), (rows,)), ((rows, lm) + plane,))
+    return (((lm, cfg.ssm_conv, rows, cfg.ssm_conv_dim), (rows,)), ((rows, lm) + plane,))
 
 
-def zeroed_state(cfg: ModelConfig, shapes):
+def zeroed_state(cfg: ModelConfig, shapes, k_axes=K_AXES):
     """Zeroed state of ``shapes`` (a family's ``state_shapes``): (K's ``st``,
-    its axes), (V's, its): the tail in the serving dtype and ``seen`` beside
-    K, the float32 state beside V."""
+    its axes ``k_axes``), (V's, its): the K leaf in the serving dtype and
+    ``seen`` beside K, the float32 state beside V."""
     (tail, seen), (plane,) = shapes
-    return (((jnp.zeros(tail, jnp.dtype(cfg.dtype)), jnp.zeros(seen, jnp.int32)), K_AXES),
+    return (((jnp.zeros(tail, jnp.dtype(cfg.dtype)), jnp.zeros(seen, jnp.int32)), k_axes),
             ((jnp.zeros(plane, jnp.float32),), V_AXES))
 
 
@@ -197,7 +202,7 @@ def _mixer_out(y: jax.Array, x: jax.Array, z: jax.Array, p: Params, cfg: ModelCo
 
 
 def mamba_prefill(h, p: Params, cfg: ModelConfig, tails, states, layer, valid):
-    """The mixer over T positions of B rows: ``tails`` [Lm, B, K, C] and
+    """The mixer over T positions of B rows: ``tails`` [Lm, K, B, C] and
     ``states`` [B, Lm, H/k, N, kP] are the rows' state of all layers, this
     one's slice read and written at ``layer``. ``valid`` [B]: real positions
     of each row."""

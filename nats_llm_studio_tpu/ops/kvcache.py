@@ -352,7 +352,9 @@ class WithState:
     axes: where each leaf of ``st`` has its row axis (static). A leaf that a
         layer scan updates a layer at a time is laid out layer-major
         ([layers, rows, ...], axis 1): the device would re-lay it out so on
-        every dispatch otherwise.
+        every dispatch otherwise. The convolution tails of the state-space and
+        gated-delta-rule layers lie a tap a plane under the layer
+        ([layers, K, rows, C], axis 2): a step works on a tap of all the rows.
     """
 
     kv: jax.Array

@@ -91,32 +91,37 @@ def unpack_state(s: jax.Array, k: int) -> jax.Array:
 def causal_conv(xbc: jax.Array, tail: jax.Array, w: jax.Array, b: jax.Array | None,
                 valid: jax.Array) -> tuple[jax.Array, jax.Array]:
     """silu(depthwise causal conv + bias) over ``xbc`` [B, T, C] that goes on
-    from ``tail`` [B, K, C], the K raw inputs before it (zeros at a start).
-    ``w`` [K, C]: w[K - 1] weighs the position itself; ``b`` None: no bias
-    (the gated-delta-rule layers'). ``valid`` [B]: how many
-    of a row's T positions are real; the new tail is the K raw inputs ending
-    at the last real one (the old tail where none is)."""
+    from ``tail`` [K, B, C], the K raw inputs before it a tap a plane (zeros
+    at a start), as the pools keep them. ``w`` [K, C]: w[K - 1] weighs the
+    position itself; ``b`` None: no bias (the gated-delta-rule layers').
+    ``valid`` [B]: how many of a row's T positions are real; the new tail is
+    the K raw inputs ending at the last real one (the old tail where none
+    is)."""
     k = w.shape[0]
     t = xbc.shape[1]
-    ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)  # [B, K + T, C]
+    # [B, K + T, C]: a chunk's few rows of tail beside its T positions
+    ext = jnp.concatenate([jnp.swapaxes(tail, 0, 1).astype(xbc.dtype), xbc], axis=1)
     wf = w.astype(jnp.float32)
     bias = None if b is None else b.astype(jnp.float32)
     out = sum(wf[j] * ext[:, 1 + j: 1 + j + t].astype(jnp.float32) for j in range(k))
     if bias is not None:
         out = bias + out
-    new_tail = jax.vmap(lambda e, v: jax.lax.dynamic_slice_in_dim(e, v, k, axis=0))(ext, valid)
+    new_tail = jax.vmap(lambda e, v: jax.lax.dynamic_slice_in_dim(e, v, k, axis=0),
+                        out_axes=1)(ext, valid)
     return jax.nn.silu(out).astype(xbc.dtype), new_tail.astype(tail.dtype)
 
 
 def conv_step(xbc: jax.Array, tail: jax.Array, w: jax.Array, b: jax.Array | None,
               fresh: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """One position a row: ``xbc`` [B, C], ``tail`` [B, K, C]. A ``fresh`` row
-    shifts its input in; a row that replays its last position finds it there
-    already and reads the tail as it is."""
-    shifted = jnp.concatenate([tail[:, 1:], xbc[:, None].astype(tail.dtype)], axis=1)
-    tail = jnp.where(fresh[:, None, None], shifted, tail)
+    """One position a row: ``xbc`` [B, C], ``tail`` [K, B, C], a tap a plane
+    with the rows on the sublanes (K = 4 taps there are a quarter of a bf16
+    tile, and the device gathers them row by row). A ``fresh`` row shifts its
+    input in as the last plane; a row that replays its last position finds it
+    there already and reads the tail as it is."""
+    shifted = jnp.concatenate([tail[1:], xbc[None].astype(tail.dtype)], axis=0)
+    tail = jnp.where(fresh[None, :, None], shifted, tail)
     bias = None if b is None else b.astype(jnp.float32)
-    out = jnp.sum(w.astype(jnp.float32)[None] * tail.astype(jnp.float32), axis=1)
+    out = jnp.sum(w.astype(jnp.float32)[:, None] * tail.astype(jnp.float32), axis=0)
     if bias is not None:
         out = bias + out
     return jax.nn.silu(out).astype(xbc.dtype), tail

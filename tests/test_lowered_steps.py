@@ -30,7 +30,19 @@ the sixteen digests of ``tiny-ssm``, ``tiny-swa``, ``tiny-mla-plain``,
 one ``optimization_barrier`` a kind of layer that projects q / k / v; the
 parent's are in ``CHANGES.md``), the four of the dense family did not move,
 and ``test_holding_the_products_apart_changes_no_value`` runs every moved
-program with the barrier and without it."""
+program with the barrier and without it.
+
+PR 57 laid the state-space family's convolution tails a tap a plane
+([Lm, K, rows, C], as ``tiny-gdn``'s lay since PR 48) and gave
+``ops/ssm_scan.py conv_step`` / ``causal_conv`` the tail as [K, B, C]: the
+digests of ``tiny-ssm`` (decode, prefill) and of ``tiny-gdn``'s prefill (its
+two ``swapaxes`` a linear layer are gone) are PR 57's, and ``tiny-nemotron``
+(the same family at two groups with layers of experts alone) came in with its
+three; the parent's are in ``CHANGES.md``. ``tiny-gdn``'s decode steps (the
+Pallas prologue), ``tiny-sala`` (its K state is pooled keys, rows on axis 1)
+and the dense, window and latent families did not move.
+``tests/test_ssm_hybrid.py`` holds the moved programs' VALUES to the parent's
+(``test_a_served_stream_is_the_stream_the_parent_commit_served``)."""
 import hashlib
 import json
 import re
@@ -50,8 +62,8 @@ T, SEQ, B = 16, 128, 2
 # the toys' references by name: the benchmark's own, then the rehearsal's
 REHEARSAL = run.Manifest(ROOT / "benchmark/tests/rehearsal/manifest.json")
 LOWERED_SHA = {
-    ("tiny-ssm", "decode"): "a219fe75757fadf74eb84dbb1ccd54cca9a798e2034aa028c3e72a4480405331",
-    ("tiny-ssm", "prefill"): "14bb40291119d010c0f5dab02058109cf4532f8a218951542967a7217a996031",
+    ("tiny-ssm", "decode"): "b40678e4cfc2450d28dc4f67794da99ba094d9e6b3743ef53504fc9ae818bdb0",
+    ("tiny-ssm", "prefill"): "01b06b666ffc2755d8ea88602aae486da030c92c5423020257eeda9ab3ed76ee",
     ("tiny-swa", "decode"): "693c8e351f5caa379d41cf9372395060b02666c04527753014801572318b4aa8",
     ("tiny-swa", "decode_counted"): "15a440d80007c6fd1a517cd524438c85d27a8a5988e52367394cd13de3fe0635",
     ("tiny-swa", "prefill"): "e6636ec1d835c54ed1e556bd37a1ae85cf89f0503ec82d6313a5a6ad2e1cacab",
@@ -63,13 +75,16 @@ LOWERED_SHA = {
     ("tiny-mla", "prefill"): "374776daf2483687dda6a92831f62d86a52edb8ef63af19c7fe70b728b3a6daa",
     ("tiny-gdn", "decode"): "4311d3242d157f2dfc781f3bc38f71091d25489e96ab3e96e4ea48ad05bdfe1e",
     ("tiny-gdn", "decode_counted"): "b1fe07b14d238b80cba7e787fff0c51ac7eaa044c6881634da153e15a45abb61",
-    ("tiny-gdn", "prefill"): "4051ff8cbfb3a1be423346caaad8bd74f3089c781806f067868115f3583ce601",
+    ("tiny-gdn", "prefill"): "2abedf16cd907235189db54096170f8d4df1aa57cc615d59e26d6e16bdef2326",
     ("tiny-sala", "decode"): "fbcb55dc371bdbf6422c04f608feaa95b92715fe4f39692465244288688aeb83",
     ("tiny-sala", "prefill"): "f80b9b2ca2eeef6e0625c11d429679520cc4d21b9537924828919f2c47f47d61",
     ("tiny-granite", "decode"): "4d3a0abc45bc4b9d95e5d2ca7eaba9822dacf8f5f632a44883d9e2a154649e17",
     ("tiny-granite", "prefill"): "4fad5d90689e3b2428d669cd5fbfc29370f0a76ea11f1cde122fccf5907a83d8",
     ("tiny-bias", "decode"): "fbf069912e6e976a88066bf91e754088b5849234f493369ab3f97df205094285",
     ("tiny-bias", "prefill"): "68c6e487c621d9c3a7cdc731ad36f0f4be66871daf0b5ae3b6e4b154566a53a7",
+    ("tiny-nemotron", "decode"): "fba9e9b5ee93680a3016092dd53c0cf42bb34f90697eb68dfacd08a39cc9f16f",
+    ("tiny-nemotron", "decode_counted"): "72f2d743918ec97b1bc45a38d4392d943c09f63fbe49774c07262e8ccdac343e",
+    ("tiny-nemotron", "prefill"): "c9f8561499ad5690b3acded0209edb878075dca092fa30b6ae7c0860eafaed40",
 }
 
 

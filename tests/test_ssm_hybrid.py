@@ -727,3 +727,139 @@ def test_the_refusals_and_the_state_pool_are_on_the_metrics_page():
     for name in ("lmstudio_ssm_{name}_total", "lmstudio_ssm_state_pool_bytes",
                  "lmstudio_feature_refused"):
         assert name in text
+
+
+# -- the tails a tap a plane: the parent's arithmetic, another place in memory --
+
+NEMO = json.loads((ROOT / "benchmark/tests/rehearsal/configs/tiny-nemotron.json").read_text())
+REF_GROUPS = run.load_module(ROOT / "benchmark/references/ssm_latent_moe.py")
+PARENT_STREAMS = ROOT / "tests/ssm_hybrid_parent_streams.json"
+GROUPS = [1, 8]
+
+
+def grouped_config(groups: int) -> ModelConfig:
+    """One group: ``tiny-ssm`` (8 heads of 16, conv_dim 160). Eight: the toy
+    of ``nemotron_h`` (one-sublayer layers, a live router) at 16 heads of 64
+    in 8 groups, a state row a group, conv_dim 1,280."""
+    if groups == 1:
+        return REF.model_config(CONF, SEQ).with_(dtype="float32")
+    hf = dict(NEMO, n_groups=groups, mamba_num_heads=2 * groups)
+    return REF_GROUPS.model_config(hf, SEQ).with_(dtype="float32")
+
+
+def grouped_model(groups: int):
+    """(cfg, seeded parameters) of ``grouped_config``."""
+    from nats_llm_studio_tpu.parallel.mesh import build_mesh
+
+    cfg = grouped_config(groups)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(weights, "INIT_STD", 0.05 if groups == 1 else 0.08)
+    try:
+        mesh = build_mesh({"tp": 1}, devices=jax.local_devices()[:1])
+        return cfg, weights.make_seeded_params(
+            4321, REF if groups == 1 else REF_GROUPS)(None, cfg, mesh)
+    finally:
+        mp.undo()
+
+
+def grouped_stream(groups: int) -> list[dict]:
+    """The served greedy stream the fixture holds: a prompt of 40 in two
+    chunks (the second carries state and tail, and is padded by 8), its state
+    written into slot 1, then 24 paged decode steps. Each position: the token
+    and its top-5 (ids, log-probabilities)."""
+    cfg, params = grouped_model(groups)
+    served = serve(cfg, params, tokens(1, PROMPT), STEPS + 1, chunks=(32, 8), pad=8)
+    return [{"token": e["bytes"][0], "top": [t["bytes"][0] for t in e["top_logprobs"]],
+             "logprobs": [t["logprob"] for t in e["top_logprobs"]]} for e in served]
+
+
+def parent_conv_step(xbc, tail, w, b, fresh):
+    """``ssm_scan.conv_step`` as the parent commit had it, ``tail`` [B, K, C]."""
+    shifted = jnp.concatenate([tail[:, 1:], xbc[:, None].astype(tail.dtype)], axis=1)
+    tail = jnp.where(fresh[:, None, None], shifted, tail)
+    out = jnp.sum(w.astype(jnp.float32)[None] * tail.astype(jnp.float32), axis=1)
+    if b is not None:
+        out = b.astype(jnp.float32) + out
+    return jax.nn.silu(out).astype(xbc.dtype), tail
+
+
+def parent_causal_conv(xbc, tail, w, b, valid):
+    """``ssm_scan.causal_conv`` as the parent commit had it, ``tail`` [B, K, C]."""
+    k, t = w.shape[0], xbc.shape[1]
+    ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+    wf = w.astype(jnp.float32)
+    out = sum(wf[j] * ext[:, 1 + j: 1 + j + t].astype(jnp.float32) for j in range(k))
+    if b is not None:
+        out = b.astype(jnp.float32) + out
+    new_tail = jax.vmap(lambda e, v: jax.lax.dynamic_slice_in_dim(e, v, k, axis=0))(ext, valid)
+    return jax.nn.silu(out).astype(xbc.dtype), new_tail.astype(tail.dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("groups", GROUPS)
+def test_the_convolution_a_tap_a_plane_is_the_parents_row_major_one(groups, dtype):
+    """``conv_step`` and ``causal_conv`` on ``tail`` [K, B, C] against the
+    parent's formulas on [B, K, C], written out above: the same sums in the
+    same order, so equal to float32 round-off, and the tails bit for bit.
+    The step: rows that consume their position, a live row that replays its
+    last one (``start_pos < seen``) and a row that is not live, as
+    ``forward_decode_paged`` makes ``fresh``. The prefill: a ``valid`` of
+    every length from 0 (the old tail is kept) through the K - 1 that leave
+    part of the old tail in the new one, up to all T."""
+    cfg = grouped_config(groups)
+    k, c, dt = cfg.ssm_conv, cfg.ssm_conv_dim, jnp.dtype(dtype)
+    assert c == cfg.ssm_d_inner + 2 * groups * cfg.ssm_d_state
+    ks = jax.random.split(jax.random.PRNGKey(groups), 6)
+    w, b = jax.random.normal(ks[0], (k, c)).astype(dt), jax.random.normal(ks[1], (c,)).astype(dt)
+    rows = 6
+    live = jnp.asarray([True, True, False, True, False, True])
+    start, seen = jnp.asarray([7, 4, 9, 0, 0, 12]), jnp.asarray([7, 5, 3, 0, 0, 12])
+    fresh = live & (start >= seen)
+    assert fresh.tolist() == [True, False, False, True, False, True]
+    tail = jax.random.normal(ks[2], (rows, k, c)).astype(dt)
+    planes = jnp.swapaxes(tail, 0, 1)
+    x = jax.random.normal(ks[3], (rows, c)).astype(dt)
+    t = 6
+    valid = jnp.asarray([0, 1, 2, 3, t - 1, t])
+    xs = jax.random.normal(ks[4], (rows, t, c)).astype(dt)
+    for new, parent, args in ((ssm_scan.conv_step, parent_conv_step, (x, fresh)),
+                              (ssm_scan.causal_conv, parent_causal_conv, (xs, valid))):
+        for bias in (b, None):
+            want, want_tail = parent(args[0], tail, w, bias, args[1])
+            got, got_tail = jax.jit(new)(args[0], planes, w, bias, args[1])
+            assert got_tail.shape == (k, rows, c) and got_tail.dtype == dt and got.dtype == dt
+            np.testing.assert_array_equal(jnp.swapaxes(got_tail, 0, 1), want_tail)
+            np.testing.assert_allclose(got.astype(jnp.float32), want.astype(jnp.float32),
+                                       rtol=1e-6, atol=1e-6)
+    # the prefill's last case, row by row of ``valid``
+    np.testing.assert_array_equal(got_tail[:, 0], tail[0])          # no real position
+    np.testing.assert_array_equal(got_tail[:-2, 2], tail[2, 2:])    # two of the old, two new
+
+
+@pytest.mark.parametrize("groups", GROUPS)
+def test_a_served_stream_is_the_stream_the_parent_commit_served(groups):
+    """``tests/ssm_hybrid_parent_streams.json`` was recorded with
+    ``grouped_stream`` from the PARENT commit (948cf99, tails [Lm, rows, K,
+    C]) before ``state_shapes``, ``conv_step`` and ``causal_conv`` were
+    edited: a chunked, padded prefill and 24 decode steps at one group and at
+    eight (B, C and the gated norm a group's, one-sublayer layers, a live
+    router). Where a tap lies in memory changes no arithmetic: the tokens are
+    the parent's and the top-5 log-probabilities its to float32 round-off.
+    The check that ``correct``'s wide limits could not give PR 56."""
+    want = json.loads(PARENT_STREAMS.read_text())[str(groups)]
+    got = grouped_stream(groups)
+    assert len(got) == len(want) == STEPS + 1
+    assert [e["token"] for e in got] == [e["token"] for e in want]
+    assert [e["top"] for e in got] == [e["top"] for e in want]
+    np.testing.assert_allclose([e["logprobs"] for e in got], [e["logprobs"] for e in want],
+                               rtol=0, atol=1e-5)
+
+
+def test_the_tails_lie_a_tap_a_plane_and_a_row_moves_by_its_axes(model):
+    cfg, _ = model
+    (tail, seen), (plane,) = ssm_hybrid.state_shapes(cfg, SLOTS)
+    assert tail == (cfg.n_ssm_layers, cfg.ssm_conv, SLOTS, cfg.ssm_conv_dim) and seen == (SLOTS,)
+    (k_st, k_axes), (v_st, v_axes) = ssm_hybrid.make_state(cfg, SLOTS)
+    assert k_axes == ssm_hybrid.K_AXES == (2, 0) and v_axes == (0,)
+    assert [a.shape[ax] for a, ax in zip(k_st + v_st, k_axes + v_axes)] == [SLOTS] * 3
+    assert ssm_hybrid.state_bytes_per_slot(cfg) * SLOTS == sum(a.nbytes for a in k_st + v_st)

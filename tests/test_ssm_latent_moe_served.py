@@ -167,3 +167,43 @@ async def test_a_chunked_group_of_four_narrows_and_each_row_takes_its_state_with
         b.stop()
     assert got == alone and all(len(t) == 6 for t in alone)
     assert [w for w, _, _ in group] == NARROW_WIDTHS
+
+
+def test_a_row_taken_out_of_its_slot_and_written_to_another_resumes_its_stream_at_eight_groups():
+    """The path a chunked admit and a settle take (``serve/programs.py``: a
+    row out of a pool or a group by ``state_row``, into a slot by
+    ``state_write_row``, each leaf on the axis ``WithState.axes`` names: the
+    tails' rows lie on axis 2 of [Lm, K, rows, C]), at 8 groups of two heads:
+    a request prefilled in three chunks decodes 8 steps in slot 1, its state,
+    tail and ``seen`` are taken out, written to slot 2 and slot 1's zeroed,
+    and its next 8 steps from slot 2 are the 8 it gives when left where it
+    was."""
+    import jax.numpy as jnp
+    import numpy as np
+    from test_ssm_hybrid import grouped_model
+    from test_ssm_latent_moe import decode, empty_pools, entry, into_pool, prefill
+
+    from nats_llm_studio_tpu.ops.kvcache import WithState, state_row, state_write_row
+
+    cfg, params = grouped_model(8)
+    assert cfg.ssm_n_groups == 8 and ssm_hybrid.K_AXES == (2, 0)
+    p = tokens(1, 40)
+    logits, rows = prefill(cfg, params, p, chunks=(17, 17, 6))
+    began, pools, _ = decode(cfg, params, into_pool(empty_pools(cfg), rows), entry(logits),
+                             len(p), 8)
+    stayed, _, _ = decode(cfg, params, pools, began[-1], len(p) + 8, 8)
+    moved = []
+    for pool in pools:
+        row = state_row(pool, 1)
+        assert [r.shape[ax] for r, ax in zip(row, pool.axes)] == [1] * len(row)
+        there = WithState(pool.kv, state_write_row(pool, row, 2), pool.axes)
+        moved.append(WithState(pool.kv, state_write_row(
+            there, tuple(jnp.zeros_like(r) for r in row), 1), pool.axes))
+        for a, b in zip(state_row(moved[-1], 2), row):
+            np.testing.assert_array_equal(a, b)
+    resumed, _, _ = decode(cfg, params, tuple(moved), began[-1], len(p) + 8, 8, slot=2)
+    assert [e["bytes"] for e in resumed] == [e["bytes"] for e in stayed]
+    np.testing.assert_allclose(
+        [[t["logprob"] for t in e["top_logprobs"]] for e in resumed],
+        [[t["logprob"] for t in e["top_logprobs"]] for e in stayed], rtol=0, atol=1e-5)
+    assert len({e["bytes"][0] for e in stayed}) > 3   # a stream, not one token again and again

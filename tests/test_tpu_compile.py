@@ -791,6 +791,13 @@ def _ssm_pools(cfg, sharding):
             WithState(kv(), (sds(plane, jnp.float32),), ssm_hybrid.V_AXES))
 
 
+def _tail_tiles(text: str, tails) -> set[str]:
+    """The tiles the compiler gave a layer's convolution tail [K, slots, C]
+    wherever the compiled text names it."""
+    _, k, slots, c = tails.shape
+    return set(re.findall(rf"bf16\[{k},{slots},{c}\]\{{[^}}]*?:(T\(\d+,128\)\(2,1\))", text))
+
+
 def test_ssm_state_step_at_the_benchmark_cells_shapes(one_chip, no_cache, ssm_cell):
     """One layer's step over the state pool [32, 36, 32, 128, 128] f32
     (2.4 GB), the live slots a traced mask: Mosaic tiles it, the list rides
@@ -863,6 +870,10 @@ def test_a_decode_launch_of_the_state_space_family_copies_no_pool(one_chip, no_c
     copies = [ln.strip()[:160] for ln in text.splitlines()
               if (" copy(" in ln or "copy-start(" in ln) and any(p in ln for p in pools)]
     assert not copies, copies
+    # a tap a plane, the slots on the sublanes: whole 8-row bf16 tiles, where
+    # [slots, K, C] gave T(4,128)(2,1) and two relayout copies a layer (PR 57)
+    assert tails.shape == (cfg.n_ssm_layers, cfg.ssm_conv, SSM_SLOTS, cfg.ssm_conv_dim)
+    assert _tail_tiles(text, tails) == {"T(8,128)(2,1)"}
     ma = compiled.memory_analysis()
     state_bytes = int(np.prod(state.shape)) * 4
     assert ma.alias_size_in_bytes >= state_bytes + 2 * int(np.prod(kv.shape)) * 2
@@ -1659,6 +1670,8 @@ def test_a_decode_burst_of_the_latent_expert_family_copies_no_pool_and_no_stack(
               if (" copy(" in ln or "copy-start(" in ln) and any(p in ln for p in pools)
               and "S(1)" not in ln]
     assert not copies, copies
+    assert tails.shape == (5, cfg.ssm_conv, LMOE_SLOTS, cfg.ssm_conv_dim)
+    assert _tail_tiles(text, tails) == {"T(8,128)(2,1)"}   # a tap a plane (PR 57)
     assert not _expert_stack_copies(text, cfg)
     ma = compiled.memory_analysis()
     state_bytes = int(np.prod(state.shape)) * 4
